@@ -1,0 +1,828 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+Drives the two hot paths once each through the entry points a user
+calls, at the full width of models the repo supports, on one TPU chip:
+
+  kernels  every Pallas kernel compiled by Mosaic (never interpreted) at
+           the shapes the next two phases use, against the dense function
+           that sits beside it, flash backward included;
+  train    GPT-3 1.3B (vocab 50304, hidden 2048, 24 layers, 16 heads of
+           128, S=1024, bf16 params and moments, B=4) through
+           ``fleet.init`` -> ``ParallelEngine.train_step``;
+  serve    Llama-7B widths (hidden 4096, 32 heads of 128, ffn 11008,
+           vocab 32000), depth cut to fit one chip, through
+           ``Config.enable_paged_kv`` -> ``create_predictor`` ->
+           ``ServingEngine`` in both of its modes.
+
+``--four-chips`` adds the same train step over a real 2x2 mesh in two
+layouts (mp2 x dp2 on ParallelEngine; pp2 x mp2 on GPTForCausalLMPipe via
+``fleet.distributed_model(...).train_batch``); asked for, fewer than four
+TPU devices is an error.
+
+    python chip_smoke.py                        # one chip
+    python chip_smoke.py --four-chips           # one four-chip host
+    python -m paddle_tpu.distributed.launch chip_smoke.py --four-chips
+    python chip_smoke.py --phases kernels       # a subset, in order
+
+Process model: a chip belongs to one process at a time and HBM is only
+reliably released at process exit, so every phase is a child process and
+this parent never touches JAX. A child that finds no TPU exits non-zero
+and says which platform it found; the parent then prints no result.
+
+The last line of stdout on success is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``
+with the device as JAX reported it to the children. Any failed check, in
+any phase, is a non-zero exit and no such line.
+
+``--rehearsal`` is for whoever types it: the same phases at toy sizes on
+the CPU (kernels in interpret mode, four virtual devices), to debug this
+script without spending chip time. Its output says REHEARSAL on every
+phase and in the result line. The program never chooses it.
+
+The step times and token rates printed here name the device beside them
+and are not metrics: nothing is claimed from them.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import subprocess
+import sys
+import threading
+import time
+
+ONE_CHIP_PHASES = ("kernels", "train", "serve")
+FOUR_CHIP_PHASES = ("mp2dp2", "pp2mp2")
+# seconds per child, compilation included. The one-chip three sum to
+# 1100, inside the 1200 s that run is allowed; measured cold on a v5e
+# they took 72, 122 and 106 s (CHANGES.md PR 21).
+PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
+                 "mp2dp2": 400, "pp2mp2": 400}
+RESULT_TAG = "CHIP_SMOKE_PHASE_RESULT "
+
+# Tolerances, each with its reason. Every comparison is
+#   max|kernel - ref| / max(1, max|ref|)
+# with the kernel fed bf16 and the dense twin fed the SAME values in f32.
+# bf16 keeps 8 significant bits (relative step 2^-8 = 3.9e-3).
+# - attention forward: probabilities are rounded to bf16 before the PV
+#   matmul and the output is rounded to bf16 once more; outputs are O(1)
+#   convex combinations of |v| <= ~4.5, so a few times 3.9e-3 * 4.5.
+TOL_ATTN = 3e-2
+# - rms_norm: f32 math on both sides, the kernel rounds its output to
+#   bf16 once: half a bf16 step of the largest output.
+TOL_RMS = 1e-2
+# - flash backward: ds and p are rounded to bf16 before their matmuls
+#   and 1024 rows accumulate in f32, so the error random-walks to about
+#   sqrt(N) * 3.9e-3 of a term against a sum of about sqrt(N) terms.
+TOL_BWD = 5e-2
+# - first training loss: ln(V) plus half the logit variance at init
+#   (tied embedding std 0.02 over a unit-variance hidden state: 0.41 at
+#   hidden 2048), computed through bf16 weights.
+LOSS_BAND = 1.0
+# - one chip against four: same seed, same batch, same math; only the
+#   reduction order (mp splits each contraction, dp/pp split the batch
+#   mean) and where bf16 rounding lands differ. The loss is a mean over
+#   4096 tokens so per-token bf16 noise averages out; a missing or
+#   doubled collective moves it by tenths.
+TOL_LOSS_4CHIP = 2e-2
+# - serving: the engine's first generated token must score within this
+#   of the best logit of an independent full forward (flash path) on the
+#   same prompt. Random weights make near-ties common, so tokens are not
+#   compared for equality; logits have std ~1.3, a wrong token sits
+#   several units below the maximum.
+TOL_LOGIT = 0.5
+
+
+class Sizes:
+    """The full-width sizes, and the toy ones ``--rehearsal`` swaps in."""
+
+    def __init__(self, rehearsal: bool):
+        self.rehearsal = rehearsal
+        self.state_dtype = "bfloat16"
+        if not rehearsal:
+            # train: exactly bench.py's bench_gpt
+            self.gpt = dict(vocab_size=50304, hidden_size=2048,
+                            num_layers=24, num_heads=16,
+                            max_position_embeddings=1024,
+                            dtype="bfloat16")
+            self.B, self.S, self.steps = 4, 1024, 4
+            # serve: Llama-7B widths. Depth 8 of 32: 8 layers are 1.88B
+            # parameters = 3.8 GB in bf16, and the default page pool
+            # (8 rows x 18 pages + 1, on the power-of-two lattice: 256
+            # pages x 16.8 MB) is 4.3 GB, so weights + pool + the
+            # 2048-token prefill's activations fit 16 GB with room.
+            self.llama = dict(hidden_size=4096, num_heads=32,
+                              intermediate_size=11008, vocab_size=32000,
+                              max_position_embeddings=2304, num_layers=8,
+                              dtype="bfloat16")
+            self.page, self.max_batch = 128, 8
+            self.decode_chunk, self.prefill_chunk = 8, 256
+            self.new_tokens = 32
+            # one prompt per prefill bucket (128 .. 2048), then a dozen
+            self.warm_lens = (100, 200, 400, 900, 1500)
+            self.len_range, self.n_requests = (100, 1500), 12
+            self.ref_prompt_len = 128
+        else:
+            self.gpt = dict(vocab_size=1024, hidden_size=128,
+                            num_layers=2, num_heads=4,
+                            max_position_embeddings=64,
+                            dtype="bfloat16")
+            self.B, self.S, self.steps = 4, 64, 4
+            self.llama = dict(hidden_size=128, num_heads=4,
+                              intermediate_size=256, vocab_size=512,
+                              max_position_embeddings=288, num_layers=2,
+                              dtype="bfloat16")
+            self.page, self.max_batch = 16, 4
+            self.decode_chunk, self.prefill_chunk = 4, 32
+            self.new_tokens = 6
+            self.warm_lens = (20, 100, 200)
+            self.len_range, self.n_requests = (10, 200), 6
+            self.ref_prompt_len = 64
+
+
+# ---------------------------------------------------------------------------
+# what every child does first and last
+# ---------------------------------------------------------------------------
+class Failed(Exception):
+    """A check did not hold."""
+
+
+def check(cond: bool, what: str) -> None:
+    print(("  ok   " if cond else "  FAIL ") + what, flush=True)
+    if not cond:
+        raise Failed(what)
+
+
+class JaxEvents:
+    """Counts of XLA compile requests and persistent-cache traffic, from
+    JAX's own monitoring events (nothing here changes what JAX does)."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    REQ = "/jax/compilation_cache/compile_requests_use_cache"
+    HIT = "/jax/compilation_cache/cache_hits"
+    WRITE = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.n = {self.COMPILE: 0, self.REQ: 0, self.HIT: 0, self.WRITE: 0}
+        self.compile_secs = 0.0
+        mon.register_event_listener(self._event)
+        mon.register_event_duration_secs_listener(self._duration)
+
+    def _event(self, name, **kw):
+        if name in self.n:
+            self.n[name] += 1
+
+    def _duration(self, name, secs, **kw):
+        if name == self.COMPILE:
+            self.n[name] += 1
+            self.compile_secs += secs
+
+    @property
+    def compiles(self) -> int:
+        return self.n[self.COMPILE]
+
+    def cache_line(self) -> dict:
+        req, hit = self.n[self.REQ], self.n[self.HIT]
+        return {"xla_compile_requests": self.compiles,
+                "xla_compile_or_fetch_s": round(self.compile_secs, 1),
+                "cache_requests": req, "cache_hits": hit,
+                "cache_misses": req - hit,
+                "cache_entries_written": self.n[self.WRITE]}
+
+
+def start_child(rehearsal: bool, need_devices: int = 1):
+    """Import JAX and the package, say what we run on, refuse a machine
+    without a TPU. Returns (jax, device dict, JaxEvents)."""
+    import jax
+
+    import paddle_tpu  # noqa: F401  (first: places the compile cache)
+
+    events = JaxEvents()
+    devs = jax.devices()
+    d = devs[0]
+    try:
+        import libtpu
+
+        libtpu_version = getattr(libtpu, "__version__", "unknown")
+    except ImportError:
+        libtpu_version = "not installed"
+    device = {"platform": d.platform, "kind": d.device_kind,
+              "count": len(devs)}
+    import jaxlib
+
+    print(f"  device: platform={d.platform} device_kind={d.device_kind!r} "
+          f"count={len(devs)} jax={jax.__version__} "
+          f"jaxlib={jaxlib.__version__} libtpu={libtpu_version}",
+          flush=True)
+    cache_dir = jax.config.jax_compilation_cache_dir
+    where = ("JAX_COMPILATION_CACHE_DIR, set from outside"
+             if os.environ.get("JAX_COMPILATION_CACHE_DIR")
+             else "the fixed path in the checkout" if cache_dir
+             else "a process pinned to the CPU keeps none")
+    print(f"  compile cache: {cache_dir} ({where})", flush=True)
+    if rehearsal:
+        print("  REHEARSAL: toy sizes on the CPU, asked for by the caller; "
+              "proves nothing about the chip", flush=True)
+    elif d.platform != "tpu":
+        print(f"chip_smoke: no accelerator: jax found platform "
+              f"{d.platform!r} ({d.device_kind}); this script only "
+              "passes on a TPU", file=sys.stderr, flush=True)
+        sys.exit(3)
+    if not rehearsal and len(devs) < need_devices:
+        print(f"chip_smoke: this phase needs {need_devices} TPU devices, "
+              f"jax found {len(devs)}", file=sys.stderr, flush=True)
+        sys.exit(3)
+    return jax, device, events
+
+
+def kernel_names(text: str) -> dict:
+    """kernel_name -> count over the Mosaic custom calls of a lowered
+    program (each pallas_call lowers to one ``tpu_custom_call``)."""
+    import re
+
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    out = {}
+    for n in names:
+        out[n] = out.get(n, 0) + 1
+    if text.count("tpu_custom_call") < len(names):
+        raise Failed("kernel_name attributes without tpu_custom_call")
+    return out
+
+
+def finish_child(phase: str, device: dict, events: JaxEvents,
+                 extra: dict) -> None:
+    out = {"phase": phase, "ok": True, "device": device}
+    out.update(events.cache_line())
+    out.update(extra)
+    print(RESULT_TAG + json.dumps(out), flush=True)
+
+
+# ---------------------------------------------------------------------------
+# phase: kernels
+# ---------------------------------------------------------------------------
+def kernel_cases(sz: Sizes):
+    """(name, kernel names, build, tolerance) for every Pallas kernel at
+    the shapes the train and serve phases use. ``build(normal)`` returns
+    (kernel_fn, dense_fn, args): ``normal(shape)`` makes each big bf16
+    operand, so the smoke passes seeded random arrays and
+    tests/test_chip_bringup.py passes shapes only and lowers the same
+    table. dense_fn takes the same args cast to f32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.core.bucketing import bucket
+
+    g, L = sz.gpt, sz.llama
+    Hd = g["hidden_size"] // g["num_heads"]
+    Ld = L["hidden_size"] // L["num_heads"]
+    H, hid = L["num_heads"], L["hidden_size"]
+    page, B = sz.page, sz.max_batch
+    M = L["max_position_embeddings"]
+    npages = -(-M // page)
+    P = B * npages + 1
+    interpret = sz.rehearsal
+    r = np.random.RandomState(1)      # block tables and lengths only
+    cases = []
+
+    def flash(normal):
+        from paddle_tpu.ops.attention import _sdpa_raw
+        from paddle_tpu.ops.pallas.flash_attention import \
+            flash_attention_fwd
+
+        shape = (sz.B, sz.S, g["num_heads"], Hd)
+        return (lambda q, k, v, w: flash_attention_fwd(
+                    q, k, v, True, None, interpret),
+                lambda q, k, v, w: _sdpa_raw(
+                    q, k, v, attn_mask=None, dropout_p=0.0,
+                    is_causal=True),
+                tuple(normal(shape) for _ in range(4)))
+
+    cases.append(("flash_attention fwd", ("flash_attention_fwd",), flash,
+                  TOL_ATTN))
+
+    def flash_bwd(normal):
+        kern, dense, args = flash(normal)
+
+        def grads(f):
+            return lambda q, k, v, w: jnp.stack(jax.grad(
+                lambda q, k, v: (f(q, k, v, w).astype(jnp.float32)
+                                 * w.astype(jnp.float32)).sum(),
+                argnums=(0, 1, 2))(q, k, v))
+
+        return grads(kern), grads(dense), args
+
+    cases.append(("flash_attention bwd (dq, dk, dv)",
+                  ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv"), flash_bwd, TOL_BWD))
+
+    # rms_norm at the row counts serving feeds it: a decode step
+    # [B, 1], the widest prefill bucket [1, Sb], a unified chunk [B, Sc]
+    buckets = sorted({min(bucket(n), M) for n in sz.warm_lens})
+    for shp in ((B, 1, hid), (1, buckets[-1], hid),
+                (B, sz.prefill_chunk, hid)):
+        def rms(normal, shp=shp):
+            from paddle_tpu.ops.pallas.rms_norm import (rms_norm_dense,
+                                                        rms_norm_fused)
+
+            return (lambda x, w: rms_norm_fused(x, w, 1e-5, interpret),
+                    lambda x, w: rms_norm_dense(x, w, 1e-5),
+                    (normal(shp), normal(shp[-1:])))
+
+        cases.append((f"rms_norm_fused {shp}", ("rms_norm_fused",), rms,
+                      TOL_RMS))
+
+    def ragged_lens():
+        return r.randint(1, M - 1, (B,)).astype("int32")
+
+    def decode(normal):
+        from paddle_tpu.models.llama import _cache_attention_dense
+        from paddle_tpu.ops.pallas.decode_attention import \
+            decode_attention
+
+        off = jnp.asarray(ragged_lens())
+        return (lambda q, kc, vc: decode_attention(
+                    q, kc, vc, off, interpret=interpret),
+                lambda q, kc, vc: _cache_attention_dense(
+                    q, kc, vc, off, 1),
+                (normal((B, 1, H, Ld)), normal((B, H, M, Ld)),
+                 normal((B, H, M, Ld))))
+
+    cases.append((f"decode_attention Sq=1 B={B} M={M}",
+                  ("decode_attention",), decode, TOL_ATTN))
+
+    def table(rows):
+        return jnp.asarray(r.permutation(P - 1)[:rows * npages].reshape(
+            rows, npages), jnp.int32)
+
+    pool = (P, H, page, Ld)
+
+    def paged(rows, Sq, lens):
+        def build(normal):
+            from paddle_tpu.ops.pallas.decode_attention import (
+                paged_attention_dense, paged_decode_attention)
+
+            tbl = table(rows)
+            return (lambda q, kp, vp: paged_decode_attention(
+                        q, kp, vp, tbl, lens, interpret=interpret),
+                    lambda q, kp, vp: paged_attention_dense(
+                        q, kp, vp, tbl, lens),
+                    (normal((rows, Sq, H, Ld)), normal(pool),
+                     normal(pool)))
+
+        return build
+
+    cases.append((f"paged_decode_attention Sq=1 B={B} page={page}",
+                  ("paged_decode_attention",),
+                  paged(B, 1, jnp.asarray(ragged_lens())), TOL_ATTN))
+    # the bucketed prefill runs the SAME paged kernel at Sq = the bucket
+    for Sb in buckets:
+        cases.append((f"paged_decode_attention prefill Sq={Sb}",
+                      ("paged_decode_attention",),
+                      paged(1, Sb, jnp.zeros((1,), jnp.int32)), TOL_ATTN))
+
+    def ragged(normal):
+        from paddle_tpu.ops.pallas.ragged_paged_attention import (
+            ragged_paged_attention, ragged_paged_attention_dense)
+
+        Sc = sz.prefill_chunk
+        # a full chunk mid-prompt, a first chunk, a partial last chunk,
+        # a dead row, and decode rows for the rest
+        starts = ragged_lens()
+        nv = [1] * B
+        starts[0], nv[0] = 3 * Sc, Sc
+        starts[1], nv[1] = 0, Sc
+        starts[2], nv[2] = Sc, max(Sc // 3, 1)
+        starts[3], nv[3] = 0, 0
+        starts = jnp.asarray(starts)
+        nv = jnp.asarray(nv, jnp.int32)
+        tbl = table(B)
+        return (lambda q, kp, vp: ragged_paged_attention(
+                    q, kp, vp, tbl, starts, nv, interpret=interpret),
+                lambda q, kp, vp: ragged_paged_attention_dense(
+                    q, kp, vp, tbl, starts, nv),
+                (normal((B, Sc, H, Ld)), normal(pool), normal(pool)))
+
+    cases.append((f"ragged_paged_attention Sc={sz.prefill_chunk} B={B}",
+                  ("ragged_paged_attention",), ragged, TOL_ATTN))
+    return cases
+
+
+def phase_kernels(sz: Sizes) -> None:
+    jax, device, events = start_child(sz.rehearsal)
+    import jax.numpy as jnp
+    import numpy as np
+
+    results = {}
+    rng = np.random.default_rng(0)
+
+    def normal(shape):
+        return jnp.asarray(rng.standard_normal(shape, dtype=np.float32),
+                           jnp.bfloat16)
+
+    for name, expect, build, tol in kernel_cases(sz):
+        kern, dense, args = build(normal)
+        lowered = jax.jit(kern).lower(*args)
+        found = kernel_names(lowered.as_text())
+        check(sz.rehearsal or all(found.get(n, 0) >= 1 for n in expect),
+              f"{name}: lowered to Mosaic tpu_custom_call {found}")
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(lowered.compile()(*args))
+        t_first = time.perf_counter() - t0
+        ref = jax.block_until_ready(jax.jit(dense)(
+            *[a.astype(jnp.float32) for a in args]))
+        out32 = np.asarray(out.astype(jnp.float32))
+        ref32 = np.asarray(ref.astype(jnp.float32))
+        check(out32.shape == ref32.shape and np.isfinite(out32).all(),
+              f"{name}: finite, shape {out32.shape}")
+        err = float(np.abs(out32 - ref32).max()
+                    / max(1.0, np.abs(ref32).max()))
+        check(err <= tol, f"{name}: err {err:.2e} <= {tol:.0e} of its "
+                          f"dense twin (compile + run {t_first:.1f}s)")
+        results[name] = round(err, 5)
+    finish_child("kernels", device, events, {"errors": results})
+
+
+# ---------------------------------------------------------------------------
+# phase: train (and the two four-chip layouts of the same step)
+# ---------------------------------------------------------------------------
+LAYOUTS = {
+    "train": {"dp_degree": 1, "mp_degree": 1},
+    "mp2dp2": {"dp_degree": 2, "mp_degree": 2},
+    "pp2mp2": {"pp_degree": 2, "mp_degree": 2},
+}
+
+
+def phase_train(sz: Sizes, layout: str) -> None:
+    degrees = LAYOUTS[layout]
+    n_dev = 1
+    for v in degrees.values():
+        n_dev *= v
+    jax, device, events = start_child(sz.rehearsal, need_devices=n_dev)
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.distributed.engine import ParallelEngine, param_spec
+    from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
+                                   GPTForCausalLMPipe,
+                                   GPTPretrainingCriterion)
+
+    cfg = GPTConfig(**sz.gpt)
+    pipe = degrees.get("pp_degree", 1) > 1
+    strategy = fleet.DistributedStrategy()
+    strategy.hybrid_configs = dict(degrees)
+    if pipe:
+        strategy.pipeline_configs = {"accumulate_steps": 2,
+                                     "micro_batch_size": sz.B // 2}
+    hcg = fleet.init(is_collective=True, strategy=strategy)
+    print(f"  layout {layout}: {degrees}, mesh {dict(hcg.mesh.shape)} on "
+          f"{[d.id for d in hcg.mesh.devices.flat]}", flush=True)
+
+    t_setup = time.perf_counter()
+    paddle.seed(0)
+    model = GPTForCausalLMPipe(cfg) if pipe else GPTForCausalLM(cfg)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters(),
+                                 state_dtype=sz.state_dtype)
+    ids = np.random.RandomState(0).randint(0, cfg.vocab_size,
+                                           (sz.B, sz.S + 1))
+    x, y = paddle.to_tensor(ids[:, :-1]), paddle.to_tensor(ids[:, 1:])
+    if pipe:
+        dist_model = fleet.distributed_model(model)
+        dopt = fleet.distributed_optimizer(opt)
+
+        def step():
+            return dist_model.train_batch([x, y], dopt)
+    else:
+        crit = GPTPretrainingCriterion(cfg)
+        eng = ParallelEngine(model, opt, hcg.mesh)
+        train_step = eng.train_step(lambda m, b: crit(m(b["x"]), b["y"]))
+
+        def step():
+            return train_step({"x": x, "y": y})
+
+    losses = [float(step())]          # warm-up: trace + compile + run
+    t_setup = time.perf_counter() - t_setup
+    compiles_after_warmup = events.compiles
+    t0 = time.perf_counter()
+    for _ in range(sz.steps):
+        losses.append(float(step()))  # float() waits for the device
+    t_run = time.perf_counter() - t0
+    if pipe:
+        eng = dist_model.engine
+    step_s = t_run / sz.steps
+    print(f"  set-up (build + compile + first step) {t_setup:.1f}s, then "
+          f"{sz.steps} steps in {t_run:.2f}s: {step_s * 1e3:.0f} ms/step, "
+          f"{sz.B * sz.S / step_s:.0f} tokens/s on {n_dev} x "
+          f"{device['kind']} (not a metric)", flush=True)
+    print(f"  losses {[round(v, 4) for v in losses]}", flush=True)
+
+    lnv = math.log(cfg.vocab_size)
+    check(all(math.isfinite(v) for v in losses), "every loss finite")
+    check(abs(losses[0] - lnv) < LOSS_BAND,
+          f"first loss {losses[0]:.3f} within {LOSS_BAND} of "
+          f"ln({cfg.vocab_size})={lnv:.2f}")
+    check(losses[-1] < losses[0],
+          f"loss fell: {losses[0]:.3f} -> {losses[-1]:.3f}")
+    check(eng.stats.compiles == 1,
+          f"eng.stats.compiles == 1 (got {eng.stats.compiles})")
+    check(events.compiles == compiles_after_warmup,
+          f"no XLA compile after the warm-up step "
+          f"({events.compiles - compiles_after_warmup} seen)")
+    # the model state the step carries, per device, as the engine
+    # measures it: params plus two moments in the dtype that was asked for
+    acct = eng.state_accounting().components
+    print(f"  state per device: params {acct['params'] / 2**30:.2f} GiB, "
+          f"optimizer {acct['optimizer_state'] / 2**30:.2f} GiB, masters "
+          f"{acct.get('master_weights', 0) / 2**30:.2f} GiB", flush=True)
+    ratio = (np.dtype(sz.state_dtype).itemsize
+             / np.dtype(cfg.dtype).itemsize)
+    check(acct["optimizer_state"] <= 2 * ratio * acct["params"] * 1.01,
+          f"optimizer moments are stored in {sz.state_dtype} as asked "
+          f"(two moments, {ratio:g}x the {cfg.dtype} parameter bytes each)")
+    found = kernel_names(eng.lowered_text())
+    check(sz.rehearsal or all(found.get(n, 0) >= 1 for n in
+                              ("flash_attention_fwd", "flash_attention_dq",
+                               "flash_attention_dkv")),
+          f"compiled step holds flash fwd and bwd as Mosaic calls {found}")
+
+    # every device of the mesh holds memory, and each parameter's shards
+    # have the shape its PartitionSpec says
+    from jax.sharding import NamedSharding
+
+    mesh_devs = list(hcg.mesh.devices.flat)
+    holders = set()
+    bad = []
+    for p in eng.params:
+        want = NamedSharding(hcg.mesh, param_spec(p)).shard_shape(
+            tuple(p._value.shape))
+        for sh in p._value.addressable_shards:
+            holders.add(sh.device)
+            if tuple(sh.data.shape) != tuple(want):
+                bad.append((getattr(p, "name", "?"),
+                            tuple(sh.data.shape), tuple(want)))
+    check(not bad, f"every parameter shard has its param_spec shape "
+                   f"({len(eng.params)} params; first bad: {bad[:1]})")
+    check(holders == set(mesh_devs),
+          f"parameter shards live on all {len(mesh_devs)} mesh devices "
+          f"(found {sorted(d.id for d in holders)})")
+    mem = {}
+    for d in mesh_devs:
+        stats = d.memory_stats() or {}
+        mem[d.id] = stats.get("bytes_in_use", 0)
+    print("  bytes_in_use per device: "
+          + ", ".join(f"{i}: {b / 2**30:.2f} GiB" for i, b in mem.items()),
+          flush=True)
+    if not sz.rehearsal:       # the CPU backend reports no memory stats
+        check(all(b > 0 for b in mem.values()),
+              "every mesh device reports bytes_in_use > 0")
+    finish_child(layout, device, events, {
+        "losses": losses, "setup_s": round(t_setup, 1),
+        "step_ms": round(step_s * 1e3, 1), "devices_used": n_dev})
+
+
+# ---------------------------------------------------------------------------
+# phase: serve
+# ---------------------------------------------------------------------------
+def phase_serve(sz: Sizes) -> None:
+    jax, device, events = start_child(sz.rehearsal)
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import (Config, ServingEngine,
+                                      create_predictor)
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    cfg = LlamaConfig(**sz.llama)
+    print(f"  Llama widths hidden={cfg.hidden_size} heads={cfg.num_heads}x"
+          f"{cfg.head_dim} ffn={cfg.intermediate_size} vocab="
+          f"{cfg.vocab_size}, depth {cfg.num_layers} "
+          f"({cfg.num_params() / 1e9:.2f}B params; cut from 32 so weights "
+          f"+ the page pool fit one 16 GB chip), page_size={sz.page}",
+          flush=True)
+    t0 = time.perf_counter()
+    paddle.set_default_dtype(cfg.dtype)
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    pred = create_predictor(
+        Config().set_model(model).enable_paged_kv(page_size=sz.page))
+    print(f"  model + predictor built in {time.perf_counter() - t0:.1f}s",
+          flush=True)
+
+    r = np.random.RandomState(0)
+
+    def prompts(lens):
+        return [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
+                for n in lens]
+
+    warm = prompts(sz.warm_lens)
+    lens = r.randint(sz.len_range[0], sz.len_range[1] + 1,
+                     (sz.n_requests,))
+    lens[0] = sz.ref_prompt_len     # the one checked against a reference
+    mix = prompts(lens)
+    print(f"  warm-up prompt lengths {list(sz.warm_lens)}, then "
+          f"{sz.n_requests} requests of {sorted(int(n) for n in lens)} "
+          f"tokens, {sz.new_tokens} new tokens each", flush=True)
+
+    # reference for one prompt: an independent full forward through
+    # Predictor.run (no KV cache: the flash-attention path)
+    logits = pred.run([mix[0][None, :]])[0]
+    check(logits.shape == (1, sz.ref_prompt_len, cfg.vocab_size)
+          and np.isfinite(logits.astype("float32")).all(),
+          f"reference forward: finite logits of shape {logits.shape}")
+    ref_last = logits[0, -1].astype("float32")
+
+    modes = (("bucketed prefill + fused decode scan", {}),
+             ("chunked unified step",
+              {"prefill_chunk": sz.prefill_chunk}))
+    report = {}
+    for mode, kw in modes:
+        print(f"  -- ServingEngine {mode} {kw}", flush=True)
+        eng = ServingEngine(pred, max_batch=sz.max_batch,
+                            decode_chunk=sz.decode_chunk, **kw)
+        t0 = time.perf_counter()
+        for p in warm:
+            eng.submit(p, max_new_tokens=sz.new_tokens)
+        done = eng.run()
+        t_setup = time.perf_counter() - t0
+        check(len(done) == len(warm), f"warm-up mix drained ({len(done)})")
+        compiles0, xla0 = eng.stats.compiles, events.compiles
+        t0 = time.perf_counter()
+        rids = [eng.submit(p, max_new_tokens=sz.new_tokens) for p in mix]
+        done = eng.run()
+        t_run = time.perf_counter() - t0
+        outs = [np.asarray(done[rid].new_tokens) for rid in rids
+                if rid in done]
+        n_tok = sum(len(o) for o in outs)
+        print(f"    set-up (compile + warm-up mix) {t_setup:.1f}s; "
+              f"{len(mix)} requests in {t_run:.2f}s, {n_tok / t_run:.0f} "
+              f"generated tokens/s on {device['kind']} (not a metric); "
+              f"pool {eng.P} pages", flush=True)
+        check(len(outs) == len(rids) and not any(
+            done[rid].shed for rid in rids), "every request finished")
+        check(all(len(o) == sz.new_tokens for o in outs),
+              f"every request returned exactly {sz.new_tokens} tokens")
+        check(all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
+              "every token in vocabulary")
+        check(eng.stats.compiles == compiles0,
+              f"eng.stats.compiles flat after warm-up ({compiles0})")
+        check(events.compiles == xla0,
+              f"no XLA compile after warm-up "
+              f"({events.compiles - xla0} seen)")
+        tok0 = int(outs[0][0])
+        gap = float(ref_last.max() - ref_last[tok0])
+        check(gap <= TOL_LOGIT,
+              f"first token {tok0} scores within {TOL_LOGIT} of the "
+              f"reference forward's best logit (gap {gap:.3f})")
+        want = {"decode": ("paged_decode_attention", "rms_norm_fused"),
+                "prefill": ("paged_decode_attention", "rms_norm_fused"),
+                "unified": ("ragged_paged_attention", "rms_norm_fused")}
+        sites = eng.program_sites()
+        for site in sites:
+            found = kernel_names(eng.lowered_text(site))
+            check(sz.rehearsal or all(found.get(n, 0) >= 1
+                                      for n in want.get(site[0], ())),
+                  f"program {site} holds Mosaic calls {found}")
+        kinds = {s[0] for s in sites}
+        check(("unified" in kinds) if kw else
+              ({"prefill", "decode"} <= kinds),
+              f"the programs that ran: {sorted(map(str, sites))}")
+        report[mode] = {"setup_s": round(t_setup, 1),
+                        "run_s": round(t_run, 2), "pool_pages": eng.P}
+        del eng      # its page pool, before the next engine builds one
+    finish_child("serve", device, events, {"modes": report})
+
+
+# ---------------------------------------------------------------------------
+# parent: children, in order, one at a time; never imports JAX
+# ---------------------------------------------------------------------------
+def run_phase_child(phase: str, rehearsal: bool, env: dict):
+    """Run one phase as a child, passing its output through line by
+    line. Returns its result dict, or None if it failed, timed out or
+    printed none. The child is dead when this returns."""
+    cmd = [sys.executable, os.path.abspath(__file__), "--phase", phase]
+    if rehearsal:
+        cmd.append("--rehearsal")
+    print(f"== phase {phase}" + (" (REHEARSAL)" if rehearsal else ""),
+          flush=True)
+    t0 = time.perf_counter()
+    proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
+                            stderr=subprocess.STDOUT, text=True, env=env)
+    timer = threading.Timer(PHASE_TIMEOUT[phase], proc.kill)
+    timer.daemon = True
+    timer.start()
+    result = None
+    try:
+        for line in proc.stdout:
+            if line.startswith(RESULT_TAG):
+                result = json.loads(line[len(RESULT_TAG):])
+            else:
+                sys.stdout.write(line)
+                sys.stdout.flush()
+        rc = proc.wait()
+    finally:
+        timer.cancel()
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    dt = time.perf_counter() - t0
+    if rc != 0 or result is None:
+        why = (f"killed after {PHASE_TIMEOUT[phase]}s" if rc in (-9, 137)
+               else f"exit code {rc}")
+        print(f"== phase {phase} FAILED ({why}, {dt:.0f}s)", flush=True)
+        return None
+    keys = ("xla_compile_requests", "xla_compile_or_fetch_s",
+            "cache_hits", "cache_misses", "cache_entries_written")
+    print(f"== phase {phase} ok in {dt:.0f}s; "
+          + ", ".join(f"{k}={result[k]}" for k in keys), flush=True)
+    return result
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--four-chips", action="store_true",
+                    help="also run the train step over a 2x2 mesh in two "
+                         "layouts; fewer than four TPU devices is an error")
+    ap.add_argument("--phases", default=None,
+                    help="comma-separated subset, run in the given order: "
+                         + ",".join(ONE_CHIP_PHASES + FOUR_CHIP_PHASES))
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="toy sizes on the CPU; output says REHEARSAL")
+    ap.add_argument("--phase", default=None, help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+    sz = Sizes(args.rehearsal)
+
+    if args.phase:                       # a child: one phase, this process
+        try:
+            if args.phase == "kernels":
+                phase_kernels(sz)
+            elif args.phase == "serve":
+                phase_serve(sz)
+            else:
+                phase_train(sz, args.phase)
+        except Failed as e:
+            print(f"chip_smoke: phase {args.phase}: check failed: {e}",
+                  file=sys.stderr, flush=True)
+            return 1
+        return 0
+
+    if args.phases:
+        phases = tuple(args.phases.split(","))
+        unknown = set(phases) - set(ONE_CHIP_PHASES + FOUR_CHIP_PHASES)
+        if unknown:
+            ap.error(f"unknown phases {sorted(unknown)}")
+    else:
+        phases = ONE_CHIP_PHASES + (FOUR_CHIP_PHASES if args.four_chips
+                                    else ())
+    if set(phases) & set(FOUR_CHIP_PHASES) and "train" not in phases:
+        phases = ("train",) + phases    # their one-chip reference loss
+
+    env = dict(os.environ)
+    if args.rehearsal:
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                            " --xla_force_host_platform_device_count=4")
+    t0 = time.perf_counter()
+    results = {}
+    for phase in phases:
+        res = run_phase_child(phase, args.rehearsal, env)
+        if res is None:
+            print(f"chip_smoke: FAILED in phase {phase}; no result",
+                  file=sys.stderr, flush=True)
+            return 1
+        results[phase] = res
+    ok = True
+    for phase in FOUR_CHIP_PHASES:
+        if phase in results:
+            a, b = results["train"]["losses"][0], results[phase]["losses"][0]
+            good = abs(a - b) <= TOL_LOSS_4CHIP
+            ok &= good
+            print(("  ok   " if good else "  FAIL ")
+                  + f"{phase} step-0 loss {b:.4f} vs one chip {a:.4f}: "
+                    f"|diff| {abs(a - b):.4f} <= {TOL_LOSS_4CHIP}",
+                  flush=True)
+    if not ok:
+        print("chip_smoke: FAILED: four-chip loss differs from one chip; "
+              "no result", file=sys.stderr, flush=True)
+        return 1
+    if not set(phases) & set(FOUR_CHIP_PHASES):
+        print("four-chip phase: not run (ask with --four-chips on a "
+              "four-chip host)", flush=True)
+    device = results[phases[-1]]["device"]
+    print(f"chip_smoke: {len(phases)} phases ok in "
+          f"{time.perf_counter() - t0:.0f}s: {', '.join(phases)}",
+          flush=True)
+    final = {"ok": True, "device": device}
+    if args.rehearsal:
+        final["rehearsal"] = True
+    print(json.dumps(final), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -279,12 +279,8 @@ def in_spmd_region() -> bool:
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis inside an SPMD region.
-
-    Compat shim: ``lax.axis_size`` only exists in newer jax; a psum over
-    a python int constant-folds to the axis size at trace time on every
-    version."""
-    return lax.psum(1, axis_name)
+    """Static size of a named mesh axis inside an SPMD region."""
+    return lax.axis_size(axis_name)
 
 
 def axis_index(axis_names: Tuple[str, ...]):

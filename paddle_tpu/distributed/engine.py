@@ -46,18 +46,11 @@ from ..observability import moestats as _moestats
 from ..observability.catalog import train_metrics as _train_metrics
 from ..tensor import Tensor
 
-try:
-    from jax import shard_map as _shard_map_mod  # jax >= 0.8
 
-    def _shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _sm
+def _shard_map(f, mesh, in_specs, out_specs, check_rep=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
-    def _shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_rep)
 
 __all__ = ["ParallelEngine", "bind_params", "param_spec", "shard_module_params"]
 
@@ -1360,11 +1353,33 @@ class ParallelEngine:
         step's jit cache / CompileStats are never touched. Returns
         None before any step has run."""
         key = key if key is not None else self._last_key
-        if key is None or key not in self._compiled:
-            return None
         led = self._mem_ledgers.get(key)
         if led is not None:
             return led
+        led = self._with_aot_args(
+            key, lambda fn, args: _ml.analyze(fn, args, program="train"))
+        if led is not None:
+            self._mem_ledgers[key] = led
+        return led
+
+    def lowered_text(self, key=None) -> Optional[str]:
+        """StableHLO text of the last-run (or given-key) compiled train
+        step, lowered again from the SAME jitted program at the engine's
+        current values (one extra trace, no XLA compile; the live jit
+        cache and CompileStats are untouched). This is how a caller
+        proves which kernels the step contains: each Pallas kernel is a
+        ``tpu_custom_call`` carrying its ``kernel_name``. None before
+        any step has run."""
+        key = key if key is not None else self._last_key
+        return self._with_aot_args(
+            key, lambda fn, args: fn.lower(*args).as_text())
+
+    def _with_aot_args(self, key, use):
+        """``use(jitted_step, example_args)`` for the program under
+        ``key``, with the args rebuilt from the engine's current
+        param/state values; None when that program has not run."""
+        if key is None or key not in self._compiled:
+            return None
         stored = self._mem_args.get(key)
         if stored is None or self.optimizer is None:
             return None
@@ -1383,13 +1398,9 @@ class ParallelEngine:
             # key[3] pins which params carried masters at trace time
             mvals = {i: opt._master_weights[id(self.params[i])]
                      for i in key[3]}
-            led = _ml.analyze(
-                self._compiled[key],
-                (pvals, svals, mvals, qvals, leaf_vals, lr, stepc, seed,
-                 amp_in),
-                program="train")
-        self._mem_ledgers[key] = led
-        return led
+            return use(self._compiled[key],
+                       (pvals, svals, mvals, qvals, leaf_vals, lr, stepc,
+                        seed, amp_in))
 
     def state_accounting(self, batch_tokens: Optional[int] = None):
         """Measured per-device model-state accounting

@@ -48,6 +48,13 @@ class PipelineParallel(_DelegateWrapper):
         self.total_loss = None
 
     # -- engine plumbing -------------------------------------------------
+    @property
+    def engine(self) -> Optional[ParallelEngine]:
+        """The ParallelEngine behind the compiled pipeline step (its
+        stats, ledgers and lowered text); None before the first
+        train_batch."""
+        return self._engine
+
     def _ensure_engine(self, optimizer):
         if self._engine is None:
             self._layers._num_microbatches = self.accumulate_steps

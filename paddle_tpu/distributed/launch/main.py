@@ -11,9 +11,15 @@ process drives all local chips (the reference runs one per GPU). So:
 - --nnodes N: this process is one trainer of N; we export the PADDLE_*
   envs and (when available) point jax.distributed at the coordinator so
   multi-host meshes form over DCN;
-- --nproc_per_node K (testing / CPU simulation): fork K local trainer
+- --nproc_per_node K (CPU simulation only): fork K local trainer
   processes with ranked envs, watch them, propagate the first failure
-  (the watcher role).
+  (the watcher role). Every child would see every local chip, and a chip
+  belongs to one process at a time, so on a host with TPUs this path
+  refuses to start unless the children are pinned to the CPU
+  (``JAX_PLATFORMS=cpu``).
+
+The launcher itself never touches JAX: the parent only sets environment,
+so the chips are left to the child.
 """
 from __future__ import annotations
 
@@ -39,7 +45,8 @@ def _parse(argv=None):
     p.add_argument("--rank", type=int, default=None,
                    help="this host's rank (default: from env or 0)")
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="local trainer processes (testing; TPU uses 1)")
+                   help="local trainer processes (CPU simulation; "
+                        "refused on a host with TPUs)")
     p.add_argument("--devices", default=None,
                    help="visible device ids, comma separated")
     p.add_argument("--log_dir", default=None, help="per-rank log dir")
@@ -65,6 +72,14 @@ def _base_env(args, rank: int, world: int) -> dict:
         env["TPU_VISIBLE_DEVICES"] = args.devices
     env["PADDLE_DISTRI_BACKEND"] = "xla"
     return env
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips on this host's PCI bus, counted without creating a JAX
+    backend (the parent must not take the chips)."""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
 
 
 def _watch(procs: List[subprocess.Popen]) -> int:
@@ -111,6 +126,16 @@ def launch(argv=None) -> int:
         return subprocess.call(cmd, env=env)
 
     # simulation path: K ranked local processes (reference build_pod)
+    chips = _local_tpu_chips()
+    if chips and os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        print(f"launch: --nproc_per_node {args.nproc_per_node} refused: "
+              f"this host has {chips} TPU chip(s), every child would "
+              "open all of them, and a chip belongs to one process at a "
+              "time. One process drives all local chips (drop "
+              "--nproc_per_node), or set JAX_PLATFORMS=cpu for the CPU "
+              "simulation.", file=sys.stderr)
+        return 2
+
     def build_pod(attempt: int):
         procs = []
         world = args.nproc_per_node * world_hosts

@@ -400,6 +400,7 @@ def block_multihead_attention(qkv, key_cache, value_cache,
     reference updates in place).
     """
     from ....core.enforce import enforce as _enf
+    from ....ops.pallas import is_tpu_platform
     from ....ops.pallas.decode_attention import (paged_attention_dense,
                                                  paged_supported,
                                                  paged_decode_attention)
@@ -508,8 +509,8 @@ def block_multihead_attention(qkv, key_cache, value_cache,
     vp = vp.at[pid, :, slot, :].set(vw.astype(vp.dtype))
     q4 = q[:, None]                                        # [B,1,H,D]
     if (_flags._get("use_pallas_kernels", True)
-            and paged_supported(q4.shape, kp.shape)
-            and jax.default_backend() != "cpu"):
+            and is_tpu_platform()
+            and paged_supported(q4.shape, kp.shape)):
         out = paged_decode_attention(q4, kp, vp, tbl, off)
     else:
         out = paged_attention_dense(q4, kp, vp, tbl, off)

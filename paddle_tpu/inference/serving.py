@@ -390,6 +390,8 @@ class ServingEngine:
         self._mem_on = bool(mem_ledger) or bool(int(os.environ.get(
             "PADDLE_TPU_MEM_LEDGER", "0") or 0))
         self._mem_ledgers: Dict[Any, Any] = {}
+        # site -> (jitted fn, arg shapes) of every program that has run
+        self._site_programs: Dict[Any, Any] = {}
         self._live_peak = 0
         self.gen = cfg.generation
         self._rng = jax.random.PRNGKey(self.gen.seed)
@@ -1955,6 +1957,14 @@ class ServingEngine:
         if self._mem_on and site not in self._mem_ledgers:
             self._mem_ledgers[site] = _ml.analyze(
                 fn, args, program="_".join(str(s) for s in site))
+        if site not in self._site_programs:
+            # shapes only (the cache buffers are donated by the call):
+            # enough for lowered_text to re-lower this program later
+            self._site_programs[site] = (fn, jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=getattr(a, "sharding", None))
+                if hasattr(a, "shape") else a, args))
         with _cl.capture() as cap:
             out = fn(*args)
         if len(cap):
@@ -1976,6 +1986,22 @@ class ServingEngine:
         speculative decoding, or ("page_copy",) with the prefix
         cache."""
         return self._ledgers.get(site)
+
+    def lowered_text(self, site) -> Optional[str]:
+        """StableHLO text of a serving program that has run (site as in
+        ``comm_ledger``; ``program_sites()`` lists them), lowered again
+        from the same jitted function at the recorded shapes — one extra
+        trace, no XLA compile. Each Pallas kernel in it is a
+        ``tpu_custom_call`` carrying its ``kernel_name``."""
+        prog = self._site_programs.get(site)
+        if prog is None:
+            return None
+        fn, avals = prog
+        return fn.lower(*avals).as_text()
+
+    def program_sites(self) -> List[Any]:
+        """Sites of every compiled serving program run so far."""
+        return list(self._site_programs)
 
     # -- memory accounting (observability/memledger) ---------------------
     def memory_ledger(self, site=("decode",)) -> Optional[Any]:

@@ -119,35 +119,20 @@ def _apply_rope(x, cos, sin, offset):
     return x * c.astype(x.dtype) + _rot_half(x) * s.astype(x.dtype)
 
 
-_kernel_warned: set = set()
-
-
-def _dispatch_kernel(name, supported, kernel, fallback):
+def _dispatch_kernel(name, supported, kernel, dense):
     """Pallas-kernel dispatch policy, shared by the cache/paged
-    attention paths: try the kernel when the flag + shape gate + TPU
-    backend allow, warn ONCE PER KERNEL on failure, fall back to XLA."""
+    attention paths: the flag, the platform and the kernel's shape gate
+    choose the lowering; the chosen one is called bare, so a kernel the
+    gate admitted and Mosaic refuses fails the run."""
     from ..core import flags as _flags
+    from ..ops.pallas import is_tpu_platform
 
-    # the semantic scope names BOTH outcomes (kernel or XLA fallback)
-    # after the kernel, so device traces show e.g. `decode_attention`
-    # over whichever lowering actually ran
-    if (_flags._get("use_pallas_kernels", True) and supported()
-            and (jax.default_backend() != "cpu")):
-        try:
-            with _annotate(name):
-                return kernel()
-        except (KeyboardInterrupt, SystemExit):
-            raise
-        except Exception as e:
-            if name not in _kernel_warned:
-                _kernel_warned.add(name)
-                import warnings
-
-                warnings.warn(f"{name}: Pallas kernel unavailable "
-                              f"({type(e).__name__}: {e}); using dense "
-                              "XLA fallback")
+    use_kernel = (_flags._get("use_pallas_kernels", True)
+                  and is_tpu_platform() and supported())
+    # the semantic scope names BOTH lowerings after the kernel, so
+    # device traces show e.g. `decode_attention` over whichever ran
     with _annotate(name):
-        return fallback()
+        return kernel() if use_kernel else dense()
 
 
 @def_op("llama_rms_norm")
@@ -165,9 +150,8 @@ def _rms_norm_dispatch(x, weight, epsilon=1e-5):
 class LlamaRMSNorm(RMSNorm):
     """RMSNorm routed through the shared Pallas dispatch policy: the
     fused one-VMEM-pass kernel (ops/pallas/rms_norm.py) when the Mosaic
-    shape gate admits the geometry on TPU, the numerically identical
-    dense XLA path otherwise — the swap only changes the lowering,
-    never the results (both accumulate in f32 with the same formula)."""
+    shape gate admits the geometry on TPU, the dense XLA path otherwise
+    (both accumulate in f32 with the same formula)."""
 
     def forward(self, x):
         return _rms_norm_dispatch(x, self.weight,

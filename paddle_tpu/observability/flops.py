@@ -14,41 +14,49 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 __all__ = ["params_from_config", "train_flops_per_token",
-           "peak_flops_per_chip", "mfu", "ici_bytes_per_sec",
-           "comm_seconds_lower_bound"]
+           "PEAKS_BY_DEVICE_KIND", "peak_flops_per_chip", "mfu",
+           "ici_bytes_per_sec", "comm_seconds_lower_bound"]
 
-# Peak dense bf16 FLOPs and HBM bandwidth per chip by TPU generation
-# (public specs — the same table bench.py uses for its roofline lines).
-PEAK_BY_CHIP = {
-    "v4": (275e12, 1.2e12),
-    "v5e": (197e12, 0.819e12), "v5 lite": (197e12, 0.819e12),
-    "v5litepod": (197e12, 0.819e12),
-    "v5p": (459e12, 2.765e12),
-    "v6e": (918e12, 1.64e12), "v6 lite": (918e12, 1.64e12),
+# The one peaks table, keyed by ``device_kind`` as JAX reports it:
+# (dense bf16 FLOP/s, HBM bytes/s, aggregate ICI bytes/s) per chip.
+# Source: Google Cloud TPU documentation, the system-architecture page
+# of each generation ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600
+# Gbit/s interchip interconnect; likewise "TPU v4", "TPU v5p", "TPU
+# v6e"). A TPU whose kind is not here is an error, not a default: every
+# MFU, roofline share and comm floor would silently be about another
+# chip. bench.py imports this table.
+_V5E = (197e12, 0.819e12, 200e9)
+_V5P = (459e12, 2.765e12, 600e9)
+_V6E = (918e12, 1.64e12, 448e9)
+PEAKS_BY_DEVICE_KIND = {        # both spellings jax itself matches on
+    "TPU v4": (275e12, 1.2e12, 300e9),
+    "TPU v5 lite": _V5E, "TPU v5e": _V5E,
+    "TPU v5": _V5P, "TPU v5p": _V5P,
+    "TPU v6 lite": _V6E, "TPU v6e": _V6E,
 }
 
-# Aggregate ICI bandwidth per chip (bytes/s, public specs: v4 2400
-# Gbps, v5e 1600, v5p 4800, v6e 3584 — all links, both directions).
-# The comm floor below uses it to turn ledger wire bytes into a
-# lower-bound transfer time, contextualizing exposed-comm seconds.
-ICI_BY_CHIP = {
-    "v4": 300e9,
-    "v5e": 200e9, "v5 lite": 200e9, "v5litepod": 200e9,
-    "v5p": 600e9,
-    "v6e": 448e9, "v6 lite": 448e9,
-}
+
+def _peaks(device) -> Tuple[float, float, float]:
+    """Table row for a jax device. CPU is a device the table knows:
+    explicit zeros (MFU, roofline and comm floors are then reported as
+    0, well-defined). An unknown TPU kind raises."""
+    if device.platform != "tpu":
+        return (0.0, 0.0, 0.0)
+    kind = device.device_kind
+    if kind not in PEAKS_BY_DEVICE_KIND:
+        raise KeyError(
+            f"TPU device_kind {kind!r} is not in the peaks table "
+            f"(paddle_tpu/observability/flops.py knows "
+            f"{sorted(PEAKS_BY_DEVICE_KIND)}); add its published peaks "
+            "with their source")
+    return PEAKS_BY_DEVICE_KIND[kind]
 
 
 def ici_bytes_per_sec(device) -> float:
-    """Aggregate ICI bytes/s of a jax device's chip generation; 0.0 on
-    CPU (no ICI — comm floors are then reported as 0, well-defined)."""
-    kind = str(getattr(device, "device_kind", "")).lower()
-    for k, v in ICI_BY_CHIP.items():
-        if k in kind:
-            return v
-    if "tpu" in str(getattr(device, "platform", "")).lower():
-        return ICI_BY_CHIP["v5p"]    # unknown generation: assume v5p
-    return 0.0
+    """Aggregate ICI bytes/s (all links, both directions) of a jax
+    device's chip; 0.0 on CPU (no ICI). The comm floor below uses it to
+    turn ledger wire bytes into a lower-bound transfer time."""
+    return _peaks(device)[2]
 
 
 def comm_seconds_lower_bound(wire_bytes: float, device) -> float:
@@ -92,13 +100,7 @@ def train_flops_per_token(n_params: int, *, config=None,
 def peak_flops_per_chip(device) -> Tuple[float, float]:
     """(peak dense bf16 FLOPs/s, HBM bytes/s) for a jax device; (0, 0)
     on CPU, where MFU is not meaningful."""
-    kind = str(getattr(device, "device_kind", "")).lower()
-    for k, v in PEAK_BY_CHIP.items():
-        if k in kind:
-            return v
-    if "tpu" in str(getattr(device, "platform", "")).lower():
-        return PEAK_BY_CHIP["v5p"]   # unknown generation: assume v5p
-    return (0.0, 0.0)
+    return _peaks(device)[:2]
 
 
 def mfu(n_params: int, tokens_per_sec: float, n_devices: int,

@@ -401,17 +401,12 @@ def rms_norm(x, weight=None, bias=None, epsilon=1e-6, begin_norm_axis=-1):
             and begin_norm_axis in (-1, x.ndim - 1)
             and weight.ndim == 1):
         from ..core import flags as _flags
+        from .pallas import is_tpu_platform
+        from .pallas.rms_norm import rms_norm_fused, rms_norm_supported
 
-        if _flags._get("use_pallas_kernels", True):
-            try:
-                import jax as _jax
-
-                if "tpu" in str(_jax.devices()[0].platform).lower():
-                    from .pallas.rms_norm import rms_norm_fused
-
-                    return rms_norm_fused(x, weight, float(epsilon))
-            except Exception:
-                pass
+        if (_flags._get("use_pallas_kernels", True) and is_tpu_platform()
+                and rms_norm_supported(x.shape)):
+            return rms_norm_fused(x, weight, float(epsilon))
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     axes = tuple(range(begin_norm_axis % x.ndim, x.ndim))

@@ -39,14 +39,10 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from . import (_BLOCKS_LARGE as _BLOCKS, compiler_params as
-               _compiler_params, is_tpu_platform, pick_block as _pick_block)
+               _compiler_params, pick_block as _pick_block)
 
 __all__ = ["decode_attention", "paged_decode_attention",
            "paged_attention_dense", "paged_supported"]
@@ -96,8 +92,6 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
 
 
 def supported(q_shape, cache_shape) -> bool:
-    if pltpu is None:  # no TPU pallas backend
-        return False
     B, Sq, H, D = q_shape
     KV, M = cache_shape[1], cache_shape[2]
     if H % KV or _pick_block(M, prefer=_BLOCKS) <= 0:
@@ -111,7 +105,7 @@ def supported(q_shape, cache_shape) -> bool:
 
 
 def decode_attention(q, k_cache, v_cache, offset, scale=None,
-                     interpret=None):
+                     interpret=False):
     """q [B,Sq,H,D] against caches [B,KV,M,D] (head-major: each head's
     [M,D] plane is contiguous, the Mosaic-tileable layout); cache
     positions <= offset+row are attended. offset may be traced, and may
@@ -122,8 +116,6 @@ def decode_attention(q, k_cache, v_cache, offset, scale=None,
     G = H // KV
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    if interpret is None:
-        interpret = not is_tpu_platform()
     block_kv = _pick_block(M, prefer=_BLOCKS)
     nkv = M // block_kv
     q5 = q.reshape(B, Sq, KV, G, D)
@@ -156,6 +148,7 @@ def decode_attention(q, k_cache, v_cache, offset, scale=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, KV, G, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
         **_compiler_params(2, interpret),
     )(lengths, q5, k_cache, v_cache)
     return out.reshape(B, Sq, H, D)
@@ -206,8 +199,6 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
 
 
 def paged_supported(q_shape, pool_shape) -> bool:
-    if pltpu is None:
-        return False
     B, Sq, H, D = q_shape
     P, KV, page = pool_shape[0], pool_shape[1], pool_shape[2]
     if H % KV or D % 128 != 0:
@@ -218,7 +209,7 @@ def paged_supported(q_shape, pool_shape) -> bool:
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           scale=None, interpret=None):
+                           scale=None, interpret=False):
     """Block-table KV attention (the TPU redesign of the reference's
     paged cache kernel: phi/kernels/fusion/gpu/
     block_multi_head_attention_kernel.cu + block_attn.h — there, CUDA
@@ -240,8 +231,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     G = H // KV
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    if interpret is None:
-        interpret = not is_tpu_platform()
     q5 = q.reshape(B, Sq, KV, G, D)
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
     tbl = jnp.asarray(block_tables, jnp.int32).reshape(B * npages)
@@ -273,6 +262,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, KV, G, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
         **_compiler_params(2, interpret),
     )(lengths, tbl, q5, k_pool, v_pool)
     return out.reshape(B, Sq, H, D)

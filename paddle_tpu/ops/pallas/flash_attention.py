@@ -43,19 +43,14 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces are unavailable on CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from . import (_BLOCKS_LARGE as _BLOCKS, compiler_params as
-               _compiler_params, is_tpu_platform, pick_block as _pick_block)
+               _compiler_params, pick_block as _pick_block)
 
-__all__ = ["flash_attention_fwd"]
+__all__ = ["flash_attention_fwd", "flash_supported"]
+
+_VMEM = pltpu.VMEM
 
 _NEG = -1e30
 
@@ -162,7 +157,7 @@ def _pallas_fa(q3, k3, v3, qseg, kseg, H, causal, scale, block_q, block_kv,
     Skv = k3.shape[1]
     q_off = Skv - Sq
     nq, nkv = Sq // block_q, Skv // block_kv
-    kw = {} if _VMEM is None else {"memory_space": _VMEM}
+    kw = {"memory_space": _VMEM}
 
     def kv_index(b, i, j):
         # clamp past the causal frontier: re-use the resident block, no DMA
@@ -202,6 +197,7 @@ def _pallas_fa(q3, k3, v3, qseg, kseg, H, causal, scale, block_q, block_kv,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
         **_compiler_params(2, interpret),
     )(*args)
 
@@ -344,6 +340,7 @@ def _pallas_fa_bwd(q3, k3, v3, do3, lse, delta, qseg, kseg, H, causal,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attention_dq",
         **_compiler_params(2, interpret),
     )(*dq_args)
 
@@ -393,29 +390,26 @@ def _pallas_fa_bwd(q3, k3, v3, do3, lse, delta, qseg, kseg, H, causal,
         ],
         scratch_shapes=dkv_scratch,
         interpret=interpret,
+        name="flash_attention_dkv",
         **_compiler_params(2, interpret),
     )(*dkv_args)
     return dq, dk, dv
 
 
-def _supported(q, k) -> bool:
-    if pltpu is None:  # no TPU pallas backend: scratch/VMEM unavailable
-        return False
-    B, Sq, H, D = q.shape
-    Skv = k.shape[1]
-    if _pick_block(Sq) <= 0 or _pick_block(Skv) <= 0:
-        return False
-    # D must fill whole 128-wide VPU lanes ON REAL TPU: sub-lane head
-    # dims were observed to hang the Mosaic compiler on v5e (same gate
-    # as rms_norm/decode_attention); interpret mode has no such limit
-    if not _interpret_default() and D % 128 != 0:
-        return False
-    # rectangular causal convention needs q to be a suffix of the kv span
-    return Skv >= Sq
+def _blockable(q_shape, k_shape) -> bool:
+    """What the kernel needs in any mode: a block that divides each
+    sequence, and (rectangular causal convention) q a suffix of the kv
+    span."""
+    Sq, Skv = q_shape[1], k_shape[1]
+    return (_pick_block(Sq) > 0 and _pick_block(Skv) > 0 and Skv >= Sq)
 
 
-def _interpret_default() -> bool:
-    return not is_tpu_platform()
+def flash_supported(q_shape, k_shape) -> bool:
+    """Mosaic shape gate for dispatch sites (ops/attention.py): the
+    kernel's own needs plus a head dim that fills whole 128-wide VPU
+    lanes (the same gate as rms_norm/decode_attention). Shapes it
+    rejects take the dense XLA path."""
+    return _blockable(q_shape, k_shape) and q_shape[3] % 128 == 0
 
 
 def _to3(x):
@@ -429,11 +423,13 @@ def _from3(x3, B, H):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention_fwd(q, k, v, causal=False, scale=None, interpret=None,
+def flash_attention_fwd(q, k, v, causal=False, scale=None, interpret=False,
                         q_segment_ids=None, kv_segment_ids=None):
-    """[B, S, H, D] → [B, S, H, D]; raises ValueError when the shape
-    needs the XLA fallback (caller catches). Optional int32 segment ids
-    [B, Sq]/[B, Skv] restrict attention to equal segments (varlen)."""
+    """[B, S, H, D] → [B, S, H, D]. Dispatch sites ask
+    :func:`flash_supported` first; a shape no block divides is a
+    ValueError here. Optional int32 segment ids [B, Sq]/[B, Skv]
+    restrict attention to equal segments (varlen). ``interpret=True``
+    (tests) runs the Pallas interpreter instead of Mosaic."""
     out, _ = _fa_fwd(q, k, v, causal, scale, interpret, q_segment_ids,
                      kv_segment_ids)
     return out
@@ -443,8 +439,6 @@ def _prep(q, k, causal, scale, interpret, qseg, kseg):
     B, Sq, H, D = q.shape
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    if interpret is None:
-        interpret = _interpret_default()
     if (qseg is None) != (kseg is None):
         raise ValueError("flash: q/kv segment ids must be given together")
     if qseg is not None:
@@ -479,8 +473,8 @@ def _prep(q, k, causal, scale, interpret, qseg, kseg):
 
 
 def _fa_fwd(q, k, v, causal, scale, interpret, qseg=None, kseg=None):
-    if not _supported(q, k):
-        raise ValueError("flash pallas kernel: unsupported shape "
+    if not _blockable(q.shape, k.shape):
+        raise ValueError("flash pallas kernel: no block tiles shape "
                          f"{q.shape}/{k.shape}")
     B, Sq, H, D = q.shape
     scale, interpret, qseg3, kseg3, block_q, block_kv = _prep(

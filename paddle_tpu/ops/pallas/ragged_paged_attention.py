@@ -46,13 +46,9 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
-from . import compiler_params as _compiler_params, is_tpu_platform
+from . import compiler_params as _compiler_params
 
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_dense",
            "ragged_supported"]
@@ -108,8 +104,6 @@ def _ragged_kernel(len_ref, nv_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
 def ragged_supported(q_shape, pool_shape) -> bool:
     """Same Mosaic gates as the paged decode kernel: whole-lane head
     dim, sublane-tileable page, q block resident in VMEM."""
-    if pltpu is None:
-        return False
     B, Sq, H, D = q_shape
     KV, page = pool_shape[1], pool_shape[2]
     if H % KV or D % 128 != 0:
@@ -120,7 +114,7 @@ def ragged_supported(q_shape, pool_shape) -> bool:
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts,
-                           seq_lens, scale=None, interpret=None):
+                           seq_lens, scale=None, interpret=False):
     """Unified mixed prefill/decode attention over the paged KV pool.
 
     q            [B, Sb, H, D]  slot i of row b sits at absolute cache
@@ -140,8 +134,6 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts,
     G = H // KV
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    if interpret is None:
-        interpret = not is_tpu_platform()
     q5 = q.reshape(B, Sq, KV, G, D)
     starts = jnp.asarray(starts, jnp.int32).reshape(B)
     seq_lens = jnp.asarray(seq_lens, jnp.int32).reshape(B)
@@ -176,6 +168,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, KV, G, D), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
         **_compiler_params(2, interpret),
     )(starts, seq_lens, tbl, q5, k_pool, v_pool)
     return out.reshape(B, Sq, H, D)

@@ -16,16 +16,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
-from . import is_tpu_platform, pick_block
+from . import pick_block
 
 __all__ = ["rms_norm_fused", "rms_norm_supported", "rms_norm_dense"]
 
@@ -48,15 +41,11 @@ def _rms_ref(x2, w, eps):
         x2.dtype)
 
 
-def _interpret_default() -> bool:
-    return not is_tpu_platform()
-
-
 def rms_norm_supported(shape) -> bool:
     """Mosaic gate for kernel-dispatch sites: True when the flattened
     row count and the hidden dim of ``shape`` tile cleanly on real TPU
-    (see _mosaic_tileable).  Callers fall back to rms_norm_dense when
-    this returns False."""
+    (see _mosaic_tileable).  Callers take rms_norm_dense when this
+    returns False."""
     H = int(shape[-1])
     T = 1
     for d in shape[:-1]:
@@ -72,31 +61,27 @@ def rms_norm_dense(x, weight, eps=1e-6):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def rms_norm_fused(x, weight, eps=1e-6, interpret=None):
-    """x: [..., H] (normalized over the last dim), weight: [H]."""
+def rms_norm_fused(x, weight, eps=1e-6, interpret=False):
+    """x: [..., H] (normalized over the last dim), weight: [H].
+    Dispatch sites ask :func:`rms_norm_supported` first;
+    ``interpret=True`` (tests) runs the Pallas interpreter."""
     out, _ = _fwd(x, weight, eps, interpret)
     return out
 
 
 def _mosaic_tileable(T, bt, H) -> bool:
-    """Real-TPU shape gate: the second-minor block dim must divide by 8
+    """Mosaic shape gate: the second-minor block dim must divide by 8
     (or equal the array dim) per the Mosaic tiling rule, and H must
-    fill whole 128-wide VPU lanes — sub-lane H (tiny-model hidden 64)
-    was observed to HANG the Mosaic compiler on v5e, so those shapes
-    take the XLA path."""
+    fill whole 128-wide VPU lanes; other shapes take the XLA path."""
     return (bt % 8 == 0 or bt == T) and H % 128 == 0
 
 
 def _fwd(x, weight, eps, interpret):
-    if interpret is None:
-        interpret = _interpret_default()
     H = x.shape[-1]
     x2 = x.reshape(-1, H)
     T = x2.shape[0]
     bt = _pick_block(T)
-    if not interpret and not _mosaic_tileable(T, bt, H):
-        return _rms_ref(x2, weight, eps).reshape(x.shape), (x, weight)
-    kw = {} if _VMEM is None else {"memory_space": _VMEM}
+    kw = {"memory_space": pltpu.VMEM}
     out = pl.pallas_call(
         partial(_kernel, eps=eps),
         grid=(T // bt,),
@@ -105,6 +90,7 @@ def _fwd(x, weight, eps, interpret):
         out_specs=pl.BlockSpec((bt, H), lambda i: (i, 0), **kw),
         out_shape=jax.ShapeDtypeStruct((T, H), x.dtype),
         interpret=interpret,
+        name="rms_norm_fused",
     )(x2, weight)
     return out.reshape(x.shape), (x, weight)
 

@@ -369,9 +369,10 @@ class Adam(Optimizer):
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, parameters=None, weight_decay=None,
                  grad_clip=None, lazy_mode=False, multi_precision=False,
+                 state_dtype=None,
                  use_multi_tensor=True, name=None, **kwargs):
         super().__init__(learning_rate, parameters, weight_decay, grad_clip,
-                         name, multi_precision)
+                         name, multi_precision, state_dtype)
         self._beta1 = beta1
         self._beta2 = beta2
         self._epsilon = epsilon
@@ -431,10 +432,11 @@ class AdamW(Adam):
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, parameters=None, weight_decay=0.01,
                  lr_ratio=None, apply_decay_param_fun=None, grad_clip=None,
-                 lazy_mode=False, multi_precision=False, name=None, **kwargs):
+                 lazy_mode=False, multi_precision=False, name=None,
+                 state_dtype=None, **kwargs):
         super().__init__(learning_rate, beta1, beta2, epsilon, parameters,
                          weight_decay, grad_clip, lazy_mode, multi_precision,
-                         name=name)
+                         name=name, state_dtype=state_dtype)
         self._decoupled = True
         self._apply_decay_param_fun = apply_decay_param_fun
         # NOTE: apply_decay_param_fun is honored in step() by zeroing decay

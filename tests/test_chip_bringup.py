@@ -1,0 +1,208 @@
+"""What the chip bring-up (chip_smoke.py, ISSUE 21) rests on, checked on
+the CPU: every Pallas kernel lowers for the TPU at the smoke's full-width
+shapes; importing the package or the launcher creates no backend; the
+compile-cache rule; the peaks table refuses an unknown TPU; a kernel the
+gate admitted is called bare, so its failure is the caller's failure.
+"""
+import inspect
+import json
+import os
+import subprocess
+import sys
+import warnings
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import chip_smoke
+import paddle_tpu as paddle
+from paddle_tpu import _bootstrap
+from paddle_tpu.observability import flops
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ---------------------------------------------------------------------------
+# kernels: JAX's own lowering to a Mosaic custom call, no chip needed
+# ---------------------------------------------------------------------------
+_CASES = chip_smoke.kernel_cases(chip_smoke.Sizes(rehearsal=False))
+
+
+@pytest.mark.parametrize("case", _CASES, ids=[c[0] for c in _CASES])
+def test_kernel_lowers_for_tpu_at_full_width(case):
+    name, expect, build, _tol = case
+    kern, _dense, avals = build(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+    text = jax.jit(kern).trace(*avals).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    found = chip_smoke.kernel_names(text)
+    assert all(found.get(n, 0) >= 1 for n in expect), (name, found)
+
+
+def test_no_kernel_resolves_interpret_for_itself():
+    from paddle_tpu.ops.pallas import (decode_attention as da,
+                                       flash_attention as fa,
+                                       ragged_paged_attention as ra,
+                                       rms_norm as rn)
+
+    for fn in (fa.flash_attention_fwd, rn.rms_norm_fused,
+               da.decode_attention, da.paged_decode_attention,
+               ra.ragged_paged_attention):
+        fn = getattr(fn, "__wrapped__", fn)
+        assert inspect.signature(fn).parameters["interpret"].default \
+            is False, fn
+
+
+# ---------------------------------------------------------------------------
+# the parent of a launch must leave the chip to its child
+# ---------------------------------------------------------------------------
+def test_imports_create_no_backend_and_place_the_cache():
+    """In a fresh process not pinned to the CPU (no backend is created,
+    so the missing TPU is never asked for)."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR")}
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import json, jax, paddle_tpu\n"
+         "import paddle_tpu.distributed.launch.main\n"
+         "from jax._src import xla_bridge\n"
+         "print(json.dumps({'backends': len(xla_bridge._backends),"
+         " 'dir': jax.config.jax_compilation_cache_dir}))"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["backends"] == 0
+    assert got["dir"] == os.path.join(REPO, ".paddle_tpu_cache", "xla")
+
+
+# ---------------------------------------------------------------------------
+# compile cache: placed from outside, else one fixed path in the checkout
+# ---------------------------------------------------------------------------
+def test_compile_cache_rule():
+    rule = _bootstrap.compile_cache_dir
+    # the variable set: the code sets nothing (JAX reads it itself)
+    assert rule({"JAX_COMPILATION_CACHE_DIR": "/somewhere"}, "") is None
+    assert rule({"JAX_COMPILATION_CACHE_DIR": "/somewhere"}, "cpu") is None
+    # unset: the fixed path inside the checkout, the same every call
+    fixed = rule({}, "")
+    assert fixed == rule({}, "tpu") == rule({}, "")
+    assert fixed == os.path.join(REPO, ".paddle_tpu_cache", "xla")
+    # a process pinned to the CPU keeps none
+    assert rule({}, "cpu") is None
+    # the autotune cache moved beside it, out of the user's home
+    from paddle_tpu.ops.pallas import autotune
+
+    assert autotune._default_path() == os.path.join(
+        REPO, ".paddle_tpu_cache", "autotune.json")
+
+
+# ---------------------------------------------------------------------------
+# one peaks table; an unknown TPU is an error
+# ---------------------------------------------------------------------------
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_peaks_table_known_unknown_and_cpu():
+    v5e = _FakeDevice("tpu", "TPU v5 lite")
+    assert flops.peak_flops_per_chip(v5e) == (197e12, 0.819e12)
+    assert flops.ici_bytes_per_sec(v5e) == 200e9
+    unknown = _FakeDevice("tpu", "TPU v99 mega")
+    with pytest.raises(KeyError, match="TPU v99 mega"):
+        flops.peak_flops_per_chip(unknown)
+    with pytest.raises(KeyError):
+        flops.ici_bytes_per_sec(unknown)
+    assert flops.peak_flops_per_chip(jax.devices()[0]) == (0.0, 0.0)
+
+
+# ---------------------------------------------------------------------------
+# gate, then the kernel bare: a failure behind an admitting gate propagates
+# ---------------------------------------------------------------------------
+class _MosaicSaysNo(RuntimeError):
+    pass
+
+
+def _boom(*a, **k):
+    raise _MosaicSaysNo("refused by the compiler")
+
+
+def _as_tpu(monkeypatch):
+    import paddle_tpu.ops.attention as attn
+    import paddle_tpu.ops.pallas as pallas
+
+    monkeypatch.setattr(pallas, "is_tpu_platform", lambda: True)
+    monkeypatch.setattr(attn, "is_tpu_platform", lambda: True)
+
+
+def _flash_site(monkeypatch):
+    import paddle_tpu.ops.attention as attn
+
+    monkeypatch.setattr(attn, "flash_attention_fwd", _boom)
+    q = paddle.to_tensor(jnp.zeros((1, 128, 2, 128), jnp.float32))
+    return lambda: attn.flash_attention(q, q, q, causal=True)
+
+
+def _rms_norm_site(monkeypatch):
+    import paddle_tpu.ops.pallas.rms_norm as rn
+    from paddle_tpu.ops import nn_ops
+
+    monkeypatch.setattr(rn, "rms_norm_fused", _boom)
+    x = paddle.to_tensor(jnp.zeros((8, 128), jnp.float32))
+    w = paddle.to_tensor(jnp.ones((128,), jnp.float32))
+    return lambda: nn_ops.rms_norm(x, w)
+
+
+def _llama_paged_site(monkeypatch):
+    import paddle_tpu.ops.pallas.decode_attention as da
+    from paddle_tpu.models import llama
+
+    monkeypatch.setattr(da, "paged_decode_attention", _boom)
+    q = jnp.zeros((2, 1, 4, 128), jnp.float32)
+    pool = jnp.zeros((5, 4, 16, 128), jnp.float32)
+    tbl = jnp.zeros((2, 2), jnp.int32)
+    return lambda: llama._paged_attention(q, pool, pool, tbl,
+                                          jnp.zeros((2,), jnp.int32), 1)
+
+
+@pytest.mark.parametrize("site", [_flash_site, _rms_norm_site,
+                                  _llama_paged_site])
+def test_kernel_failure_behind_admitting_gate_propagates(site, monkeypatch):
+    _as_tpu(monkeypatch)
+    call = site(monkeypatch)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")        # a warn-and-go-on would raise
+        with pytest.raises(_MosaicSaysNo):
+            call()
+
+
+def test_gate_rejection_takes_the_dense_path(monkeypatch):
+    """The other half of the rule: a shape the gate rejects never
+    reaches the kernel, on a TPU or off it."""
+    import paddle_tpu.ops.pallas.rms_norm as rn
+    from paddle_tpu.ops import nn_ops
+
+    _as_tpu(monkeypatch)
+    monkeypatch.setattr(rn, "rms_norm_fused", _boom)
+    x = paddle.to_tensor(jnp.ones((3, 96), jnp.float32))   # sub-lane H
+    w = paddle.to_tensor(jnp.ones((96,), jnp.float32))
+    out = nn_ops.rms_norm(x, w)
+    assert out.shape == [3, 96] or tuple(out.shape) == (3, 96)
+
+
+# ---------------------------------------------------------------------------
+# what kept the 1.3B step off the chip: state_dtype died in Adam's **kwargs,
+# the moments stayed f32 and the step's arguments alone were 12.2 GiB
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("cls", ["Adam", "AdamW"])
+def test_state_dtype_reaches_the_moments(cls):
+    m = paddle.nn.Linear(4, 4)
+    opt = getattr(paddle.optimizer, cls)(parameters=m.parameters(),
+                                         state_dtype="bfloat16")
+    m(paddle.to_tensor(jnp.ones((2, 4), jnp.float32))).sum().backward()
+    opt.step()
+    moments = [v for st in opt._states.values() for v in st.values()
+               if jnp.issubdtype(v.dtype, jnp.floating)]
+    assert moments and all(v.dtype == jnp.bfloat16 for v in moments)

@@ -71,7 +71,7 @@ class TestEpPlumbing:
         assert moe._group.axis_names == ("ep",)
         assert moe.world_size == 2
         # expert stack sharded over 'ep' on dim 0
-        assert tuple(moe.w1.dist_attr) == (("ep",), None, None)
+        assert moe.w1.dist_attr == P(("ep",), None, None)
 
     def test_custom_order_without_ep_raises(self):
         from paddle_tpu.distributed.fleet.base.topology import \

@@ -47,16 +47,9 @@ from paddle_tpu.distributed import quant_comm as qc
 from paddle_tpu.distributed.engine import ParallelEngine
 from paddle_tpu.observability import commledger as cl
 
-try:
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
-except Exception:  # pragma: no cover - newer jax
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 INT8 = qc.make_config({"dtype": "int8", "chunk": 16})

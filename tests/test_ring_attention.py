@@ -61,20 +61,8 @@ def test_ring_attention_sep_parity():
                 axes=("sep",), causal=True)
             return lax.all_gather(o._value, "sep", axis=1, tiled=True)
 
-    try:
-        from jax import shard_map as _sm
-
-        def shard_map(f, mesh, in_specs, out_specs):
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-    except Exception:
-        from jax.experimental.shard_map import shard_map as _sms
-
-        def shard_map(f, mesh, in_specs, out_specs):
-            return _sms(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
-
-    f = shard_map(run, hcg.mesh, (P(), P(), P()), P())
+    f = jax.shard_map(run, mesh=hcg.mesh, in_specs=(P(), P(), P()),
+                      out_specs=P(), check_vma=False)
     out = jax.jit(f)(*qkv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(golden._value),
                                rtol=1e-4, atol=1e-5)
