@@ -688,6 +688,16 @@ def phase_serve(sz: Sizes) -> None:
             check(sz.rehearsal or all(found.get(n, 0) >= 1
                                       for n in want.get(site[0], ())),
                   f"program {site} holds Mosaic calls {found}")
+        # the page pool is written in place: no copy of a whole pool
+        # (a layout change around the write) in the compiled decode or
+        # unified program, nor in the largest prefill program
+        pool_shape = eng.pools[0][0].shape
+        for site in [s for s in sites if s[0] in ("decode", "unified")] \
+                + sorted(s for s in sites if s[0] == "prefill")[-1:]:
+            n = eng.pool_copies(eng.compiled_text(site), pool_shape)
+            check(sz.rehearsal or n == 0,
+                  f"compiled program {site}: {n} copies of a whole "
+                  f"{'x'.join(map(str, pool_shape))} pool")
         kinds = {s[0] for s in sites}
         check(("unified" in kinds) if kw else
               ({"prefill", "decode"} <= kinds),

@@ -403,7 +403,8 @@ def block_multihead_attention(qkv, key_cache, value_cache,
     from ....ops.pallas import is_tpu_platform
     from ....ops.pallas.decode_attention import (paged_attention_dense,
                                                  paged_supported,
-                                                 paged_decode_attention)
+                                                 paged_decode_attention,
+                                                 paged_kv_write)
     from ....core import flags as _flags
 
     for knob, name in ((pre_key_cache, "pre_key_cache"),
@@ -502,11 +503,7 @@ def block_multihead_attention(qkv, key_cache, value_cache,
                      "seq_lens_decoder exceeds its block table "
                      f"({tbl.shape[1]} pages x {page}); allocate more "
                      "pages")
-    pid = jnp.take_along_axis(tbl.astype(jnp.int32),
-                              (off // page)[:, None], axis=1)[:, 0]
-    slot = off % page
-    kp = kp.at[pid, :, slot, :].set(kw.astype(kp.dtype))
-    vp = vp.at[pid, :, slot, :].set(vw.astype(vp.dtype))
+    kp, vp = paged_kv_write(kp, vp, kw[:, None], vw[:, None], tbl, off)
     q4 = q[:, None]                                        # [B,1,H,D]
     if (_flags._get("use_pallas_kernels", True)
             and is_tpu_platform()

@@ -94,6 +94,7 @@ accept loop) sit outside the compiled scan.
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 from collections import Counter, OrderedDict, deque
@@ -1993,11 +1994,33 @@ class ServingEngine:
         from the same jitted function at the recorded shapes — one extra
         trace, no XLA compile. Each Pallas kernel in it is a
         ``tpu_custom_call`` carrying its ``kernel_name``."""
+        low = self._lower(site)
+        return None if low is None else low.as_text()
+
+    def compiled_text(self, site) -> Optional[str]:
+        """Optimized HLO of the same program, as the backend compiled it
+        (layouts, fusions, copies): ``lowered_text`` plus one XLA
+        compile, or a hit in the persistent cache."""
+        low = self._lower(site)
+        return None if low is None else low.compile().as_text()
+
+    @staticmethod
+    def pool_copies(hlo_text: str, pool_dims) -> int:
+        """Ops named ``copy*`` in optimized HLO whose result has the
+        page pool's shape: each is a layout change of a whole pool, so
+        its bytes scale with the pool and not with the rows a step
+        writes. 0 in every serving program since ``paged_kv_write``."""
+        dims = ",".join(str(d) for d in pool_dims)
+        return len(re.findall(
+            r"^\s*(?:ROOT\s+)?%?copy[\w.\-]*\s*=\s*\w+\["
+            + re.escape(dims) + r"\]", hlo_text, re.M))
+
+    def _lower(self, site):
         prog = self._site_programs.get(site)
         if prog is None:
             return None
         fn, avals = prog
-        return fn.lower(*avals).as_text()
+        return fn.lower(*avals)
 
     def program_sites(self) -> List[Any]:
         """Sites of every compiled serving program run so far."""

@@ -270,33 +270,25 @@ class LlamaAttention(Layer):
         if cache is not None:
             if len(cache) == 3:         # paged: (k_pool, v_pool, tables)
                 k_pool, v_pool, tables = cache
-                page = k_pool.shape[2]
                 off = jnp.broadcast_to(
                     jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
-                pos = off[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
-                if valid is not None:
-                    # unified mixed prefill-chunk/decode step: only the
-                    # first valid[b] slots of row b are real tokens.
-                    # CONTRACT: the caller's table carries ONE EXTRA
-                    # trailing column that always maps to the trash
-                    # page (inference/serving.py builds it) — dead
-                    # slots' kv writes are redirected there instead of
-                    # clobbering the row's own future cache slots
-                    nv = jnp.asarray(valid, jnp.int32).reshape(B)
-                    alive = jnp.arange(S, dtype=jnp.int32)[None] \
-                        < nv[:, None]
-                    pos = jnp.where(alive, pos,
-                                    (tables.shape[1] - 1) * page)
-                pid = jnp.take_along_axis(tables, pos // page, axis=1)
-                slot = pos % page        # [B,S]
-                # advanced-index scatter: [B,S] page ids + slots land
-                # the new [B,S,KV,D] kv rows in their physical pages
-                # (rows a row does not own are mapped to the trash page
-                # by the table, see inference paged allocator)
-                k_pool = k_pool.at[pid, :, slot, :].set(
-                    kv_.astype(k_pool.dtype))
-                v_pool = v_pool.at[pid, :, slot, :].set(
-                    vv.astype(v_pool.dtype))
+                nv = None if valid is None \
+                    else jnp.asarray(valid, jnp.int32).reshape(B)
+                # the new [B,S,KV,D] kv rows land in their physical
+                # pages, in place (rows a row does not own are mapped
+                # to the trash page by the table, see inference paged
+                # allocator). With ``valid`` (the unified mixed
+                # prefill-chunk/decode step) only the first valid[b]
+                # slots of row b are real tokens. CONTRACT: the
+                # caller's table then carries ONE EXTRA trailing column
+                # that always maps to the trash page
+                # (inference/serving.py builds it) — dead slots' kv
+                # writes are redirected there instead of clobbering the
+                # row's own future cache slots
+                from ..ops.pallas.decode_attention import paged_kv_write
+
+                k_pool, v_pool = paged_kv_write(
+                    k_pool, v_pool, kv_, vv, tables, offset, nv)
                 if valid is not None:
                     # the trailing trash column is a write-side device
                     # only: attention sees the canonical [B, npages]

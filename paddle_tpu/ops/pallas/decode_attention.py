@@ -32,6 +32,7 @@ static-shape cache layout of models/llama.py).
 """
 from __future__ import annotations
 
+import operator
 from functools import partial
 
 import jax
@@ -45,7 +46,7 @@ from . import (_BLOCKS_LARGE as _BLOCKS, compiler_params as
                _compiler_params, pick_block as _pick_block)
 
 __all__ = ["decode_attention", "paged_decode_attention",
-           "paged_attention_dense", "paged_supported"]
+           "paged_attention_dense", "paged_supported", "paged_kv_write"]
 
 _NEG = -1e30
 
@@ -266,6 +267,94 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         **_compiler_params(2, interpret),
     )(lengths, tbl, q5, k_pool, v_pool)
     return out.reshape(B, Sq, H, D)
+
+
+def _concrete_zero(offset) -> bool:
+    """True for an integer 0 known at trace time (int, numpy int, a
+    concrete 0-d integer array); False for tracers and per-row
+    offsets."""
+    try:
+        return operator.index(offset) == 0
+    except TypeError:       # tracers, [B] arrays, floats
+        return False
+
+
+def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
+                   valid=None):
+    """Land new K/V rows in their physical pages, touching only the
+    pages written; returns the updated ``(k_pool, v_pool)``.
+
+    k/v_pool     [P, KV, page, D]  (the layout the kernels above read)
+    k/v_new      [B, S, KV, D]     rows at positions offset[b]..+S-1
+    block_tables [B, npages]       logical->physical page map per row
+    offset       Python int, scalar or [B]
+    valid        None or [B]: only the first valid[b] rows of row b are
+                 real; the rest go to the page of the table's LAST
+                 column (the caller's trash column)
+
+    The form is chosen from what the trace can see. An advanced-index
+    scatter ``pool.at[pid, :, slot, :]`` has two scatter dims around a
+    window dim, and XLA's TPU scatter then copies the WHOLE pool into a
+    window-contiguous layout and back (two pool-sized copies per pool
+    per call). Both forms below scatter along the leading dim of a
+    view whose layout is the pool's own, so they compile in place:
+
+    - whole pages, when ``offset`` is a concrete integer 0 (a Python or
+      numpy int, not a tracer) and the rows fill pages from slot 0
+      (``S < page`` or ``S % page == 0``: every prefill bucket):
+      ``[KV, page, D]`` windows by page id (``[KV, S, D]`` at slot 0
+      when ``S < page``);
+    - rows otherwise (decode, ragged chunks, ``valid``): rows of ``D``
+      into the flat ``[P*KV*page, D]`` view (a bitcast).
+
+    A row whose position lies past the block table (a scan that steps
+    on after a request's last token, a speculative write-ahead at the
+    context limit) or whose page id is outside the pool is DROPPED, as
+    the advanced-index scatter dropped it: no page is written for it.
+    Several rows may name one slot (dead rows, padding: the trash
+    page); any of them may win there, as with any scatter.
+    """
+    P, KV, page, D = k_pool.shape
+    B, S = k_new.shape[0], k_new.shape[1]
+    tbl = jnp.asarray(block_tables, jnp.int32)
+    npages = tbl.shape[1]
+    if (valid is None and _concrete_zero(offset)
+            and (S < page or S % page == 0)):
+        n = -(-S // page)
+        pids = tbl[:, :n].reshape(B * n)
+
+        def put(pool, new):
+            new = new.astype(pool.dtype).reshape(B * n, -1, KV, D)
+            new = jnp.swapaxes(new, 1, 2)          # [B*n, KV, rows, D]
+            return pool.at[pids, :, :new.shape[2], :].set(new)
+    else:
+        off = jnp.broadcast_to(
+            jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
+        pos = off[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+        if valid is not None:
+            alive = jnp.arange(S, dtype=jnp.int32)[None] \
+                < jnp.asarray(valid, jnp.int32).reshape(B, 1)
+            pos = jnp.where(alive, pos, (npages - 1) * page)
+        lpage = pos // page
+        pid = jnp.take_along_axis(
+            tbl, jnp.minimum(lpage, npages - 1), axis=1)        # [B,S]
+        heads = jnp.arange(KV, dtype=jnp.int32)
+        rows = (pid[:, :, None] * KV + heads) * page \
+            + (pos % page)[:, :, None]
+        # the flat index of a page id outside the pool would wrap or
+        # land in another page: send it, and positions past the table,
+        # one past the view's end, where the scatter drops it
+        lost = (lpage >= npages) | (pid < 0) | (pid >= P)
+        rows = jnp.where(lost[:, :, None], P * KV * page, rows)
+        rows = rows.reshape(B * S * KV)
+
+        def put(pool, new):
+            flat = pool.reshape(P * KV * page, D)
+            flat = flat.at[rows].set(
+                new.astype(pool.dtype).reshape(B * S * KV, D), mode="drop")
+            return flat.reshape(P, KV, page, D)
+
+    return put(k_pool, k_new), put(v_pool, v_new)
 
 
 def paged_attention_dense(q, k_pool, v_pool, block_tables, lengths):
