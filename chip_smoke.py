@@ -14,6 +14,11 @@ calls, at the full width of models the repo supports, on one TPU chip:
            ``Config.enable_paged_kv`` -> ``create_predictor`` ->
            ``ServingEngine`` in both of its modes.
 
+``--phases serve_latent`` (only when named) serves the latent-attention
+expert decoder (models/mla_moe.py) at its published widths and two
+layers through the same entry points: latent pools written in place,
+``mla_paged_decode_attention`` in the decode program, 0 dropped pairs.
+
 ``--four-chips`` adds the same train step over a real 2x2 mesh in two
 layouts (mp2 x dp2 on ParallelEngine; pp2 x mp2 on GPTForCausalLMPipe via
 ``fleet.distributed_model(...).train_batch``); asked for, fewer than four
@@ -55,11 +60,13 @@ import time
 
 ONE_CHIP_PHASES = ("kernels", "train", "serve")
 FOUR_CHIP_PHASES = ("mp2dp2", "pp2mp2")
+# run only when named in --phases: the default three fill their time limit
+EXTRA_PHASES = ("serve_latent",)
 # seconds per child, compilation included. The one-chip three sum to
 # 1100, inside the 1200 s that run is allowed; measured cold on a v5e
 # they took 72, 122 and 106 s (CHANGES.md PR 21).
 PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
-                 "mp2dp2": 400, "pp2mp2": 400}
+                 "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 400}
 RESULT_TAG = "CHIP_SMOKE_PHASE_RESULT "
 
 # Tolerances, each with its reason. Every comparison is
@@ -124,6 +131,18 @@ class Sizes:
             self.warm_lens = (100, 200, 400, 900, 1500)
             self.len_range, self.n_requests = (100, 1500), 12
             self.ref_prompt_len = 128
+            # serve_latent: the latent-attention expert decoder at its
+            # published widths, the dense layer and one expert layer that
+            # holds 32 of the router's 128 experts (1.76B parameters)
+            self.latent = dict(
+                vocab_size=65536, hidden_size=4096, num_layers=2,
+                num_heads=64, kv_lora_rank=512, qk_nope_head_dim=128,
+                qk_rope_head_dim=64, v_head_dim=128,
+                intermediate_size=16384, moe_intermediate_size=2048,
+                num_experts=128, num_local_experts=32,
+                num_experts_per_tok=8, max_position_embeddings=1152,
+                dtype="bfloat16")
+            self.latent_batch = 32
         else:
             self.gpt = dict(vocab_size=1024, hidden_size=128,
                             num_layers=2, num_heads=4,
@@ -140,6 +159,14 @@ class Sizes:
             self.warm_lens = (20, 100, 200)
             self.len_range, self.n_requests = (10, 200), 6
             self.ref_prompt_len = 64
+            self.latent = dict(
+                vocab_size=512, hidden_size=128, num_layers=2, num_heads=8,
+                kv_lora_rank=128, qk_nope_head_dim=32, qk_rope_head_dim=16,
+                v_head_dim=32, intermediate_size=256,
+                moe_intermediate_size=64, num_experts=16,
+                num_local_experts=4, num_experts_per_tok=4,
+                max_position_embeddings=288, dtype="bfloat16")
+            self.latent_batch = 4
 
 
 # ---------------------------------------------------------------------------
@@ -409,6 +436,26 @@ def kernel_cases(sz: Sizes):
 
     cases.append((f"ragged_paged_attention Sc={sz.prefill_chunk} B={B}",
                   ("ragged_paged_attention",), ragged, TOL_ATTN))
+
+    def latent(normal):
+        from paddle_tpu.models.mla_moe import MLAMoEConfig
+        from paddle_tpu.ops.pallas.mla_attention import (
+            mla_paged_attention_dense, mla_paged_decode_attention)
+
+        cfg = MLAMoEConfig(**sz.latent)
+        Hl, dc, dr = cfg.num_heads, cfg.kv_lora_rank, cfg.rope_cache_width
+        tbl, lens = table(B), jnp.asarray(ragged_lens())
+        scale = cfg.softmax_scale
+        return (lambda ql, qr, cp, rp: mla_paged_decode_attention(
+                    ql, qr, cp, rp, tbl, lens, scale, interpret=interpret),
+                lambda ql, qr, cp, rp: mla_paged_attention_dense(
+                    ql[:, None], qr[:, None], cp, rp, tbl, lens,
+                    scale)[:, 0],
+                (normal((B, Hl, dc)), normal((B, Hl, dr)),
+                 normal((P, 1, page, dc)), normal((P, 1, page, dr))))
+
+    cases.append((f"mla_paged_decode_attention B={B} page={page}",
+                  ("mla_paged_decode_attention",), latent, TOL_ATTN))
     return cases
 
 
@@ -708,6 +755,99 @@ def phase_serve(sz: Sizes) -> None:
     finish_child("serve", device, events, {"modes": report})
 
 
+def phase_serve_latent(sz: Sizes) -> None:
+    """The latent-attention expert decoder through ServingEngine in its
+    default mode: the latent pools written in place, the latent kernel
+    in the decode program, no routed pair dropped."""
+    jax, device, events = start_child(sz.rehearsal)
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import (Config, ServingEngine,
+                                      create_predictor)
+    from paddle_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
+
+    cfg = MLAMoEConfig(**sz.latent)
+    t0 = time.perf_counter()
+    paddle.set_default_dtype(cfg.dtype)
+    paddle.seed(0)
+    model = MLAMoEForCausalLM(cfg)
+    n_par = sum(int(np.prod(p.shape)) for p in model.parameters())
+    print(f"  latent attention {cfg.num_heads} heads x ({cfg.qk_nope_head_dim}"
+          f"+{cfg.qk_rope_head_dim}), latent {cfg.kv_lora_rank}; "
+          f"{cfg.num_local_experts} of {cfg.num_experts} experts of "
+          f"{cfg.moe_intermediate_size} held, {cfg.num_experts_per_tok} a "
+          f"token; depth {cfg.num_layers} ({n_par / 1e9:.2f}B params); "
+          f"built in {time.perf_counter() - t0:.1f}s", flush=True)
+    pred = create_predictor(
+        Config().set_model(model).enable_paged_kv(page_size=sz.page))
+    r = np.random.RandomState(0)
+
+    def prompts(lens):
+        return [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
+                for n in lens]
+
+    # one prompt per prefill bucket the mix can reach (up to 1024)
+    warm = prompts([n for n in sz.warm_lens if n <= 900])
+    lens = r.randint(sz.len_range[0], min(sz.len_range[1], 900) + 1,
+                     (sz.n_requests,))
+    lens[0] = sz.ref_prompt_len
+    mix = prompts(lens)
+    logits = pred.run([mix[0][None, :]])[0]
+    ref_last = logits[0, -1].astype("float32")
+    check(np.isfinite(ref_last).all(), "reference forward: finite logits")
+    eng = ServingEngine(pred, max_batch=sz.latent_batch)
+    check([(c.shape[1], c.shape[3], k.shape[3]) for c, k in eng.pools]
+          == [(1, cfg.kv_lora_rank, cfg.rope_cache_width)] * cfg.num_layers,
+          f"the pool holds one latent and one rotated key a position: "
+          f"{eng.pools[0][0].shape} + {eng.pools[0][1].shape}")
+    t0 = time.perf_counter()
+    for p in warm:
+        eng.submit(p, max_new_tokens=sz.new_tokens)
+    done = eng.run()
+    t_setup = time.perf_counter() - t0
+    check(len(done) == len(warm), f"warm-up mix drained ({len(done)})")
+    compiles0, xla0 = eng.stats.compiles, events.compiles
+    t0 = time.perf_counter()
+    rids = [eng.submit(p, max_new_tokens=sz.new_tokens) for p in mix]
+    done = eng.run()
+    t_run = time.perf_counter() - t0
+    outs = [np.asarray(done[rid].new_tokens) for rid in rids if rid in done]
+    print(f"    set-up {t_setup:.1f}s; {len(mix)} requests in {t_run:.2f}s "
+          f"on {device['kind']} (not a metric); pool {eng.P} pages",
+          flush=True)
+    check(len(outs) == len(rids)
+          and all(len(o) == sz.new_tokens for o in outs)
+          and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
+          f"every request returned {sz.new_tokens} tokens of the vocabulary")
+    check(eng.stats.compiles == compiles0 and events.compiles == xla0,
+          "no compile after warm-up")
+    tok0 = int(outs[0][0])
+    gap = float(ref_last.max() - ref_last[tok0])
+    check(gap <= TOL_LOGIT,
+          f"first token {tok0} scores within {TOL_LOGIT} of the reference "
+          f"forward's best logit (gap {gap:.3f})")
+    st = eng.moe_stats()
+    check(st["dropped"] == 0 and st["tokens"][-1] > 0,
+          f"expert layers dropped {st['dropped']} routed pairs of "
+          f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
+    sites = eng.program_sites()
+    found = kernel_names(eng.lowered_text(("decode",)))
+    check(sz.rehearsal or found.get("mla_paged_decode_attention", 0)
+          == cfg.num_layers,
+          f"program ('decode',) holds Mosaic calls {found}")
+    for site in [("decode",)] + sorted(s for s in sites
+                                       if s[0] == "prefill")[-1:]:
+        text = eng.compiled_text(site)
+        n = sum(eng.pool_copies(text, a.shape) for a in eng.pools[0])
+        check(sz.rehearsal or n == 0,
+              f"compiled program {site}: {n} copies of a whole latent or "
+              f"rotated-key pool")
+    finish_child("serve_latent", device, events,
+                 {"setup_s": round(t_setup, 1), "run_s": round(t_run, 2),
+                  "pool_pages": eng.P})
+
+
 # ---------------------------------------------------------------------------
 # parent: children, in order, one at a time; never imports JAX
 # ---------------------------------------------------------------------------
@@ -760,7 +900,8 @@ def main(argv=None) -> int:
                          "layouts; fewer than four TPU devices is an error")
     ap.add_argument("--phases", default=None,
                     help="comma-separated subset, run in the given order: "
-                         + ",".join(ONE_CHIP_PHASES + FOUR_CHIP_PHASES))
+                         + ",".join(ONE_CHIP_PHASES + FOUR_CHIP_PHASES
+                                    + EXTRA_PHASES))
     ap.add_argument("--rehearsal", action="store_true",
                     help="toy sizes on the CPU; output says REHEARSAL")
     ap.add_argument("--phase", default=None, help=argparse.SUPPRESS)
@@ -773,6 +914,8 @@ def main(argv=None) -> int:
                 phase_kernels(sz)
             elif args.phase == "serve":
                 phase_serve(sz)
+            elif args.phase == "serve_latent":
+                phase_serve_latent(sz)
             else:
                 phase_train(sz, args.phase)
         except Failed as e:
@@ -783,7 +926,8 @@ def main(argv=None) -> int:
 
     if args.phases:
         phases = tuple(args.phases.split(","))
-        unknown = set(phases) - set(ONE_CHIP_PHASES + FOUR_CHIP_PHASES)
+        unknown = set(phases) - set(ONE_CHIP_PHASES + FOUR_CHIP_PHASES
+                                    + EXTRA_PHASES)
         if unknown:
             ap.error(f"unknown phases {sorted(unknown)}")
     else:

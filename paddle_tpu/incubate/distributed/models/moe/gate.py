@@ -1,4 +1,5 @@
-"""MoE gates: naive top-k, Switch (top-1), GShard (top-2).
+"""MoE gates: naive top-k, Switch (top-1), GShard (top-2), and the
+sigmoid top-k gate with a selection bias (no capacity, no drops).
 
 TPU-native re-design of the reference's gate zoo
 (reference: python/paddle/incubate/distributed/models/moe/gate/
@@ -20,7 +21,8 @@ from typing import Optional
 
 from .....nn.layer import Layer
 
-__all__ = ["BaseGate", "NaiveGate", "SwitchGate", "GShardGate"]
+__all__ = ["BaseGate", "NaiveGate", "SwitchGate", "GShardGate",
+           "SigmoidTopKGate"]
 
 
 class BaseGate(Layer):
@@ -91,3 +93,39 @@ class GShardGate(BaseGate):
                 "random_routing=False for deterministic top-k")
         self.top_k = topk
         self.capacity_factor = capacity
+
+
+class SigmoidTopKGate(BaseGate):
+    """Sigmoid scores, top-k chosen on ``score + bias`` (the bias steers
+    the CHOICE only: auxiliary-loss-free load balancing), weights
+    ``scaling * score / sum(chosen scores)``. No capacity: every chosen
+    pair is computed (``GatedMoELayer``). The router product and the
+    top-k run in float32 whatever the model's type, because a near-tie
+    between the k-th and the next score flips an expert under bf16
+    rounding."""
+
+    def __init__(self, d_model, num_experts, topk: int = 8,
+                 routed_scaling_factor: float = 1.0, **kw):
+        super().__init__(d_model, num_experts)
+        self.top_k = topk
+        self.capacity_factor = None
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.bias = self.create_parameter((num_experts,), is_bias=True)
+
+    def route(self, x2d):
+        """Values in, values out: tokens [T, d] -> (expert ids [T, k]
+        int32 over ALL ``num_experts``, weights [T, k] float32,
+        normalised over the k chosen)."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        s = jax.nn.sigmoid(jnp.dot(
+            x2d.astype(jnp.float32), self.weight._value.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, idx = lax.top_k(s + self.bias._value.astype(jnp.float32),
+                           self.top_k)
+        sel = jnp.take_along_axis(s, idx, axis=-1)
+        w = self.routed_scaling_factor * sel / jnp.sum(sel, -1,
+                                                       keepdims=True)
+        return idx.astype(jnp.int32), w

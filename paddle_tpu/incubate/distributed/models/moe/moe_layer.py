@@ -38,9 +38,10 @@ from .....distributed import collective as C
 from .....nn.layer import Layer
 from .....observability import moestats as _moestats
 from .....tensor import Tensor
-from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate
+from .gate import (BaseGate, GShardGate, NaiveGate, SigmoidTopKGate,
+                   SwitchGate)
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "GatedMoELayer"]
 
 
 def _topk_dispatch(probs, k: int, cap: int):
@@ -322,3 +323,132 @@ class MoELayer(Layer):
         return (f"d={self.d_model}, h={self.d_hidden}, "
                 f"E={self.num_experts}, ep={self.world_size}, "
                 f"gate={type(self.gate).__name__}")
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """(silu(x W_g) * x W_u) W_d, products accumulated and returned in
+    float32."""
+    g = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                   preferred_element_type=jnp.float32)
+
+
+def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
+                  expert_offset: int = 0):
+    """The held experts' part of a routed SwiGLU layer, with work in
+    proportion to the routed pairs: the (token, expert) pairs are sorted
+    by expert and the three products are grouped over the held experts
+    (``lax.ragged_dot``: XLA's grouped matmul on TPU). No capacity, no
+    ``[T, E, C]`` tensor, no dropped pair.
+
+    x2d      [T, d]       tokens
+    idx      [T, k] int32 chosen experts, numbered over ALL experts
+    weights  [T, k] f32   their weights (normalised over all k chosen)
+    w_*      [El, ...]    the experts held here: expert_offset ..
+                          expert_offset + El - 1
+
+    Returns (y [T, d] float32: the sum over chosen AND held experts;
+    sizes [El + 2] int32: the pairs in each held expert's group, the
+    pairs whose expert is held elsewhere, and the pairs that lay inside a
+    group AND were summed into y: what was computed, counted from the
+    sorted rows and not from the router's choice, so a pair that is
+    held but fell outside every group shows as missing from the last).
+    Pairs of absent experts sort behind every group and are never
+    multiplied."""
+    T, k = idx.shape
+    El = w_gate.shape[0]
+    local = idx - expert_offset
+    held = (local >= 0) & (local < El)
+    e = jnp.where(held, local, El).reshape(T * k)
+    order = jnp.argsort(e, stable=True)
+    gs = jnp.bincount(e, length=El).astype(jnp.int32)
+    xs = x2d[order // k]                                   # [T*k, d]
+    g = lax.ragged_dot(xs, w_gate, gs,
+                       preferred_element_type=jnp.float32)
+    u = lax.ragged_dot(xs, w_up, gs, preferred_element_type=jnp.float32)
+    out = lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype), w_down,
+                         gs, preferred_element_type=jnp.float32)
+    # a sorted row counts if its pair is held and a group covered it
+    used = held.reshape(T * k)[order] & (jnp.arange(T * k) < gs.sum())
+    wf = weights.reshape(T * k)[order]
+    out = jnp.where(used[:, None], out * wf[:, None], 0.0)
+    y = out[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
+    sizes = jnp.concatenate([gs, jnp.stack([(~held).sum(), used.sum()])
+                             .astype(jnp.int32)])
+    return y, sizes
+
+
+class GatedMoELayer(Layer):
+    """Routed SwiGLU experts plus shared experts, for one holder of an
+    expert-parallel layer: it is TOLD which experts it holds
+    (``expert_offset``, ``num_local_experts``), routes every token over
+    all ``num_experts`` (``SigmoidTopKGate``), and computes its own
+    experts' part of the sum. The shared experts run whole on every
+    holder. Inference only (no tape backward); on one chip there is no
+    exchange, and nothing stands in for the absent holders.
+
+    ``forward(x, counts=None)``: ``counts`` is an optional
+    ``[num_local_experts + 3]`` int32 routing counter (``routed_swiglu``'s
+    sizes: pairs per held expert, pairs of absent experts, pairs
+    computed and summed; then the tokens seen); when given the updated
+    counter is returned beside the output.
+    """
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 num_local_experts: Optional[int] = None,
+                 expert_offset: int = 0, top_k: int = 8,
+                 routed_scaling_factor: float = 1.0,
+                 num_shared_experts: int = 1, weight_attr=None,
+                 down_attr=None):
+        super().__init__()
+        El = num_experts if num_local_experts is None \
+            else int(num_local_experts)
+        enforce(0 <= expert_offset and expert_offset + El <= num_experts,
+                f"held experts {expert_offset}..{expert_offset + El - 1} "
+                f"lie outside the router's {num_experts}")
+        self.d_model, self.d_hidden = d_model, d_hidden
+        self.num_experts, self.num_local_experts = num_experts, El
+        self.expert_offset = int(expert_offset)
+        self.gate = SigmoidTopKGate(
+            d_model, num_experts, topk=top_k,
+            routed_scaling_factor=routed_scaling_factor)
+        d, h, hs = d_model, d_hidden, d_hidden * num_shared_experts
+        down_attr = down_attr if down_attr is not None else weight_attr
+        self.w_gate = self.create_parameter((El, d, h), attr=weight_attr)
+        self.w_up = self.create_parameter((El, d, h), attr=weight_attr)
+        self.w_down = self.create_parameter((El, h, d), attr=down_attr)
+        self.shared = hs > 0
+        if self.shared:
+            self.shared_gate = self.create_parameter((d, hs),
+                                                     attr=weight_attr)
+            self.shared_up = self.create_parameter((d, hs),
+                                                   attr=weight_attr)
+            self.shared_down = self.create_parameter((hs, d),
+                                                     attr=down_attr)
+
+    def forward(self, x, counts=None):
+        xv = x._value if isinstance(x, Tensor) else x
+        shape = xv.shape
+        x2d = xv.reshape(-1, self.d_model)
+        idx, w = self.gate.route(x2d)
+        y, sizes = routed_swiglu(
+            x2d, idx, w, self.w_gate._value, self.w_up._value,
+            self.w_down._value, self.expert_offset)
+        # a no-op unless a collection is open on this thread
+        _moestats.record({"choices": idx, "load": sizes})
+        if self.shared:
+            y = y + swiglu(x2d, self.shared_gate._value,
+                            self.shared_up._value, self.shared_down._value)
+        out = Tensor(y.astype(xv.dtype).reshape(shape),
+                     stop_gradient=True)
+        if counts is None:
+            return out
+        seen = jnp.asarray([x2d.shape[0]], jnp.int32)
+        return out, counts + jnp.concatenate([sizes, seen])
+
+    def extra_repr(self):
+        return (f"d={self.d_model}, h={self.d_hidden}, "
+                f"E={self.num_experts}, held={self.expert_offset}.."
+                f"{self.expert_offset + self.num_local_experts - 1}, "
+                f"k={self.gate.top_k}")

@@ -67,6 +67,27 @@ from ..core.bucketing import bucket as _bucket  # noqa: E402
 from ..core.compile_stats import CompileStats  # noqa: E402,F401
 
 
+def _kv_pool_shapes(model, P: int, page: int):
+    """Per layer, the shapes ``(first, second)`` of the two arrays the
+    page pool holds, ``[P, cache heads, page, width]`` each. A model
+    says so itself (``kv_pool_shapes(P, page)``: a latent-attention
+    model pools a latent and a rotated key); without that it is K and V
+    of ``num_kv_heads x head_dim``."""
+    fn = getattr(model, "kv_pool_shapes", None)
+    if fn is not None:
+        return [(tuple(a), tuple(b)) for a, b in fn(P, page)]
+    cfg = model.config
+    shape = (P, cfg.num_kv_heads, page, cfg.head_dim)
+    return [(shape, shape)] * cfg.num_layers
+
+
+def _kv_page_bytes(model, page: int, dtype) -> int:
+    """Bytes one page takes over every layer's two pooled arrays."""
+    return sum(int(np.prod(a)) + int(np.prod(b))
+               for a, b in _kv_pool_shapes(model, 1, page)) \
+        * np.dtype(dtype).itemsize
+
+
 def _sample(logits, key, gen: "GenerationConfig"):
     """Greedy / temperature / top-k / top-p sampling (traceable; used by
     both the first-token host step and the compiled decode loop)."""
@@ -352,7 +373,6 @@ class Predictor:
         bucket lattice, every mix whose page demand lands in the same
         bucket reuses the same compiled programs (the extra pages are
         never referenced by any table entry below the trash id)."""
-        cfg = self._model.config
         B = len(lengths)
         npages = -(-M // page)
         need = [-(-(int(l) + n_new) // page) for l in lengths]
@@ -363,12 +383,11 @@ class Predictor:
         for b, nb in enumerate(need):
             table[b, :nb] = np.arange(nxt, nxt + nb)
             nxt += nb
-        shape = (P, cfg.num_kv_heads, page, cfg.head_dim)
         # one table copy per layer: the cache pytree is DONATED to the
         # compiled step, and XLA rejects donating one buffer twice
-        return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+        return [(jnp.zeros(a, dtype), jnp.zeros(b, dtype),
                  jnp.asarray(table))
-                for _ in range(cfg.num_layers)], P
+                for a, b in _kv_pool_shapes(self._model, P, page)], P
 
     def generate(self, input_ids, max_new_tokens: Optional[int] = None,
                  lengths=None, **overrides):

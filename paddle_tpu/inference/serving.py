@@ -222,7 +222,7 @@ class ServingEngine:
         import inspect
         import os
 
-        from . import _bucket
+        from . import _bucket, _kv_page_bytes, _kv_pool_shapes
 
         cfg = predictor.config
         enforce(cfg._kv_page_size,
@@ -295,9 +295,8 @@ class ServingEngine:
         # harness) fall back to the geometric default.
         geom = self.B * self.npages + 1
         if pool_pages == "auto":
-            page_bytes = (2 * mcfg.num_layers * mcfg.num_kv_heads
-                          * self.page * mcfg.head_dim
-                          * np.dtype(self._dtype).itemsize)
+            page_bytes = _kv_page_bytes(predictor._model, self.page,
+                                        self._dtype)
             resident = sum(_ml.shard_bytes(p._value)
                            for p in predictor._params)
             fit = _ml.suggest_pool_pages(jax.devices()[0], page_bytes,
@@ -357,10 +356,26 @@ class ServingEngine:
         # gate for the refcount migration
         self.debug = bool(debug_invariants) or bool(int(os.environ.get(
             "PADDLE_TPU_SERVING_DEBUG", "0") or 0))
-        shape = (self.P, mcfg.num_kv_heads, self.page, mcfg.head_dim)
-        self.pools = [(jnp.zeros(shape, self._dtype),
-                       jnp.zeros(shape, self._dtype))
-                      for _ in range(mcfg.num_layers)]
+        # pool geometry is the model's (inference._kv_pool_shapes): K and
+        # V of num_kv_heads x head_dim, or what the model says it pools
+        shapes = _kv_pool_shapes(predictor._model, self.P, self.page)
+        enforce(phase is None or all(a == b for a, b in shapes),
+                "the disaggregated phases migrate a page as ONE stacked "
+                "array of every layer's two pooled arrays; this model "
+                "pools two arrays of different shapes "
+                f"({shapes[0][0][1:]} and {shapes[0][1][1:]}: a latent "
+                "cache), so run it on unified replicas (phase=None)")
+        self.pools = [(jnp.zeros(a, self._dtype), jnp.zeros(b, self._dtype))
+                      for a, b in shapes]
+        # routing counters of an expert model, on the device beside the
+        # pools: one small int32 array per layer, donated to the decode
+        # program with the caches and fetched only by moe_stats()
+        cshape = getattr(predictor._model, "moe_counter_shape", None)
+        self._moe_counts = None
+        if cshape is not None:
+            layers, *row = cshape()
+            self._moe_counts = [jnp.zeros(row, jnp.int32)
+                                for _ in range(layers)]
         self.tables = np.full((self.B, self.npages), self.trash, np.int32)
         self.slots: List[Optional[_Slot]] = [None] * self.B
         self.queue: deque = deque()
@@ -1673,6 +1688,8 @@ class ServingEngine:
                 tbl[mid_prefill, :] = self.trash
         caches = [(kp, vp, jnp.asarray(tbl))
                   for kp, vp in self.pools]
+        if self._moe_counts is not None:
+            caches = [c + (n,) for c, n in zip(caches, self._moe_counts)]
         fn = self._decode_step_fn()
         self.stats.note("serve_decode",
                         (self.B, self.M, self.chunk, self.P,
@@ -1683,6 +1700,8 @@ class ServingEngine:
             ("decode",), fn, self._pvals(), jnp.asarray(tok), caches,
             jnp.asarray(pos), sub)
         self.pools = [(c[0], c[1]) for c in caches]
+        if self._moe_counts is not None:
+            self._moe_counts = [c[3] for c in caches]
         toks = np.asarray(toks)
         emitted = 0
         for b in active:
@@ -2039,10 +2058,10 @@ class ServingEngine:
         analyzed executable's byte classes plus the measured resident
         state (params + the KV page pool, with the per-page byte cost
         and pool geometry the "auto" sizing uses)."""
-        mcfg = self.pred._model.config
-        page_bytes = (2 * mcfg.num_layers * mcfg.num_kv_heads
-                      * self.page * mcfg.head_dim
-                      * np.dtype(self._dtype).itemsize)
+        from . import _kv_page_bytes
+
+        page_bytes = _kv_page_bytes(self.pred._model, self.page,
+                                    self._dtype)
         pool_bytes = sum(_ml.shard_bytes(kp) + _ml.shard_bytes(vp)
                          for kp, vp in self.pools)
         return {
@@ -2057,6 +2076,34 @@ class ServingEngine:
                 "live_peak_bytes": self._live_peak,
             },
         }
+
+    def release_pools(self) -> None:
+        """Give the page pools' device memory back while the engine and
+        its model are still held (a caller that needs the HBM for
+        another program over the same weights). The engine serves
+        nothing after this."""
+        self.pools = None
+
+    def moe_stats(self) -> Optional[Dict[str, Any]]:
+        """Routing counters of an expert model's decode steps, fetched
+        from the device now (the only time the host reads them; no step
+        waits for this): per layer, the routed pairs each held expert
+        took, the pairs that went to experts held elsewhere, the pairs
+        whose product was computed and summed, and the tokens seen
+        (every row of the decode batch, live or not: a dead row is
+        routed like any other). ``dropped`` = the pairs the router sent
+        to a held expert (tokens x k less the absent ones) that the
+        grouped products did not cover; the layer has no capacity, so
+        anything but 0 is a fault of the sort or of the group sizes.
+        None for a model without routed experts."""
+        if self._moe_counts is None:
+            return None
+        c = np.stack([np.asarray(a) for a in self._moe_counts]
+                     ).astype(np.int64)
+        k = int(getattr(self.pred._model.config, "num_experts_per_tok", 0))
+        return {"pairs": c[:, :-3], "absent_pairs": c[:, -3],
+                "summed_pairs": c[:, -2], "tokens": c[:, -1],
+                "dropped": int((c[:, -1] * k - c[:, -3] - c[:, -2]).sum())}
 
     def roofline_report(self):
         """Roofline verdict of the shared decode round

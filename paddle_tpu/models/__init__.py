@@ -7,7 +7,10 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
                     LlamaPretrainingCriterion, llama_13b, llama_7b,
                     llama_tiny, llama_tiny_draft)
 
-__all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM", "GPTForCausalLMPipe",
+from .mla_moe import MLAMoEConfig, MLAMoEForCausalLM, mla_moe_tiny
+
+__all__ = ["MLAMoEConfig", "MLAMoEForCausalLM", "mla_moe_tiny",
+           "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTForCausalLMPipe",
            "GPTPretrainingCriterion", "gpt_tiny", "gpt_125m", "gpt_350m",
            "gpt_1p3b", "gpt_13b", "gpt_moe_tiny", "ernie_moe_base",
            "LlamaConfig", "LlamaModel", "LlamaForCausalLM",

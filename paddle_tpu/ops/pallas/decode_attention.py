@@ -284,7 +284,9 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
     """Land new K/V rows in their physical pages, touching only the
     pages written; returns the updated ``(k_pool, v_pool)``.
 
-    k/v_pool     [P, KV, page, D]  (the layout the kernels above read)
+    k/v_pool     [P, KV, page, D]  (the layout the kernels above read;
+                                   the two may differ in D: a latent
+                                   cache pools a latent and a rotated key)
     k/v_new      [B, S, KV, D]     rows at positions offset[b]..+S-1
     block_tables [B, npages]       logical->physical page map per row
     offset       Python int, scalar or [B]
@@ -314,7 +316,7 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
     Several rows may name one slot (dead rows, padding: the trash
     page); any of them may win there, as with any scatter.
     """
-    P, KV, page, D = k_pool.shape
+    P, KV, page = k_pool.shape[:3]
     B, S = k_new.shape[0], k_new.shape[1]
     tbl = jnp.asarray(block_tables, jnp.int32)
     npages = tbl.shape[1]
@@ -324,7 +326,8 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
         pids = tbl[:, :n].reshape(B * n)
 
         def put(pool, new):
-            new = new.astype(pool.dtype).reshape(B * n, -1, KV, D)
+            new = new.astype(pool.dtype).reshape(B * n, -1, KV,
+                                                 pool.shape[-1])
             new = jnp.swapaxes(new, 1, 2)          # [B*n, KV, rows, D]
             return pool.at[pids, :, :new.shape[2], :].set(new)
     else:
@@ -349,6 +352,7 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
         rows = rows.reshape(B * S * KV)
 
         def put(pool, new):
+            D = pool.shape[-1]
             flat = pool.reshape(P * KV * page, D)
             flat = flat.at[rows].set(
                 new.astype(pool.dtype).reshape(B * S * KV, D), mode="drop")

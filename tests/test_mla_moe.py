@@ -1,0 +1,353 @@
+"""The latent-attention expert decoder (models/mla_moe.py), its expert
+layer (GatedMoELayer), its paged latent kernel and its life under
+ServingEngine, against the plain float32 reference
+(benchmarks/references/sarvam.py) at a tiny size on the CPU, seeded
+weights. Also: the Llama engine's pools and program keys are as they
+were before the engine took its pool geometry from the model.
+"""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import paddle_tpu as paddle  # noqa: E402
+from benchmarks.harness import weights  # noqa: E402
+from benchmarks.harness.families import mla_moe_serving as fam  # noqa: E402
+from benchmarks.references import sarvam as ref  # noqa: E402
+from paddle_tpu.incubate.distributed.models.moe import (  # noqa: E402
+    GatedMoELayer, SigmoidTopKGate)
+from paddle_tpu.incubate.distributed.models.moe.moe_layer import (  # noqa: E402
+    routed_swiglu)
+from paddle_tpu.inference import (Config, ServingEngine,  # noqa: E402
+                                  create_predictor)
+from paddle_tpu.models.mla_moe import (MLAMoEForCausalLM,  # noqa: E402
+                                       yarn_inv_freq)
+from paddle_tpu.ops.pallas import mla_attention as ma  # noqa: E402
+
+CFG = {
+    "hidden_size": 64, "intermediate_size": 128,
+    "moe_intermediate_size": 32, "num_hidden_layers": 3,
+    "first_k_dense_replace": 1, "num_attention_heads": 4,
+    "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "q_head_dim": 24, "head_dim": 40, "v_head_dim": 16,
+    "num_experts": 4, "router_experts": 16, "expert_offset": 4,
+    "num_experts_per_tok": 4, "num_shared_experts": 1,
+    "routed_scaling_factor": 2.5, "use_qk_norm": True, "vocab_size": 256,
+    "rope_theta": 10000, "rms_norm_eps": 1e-6,
+    "rope_scaling": {"type": "deepseek_yarn", "factor": 4,
+                     "original_max_position_embeddings": 32,
+                     "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                     "mscale_all_dim": 1},
+    "torch_dtype": "float32", "initializer_range": 0.3}
+SEED = 2 ** 31 + 99
+M = 96
+
+
+def build(cfg=CFG, seed=SEED):
+    paddle.set_default_dtype("float32")
+    model = MLAMoEForCausalLM(fam.model_config(cfg, M))
+    model.eval()
+    named = list(model.named_parameters())
+    weights.load(named, {n: fam.names_of(n, cfg) for n, _ in named},
+                 ref.leaf_table(cfg), seed, "float32")
+    return model
+
+
+@pytest.fixture(scope="module")
+def model():
+    return build()
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(3).integers(0, 256, 40).astype(np.int32)
+
+
+def ref_logits(prompt, served, cfg=CFG):
+    return ref.ServeReference(cfg, SEED).logits([(prompt, served)])[0]
+
+
+def test_full_forward_is_the_reference(model, tokens):
+    got = np.asarray(model(paddle.to_tensor(tokens[None]))._value)[0]
+    want = ref_logits(tokens[:1], np.append(tokens[1:], 0))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_yarn_frequencies_are_the_references():
+    rs = CFG["rope_scaling"]
+    np.testing.assert_allclose(yarn_inv_freq(8, 10000, rs),
+                               ref.yarn_inv_freq(8, 10000, rs))
+    big = {"factor": 40, "original_max_position_embeddings": 4096,
+           "beta_fast": 32, "beta_slow": 1}
+    inv = yarn_inv_freq(64, 10000, big)
+    plain = 1.0 / 10000 ** (np.arange(0, 64, 2) / 64)
+    np.testing.assert_allclose(inv, ref.yarn_inv_freq(64, 10000, big))
+    assert inv[0] == plain[0]                       # fast dims untouched
+    np.testing.assert_allclose(inv[-1], plain[-1] / 40)   # slow: / factor
+
+
+def test_engine_prefill_then_decode_is_the_reference(model, tokens):
+    """Prefill buckets + the decode program over the latent pool: every
+    served token's logit gap to the reference's full forward is 0 up to
+    float32 noise, for two ragged requests sharing the batch."""
+    pred = create_predictor(Config().set_model(model).enable_paged_kv(
+        page_size=8))
+    eng = ServingEngine(pred, max_batch=2)
+    shapes = [(p.shape, r.shape) for p, r in eng.pools]
+    assert shapes == [((eng.P, 1, 8, 32), (eng.P, 1, 8, 128))] * 3
+    rids = [eng.submit(tokens[:21], max_new_tokens=9),
+            eng.submit(tokens[5:18], max_new_tokens=12)]
+    done = eng.run()
+    for rid, prompt in zip(rids, (tokens[:21], tokens[5:18])):
+        served = np.asarray(done[rid].new_tokens)
+        lg = ref_logits(prompt, served)
+        assert ref.served_gap(lg, served).max() < 1e-3
+    st = eng.moe_stats()
+    assert st["dropped"] == 0
+    assert (st["tokens"] == [0, st["tokens"][1], st["tokens"][1]]).all()
+    assert st["tokens"][1] > 0 and st["tokens"][1] % 2 == 0   # B rows a step
+    np.testing.assert_array_equal(
+        st["pairs"].sum(1) + st["absent_pairs"], st["tokens"] * 4)
+    np.testing.assert_array_equal(st["summed_pairs"], st["pairs"].sum(1))
+    eng.release_pools()
+    assert eng.pools is None
+
+
+def test_generate_static_and_paged_agree_with_full_forwards(model, tokens):
+    want = list(tokens[:11])
+    for _ in range(5):
+        lg = np.asarray(model(paddle.to_tensor(
+            np.asarray([want], np.int32)))._value)[0, -1]
+        want.append(int(lg.argmax()))
+    for conf in (Config().set_model(model),
+                 Config().set_model(model).enable_paged_kv(page_size=8)):
+        out = create_predictor(conf).generate(
+            paddle.to_tensor(tokens[None, :11]), max_new_tokens=5)
+        assert list(np.asarray(out._value)[0]) == want
+
+
+def test_absorbed_is_unabsorbed(model, tokens):
+    """One layer's attention over the same 24 positions: the prefill
+    form (per-head keys and values) against the absorbed form through
+    the cache (8 positions prefilled, 16 fed one at a time and 4 at a
+    time)."""
+    attn = model.layers[1].self_attn
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 64), jnp.float32)
+    want, _ = attn(x, cache=None)
+    for step in (1, 4):
+        cache = model._empty_caches(2, 32, jnp.float32)[0]
+        out, cache = attn(x[:, :8], cache=cache, offset=0)
+        outs = [out]
+        for t in range(8, 24, step):
+            out, cache = attn(x[:, t:t + step], cache=cache,
+                              offset=jnp.asarray([t, t], jnp.int32))
+            outs.append(out)
+        np.testing.assert_allclose(np.concatenate(outs, 1), want,
+                                   rtol=1e-4, atol=1e-5)
+
+
+def _expert_layer(offset, held, seed=0):
+    paddle.seed(seed)
+    layer = GatedMoELayer(64, 32, 16, held, offset, top_k=4,
+                          routed_scaling_factor=2.5)
+    return layer
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """Experts 0..15 held 4 to a holder: the holders' routed parts, with
+    the shared expert counted once, add up to the layer that holds all
+    16, and that is the reference's uncut layer."""
+    whole = _expert_layer(0, 16)
+    rng = np.random.default_rng(0)
+    for p in whole.parameters():
+        p._value = jnp.asarray(rng.normal(0, 0.2, p.shape), jnp.float32)
+    x = jnp.asarray(rng.normal(0, 1, (24, 64)), jnp.float32)
+    want = np.asarray(whole(x)._value)
+    shared = np.asarray(whole.shared_down._value.T @ (
+        jax.nn.silu(x @ whole.shared_gate._value)
+        * (x @ whole.shared_up._value)).T).T
+    total = shared.copy()
+    for off in (0, 4, 8, 12):
+        part = _expert_layer(off, 4)
+        for name in ("w_gate", "w_up", "w_down"):
+            getattr(part, name)._value = getattr(whole, name)._value[
+                off:off + 4]
+        for name in ("shared_gate", "shared_up", "shared_down"):
+            getattr(part, name)._value = getattr(whole, name)._value
+        part.gate.weight._value = whole.gate.weight._value
+        part.gate.bias._value = whole.gate.bias._value
+        total += np.asarray(part(x)._value) - shared
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
+    # the reference, uncut: route, then expert by expert
+    cfg = dict(CFG, num_experts=16, expert_offset=0)
+    with jax.default_matmul_precision("highest"):
+        idx, g = ref.route(x, whole.gate.weight._value,
+                           whole.gate.bias._value, cfg)
+        y = ref.swiglu(x, whole.shared_gate._value, whole.shared_up._value,
+                       whole.shared_down._value, "float32")
+        for j in range(16):
+            y = y + ref.expert_part(
+                x, idx, g, j, whole.w_gate._value[j], whole.w_up._value[j],
+                whole.w_down._value[j], "float32")
+    np.testing.assert_allclose(want, np.asarray(y), rtol=1e-4, atol=1e-5)
+
+
+def test_selection_bias_changes_the_choice_and_never_the_weights():
+    paddle.seed(1)
+    gate = SigmoidTopKGate(64, 16, topk=4, routed_scaling_factor=2.5)
+    rng = np.random.default_rng(2)
+    gate.weight._value = jnp.asarray(rng.normal(0, 0.3, (64, 16)),
+                                     jnp.float32)
+    x = jnp.asarray(rng.normal(0, 1, (50, 64)), jnp.float32)
+    gate.bias._value = jnp.zeros(16)
+    idx0, w0 = map(np.asarray, gate.route(x))
+    gate.bias._value = jnp.zeros(16).at[3].set(10.0)
+    idx1, w1 = map(np.asarray, gate.route(x))
+    assert (idx1 == 3).any(axis=1).all()        # the bias steers the choice
+    assert not (idx0 == 3).any(axis=1).all()
+    s = np.asarray(jax.nn.sigmoid(x @ gate.weight._value))
+    for idx, w in ((idx0, w0), (idx1, w1)):     # weights: scores alone
+        sel = np.take_along_axis(s, idx, 1)
+        np.testing.assert_allclose(w, 2.5 * sel / sel.sum(1, keepdims=True),
+                                   rtol=1e-5)
+        np.testing.assert_allclose(w.sum(1), 2.5, rtol=1e-5)
+
+
+def test_every_token_to_one_expert_drops_nothing():
+    """No capacity: 40 tokens that all choose held expert 2 (and three
+    absent ones) are all computed."""
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(0, 1, (40, 64)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.normal(0, 0.2, (4, 64, 32)), jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.normal(0, 0.2, (4, 32, 64)), jnp.float32)
+    idx = jnp.tile(jnp.asarray([[9, 6, 12, 1]], jnp.int32), (40, 1))
+    g = jnp.asarray(rng.uniform(0.1, 1, (40, 4)), jnp.float32)
+    y, sizes = routed_swiglu(x, idx, g, wg, wu, wd, expert_offset=4)
+    # group sizes, pairs of absent experts, pairs computed and summed
+    assert list(np.asarray(sizes)) == [0, 0, 40, 0, 120, 40]
+    want = g[:, 1:2] * ((jax.nn.silu(x @ wg[2]) * (x @ wu[2])) @ wd[2])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_a_capacity_on_the_groups_shows_as_dropped_pairs(monkeypatch):
+    """The computed pairs are counted from the sorted rows and the group
+    sizes, not from the router's choice: clamp the groups to 16 rows, as
+    a capacity would, and 24 of the 40 held pairs are missing from the
+    count (``ServingEngine.moe_stats()["dropped"]`` = held - summed)."""
+    real = jnp.bincount
+    monkeypatch.setattr(jnp, "bincount", lambda *a, **k: jnp.minimum(
+        real(*a, **k), 16))
+    x = jnp.ones((40, 64), jnp.float32)
+    w = jnp.ones((4, 64, 32), jnp.float32)
+    idx = jnp.tile(jnp.asarray([[9, 6, 12, 1]], jnp.int32), (40, 1))
+    _, sizes = routed_swiglu(x, idx, jnp.ones((40, 4)), w, w,
+                             jnp.ones((4, 32, 64)), expert_offset=4)
+    absent, summed = map(int, sizes[-2:])
+    assert (absent, summed) == (120, 16)
+    assert 40 * 4 - absent - summed == 24
+
+
+def test_expert_layer_builds_no_dispatch_tensor():
+    """Work in proportion to the routed pairs: no intermediate of the
+    lowered layer has T x E x anything elements ([T, E, C] algebra)."""
+    layer = _expert_layer(0, 16)
+    T = 64
+    x = jnp.zeros((T, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda v: layer(v)._value)(x)
+    biggest = max(int(np.prod(v.aval.shape)) for eq in jaxpr.jaxpr.eqns
+                  for v in eq.outvars if hasattr(v.aval, "shape"))
+    assert biggest <= T * 4 * 64        # [T*k, d]: the sorted pairs
+
+
+@pytest.mark.parametrize("lengths", [[0, 5, 17, 63], [31, 32, 33, 16]])
+def test_latent_kernel_is_its_dense_twin(lengths):
+    B, H, dc, dr, page, npages, P = 4, 8, 128, 64, 16, 4, 24
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    ql = jax.random.normal(ks[0], (B, H, dc), jnp.float32)
+    qr = jax.random.normal(ks[1], (B, H, dr), jnp.float32)
+    cp = jax.random.normal(ks[2], (P, 1, page, dc), jnp.float32)
+    rp = jax.random.normal(ks[3], (P, 1, page, dr), jnp.float32)
+    tbl = np.random.default_rng(1).permutation(P - 1)[:B * npages] \
+        .reshape(B, npages).astype(np.int32)
+    ln = jnp.asarray(lengths, jnp.int32)
+    assert ma.mla_paged_supported(ql.shape, cp.shape, rp.shape)
+    got = ma.mla_paged_decode_attention(ql, qr, cp, rp, tbl, ln, 0.11,
+                                        interpret=True)
+    want = ma.mla_paged_attention_dense(ql[:, None], qr[:, None], cp, rp,
+                                        tbl, ln, 0.11)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_latent_kernel_gate():
+    ok = ((128, 64, 512), (2048, 1, 128, 512), (2048, 1, 128, 64))
+    assert ma.mla_paged_supported(*ok)
+    assert not ma.mla_paged_supported((128, 64, 32), (64, 1, 16, 32),
+                                      (64, 1, 16, 8))      # tiny latent
+    assert not ma.mla_paged_supported((4, 64, 512), (64, 8, 128, 512),
+                                      (64, 8, 128, 64))    # per-head cache
+
+
+@pytest.mark.parametrize("kw, needle", [
+    ({"prefill_chunk": 16}, "valid"),
+    ({"prefill_chunk": 16, "prefix_cache": True}, "valid"),
+    ({"phase": "decode"}, "latent cache"),
+])
+def test_engine_paths_the_latent_pools_do_not_serve_are_refused(
+        model, kw, needle):
+    pred = create_predictor(Config().set_model(model).enable_paged_kv(
+        page_size=8))
+    with pytest.raises(Exception, match=needle):
+        ServingEngine(pred, max_batch=2, **kw)
+
+
+def test_llama_engine_pools_and_program_keys_unchanged():
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+
+    paddle.seed(0)
+    cfg = llama_tiny()
+    pred = create_predictor(Config().set_model(
+        LlamaForCausalLM(cfg)).enable_paged_kv(page_size=8))
+    eng = ServingEngine(pred, max_batch=2, decode_chunk=2)
+    shape = (eng.P, cfg.num_kv_heads, 8, cfg.head_dim)
+    assert [(k.shape, v.shape) for k, v in eng.pools] == \
+        [(shape, shape)] * cfg.num_layers
+    assert eng._moe_counts is None and eng.moe_stats() is None
+    eng.submit(np.arange(11) % 250, max_new_tokens=5)
+    eng.run()
+    assert list(eng._step_fns) == [(2, eng.M, 2, 0.0, 0, 1.0)]
+    assert list(pred._prefill_fns) == [(1, 64, eng.M, 8)]
+    assert eng.memory_summary()["state"]["page_bytes"] == \
+        2 * cfg.num_layers * cfg.num_kv_heads * 8 * cfg.head_dim * 4
+    assert eng.program_sites() == [("prefill", 64), ("decode",)]
+
+
+def test_serving_programs_compile_for_a_v5e_in_place():
+    """The engine's own decode and prefill programs of the decoder at
+    the benchmark configuration's widths (two layers, no weights),
+    compiled by the TPU compiler for a described v5e in a process of its
+    own: 0 pool-shaped copies, the latent kernel in decode, XLA's
+    grouped matmul in both."""
+    import json
+    import subprocess
+
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tools", "mla_serving_aot.py")],
+        capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    if "skipped" in out:
+        pytest.skip(out["skipped"])
+    progs = {c["program"]: c for c in out["programs"]}
+    assert set(progs) == {"decode", "prefill_1024"}
+    for c in progs.values():
+        assert c["pool_copies"] == 0 and c["ragged_dot"], c
+    assert progs["decode"]["kernel"] and not progs["prefill_1024"]["kernel"]

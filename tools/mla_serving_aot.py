@@ -1,0 +1,120 @@
+"""Do the serving programs of the latent-attention expert decoder compile
+for a v5e, in place and with the kernel? (no chip needed)
+
+Builds ``MLAMoEForCausalLM`` at the benchmark configuration's widths
+(``benchmarks/configs/sarvam-105b.json``) with ``--layers`` layers (2: the
+dense layer and one expert layer) and NO weights (``LazyGuard``), takes
+``ServingEngine``'s own decode and prefill programs, and compiles them
+with the TPU compiler installed beside JAX for a DESCRIBED v5e:2x2
+topology, as ``tools/paged_write_aot.py`` does for the page-pool write.
+Per program: pool-shaped ``copy`` ops in the optimized HLO (0 = the
+latent pools are written in place), whether ``mla_paged_decode_attention``
+and XLA's grouped matmul (``ragged-dot``) are in it, and the compiler's
+memory analysis. Prints one JSON line, ``{"programs": [...]}`` or
+``{"skipped": why}``.
+
+    python tools/mla_serving_aot.py [--layers 6] [--batch 128] [--dump DIR]
+"""
+import argparse
+import json
+import os
+import sys
+
+
+def main(argv):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--layers", type=int, default=2)
+    ap.add_argument("--batch", type=int, default=128)
+    ap.add_argument("--prefill", type=int, default=1024)
+    ap.add_argument("--dump", help="write each program's optimized HLO "
+                    "into this directory")
+    args = ap.parse_args(argv)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds it
+        print(json.dumps({"skipped": f"{type(e).__name__}: {e}"[:300]}))
+        return 0
+    import paddle_tpu as paddle
+    from benchmarks.harness.families.mla_moe_serving import model_config
+    from paddle_tpu.inference import (Config, ServingEngine,
+                                      create_predictor)
+    from paddle_tpu.models.mla_moe import MLAMoEForCausalLM
+    from paddle_tpu.ops import pallas
+
+    # trace the programs the chip would run: the kernels' own gates
+    # still decide, the platform question is answered for the target
+    pallas.is_tpu_platform = lambda: True
+    cfg = json.load(open(os.path.join(
+        root, "benchmarks", "configs", "sarvam-105b.json")))
+    cfg["num_hidden_layers"] = args.layers
+    srv = cfg["serving"]
+    paddle.set_default_dtype(cfg["torch_dtype"])
+    with paddle.LazyGuard():
+        model = MLAMoEForCausalLM(model_config(cfg, srv["max_length"]))
+    pred = create_predictor(Config().set_model(model).enable_paged_kv(
+        page_size=srv["page_size"]))
+    eng = ServingEngine(pred, max_batch=args.batch,
+                        pool_pages=srv["pool_pages"])
+    dev = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a, dtype=None):
+        return jax.ShapeDtypeStruct(tuple(a.shape), dtype or a.dtype,
+                                    sharding=dev)
+
+    pvals = tuple(sds(p._value) for p in pred._params)
+    B, npages = eng.B, eng.npages
+
+    def caches(rows, counts):
+        tbl = jax.ShapeDtypeStruct((rows, npages), jnp.int32, sharding=dev)
+        out = [(sds(c), sds(r), tbl) for c, r in eng.pools]
+        if counts:
+            out = [c + (sds(n),) for c, n in zip(out, eng._moe_counts)]
+        return out
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=dev)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)
+    programs = {
+        "decode": (eng._decode_step_fn(),
+                   (pvals, i32(B), caches(B, True), i32(B), rng)),
+        f"prefill_{args.prefill}": (
+            pred._prefill_fn(1, args.prefill, eng.M),
+            (pvals, i32(1, args.prefill), caches(1, False), i32(1))),
+    }
+    out = []
+    for name, (fn, avals) in programs.items():
+        compiled = fn.lower(*avals).compile()
+        text = compiled.as_text()
+        if args.dump:
+            os.makedirs(args.dump, exist_ok=True)
+            with open(os.path.join(args.dump, name + ".hlo"), "w") as f:
+                f.write(text)
+        mem = compiled.memory_analysis()
+        out.append({
+            "program": name,
+            "pool_copies": sum(ServingEngine.pool_copies(text, s.shape)
+                               for s in eng.pools[0]),
+            "kernel": "mla_paged_decode_attention" in text,
+            "ragged_dot": "ragged-dot" in text,
+            "argument_gib": mem.argument_size_in_bytes / 2 ** 30,
+            "temp_gib": mem.temp_size_in_bytes / 2 ** 30,
+            "alias_gib": mem.alias_size_in_bytes / 2 ** 30,
+        })
+    print(json.dumps({"layers": args.layers, "batch": B, "programs": out}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
