@@ -403,9 +403,16 @@ def kernel_cases(sz: Sizes):
 
         return build
 
-    cases.append((f"paged_decode_attention Sq=1 B={B} page={page}",
+    # a batch as the engine holds it: ragged rows, one that ends on a
+    # page's last slot, and free slots (length 0) between them. The
+    # kernel walks each row's own pages and one page of a free slot
+    lens = ragged_lens()
+    lens[0] = 2 * page - 1
+    lens[1::3] = 0
+    cases.append((f"paged_decode_attention Sq=1 B={B} page={page} "
+                  f"lengths={lens.tolist()}",
                   ("paged_decode_attention",),
-                  paged(B, 1, jnp.asarray(ragged_lens())), TOL_ATTN))
+                  paged(B, 1, jnp.asarray(lens)), TOL_ATTN))
     # the bucketed prefill runs the SAME paged kernel at Sq = the bucket
     for Sb in buckets:
         cases.append((f"paged_decode_attention prefill Sq={Sb}",

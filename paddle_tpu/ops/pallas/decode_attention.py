@@ -6,17 +6,30 @@ the 2,023-LoC masked cache-KV decoder loop — and
 phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu, the
 paged/block KV-cache attention kernel).
 
-Design — the cache STREAMS through VMEM in blocks as the innermost grid
-dimension; nothing is ever resident at O(cache_len):
+Design — the cache STREAMS through VMEM a block at a time; nothing is
+ever resident at O(cache_len), and no step is spent on a block past a
+row's frontier (``offset`` + new tokens, a SCALAR-PREFETCH input):
 
-- grid = (B, KV_heads, cache_blocks). Online-softmax statistics and the
-  output accumulator live in VMEM scratch, carried across the
-  sequentially-iterated cache-block axis.
-- the valid cache length (``offset`` + new tokens) is a SCALAR-PREFETCH
-  input: the BlockSpec index maps clamp the cache block index to the
-  last valid block, so blocks past the frontier are never DMA'd from
-  HBM — the TPU equivalent of the paged kernel only touching mapped
-  pages. Compute for those steps is skipped with ``pl.when``.
+- ``decode_attention`` (contiguous cache): grid = (B, KV_heads,
+  cache_blocks). Online-softmax statistics and the output accumulator
+  live in VMEM scratch, carried across the sequentially-iterated
+  cache-block axis. The BlockSpec index maps clamp the cache block index
+  to the last valid block, so blocks past the frontier are never DMA'd
+  from HBM; compute for those steps is skipped with ``pl.when`` (each
+  still costs its grid step).
+- ``paged_decode_attention`` (page pool): the work follows the pages the
+  rows own. Grid = (B * KV/hb,), one step per row and block of ``hb`` KV
+  heads; inside it a ``fori_loop`` over the row's OWN page count fetches
+  ``[hb, page, D]`` of K and of V (contiguous in the head-major pool,
+  which stays in HBM) by hand into two VMEM buffers, the next page's
+  copy, or the next grid step's first page, in flight while this one
+  computes. A free slot (length 0) costs one step and one page. ``hb``
+  follows the shape (``_paged_head_block``): every KV head when the q
+  rows are few (decode), one head for a prefill bucket. The heads of a
+  block share ONE matmul per page: scores are ``[hb*Sq*G, hb*page]`` and
+  the mask that keeps a row to positions <= its own also keeps it to
+  its own head's columns, so the MXU sees two large products, not
+  ``2*hb`` of four rows.
 - GQA is native: the q heads of one KV group form the sublane axis of a
   single [Sq*G, D] block, so the cache is read once per KV head (the
   dense fallback repeats it per q head).
@@ -158,29 +171,110 @@ def decode_attention(q, k_cache, v_cache, offset, scale=None,
 # ---------------------------------------------------------------------------
 # Paged (block-table) KV cache attention
 # ---------------------------------------------------------------------------
-def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
-                  acc_s, *, scale, page, npages, Sq, G):
-    j = pl.program_id(2)
-    off = len_ref[pl.program_id(0)]
-    j_last = (off + Sq - 1) // page
+# What a block of several KV heads may hold in VMEM, by the count of
+# ``_paged_vmem_bytes``. A quarter of Mosaic's 16 MiB scoped default:
+# the count of the compiler's temporaries is an estimate.
+_PAGED_VMEM_BUDGET = 4 * 1024 * 1024
 
-    @pl.when(j == 0)
-    def _():
-        m_s[...] = jnp.full_like(m_s, _NEG)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
 
-    @pl.when(j <= j_last)
+def _paged_vmem_bytes(hb, Sq, G, page, D, itemsize) -> int:
+    """VMEM the paged kernel needs for a block of ``hb`` KV heads: the
+    two page buffers of K and of V, q in and o out (two pipeline buffers
+    each), the softmax statistics and the accumulator, and the f32
+    temporaries of one page's ``[hb*Sq*G, hb*page]`` scores (the score,
+    its mask bound, the probabilities in f32 and in the pool's dtype,
+    and what the compiler keeps beside them: counted as six)."""
+    rows, cols = hb * Sq * G, hb * page
+    pages = 2 * 2 * cols * D * itemsize
+    qo = 2 * 2 * rows * D * itemsize
+    stats = rows * (2 * 128 + D) * 4
+    scores = 6 * rows * max(cols, 128) * 4
+    return pages + qo + stats + scores
+
+
+def _paged_head_block(Sq, G, KV, page, D, itemsize) -> int:
+    """KV heads one fetch brings: the largest divisor of ``KV`` whose
+    block fits ``_PAGED_VMEM_BUDGET``; one head where none does (the
+    gate's ``Sq*G <= 2048`` bounds that block). The scores of a block
+    are ``[hb*Sq*G, hb*page]`` (heads share one matmul and a mask keeps
+    each to its own page), so few q rows take every head and a prefill
+    bucket takes one."""
+    for hb in range(KV, 1, -1):
+        if KV % hb == 0 and _paged_vmem_bytes(
+                hb, Sq, G, page, D, itemsize) <= _PAGED_VMEM_BUDGET:
+            return hb
+    return 1
+
+
+def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                  v_buf, sem, slot_ref, m_s, l_s, acc_s, *, scale, page,
+                  npages, Sq, G, hb, nh):
+    t = pl.program_id(0)
+    b, blk = t // nh, t % nh
+    rows, cols = Sq * hb * G, hb * page
+
+    def fetch(row, head_blk, j, slot):
+        """The copies of logical page ``j`` of ``row``: K and V of
+        ``hb`` heads, ``[hb, page, D]`` contiguous in each pool."""
+        pid = tbl_ref[row * npages + j]
+        heads = pl.ds(head_blk * hb, hb)
+        return (pltpu.make_async_copy(k_hbm.at[pid, heads], k_buf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pid, heads], v_buf.at[slot],
+                                      sem.at[1, slot]))
+
+    @pl.when(t == 0)
     def _():
-        qb = q_ref[0, :, 0, :, :].reshape(Sq * G, -1)      # [Sq*G, D]
-        kb = k_ref[0, 0]                                   # [page, D]
-        vb = v_ref[0, 0]
+        slot_ref[0] = 0
+        for copy in fetch(0, 0, 0, 0):
+            copy.start()
+
+    off = len_ref[b]
+    # pages up to the one the last q row's own position falls in: at
+    # least one (a free slot, position 0), never past the table
+    n = jnp.minimum((off + Sq - 1) // page, npages - 1) + 1
+    slot0 = slot_ref[0]
+    m_s[...] = jnp.full_like(m_s, _NEG)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    qb = q_ref[0].reshape(rows, -1)                  # rows (s, head, g)
+
+    def mask_bound():
+        """Column (head', p) of page j is position j*page + p; row (s,
+        head, g) sees it if head' == head and the position is <= off +
+        s: if ``j * page <= bound``."""
+        ri = lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+        ci = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+        bound = off + ri // (hb * G) - ci % page
+        if hb > 1:
+            bound = jnp.where((ri // G) % hb == ci // page, bound, -1)
+        return bound
+
+    # the same for every page. A block of several heads was sized with
+    # room for it; a single head may fill VMEM (the gate's edge), and
+    # builds it page by page as its grid steps did
+    bound = mask_bound() if hb > 1 else None
+
+    def visit(j, _):
+        slot = (slot0 + j) % 2
+        more = j + 1 < n
+
+        # the next page of this row, or the first page of the next grid
+        # step (every step has one), is in flight while this one computes
+        @pl.when(more | (t + 1 < pl.num_programs(0)))
+        def _():
+            nxt = [jnp.where(more, here, there) for here, there in
+                   ((b, (t + 1) // nh), (blk, (t + 1) % nh), (j + 1, 0))]
+            for copy in fetch(*nxt, 1 - slot):
+                copy.start()
+
+        for copy in fetch(b, blk, j, slot):
+            copy.wait()
+        kb = k_buf[slot].reshape(cols, -1)               # [hb*page, D]
+        vb = v_buf[slot].reshape(cols, -1)
         s = lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        rows = lax.broadcasted_iota(jnp.int32, (Sq * G, page), 0) // G
-        cols = j * page + lax.broadcasted_iota(
-            jnp.int32, (Sq * G, page), 1)
-        keep = cols <= off + rows
+        keep = j * page <= (mask_bound() if bound is None else bound)
         s = jnp.where(keep, s, _NEG)
         m_prev = m_s[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -192,11 +286,10 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
             preferred_element_type=jnp.float32)
         m_s[:, :1] = m_new
 
-    @pl.when(j == npages - 1)
-    def _():
-        l = jnp.maximum(l_s[:, :1], 1e-30)
-        o_ref[0, :, 0, :, :] = (acc_s[...] / l).reshape(
-            Sq, G, -1).astype(o_ref.dtype)
+    lax.fori_loop(0, n, visit, None)
+    slot_ref[0] = (slot0 + n) % 2
+    l = jnp.maximum(l_s[:, :1], 1e-30)
+    o_ref[0] = (acc_s[...] / l).reshape(o_ref.shape[1:]).astype(o_ref.dtype)
 
 
 def paged_supported(q_shape, pool_shape) -> bool:
@@ -209,15 +302,17 @@ def paged_supported(q_shape, pool_shape) -> bool:
     return Sq * (H // KV) <= 2048
 
 
+@partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            scale=None, interpret=False):
     """Block-table KV attention (the TPU redesign of the reference's
     paged cache kernel: phi/kernels/fusion/gpu/
     block_multi_head_attention_kernel.cu + block_attn.h — there, CUDA
-    threads chase the block table; here the BLOCKSPEC INDEX MAP does:
-    the physical page id is gathered from a scalar-prefetched table, so
-    the DMA engine fetches exactly the pages a row owns and never
-    touches pages past its frontier).
+    threads chase the block table; here a loop inside the kernel does:
+    the physical page id is read from a scalar-prefetched table and the
+    page is copied from the pool, which stays in HBM, so the kernel
+    fetches exactly the pages a row owns and spends no step on a page
+    past its frontier).
 
     q            [B, Sq, H, D]  rows at absolute positions
                                 lengths[b]..lengths[b]+Sq-1
@@ -225,6 +320,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                                 pages (each [page, D] plane contiguous)
     block_tables [B, npages]    logical->physical page map per row
     lengths      [B]            tokens already in cache per row (ragged)
+
+    Table entries up to a row's frontier page must name pages of the
+    pool; later entries are never read.
+
+    Jitted on its own: a serving program calls it once a layer with the
+    same shapes, and then traces and lowers it once, not once a layer
+    (a second of set-up a program at 16 layers).
     """
     B, Sq, H, D = q.shape
     P, KV, page = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
@@ -232,39 +334,44 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     G = H // KV
     if scale is None:
         scale = 1.0 / np.sqrt(D)
+    hb = _paged_head_block(Sq, G, KV, page, D, k_pool.dtype.itemsize)
+    nh = KV // hb
+    rows = Sq * hb * G
     q5 = q.reshape(B, Sq, KV, G, D)
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
     tbl = jnp.asarray(block_tables, jnp.int32).reshape(B * npages)
 
-    def pool_index(b, h, j, ln, tb):
-        jc = jnp.minimum(j, (ln[b] + Sq - 1) // page)
-        return (tb[b * npages + jc], h, 0, 0)
+    def q_index(t, ln, tb):
+        return (t // nh, 0, t % nh, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, npages),
+        grid=(B * nh,),
         in_specs=[
-            pl.BlockSpec((1, Sq, 1, G, D), lambda b, h, j, ln, tb:
-                         (b, 0, h, 0, 0)),
-            pl.BlockSpec((1, 1, page, D), pool_index),
-            pl.BlockSpec((1, 1, page, D), pool_index),
+            pl.BlockSpec((1, Sq, hb, G, D), q_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, Sq, 1, G, D),
-                               lambda b, h, j, ln, tb: (b, 0, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Sq, hb, G, D), q_index),
         scratch_shapes=[
-            pltpu.VMEM((Sq * G, 128), jnp.float32),
-            pltpu.VMEM((Sq * G, 128), jnp.float32),
-            pltpu.VMEM((Sq * G, D), jnp.float32),
+            pltpu.VMEM((2, hb, page, D), k_pool.dtype),
+            pltpu.VMEM((2, hb, page, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         partial(_paged_kernel, scale=scale, page=page, npages=npages,
-                Sq=Sq, G=G),
+                Sq=Sq, G=G, hb=hb, nh=nh),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, KV, G, D), q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
-        **_compiler_params(2, interpret),
+        # one sequential axis: a step starts the next step's first page
+        **_compiler_params(0, interpret),
     )(lengths, tbl, q5, k_pool, v_pool)
     return out.reshape(B, Sq, H, D)
 

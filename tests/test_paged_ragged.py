@@ -7,9 +7,9 @@ Reference parity targets:
   (python surface / semantics: per-seq block tables, ragged lengths)
 
 TPU redesign under test: the physical page id comes from a
-scalar-prefetched block table inside the Pallas BlockSpec index map
-(ops/pallas/decode_attention.py), and the Predictor allocates pages per
-row with a trash page absorbing right-pad writes.
+scalar-prefetched block table, read by the kernel's own loop over the
+pages a row owns (ops/pallas/decode_attention.py), and the Predictor
+allocates pages per row with a trash page absorbing right-pad writes.
 """
 import numpy as np
 import pytest
@@ -17,6 +17,7 @@ import pytest
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
+from paddle_tpu.ops.pallas import decode_attention as da
 from paddle_tpu.ops.pallas.decode_attention import (
     _dense_ragged, decode_attention, paged_attention_dense,
     paged_decode_attention)
@@ -24,6 +25,78 @@ from paddle_tpu.ops.pallas.decode_attention import (
 
 def _rand(r, *shape):
     return jnp.asarray(r.randn(*shape), jnp.float32)
+
+
+# (Sq, G, KV, page, dtype) for the kernel's page walk: decode, a small
+# and the largest prefill bucket; MHA and two GQA groupings; 1, 2 and 8
+# KV heads, so that the head block is one head, a proper divisor of KV
+# and all of KV (TestHeadBlockRule pins which); every page the tools
+# compile; both pool dtypes
+WALK_CASES = [
+    (1, 1, 1, 8, "float32"), (1, 1, 2, 16, "bfloat16"),
+    (1, 1, 8, 64, "float32"), (1, 1, 8, 128, "bfloat16"),
+    (1, 4, 1, 16, "float32"), (1, 4, 2, 64, "bfloat16"),
+    (1, 4, 8, 128, "bfloat16"), (1, 4, 8, 8, "float32"),
+    (1, 4, 8, 16, "float32"), (1, 4, 8, 64, "bfloat16"),
+    (1, 8, 1, 64, "bfloat16"), (1, 8, 2, 128, "float32"),
+    (1, 8, 8, 8, "float32"), (1, 8, 8, 128, "bfloat16"),
+    (64, 1, 2, 8, "float32"), (64, 1, 8, 64, "bfloat16"),
+    (64, 4, 1, 64, "float32"), (64, 4, 8, 128, "bfloat16"),
+    (64, 4, 8, 16, "float32"), (64, 8, 8, 128, "float32"),
+    (512, 1, 8, 64, "bfloat16"), (512, 1, 2, 128, "float32"),
+    (512, 4, 1, 8, "float32"), (512, 4, 8, 128, "bfloat16"),
+]
+
+
+def _walk_batch(r, Sq, G, KV, page, dtype, poison=False):
+    """One batch that holds every edge of the page walk: lengths 0, 1,
+    page-1, page, page+1 and the whole table, free slots (length 0)
+    between the live rows, physical pages in shuffled order. With
+    ``poison`` the pages no row owns hold NaN and the table entries past
+    each row's frontier name those pages or no page at all; the second
+    result is the clean pool and table the dense twin may read."""
+    D = 128
+    npages = -(-(page + 1 + Sq) // page) + 1
+    full = npages * page - Sq
+    lens = np.array([0, 1, 0, page - 1, page, 0, page + 1, full], np.int32)
+    B = len(lens)
+    P = B * npages + 3
+    order = r.permutation(P)
+    tbl = order[:B * npages].reshape(B, npages).astype(np.int32)
+    kp = r.randn(P, KV, page, D).astype("float32")
+    vp = r.randn(P, KV, page, D).astype("float32")
+    q = jnp.asarray(r.randn(B, Sq, KV * G, D), dtype)
+    clean = (jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+             jnp.asarray(tbl))
+    if not poison:
+        return q, clean, clean, jnp.asarray(lens)
+    owned = (lens + Sq - 1) // page + 1           # pages a row walks
+    past = np.arange(npages)[None] >= owned[:, None]
+    spare = order[B * npages:]
+    bad = np.where(r.rand(B, npages) < 0.5, spare[0],
+                   np.where(r.rand(B, npages) < 0.5, P + 1000, -7))
+    dirty_tbl = np.where(past, bad, tbl).astype(np.int32)
+    unowned = np.ones(P, bool)
+    unowned[tbl[~past]] = False
+    kp_d, vp_d = kp.copy(), vp.copy()
+    kp_d[unowned] = np.nan
+    vp_d[unowned] = np.nan
+    # the dense twin gathers every table entry and multiplies a masked
+    # probability of 0 into it: it reads zeros where the kernel must
+    # read nothing
+    kp[unowned] = 0
+    vp[unowned] = 0
+    clean = (jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+             jnp.asarray(np.where(past, spare[0], tbl).astype(np.int32)))
+    dirty = (jnp.asarray(kp_d, dtype), jnp.asarray(vp_d, dtype),
+             jnp.asarray(dirty_tbl))
+    return q, dirty, clean, jnp.asarray(lens)
+
+
+def _tol(dtype):
+    # bf16 operands: the kernel rounds p to the pool's dtype before PV,
+    # the dense twin keeps it in float32
+    return 1e-4 if dtype == "float32" else 3e-2
 
 
 class TestPagedKernel:
@@ -54,6 +127,35 @@ class TestPagedKernel:
         ref = paged_attention_dense(q, kp, vp, tbl, lens)
         assert float(jnp.abs(out - ref).max()) < 1e-4
 
+    @pytest.mark.parametrize("Sq,G,KV,page,dtype", WALK_CASES)
+    def test_page_walk_edges_in_one_batch(self, Sq, G, KV, page, dtype):
+        r = np.random.RandomState(Sq + 7 * G + 31 * KV + page)
+        q, (kp, vp, tbl), _, lens = _walk_batch(r, Sq, G, KV, page, dtype)
+        assert da.paged_supported(q.shape, kp.shape)
+        out = paged_decode_attention(q, kp, vp, tbl, lens, interpret=True)
+        ref = paged_attention_dense(q, kp, vp, tbl, lens)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        err = jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))
+        assert float(err.max()) < _tol(dtype)
+
+    @pytest.mark.parametrize("Sq,G,KV,page,dtype", [
+        (1, 4, 8, 128, "bfloat16"), (1, 1, 8, 16, "float32"),
+        (1, 8, 2, 64, "bfloat16"), (64, 4, 8, 128, "bfloat16"),
+        (64, 1, 8, 16, "float32"), (512, 4, 2, 128, "float32")])
+    def test_walk_stops_at_the_frontier(self, Sq, G, KV, page, dtype):
+        """Pages no row owns hold NaN, and the table past each row's
+        frontier names them, or ids outside the pool: a walk that went
+        one page too far would read them."""
+        r = np.random.RandomState(Sq + 7 * G + 31 * KV + page + 1)
+        q, (kp, vp, tbl), (kc, vc, tc), lens = _walk_batch(
+            r, Sq, G, KV, page, dtype, poison=True)
+        assert bool(jnp.isnan(kp.astype(jnp.float32)).any())
+        out = paged_decode_attention(q, kp, vp, tbl, lens, interpret=True)
+        assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+        ref = paged_attention_dense(q, kc, vc, tc, lens)
+        err = jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))
+        assert float(err.max()) < _tol(dtype)
+
     def test_paged_vs_contiguous_cache(self):
         """Pages laid out to mirror a contiguous cache must reproduce
         the contiguous kernel's output exactly."""
@@ -73,6 +175,54 @@ class TestPagedKernel:
                                        interpret=True)
         dense = decode_attention(q, kc, vc, lens, interpret=True)
         assert float(jnp.abs(paged - dense).max()) < 1e-4
+
+
+class TestHeadBlockRule:
+    """``_paged_head_block``: a pure function of the call's shapes."""
+
+    def test_decode_takes_every_head_and_a_prefill_bucket_one(self):
+        # the serving cells: 32 q heads over 8 KV heads of 128, page 128
+        assert da._paged_head_block(1, 4, 8, 128, 128, 2) == 8
+        for Sb in (64, 128, 256, 512):
+            assert da._paged_head_block(Sb, 4, 8, 128, 128, 2) == 1
+        # MHA at Llama-7B widths (chip_smoke.py): a proper divisor
+        assert da._paged_head_block(1, 1, 32, 128, 128, 2) == 16
+
+    def test_walk_cases_cover_one_head_a_divisor_and_all(self):
+        kinds = set()
+        for Sq, G, KV, page, dtype in WALK_CASES:
+            hb = da._paged_head_block(Sq, G, KV, page, 128,
+                                      jnp.dtype(dtype).itemsize)
+            assert KV % hb == 0
+            kinds.add("one" if hb == 1 and KV > 1 else
+                      "all" if hb == KV else "divisor")
+        assert kinds == {"one", "divisor", "all"}
+
+    @pytest.mark.parametrize("itemsize", [2, 4])
+    @pytest.mark.parametrize("page", [8, 16, 64, 128])
+    def test_nothing_exceeds_the_budget(self, page, itemsize):
+        """A block of several heads fits the budget; a single head (the
+        floor) fits Mosaic's scoped limit at every shape the gate
+        admits."""
+        for KV in (1, 2, 4, 8, 32):
+            for G in (1, 4, 8):
+                for Sq in (1, 2, 5, 16, 64, 128, 256, 512, 2048):
+                    if not da.paged_supported((1, Sq, KV * G, 128),
+                                              (4, KV, page, 128)):
+                        continue
+                    hb = da._paged_head_block(Sq, G, KV, page, 128,
+                                              itemsize)
+                    need = da._paged_vmem_bytes(hb, Sq, G, page, 128,
+                                                itemsize)
+                    assert need <= (da._PAGED_VMEM_BUDGET if hb > 1
+                                    else 16 * 2 ** 20), (Sq, G, KV, hb)
+                    # the largest: the next divisor up does not fit
+                    for up in range(hb + 1, KV + 1):
+                        if KV % up == 0:
+                            assert da._paged_vmem_bytes(
+                                up, Sq, G, page, 128, itemsize) \
+                                > da._PAGED_VMEM_BUDGET
+                            break
 
 
 class TestRaggedGenerate:

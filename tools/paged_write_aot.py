@@ -7,7 +7,9 @@ program — and counts the ops of the optimized HLO named ``copy`` whose
 result has the pool's shape. Such a copy is a layout change of the whole
 pool: its bytes scale with the pool, not with the rows written
 (PERF.md, PR 26). Prints one JSON line, ``{"cases": [...]}`` or
-``{"skipped": why}`` where the topology cannot be described.
+``{"skipped": why}`` where the topology cannot be described. Beside a
+case that runs ``paged_decode_attention``: the KV heads one of its
+fetches brings and the VMEM bytes the kernel counts for that block.
 
 Run it in a process of its own (``tests/test_paged_kv_write.py`` does):
 only one process at a time may load the TPU's library, and it keeps it.
@@ -115,6 +117,10 @@ def main(argv):
             "bytes_accessed": float(cost.get("bytes accessed", -1)),
             "kernel": kernel is not None and kernel in text,
         })
+        if attn == "kernel":    # by the kernel's own count, which picks hb
+            hb = da._paged_head_block(S, H // KV, KV, page, D, dt.itemsize)
+            out[-1].update(head_block=hb, vmem_bytes=da._paged_vmem_bytes(
+                hb, S, H // KV, page, D, dt.itemsize))
     print(json.dumps({"cases": out, "old": "--old" in argv}))
     return 0
 
