@@ -958,7 +958,7 @@ def bench_serving_disagg(on_tpu, dev):
                       * mcfg.head_dim * np.dtype(peng._dtype).itemsize)
         lens = [len(p) for _, p in trace] \
             + [len_hi] * len(rt_d.frontdoor)
-        closed = sum((-(-L // page)) * page_bytes + peng.npages * 4
+        closed = sum((-(-L // page)) * page_bytes + peng.cache.npages * 4
                      for L in lens)
         bytes_exact = rt_d.migrator.wire_bytes == closed
         parity = out_d == out_u
@@ -1006,7 +1006,7 @@ def bench_serving_disagg(on_tpu, dev):
             "wire_bytes": int(rt_d.migrator.wire_bytes),
             "closed_form": int(closed),
             "page_bytes": int(page_bytes),
-            "block_table_row_bytes": int(peng.npages * 4)})
+            "block_table_row_bytes": int(peng.cache.npages * 4)})
     finally:
         paddle.set_default_dtype(old_dtype)
 

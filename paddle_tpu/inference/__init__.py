@@ -67,27 +67,6 @@ from ..core.bucketing import bucket as _bucket  # noqa: E402
 from ..core.compile_stats import CompileStats  # noqa: E402,F401
 
 
-def _kv_pool_shapes(model, P: int, page: int):
-    """Per layer, the shapes ``(first, second)`` of the two arrays the
-    page pool holds, ``[P, cache heads, page, width]`` each. A model
-    says so itself (``kv_pool_shapes(P, page)``: a latent-attention
-    model pools a latent and a rotated key); without that it is K and V
-    of ``num_kv_heads x head_dim``."""
-    fn = getattr(model, "kv_pool_shapes", None)
-    if fn is not None:
-        return [(tuple(a), tuple(b)) for a, b in fn(P, page)]
-    cfg = model.config
-    shape = (P, cfg.num_kv_heads, page, cfg.head_dim)
-    return [(shape, shape)] * cfg.num_layers
-
-
-def _kv_page_bytes(model, page: int, dtype) -> int:
-    """Bytes one page takes over every layer's two pooled arrays."""
-    return sum(int(np.prod(a)) + int(np.prod(b))
-               for a, b in _kv_pool_shapes(model, 1, page)) \
-        * np.dtype(dtype).itemsize
-
-
 def _sample(logits, key, gen: "GenerationConfig"):
     """Greedy / temperature / top-k / top-p sampling (traceable; used by
     both the first-token host step and the compiled decode loop)."""
@@ -359,12 +338,13 @@ class Predictor:
         return self._decode_fns[key]
 
     # -- paged KV-cache pool (reference: block_multi_head_attention's
-    #    block tables; here a host-side bump allocator + trash page) ---
+    #    block tables; here a PagedKVCache sized for the call) ---
     def _paged_caches(self, lengths, n_new, M, page, dtype):
-        """Allocate per-row physical pages for len+n_new tokens. Logical
-        pages a row does not own map to one shared TRASH page, so
-        prefill's right-pad writes land harmlessly (they are never
-        attended: the mask stops at each row's frontier).
+        """Allocate per-row physical pages for len+n_new tokens from a
+        ``PagedKVCache`` sized for this call. Logical pages a row does
+        not own map to one shared TRASH page, so prefill's right-pad
+        writes land harmlessly (they are never attended: the mask stops
+        at each row's frontier).
 
         The physical pool size P is BUCKETED to a power of two exactly
         like S: jax.jit keys compiled programs on the pool shape, so an
@@ -373,21 +353,17 @@ class Predictor:
         bucket lattice, every mix whose page demand lands in the same
         bucket reuses the same compiled programs (the extra pages are
         never referenced by any table entry below the trash id)."""
-        B = len(lengths)
-        npages = -(-M // page)
+        from .kv_cache import PagedKVCache
+
         need = [-(-(int(l) + n_new) // page) for l in lengths]
-        P = _bucket(sum(need) + 1, lo=8)      # +1 trash page (id P-1)
-        trash = P - 1
-        table = np.full((B, npages), trash, np.int32)
-        nxt = 0
+        cache = PagedKVCache(self._model, page, M, len(lengths), dtype,
+                             pool_pages=sum(need) + 1)  # +1 trash page
+        # the call owns its whole pool: page ids in order, from 0
+        ids = sorted(cache.allocate(cache.usable))
         for b, nb in enumerate(need):
-            table[b, :nb] = np.arange(nxt, nxt + nb)
-            nxt += nb
-        # one table copy per layer: the cache pytree is DONATED to the
-        # compiled step, and XLA rejects donating one buffer twice
-        return [(jnp.zeros(a, dtype), jnp.zeros(b, dtype),
-                 jnp.asarray(table))
-                for a, b in _kv_pool_shapes(self._model, P, page)], P
+            cache.set_row(b, ids[:nb])
+            del ids[:nb]
+        return cache.bind(cache.rows()), cache.P
 
     def generate(self, input_ids, max_new_tokens: Optional[int] = None,
                  lengths=None, **overrides):

@@ -140,7 +140,7 @@ class KVMigrator:
                  if e.can_import(prompt_len, max_new_tokens)]
         if not cands:
             return None
-        return max(cands, key=lambda e: e._avail_pages())
+        return max(cands, key=lambda e: e.cache.available())
 
     def _transmit(self, wire: Dict[str, Any]) -> Dict[str, Any]:
         """The wire seam: in-process fleets hand the frame over

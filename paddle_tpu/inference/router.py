@@ -76,7 +76,7 @@ class Replica:
         """Ordering key: fewest queued+active rows first, then most
         free pages (negated)."""
         eng = self.engine
-        return (len(eng.queue) + eng.num_active, -eng._avail_pages())
+        return (len(eng.queue) + eng.num_active, -eng.cache.available())
 
 
 class _Pending:
@@ -166,7 +166,7 @@ class Router:
                 eng = r.engine
                 if not eng.prefix:
                     continue
-                run = eng.prefix_match(eng._prefix_hashes(prompt))
+                run = eng.prefix_match(eng.cache.prefix_hashes(prompt))
                 if run > best_run:
                     best, best_run = r, run
             if best is not None:
@@ -331,7 +331,7 @@ class Router:
                 "phase": r.phase, "health": h,
                 "active": r.engine.num_active,
                 "queued": len(r.engine.queue),
-                "free_pages": r.engine._avail_pages(),
+                "free_pages": r.engine.cache.available(),
             }
         return {"status": "degraded" if n_bad else "ok",
                 "replicas": reps, "pending": len(self._placed)}

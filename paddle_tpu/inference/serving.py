@@ -8,11 +8,12 @@ reference serves with block_multi_head_attention + its serving runtime
 design per the Ragged Paged Attention paper in PAPERS.md — ONE compiled
 program for arbitrary length mixes):
 
-- ``ServingEngine`` owns ONE fixed-size physical page pool (its shape
-  never changes for the engine's lifetime) plus a host-side page free
-  list. Requests are admitted into B slots of an in-flight batch; a
-  request's pages are popped from the free list at admission and pushed
-  back at completion — eviction + backfill, not drain-and-refill.
+- ``ServingEngine`` schedules over ONE fixed-size physical page pool
+  (``PagedKVCache``, inference/kv_cache.py: its shape never changes for
+  the engine's lifetime) with a host-side page free list. Requests are
+  admitted into B slots of an in-flight batch; a request's pages are
+  popped from the free list at admission and pushed back at
+  completion — eviction + backfill, not drain-and-refill.
 - PREFILL runs per arrival at [1, Sb] with Sb on the same power-of-two
   bucket lattice as the Predictor, writing straight into the arrival's
   pages through its block-table row (right-pad writes land in the
@@ -95,9 +96,8 @@ accept loop) sit outside the compiled scan.
 from __future__ import annotations
 
 import re
-import threading
 import time
-from collections import Counter, OrderedDict, deque
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -116,8 +116,12 @@ from ..observability.spans import (RequestTrace, SpanRing,
                                    parse_traceparent as
                                    _parse_traceparent)
 from ..tensor import Tensor
+from .kv_cache import PagedKVCache
 
 __all__ = ["ServingEngine", "ServingRequest"]
+
+# how long after a shed health() keeps reporting "degraded"
+DEGRADED_WINDOW_S = 30.0
 
 
 @dataclass
@@ -204,6 +208,18 @@ class ServingEngine:
 
     ``submit`` only queues; ``step()`` runs one admission + decode round
     (the unit a serving loop would tick), ``run()`` drains everything.
+
+    The engine is the scheduler: the queue, the slots and the rounds.
+    What a page is — geometry, device arrays, tables, allocator, prefix
+    map, spill tier, page programs — belongs to ``self.cache``
+    (``inference/kv_cache.py``), and the engine's own code is
+    single-threaded: the one lock is the cache's. Public surface that
+    callers outside the package hold the engine to (``benchmarks/``
+    reads it): ``P``, ``slots`` (``pos``, ``req.new_tokens``, ``state``),
+    ``queue``, ``finished``, ``num_active``, ``stats``, ``submit``,
+    ``step``, ``run``, ``request_traces()``, ``program_sites()``,
+    ``lowered_text(site)``, ``moe_stats()``, ``release_pools()``, and
+    ``pools``, which may be ASSIGNED ``None`` to free the device arrays.
     """
 
     def __init__(self, predictor, max_batch: Optional[int] = None,
@@ -211,7 +227,6 @@ class ServingEngine:
                  trace_ring: int = 256, mem_ledger: bool = False,
                  max_queue: Optional[int] = None,
                  admission_deadline_s: Optional[float] = None,
-                 degraded_window_s: float = 30.0,
                  prefill_chunk: Optional[int] = None,
                  prefill_token_budget: Optional[int] = None,
                  prefix_cache: bool = False,
@@ -221,8 +236,9 @@ class ServingEngine:
                  debug_invariants: bool = False):
         import inspect
         import os
+        import weakref
 
-        from . import _bucket, _kv_page_bytes, _kv_pool_shapes
+        from . import _bucket
 
         cfg = predictor.config
         enforce(cfg._kv_page_size,
@@ -233,7 +249,6 @@ class ServingEngine:
         self.page = int(cfg._kv_page_size)
         mcfg = predictor._model.config
         self.M = int(cfg.max_length or mcfg.max_position_embeddings)
-        self.npages = -(-self.M // self.page)
         self.B = int(max_batch or cfg.max_batch_size)
         enforce(self.B >= 1 and decode_chunk >= 1,
                 "max_batch and decode_chunk must be >= 1")
@@ -285,98 +300,22 @@ class ServingEngine:
         # elder is waiting for — livelock)
         self._page_stalled = False
         self._dtype = predictor._params[0]._value.dtype
-        # one pool for the engine's whole lifetime, on the same bucket
-        # lattice as Predictor._paged_caches: the compiled programs are
-        # keyed on this shape and NEVER change it. pool_pages="auto"
-        # sizes it from measured HBM headroom (memledger.
-        # suggest_pool_pages: bytes_limit minus the resident params,
-        # 10% margin) capped at the geometric maximum the batch can
-        # ever reference; backends without memory stats (the CPU
-        # harness) fall back to the geometric default.
-        geom = self.B * self.npages + 1
-        if pool_pages == "auto":
-            page_bytes = _kv_page_bytes(predictor._model, self.page,
-                                        self._dtype)
-            resident = sum(_ml.shard_bytes(p._value)
-                           for p in predictor._params)
-            fit = _ml.suggest_pool_pages(jax.devices()[0], page_bytes,
-                                         resident)
-            want = min(fit, geom) if fit else geom
-        else:
-            want = pool_pages or geom
-        self.P = _bucket(int(want), lo=8)
-        self.trash = self.P - 1
-        self._free_pages = list(range(self.P - 1))
-        # prefix cache: pages become ref-counted and content-
-        # addressable. _hash_page maps the rolling prompt-prefix hash
-        # of a COMPLETED page-aligned chunk to the physical page that
-        # holds its KV; _page_hash is the inverse; _lru keeps
-        # registered pages whose refcount dropped to 0 (still
-        # hit-able, reclaimed oldest-first under pool pressure). All
-        # allocator state moves under ONE re-entrant lock so the
-        # accounting stays coherent if a serving loop ever drives the
-        # engine from a thread next to the metrics exporter.
+        # prefix cache: admission maps the longest cached prefix into
+        # the new row's table, the chunk planner skips it
         self.prefix = bool(prefix_cache)
         if self.prefix:
             enforce(self.chunked,
                     "prefix_cache needs chunked prefill "
                     "(prefill_chunk): cache hits are whole "
                     "page-aligned chunks the chunk planner skips")
-        self._lock = threading.RLock()
-        self._refcount = [0] * self.P
-        self._hash_page: Dict[int, int] = {}
-        self._page_hash: Dict[int, int] = {}
-        self._lru: "OrderedDict[int, None]" = OrderedDict()
-        self._pfx = {"lookups": 0, "hits": 0, "cow": 0, "reclaimed": 0,
-                     "registered": 0, "skipped_tokens": 0,
-                     "fed_tokens": 0}
-        # host memory tier for the KV cache (distributed/host_offload.py
-        # is the training-side twin): up to host_spill_pages reclaimed
-        # prefix-cache pages keep their payload in host memory, keyed
-        # by the SAME rolling prefix hash, and fault back through the
-        # normal admission path (one page allocation + one page write,
-        # then registered + idle so the hit run pins it like any cached
-        # page). A hash's KV lives device-side OR host-side, never
-        # both. Reclaim only STAGES (page, hash) under the lock; the
-        # device read that captures the payload runs in _alloc_pages
-        # AFTER the lock is released and BEFORE the allocated pages are
-        # handed out — the page cannot be rewritten in between, and no
-        # jitted dispatch ever runs under self._lock.
-        self.spill_pages = int(host_spill_pages or 0)
-        enforce(self.spill_pages == 0 or self.prefix,
+        enforce(not host_spill_pages or self.prefix,
                 "host_spill_pages rides the prefix cache (pages are "
                 "keyed by prefix hash); set prefix_cache=True")
-        self._spilled: "OrderedDict[int, Any]" = OrderedDict()
-        self._spill_pending: List[Tuple[int, int]] = []
-        self._spill_ledger: Dict[Tuple[str, str], int] = {}
-        self._spill_counts = {"spilled": 0, "faulted": 0, "dropped": 0}
         # debug-mode pool-accounting invariant (free + idle + live
         # partition the pool; refcounts == slot membership) checked
         # after every admit/finish/preempt — the free-list hardening
         # gate for the refcount migration
-        self.debug = bool(debug_invariants) or bool(int(os.environ.get(
-            "PADDLE_TPU_SERVING_DEBUG", "0") or 0))
-        # pool geometry is the model's (inference._kv_pool_shapes): K and
-        # V of num_kv_heads x head_dim, or what the model says it pools
-        shapes = _kv_pool_shapes(predictor._model, self.P, self.page)
-        enforce(phase is None or all(a == b for a, b in shapes),
-                "the disaggregated phases migrate a page as ONE stacked "
-                "array of every layer's two pooled arrays; this model "
-                "pools two arrays of different shapes "
-                f"({shapes[0][0][1:]} and {shapes[0][1][1:]}: a latent "
-                "cache), so run it on unified replicas (phase=None)")
-        self.pools = [(jnp.zeros(a, self._dtype), jnp.zeros(b, self._dtype))
-                      for a, b in shapes]
-        # routing counters of an expert model, on the device beside the
-        # pools: one small int32 array per layer, donated to the decode
-        # program with the caches and fetched only by moe_stats()
-        cshape = getattr(predictor._model, "moe_counter_shape", None)
-        self._moe_counts = None
-        if cshape is not None:
-            layers, *row = cshape()
-            self._moe_counts = [jnp.zeros(row, jnp.int32)
-                                for _ in range(layers)]
-        self.tables = np.full((self.B, self.npages), self.trash, np.int32)
+        self.debug = bool(debug_invariants)
         self.slots: List[Optional[_Slot]] = [None] * self.B
         self.queue: deque = deque()
         self.finished: Dict[int, ServingRequest] = {}
@@ -416,7 +355,7 @@ class ServingEngine:
         # speculative decoding: a draft model proposes spec_tokens
         # greedy tokens per decode row; ONE verify dispatch on the
         # SAME unified [B, Sc] lattice scores all k+1 positions per
-        # row. The draft's KV pools share the engine's page tables and
+        # row. The draft's KV pools share the cache's page tables and
         # allocator (same page ids, draft geometry), so prefix hits
         # and copy-on-write cover the draft for free.
         self.spec = int(spec_tokens or 0)
@@ -446,18 +385,33 @@ class ServingEngine:
                     "draft max_position_embeddings must cover the "
                     "engine's max_length")
             self._draft_dtype = draft_predictor._params[0]._value.dtype
-            dshape = (self.P, dcfg.num_kv_heads, self.page,
-                      dcfg.head_dim)
-            self.draft_pools = [(jnp.zeros(dshape, self._draft_dtype),
-                                 jnp.zeros(dshape, self._draft_dtype))
-                                for _ in range(dcfg.num_layers)]
         self._spec = {"proposed": 0, "accepted": 0, "rounds": 0,
                       "committed": 0}
+        # the page pool: ONE for the engine's whole lifetime, its shape
+        # (on the same bucket lattice as Predictor._paged_caches) keys
+        # every compiled program here. Its page programs run through
+        # _run_captured like the engine's own, so their sites, ledgers
+        # and CompileStats notes are the engine's — through a weak
+        # reference: a cache that held the engine would be a cycle, and
+        # the pools' HBM would wait for a garbage collection
+        run = weakref.WeakMethod(self._run_captured)
+        self.cache = PagedKVCache(
+            predictor._model, self.page, self.M, self.B, self._dtype,
+            pool_pages=pool_pages,
+            resident_bytes=sum(_ml.shard_bytes(p._value)
+                               for p in predictor._params)
+            if pool_pages == "auto" else 0,
+            spill_pages=host_spill_pages,
+            draft=None if draft_predictor is None else
+            (draft_predictor._model, self._draft_dtype),
+            dispatch=lambda *a: run()(*a), stats=self.stats,
+            metrics=self._metrics)
+        if phase is not None:
+            self.cache.check_stackable()
         if self.prefix:
-            # pre-compile the page-copy program(s) with a trash-page
-            # self-copy (a no-op write) so the first real
-            # copy-on-write after warmup costs zero compiles
-            self._copy_page(self.trash, self.trash)
+            # the first real copy-on-write after warmup then costs
+            # zero compiles
+            self.cache.warm_copy()
         # graceful degradation: a bounded admission queue sheds at
         # submit (reason "queue_full"); a per-request admission deadline
         # sheds queued requests whose wait already blew their budget
@@ -466,11 +420,8 @@ class ServingEngine:
         # path is counted on paddle_tpu_serving_shed_total instead.
         self.max_queue = int(max_queue) if max_queue else None
         self.admission_deadline_s = admission_deadline_s
-        self._degraded_window = float(degraded_window_s)
         self._last_shed_time: Optional[float] = None
         # /healthz integration: report "degraded" while shedding
-        import weakref
-
         from ..observability import exporter as _exporter
 
         ref = weakref.ref(self)
@@ -499,6 +450,21 @@ class ServingEngine:
                         "PADDLE_TPU_TIMESERIES_S", "5.0")))
             except (OSError, ValueError):
                 self.sampler = None    # unwritable dir: serve anyway
+
+    @property
+    def P(self) -> int:
+        """Pages in the pool, the trash page included."""
+        return self.cache.P
+
+    @property
+    def pools(self):
+        """The cache's device arrays, per layer; assigning ``None``
+        frees them (``release_pools``)."""
+        return self.cache.pools
+
+    @pools.setter
+    def pools(self, value):
+        self.cache.pools = value
 
     # -- admission -------------------------------------------------------
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -537,9 +503,10 @@ class ServingEngine:
         enforce(L + n_new <= self.M,
                 f"prompt ({L}) + max_new_tokens ({n_new}) exceeds cache "
                 f"length {self.M}; raise Config.max_length")
-        enforce(self._pages_needed(L, n_new) <= self.P - 1,
-                f"request needs {self._pages_needed(L, n_new)} pages but "
-                f"the pool only has {self.P - 1}; raise pool_pages")
+        need = self.cache.pages_for(L + n_new)
+        enforce(need <= self.cache.usable,
+                f"request needs {need} pages but the pool only has "
+                f"{self.cache.usable}; raise pool_pages")
         if trace_id is not None and "-" in trace_id:
             # a full traceparent header: the caller's span becomes
             # this trace's parent unless explicitly overridden. A
@@ -616,323 +583,33 @@ class ServingEngine:
 
     def health(self) -> str:
         """"ok", or "degraded" while the engine is shedding load (a
-        shed within ``degraded_window_s``, or the admission queue at
-        its bound) — surfaced on /healthz by the metrics exporter."""
+        shed within the last ``DEGRADED_WINDOW_S``, or the admission
+        queue at its bound) — surfaced on /healthz by the metrics exporter."""
         if self.max_queue is not None and \
                 len(self.queue) >= self.max_queue:
             return "degraded"
         if self._last_shed_time is not None and \
                 time.perf_counter() - self._last_shed_time \
-                <= self._degraded_window:
+                <= DEGRADED_WINDOW_S:
             return "degraded"
         return "ok"
 
-    def _pages_needed(self, L: int, n_new: int) -> int:
-        return -(-(L + n_new) // self.page)
-
-    def _pages_for(self, tokens: int) -> int:
-        return -(-tokens // self.page)
-
-    # -- page accounting (ref-counted pool + prefix cache) ----------------
-    def _avail_pages(self) -> int:
-        """Pages the allocator can produce right now: the free list
-        plus idle registered pages the LRU would yield."""
-        with self._lock:
-            return len(self._free_pages) + len(self._lru)
-
-    def _alloc_pages(self, n: int) -> List[int]:
-        """Pop n pages at refcount 1 — free list first, then reclaim
-        idle cached pages oldest-first. Callers check _avail_pages.
-        Reclaims staged for host spill are drained here AFTER the lock
-        is released and BEFORE the pages are handed out: the payload is
-        still intact (nothing writes a page between reclaim and its
-        next prefill dispatch) and the device read never holds the
-        lock."""
-        with self._lock:
-            out = []
-            for _ in range(n):
-                if not self._free_pages:
-                    self._cache_reclaim()
-                pg = self._free_pages.pop()
-                self._refcount[pg] = 1
-                out.append(pg)
-        if self._spill_pending:
-            self._drain_spills()
-        return out
-
-    def _cache_reclaim(self):
-        """Evict the oldest idle cached page: unregister its hash and
-        return it to the free list (the cache yields under pressure).
-        With the host tier on, the (page, hash) pair is staged so
-        _alloc_pages captures the payload host-side after release."""
-        with self._lock:
-            enforce(self._lru, "page pool exhausted: allocator asked "
-                    "to reclaim with no idle cached pages")
-            pg, _ = self._lru.popitem(last=False)
-            h = self._page_hash.pop(pg)
-            del self._hash_page[h]
-            self._pfx["reclaimed"] += 1
-            self._metrics["prefix_events"].inc(event="reclaimed")
-            if self.spill_pages:
-                self._spill_pending.append((pg, h))
-            self._free_pages.append(pg)
-
-    def _ref_page(self, pg: int):
-        """Take one reference on a cached page (admission hit); an
-        idle page leaves the LRU — it is live again."""
-        with self._lock:
-            self._refcount[pg] += 1
-            if self._refcount[pg] == 1:
-                self._lru.pop(pg, None)
-
-    def _release_pages(self, pages: List[int]):
-        """Drop one reference per page. A registered page that idles
-        parks on the LRU tail (still hit-able); an unregistered one
-        goes straight back to the free list."""
-        with self._lock:
-            for pg in pages:
-                self._refcount[pg] -= 1
-                if self._refcount[pg] > 0:
-                    continue
-                if pg in self._page_hash:
-                    self._lru[pg] = None
-                else:
-                    self._free_pages.append(pg)
-
-    def _register_page(self, h: int, pg: int):
-        """Publish a completed page under its prefix hash. First
-        writer wins: a page already registered (or a hash already
-        mapped) stays as-is, so the maps remain a bijection."""
-        with self._lock:
-            if h in self._hash_page or pg in self._page_hash:
-                return
-            self._hash_page[h] = pg
-            self._page_hash[pg] = h
-            self._pfx["registered"] += 1
-            self._metrics["prefix_events"].inc(event="registered")
-
-    # -- host spill tier (the serving face of distributed/host_offload) --
-    def _note_spill(self, direction: str, nbytes: int):
-        """Book one ledger entry and republish the offload gauges.
-        Cumulative totals as GAUGES (set, not inc) — the same contract
-        as the training tier, so the closed-form cross-check reads one
-        number per (component, direction)."""
-        with self._lock:
-            k = ("kv_page", direction)
-            self._spill_ledger[k] = self._spill_ledger.get(k, 0) + nbytes
-            host = sum(self._payload_nbytes(p)
-                       for p in self._spilled.values())
-            vals = dict(self._spill_ledger)
-            npages = len(self._spilled)
-        m = self._metrics
-        for (comp, d), v in vals.items():
-            m["offload_bytes"].set(v, component=comp, direction=d)
-        m["offload_host"].set(host, component="kv_page")
-        m["offload_spilled_pages"].set(npages)
-
-    @staticmethod
-    def _payload_nbytes(payload) -> int:
-        return sum(int(a.nbytes) for pools in payload if pools
-                   for kv in pools for a in kv)
-
-    def _page_read_fn(self):
-        """ONE compiled page-read program per pool geometry (traced
-        src index — the page-copy discipline): returns the page row of
-        every pool, to be copied host-side by the caller."""
-        key = ("page_read",)
-        if key in self._step_fns:
-            return self._step_fns[key]
-
-        def read(pools, src):
-            return jax.tree_util.tree_map(
-                lambda a: lax.dynamic_index_in_dim(a, src, axis=0,
-                                                   keepdims=False),
-                pools)
-
-        self._step_fns[key] = jax.jit(read)
-        return self._step_fns[key]
-
-    def _page_write_fn(self):
-        """ONE compiled page-write program per pool geometry (traced
-        dst index, donated pools): the fault-back inverse of
-        _page_read_fn."""
-        key = ("page_write",)
-        if key in self._step_fns:
-            return self._step_fns[key]
-
-        def write(pools, rows, dst):
-            return jax.tree_util.tree_map(
-                lambda a, r: lax.dynamic_update_slice_in_dim(
-                    a, r[None], dst, axis=0),
-                pools, rows)
-
-        self._step_fns[key] = jax.jit(write, donate_argnums=(0,))
-        return self._step_fns[key]
-
-    def _drain_spills(self):
-        """Capture staged reclaim payloads host-side (d2h). Runs with
-        the lock RELEASED; the staged pages sit on the free list or in
-        the caller's fresh allocation, unwritten until the next
-        compiled dispatch, so the read is race-free."""
-        with self._lock:
-            pending, self._spill_pending = self._spill_pending, []
-        fn = self._page_read_fn()
-        for pg, h in pending:
-            src = jnp.asarray(pg, jnp.int32)
-            self.stats.note("page_read",
-                            ("target", len(self.pools),
-                             str(self._dtype)))
-            rows = self._run_captured(("page_read",), fn,
-                                      self.pools, src)
-            target = [tuple(np.asarray(r) for r in kv) for kv in rows]
-            draft = None
-            if self._draft is not None:
-                self.stats.note("page_read",
-                                ("draft", len(self.draft_pools),
-                                 str(self._draft_dtype)))
-                drows = self._run_captured(("page_read_draft",), fn,
-                                           self.draft_pools, src)
-                draft = [tuple(np.asarray(r) for r in kv)
-                         for kv in drows]
-            payload = (target, draft)
-            with self._lock:
-                self._spilled[h] = payload
-                self._spill_counts["spilled"] += 1
-                dropped = []
-                while len(self._spilled) > self.spill_pages:
-                    dropped.append(self._spilled.popitem(last=False))
-                self._spill_counts["dropped"] += len(dropped)
-            self._note_spill("d2h", self._payload_nbytes(payload))
-
-    def _fault_spilled(self, req: ServingRequest):
-        """Fault host-spilled prefix pages back onto the device ahead
-        of admission: extend the DEVICE hit run with spilled hashes by
-        allocating one page each (normal admission accounting — the
-        allocation may itself reclaim/spill colder pages), writing the
-        payload back, and registering the page idle so _admit_plan
-        pins it like any cached hit."""
-        if not self.spill_pages or not self._spilled:
-            return
-        floor = self._pages_for(min(len(req.prompt), self.Sc)) + 1
-        for h in self._prefix_hashes(req.prompt):
-            with self._lock:
-                if h in self._hash_page:
-                    continue          # device run keeps extending
-                payload = self._spilled.pop(h, None)
-            if payload is None:
-                return                # run over: neither cached nor spilled
-            if self._avail_pages() <= floor:
-                with self._lock:      # keep it host-side for next time
-                    self._spilled[h] = payload
-                    self._spilled.move_to_end(h, last=False)
-                return
-            [pg] = self._alloc_pages(1)
-            target, draft = payload
-            dst = jnp.asarray(pg, jnp.int32)
-            fn = self._page_write_fn()
-            rows = [tuple(jnp.asarray(a) for a in kv) for kv in target]
-            self.stats.note("page_write",
-                            ("target", len(self.pools),
-                             str(self._dtype)))
-            self.pools = self._run_captured(("page_write",), fn,
-                                            self.pools, rows, dst)
-            if self._draft is not None and draft is not None:
-                drows = [tuple(jnp.asarray(a) for a in kv)
-                         for kv in draft]
-                self.stats.note("page_write",
-                                ("draft", len(self.draft_pools),
-                                 str(self._draft_dtype)))
-                self.draft_pools = self._run_captured(
-                    ("page_write_draft",), fn, self.draft_pools,
-                    drows, dst)
-            self._register_page(h, pg)
-            self._release_pages([pg])     # idle + registered: hit-able
-            with self._lock:
-                self._spill_counts["faulted"] += 1
-            self._note_spill("h2d", self._payload_nbytes(payload))
-
-    def spill_stats(self) -> Dict[str, Any]:
-        """Host-tier counters: pages spilled/faulted/dropped, resident
-        host bytes, and the cumulative transfer ledger per direction."""
-        with self._lock:
-            out = dict(self._spill_counts)
-            out["host_pages"] = len(self._spilled)
-            out["host_bytes"] = sum(self._payload_nbytes(p)
-                                    for p in self._spilled.values())
-            out["transfer_bytes"] = {d: v for (_c, d), v
-                                     in self._spill_ledger.items()}
-            return out
-
-    def _prefix_hashes(self, prompt: np.ndarray) -> List[int]:
-        """Rolling hash per FULL page-aligned prompt chunk: h_j covers
-        prompt[:(j+1)*page], so equal hashes mean equal whole
-        prefixes — a hit run is always a shared prefix, never a
-        shared interior."""
-        page = self.page
-        arr = np.ascontiguousarray(np.asarray(prompt, np.int64))
-        out: List[int] = []
-        h = hash(("paddle_tpu_prefix", page))
-        for j in range(len(arr) // page):
-            h = hash((h, arr[j * page:(j + 1) * page].tobytes()))
-            out.append(h)
-        return out
-
     def check_invariants(self):
-        """Pool-accounting invariant (the free-list hardening gate):
-        free list, idle (LRU) pages, and refcounted live pages
-        partition the usable pool exactly; every page's refcount
-        equals the number of slots holding it; the hash<->page maps
-        stay bijective. Raises on any violation — double free, leak,
-        or refcount drift."""
-        with self._lock:
-            bad: List[str] = []
-            usable = self.P - 1
-            free, lru = list(self._free_pages), list(self._lru)
-            fs, ls = set(free), set(lru)
-            held = Counter(pg for s in self.slots if s is not None
-                           for pg in s.pages)
-            live = {pg for pg in range(usable) if self._refcount[pg] > 0}
-            if len(fs) != len(free):
-                bad.append("duplicate pages on the free list")
-            if self.trash in fs | ls | live:
-                bad.append("trash page entered circulation")
-            if fs & ls or fs & live or ls & live:
-                bad.append("free/idle/live page sets overlap")
-            if len(free) + len(lru) + len(live) != usable:
-                bad.append(f"free({len(free)}) + idle({len(lru)}) + "
-                           f"live({len(live)}) != pool({usable})")
-            if set(held) != live:
-                bad.append("refcounted pages != pages held by slots")
-            drift = {pg: (int(c), self._refcount[pg])
-                     for pg, c in held.items()
-                     if self._refcount[pg] != c}
-            if drift:
-                bad.append(f"refcount drift (held, rc): {drift}")
-            if len(self._hash_page) != len(self._page_hash) or \
-                    set(self._page_hash) != set(self._hash_page.values()):
-                bad.append("prefix hash maps out of sync")
-            if not ls <= set(self._page_hash):
-                bad.append("LRU page not registered in the cache")
-            if set(self._spilled) & set(self._hash_page):
-                bad.append("hash both device-registered and host-"
-                           "spilled (the tier owns a hash exclusively)")
-            if len(self._spilled) > max(self.spill_pages, 0):
-                bad.append(f"host tier over its cap: "
-                           f"{len(self._spilled)} > {self.spill_pages}")
-            enforce(not bad,
-                    "serving pool invariant violated: " + "; ".join(bad))
+        """The cache's pool-accounting invariant against the pages this
+        engine's slots hold (``PagedKVCache.check_invariants``)."""
+        self.cache.check_invariants(
+            s.pages for s in self.slots if s is not None)
 
     def prefix_cache_stats(self) -> Dict[str, Any]:
         """Host-side prefix-cache counters: page lookups/hits at
         admission, prompt tokens skipped vs fed, copy-on-writes, LRU
         reclaims, plus the current registered/idle page counts."""
-        with self._lock:
-            out = dict(self._pfx)
-            out["hit_rate"] = (out["hits"] / out["lookups"]
-                               if out["lookups"] else 0.0)
-            out["registered_pages"] = len(self._page_hash)
-            out["idle_pages"] = len(self._lru)
-            return out
+        return self.cache.prefix_stats()
+
+    def spill_stats(self) -> Dict[str, Any]:
+        """Host-tier counters: pages spilled/faulted/dropped, resident
+        host bytes, and the cumulative transfer ledger per direction."""
+        return self.cache.spill_stats()
 
     def spec_stats(self) -> Dict[str, Any]:
         """Speculative-decoding counters: drafts proposed/accepted,
@@ -946,35 +623,34 @@ class ServingEngine:
         return out
 
     def _admit_plan(self, req: ServingRequest):
-        """Admission plan, pure (no allocation): returns (cold pages
+        """Admission plan (allocates nothing for the request; with the
+        host tier on it first faults the prompt's spilled pages back,
+        so the match sees them as ordinary idle hits): returns (cold pages
         to allocate now, reserve pages the availability check must
-        also cover, cache-hit pages, prefix hashes, fed0 = prompt
-        tokens the cache already holds). Legacy: the whole len+new
-        footprint. Chunked: only the first chunk's pages past the hit
-        run — the rest are reserved incrementally (_plan_chunks). A
-        FULL-prompt hit refeeds the last prompt token (fed0 = L-1) so
-        the unified step still samples token 0; its copy-on-write
-        page is the reserve."""
+        also cover — idle hit pages among them: they count as available
+        but the hit itself is about to pin them —, cache-hit pages,
+        prefix hashes, fed0 = prompt tokens the cache already holds).
+        Legacy: the whole len+new footprint. Chunked: only the first
+        chunk's pages past the hit run — the rest are reserved
+        incrementally (_plan_chunks). A FULL-prompt hit refeeds the
+        last prompt token (fed0 = L-1) so the unified step still
+        samples token 0; its copy-on-write page is in the reserve."""
+        cache = self.cache
         L = len(req.prompt)
         if not self.chunked:
-            return (self._pages_needed(L, req.max_new_tokens), 0,
+            return (cache.pages_for(L + req.max_new_tokens), 0,
                     [], None, 0)
         if not self.prefix:
-            return (self._pages_for(min(L, self.Sc)), 0, [], None, 0)
-        hashes = self._prefix_hashes(req.prompt)
-        hits: List[int] = []
-        with self._lock:
-            for h in hashes:
-                pg = self._hash_page.get(h)
-                if pg is None:
-                    break
-                hits.append(pg)
+            return (cache.pages_for(min(L, self.Sc)), 0, [], None, 0)
+        hashes = cache.prefix_hashes(req.prompt)
+        cache.fault_in(hashes, cache.pages_for(min(L, self.Sc)) + 1)
+        hits, idle = cache.match_prefix(hashes)
         k = len(hits)
         fed0 = k * self.page
         if fed0 >= L:
             fed0 = L - 1
-        cold = self._pages_for(min(L, fed0 + self.Sc)) - k
-        reserve = 1 if fed0 < k * self.page else 0
+        cold = cache.pages_for(min(L, fed0 + self.Sc)) - k
+        reserve = (1 if fed0 < k * self.page else 0) + idle
         return max(cold, 0), reserve, hits, hashes, fed0
 
     def _pvals(self):
@@ -998,27 +674,17 @@ class ServingEngine:
             free = [b for b in range(self.B) if self.slots[b] is None]
             if not free:
                 return
-            # host tier: fault spilled prefix pages back first, so the
-            # plan below sees them as ordinary idle cached hits
-            self._fault_spilled(req)
             cold, reserve, hits, hashes, fed0 = self._admit_plan(req)
-            with self._lock:
-                # idle hit pages count toward _avail_pages but are
-                # about to be pinned by the hit itself — charge them
-                idle_hits = sum(1 for pg in hits
-                                if self._refcount[pg] == 0)
-            if cold + reserve + idle_hits > self._avail_pages():
+            if cold + reserve > self.cache.available():
                 return                    # head-of-line waits for evictions
             self.queue.popleft()
             b = free[0]
             # a backfill is an admission that joins rows mid-decode
             # (the continuous-batching event; a cold admit is not one)
             backfill = self.num_active > 0
-            for pg in hits:
-                self._ref_page(pg)        # pin BEFORE any reclaim can run
-            pages = list(hits) + self._alloc_pages(cold)
-            self.tables[b, :] = self.trash
-            self.tables[b, :len(pages)] = pages
+            self.cache.pin(hits)          # BEFORE any reclaim can run
+            pages = list(hits) + self.cache.allocate(cold)
+            self.cache.set_row(b, pages)
             slot = _Slot(
                 req, pages, state="prefill" if self.chunked else "decode",
                 seq=self._admit_seq)
@@ -1031,11 +697,8 @@ class ServingEngine:
             m = self._metrics
             m["requests"].inc(event="admitted")
             if hashes is not None:
-                self._pfx["lookups"] += len(hashes)
-                self._pfx["hits"] += len(hits)
-                self._pfx["skipped_tokens"] += fed0
-                if hits:
-                    m["prefix_events"].inc(len(hits), event="hit")
+                self.cache.note(lookups=len(hashes), hits=len(hits),
+                                skipped_tokens=fed0)
             if self.debug:
                 self.check_invariants()
             if backfill:
@@ -1066,15 +729,14 @@ class ServingEngine:
         Sb = min(_bucket(L), self.M)
         ids = np.zeros((1, Sb), np.int32)
         ids[0, :L] = req.prompt
-        caches = [(kp, vp, jnp.asarray(self.tables[b:b + 1]))
-                  for kp, vp in self.pools]
+        caches = self.cache.bind(self.cache.rows(b))
         fn = self.pred._prefill_fn(1, Sb, self.M)
         self.stats.note("prefill", (1, Sb, self.M, self.page, self.P,
                                     str(ids.dtype), str(self._dtype)))
         last, caches = self._run_captured(
             ("prefill", Sb), fn, self._pvals(), jnp.asarray(ids), caches,
             jnp.asarray([L], jnp.int32))
-        self.pools = [(c[0], c[1]) for c in caches]
+        self.cache.commit(caches)
         self._rng, sub = jax.random.split(self._rng)
         tok0 = int(np.asarray(_sample(last, sub, self.gen))[0])
         req.new_tokens.append(tok0)
@@ -1259,14 +921,6 @@ class ServingEngine:
     def _draft_pvals(self):
         return tuple(p._value for p in self._draft._params)
 
-    def _extended_tables(self) -> np.ndarray:
-        """The model's `valid` contract: one extra trailing table
-        column that ALWAYS maps to the trash page (dead-slot and
-        overdraft writes land there; attention slices it back off)."""
-        return np.concatenate(
-            [self.tables,
-             np.full((self.B, 1), self.trash, np.int32)], axis=1)
-
     def _propose(self, k_use: Dict[int, int]) -> np.ndarray:
         """Run the draft proposal scan for this round's decode rows;
         returns the [B, k] proposed ids. Also writes the rows' last
@@ -1283,8 +937,8 @@ class ServingEngine:
             tok[b] = s.req.new_tokens[-1]
             pos[b] = s.pos + len(s.req.new_tokens) - 1
             nv[b] = 1
-        caches = [(kp, vp, jnp.asarray(self._extended_tables()))
-                  for kp, vp in self.draft_pools]
+        caches = self.cache.bind(self.cache.rows(extended=True),
+                                 draft=True)
         fn = self._propose_fn()
         self.stats.note("draft_propose",
                         (B, self.spec, self.M, self.page, self.P,
@@ -1293,7 +947,7 @@ class ServingEngine:
             ("draft_propose",), fn, self._draft_pvals(),
             jnp.asarray(tok), caches, jnp.asarray(pos),
             jnp.asarray(nv))
-        self.draft_pools = [(c[0], c[1]) for c in caches]
+        self.cache.commit(caches, draft=True)
         return np.asarray(toks)
 
     def _draft_feed(self, feeders, ids: np.ndarray, starts: np.ndarray):
@@ -1305,8 +959,8 @@ class ServingEngine:
         nvf = np.zeros((B,), np.int32)
         for b, n, _last in feeders:
             nvf[b] = n
-        caches = [(kp, vp, jnp.asarray(self._extended_tables()))
-                  for kp, vp in self.draft_pools]
+        caches = self.cache.bind(self.cache.rows(extended=True),
+                                 draft=True)
         fn = self._draft_chunk_fn()
         self.stats.note("draft_chunk",
                         (B, self.Sc, self.M, self.page, self.P,
@@ -1315,7 +969,7 @@ class ServingEngine:
             ("draft_chunk", self.Sc), fn, self._draft_pvals(),
             jnp.asarray(ids), caches, jnp.asarray(starts),
             jnp.asarray(nvf))
-        self.draft_pools = [(c[0], c[1]) for c in caches]
+        self.cache.commit(caches, draft=True)
 
     def _plan_chunks(self):
         """Pick this round's prefill feeders (admission order) under
@@ -1344,7 +998,7 @@ class ServingEngine:
             last = s.fed + n == L
             want_tokens = (L + s.req.max_new_tokens) if last \
                 else (s.fed + n)
-            extra = self._pages_for(want_tokens) - len(s.pages)
+            extra = self.cache.pages_for(want_tokens) - len(s.pages)
             # copy-on-write: shared/registered pages are immutable, so
             # any page this chunk writes into that another slot (or
             # the cache) can still see is copied to a private page
@@ -1352,89 +1006,31 @@ class ServingEngine:
             # fires on the full-prefix-hit refeed (position L-1 lands
             # in the final hit page).
             cow = self._cow_plan(s, n)
-            if max(extra, 0) + len(cow) > self._avail_pages():
+            if max(extra, 0) + len(cow) > self.cache.available():
                 stalled = True
                 self._metrics["prefill_stall"].inc()
                 continue
             if extra > 0:
-                newp = self._alloc_pages(extra)
-                self.tables[b, len(s.pages):len(s.pages) + extra] = newp
-                s.pages.extend(newp)
+                s.pages.extend(self.cache.allocate(extra))
             for j in cow:
-                self._cow_page(b, j)
+                s.pages[j] = self.cache.copy_on_write(s.pages[j])
+            if extra > 0 or cow:
+                self.cache.set_row(b, s.pages)
             feeders.append((b, n, last))
             budget -= n
         self._page_stalled = stalled
         return feeders, stalled
 
-    # -- copy-on-write ---------------------------------------------------
     def _cow_plan(self, s: _Slot, n: int) -> List[int]:
         """Table columns of ``s`` whose pages the next n-token chunk
-        writes into while shared (refcount > 1) or registered in the
-        prefix cache — those must be copied before the write."""
+        writes into while shared or registered in the prefix cache —
+        those must be copied before the write."""
         if not self.prefix:
             return []
         jlo = s.fed // self.page
-        jhi = (s.fed + n - 1) // self.page
-        out: List[int] = []
-        with self._lock:
-            for j in range(jlo, min(jhi, len(s.pages) - 1) + 1):
-                pg = s.pages[j]
-                if self._refcount[pg] > 1 or pg in self._page_hash:
-                    out.append(j)
-        return out
-
-    def _cow_page(self, b: int, j: int):
-        """Replace table column j of row b with a private copy of its
-        page (device-side copy into a freshly allocated page), then
-        drop the reference on the shared original."""
-        s = self.slots[b]
-        old = s.pages[j]
-        [new] = self._alloc_pages(1)
-        self._copy_page(old, new)
-        s.pages[j] = new
-        self.tables[b, j] = new
-        self._release_pages([old])
-        self._pfx["cow"] += 1
-        self._metrics["prefix_events"].inc(event="cow")
-
-    def _page_copy_fn(self):
-        """ONE compiled page-copy program per pool geometry: src/dst
-        page ids are TRACED scalars (dynamic slice in/out), so every
-        (src, dst) pair reuses the same executable — a Python-side
-        ``.at[dst].set(pool[src])`` would recompile per pair."""
-        key = ("page_copy",)
-        if key in self._step_fns:
-            return self._step_fns[key]
-
-        def copy(pools, src, dst):
-            def one(a):
-                row = lax.dynamic_index_in_dim(a, src, axis=0,
-                                               keepdims=True)
-                return lax.dynamic_update_slice_in_dim(a, row, dst,
-                                                       axis=0)
-
-            return jax.tree_util.tree_map(one, pools)
-
-        self._step_fns[key] = jax.jit(copy, donate_argnums=(0,))
-        return self._step_fns[key]
-
-    def _copy_page(self, src: int, dst: int):
-        """Copy one physical page in every pool (and the draft pools
-        when speculative decoding is on — they share page ids)."""
-        fn = self._page_copy_fn()
-        s = jnp.asarray(src, jnp.int32)
-        d = jnp.asarray(dst, jnp.int32)
-        self.stats.note("page_copy",
-                        ("target", len(self.pools), str(self._dtype)))
-        self.pools = self._run_captured(("page_copy",), fn,
-                                        self.pools, s, d)
-        if self._draft is not None:
-            self.stats.note("page_copy",
-                            ("draft", len(self.draft_pools),
-                             str(self._draft_dtype)))
-            self.draft_pools = self._run_captured(
-                ("page_copy_draft",), fn, self.draft_pools, s, d)
+        jhi = min((s.fed + n - 1) // self.page, len(s.pages) - 1)
+        return [jlo + i for i in
+                self.cache.shared(s.pages[jlo:jhi + 1])]
 
     def _unified_round(self, feeders):
         """One unified dispatch: every feeder writes its next prompt
@@ -1482,8 +1078,7 @@ class ServingEngine:
                 ku = k_use[b]
                 if ku > 0:
                     ids[b, 1:1 + ku] = drafts[b, :ku]
-        tbl = self._extended_tables()
-        caches = [(kp, vp, jnp.asarray(tbl)) for kp, vp in self.pools]
+        caches = self.cache.bind(self.cache.rows(extended=True))
         if spec:
             fn = self._unified_spec_step_fn()
             self.stats.note("unified_spec",
@@ -1504,7 +1099,7 @@ class ServingEngine:
                 ("unified", self.Sc), fn, self._pvals(),
                 jnp.asarray(ids), caches, jnp.asarray(starts),
                 jnp.asarray(nvalid), sub)
-        self.pools = [(c[0], c[1]) for c in caches]
+        self.cache.commit(caches)
         # mirror the chunks into the draft pools BEFORE commits can
         # retire a feeder (a finished row's table goes all-trash, and
         # its registered pages must carry draft KV into the cache)
@@ -1532,7 +1127,7 @@ class ServingEngine:
                 # immutable KV: publish them under their prefix hash
                 full = s.fed // self.page
                 for j in range(s.registered, full):
-                    self._register_page(s.hashes[j], s.pages[j])
+                    self.cache.register(s.hashes[j], s.pages[j])
                 s.registered = max(s.registered, full)
             if last:
                 tok0 = int(toks[b, nvalid[b] - 1]) if spec \
@@ -1560,7 +1155,7 @@ class ServingEngine:
                     # it on this replica
                     s.state = "migrate"
         if self.prefix:
-            self._pfx["fed_tokens"] += fed_tokens
+            self.cache.note(fed_tokens=fed_tokens)
         emitted = 0
         for b in decode_rows:
             req = self.slots[b].req
@@ -1627,8 +1222,7 @@ class ServingEngine:
         # refcount-aware release: pages shared with elder slots (or
         # registered in the prefix cache) survive the preemption —
         # the sharers keep decoding against them untouched
-        self._release_pages(s.pages)
-        self.tables[b, :] = self.trash
+        self.cache.release_row(b, s.pages)
         self.slots[b] = None
         self.queue.appendleft(s.req)
         m = self._metrics
@@ -1678,18 +1272,12 @@ class ServingEngine:
         # In chunked mode, stalled mid-prefill rows ride the same way —
         # their REAL table rows are masked to all-trash for this round
         # so the riding write cannot clobber their fed pages
-        tbl = self.tables
-        if self.chunked:
-            mid_prefill = [b for b in range(self.B)
-                           if self.slots[b] is not None
-                           and self.slots[b].state == "prefill"]
-            if mid_prefill:
-                tbl = self.tables.copy()
-                tbl[mid_prefill, :] = self.trash
-        caches = [(kp, vp, jnp.asarray(tbl))
-                  for kp, vp in self.pools]
-        if self._moe_counts is not None:
-            caches = [c + (n,) for c, n in zip(caches, self._moe_counts)]
+        mid_prefill = [b for b in range(self.B)
+                       if self.slots[b] is not None
+                       and self.slots[b].state == "prefill"] \
+            if self.chunked else ()
+        caches = self.cache.bind(self.cache.rows(masked=mid_prefill),
+                                 counters=True)
         fn = self._decode_step_fn()
         self.stats.note("serve_decode",
                         (self.B, self.M, self.chunk, self.P,
@@ -1699,9 +1287,7 @@ class ServingEngine:
         toks, caches = self._run_captured(
             ("decode",), fn, self._pvals(), jnp.asarray(tok), caches,
             jnp.asarray(pos), sub)
-        self.pools = [(c[0], c[1]) for c in caches]
-        if self._moe_counts is not None:
-            self._moe_counts = [c[3] for c in caches]
+        self.cache.commit(caches)
         toks = np.asarray(toks)
         emitted = 0
         for b in active:
@@ -1737,8 +1323,7 @@ class ServingEngine:
         the free list), table row to all-trash, slot open for
         backfill."""
         slot = self.slots[b]
-        self._release_pages(slot.pages)
-        self.tables[b, :] = self.trash
+        self.cache.release_row(b, slot.pages)
         self.slots[b] = None
         self.finished[slot.req.rid] = slot.req
         req = slot.req
@@ -1765,15 +1350,9 @@ class ServingEngine:
     def prefix_match(self, hashes: List[int]) -> int:
         """Leading page-aligned prompt chunks whose KV this replica's
         prefix cache already holds — the router's affinity signal
-        (computed over the SAME rolling hashes _prefix_hashes
-        registers under)."""
-        n = 0
-        with self._lock:
-            for h in hashes:
-                if h not in self._hash_page:
-                    break
-                n += 1
-        return n
+        (computed over the SAME rolling hashes, ``cache.prefix_hashes``,
+        pages are registered under)."""
+        return len(self.cache.match_prefix(hashes)[0])
 
     def migratable(self) -> List[int]:
         """rids parked for migration on a prefill replica: prompt
@@ -1787,19 +1366,16 @@ class ServingEngine:
         geometry RIGHT NOW (a free slot plus its full page footprint).
         False is the backpressure signal the disagg layer acts on."""
         if any(s is None for s in self.slots):
-            return self._pages_needed(prompt_len, max_new_tokens) \
-                <= self._avail_pages()
+            return self.cache.pages_for(prompt_len + max_new_tokens) \
+                <= self.cache.available()
         return False
 
     def export_request(self, rid: int) -> Dict[str, Any]:
         """Export one migratable row: the committed KV page payloads
-        (read through the compiled page-read program — traced src
-        index, so exports never recompile), its block-table row, and
-        the host request state; the row is then evicted (pages
-        released, slot open for backfill). Each page payload is one
-        [2*layers, kv_heads, page, head_dim] array (k/v interleaved
-        per layer). Delivery framing — crc32 per page, wire-byte
-        booking — lives in inference/disagg.py."""
+        and its block-table row (``PagedKVCache.export_row``), and the
+        host request state; the row is then evicted (pages released,
+        slot open for backfill). Delivery framing — crc32 per page,
+        wire-byte booking — lives in inference/disagg.py."""
         b = next((i for i, s in enumerate(self.slots)
                   if s is not None and s.state == "migrate"
                   and s.req.rid == rid), None)
@@ -1807,18 +1383,8 @@ class ServingEngine:
                 f"rid {rid} is not parked for migration")
         s = self.slots[b]
         req = s.req
-        k = self._pages_for(len(req.prompt))  # pages with committed KV
-        fn = self._page_read_fn()
-        payloads: List[np.ndarray] = []
-        for j in range(k):
-            src = jnp.asarray(s.pages[j], jnp.int32)
-            self.stats.note("page_read",
-                            ("target", len(self.pools),
-                             str(self._dtype)))
-            rows = self._run_captured(("page_read",), fn, self.pools,
-                                      src)
-            payloads.append(np.stack([np.asarray(a)
-                                      for kv in rows for a in kv]))
+        k = self.cache.pages_for(len(req.prompt))  # with committed KV
+        payloads, table_row = self.cache.export_row(b, s.pages[:k])
         now = time.perf_counter()
         tr = self._live_traces.pop(rid, None)
         if tr is not None:
@@ -1832,9 +1398,8 @@ class ServingEngine:
                "t_submit": req.t_submit,
                "t_first_token": req.t_first_token,
                "trace_id": req.trace_id, "parent_span_id": req.span_id,
-               "pages": payloads, "table_row": self.tables[b].copy()}
-        self._release_pages(s.pages)
-        self.tables[b, :] = self.trash
+               "pages": payloads, "table_row": table_row}
+        self.cache.release_row(b, s.pages)
         self.slots[b] = None
         self._metrics["requests"].inc(event="migrated_out")
         if self.debug:
@@ -1843,10 +1408,9 @@ class ServingEngine:
 
     def import_request(self, pkg: Dict[str, Any]) -> Optional[int]:
         """Adopt a migrated request on a decode replica: allocate its
-        full page footprint, write the committed page payloads through
-        the compiled page-write program (traced dst index — imports
-        never recompile), and park the row mid-decode exactly where
-        the prefill replica stopped. Returns the local rid, or None
+        full page footprint, write the committed page payloads
+        (``PagedKVCache.import_row``), and park the row mid-decode
+        exactly where the prefill replica stopped. Returns the local rid, or None
         when this replica refuses (no free slot / not enough pages) —
         the disagg layer's backpressure signal. crc verification
         happens in inference/disagg.py BEFORE this call."""
@@ -1855,23 +1419,11 @@ class ServingEngine:
         prompt = np.asarray(pkg["prompt"], np.int64)
         L, n_new = len(prompt), int(pkg["max_new_tokens"])
         free = [b for b in range(self.B) if self.slots[b] is None]
-        if not free or self._pages_needed(L, n_new) > \
-                self._avail_pages():
+        need = self.cache.pages_for(L + n_new)
+        if not free or need > self.cache.available():
             return None
         b = free[0]
-        pages = self._alloc_pages(self._pages_needed(L, n_new))
-        fn = self._page_write_fn()
-        nl = len(self.pools)
-        for j, arr in enumerate(pkg["pages"]):
-            rows = [(jnp.asarray(arr[2 * l]),
-                     jnp.asarray(arr[2 * l + 1])) for l in range(nl)]
-            dst = jnp.asarray(pages[j], jnp.int32)
-            self.stats.note("page_write",
-                            ("target", nl, str(self._dtype)))
-            self.pools = self._run_captured(("page_write",), fn,
-                                            self.pools, rows, dst)
-        self.tables[b, :] = self.trash
-        self.tables[b, :len(pages)] = pages
+        pages = self.cache.import_row(b, pkg["pages"], need)
         rid = self._next_rid
         self._next_rid += 1
         req = ServingRequest(rid, prompt, n_new, pkg["eos_token_id"],
@@ -1922,19 +1474,16 @@ class ServingEngine:
         m = self._metrics
         m["queue_depth"].set(len(self.queue))
         m["active_slots"].set(self.num_active)
-        with self._lock:
-            n_free, n_idle = len(self._free_pages), len(self._lru)
-            n_reg = len(self._page_hash)
+        c = self.cache.counts()
+        n_free, n_idle, n_reg = c["free"], c["idle"], c["registered"]
         m["free_pages"].set(n_free)
-        usable = self.P - 1              # trash page is never allocable
+        usable = self.cache.usable       # trash page is never allocable
         # idle cached pages are reclaimable on demand: occupancy
         # reports pages slots actually hold, not cache residue
         m["page_occupancy"].set(
             (usable - n_free - n_idle) / usable if usable else 0.0)
         if self.prefix:
-            lk = self._pfx["lookups"]
-            m["prefix_hit_rate"].set(
-                self._pfx["hits"] / lk if lk else 0.0)
+            m["prefix_hit_rate"].set(c["hit_rate"])
             m["prefix_pages"].set(n_reg - n_idle, state="active")
             m["prefix_pages"].set(n_idle, state="idle")
             # the hash-table size router prefix-affinity steering
@@ -2058,20 +1607,14 @@ class ServingEngine:
         analyzed executable's byte classes plus the measured resident
         state (params + the KV page pool, with the per-page byte cost
         and pool geometry the "auto" sizing uses)."""
-        from . import _kv_page_bytes
-
-        page_bytes = _kv_page_bytes(self.pred._model, self.page,
-                                    self._dtype)
-        pool_bytes = sum(_ml.shard_bytes(kp) + _ml.shard_bytes(vp)
-                         for kp, vp in self.pools)
         return {
             "executables": {led.program: led.to_dict()
                             for led in self._mem_ledgers.values()},
             "state": {
                 "params_bytes": sum(_ml.shard_bytes(p._value)
                                     for p in self.pred._params),
-                "kv_pool_bytes": pool_bytes,
-                "page_bytes": page_bytes,
+                "kv_pool_bytes": self.cache.pool_bytes(),
+                "page_bytes": self.cache.page_bytes,
                 "pool_pages": self.P,
                 "live_peak_bytes": self._live_peak,
             },
@@ -2082,7 +1625,7 @@ class ServingEngine:
         its model are still held (a caller that needs the HBM for
         another program over the same weights). The engine serves
         nothing after this."""
-        self.pools = None
+        self.cache.release()
 
     def moe_stats(self) -> Optional[Dict[str, Any]]:
         """Routing counters of an expert model's decode steps, fetched
@@ -2096,9 +1639,9 @@ class ServingEngine:
         grouped products did not cover; the layer has no capacity, so
         anything but 0 is a fault of the sort or of the group sizes.
         None for a model without routed experts."""
-        if self._moe_counts is None:
+        if self.cache.counters is None:
             return None
-        c = np.stack([np.asarray(a) for a in self._moe_counts]
+        c = np.stack([np.asarray(a) for a in self.cache.counters]
                      ).astype(np.int64)
         k = int(getattr(self.pred._model.config, "num_experts_per_tok", 0))
         return {"pairs": c[:, :-3], "absent_pairs": c[:, -3],

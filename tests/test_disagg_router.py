@@ -146,9 +146,9 @@ class TestDisaggParity:
         # 2) wire bytes pin to the closed form, on the migrator, the
         #    migration counter, AND the comm ledger's migrate axis
         pb = _page_bytes(peng)
-        want = sum((-(-len(p) // PAGE)) * pb + peng.npages * 4
+        want = sum((-(-len(p) // PAGE)) * pb + peng.cache.npages * 4
                    for _, p, _ in trace)
-        want += (-(-19 // PAGE)) * pb + peng.npages * 4   # the warmup
+        want += (-(-19 // PAGE)) * pb + peng.cache.npages * 4   # the warmup
         assert rt.migrator.wire_bytes == want
         assert m["migration_bytes"].value() - mig_bytes0 == want
         assert m["comm_bytes"].value(axis="migrate",
@@ -430,7 +430,7 @@ class TestPrefixGaugeSatellite:
         eng.submit(np.arange(1, 1 + 3 * PAGE), max_new_tokens=2)
         eng.run()
         m = serving_metrics()
-        assert m["prefix_hash_entries"].value() == len(eng._hash_page)
+        assert m["prefix_hash_entries"].value() == eng.cache.counts()["registered"]
         assert m["prefix_hash_entries"].value() >= 3
 
 
@@ -454,6 +454,7 @@ class TestDisaggLintPins:
 
         assert _in_scope("paddle_tpu/inference/router.py")
         assert _in_scope("paddle_tpu/inference/disagg.py")
+        assert _in_scope("paddle_tpu/inference/kv_cache.py")
 
     def test_migrate_axis_vocabulary(self):
         assert MIGRATE_AXES == ("migrate",)

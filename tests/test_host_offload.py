@@ -495,7 +495,8 @@ def test_tpulint_offload_surface_zero_baseline():
 
         findings = lint_paths(
             [repo / "paddle_tpu" / "distributed" / "host_offload.py",
-             repo / "paddle_tpu" / "inference" / "serving.py"],
+             repo / "paddle_tpu" / "inference" / "serving.py",
+             repo / "paddle_tpu" / "inference" / "kv_cache.py"],
             ALL_RULES, root=repo)
     finally:
         sys.path.remove(str(repo))

@@ -320,7 +320,7 @@ def test_llama_engine_pools_and_program_keys_unchanged():
     shape = (eng.P, cfg.num_kv_heads, 8, cfg.head_dim)
     assert [(k.shape, v.shape) for k, v in eng.pools] == \
         [(shape, shape)] * cfg.num_layers
-    assert eng._moe_counts is None and eng.moe_stats() is None
+    assert eng.cache.counters is None and eng.moe_stats() is None
     eng.submit(np.arange(11) % 250, max_new_tokens=5)
     eng.run()
     assert list(eng._step_fns) == [(2, eng.M, 2, 0.0, 0, 1.0)]

@@ -229,7 +229,7 @@ def test_row_ending_at_the_context_limit_leaves_its_neighbour_alone():
         eng = ServingEngine(pred, max_batch=2, pool_pages=8, decode_chunk=4)
         rids = [eng.submit(p, max_new_tokens=n) for p, n in requests]
         eng._admit()                # pages are handed out at admission
-        tables = eng.tables.copy()
+        tables = eng.cache.tables.copy()
         done = eng.run()
         return eng, tables, [done[rid].new_tokens for rid in rids]
 

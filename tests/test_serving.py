@@ -97,7 +97,7 @@ class TestEvictionBackfill:
         ref_a = _solo(tiny_model, a, 8)
         eos = int(ref_a[len(a) + 1])          # a's 2nd new token
         eng = ServingEngine(paged_pred, max_batch=2)
-        free0 = len(eng._free_pages)
+        free0 = eng.cache.counts()["free"]
         ra = eng.submit(a, max_new_tokens=8, eos_token_id=eos)
         rb = eng.submit(b, max_new_tokens=8)
         rc = eng.submit(c, max_new_tokens=3)  # queued: batch is full
@@ -114,8 +114,27 @@ class TestEvictionBackfill:
         np.testing.assert_array_equal(done[rb].output_ids,
                                       _solo(tiny_model, b, 8))
         # every page returned to the free list
-        assert len(eng._free_pages) == free0
-        assert (eng.tables == eng.trash).all()
+        assert eng.cache.counts()["free"] == free0
+        assert (eng.cache.tables == eng.cache.trash).all()
+
+    def test_dropping_the_engine_frees_the_pools_at_once(self, paged_pred):
+        """The engine and its cache are no reference cycle (the cache
+        runs its page programs through the engine WEAKLY): the pools'
+        device memory goes with the last reference, not with the next
+        garbage collection."""
+        import gc
+        import weakref
+
+        eng = ServingEngine(paged_pred, max_batch=2)
+        eng.submit(np.arange(1, 12), max_new_tokens=2)
+        eng.run()
+        gone = weakref.ref(eng), weakref.ref(eng.cache)
+        gc.disable()
+        try:
+            del eng
+            assert [r() for r in gone] == [None, None]
+        finally:
+            gc.enable()
 
     def test_pool_capacity_gates_admission(self, tiny_model):
         """Admission waits for pages, not just slots; a request that

@@ -205,8 +205,9 @@ class TestPoolInvariant:
         shed = [r for r in eng.finished.values() if r.shed]
         assert shed, "queue bound must shed"
         eng.check_invariants()
-        free = len(eng._free_pages) + len(eng._lru)
-        live = sum(1 for pg in range(eng.P - 1) if eng._refcount[pg])
+        c = eng.cache.counts()
+        free = c["free"] + c["idle"]
+        live = sum(1 for pg in range(eng.P - 1) if eng.cache.refcount(pg))
         assert free + live == eng.P - 1
 
     def test_invariant_catches_double_free(self, paged_pred):
@@ -215,7 +216,7 @@ class TestPoolInvariant:
         eng = ServingEngine(paged_pred, max_batch=2, prefill_chunk=16,
                             prefix_cache=True)
         eng.check_invariants()
-        eng._free_pages.append(eng._free_pages[0])
+        eng.cache._free_pages.append(eng.cache._free_pages[0])
         with pytest.raises(PreconditionNotMetError, match="invariant"):
             eng.check_invariants()
 

@@ -290,7 +290,7 @@ class TestIncrementalPages:
         np.testing.assert_array_equal(done[rs].output_ids,
                                       _solo(tiny_model, short_p, 8))
         # every page came back
-        assert len(eng._free_pages) == 15
+        assert eng.cache.counts()["free"] == 15
 
     def test_page_starved_pool_preempts_and_completes(self, tiny_model):
         """Two prompts whose combined footprint exceeds the pool: both
@@ -317,7 +317,7 @@ class TestIncrementalPages:
                                       _solo(tiny_model, a, 8))
         np.testing.assert_array_equal(done[rb].output_ids,
                                       _solo(tiny_model, b, 8))
-        assert len(eng._free_pages) == 7          # pool fully returned
+        assert eng.cache.counts()["free"] == 7          # pool fully returned
         # the loser's trace records the preemption instant
         spans = [sp["name"] for t in eng.request_traces()
                  for sp in t["spans"]]
@@ -380,6 +380,7 @@ def test_tpulint_unified_serving_zero_baseline():
 
         findings = lint_paths(
             [REPO / "paddle_tpu" / "inference" / "serving.py",
+             REPO / "paddle_tpu" / "inference" / "kv_cache.py",
              REPO / "paddle_tpu" / "ops" / "pallas"
                   / "ragged_paged_attention.py"],
             ALL_RULES, root=REPO)

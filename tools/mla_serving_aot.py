@@ -74,13 +74,13 @@ def main(argv):
                                     sharding=dev)
 
     pvals = tuple(sds(p._value) for p in pred._params)
-    B, npages = eng.B, eng.npages
+    B, npages = eng.B, eng.cache.npages
 
     def caches(rows, counts):
         tbl = jax.ShapeDtypeStruct((rows, npages), jnp.int32, sharding=dev)
         out = [(sds(c), sds(r), tbl) for c, r in eng.pools]
         if counts:
-            out = [c + (sds(n),) for c, n in zip(out, eng._moe_counts)]
+            out = [c + (sds(n),) for c, n in zip(out, eng.cache.counters)]
         return out
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,

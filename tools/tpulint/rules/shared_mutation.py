@@ -38,6 +38,7 @@ from ..project import ClassInfo, Project, ProjectRule
 
 SCOPE = ("observability/", "distributed/checkpoint/",
          "distributed/watchdog.py", "inference/serving.py",
+         "inference/kv_cache.py",
          "inference/router.py", "inference/disagg.py",
          "fleet/elastic/")
 
