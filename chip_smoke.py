@@ -748,10 +748,14 @@ def phase_serve(sz: Sizes) -> None:
         pool_shape = eng.pools[0][0].shape
         for site in [s for s in sites if s[0] in ("decode", "unified")] \
                 + sorted(s for s in sites if s[0] == "prefill")[-1:]:
-            n = eng.pool_copies(eng.compiled_text(site), pool_shape)
+            text = eng.compiled_text(site)
+            n = eng.pool_copies(text, pool_shape)
             check(sz.rehearsal or n == 0,
                   f"compiled program {site}: {n} copies of a whole "
                   f"{'x'.join(map(str, pool_shape))} pool")
+            if site == ("decode",):
+                check_decode_donation(eng, text, 2 * cfg.num_layers)
+        check_overlap(eng, most=not kw)
         kinds = {s[0] for s in sites}
         check(("unified" in kinds) if kw else
               ({"prefill", "decode"} <= kinds),
@@ -760,6 +764,30 @@ def phase_serve(sz: Sizes) -> None:
                         "run_s": round(t_run, 2), "pool_pages": eng.P}
         del eng      # its page pool, before the next engine builds one
     finish_child("serve", device, events, {"modes": report})
+
+
+def check_decode_donation(eng, text: str, n_state: int) -> None:
+    """The decode program writes in place what the cache lent it (the
+    pools, an expert model's counters) and nothing else: its round array
+    (tables, pos, token, mask) is read by every layer, not donated."""
+    donated = eng.donated_params(text)
+    check(len(donated) == n_state
+          and all(n.startswith("state") for n in donated),
+          f"compiled program ('decode',) donates the {n_state} arrays it "
+          f"was lent and not its round array: {donated}")
+
+
+def check_overlap(eng, most: bool = True) -> None:
+    """One decode round in flight: rounds were launched before the round
+    ahead of them was read (every round but the first of a ``run()`` in
+    the default mode; chunked mode overlaps only its runs of pure-decode
+    rounds), and ``run()`` left none unread."""
+    st = eng.overlap_stats()
+    check(st["in_flight"] == 0 and st["rounds"] > 0
+          and (not most or st["overlapped"] * 2 >= st["rounds"]),
+          f"decode rounds launched with the one before unretired: "
+          f"{st['overlapped']} of {st['rounds']}, "
+          f"{st['in_flight']} in flight after run()")
 
 
 def phase_serve_latent(sz: Sizes) -> None:
@@ -850,6 +878,9 @@ def phase_serve_latent(sz: Sizes) -> None:
         check(sz.rehearsal or n == 0,
               f"compiled program {site}: {n} copies of a whole latent or "
               f"rotated-key pool")
+        if site == ("decode",):     # the routing counters ride along
+            check_decode_donation(eng, text, 3 * cfg.num_layers)
+    check_overlap(eng)
     finish_child("serve_latent", device, events,
                  {"setup_s": round(t_setup, 1), "run_s": round(t_run, 2),
                   "pool_pages": eng.P})

@@ -34,7 +34,8 @@ from ..core.bucketing import bucket as _bucket
 from ..core.enforce import enforce
 from ..observability import memledger as _ml
 
-__all__ = ["PagedKVCache", "pool_shapes", "page_bytes"]
+__all__ = ["PagedKVCache", "pool_shapes", "page_bytes", "with_table",
+           "without_table"]
 
 
 def pool_shapes(model, P: int, page: int):
@@ -186,45 +187,55 @@ class PagedKVCache:
         self.tables[b, :] = self.trash
         self.tables[b, :len(pages)] = pages
 
-    def rows(self, b: Optional[int] = None, masked=(),
+    def rows(self, b: Optional[int] = None, only=None,
              extended: bool = False) -> np.ndarray:
         """The block tables a program is bound to: row ``b`` alone
-        (``[1, npages]``) or all of them; rows in ``masked`` read
-        all-trash (a row that rides a round it must not write in);
-        ``extended`` adds the model's ``valid`` contract, one trailing
-        column that ALWAYS maps to the trash page (dead-slot and
-        overdraft writes land there; attention slices it back off)."""
+        (``[1, npages]``) or all of them; with ``only``, every row NOT
+        listed reads all-trash (a row that rides a round it must not
+        write in); ``extended`` adds the model's ``valid`` contract, one
+        trailing column that ALWAYS maps to the trash page (dead-slot
+        and overdraft writes land there; attention slices it back
+        off)."""
         tbl = self.tables if b is None else self.tables[b:b + 1]
-        if len(masked):
-            tbl = tbl.copy()
-            tbl[masked, :] = self.trash
+        if only is not None:
+            tbl = np.full_like(self.tables, self.trash)
+            tbl[only] = self.tables[only]
         if extended:
             tbl = np.concatenate(
                 [tbl, np.full((len(tbl), 1), self.trash, np.int32)],
                 axis=1)
         return tbl
 
-    def bind(self, rows: np.ndarray, draft: bool = False,
-             counters: bool = False) -> List[tuple]:
+    def bind(self, rows: np.ndarray, draft: bool = False) -> List[tuple]:
         """The per-layer ``(a, b, table)`` tuples a compiled program
-        takes (``+ (counter,)`` with ``counters``). One table upload
-        per layer: the cache pytree is DONATED to the program, and XLA
-        rejects donating one buffer twice."""
-        caches = [(a, b, jnp.asarray(rows))
-                  for a, b in (self.draft_pools if draft else self.pools)]
-        if counters and self.counters is not None:
-            caches = [c + (n,) for c, n in zip(caches, self.counters)]
-        return caches
+        takes whole. One table upload per layer: the cache pytree is
+        DONATED to the program, and XLA rejects donating one buffer
+        twice. A program that runs every round takes ``lend()`` and ONE
+        table of its own instead (``with_table``)."""
+        return [(a, b, jnp.asarray(rows))
+                for a, b in (self.draft_pools if draft else self.pools)]
 
     def commit(self, caches: List[tuple], draft: bool = False) -> None:
         """Take back what ``bind`` lent, as the program returned it."""
         pools = [(c[0], c[1]) for c in caches]
         if draft:
             self.draft_pools = pools
-            return
-        self.pools = pools
-        if len(caches[0]) > 3:
-            self.counters = [c[3] for c in caches]
+        else:
+            self.pools = pools
+
+    def lend(self) -> List[tuple]:
+        """What a program that brings its own table is given to donate:
+        per layer ``(a, b)``, ``+ (counter,)`` for a model that keeps
+        device counters beside its pools. No table, so no upload."""
+        if self.counters is None:
+            return list(self.pools)
+        return [p + (n,) for p, n in zip(self.pools, self.counters)]
+
+    def take_back(self, state: List[tuple]) -> None:
+        """Take back what ``lend`` lent, as the program returned it."""
+        self.pools = [(s[0], s[1]) for s in state]
+        if self.counters is not None:
+            self.counters = [s[2] for s in state]
 
     # -- page accounting (ref-counted pool + prefix cache) ---------------
     def available(self) -> int:
@@ -620,6 +631,18 @@ class PagedKVCache:
                                             for l in range(nl)]})
         self.set_row(b, pages)
         return pages
+
+
+def with_table(state: List[tuple], table) -> List[tuple]:
+    """Inside a traced program: the per-layer ``(a, b, table[,
+    counter])`` tuples ``model.forward`` takes, put together from what
+    ``lend`` lent and the ONE table every layer reads."""
+    return [(s[0], s[1], table) + tuple(s[2:]) for s in state]
+
+
+def without_table(caches: List[tuple]) -> List[tuple]:
+    """The inverse: what the program hands back for ``take_back``."""
+    return [(c[0], c[1]) + tuple(c[3:]) for c in caches]
 
 
 def _page_programs():

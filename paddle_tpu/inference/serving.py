@@ -25,6 +25,16 @@ program for arbitrary length mixes):
   (B, pool_bucket) — admissions and evictions never change a compiled
   shape. ``decode_chunk`` fuses that many decode steps into one
   ``lax.scan`` launch; admission/eviction happens at chunk boundaries.
+- ONE DECODE ROUND IS IN FLIGHT: ``step()`` launches round k and only
+  then retires round k-1 (the one blocking fetch), so the device runs a
+  round while the host finishes rows, returns to its caller, admits and
+  prepares the next. The program carries its own feed — the last token
+  of a round and the sampling key stay on the device, a row new to the
+  batch enters through a host token column — and a round's host inputs
+  travel as one array. A row's position and the tokens it has left are
+  known from counts alone, so only an ``eos_token_id`` hit is found a
+  round late (one wasted round, its token dropped). Whoever needs the
+  host's view current retires the round first (``_drain``).
 
 CHUNKED PREFILL (``prefill_chunk``, the Ragged Paged Attention design):
 the per-arrival prefill program above head-of-line-blocks every decode
@@ -116,7 +126,7 @@ from ..observability.spans import (RequestTrace, SpanRing,
                                    parse_traceparent as
                                    _parse_traceparent)
 from ..tensor import Tensor
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, with_table, without_table
 
 __all__ = ["ServingEngine", "ServingRequest"]
 
@@ -173,7 +183,7 @@ class _Slot:
     """Host-side state of one in-flight batch row."""
 
     __slots__ = ("req", "pages", "pos", "state", "fed", "chunks", "seq",
-                 "hashes", "registered", "hit_pages")
+                 "hashes", "registered", "hit_pages", "inflight")
 
     def __init__(self, req: ServingRequest, pages: List[int],
                  state: str = "decode", seq: int = 0):
@@ -183,8 +193,13 @@ class _Slot:
         self.pos = len(req.prompt)
         # chunked-prefill scheduler state: "prefill" while prompt
         # tokens remain unfed, then "decode"; legacy (unchunked) slots
-        # are born "decode" because admission prefills synchronously
+        # are born "decode" because admission prefills synchronously.
+        # "finishing" once the round that brings the row's last token
+        # is launched: it rides no further round and waits for that
+        # one to be retired
         self.state = state
+        # tokens of launched decode rounds the host has not read yet
+        self.inflight = 0
         self.fed = 0            # prompt tokens already written
         self.chunks = 0         # chunks fed (span/telemetry index)
         self.seq = seq          # admission order (scheduler fairness)
@@ -195,6 +210,18 @@ class _Slot:
         self.hashes: Optional[List[int]] = None
         self.registered = 0
         self.hit_pages = 0
+
+
+@dataclass
+class _Round:
+    """One launched decode round the host has not read yet: the device
+    array of its tokens, and per row in it ``(b, slot, tokens it takes,
+    the request's live trace)``."""
+
+    toks: Any
+    rows: List[tuple]
+    t0: float
+    index: int
 
 
 class ServingEngine:
@@ -209,6 +236,19 @@ class ServingEngine:
     ``submit`` only queues; ``step()`` runs one admission + decode round
     (the unit a serving loop would tick), ``run()`` drains everything.
 
+    The ``step()`` contract: it LAUNCHES one decode round and RETIRES
+    the one launched by the call before, so a round's tokens reach
+    ``req.new_tokens`` one call later. A slot whose last round is out
+    reads ``state == "finishing"`` (never ``"decode"`` with tokens to
+    come) and comes free when that round is retired; a ``step()`` with
+    nothing to launch retires what is in flight, and ``run()`` leaves
+    nothing in flight, whether it ran to the end or to ``max_steps``.
+    The drain rule for new code: anything that reads or reshuffles
+    slots, pages or device counters while a round may be outstanding
+    calls ``_drain()`` first. Greedy streams are what a synchronous
+    engine returns; sampled streams (temperature > 0) repeat from the
+    seed but differ from versions that split the key on the host.
+
     The engine is the scheduler: the queue, the slots and the rounds.
     What a page is — geometry, device arrays, tables, allocator, prefix
     map, spill tier, page programs — belongs to ``self.cache``
@@ -218,7 +258,8 @@ class ServingEngine:
     reads it): ``P``, ``slots`` (``pos``, ``req.new_tokens``, ``state``),
     ``queue``, ``finished``, ``num_active``, ``stats``, ``submit``,
     ``step``, ``run``, ``request_traces()``, ``program_sites()``,
-    ``lowered_text(site)``, ``moe_stats()``, ``release_pools()``, and
+    ``lowered_text(site)``, ``moe_stats()``, ``overlap_stats()``,
+    ``release_pools()``, and
     ``pools``, which may be ASSIGNED ``None`` to free the device arrays.
     """
 
@@ -387,6 +428,12 @@ class ServingEngine:
             self._draft_dtype = draft_predictor._params[0]._value.dtype
         self._spec = {"proposed": 0, "accepted": 0, "rounds": 0,
                       "committed": 0}
+        # one decode round in flight: launched, not read yet. The token
+        # feed of the next round stays on the device (_tok_last)
+        self._inflight: Optional[_Round] = None
+        self._tok_last = jnp.zeros((self.B,), jnp.int32)
+        self._t_retired = 0.0
+        self._overlap = {"rounds": 0, "overlapped": 0}
         # the page pool: ONE for the engine's whole lifetime, its shape
         # (on the same bucket lattice as Predictor._paged_caches) keys
         # every compiled program here. Its page programs run through
@@ -622,6 +669,21 @@ class ServingEngine:
                                   if out["rounds"] else 0.0)
         return out
 
+    def overlap_stats(self) -> Dict[str, Any]:
+        """How the decode rounds overlapped the host: rounds launched,
+        how many of them while the round before was still unretired,
+        rounds in flight now (0 after ``run()``), and the median time a
+        retire blocked in its fetch — near zero means the host sets the
+        pace, near the device's round time means the device does. (The
+        median is the process registry's histogram: every engine's.)"""
+        out = dict(self._overlap)
+        out["overlapped_share"] = (out["overlapped"] / out["rounds"]
+                                   if out["rounds"] else 0.0)
+        out["in_flight"] = int(self._inflight is not None)
+        out["fetch_wait_p50_s"] = \
+            self._metrics["fetch_wait"].percentile(50)
+        return out
+
     def _admit_plan(self, req: ServingRequest):
         """Admission plan (allocates nothing for the request; with the
         host tier on it first faults the prompt's spilled pages back,
@@ -674,6 +736,10 @@ class ServingEngine:
             free = [b for b in range(self.B) if self.slots[b] is None]
             if not free:
                 return
+            if self.chunked:
+                # the plan may fault pages in, and the chunk rounds
+                # that follow copy on write and spill
+                self._drain()
             cold, reserve, hits, hashes, fed0 = self._admit_plan(req)
             if cold + reserve > self.cache.available():
                 return                    # head-of-line waits for evictions
@@ -761,7 +827,19 @@ class ServingEngine:
         """One shared compiled decode program for the whole in-flight
         batch: [B] tokens at per-row offsets against the fixed pool,
         ``chunk`` steps fused in one lax.scan. Keyed ONLY on lattice
-        constants — admissions/evictions never change its shape."""
+        constants — admissions/evictions never change its shape.
+
+        The program carries its own feed, so a round can be launched
+        before the one ahead of it has been read: ``state`` is what the
+        cache lends (donated: the pools, and an expert model's
+        counters); ``round_`` is the ONE host array of a round,
+        ``[B, npages + 3]`` int32, not donated and read by every layer
+        — the block tables, then a column each of ``pos``, a host token
+        and a mask; a row starts from its host token where the mask is
+        set (a row new to the batch) and from ``tok_prev``, the last
+        token of the round before, where it is not. The key advances
+        inside and is handed back. Returns ``(toks [B, chunk], the last
+        of them [B], state, key)``."""
         gen = self.gen
         key = (self.B, self.M, self.chunk, gen.temperature, gen.top_k,
                gen.top_p)
@@ -773,7 +851,12 @@ class ServingEngine:
         from ..autograd import no_grad
         from ..distributed.engine import bind_params
 
-        def step(pvals, tok0, caches, pos0, rng):
+        def step(pvals, state, round_, tok_prev, rng):
+            npg = round_.shape[1] - 3
+            table, pos0 = round_[:, :npg], round_[:, npg]
+            tok0 = jnp.where(round_[:, npg + 2] != 0, round_[:, npg + 1],
+                             tok_prev)
+
             def body(carry, _):
                 tok, caches, pos, rng = carry
                 with no_grad(), bind_params(params, pvals):
@@ -786,11 +869,13 @@ class ServingEngine:
                 nxt = _sample(lv[:, -1], sub, gen)
                 return (nxt, caches, pos + 1, rng), nxt
 
-            (_, caches, _, _), toks = lax.scan(
-                body, (tok0, caches, pos0, rng), None, length=chunk)
-            return jnp.swapaxes(toks, 0, 1), caches     # [B, chunk]
+            (tok, caches, _, rng), toks = lax.scan(
+                body, (tok0, with_table(state, table), pos0, rng), None,
+                length=chunk)
+            return (jnp.swapaxes(toks, 0, 1), tok,     # [B, chunk], [B]
+                    without_table(caches), rng)
 
-        self._step_fns[key] = jax.jit(step, donate_argnums=(2,))
+        self._step_fns[key] = jax.jit(step, donate_argnums=(1,))
         return self._step_fns[key]
 
     # -- unified chunked-prefill + decode step ---------------------------
@@ -1038,6 +1123,7 @@ class ServingEngine:
         to spec_tokens+1 (speculative: last token + k draft proposals
         verified in the same dispatch), dead rows ride along at
         seq_len 0 — ONE compiled program, fixed shape."""
+        self._drain()       # it reads and appends every row's tokens
         t0 = time.perf_counter()
         B = self.B
         spec = self._draft is not None
@@ -1211,6 +1297,7 @@ class ServingEngine:
         token yet, so restarting its prefill from scratch is exact.
         The oldest row is never preempted, so it monotonically acquires
         pages and the engine always makes progress."""
+        self._drain()
         rows = [b for b in range(self.B)
                 if self.slots[b] is not None
                 and self.slots[b].state == "prefill"]
@@ -1242,57 +1329,105 @@ class ServingEngine:
         when any are ready (decode rows ride along); otherwise run the
         cheap fused decode scan — except in spec mode, where decode
         rows always take the unified verify path (draft proposals need
-        the [B, Sc] lattice); preempt only when nothing can move."""
+        the [B, Sc] lattice); preempt only when nothing can move. Only
+        runs of pure-decode rounds overlap: a mid-prefill row (its
+        pages are reserved, copied on write and spilled here) or a
+        unified round first retires the decode round in flight."""
+        if any(s is not None and s.state == "prefill" for s in self.slots):
+            self._drain()
         feeders, stalled = self._plan_chunks()
         has_decode = any(s is not None and s.state == "decode"
                          for s in self.slots)
         if feeders or (self._draft is not None and has_decode):
             self._unified_round(feeders)
-        elif has_decode:
-            self._decode_round()
-        elif stalled:
+        elif stalled and not has_decode:
             self._preempt_youngest()
+        else:
+            self._decode_round()    # with no decode row: only retires
 
     def _decode_round(self):
-        active = [b for b in range(self.B) if self.slots[b] is not None
-                  and self.slots[b].state == "decode"]
-        if not active:
-            return
+        """Launch the next decode round, THEN retire the one before it:
+        the device runs round k while the host reads round k-1, finishes
+        its rows, returns to the caller, admits, and prepares round
+        k+1. With no row to launch this only retires."""
+        prev = self._inflight
+        self._inflight = self._launch_round()
+        if prev is not None:
+            self._retire_round(prev)
+
+    def _launch_round(self) -> Optional[_Round]:
+        """Build a round's one host array, dispatch, keep the device
+        arrays that come back; reads no device value. A row's position
+        and the tokens it has left are known from counts alone (what the
+        host holds plus what the unretired round brings), so a row whose
+        last token is on its way is left out, as are free slots and
+        mid-prefill rows: their table rows read all-trash, their writes
+        hit the trash page, their outputs are ignored."""
+        rows = [(b, s) for b, s in enumerate(self.slots)
+                if s is not None and s.state == "decode"]
+        if not rows:
+            return None
         t0 = time.perf_counter()
-        round_traces = [self._live_traces.get(self.slots[b].req.rid)
-                        for b in active]
-        tok = np.zeros((self.B,), np.int32)
-        pos = np.zeros((self.B,), np.int32)
-        for b in active:
-            s = self.slots[b]
-            tok[b] = s.req.new_tokens[-1]
-            pos[b] = s.pos + len(s.req.new_tokens) - 1
-        # free slots ride along at pos 0 with an all-trash table row:
-        # their writes hit the trash page, their outputs are ignored.
-        # In chunked mode, stalled mid-prefill rows ride the same way —
-        # their REAL table rows are masked to all-trash for this round
-        # so the riding write cannot clobber their fed pages
-        mid_prefill = [b for b in range(self.B)
-                       if self.slots[b] is not None
-                       and self.slots[b].state == "prefill"] \
-            if self.chunked else ()
-        caches = self.cache.bind(self.cache.rows(masked=mid_prefill),
-                                 counters=True)
+        npg = self.cache.npages
+        host = np.zeros((self.B, npg + 3), np.int32)
+        host[:, :npg] = self.cache.rows(only=[b for b, _ in rows])
+        taken = []
+        for b, s in rows:
+            req = s.req
+            have = len(req.new_tokens) + s.inflight
+            host[b, npg] = s.pos + have - 1
+            if not s.inflight:
+                # new to the batch, or the pipeline was drained: the
+                # host knows the row's last token and feeds it
+                host[b, npg + 1] = req.new_tokens[-1]
+                host[b, npg + 2] = 1
+            take = min(self.chunk, req.max_new_tokens - have)
+            s.inflight += take
+            if have + take >= req.max_new_tokens:
+                s.state = "finishing"
+            taken.append((b, s, take, self._live_traces.get(req.rid)))
         fn = self._decode_step_fn()
         self.stats.note("serve_decode",
                         (self.B, self.M, self.chunk, self.P,
                          self.gen.temperature, self.gen.top_k,
                          self.gen.top_p, str(self._dtype)))
-        self._rng, sub = jax.random.split(self._rng)
-        toks, caches = self._run_captured(
-            ("decode",), fn, self._pvals(), jnp.asarray(tok), caches,
-            jnp.asarray(pos), sub)
-        self.cache.commit(caches)
-        toks = np.asarray(toks)
+        toks, self._tok_last, state, self._rng = self._run_captured(
+            ("decode",), fn, self._pvals(), self.cache.lend(),
+            jnp.asarray(host), self._tok_last, self._rng)
+        self.cache.take_back(state)
+        overlapped = self._inflight is not None    # still unretired
+        self._overlap["rounds"] += 1
+        self._overlap["overlapped"] += overlapped
+        self._metrics["rounds"].inc(
+            overlapped="true" if overlapped else "false")
+        rnd = _Round(toks, taken, t0, self._round)
+        self._round += 1
+        return rnd
+
+    def _retire_round(self, rnd: _Round):
+        """The one blocking fetch of a decode round, then what the host
+        owes its rows: tokens appended, finished rows evicted, metrics
+        and spans. A row that an ``eos_token_id`` finished a round ago
+        rode this round too (the hit was not known at its launch): its
+        slot is gone or another request's, and its token is dropped."""
+        t_wait = time.perf_counter()
+        toks = np.asarray(rnd.toks)
+        now = time.perf_counter()
+        m = self._metrics
+        m["fetch_wait"].observe(now - t_wait)
         emitted = 0
-        for b in active:
-            req = self.slots[b].req
-            for t in toks[b]:
+        for b, s, take, tr in rnd.rows:
+            if self.slots[b] is not s:
+                continue
+            s.inflight -= take
+            req = s.req
+            if tr is not None:
+                # one "decode_round" span per request per round, launch
+                # to retire (the Chrome export shows the shared rounds
+                # lining up across rids, each overlapping the next)
+                tr.add("decode_round", rnd.t0, now,
+                       {"round": rnd.index, "chunk": self.chunk})
+            for t in toks[b, :take]:
                 t = int(t)
                 req.new_tokens.append(t)
                 emitted += 1
@@ -1303,19 +1438,20 @@ class ServingEngine:
                     break               # rest of the chunk is discarded
         self.stats.count_tokens(("decode", self.B, self.chunk, self.P),
                                 emitted)
-        m = self._metrics
-        now = time.perf_counter()
-        m["decode_round_seconds"].observe(now - t0)
+        # a round's time is what it had of the device: from its launch,
+        # or from the moment the round ahead of it was read
+        m["decode_round_seconds"].observe(
+            now - max(rnd.t0, self._t_retired))
         m["tokens"].inc(emitted, phase="decode")
-        # per-request decode-round spans: each request in flight this
-        # round gets one "decode_round" span on its trace lane (the
-        # Chrome export shows the shared rounds lining up across rids);
-        # round_traces was captured before evictions could retire them
-        for tr in round_traces:
-            if tr is not None:
-                tr.add("decode_round", t0, now,
-                       {"round": self._round, "chunk": self.chunk})
-        self._round += 1
+        self._t_retired = now
+
+    def _drain(self):
+        """Retire the round in flight, if any: whoever reads or
+        reshuffles slots, pages or counters calls this first, so the
+        host's view is current."""
+        rnd, self._inflight = self._inflight, None
+        if rnd is not None:
+            self._retire_round(rnd)
 
     def _finish(self, b: int):
         """Evict a finished row: one reference dropped per page
@@ -1376,6 +1512,7 @@ class ServingEngine:
         host request state; the row is then evicted (pages released,
         slot open for backfill). Delivery framing — crc32 per page,
         wire-byte booking — lives in inference/disagg.py."""
+        self._drain()
         b = next((i for i, s in enumerate(self.slots)
                   if s is not None and s.state == "migrate"
                   and s.req.rid == rid), None)
@@ -1416,6 +1553,7 @@ class ServingEngine:
         happens in inference/disagg.py BEFORE this call."""
         enforce(self.phase != "prefill",
                 "a prefill replica cannot adopt migrated rows")
+        self._drain()
         prompt = np.asarray(pkg["prompt"], np.int64)
         L, n_new = len(prompt), int(pkg["max_new_tokens"])
         free = [b for b in range(self.B) if self.slots[b] is None]
@@ -1458,9 +1596,16 @@ class ServingEngine:
 
     def step(self):
         """One serving tick: admit arrivals, then one shared round —
-        legacy mode prefills each arrival at admission and decodes the
+        legacy mode prefills each arrival at admission (synchronously,
+        dispatched behind the decode round in flight) and decodes the
         batch; chunked mode folds pending prompt chunks and decode rows
-        into the unified dispatch (_chunked_round)."""
+        into the unified dispatch (_chunked_round).
+
+        A decode round is launched here and retired by the NEXT call
+        (class docstring): this call launches round k and then retires
+        round k-1, so the tokens of round k reach ``req.new_tokens``
+        one call later, and a call with nothing to launch retires what
+        is in flight."""
         self._admit()
         if self.chunked:
             self._chunked_round()
@@ -1583,6 +1728,19 @@ class ServingEngine:
             r"^\s*(?:ROOT\s+)?%?copy[\w.\-]*\s*=\s*\w+\["
             + re.escape(dims) + r"\]", hlo_text, re.M))
 
+    @staticmethod
+    def donated_params(hlo_text: str) -> List[str]:
+        """Names of the entry parameters optimized HLO aliases to an
+        output, in parameter order: what the program writes in place.
+        The decode program's are the pools (and an expert model's
+        counters), never its round array."""
+        head = hlo_text.split("\n", 1)[0]
+        idx = sorted({int(n) for n in re.findall(
+            r"\{[\d, ]*\}: \((\d+), ", head)})
+        sig = re.search(r"^ENTRY [^(]*\((.*?)\) -> ", hlo_text, re.M)
+        names = [a.split(":")[0] for a in sig.group(1).split(", ")]
+        return [names[i] for i in idx]
+
     def _lower(self, site):
         prog = self._site_programs.get(site)
         if prog is None:
@@ -1625,6 +1783,7 @@ class ServingEngine:
         its model are still held (a caller that needs the HBM for
         another program over the same weights). The engine serves
         nothing after this."""
+        self._drain()
         self.cache.release()
 
     def moe_stats(self) -> Optional[Dict[str, Any]]:
@@ -1641,6 +1800,7 @@ class ServingEngine:
         None for a model without routed experts."""
         if self.cache.counters is None:
             return None
+        self._drain()
         c = np.stack([np.asarray(a) for a in self.cache.counters]
                      ).astype(np.int64)
         k = int(getattr(self.pred._model.config, "num_experts_per_tok", 0))
@@ -1727,11 +1887,15 @@ class ServingEngine:
 
     def run(self, max_steps: Optional[int] = None
             ) -> Dict[int, ServingRequest]:
-        """Drain the queue + in-flight batch; returns {rid: request}."""
+        """Drain the queue + in-flight batch; returns {rid: request}.
+        Whether it ran to the end or to ``max_steps``, no decode round
+        is left in flight: what ``finished`` and every ``new_tokens``
+        show is current."""
         steps = 0
         while self.queue or self.num_active:
             self.step()
             steps += 1
             if max_steps is not None and steps >= max_steps:
                 break
+        self._drain()
         return self.finished

@@ -27,6 +27,11 @@ SCHEMA_PATH = __file__.rsplit("/", 1)[0] + "/schema.json"
 # chip): denser low end than the generic latency lattice.
 _FAST_BUCKETS = (0.0005, 0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1,
                  0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0)
+# a decode round's fetch wait is read against the round's own time
+# (10-35 ms in the serving cells): a few ms wide where that lies
+_ROUND_BUCKETS = (0.0002, 0.0005, 0.001, 0.002, 0.003, 0.004, 0.006,
+                  0.008, 0.01, 0.012, 0.014, 0.016, 0.018, 0.02, 0.024,
+                  0.028, 0.032, 0.04, 0.05, 0.1, 0.25, 1.0, 5.0)
 
 
 def comm_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
@@ -471,8 +476,22 @@ def serving_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
             buckets=DEFAULT_LATENCY_BUCKETS),
         "decode_round_seconds": r.histogram(
             "paddle_tpu_serving_decode_round_seconds",
-            "one shared chunked decode round for the in-flight batch",
+            "one shared chunked decode round for the in-flight batch: "
+            "from its launch, or from the retire of the round ahead of "
+            "it where that came later, to its own retire",
             unit="s", buckets=DEFAULT_LATENCY_BUCKETS),
+        "rounds": r.counter(
+            "paddle_tpu_serving_rounds_total",
+            "decode rounds launched, by whether the round before was "
+            "still unretired at the launch (overlapped: the device had "
+            "this round queued while the host read the last one)",
+            labelnames=("overlapped",)),
+        "fetch_wait": r.histogram(
+            "paddle_tpu_serving_fetch_wait_seconds",
+            "time a decode round's retire blocked in its one fetch of "
+            "the round's tokens: near zero means the host sets the "
+            "pace, near the device's round time means the device does",
+            unit="s", buckets=_ROUND_BUCKETS),
         "unified_round_seconds": r.histogram(
             "paddle_tpu_serving_unified_round_seconds",
             "one unified mixed prefill-chunk + decode dispatch "

@@ -5,12 +5,14 @@ rotated key) is all a cache needs.
 """
 from types import SimpleNamespace
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from paddle_tpu.core.enforce import PreconditionNotMetError
 from paddle_tpu.inference.kv_cache import (PagedKVCache, page_bytes,
-                                           pool_shapes)
+                                           pool_shapes, with_table,
+                                           without_table)
 
 PAGE = 4
 
@@ -105,26 +107,39 @@ def test_bind_commit_and_rows(model, draft):
     assert all(p[0] is q[0] and p[1] is q[1] for p, q in zip(pools, back))
     assert (c.pools[0][0] is back[0][0]) != draft   # the other set untouched
     assert c.rows(1).tolist() == [[4] + [7] * 7]
-    masked = c.rows(masked=[0])
+    masked = c.rows(only=[1])
     assert (masked[0] == 7).all() and masked[1, 0] == 4
     assert c.tables[0, 0] == 5                      # a copy was masked
+    assert (c.rows(only=[]) == 7).all()
     ext = c.rows(extended=True)
     assert ext.shape == (2, 9) and (ext[:, -1] == 7).all()
 
 
-def test_counters_ride_the_decode_bind():
+def test_counters_ride_what_the_decode_program_is_lent():
+    """``lend`` / ``take_back``: the donated part of the decode program,
+    no table in it; ``with_table`` / ``without_table`` put the ONE table
+    every layer reads in and out inside the program."""
     m = kv_model()
     m.moe_counter_shape = lambda: (2, 5)
     c = PagedKVCache(m, PAGE, 32, 2, np.float32, pool_pages=8)
     assert [n.shape for n in c.counters] == [(5,), (5,)]
-    assert all(len(t) == 3 for t in c.bind(c.rows()))
-    caches = c.bind(c.rows(), counters=True)
-    assert all(len(t) == 4 for t in caches)
-    c.commit([t[:3] + (t[3] + 3,) for t in caches])
+    assert all(len(t) == 3 for t in c.bind(c.rows()))   # (a, b, table)
+    state = c.lend()
+    assert all(len(t) == 3 and t[2].shape == (5,) for t in state)
+    table = jnp.asarray(c.rows())
+    caches = with_table(state, table)
+    assert all(len(t) == 4 and t[2] is table for t in caches)
+    back = without_table([t[:3] + (t[3] + 3,) for t in caches])
+    c.take_back(back)
     assert [int(n.sum()) for n in c.counters] == [15, 15]
-    assert make().counters is None
-    assert all(len(t) == 3 for t in make().bind(make().rows(),
-                                                counters=True))
+    assert all(p[0] is s[0] and p[1] is s[1]
+               for p, s in zip(c.pools, state))
+    plain = make()
+    assert plain.counters is None
+    assert all(len(t) == 2 for t in plain.lend())
+    assert all(len(t) == 3 for t in with_table(plain.lend(), table))
+    plain.take_back(plain.lend())
+    assert plain.counters is None
 
 
 # -- accounting ---------------------------------------------------------------

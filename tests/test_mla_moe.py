@@ -351,3 +351,8 @@ def test_serving_programs_compile_for_a_v5e_in_place():
     for c in progs.values():
         assert c["pool_copies"] == 0 and c["ragged_dot"], c
     assert progs["decode"]["kernel"] and not progs["prefill_1024"]["kernel"]
+    # the pools and the routing counters are donated, the round's one
+    # host array (tables, pos, token, mask) is not
+    donated = progs["decode"]["donated"]
+    assert len(donated) == 2 * 3, donated       # 2 layers x (a, b, counter)
+    assert all(n.startswith("state") for n in donated), donated

@@ -267,10 +267,15 @@ def test_v5e_compile_has_no_pool_sized_copy(form):
         pytest.skip(f"no v5e:2x2 topology here: {res['skipped']}")
     cases = res["cases"]
     assert len(cases) >= 4
+    assert "decode_48x1_round" in [c["case"] for c in cases]
     for c in cases:
         assert c["kernel"] == ("dense" not in c["case"]), c
         if form == "new":
             assert c["pool_copies"] == 0, c
+            # the pools are written in place, and nothing else is: not
+            # the tables, nor the decode program's one round array
+            assert len(c["donated"]) == 4 and all(
+                n.startswith("pools_") for n in c["donated"]), c
             if c["case"].startswith("decode"):  # XLA's own byte count:
                 assert c["bytes_accessed"] < c["pool_bytes"], c  # < 1 pool
         else:

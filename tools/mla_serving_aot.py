@@ -10,7 +10,8 @@ topology, as ``tools/paged_write_aot.py`` does for the page-pool write.
 Per program: pool-shaped ``copy`` ops in the optimized HLO (0 = the
 latent pools are written in place), whether ``mla_paged_decode_attention``
 and XLA's grouped matmul (``ragged-dot``) are in it, and the compiler's
-memory analysis. Prints one JSON line, ``{"programs": [...]}`` or
+memory analysis, and the parameters it aliases to outputs: the pools and
+the routing counters of the decode program, and not its round array. Prints one JSON line, ``{"programs": [...]}`` or
 ``{"skipped": why}``.
 
     python tools/mla_serving_aot.py [--layers 6] [--batch 128] [--dump DIR]
@@ -76,22 +77,21 @@ def main(argv):
     pvals = tuple(sds(p._value) for p in pred._params)
     B, npages = eng.B, eng.cache.npages
 
-    def caches(rows, counts):
-        tbl = jax.ShapeDtypeStruct((rows, npages), jnp.int32, sharding=dev)
-        out = [(sds(c), sds(r), tbl) for c, r in eng.pools]
-        if counts:
-            out = [c + (sds(n),) for c, n in zip(out, eng.cache.counters)]
-        return out
-
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=dev)
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)
+    # the decode program: what the cache lends (pools and counters,
+    # donated), one round array (tables, pos, host token, mask: not
+    # donated), the token feed and the key
+    state = jax.tree_util.tree_map(sds, eng.cache.lend())
     programs = {
         "decode": (eng._decode_step_fn(),
-                   (pvals, i32(B), caches(B, True), i32(B), rng)),
+                   (pvals, state, i32(B, npages + 3), i32(B), rng)),
         f"prefill_{args.prefill}": (
             pred._prefill_fn(1, args.prefill, eng.M),
-            (pvals, i32(1, args.prefill), caches(1, False), i32(1))),
+            (pvals, i32(1, args.prefill),
+             [(sds(c), sds(r), i32(1, npages)) for c, r in eng.pools],
+             i32(1))),
     }
     out = []
     for name, (fn, avals) in programs.items():
@@ -111,6 +111,9 @@ def main(argv):
             "argument_gib": mem.argument_size_in_bytes / 2 ** 30,
             "temp_gib": mem.temp_size_in_bytes / 2 ** 30,
             "alias_gib": mem.alias_size_in_bytes / 2 ** 30,
+            # what the program writes in place: all of what was lent
+            # and, in decode, nothing else
+            "donated": ServingEngine.donated_params(text),
         })
     print(json.dumps({"layers": args.layers, "batch": B, "programs": out}))
     return 0

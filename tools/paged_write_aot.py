@@ -6,7 +6,8 @@ reads the pool, pools donated — the shape of every ``ServingEngine``
 program — and counts the ops of the optimized HLO named ``copy`` whose
 result has the pool's shape. Such a copy is a layout change of the whole
 pool: its bytes scale with the pool, not with the rows written
-(PERF.md, PR 26). Prints one JSON line, ``{"cases": [...]}`` or
+(PERF.md, PR 26). Also lists the parameters the compiled program
+aliases to its outputs: the pools, and nothing else. Prints one JSON line, ``{"cases": [...]}`` or
 ``{"skipped": why}`` where the topology cannot be described. Beside a
 case that runs ``paged_decode_attention``: the KV heads one of its
 fetches brings and the VMEM bytes the kernel counts for that block.
@@ -27,6 +28,10 @@ LAYERS = 2
 # and dtypes at the decode shape
 CASES = [
     ("decode_48x1", 48, 1, 128, "bfloat16", "kernel"),
+    # as the engine's decode program takes them: the tables and the
+    # offsets are columns of ONE [B, npages + 3] array, read by every
+    # layer and not donated
+    ("decode_48x1_round", 48, 1, 128, "bfloat16", "kernel"),
     ("prefill_1x512", 1, 512, 128, "bfloat16", "kernel"),
     ("prefill_1x64", 1, 64, 128, "bfloat16", "kernel"),
     ("prefill_1x2048_dense", 1, 2048, 128, "bfloat16", "dense"),
@@ -80,7 +85,11 @@ def main(argv):
         kernel = {"kernel": "paged_decode_attention",
                   "ragged": "ragged_paged_attention"}.get(attn)
 
+        one_array = name.endswith("_round")
+
         def prog(q, new, pools_, tables, off, nv):
+            if one_array:
+                tables, off = tables[:, :npages], tables[:, npages]
             acc = jnp.zeros(q.shape, jnp.float32)
             done = []
             for kp, vp in pools_:
@@ -102,7 +111,8 @@ def main(argv):
         pool = sds((pools, KV, page, D), dt)
         args = (sds((B, S, H, D), dt), sds((B, S, KV, D), dt),
                 [(pool, pool)] * LAYERS,
-                sds((B, npages + (attn == "ragged")), jnp.int32),
+                sds((B, npages + (attn == "ragged") + 3 * one_array),
+                    jnp.int32),
                 sds((B,), jnp.int32), sds((B,), jnp.int32))
         compiled = jax.jit(prog, donate_argnums=(2,)).lower(*args).compile()
         text = compiled.as_text()
@@ -116,6 +126,7 @@ def main(argv):
             "pool_bytes": pools * KV * page * D * dt.itemsize,
             "bytes_accessed": float(cost.get("bytes accessed", -1)),
             "kernel": kernel is not None and kernel in text,
+            "donated": ServingEngine.donated_params(text),
         })
         if attn == "kernel":    # by the kernel's own count, which picks hb
             hb = da._paged_head_block(S, H // KV, KV, page, D, dt.itemsize)
