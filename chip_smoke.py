@@ -19,6 +19,12 @@ expert decoder (models/mla_moe.py) at its published widths and two
 layers through the same entry points: latent pools written in place,
 ``mla_paged_decode_attention`` in the decode program, 0 dropped pairs.
 
+``--phases serve_hybrid`` (only when named) serves the decoder with
+window and full attention layers (models/hybrid_moe.py) at its published
+widths and three layers the same way: both decode kernels against their
+dense twins on the chip, 40 decode rounds past a ring's wrap, two
+classes of pools written in place.
+
 ``--four-chips`` adds the same train step over a real 2x2 mesh in two
 layouts (mp2 x dp2 on ParallelEngine; pp2 x mp2 on GPTForCausalLMPipe via
 ``fleet.distributed_model(...).train_batch``); asked for, fewer than four
@@ -61,12 +67,13 @@ import time
 ONE_CHIP_PHASES = ("kernels", "train", "serve")
 FOUR_CHIP_PHASES = ("mp2dp2", "pp2mp2")
 # run only when named in --phases: the default three fill their time limit
-EXTRA_PHASES = ("serve_latent",)
+EXTRA_PHASES = ("serve_latent", "serve_hybrid")
 # seconds per child, compilation included. The one-chip three sum to
 # 1100, inside the 1200 s that run is allowed; measured cold on a v5e
 # they took 72, 122 and 106 s (CHANGES.md PR 21).
 PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
-                 "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 400}
+                 "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 400,
+                 "serve_hybrid": 500}
 RESULT_TAG = "CHIP_SMOKE_PHASE_RESULT "
 
 # Tolerances, each with its reason. Every comparison is
@@ -143,6 +150,21 @@ class Sizes:
                 num_experts_per_tok=8, max_position_embeddings=1152,
                 dtype="bfloat16")
             self.latent_batch = 32
+            # serve_hybrid: window and full attention layers at their
+            # published widths (64 heads, keys 192 against values 128,
+            # 8 and 4 KV heads, a window of 128 with a sink), the dense
+            # layer and two expert layers that hold 16 of the router's
+            # 256 experts (1.44B parameters)
+            self.hybrid = dict(
+                vocab_size=19072, hidden_size=4096,
+                attention_kinds=["full", "window", "window"],
+                ffn_kinds=["dense", "experts", "experts"],
+                num_local_experts=16, max_position_embeddings=1152,
+                dtype="bfloat16")
+            self.hybrid_batch = 32
+            # prompts that end just short of a ring's wrap (position 256
+            # at pages of 128, a ring of 2), then 40 decode rounds
+            self.hybrid_lens, self.hybrid_new = (250, 240, 200, 100), 40
         else:
             self.gpt = dict(vocab_size=1024, hidden_size=128,
                             num_layers=2, num_heads=4,
@@ -167,6 +189,18 @@ class Sizes:
                 num_local_experts=4, num_experts_per_tok=4,
                 max_position_embeddings=288, dtype="bfloat16")
             self.latent_batch = 4
+            self.hybrid = dict(
+                vocab_size=512, hidden_size=128,
+                attention_kinds=["full", "window", "window"],
+                ffn_kinds=["dense", "experts", "experts"], num_heads=8,
+                num_kv_heads=2, window_num_kv_heads=4, qk_head_dim=48,
+                v_head_dim=32, rotary_dim=16, sliding_window=16,
+                intermediate_size=256, moe_intermediate_size=64,
+                num_experts=16, num_local_experts=4,
+                num_experts_per_tok=4, max_position_embeddings=288,
+                attention_block=32, dtype="bfloat16")
+            self.hybrid_batch = 4
+            self.hybrid_lens, self.hybrid_new = (30, 28, 20, 10), 12
 
 
 # ---------------------------------------------------------------------------
@@ -886,6 +920,135 @@ def phase_serve_latent(sz: Sizes) -> None:
                   "pool_pages": eng.P})
 
 
+def phase_serve_hybrid(sz: Sizes) -> None:
+    """The decoder with window and full attention layers through
+    ServingEngine in its default mode: both decode kernels agree with
+    their dense twins at the model's own shapes, decode runs past a
+    ring's wrap, two classes of pools are written in place, no routed
+    pair is dropped."""
+    jax, device, events = start_child(sz.rehearsal)
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import (Config, ServingEngine,
+                                      create_predictor)
+    from paddle_tpu.models.hybrid_moe import (HybridMoEConfig,
+                                              HybridMoEForCausalLM)
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    cfg = HybridMoEConfig(**sz.hybrid)
+    page, B = sz.page, sz.hybrid_batch
+    ring = -(-cfg.sliding_window // page) + 1
+    r = np.random.RandomState(0)
+    # the kernels alone, at the shapes the decode program calls them
+    # with: rows below, at and past the window, and at the ring's seam
+    W = cfg.sliding_window
+    lens = np.resize([0, W - 1, W, W + 1, 2 * page - 1, 2 * page,
+                      5 * page + 3], B).astype("int32")
+    for kind, ncols, window in (("full", 6, None), ("window", ring, W)):
+        KV = cfg.kv_heads(kind)
+        P = B * ncols + 1
+        rnd = lambda *shape: jnp.asarray(r.standard_normal(shape),
+                                         jnp.bfloat16)
+        kp = rnd(P, KV, page, cfg.k_cache_width)
+        vp = rnd(P, KV, page, cfg.v_head_dim)
+        tbl = r.permutation(P - 1)[:B * ncols].reshape(B, ncols).astype(
+            "int32")
+        q = rnd(B, 1, cfg.num_heads, cfg.k_cache_width)
+        sk = jnp.asarray(r.standard_normal(cfg.num_heads), jnp.float32) \
+            if cfg.sink(kind) else None
+        kw = dict(scale=cfg.softmax_scale, sinks=sk, window=window)
+        got = da.paged_decode_attention(q, kp, vp, tbl, lens,
+                                        interpret=sz.rehearsal, **kw)
+        want = da.paged_attention_dense(q, kp, vp, tbl, lens, **kw)
+        err = float(jnp.abs(got.astype(jnp.float32)
+                            - want.astype(jnp.float32)).max())
+        check(err <= TOL_ATTN,
+              f"{kind} decode kernel ({cfg.num_heads // KV} query heads a "
+              f"KV head, keys {cfg.k_cache_width} against values "
+              f"{cfg.v_head_dim}, sink {sk is not None}, window {window}) "
+              f"within {TOL_ATTN} of its dense twin (max err {err:.2e})")
+    t0 = time.perf_counter()
+    paddle.set_default_dtype(cfg.dtype)
+    paddle.seed(0)
+    model = HybridMoEForCausalLM(cfg)
+    n_par = sum(int(np.prod(p.shape)) for p in model.parameters())
+    print(f"  attention {cfg.attention_kinds}: {cfg.num_heads} heads, keys "
+          f"{cfg.qk_head_dim} against values {cfg.v_head_dim}, window "
+          f"{cfg.sliding_window}; {cfg.num_local_experts} of "
+          f"{cfg.num_experts} experts held; depth {cfg.num_layers} "
+          f"({n_par / 1e9:.2f}B params); built in "
+          f"{time.perf_counter() - t0:.1f}s", flush=True)
+    pred = create_predictor(
+        Config().set_model(model).enable_paged_kv(page_size=page))
+    mix = [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
+           for n in sz.hybrid_lens]
+    logits = pred.run([mix[0][None, :]])[0]
+    ref_last = logits[0, -1].astype("float32")
+    check(np.isfinite(ref_last).all(), "reference forward: finite logits")
+    eng = ServingEngine(pred, max_batch=B, debug_invariants=True)
+    check((eng.cache.ring, eng.cache.Pw) == (ring, B * ring + 1)
+          and [a.shape[0] for a, _ in eng.pools]
+          == [eng.cache.Pw if w else eng.P
+              for w in eng.cache.window_layers],
+          f"two classes of pages: {eng.P} full, {eng.cache.Pw} window "
+          f"(a ring of {ring} a row)")
+    t0 = time.perf_counter()
+    rids = [eng.submit(p, max_new_tokens=sz.hybrid_new) for p in mix]
+    done = eng.run()
+    t_run = time.perf_counter() - t0
+    outs = [np.asarray(done[rid].new_tokens) for rid in rids if rid in done]
+    check(len(outs) == len(rids)
+          and all(len(o) == sz.hybrid_new for o in outs)
+          and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
+          f"every request returned {sz.hybrid_new} tokens of the "
+          f"vocabulary; the longest context {len(mix[0]) + sz.hybrid_new} "
+          f"wrapped its ring at {ring * page}")
+    tok0 = int(outs[0][0])
+    gap = float(ref_last.max() - ref_last[tok0])
+    check(gap <= TOL_LOGIT,
+          f"first token {tok0} scores within {TOL_LOGIT} of the reference "
+          f"forward's best logit (gap {gap:.3f})")
+    # decode through the ring against one forward over the whole context
+    seq = np.concatenate([mix[0], outs[0][:-1]])
+    full = pred.run([seq[None, :]])[0][0].astype("float32")
+    at = full[len(mix[0]) - 1:]
+    gaps = at.max(-1) - at[np.arange(len(outs[0])), outs[0]]
+    check(float(gaps.max()) <= TOL_LOGIT,
+          f"every served token of the longest request scores within "
+          f"{TOL_LOGIT} of a full forward's best (widest "
+          f"{float(gaps.max()):.3f})")
+    st = eng.moe_stats()
+    check(st["dropped"] == 0 and st["tokens"][-1] > 0,
+          f"expert layers dropped {st['dropped']} routed pairs of "
+          f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
+    c = eng.cache.counts()["classes"]
+    check(c["full"]["used"] == 0 and c["window"]["used"] == 0,
+          f"both classes back to free: {c}")
+    found = kernel_names(eng.lowered_text(("decode",)))
+    # the kernel is jitted on its own: one call site a kind in the text,
+    # however many layers call it
+    check(sz.rehearsal or (
+        found.get("paged_decode_attention", 0) >= 1
+        and found.get("paged_window_decode_attention", 0) >= 1),
+        f"program ('decode',) holds Mosaic calls {found}")
+    shapes = {a.shape for pair in eng.pools for a in pair}
+    for site in [("decode",)] + sorted(
+            s for s in eng.program_sites() if s[0] == "prefill")[-1:]:
+        text = eng.compiled_text(site)
+        n = sum(eng.pool_copies(text, s) for s in shapes)
+        check(sz.rehearsal or n == 0,
+              f"compiled program {site}: {n} copies of a whole pool of "
+              f"either class")
+        if site == ("decode",):     # the routing counters ride along
+            check_decode_donation(eng, text, 3 * cfg.num_layers)
+    check_overlap(eng)
+    finish_child("serve_hybrid", device, events,
+                 {"run_s": round(t_run, 2), "pool_pages": eng.P,
+                  "window_pool_pages": eng.cache.Pw})
+
+
 # ---------------------------------------------------------------------------
 # parent: children, in order, one at a time; never imports JAX
 # ---------------------------------------------------------------------------
@@ -954,6 +1117,8 @@ def main(argv=None) -> int:
                 phase_serve(sz)
             elif args.phase == "serve_latent":
                 phase_serve_latent(sz)
+            elif args.phase == "serve_hybrid":
+                phase_serve_hybrid(sz)
             else:
                 phase_train(sz, args.phase)
         except Failed as e:

@@ -353,8 +353,15 @@ class Predictor:
         bucket lattice, every mix whose page demand lands in the same
         bucket reuses the same compiled programs (the extra pages are
         never referenced by any table entry below the trash id)."""
-        from .kv_cache import PagedKVCache
+        from ..core.enforce import enforce
+        from .kv_cache import PagedKVCache, page_classes
 
+        enforce(not page_classes(self._model)[1],
+                "Predictor.generate over the paged cache hands prefill "
+                "and decode one table a layer, and a model with window "
+                "layers needs two (a logical one to prefill through, its "
+                "ring to decode through): serve it through "
+                "ServingEngine, or generate over the static cache")
         need = [-(-(int(l) + n_new) // page) for l in lengths]
         cache = PagedKVCache(self._model, page, M, len(lengths), dtype,
                              pool_pages=sum(need) + 1)  # +1 trash page

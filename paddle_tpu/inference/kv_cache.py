@@ -34,8 +34,8 @@ from ..core.bucketing import bucket as _bucket
 from ..core.enforce import enforce
 from ..observability import memledger as _ml
 
-__all__ = ["PagedKVCache", "pool_shapes", "page_bytes", "with_table",
-           "without_table"]
+__all__ = ["PagedKVCache", "pool_shapes", "page_bytes", "page_classes",
+           "with_table", "without_table"]
 
 
 def pool_shapes(model, P: int, page: int):
@@ -52,11 +52,31 @@ def pool_shapes(model, P: int, page: int):
     return [(shape, shape)] * cfg.num_layers
 
 
-def page_bytes(model, page: int, dtype) -> int:
-    """Bytes one page takes over every layer's two pooled arrays."""
+def page_classes(model) -> Tuple[List[bool], Optional[int]]:
+    """(per layer: is it a window layer, the window or None). A model
+    says so itself (``kv_page_classes()``: per layer ``"full"`` — a row
+    holds a page for every page of its context — or ``("window", n)`` —
+    it only ever reads its last ``n`` positions); without that every
+    layer is full."""
+    fn = getattr(model, "kv_page_classes", None)
+    if fn is None:
+        return [False] * len(pool_shapes(model, 1, 1)), None
+    classes = list(fn())
+    windows = {c[1] for c in classes if c != "full"}
+    enforce(len(windows) <= 1,
+            f"one window class a model: its layers name {sorted(windows)}")
+    return [c != "full" for c in classes], \
+        (int(windows.pop()) if windows else None)
+
+
+def page_bytes(model, page: int, dtype, window: bool = False) -> int:
+    """Bytes one page takes over the two pooled arrays of every layer
+    of its class (``window``: the window layers; else the full ones,
+    which without ``kv_page_classes`` is every layer)."""
+    mask, _ = page_classes(model)
     return sum(int(np.prod(a)) + int(np.prod(b))
-               for a, b in pool_shapes(model, 1, page)) \
-        * np.dtype(dtype).itemsize
+               for (a, b), w in zip(pool_shapes(model, 1, page), mask)
+               if w == window) * np.dtype(dtype).itemsize
 
 
 def _payload_nbytes(payload) -> int:
@@ -79,6 +99,24 @@ class PagedKVCache:
     ``metrics`` the serving instrument set the prefix and spill events
     are counted on — both optional: a cache alone counts in
     ``prefix_stats()`` / ``spill_stats()`` only.
+
+    TWO CLASSES OF PAGES for a model with window layers
+    (``page_classes``). The full class is everything above: the pool
+    ``P``, ``tables``, the allocator. The window class has its own
+    arrays (only the window layers have them, ``[Pw, ...]``), its own
+    page ids ``0 .. Pw - 2`` with a trash page ``Pw - 1``, its own free
+    list, and a ``[B, ring]`` table, ``ring = ceil(window / page) + 1``:
+    a row takes its ring at admission (``take_ring``), keeps it for life
+    and gives it back in ``release_row``; position ``pos`` lives in ring
+    column ``(pos // page) % ring``, over what was ``ring`` pages back,
+    which the window no longer reaches. ``Pw = B * ring + 1`` always (a
+    row of the batch can never be short of a ring). A program is bound
+    the table of each layer's class (``bind`` / ``layer_tables``). The
+    prefix cache, the spill tier, copy-on-write, export / import and a
+    draft's pools stay full-class only and are REFUSED for such a model
+    (``refuse_windowed``): a page shared or moved at position p would
+    need the window layers' last keys at p, which the ring has
+    overwritten.
     """
 
     def __init__(self, model, page: int, max_length: int, max_batch: int,
@@ -91,6 +129,17 @@ class PagedKVCache:
         self.B = int(max_batch)
         self.dtype = dtype
         self.page_bytes = page_bytes(model, self.page, dtype)
+        # the window class: fixed by the batch, never sized from memory
+        self.window_layers, self.window = page_classes(model)
+        self.ring = self.Pw = self.window_page_bytes = 0
+        if self.window:
+            self.ring = -(-self.window // self.page) + 1
+            self.Pw = self.B * self.ring + 1
+            self.window_page_bytes = page_bytes(model, self.page, dtype,
+                                                window=True)
+            self.refuse_windowed(spill_pages, "the host spill tier")
+            self.refuse_windowed(draft is not None, "a draft's pools")
+            resident_bytes += self.Pw * self.window_page_bytes
         # one pool for the owner's whole lifetime, on the power-of-two
         # bucket lattice: the compiled programs are keyed on this shape
         # and NEVER change it. "auto" sizes it from measured HBM
@@ -108,6 +157,10 @@ class PagedKVCache:
         self.P = _bucket(int(want), lo=8)
         self.trash = self.P - 1
         self.shapes = pool_shapes(model, self.P, self.page)
+        if self.window:
+            self.shapes = [
+                ((self.Pw,) + a[1:], (self.Pw,) + b[1:]) if w else (a, b)
+                for (a, b), w in zip(self.shapes, self.window_layers)]
         self.pools = [(jnp.zeros(a, dtype), jnp.zeros(b, dtype))
                       for a, b in self.shapes]
         self.draft_pools = None
@@ -128,6 +181,10 @@ class PagedKVCache:
             self.counters = [jnp.zeros(row, jnp.int32)
                              for _ in range(layers)]
         self.tables = np.full((self.B, self.npages), self.trash, np.int32)
+        self.wtrash = self.Pw - 1
+        self.wtables = np.full((self.B, self.ring), self.wtrash, np.int32)
+        self._wfree = list(range(self.Pw - 1))
+        self._rings: Dict[int, List[int]] = {}      # table row -> ring
         # Pages become ref-counted and content-addressable. _hash_page
         # maps the rolling prompt-prefix hash of a COMPLETED
         # page-aligned chunk to the physical page that holds its KV;
@@ -172,13 +229,59 @@ class PagedKVCache:
     def pages_for(self, tokens: int) -> int:
         return -(-tokens // self.page)
 
-    def pool_bytes(self) -> int:
+    def pool_bytes(self, window: Optional[bool] = None) -> int:
+        """Bytes of the pooled arrays: every layer's, or one class's."""
         return sum(_ml.shard_bytes(a) + _ml.shard_bytes(b)
-                   for a, b in self.pools)
+                   for (a, b), w in zip(self.pools, self.window_layers)
+                   if window is None or w == window)
 
     def release(self) -> None:
         """Give the device arrays back; the cache serves nothing after."""
         self.pools = self.draft_pools = None
+
+    def refuse_windowed(self, asked, what: str) -> None:
+        """``what`` works on full-class pages alone: refused, with the
+        reason, for a model that has window layers."""
+        enforce(not (self.window and asked),
+                f"{what} cannot serve a model with window layers: its "
+                f"window layers keep a ring of {self.ring} pages a row, "
+                f"the last {self.window} positions and no more, so a page "
+                "shared, spilled, copied or moved at position p would "
+                "need the window layers' keys up to p, which the ring "
+                "has overwritten")
+
+    # -- the window class: a ring of pages a row -------------------------
+    def rings_available(self) -> bool:
+        """Whether one more row can take its ring (always, without
+        window layers)."""
+        with self._lock:
+            return len(self._wfree) >= self.ring
+
+    def take_ring(self, b: int) -> List[int]:
+        """Table row b takes its ring of window pages, for life."""
+        with self._lock:
+            enforce(b not in self._rings and len(self._wfree) >= self.ring,
+                    f"table row {b} holds a ring already, or the window "
+                    "class is out of pages")
+            ring = [self._wfree.pop() for _ in range(self.ring)]
+            self._rings[b] = ring
+        self.wtables[b, :] = ring
+        return ring
+
+    def window_prefill_rows(self, b: int, length: int
+                            ) -> Optional[np.ndarray]:
+        """The LOGICAL table ``[1, npages]`` a prefill of ``length``
+        tokens writes row b's window layers through: the prompt's last
+        ``ring`` pages map to their ring columns, every other page (the
+        older ones the window has left, the bucket's padding) to the
+        trash page. None without window layers."""
+        if not self.window:
+            return None
+        out = np.full((1, self.npages), self.wtrash, np.int32)
+        last = (length - 1) // self.page
+        for l in range(max(0, last - self.ring + 1), last + 1):
+            out[0, l] = self.wtables[b, l % self.ring]
+        return out
 
     # -- tables, bind / commit -------------------------------------------
     def set_row(self, b: int, pages: List[int]) -> None:
@@ -206,14 +309,33 @@ class PagedKVCache:
                 axis=1)
         return tbl
 
-    def bind(self, rows: np.ndarray, draft: bool = False) -> List[tuple]:
+    def window_rows(self, only=None) -> np.ndarray:
+        """The ring tables ``[B, ring]`` (``[B, 0]`` without window
+        layers); with ``only``, every row NOT listed reads all-trash, as
+        in ``rows``."""
+        if only is None:
+            return self.wtables
+        tbl = np.full_like(self.wtables, self.wtrash)
+        tbl[only] = self.wtables[only]
+        return tbl
+
+    def bind(self, rows: np.ndarray, draft: bool = False,
+             wrows: Optional[np.ndarray] = None) -> List[tuple]:
         """The per-layer ``(a, b, table)`` tuples a compiled program
-        takes whole. One table upload per layer: the cache pytree is
+        takes whole: ``rows`` for a full layer, ``wrows`` for a window
+        layer. One table upload per layer: the cache pytree is
         DONATED to the program, and XLA rejects donating one buffer
         twice. A program that runs every round takes ``lend()`` and ONE
         table of its own instead (``with_table``)."""
-        return [(a, b, jnp.asarray(rows))
-                for a, b in (self.draft_pools if draft else self.pools)]
+        if not self.window:
+            return [(a, b, jnp.asarray(rows))
+                    for a, b in (self.draft_pools if draft else self.pools)]
+        return [(a, b, jnp.asarray(wrows if w else rows))
+                for (a, b), w in zip(self.pools, self.window_layers)]
+
+    def layer_tables(self, table, wtable):
+        """Inside a traced program: per layer, the table of its class."""
+        return [wtable if w else table for w in self.window_layers]
 
     def commit(self, caches: List[tuple], draft: bool = False) -> None:
         """Take back what ``bind`` lent, as the program returned it."""
@@ -306,9 +428,16 @@ class PagedKVCache:
                     self._free_pages.append(pg)
 
     def release_row(self, b: int, pages: List[int]) -> None:
-        """Evict table row b: its pages released, the row all-trash."""
+        """Evict table row b: its pages released (and its ring of window
+        pages, if it holds one), the row all-trash."""
         self.release_pages(pages)
         self.set_row(b, [])
+        with self._lock:
+            ring = self._rings.pop(b, None)
+            if ring is not None:
+                self._wfree.extend(ring)
+        if ring is not None:
+            self.wtables[b, :] = self.wtrash
 
     def register(self, h: int, pg: int) -> None:
         """Publish a completed page under its prefix hash. First
@@ -381,12 +510,23 @@ class PagedKVCache:
             self._metrics["prefix_events"].inc(n, event=event)
 
     def counts(self) -> Dict[str, Any]:
-        """The occupancy gauges' counts, from one look under the lock."""
+        """The occupancy gauges' counts, from one look under the lock:
+        the full class at the top, and ``classes`` with pages used and
+        free of each class the cache has."""
         with self._lock:
             lk = self._pfx["lookups"]
-            return {"free": len(self._free_pages), "idle": len(self._lru),
-                    "registered": len(self._page_hash),
-                    "hit_rate": self._pfx["hits"] / lk if lk else 0.0}
+            free = len(self._free_pages)
+            out = {"free": free, "idle": len(self._lru),
+                   "registered": len(self._page_hash),
+                   "hit_rate": self._pfx["hits"] / lk if lk else 0.0,
+                   "classes": {"full": {
+                       "used": self.usable - free - len(self._lru),
+                       "free": free}}}
+            if self.window:
+                wfree = len(self._wfree)
+                out["classes"]["window"] = {
+                    "used": self.Pw - 1 - wfree, "free": wfree}
+            return out
 
     def prefix_stats(self) -> Dict[str, Any]:
         """Host-side prefix-cache counters: page lookups/hits at
@@ -400,17 +540,37 @@ class PagedKVCache:
             out["idle_pages"] = len(self._lru)
             return out
 
-    def check_invariants(self, held_pages: Iterable[List[int]]) -> None:
+    def check_invariants(self, held_pages: Iterable[List[int]],
+                         live_rows: Optional[Iterable[int]] = None) -> None:
         """Pool-accounting invariant (the free-list hardening gate):
         free list, idle (LRU) pages, and refcounted live pages
         partition the usable pool exactly; every page's refcount
         equals the number of page lists in ``held_pages`` (one per
         live row) holding it; the hash<->page maps stay bijective.
-        Raises on any violation — double free, leak, or refcount
-        drift."""
+        With window layers: the free window pages and the rows' rings
+        partition that class, a row's ring is what its table row names,
+        and (given ``live_rows``, the table rows in use) exactly those
+        rows hold a ring. Raises on any violation — double free, leak,
+        or refcount drift."""
         held = Counter(pg for pages in held_pages for pg in pages)
         with self._lock:
             bad: List[str] = []
+            if self.window:
+                rung = [pg for r in self._rings.values() for pg in r]
+                if sorted(rung + self._wfree) != list(range(self.Pw - 1)):
+                    bad.append(
+                        f"window class: free({len(self._wfree)}) + rings"
+                        f"({len(rung)}) do not partition its "
+                        f"{self.Pw - 1} pages (a leaked or doubly held "
+                        "ring page)")
+                if any(list(self.wtables[b]) != r
+                       for b, r in self._rings.items()):
+                    bad.append("a window table row is not its row's ring")
+                if live_rows is not None and \
+                        set(live_rows) != set(self._rings):
+                    bad.append(
+                        f"rows holding a ring {sorted(self._rings)} != "
+                        f"rows in use {sorted(set(live_rows))}")
             usable = self.P - 1
             free, lru = list(self._free_pages), list(self._lru)
             fs, ls = set(free), set(lru)
@@ -502,6 +662,7 @@ class PagedKVCache:
         """A private copy of a shared page (device-side copy into a
         freshly allocated page); the reference on the shared original
         is dropped. Returns the new page."""
+        self.refuse_windowed(True, "copy-on-write of a shared page")
         [new] = self.allocate(1)
         self.copy_page(old, new)
         self.release_pages([old])
@@ -595,6 +756,8 @@ class PagedKVCache:
     def check_stackable(self) -> None:
         """A page migrates as ONE stacked array of every layer's two
         pooled arrays; pools of two shapes cannot be stacked."""
+        self.refuse_windowed(True, "the migration of a row's pages "
+                             "(export / import, the disaggregated phases)")
         a, b = self.shapes[0]
         enforce(all(x == y for x, y in self.shapes),
                 "the disaggregated phases migrate a page as ONE stacked "
@@ -636,7 +799,11 @@ class PagedKVCache:
 def with_table(state: List[tuple], table) -> List[tuple]:
     """Inside a traced program: the per-layer ``(a, b, table[,
     counter])`` tuples ``model.forward`` takes, put together from what
-    ``lend`` lent and the ONE table every layer reads."""
+    ``lend`` lent and the ONE table every layer reads (or a list, the
+    table of each layer's class: ``PagedKVCache.layer_tables``)."""
+    if isinstance(table, list):
+        return [(s[0], s[1], t) + tuple(s[2:])
+                for s, t in zip(state, table)]
     return [(s[0], s[1], table) + tuple(s[2:]) for s in state]
 
 

@@ -126,7 +126,8 @@ from ..observability.spans import (RequestTrace, SpanRing,
                                    parse_traceparent as
                                    _parse_traceparent)
 from ..tensor import Tensor
-from .kv_cache import PagedKVCache, with_table, without_table
+from .kv_cache import (PagedKVCache, page_classes, with_table,
+                       without_table)
 
 __all__ = ["ServingEngine", "ServingRequest"]
 
@@ -300,6 +301,34 @@ class ServingEngine:
         # lo=page gives both), so chunk frontiers land on page
         # boundaries and the compiled shape never varies
         self.chunked = prefill_chunk is not None
+        # a model with window layers keeps a ring of pages a row for
+        # them (kv_cache.PagedKVCache): what feeds a row in pieces or
+        # shares, copies or moves its pages is refused, with its reason
+        _, window = page_classes(predictor._model)
+        if window:
+            ring = f"the last {window} positions of a row and no more"
+            enforce(not self.chunked,
+                    "prefill_chunk (the unified ragged step) cannot serve "
+                    "a model with window layers: a chunk's rows attend "
+                    "through the block table to what earlier chunks "
+                    f"wrote, and a window layer's ring holds {ring}, "
+                    "addressed by position and not by table column; "
+                    "serve it in the default mode (bucketed prefill)")
+            enforce(draft_predictor is None and not spec_tokens,
+                    "speculative decoding cannot serve a model with "
+                    "window layers: a rejected draft token has already "
+                    f"overwritten a ring slot ({ring}), and the verify "
+                    "step rides the unified ragged step")
+            enforce(phase is None,
+                    "the disaggregated phases cannot serve a model with "
+                    "window layers: a row migrates as its full-class "
+                    "pages, and its window layers' ring "
+                    f"({ring}) is not among them; run unified replicas")
+            enforce(not prefix_cache and not host_spill_pages,
+                    "the prefix cache (and its host spill tier) cannot "
+                    "serve a model with window layers: a hit at position "
+                    "p would need the window layers' keys before p, and "
+                    f"the donor's ring holds {ring}")
         if self.chunked:
             enforce(int(prefill_chunk) >= 1, "prefill_chunk must be >= 1")
             self.Sc = min(_bucket(int(prefill_chunk), lo=self.page),
@@ -645,7 +674,9 @@ class ServingEngine:
         """The cache's pool-accounting invariant against the pages this
         engine's slots hold (``PagedKVCache.check_invariants``)."""
         self.cache.check_invariants(
-            s.pages for s in self.slots if s is not None)
+            (s.pages for s in self.slots if s is not None),
+            live_rows=[b for b, s in enumerate(self.slots)
+                       if s is not None])
 
     def prefix_cache_stats(self) -> Dict[str, Any]:
         """Host-side prefix-cache counters: page lookups/hits at
@@ -741,7 +772,8 @@ class ServingEngine:
                 # that follow copy on write and spill
                 self._drain()
             cold, reserve, hits, hashes, fed0 = self._admit_plan(req)
-            if cold + reserve > self.cache.available():
+            if cold + reserve > self.cache.available() \
+                    or not self.cache.rings_available():
                 return                    # head-of-line waits for evictions
             self.queue.popleft()
             b = free[0]
@@ -751,6 +783,8 @@ class ServingEngine:
             self.cache.pin(hits)          # BEFORE any reclaim can run
             pages = list(hits) + self.cache.allocate(cold)
             self.cache.set_row(b, pages)
+            if self.cache.window:
+                self.cache.take_ring(b)
             slot = _Slot(
                 req, pages, state="prefill" if self.chunked else "decode",
                 seq=self._admit_seq)
@@ -795,7 +829,9 @@ class ServingEngine:
         Sb = min(_bucket(L), self.M)
         ids = np.zeros((1, Sb), np.int32)
         ids[0, :L] = req.prompt
-        caches = self.cache.bind(self.cache.rows(b))
+        caches = self.cache.bind(
+            self.cache.rows(b),
+            wrows=self.cache.window_prefill_rows(b, L))
         fn = self.pred._prefill_fn(1, Sb, self.M)
         self.stats.note("prefill", (1, Sb, self.M, self.page, self.P,
                                     str(ids.dtype), str(self._dtype)))
@@ -815,9 +851,11 @@ class ServingEngine:
         m["tokens"].inc(1, phase="prefill")
         tr = self._live_traces.get(req.rid)
         if tr is not None:
-            tr.add("prefill", t0, now, {"seq_bucket": Sb})
+            held = {"full_pages": len(slot.pages),
+                    "window_pages": self.cache.ring}
+            tr.add("prefill", t0, now, {"seq_bucket": Sb, **held})
             m["stage_seconds"].observe(now - t0, stage="prefill")
-            tr.begin("decode", now)    # closed at eviction
+            tr.begin("decode", now, held)    # closed at eviction
         if len(req.new_tokens) >= req.max_new_tokens or \
                 (req.eos_token_id is not None and tok0 == req.eos_token_id):
             self._finish(b)
@@ -833,8 +871,10 @@ class ServingEngine:
         before the one ahead of it has been read: ``state`` is what the
         cache lends (donated: the pools, and an expert model's
         counters); ``round_`` is the ONE host array of a round,
-        ``[B, npages + 3]`` int32, not donated and read by every layer
-        — the block tables, then a column each of ``pos``, a host token
+        ``[B, npages + 3]`` int32 (``[B, npages + ring + 3]`` for a
+        model with window layers), not donated and read by every layer
+        — the block tables (then the ring tables), then a column each
+        of ``pos``, a host token
         and a mask; a row starts from its host token where the mask is
         set (a row new to the batch) and from ``tok_prev``, the last
         token of the round before, where it is not. The key advances
@@ -846,7 +886,7 @@ class ServingEngine:
         if key in self._step_fns:
             return self._step_fns[key]
         model, params = self.pred._model, self.pred._params
-        chunk = self.chunk
+        chunk, cache = self.chunk, self.cache
         from . import _sample
         from ..autograd import no_grad
         from ..distributed.engine import bind_params
@@ -856,6 +896,9 @@ class ServingEngine:
             table, pos0 = round_[:, :npg], round_[:, npg]
             tok0 = jnp.where(round_[:, npg + 2] != 0, round_[:, npg + 1],
                              tok_prev)
+            if cache.window:
+                table = cache.layer_tables(
+                    table[:, :cache.npages], table[:, cache.npages:])
 
             def body(carry, _):
                 tok, caches, pos, rng = carry
@@ -1368,9 +1411,13 @@ class ServingEngine:
         if not rows:
             return None
         t0 = time.perf_counter()
-        npg = self.cache.npages
+        riding = [b for b, _ in rows]
+        npg = self.cache.npages + self.cache.ring
         host = np.zeros((self.B, npg + 3), np.int32)
-        host[:, :npg] = self.cache.rows(only=[b for b, _ in rows])
+        host[:, :self.cache.npages] = self.cache.rows(only=riding)
+        if self.cache.ring:
+            host[:, self.cache.npages:npg] = \
+                self.cache.window_rows(only=riding)
         taken = []
         for b, s in rows:
             req = s.req
@@ -1444,6 +1491,13 @@ class ServingEngine:
             now - max(rnd.t0, self._t_retired))
         m["tokens"].inc(emitted, phase="decode")
         self._t_retired = now
+        live = [s for s in self.slots if s is not None]
+        if live:
+            cache = self.cache
+            m["kv_bytes_per_token"].set(
+                (sum(len(s.pages) for s in live) * cache.page_bytes
+                 + len(live) * cache.ring * cache.window_page_bytes)
+                / sum(s.pos + len(s.req.new_tokens) for s in live))
 
     def _drain(self):
         """Retire the round in flight, if any: whoever reads or
@@ -1627,6 +1681,9 @@ class ServingEngine:
         # reports pages slots actually hold, not cache residue
         m["page_occupancy"].set(
             (usable - n_free - n_idle) / usable if usable else 0.0)
+        for cls, n in c["classes"].items():
+            m["kv_pages"].set(n["used"], **{"class": cls, "state": "used"})
+            m["kv_pages"].set(n["free"], **{"class": cls, "state": "free"})
         if self.prefix:
             m["prefix_hit_rate"].set(c["hit_rate"])
             m["prefix_pages"].set(n_reg - n_idle, state="active")
@@ -1774,6 +1831,12 @@ class ServingEngine:
                 "kv_pool_bytes": self.cache.pool_bytes(),
                 "page_bytes": self.cache.page_bytes,
                 "pool_pages": self.P,
+                # a model with window layers: kv_pool_bytes ==
+                # page_bytes * pool_pages
+                # + window_page_bytes * window_pool_pages
+                "window_page_bytes": self.cache.window_page_bytes,
+                "window_pool_pages": self.cache.Pw,
+                "window_ring": self.cache.ring,
                 "live_peak_bytes": self._live_peak,
             },
         }

@@ -521,6 +521,17 @@ def serving_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
             "paddle_tpu_serving_page_occupancy",
             "fraction of the physical page pool in use (trash page "
             "excluded)"),
+        "kv_pages": r.gauge(
+            "paddle_tpu_serving_kv_pages",
+            "physical KV pages by page class (full: a page for every "
+            "page of a row's context; window: a window layer's ring of "
+            "pages a row) and state (used by live rows / free)",
+            labelnames=("class", "state")),
+        "kv_bytes_per_token": r.gauge(
+            "paddle_tpu_serving_kv_bytes_per_context_token",
+            "pool bytes the live rows hold (their pages of every class, "
+            "written or not) over the context tokens they have so far, "
+            "at the last retired decode round", unit="By"),
         "requests": r.counter(
             "paddle_tpu_serving_requests_total",
             "request lifecycle events: submitted / admitted / "

@@ -1,0 +1,73 @@
+"""Causal self-attention over a prompt in blocks of rows and keys, with
+a running maximum and sum: no ``[H, S, S]`` array is ever built, a q
+block meets only the key blocks it can see (its band alone on a window
+layer), q/k heads may be wider than v heads, and a learned sink logit a
+head may join the denominator. Plain ``lax`` (XLA fuses each block's
+mask, exponent and sums; the two products are batched matmuls), every
+slice static: what a prefill program of a model with 192-wide keys
+against 128-wide values takes, where the flash kernel wants one head
+size.
+"""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax.numpy as jnp
+
+__all__ = ["blockwise_causal_attention"]
+
+_NEG = -1e30
+
+
+def blockwise_causal_attention(q, k, v, scale: float,
+                               window: Optional[int] = None, sinks=None,
+                               block: int = 512):
+    """q [B, S, H, D] against k [B, S, KV, D] and v [B, S, KV, Dv], all at
+    positions 0..S-1; row i sees keys j <= i and, with ``window``, also
+    i - j < window. ``sinks [H]``: ``p_ij = exp(s_ij) / (sum_j exp(s_ij)
+    + exp(sink_h))``. Scores and sums in float32, the probabilities cast
+    to v's type for the second product. Returns [B, S, H, Dv] in q's
+    type."""
+    B, S, H, D = q.shape
+    KV, Dv = k.shape[2], v.shape[-1]
+    G = H // KV
+    q5 = q.reshape(B, S, KV, G, D)
+    if sinks is not None:
+        sk = jnp.asarray(sinks, jnp.float32).reshape(1, KV, G, 1, 1)
+    outs = []
+    for a in range(0, S, block):
+        e = min(a + block, S)
+        qb = q5[:, a:e]
+        lo = 0 if window is None else max(0, a - window + 1)
+        # a window layer's band for these rows is one key block
+        step = block if window is None else e - lo
+        m = jnp.full((B, KV, G, e - a, 1), _NEG, jnp.float32)
+        l = jnp.zeros((B, KV, G, e - a, 1), jnp.float32)
+        acc = jnp.zeros((B, KV, G, e - a, Dv), jnp.float32)
+        qpos = jnp.arange(a, e, dtype=jnp.int32)[:, None]
+        for c in range(lo, e, step):
+            ce = min(c + step, e)
+            s = jnp.einsum("bqkgd,bmkd->bkgqm", qb, k[:, c:ce],
+                           preferred_element_type=jnp.float32) * scale
+            kpos = jnp.arange(c, ce, dtype=jnp.int32)[None]
+            keep = kpos <= qpos
+            if window is not None:
+                keep = keep & (kpos > qpos - window)
+            s = jnp.where(keep, s, _NEG)
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p.sum(-1, keepdims=True)
+            acc = acc * corr + jnp.einsum(
+                "bkgqm,bmkd->bkgqd", p.astype(v.dtype), v[:, c:ce],
+                preferred_element_type=jnp.float32)
+            m = m_new
+        if sinks is not None:
+            m_fin = jnp.maximum(m, sk)
+            corr = jnp.exp(m - m_fin)
+            l = l * corr + jnp.exp(sk - m_fin)
+            acc = acc * corr
+        o = acc / l                                  # [B, KV, G, rows, Dv]
+        outs.append(jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(
+            B, e - a, H, Dv).astype(q.dtype))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)

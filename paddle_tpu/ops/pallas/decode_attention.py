@@ -30,6 +30,11 @@ row's frontier (``offset`` + new tokens, a SCALAR-PREFETCH input):
   the mask that keeps a row to positions <= its own also keeps it to
   its own head's columns, so the MXU sees two large products, not
   ``2*hb`` of four rows.
+  K and V may differ in width (the result is V's), a learned SINK logit
+  a query head may join the softmax's denominator, and with ``window=``
+  (kernel name ``paged_window_decode_attention``) the table is a ring of
+  pages a row and the loop walks only the one or two that intersect the
+  row's last ``window`` positions, masked by position.
 - GQA is native: the q heads of one KV group form the sublane axis of a
   single [Sq*G, D] block, so the cache is read once per KV head (the
   dense fallback repeats it per q head).
@@ -59,7 +64,8 @@ from . import (_BLOCKS_LARGE as _BLOCKS, compiler_params as
                _compiler_params, pick_block as _pick_block)
 
 __all__ = ["decode_attention", "paged_decode_attention",
-           "paged_attention_dense", "paged_supported", "paged_kv_write"]
+           "paged_attention_dense", "paged_supported", "paged_kv_write",
+           "attention_dense_masked"]
 
 _NEG = -1e30
 
@@ -177,22 +183,24 @@ def decode_attention(q, k_cache, v_cache, offset, scale=None,
 _PAGED_VMEM_BUDGET = 4 * 1024 * 1024
 
 
-def _paged_vmem_bytes(hb, Sq, G, page, D, itemsize) -> int:
+def _paged_vmem_bytes(hb, Sq, G, page, D, itemsize, Dv=None) -> int:
     """VMEM the paged kernel needs for a block of ``hb`` KV heads: the
-    two page buffers of K and of V, q in and o out (two pipeline buffers
-    each), the softmax statistics and the accumulator, and the f32
-    temporaries of one page's ``[hb*Sq*G, hb*page]`` scores (the score,
-    its mask bound, the probabilities in f32 and in the pool's dtype,
-    and what the compiler keeps beside them: counted as six)."""
+    two page buffers of K (``D`` wide) and of V (``Dv`` wide, ``D``
+    where not given), q in and o out (two pipeline buffers each), the
+    softmax statistics and the accumulator, and the f32 temporaries of
+    one page's ``[hb*Sq*G, hb*page]`` scores (the score, its mask bound,
+    the probabilities in f32 and in the pool's dtype, and what the
+    compiler keeps beside them: counted as six)."""
+    Dv = D if Dv is None else Dv
     rows, cols = hb * Sq * G, hb * page
-    pages = 2 * 2 * cols * D * itemsize
-    qo = 2 * 2 * rows * D * itemsize
-    stats = rows * (2 * 128 + D) * 4
+    pages = 2 * cols * (D + Dv) * itemsize
+    qo = 2 * rows * (D + Dv) * itemsize
+    stats = rows * (2 * 128 + Dv) * 4
     scores = 6 * rows * max(cols, 128) * 4
     return pages + qo + stats + scores
 
 
-def _paged_head_block(Sq, G, KV, page, D, itemsize) -> int:
+def _paged_head_block(Sq, G, KV, page, D, itemsize, Dv=None) -> int:
     """KV heads one fetch brings: the largest divisor of ``KV`` whose
     block fits ``_PAGED_VMEM_BUDGET``; one head where none does (the
     gate's ``Sq*G <= 2048`` bounds that block). The scores of a block
@@ -201,22 +209,36 @@ def _paged_head_block(Sq, G, KV, page, D, itemsize) -> int:
     bucket takes one."""
     for hb in range(KV, 1, -1):
         if KV % hb == 0 and _paged_vmem_bytes(
-                hb, Sq, G, page, D, itemsize) <= _PAGED_VMEM_BUDGET:
+                hb, Sq, G, page, D, itemsize, Dv) <= _PAGED_VMEM_BUDGET:
             return hb
     return 1
 
 
-def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-                  v_buf, sem, slot_ref, m_s, l_s, acc_s, *, scale, page,
-                  npages, Sq, G, hb, nh):
+def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
+                  G, hb, nh, window=None, sink=False):
+    """``window``: the rows see only the last ``window`` positions up to
+    their own, the table is a RING of ``npages`` columns (logical page
+    ``j`` sits in column ``j % npages``) and the loop walks only the
+    pages that intersect the window. ``sink``: one more input, a logit a
+    query row that takes weight in the softmax and gives no value."""
+    sink_ref = refs[0] if sink else None
+    (k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, slot_ref, m_s, l_s,
+     acc_s) = refs[1:] if sink else refs
     t = pl.program_id(0)
     b, blk = t // nh, t % nh
     rows, cols = Sq * hb * G, hb * page
 
+    def first_page(row):
+        """The lowest logical page a q row of ``row`` can see."""
+        return jnp.maximum(len_ref[row] - (window - 1), 0) // page
+
     def fetch(row, head_blk, j, slot):
         """The copies of logical page ``j`` of ``row``: K and V of
         ``hb`` heads, ``[hb, page, D]`` contiguous in each pool."""
-        pid = tbl_ref[row * npages + j]
+        if window is None:
+            pid = tbl_ref[row * npages + j]
+        else:
+            pid = tbl_ref[row * npages + j % npages]
         heads = pl.ds(head_blk * hb, hb)
         return (pltpu.make_async_copy(k_hbm.at[pid, heads], k_buf.at[slot],
                                       sem.at[0, slot]),
@@ -226,13 +248,19 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     @pl.when(t == 0)
     def _():
         slot_ref[0] = 0
-        for copy in fetch(0, 0, 0, 0):
+        for copy in fetch(0, 0, 0 if window is None else first_page(0), 0):
             copy.start()
 
     off = len_ref[b]
     # pages up to the one the last q row's own position falls in: at
-    # least one (a free slot, position 0), never past the table
-    n = jnp.minimum((off + Sq - 1) // page, npages - 1) + 1
+    # least one (a free slot, position 0), never past the table (a ring
+    # has no end: its columns are reused)
+    if window is None:
+        lo = 0
+        n = jnp.minimum((off + Sq - 1) // page, npages - 1) + 1
+    else:
+        lo = first_page(b)
+        n = (off + Sq - 1) // page + 1
     slot0 = slot_ref[0]
     m_s[...] = jnp.full_like(m_s, _NEG)
     l_s[...] = jnp.zeros_like(l_s)
@@ -256,15 +284,21 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     bound = mask_bound() if hb > 1 else None
 
     def visit(j, _):
-        slot = (slot0 + j) % 2
+        slot = (slot0 + j) % 2 if window is None else (slot0 + j - lo) % 2
         more = j + 1 < n
 
         # the next page of this row, or the first page of the next grid
         # step (every step has one), is in flight while this one computes
         @pl.when(more | (t + 1 < pl.num_programs(0)))
         def _():
+            if window is None:
+                first = 0
+            else:       # the last step looks no row up past the batch
+                first = first_page(jnp.minimum(
+                    (t + 1) // nh, pl.num_programs(0) // nh - 1))
             nxt = [jnp.where(more, here, there) for here, there in
-                   ((b, (t + 1) // nh), (blk, (t + 1) % nh), (j + 1, 0))]
+                   ((b, (t + 1) // nh), (blk, (t + 1) % nh),
+                    (j + 1, first))]
             for copy in fetch(*nxt, 1 - slot):
                 copy.start()
 
@@ -274,7 +308,11 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
         vb = v_buf[slot].reshape(cols, -1)
         s = lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        keep = j * page <= (mask_bound() if bound is None else bound)
+        if window is None:
+            keep = j * page <= (mask_bound() if bound is None else bound)
+        else:           # and no further back than the window
+            bd = mask_bound() if bound is None else bound
+            keep = (j * page <= bd) & (j * page > bd - window)
         s = jnp.where(keep, s, _NEG)
         m_prev = m_s[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -286,25 +324,42 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
             preferred_element_type=jnp.float32)
         m_s[:, :1] = m_new
 
-    lax.fori_loop(0, n, visit, None)
-    slot_ref[0] = (slot0 + n) % 2
-    l = jnp.maximum(l_s[:, :1], 1e-30)
-    o_ref[0] = (acc_s[...] / l).reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+    if window is None:
+        lax.fori_loop(0, n, visit, None)
+        slot_ref[0] = (slot0 + n) % 2
+    else:
+        lax.fori_loop(lo, n, visit, None)
+        slot_ref[0] = (slot0 + n - lo) % 2
+    if not sink:
+        l = jnp.maximum(l_s[:, :1], 1e-30)
+        o_ref[0] = (acc_s[...] / l).reshape(o_ref.shape[1:]).astype(
+            o_ref.dtype)
+        return
+    # the sink joins the denominator as one more score with no value
+    m, sk = m_s[:, :1], sink_ref[0]                     # [rows, 1]
+    m_fin = jnp.maximum(m, sk)
+    corr = jnp.exp(m - m_fin)
+    l = l_s[:, :1] * corr + jnp.exp(sk - m_fin)
+    o_ref[0] = (acc_s[...] * corr / l).reshape(o_ref.shape[1:]).astype(
+        o_ref.dtype)
 
 
-def paged_supported(q_shape, pool_shape) -> bool:
+def paged_supported(q_shape, pool_shape, v_shape=None) -> bool:
     B, Sq, H, D = q_shape
     P, KV, page = pool_shape[0], pool_shape[1], pool_shape[2]
     if H % KV or D % 128 != 0:
+        return False
+    if v_shape is not None and v_shape[-1] % 128 != 0:
         return False
     if page % 8 or page < 8:  # sublane-tileable page
         return False
     return Sq * (H // KV) <= 2048
 
 
-@partial(jax.jit, static_argnames=("scale", "interpret"))
+@partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           scale=None, interpret=False):
+                           scale=None, interpret=False, sinks=None,
+                           window=None):
     """Block-table KV attention (the TPU redesign of the reference's
     paged cache kernel: phi/kernels/fusion/gpu/
     block_multi_head_attention_kernel.cu + block_attn.h — there, CUDA
@@ -316,10 +371,22 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 
     q            [B, Sq, H, D]  rows at absolute positions
                                 lengths[b]..lengths[b]+Sq-1
-    k/v_pool     [P, KV, page, D]  shared physical page pool, head-major
-                                pages (each [page, D] plane contiguous)
+    k/v_pool     [P, KV, page, D / Dv]  shared physical page pool,
+                                head-major pages (each [page, D] plane
+                                contiguous); V may be narrower or wider
+                                than K, and the result is ``Dv`` wide
     block_tables [B, npages]    logical->physical page map per row
     lengths      [B]            tokens already in cache per row (ragged)
+    sinks        None or [H]    a learned logit a query head: it takes
+                                weight in the softmax's denominator and
+                                gives no value
+    window       None or int    a row sees only the last ``window``
+                                positions, its own included; the table
+                                is then a RING, ``[B, ring]``: logical
+                                page j sits in column ``j % ring``, and
+                                only the pages that intersect the window
+                                are fetched (kernel name
+                                ``paged_window_decode_attention``)
 
     Table entries up to a row's frontier page must name pages of the
     pool; later entries are never read.
@@ -330,11 +397,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     """
     B, Sq, H, D = q.shape
     P, KV, page = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    Dv = v_pool.shape[-1]
     npages = block_tables.shape[1]
     G = H // KV
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    hb = _paged_head_block(Sq, G, KV, page, D, k_pool.dtype.itemsize)
+    hb = _paged_head_block(Sq, G, KV, page, D, k_pool.dtype.itemsize,
+                           None if Dv == D else Dv)
     nh = KV // hb
     rows = Sq * hb * G
     q5 = q.reshape(B, Sq, KV, G, D)
@@ -344,36 +413,49 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     def q_index(t, ln, tb):
         return (t // nh, 0, t % nh, 0, 0)
 
+    ins, in_specs = [q5], [pl.BlockSpec((1, Sq, hb, G, D), q_index)]
+    if sinks is not None:
+        # one logit a score row, in the kernel's row order (s, head, g)
+        ins.append(jnp.broadcast_to(
+            jnp.asarray(sinks, jnp.float32).reshape(nh, 1, hb * G),
+            (nh, Sq, hb * G)).reshape(nh, rows, 1))
+        in_specs.append(pl.BlockSpec((1, rows, 1),
+                                     lambda t, ln, tb: (t % nh, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B * nh,),
-        in_specs=[
-            pl.BlockSpec((1, Sq, hb, G, D), q_index),
+        in_specs=in_specs + [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, Sq, hb, G, D), q_index),
+        out_specs=pl.BlockSpec((1, Sq, hb, G, Dv), q_index),
         scratch_shapes=[
             pltpu.VMEM((2, hb, page, D), k_pool.dtype),
-            pltpu.VMEM((2, hb, page, D), v_pool.dtype),
+            pltpu.VMEM((2, hb, page, Dv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, D), jnp.float32),
+            pltpu.VMEM((rows, Dv), jnp.float32),
         ],
     )
+    kw = {}
+    if window is not None:
+        kw["window"] = int(window)
+    if sinks is not None:
+        kw["sink"] = True
     out = pl.pallas_call(
         partial(_paged_kernel, scale=scale, page=page, npages=npages,
-                Sq=Sq, G=G, hb=hb, nh=nh),
+                Sq=Sq, G=G, hb=hb, nh=nh, **kw),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, KV, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Sq, KV, G, Dv), q.dtype),
         interpret=interpret,
-        name="paged_decode_attention",
+        name="paged_decode_attention" if window is None
+        else "paged_window_decode_attention",
         # one sequential axis: a step starts the next step's first page
         **_compiler_params(0, interpret),
-    )(lengths, tbl, q5, k_pool, v_pool)
-    return out.reshape(B, Sq, H, D)
+    )(lengths, tbl, *ins, k_pool, v_pool)
+    return out.reshape(B, Sq, H, Dv)
 
 
 def _concrete_zero(offset) -> bool:
@@ -387,7 +469,7 @@ def _concrete_zero(offset) -> bool:
 
 
 def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
-                   valid=None):
+                   valid=None, ring=False):
     """Land new K/V rows in their physical pages, touching only the
     pages written; returns the updated ``(k_pool, v_pool)``.
 
@@ -400,6 +482,13 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
     valid        None or [B]: only the first valid[b] rows of row b are
                  real; the rest go to the page of the table's LAST
                  column (the caller's trash column)
+    ring         the table is a ring (the page class of a window layer,
+                 ``PagedKVCache``): position ``pos`` lands in column
+                 ``(pos // page) % npages``, over what was ``npages``
+                 pages back. Rows form only; a prefill (concrete
+                 ``offset`` 0) is given the row's LOGICAL table instead,
+                 in which the host has sent every page but the prompt's
+                 last ``npages`` to the trash page
 
     The form is chosen from what the trace can see. An advanced-index
     scatter ``pool.at[pid, :, slot, :]`` has two scatter dims around a
@@ -427,7 +516,7 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
     B, S = k_new.shape[0], k_new.shape[1]
     tbl = jnp.asarray(block_tables, jnp.int32)
     npages = tbl.shape[1]
-    if (valid is None and _concrete_zero(offset)
+    if (valid is None and not ring and _concrete_zero(offset)
             and (S < page or S % page == 0)):
         n = -(-S // page)
         pids = tbl[:, :n].reshape(B * n)
@@ -446,6 +535,8 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
                 < jnp.asarray(valid, jnp.int32).reshape(B, 1)
             pos = jnp.where(alive, pos, (npages - 1) * page)
         lpage = pos // page
+        if ring:
+            lpage = lpage % npages
         pid = jnp.take_along_axis(
             tbl, jnp.minimum(lpage, npages - 1), axis=1)        # [B,S]
         heads = jnp.arange(KV, dtype=jnp.int32)
@@ -468,9 +559,14 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
     return put(k_pool, k_new), put(v_pool, v_new)
 
 
-def paged_attention_dense(q, k_pool, v_pool, block_tables, lengths):
+def paged_attention_dense(q, k_pool, v_pool, block_tables, lengths,
+                          scale=None, sinks=None, window=None):
     """XLA reference/fallback: gather the pages into a contiguous view,
-    then run the (ragged-aware) dense cache attention."""
+    then run the (ragged-aware) dense cache attention. ``scale``,
+    ``sinks`` and ``window`` as ``paged_decode_attention``; with a
+    window the table is the ring, and a column is masked by the POSITION
+    its page holds now (the newest logical page of its ring column that
+    is not past the row's last q position)."""
     B, Sq, H, D = q.shape
     page = k_pool.shape[2]
     npages = block_tables.shape[1]
@@ -478,9 +574,55 @@ def paged_attention_dense(q, k_pool, v_pool, block_tables, lengths):
     def gather(pool):
         g = pool[block_tables]                       # [B, npages, KV, page, D]
         g = jnp.swapaxes(g, 1, 2)                     # [B, KV, npages, page, D]
-        return g.reshape(B, pool.shape[1], npages * page, D)
+        return g.reshape(B, pool.shape[1], npages * page, pool.shape[-1])
 
-    return _dense_ragged(q, gather(k_pool), gather(v_pool), lengths)
+    if scale is None and sinks is None and window is None:
+        return _dense_ragged(q, gather(k_pool), gather(v_pool), lengths)
+    off = jnp.asarray(lengths, jnp.int32).reshape(B)
+    cols = jnp.arange(npages, dtype=jnp.int32)[None]
+    if window is None:
+        held = jnp.broadcast_to(cols, (B, npages))
+    else:
+        last = ((off + Sq - 1) // page)[:, None]
+        held = last - (last - cols) % npages          # may be < 0: empty
+    pos = (held[:, :, None] * page + jnp.arange(page, dtype=jnp.int32)
+           ).reshape(B, npages * page)
+    return attention_dense_masked(q, gather(k_pool), gather(v_pool), pos,
+                                  off, scale, sinks, window)
+
+
+def attention_dense_masked(q, k_cache, v_cache, pos, lengths, scale=None,
+                           sinks=None, window=None):
+    """Dense cache attention in float32 with everything the paged kernel
+    takes: q [B, S, H, D] at positions lengths[b].., caches
+    [B, KV, M, D / Dv] whose column m holds position ``pos[b, m]``
+    (negative: nothing), a row sees positions <= its own and, with a
+    window, > its own - window; ``sinks [H]`` joins the denominator."""
+    B, S, H, D = q.shape
+    KV, Dv = k_cache.shape[1], v_cache.shape[-1]
+    rep = H // KV
+    if scale is None:
+        scale = 1.0 / np.sqrt(D)
+    qf = jnp.swapaxes(q, 1, 2).astype(jnp.float32).reshape(B, KV, rep, S, D)
+    scores = jnp.einsum("bkrsd,bkmd->bkrsm", qf,
+                        k_cache.astype(jnp.float32)) * scale
+    q_pos = (jnp.asarray(lengths, jnp.int32).reshape(B)[:, None]
+             + jnp.arange(S, dtype=jnp.int32)[None])[:, :, None]   # [B,S,1]
+    keep = (pos[:, None, :] >= 0) & (pos[:, None, :] <= q_pos)
+    if window is not None:
+        keep = keep & (pos[:, None, :] > q_pos - window)
+    scores = jnp.where(keep[:, None, None], scores, _NEG)
+    if sinks is not None:
+        sk = jnp.broadcast_to(
+            jnp.asarray(sinks, jnp.float32).reshape(1, KV, rep, 1, 1),
+            scores.shape[:-1] + (1,))
+        scores = jnp.concatenate([scores, sk], axis=-1)
+    probs = jax.nn.softmax(scores, axis=-1)
+    if sinks is not None:
+        probs = probs[..., :-1]
+    out = jnp.einsum("bkrsm,bkmd->bkrsd", probs,
+                     v_cache.astype(jnp.float32))
+    return jnp.swapaxes(out.reshape(B, H, S, Dv), 1, 2).astype(q.dtype)
 
 
 def _dense_ragged(q, k_cache, v_cache, lengths):

@@ -185,7 +185,8 @@ def test_register_first_writer_wins_and_match_prefix():
     c.release_pages([a, b, other])
     assert c.match_prefix(hs) == ([a, b], 2)     # idle, still hit-able
     assert c.counts() == {"free": 5, "idle": 2, "registered": 2,
-                          "hit_rate": 0.0}
+                          "hit_rate": 0.0,
+                          "classes": {"full": {"used": 0, "free": 5}}}
     c.pin([a])
     assert c.match_prefix(hs) == ([a, b], 1) and c.counts()["idle"] == 1
     c.note(lookups=4, hits=1, skipped_tokens=PAGE)

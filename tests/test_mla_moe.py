@@ -350,7 +350,8 @@ def test_serving_programs_compile_for_a_v5e_in_place():
     assert set(progs) == {"decode", "prefill_1024"}
     for c in progs.values():
         assert c["pool_copies"] == 0 and c["ragged_dot"], c
-    assert progs["decode"]["kernel"] and not progs["prefill_1024"]["kernel"]
+    assert progs["decode"]["kernels"] == ["mla_paged_decode_attention"] \
+        and not progs["prefill_1024"]["kernels"]
     # the pools and the routing counters are donated, the round's one
     # host array (tables, pos, token, mask) is not
     donated = progs["decode"]["donated"]

@@ -1,20 +1,27 @@
-"""Do the serving programs of the latent-attention expert decoder compile
-for a v5e, in place and with the kernel? (no chip needed)
+"""Do the serving programs of an expert decoder compile for a v5e, in
+place and with its kernels? (no chip needed)
 
-Builds ``MLAMoEForCausalLM`` at the benchmark configuration's widths
-(``benchmarks/configs/sarvam-105b.json``) with ``--layers`` layers (2: the
+Builds the model of ``--config`` (``sarvam-105b``: ``MLAMoEForCausalLM``,
+the latent-attention decoder; ``mimo-v2-flash``: ``HybridMoEForCausalLM``,
+window and full layers over two classes of pages) at the benchmark
+configuration's widths (``benchmarks/configs/<config>.json``) with
+``--layers`` layers (2: the
 dense layer and one expert layer) and NO weights (``LazyGuard``), takes
 ``ServingEngine``'s own decode and prefill programs, and compiles them
 with the TPU compiler installed beside JAX for a DESCRIBED v5e:2x2
 topology, as ``tools/paged_write_aot.py`` does for the page-pool write.
 Per program: pool-shaped ``copy`` ops in the optimized HLO (0 = the
-latent pools are written in place), whether ``mla_paged_decode_attention``
-and XLA's grouped matmul (``ragged-dot``) are in it, and the compiler's
+pools of every page class are written in place), which of the decode
+kernels (``mla_paged_decode_attention``, ``paged_decode_attention``,
+``paged_window_decode_attention``) and whether XLA's grouped matmul
+(``ragged-dot``) are in it, the largest float32 buffer (a prefill program
+of the window/full decoder holds no ``[heads, S, S]`` one), and the compiler's
 memory analysis, and the parameters it aliases to outputs: the pools and
 the routing counters of the decode program, and not its round array. Prints one JSON line, ``{"programs": [...]}`` or
 ``{"skipped": why}``.
 
-    python tools/mla_serving_aot.py [--layers 6] [--batch 128] [--dump DIR]
+    python tools/mla_serving_aot.py [--config mimo-v2-flash] [--layers 6]
+        [--batch 128] [--prefill 2048] [--dump DIR]
 """
 import argparse
 import json
@@ -24,6 +31,7 @@ import sys
 
 def main(argv):
     ap = argparse.ArgumentParser()
+    ap.add_argument("--config", default="sarvam-105b")
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--batch", type=int, default=128)
     ap.add_argument("--prefill", type=int, default=1024)
@@ -47,23 +55,30 @@ def main(argv):
     except Exception as e:  # no libtpu, or another process holds it
         print(json.dumps({"skipped": f"{type(e).__name__}: {e}"[:300]}))
         return 0
+    import importlib
+    import math
+    import re
+
     import paddle_tpu as paddle
-    from benchmarks.harness.families.mla_moe_serving import model_config
     from paddle_tpu.inference import (Config, ServingEngine,
                                       create_predictor)
-    from paddle_tpu.models.mla_moe import MLAMoEForCausalLM
     from paddle_tpu.ops import pallas
 
     # trace the programs the chip would run: the kernels' own gates
     # still decide, the platform question is answered for the target
     pallas.is_tpu_platform = lambda: True
     cfg = json.load(open(os.path.join(
-        root, "benchmarks", "configs", "sarvam-105b.json")))
+        root, "benchmarks", "configs", args.config + ".json")))
     cfg["num_hidden_layers"] = args.layers
     srv = cfg["serving"]
+    fam = importlib.import_module(
+        "benchmarks.harness.families." + cfg["family"])
+    mcfg = fam.model_config(cfg, srv["max_length"])
+    models = importlib.import_module("paddle_tpu.models")
     paddle.set_default_dtype(cfg["torch_dtype"])
     with paddle.LazyGuard():
-        model = MLAMoEForCausalLM(model_config(cfg, srv["max_length"]))
+        model = getattr(models, type(mcfg).__name__.replace(
+            "Config", "ForCausalLM"))(mcfg)
     pred = create_predictor(Config().set_model(model).enable_paged_kv(
         page_size=srv["page_size"]))
     eng = ServingEngine(pred, max_batch=args.batch,
@@ -75,7 +90,7 @@ def main(argv):
                                     sharding=dev)
 
     pvals = tuple(sds(p._value) for p in pred._params)
-    B, npages = eng.B, eng.cache.npages
+    B, npages, ring = eng.B, eng.cache.npages, eng.cache.ring
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=dev)
@@ -86,13 +101,16 @@ def main(argv):
     state = jax.tree_util.tree_map(sds, eng.cache.lend())
     programs = {
         "decode": (eng._decode_step_fn(),
-                   (pvals, state, i32(B, npages + 3), i32(B), rng)),
+                   (pvals, state, i32(B, npages + ring + 3), i32(B), rng)),
         f"prefill_{args.prefill}": (
             pred._prefill_fn(1, args.prefill, eng.M),
             (pvals, i32(1, args.prefill),
              [(sds(c), sds(r), i32(1, npages)) for c, r in eng.pools],
              i32(1))),
     }
+    kernels = ("mla_paged_decode_attention", "paged_decode_attention",
+               "paged_window_decode_attention")
+    pool_shapes = {s.shape for pair in eng.pools for s in pair}
     out = []
     for name, (fn, avals) in programs.items():
         compiled = fn.lower(*avals).compile()
@@ -104,10 +122,15 @@ def main(argv):
         mem = compiled.memory_analysis()
         out.append({
             "program": name,
-            "pool_copies": sum(ServingEngine.pool_copies(text, s.shape)
-                               for s in eng.pools[0]),
-            "kernel": "mla_paged_decode_attention" in text,
+            "pool_copies": sum(ServingEngine.pool_copies(text, s)
+                               for s in pool_shapes),
+            "kernels": [k for k in kernels
+                        if re.search(rf"(?<!\w){k}(?!\w)", text)],
             "ragged_dot": "ragged-dot" in text,
+            "largest_f32_elements": max(
+                (math.prod(int(n) for n in d.split(",") if n)
+                 for d in re.findall(r"f32\[([\d,]*)\]", text)),
+                default=0),
             "argument_gib": mem.argument_size_in_bytes / 2 ** 30,
             "temp_gib": mem.temp_size_in_bytes / 2 ** 30,
             "alias_gib": mem.alias_size_in_bytes / 2 ** 30,
