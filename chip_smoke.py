@@ -824,6 +824,29 @@ def check_overlap(eng, most: bool = True) -> None:
           f"{st['in_flight']} in flight after run()")
 
 
+def check_expert_forms(eng, st, sz) -> None:
+    """The expert layers' products after the decode rounds: batched over
+    the held experts in the decode program (no grouped matmul in it),
+    sorted and grouped in every prefill bucket over the threshold (a
+    prompt of 128 tokens or fewer prefills batched as well: the form
+    follows the token count alone)."""
+    from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
+        routed_form)
+
+    buckets = sorted(s[1] for s in eng.program_sites()
+                     if s[0] == "prefill")
+    want = {"decode": routed_form(eng.B), "prefill": "+".join(sorted(
+        {routed_form(Sb) for Sb in buckets}))}
+    check(st["forms"] == want and want["decode"] == "batched"
+          and (sz.rehearsal or "sorted" in want["prefill"]),
+          f"expert products' forms {st['forms']} (decode at {eng.B} rows, "
+          f"prefill buckets {buckets})")
+    grouped = {site: "ragged_dot" in eng.lowered_text(site)
+               for site in [("decode",), ("prefill", buckets[-1])]}
+    check(sz.rehearsal or list(grouped.values()) == [False, True],
+          f"XLA's grouped matmul in the lowered programs: {grouped}")
+
+
 def phase_serve_latent(sz: Sizes) -> None:
     """The latent-attention expert decoder through ServingEngine in its
     default mode: the latent pools written in place, the latent kernel
@@ -900,6 +923,7 @@ def phase_serve_latent(sz: Sizes) -> None:
     check(st["dropped"] == 0 and st["tokens"][-1] > 0,
           f"expert layers dropped {st['dropped']} routed pairs of "
           f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
+    check_expert_forms(eng, st, sz)
     sites = eng.program_sites()
     found = kernel_names(eng.lowered_text(("decode",)))
     check(sz.rehearsal or found.get("mla_paged_decode_attention", 0)
@@ -1023,6 +1047,7 @@ def phase_serve_hybrid(sz: Sizes) -> None:
     check(st["dropped"] == 0 and st["tokens"][-1] > 0,
           f"expert layers dropped {st['dropped']} routed pairs of "
           f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
+    check_expert_forms(eng, st, sz)
     c = eng.cache.counts()["classes"]
     check(c["full"]["used"] == 0 and c["window"]["used"] == 0,
           f"both classes back to free: {c}")

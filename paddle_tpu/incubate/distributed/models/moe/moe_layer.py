@@ -334,13 +334,31 @@ def swiglu(x, w_gate, w_up, w_down):
                    preferred_element_type=jnp.float32)
 
 
+# The held experts' products take one of two forms, chosen from the number
+# of tokens T in the call (a static shape). Batched over the held experts,
+# every token goes through every held expert and the combine selects: T
+# multiply-adds, 2 T operations, per weight element, T operations per
+# byte of a bf16 weight. A TPU v5e turns at 197e12 / 819e9 = 240
+# operations a byte, so while T stays under ~240 the weights' bytes, which
+# every form has to stream, set the time, and the unchosen products cost
+# nothing. 128 is the largest size of the serving lattice (powers of two)
+# under that ridge. Above it the batched form would be compute-bound and
+# do El / (k x held share) times the work: the sorted form, whose work
+# follows the routed pairs, takes over.
+_BATCHED_MAX_TOKENS = 128
+
+
+def routed_form(T: int) -> str:
+    """The form ``routed_swiglu`` takes for a call of ``T`` tokens."""
+    return "batched" if T <= _BATCHED_MAX_TOKENS else "sorted"
+
+
 def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
                   expert_offset: int = 0):
-    """The held experts' part of a routed SwiGLU layer, with work in
-    proportion to the routed pairs: the (token, expert) pairs are sorted
-    by expert and the three products are grouped over the held experts
-    (``lax.ragged_dot``: XLA's grouped matmul on TPU). No capacity, no
-    ``[T, E, C]`` tensor, no dropped pair.
+    """The held experts' part of a routed SwiGLU layer. No capacity, no
+    ``[T, E, C]`` tensor, no dropped pair, in either form
+    (``routed_form(T)``: ``routed_swiglu_batched`` for a decode step's
+    few tokens, ``routed_swiglu_sorted`` for a prefill's many).
 
     x2d      [T, d]       tokens
     idx      [T, k] int32 chosen experts, numbered over ALL experts
@@ -349,13 +367,25 @@ def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
                           expert_offset + El - 1
 
     Returns (y [T, d] float32: the sum over chosen AND held experts;
-    sizes [El + 2] int32: the pairs in each held expert's group, the
-    pairs whose expert is held elsewhere, and the pairs that lay inside a
-    group AND were summed into y: what was computed, counted from the
-    sorted rows and not from the router's choice, so a pair that is
-    held but fell outside every group shows as missing from the last).
-    Pairs of absent experts sort behind every group and are never
-    multiplied."""
+    sizes [El + 2] int32: the pairs of each held expert, the pairs whose
+    expert is held elsewhere, and the pairs whose product was computed
+    AND summed into y: counted from what was computed and not from the
+    router's choice, so a held pair that the products missed shows as
+    missing from the last)."""
+    form = (routed_swiglu_batched if routed_form(x2d.shape[0]) == "batched"
+            else routed_swiglu_sorted)
+    return form(x2d, idx, weights, w_gate, w_up, w_down, expert_offset)
+
+
+def routed_swiglu_sorted(x2d, idx, weights, w_gate, w_up, w_down,
+                         expert_offset: int = 0):
+    """``routed_swiglu`` with work in proportion to the routed pairs: the
+    (token, expert) pairs are sorted by expert and the three products
+    are grouped over the held experts (``lax.ragged_dot``: XLA's grouped
+    matmul on TPU). The computed pairs are counted from the sorted rows
+    that lay inside a group. Pairs of absent experts sort behind every
+    group and are never multiplied, but they are rows of the
+    ``[T*k, d]`` operand all the same."""
     T, k = idx.shape
     El = w_gate.shape[0]
     local = idx - expert_offset
@@ -379,14 +409,61 @@ def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
     return y, sizes
 
 
+def _combine(idx, weights, expert_offset: int, El: int):
+    """The ``[T, El]`` combine of the batched form, by comparison and
+    without a sort: how many of a token's pairs chose each held expert
+    (0 or 1 from a top-k) and the router's weight there, 0 elsewhere."""
+    hit = (idx - expert_offset)[:, :, None] == jnp.arange(El,
+                                                          dtype=idx.dtype)
+    return (hit.sum(axis=1, dtype=jnp.int32),
+            jnp.where(hit, weights[:, :, None], 0.0).sum(axis=1))
+
+
+def routed_swiglu_batched(x2d, idx, weights, w_gate, w_up, w_down,
+                          expert_offset: int = 0):
+    """``routed_swiglu`` with work in proportion to the HELD EXPERTS: every
+    token goes through every held expert, the expert a batch dimension
+    of both operands (no weight array is transposed or copied), and the
+    combine selects; the unchosen products are computed and discarded.
+    The combine is folded into the activations, so the down product is
+    one plain matmul over (expert, hidden) and no ``[El, T, d]`` array
+    exists. No sort, no gather, no ``[T*k, d]`` operand. A select, not a
+    product with 0, drops an unchosen activation: it may be non-finite.
+    The computed pairs are counted from the combine that was applied."""
+    T = x2d.shape[0]
+    El, d, h = w_gate.shape
+    pairs, c = _combine(idx, weights, expert_offset, El)       # [T, El]
+    xb = jnp.broadcast_to(x2d, (El, T, d))
+    per_expert = (((2,), (1,)), ((0,), (0,)))
+    g = lax.dot_general(xb, w_gate, per_expert,
+                        preferred_element_type=jnp.float32)    # [El, T, h]
+    u = lax.dot_general(xb, w_up, per_expert,
+                        preferred_element_type=jnp.float32)
+    a = jnp.where((pairs > 0).T[:, :, None],
+                  jax.nn.silu(g) * u * c.T[:, :, None], 0.0)
+    y = jnp.dot(a.astype(x2d.dtype).transpose(1, 0, 2).reshape(T, El * h),
+                w_down.reshape(El * h, d),
+                preferred_element_type=jnp.float32)
+    local = idx - expert_offset
+    absent = ((local < 0) | (local >= El)).sum()
+    # what the select let in: ``pairs`` is the matrix it tested
+    sizes = jnp.concatenate([pairs.sum(axis=0), jnp.stack(
+        [absent, pairs.sum()]).astype(jnp.int32)])
+    return y, sizes
+
+
 class GatedMoELayer(Layer):
     """Routed SwiGLU experts plus shared experts, for one holder of an
     expert-parallel layer: it is TOLD which experts it holds
     (``expert_offset``, ``num_local_experts``), routes every token over
     all ``num_experts`` (``SigmoidTopKGate``), and computes its own
-    experts' part of the sum. The shared experts run whole on every
-    holder. Inference only (no tape backward); on one chip there is no
-    exchange, and nothing stands in for the absent holders.
+    experts' part of the sum (``routed_swiglu``: batched over the held
+    experts for a decode step's few tokens, where the weights' bytes set
+    the time whatever is computed; sorted and grouped, with work in
+    proportion to the routed pairs, for a prefill's many). The shared
+    experts run whole on every holder. Inference only (no tape
+    backward); on one chip there is no exchange, and nothing stands in
+    for the absent holders.
 
     ``forward(x, counts=None)``: ``counts`` is an optional
     ``[num_local_experts + 3]`` int32 routing counter (``routed_swiglu``'s
@@ -436,7 +513,8 @@ class GatedMoELayer(Layer):
             x2d, idx, w, self.w_gate._value, self.w_up._value,
             self.w_down._value, self.expert_offset)
         # a no-op unless a collection is open on this thread
-        _moestats.record({"choices": idx, "load": sizes})
+        _moestats.record({"choices": idx, "load": sizes,
+                          "form": routed_form(x2d.shape[0])})
         if self.shared:
             y = y + swiglu(x2d, self.shared_gate._value,
                             self.shared_up._value, self.shared_down._value)

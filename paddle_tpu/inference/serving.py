@@ -119,6 +119,7 @@ from jax import lax
 from ..core.enforce import enforce
 from ..observability import commledger as _cl
 from ..observability import memledger as _ml
+from ..observability import moestats as _moestats
 from ..observability.catalog import serving_metrics as _serving_metrics
 from ..observability.spans import (RequestTrace, SpanRing,
                                    format_traceparent as
@@ -417,6 +418,9 @@ class ServingEngine:
         self._mem_ledgers: Dict[Any, Any] = {}
         # site -> (jitted fn, arg shapes) of every program that has run
         self._site_programs: Dict[Any, Any] = {}
+        # "decode" | "prefill" | .. -> the forms an expert model's
+        # layers traced in this engine's programs of that kind
+        self._moe_forms: Dict[str, set] = {}
         self._live_peak = 0
         self.gen = cfg.generation
         self._rng = jax.random.PRNGKey(self.gen.seed)
@@ -1725,6 +1729,20 @@ class ServingEngine:
         site's FIRST execution also stores an XLA memory_analysis of
         the same program (lowered BEFORE the call: the cache buffers
         are donated), republished as mem gauges per execution."""
+        # an expert model's layers record the form of their products as
+        # they are traced: listen while a site runs for the first time
+        listen = site not in self._site_programs and \
+            self.cache.counters is not None
+        if listen:
+            _moestats.begin()
+        try:
+            return self._run_site(site, fn, *args)
+        finally:
+            if listen:
+                self._moe_forms.setdefault(site[0], set()).update(
+                    r["form"] for r in _moestats.drain() if "form" in r)
+
+    def _run_site(self, site, fn, *args):
         if self._mem_on and site not in self._mem_ledgers:
             self._mem_ledgers[site] = _ml.analyze(
                 fn, args, program="_".join(str(s) for s in site))
@@ -1859,8 +1877,12 @@ class ServingEngine:
         routed like any other). ``dropped`` = the pairs the router sent
         to a held expert (tokens x k less the absent ones) that the
         grouped products did not cover; the layer has no capacity, so
-        anything but 0 is a fault of the sort or of the group sizes.
-        None for a model without routed experts."""
+        anything but 0 is a fault of the products' bookkeeping.
+        ``forms`` = the form the expert layers took in the decode
+        program and in the prefill programs (``routed_form``: "batched"
+        | "sorted"; both joined by "+" if the buckets differ), as they
+        recorded it when this engine traced them; None for a kind it
+        has not traced. None for a model without routed experts."""
         if self.cache.counters is None:
             return None
         self._drain()
@@ -1869,7 +1891,10 @@ class ServingEngine:
         k = int(getattr(self.pred._model.config, "num_experts_per_tok", 0))
         return {"pairs": c[:, :-3], "absent_pairs": c[:, -3],
                 "summed_pairs": c[:, -2], "tokens": c[:, -1],
-                "dropped": int((c[:, -1] * k - c[:, -3] - c[:, -2]).sum())}
+                "dropped": int((c[:, -1] * k - c[:, -3] - c[:, -2]).sum()),
+                "forms": {kind: "+".join(sorted(
+                    self._moe_forms.get(kind, ()))) or None
+                    for kind in ("decode", "prefill")}}
 
     def roofline_report(self):
         """Roofline verdict of the shared decode round

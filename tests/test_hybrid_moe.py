@@ -61,9 +61,9 @@ M = 128
 PAGE = 8
 
 
-def build(cfg=CFG, seed=SEED, **kw):
+def build(cfg=CFG, seed=SEED, max_len=M, **kw):
     paddle.set_default_dtype("float32")
-    mcfg = fam.model_config(cfg, M)
+    mcfg = fam.model_config(cfg, max_len)
     for k, v in kw.items():
         setattr(mcfg, k, v)
     model = HybridMoEForCausalLM(mcfg)
@@ -138,6 +138,7 @@ def test_engine_prefill_then_decode_is_the_reference(model, tokens):
         assert ref.served_gap(lg, served).max() < 1e-3
     st = eng.moe_stats()
     assert st["dropped"] == 0
+    assert st["forms"] == {"decode": "batched", "prefill": "batched"}
     assert st["tokens"][0] == 0 and (st["tokens"][1:] > 0).all()
     np.testing.assert_array_equal(
         st["pairs"].sum(1) + st["absent_pairs"], st["tokens"] * 4)
@@ -146,6 +147,50 @@ def test_engine_prefill_then_decode_is_the_reference(model, tokens):
         + mem["window_page_bytes"] * mem["window_pool_pages"]
     eng.release_pools()
     assert eng.pools is None
+
+
+def test_decode_is_batched_and_a_long_prefill_sorted():
+    """A prompt of 150 tokens prefills in the 256 bucket, over the
+    threshold: its expert layers sort and group (``ragged_dot`` in the
+    program as a TPU would get it), the decode program's (2 rows a step)
+    are batched over the held experts and hold none, and the served
+    tokens are the reference's through both."""
+    prompt = np.random.default_rng(5).integers(0, 256, 150).astype(np.int32)
+    eng = engine(build(max_len=384, attention_block=16), max_batch=2)
+    rid = eng.submit(prompt, max_new_tokens=6)
+    served = np.asarray(eng.run()[rid].new_tokens)
+    assert ref.served_gap(ref_logits(prompt, served), served).max() < 1e-3
+    st = eng.moe_stats()
+    assert st["forms"] == {"decode": "batched", "prefill": "sorted"}
+    assert st["dropped"] == 0
+    assert eng.program_sites() == [("prefill", 256), ("decode",)]
+    texts = {}
+    for site in eng.program_sites():
+        fn, avals = eng._site_programs[site]
+        texts[site[0]] = fn.trace(*avals).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "ragged_dot" in texts["prefill"]
+    assert "ragged_dot" not in texts["decode"]
+
+
+def test_the_layers_form_follows_the_token_count_alone(model):
+    """This model's expert layer (no shared expert, scaling 1): batched
+    at the threshold, sorted one token above it."""
+    from paddle_tpu.incubate.distributed.models.moe import moe_layer
+    from paddle_tpu.observability import moestats
+
+    layer = next(l.mlp for l in model.layers if l.is_moe)
+    assert isinstance(layer, GatedMoELayer) and not layer.shared
+    edge = moe_layer._BATCHED_MAX_TOKENS
+    for T, form in ((edge, "batched"), (edge + 1, "sorted")):
+        moestats.begin()
+        try:
+            jaxpr = jax.make_jaxpr(lambda v: layer(v)._value)(
+                jnp.zeros((1, T, 64), jnp.float32))
+        finally:
+            forms = [r["form"] for r in moestats.drain()]
+        assert forms == [form]
+        assert ("ragged_dot" in str(jaxpr)) == (form == "sorted")
 
 
 def test_generate_over_the_static_cache_is_the_reference(model, tokens):
@@ -516,7 +561,11 @@ def test_serving_programs_compile_for_a_v5e_in_place():   # run's workers
     progs = {c["program"]: c for c in out["programs"]}
     assert set(progs) == {"decode", "prefill_1024"}
     for c in progs.values():
-        assert c["pool_copies"] == 0 and c["ragged_dot"], c
+        assert c["pool_copies"] == 0 and c["expert_weight_copies"] == 0, c
+    # batched over the 16 held experts in decode, sorted and grouped in
+    # the prefill
+    assert not progs["decode"]["ragged_dot"]
+    assert progs["prefill_1024"]["ragged_dot"]
     assert progs["decode"]["kernels"] == [
         "paged_decode_attention", "paged_window_decode_attention"]
     assert not progs["prefill_1024"]["kernels"]

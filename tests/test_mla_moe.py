@@ -22,6 +22,7 @@ from benchmarks.harness.families import mla_moe_serving as fam  # noqa: E402
 from benchmarks.references import sarvam as ref  # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe import (  # noqa: E402
     GatedMoELayer, SigmoidTopKGate)
+from paddle_tpu.incubate.distributed.models.moe import moe_layer  # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe.moe_layer import (  # noqa: E402
     routed_swiglu)
 from paddle_tpu.inference import (Config, ServingEngine,  # noqa: E402
@@ -49,9 +50,9 @@ SEED = 2 ** 31 + 99
 M = 96
 
 
-def build(cfg=CFG, seed=SEED):
+def build(cfg=CFG, seed=SEED, max_len=M):
     paddle.set_default_dtype("float32")
-    model = MLAMoEForCausalLM(fam.model_config(cfg, M))
+    model = MLAMoEForCausalLM(fam.model_config(cfg, max_len))
     model.eval()
     named = list(model.named_parameters())
     weights.load(named, {n: fam.names_of(n, cfg) for n, _ in named},
@@ -110,6 +111,8 @@ def test_engine_prefill_then_decode_is_the_reference(model, tokens):
         assert ref.served_gap(lg, served).max() < 1e-3
     st = eng.moe_stats()
     assert st["dropped"] == 0
+    # prompts of 21 and 13 tokens: buckets under the threshold too
+    assert st["forms"] == {"decode": "batched", "prefill": "batched"}
     assert (st["tokens"] == [0, st["tokens"][1], st["tokens"][1]]).all()
     assert st["tokens"][1] > 0 and st["tokens"][1] % 2 == 0   # B rows a step
     np.testing.assert_array_equal(
@@ -117,6 +120,36 @@ def test_engine_prefill_then_decode_is_the_reference(model, tokens):
     np.testing.assert_array_equal(st["summed_pairs"], st["pairs"].sum(1))
     eng.release_pools()
     assert eng.pools is None
+
+
+def tpu_program_text(eng, site):
+    """A serving program that has run, lowered for a TPU from here: the
+    CPU's own lowering writes XLA's grouped matmul out as a masked dense
+    product, a TPU's keeps it (``ragged_dot``)."""
+    fn, avals = eng._site_programs[site]
+    return fn.trace(*avals).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def test_decode_is_batched_and_a_long_prefill_sorted():
+    """A prompt of 150 tokens prefills in the 256 bucket, over the
+    threshold: its expert layers sort and group, the decode program's
+    (2 rows a step) are batched over the held experts, and the served
+    tokens are the reference's through both."""
+    prompt = np.random.default_rng(5).integers(0, 256, 150).astype(np.int32)
+    pred = create_predictor(Config().set_model(build(max_len=384))
+                            .enable_paged_kv(page_size=8))
+    eng = ServingEngine(pred, max_batch=2)
+    assert eng.moe_stats()["forms"] == {"decode": None, "prefill": None}
+    rid = eng.submit(prompt, max_new_tokens=6)
+    served = np.asarray(eng.run()[rid].new_tokens)
+    assert ref.served_gap(ref_logits(prompt, served), served).max() < 1e-3
+    st = eng.moe_stats()
+    assert st["forms"] == {"decode": "batched", "prefill": "sorted"}
+    assert st["dropped"] == 0
+    np.testing.assert_array_equal(st["summed_pairs"], st["pairs"].sum(1))
+    assert eng.program_sites() == [("prefill", 256), ("decode",)]
+    assert "ragged_dot" in tpu_program_text(eng, ("prefill", 256))
+    assert "ragged_dot" not in tpu_program_text(eng, ("decode",))
 
 
 def test_generate_static_and_paged_agree_with_full_forwards(model, tokens):
@@ -219,7 +252,12 @@ def test_selection_bias_changes_the_choice_and_never_the_weights():
         np.testing.assert_allclose(w.sum(1), 2.5, rtol=1e-5)
 
 
-def test_every_token_to_one_expert_drops_nothing():
+FORMS = {"sorted": moe_layer.routed_swiglu_sorted,
+         "batched": moe_layer.routed_swiglu_batched}
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_every_token_to_one_expert_drops_nothing(form):
     """No capacity: 40 tokens that all choose held expert 2 (and three
     absent ones) are all computed."""
     rng = np.random.default_rng(4)
@@ -229,42 +267,164 @@ def test_every_token_to_one_expert_drops_nothing():
     wd = jnp.asarray(rng.normal(0, 0.2, (4, 32, 64)), jnp.float32)
     idx = jnp.tile(jnp.asarray([[9, 6, 12, 1]], jnp.int32), (40, 1))
     g = jnp.asarray(rng.uniform(0.1, 1, (40, 4)), jnp.float32)
-    y, sizes = routed_swiglu(x, idx, g, wg, wu, wd, expert_offset=4)
-    # group sizes, pairs of absent experts, pairs computed and summed
+    y, sizes = FORMS[form](x, idx, g, wg, wu, wd, expert_offset=4)
+    # pairs an expert, pairs of absent experts, pairs computed and summed
     assert list(np.asarray(sizes)) == [0, 0, 40, 0, 120, 40]
     want = g[:, 1:2] * ((jax.nn.silu(x @ wg[2]) * (x @ wu[2])) @ wd[2])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
                                atol=1e-5)
 
 
+def _routing(case, rng, T, k, E):
+    """[T, k] choices over E experts of which 4..7 are held (offset 4),
+    or 0..3 for ``random`` (offset 0)."""
+    if case == "one_held_expert":
+        return np.tile([[9, 6, 12, 1]], (T, 1)), 4
+    if case == "no_held_pair":      # every choice outside 4..7
+        pool = np.asarray([0, 1, 2, 3, 8, 9, 10, 11, 12, 13, 14, 15])
+        return np.stack([rng.permutation(pool)[:k] for _ in range(T)]), 4
+    idx = np.stack([rng.permutation(E)[:k] for _ in range(T)])
+    return idx, (4 if case == "random_at_an_offset" else 0)
+
+
+@pytest.mark.parametrize("case", ["random", "random_at_an_offset",
+                                  "one_held_expert", "no_held_pair"])
+def test_batched_form_is_the_sorted_form_is_a_loop_over_tokens(case):
+    """The two forms of ``routed_swiglu`` and a plain loop (token by
+    token, choice by choice) agree on seeded float32 weights, and their
+    ``sizes`` agree element by element."""
+    rng = np.random.default_rng(11)
+    T, k, E, El, d, h = 24, 4, 16, 4, 64, 32
+    x = rng.normal(0, 1, (T, d)).astype(np.float32)
+    wg, wu = (rng.normal(0, 0.2, (El, d, h)).astype(np.float32)
+              for _ in range(2))
+    wd = rng.normal(0, 0.2, (El, h, d)).astype(np.float32)
+    idx, off = _routing(case, rng, T, k, E)
+    w = rng.uniform(0.1, 1, (T, k)).astype(np.float32)
+    want = np.zeros((T, d), np.float64)
+    pairs = np.zeros(El, np.int64)
+    for t in range(T):
+        for j in range(k):
+            e = idx[t, j] - off
+            if 0 <= e < El:
+                g, u = x[t] @ wg[e], x[t] @ wu[e]
+                want[t] += w[t, j] * ((g / (1 + np.exp(-g)) * u) @ wd[e])
+                pairs[e] += 1
+    sizes_want = list(pairs) + [T * k - pairs.sum(), pairs.sum()]
+    if case == "no_held_pair":
+        assert pairs.sum() == 0
+    args = (jnp.asarray(x), jnp.asarray(idx, jnp.int32), jnp.asarray(w),
+            jnp.asarray(wg), jnp.asarray(wu), jnp.asarray(wd), off)
+    for form in FORMS.values():
+        y, sizes = form(*args)
+        assert sizes.dtype == jnp.int32 and sizes.shape == (El + 2,)
+        assert list(np.asarray(sizes)) == sizes_want
+        np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4,
+                                   atol=1e-5)
+    # the public entry takes the form its token count asks for
+    y, sizes = routed_swiglu(*args)
+    assert list(np.asarray(sizes)) == sizes_want
+
+
 def test_a_capacity_on_the_groups_shows_as_dropped_pairs(monkeypatch):
-    """The computed pairs are counted from the sorted rows and the group
-    sizes, not from the router's choice: clamp the groups to 16 rows, as
-    a capacity would, and 24 of the 40 held pairs are missing from the
-    count (``ServingEngine.moe_stats()["dropped"]`` = held - summed)."""
+    """The SORTED form's computed pairs are counted from the sorted rows
+    and the group sizes, not from the router's choice: clamp the groups
+    to 16 rows, as a capacity would, and 24 of the 40 held pairs are
+    missing from the count (``ServingEngine.moe_stats()["dropped"]`` =
+    held - summed)."""
     real = jnp.bincount
     monkeypatch.setattr(jnp, "bincount", lambda *a, **k: jnp.minimum(
         real(*a, **k), 16))
     x = jnp.ones((40, 64), jnp.float32)
     w = jnp.ones((4, 64, 32), jnp.float32)
     idx = jnp.tile(jnp.asarray([[9, 6, 12, 1]], jnp.int32), (40, 1))
-    _, sizes = routed_swiglu(x, idx, jnp.ones((40, 4)), w, w,
-                             jnp.ones((4, 32, 64)), expert_offset=4)
+    _, sizes = moe_layer.routed_swiglu_sorted(
+        x, idx, jnp.ones((40, 4)), w, w, jnp.ones((4, 32, 64)),
+        expert_offset=4)
     absent, summed = map(int, sizes[-2:])
     assert (absent, summed) == (120, 16)
     assert 40 * 4 - absent - summed == 24
 
 
-def test_expert_layer_builds_no_dispatch_tensor():
-    """Work in proportion to the routed pairs: no intermediate of the
-    lowered layer has T x E x anything elements ([T, E, C] algebra)."""
-    layer = _expert_layer(0, 16)
-    T = 64
-    x = jnp.zeros((T, 64), jnp.float32)
-    jaxpr = jax.make_jaxpr(lambda v: layer(v)._value)(x)
-    biggest = max(int(np.prod(v.aval.shape)) for eq in jaxpr.jaxpr.eqns
-                  for v in eq.outvars if hasattr(v.aval, "shape"))
-    assert biggest <= T * 4 * 64        # [T*k, d]: the sorted pairs
+def test_a_combine_that_misses_an_expert_shows_as_dropped_pairs(
+        monkeypatch):
+    """The BATCHED form's computed pairs are counted from the combine
+    that is applied, not from the router's choice: zero the column of
+    held expert 2 and its 40 pairs are missing from the count and from
+    the sum, while the pairs of expert 1 (10 tokens) stay."""
+    real = moe_layer._combine
+
+    def without_expert_2(*a):
+        pairs, c = real(*a)
+        return pairs.at[:, 2].set(0), c
+
+    x = jnp.ones((40, 64), jnp.float32)
+    w = jnp.ones((4, 64, 32), jnp.float32)
+    idx = jnp.tile(jnp.asarray([[9, 6, 12, 1]], jnp.int32), (40, 1))
+    idx = idx.at[:10, 0].set(5)
+    args = (x, idx, jnp.ones((40, 4)), w, w, jnp.ones((4, 32, 64)), 4)
+    y_all, sizes = moe_layer.routed_swiglu_batched(*args)
+    assert [int(v) for v in sizes[-2:]] == [110, 50]
+    monkeypatch.setattr(moe_layer, "_combine", without_expert_2)
+    y, sizes = moe_layer.routed_swiglu_batched(*args)
+    absent, summed = map(int, sizes[-2:])
+    assert (absent, summed) == (110, 10)
+    assert 40 * 4 - absent - summed == 40
+    assert float(jnp.abs(y[10:]).max()) == 0.0 < float(y_all[10:].min())
+    np.testing.assert_allclose(np.asarray(y[:10]),
+                               np.asarray(y_all[:10]) / 2, rtol=1e-6)
+
+
+def _traced(layer, T):
+    """(intermediate shapes of the layer's forward over T tokens, the
+    forms its ``routed_swiglu`` recorded, whether XLA's grouped matmul is
+    in it)."""
+    from paddle_tpu.observability import moestats
+
+    moestats.begin()
+    try:
+        jaxpr = jax.make_jaxpr(lambda v: layer(v)._value)(
+            jnp.zeros((T, layer.d_model), jnp.float32))
+    finally:
+        forms = [r["form"] for r in moestats.drain()]
+    shapes = [tuple(v.aval.shape) for eq in jaxpr.jaxpr.eqns
+              for v in eq.outvars if hasattr(v.aval, "shape")]
+    return shapes, forms, "ragged_dot" in str(jaxpr)
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_expert_layer_builds_no_dispatch_tensor(form):
+    """Neither form has [T, E, C] algebra: the router's width (24) shows
+    in its own [T, 24] scores and nowhere else, there is no capacity,
+    and the largest intermediate is the sorted pairs' [T*k, d] above the
+    threshold (by h, the wider of h and d here) and the held experts'
+    [El, T, h] at or below it."""
+    paddle.seed(0)
+    d, h, E, El, k = 32, 64, 24, 6, 4
+    layer = GatedMoELayer(d, h, E, El, 6, top_k=k,
+                          routed_scaling_factor=2.5)
+    T = moe_layer._BATCHED_MAX_TOKENS + (40 if form == "sorted" else -40)
+    shapes, forms, _ = _traced(layer, T)
+    assert forms == [form]
+    assert all(s in ((T, E), (E,), (1, E)) for s in shapes if E in s), \
+        [s for s in shapes if E in s]
+    biggest = max(shapes, key=lambda s: int(np.prod(s)))
+    # (the shared expert's [T, h] and the broadcast tokens [El, T, d]
+    # are smaller than either)
+    assert int(np.prod(biggest)) == \
+        (T * k * h if form == "sorted" else El * T * h), biggest
+
+
+def test_the_form_is_a_function_of_the_token_count_alone():
+    """At the threshold the layer traces the batched form, one token
+    above it the sorted one; nothing else is asked."""
+    layer = _expert_layer(4, 4)
+    edge = moe_layer._BATCHED_MAX_TOKENS
+    assert [moe_layer.routed_form(t) for t in (1, edge, edge + 1, 4096)] \
+        == ["batched", "batched", "sorted", "sorted"]
+    for T, form in ((edge, "batched"), (edge + 1, "sorted")):
+        _, forms, grouped = _traced(layer, T)
+        assert forms == [form] and grouped == (form == "sorted")
 
 
 @pytest.mark.parametrize("lengths", [[0, 5, 17, 63], [31, 32, 33, 16]])
@@ -335,7 +495,9 @@ def test_serving_programs_compile_for_a_v5e_in_place():
     the benchmark configuration's widths (two layers, no weights),
     compiled by the TPU compiler for a described v5e in a process of its
     own: 0 pool-shaped copies, the latent kernel in decode, XLA's
-    grouped matmul in both."""
+    grouped matmul in the prefill program and not in the decode program
+    (its products are batched over the 32 held experts), and no copy or
+    transpose of a stacked expert weight array in either."""
     import json
     import subprocess
 
@@ -349,7 +511,9 @@ def test_serving_programs_compile_for_a_v5e_in_place():
     progs = {c["program"]: c for c in out["programs"]}
     assert set(progs) == {"decode", "prefill_1024"}
     for c in progs.values():
-        assert c["pool_copies"] == 0 and c["ragged_dot"], c
+        assert c["pool_copies"] == 0 and c["expert_weight_copies"] == 0, c
+    assert not progs["decode"]["ragged_dot"]
+    assert progs["prefill_1024"]["ragged_dot"]
     assert progs["decode"]["kernels"] == ["mla_paged_decode_attention"] \
         and not progs["prefill_1024"]["kernels"]
     # the pools and the routing counters are donated, the round's one

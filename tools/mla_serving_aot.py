@@ -14,7 +14,11 @@ Per program: pool-shaped ``copy`` ops in the optimized HLO (0 = the
 pools of every page class are written in place), which of the decode
 kernels (``mla_paged_decode_attention``, ``paged_decode_attention``,
 ``paged_window_decode_attention``) and whether XLA's grouped matmul
-(``ragged-dot``) are in it, the largest float32 buffer (a prefill program
+(``ragged-dot``) are in it (the prefill programs' expert layers sort and
+group; a decode step's are batched over the held experts and hold none),
+copies or transposes of a stacked expert weight array (0: the batched
+products read ``[El, d, h]`` and ``[El, h, d]`` as they lie), the largest
+float32 buffer (a prefill program
 of the window/full decoder holds no ``[heads, S, S]`` one), and the compiler's
 memory analysis, and the parameters it aliases to outputs: the pools and
 the routing counters of the decode program, and not its round array. Prints one JSON line, ``{"programs": [...]}`` or
@@ -111,6 +115,18 @@ def main(argv):
     kernels = ("mla_paged_decode_attention", "paged_decode_attention",
                "paged_window_decode_attention")
     pool_shapes = {s.shape for pair in eng.pools for s in pair}
+    # the held experts' stacked weights, [El, d, h] and [El, h, d] (and
+    # [El * h, d], as the batched down product reads them): an op named
+    # for a copy or a transpose with such a result moves 268 or 537 MB
+    expert_shapes = {tuple(p._value.shape) for p in pred._params
+                     if len(p._value.shape) == 3}
+    expert_shapes |= {(s[0] * s[1], s[2]) for s in expert_shapes}
+
+    def weight_copies(text, dims):
+        return len(re.findall(
+            r"^\s*(?:ROOT\s+)?%?[\w.\-]*(?:copy|transpose)[\w.\-]*\s*=\s*"
+            r"\w+\[" + ",".join(str(d) for d in dims) + r"\]", text, re.M))
+
     out = []
     for name, (fn, avals) in programs.items():
         compiled = fn.lower(*avals).compile()
@@ -127,6 +143,8 @@ def main(argv):
             "kernels": [k for k in kernels
                         if re.search(rf"(?<!\w){k}(?!\w)", text)],
             "ragged_dot": "ragged-dot" in text,
+            "expert_weight_copies": sum(
+                weight_copies(text, s) for s in expert_shapes),
             "largest_f32_elements": max(
                 (math.prod(int(n) for n in d.split(",") if n)
                  for d in re.findall(r"f32\[([\d,]*)\]", text)),
