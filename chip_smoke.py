@@ -21,9 +21,14 @@ layers through the same entry points: latent pools written in place,
 
 ``--phases serve_hybrid`` (only when named) serves the decoder with
 window and full attention layers (models/hybrid_moe.py) at its published
-widths and three layers the same way: both decode kernels against their
-dense twins on the chip, 40 decode rounds past a ring's wrap, two
-classes of pools written in place.
+widths and three layers the same way, in its two shapes one after the
+other (MiMo-V2's block: keys 192 against values 128, a window of 128
+with a sink, a ring of 2 pages; then the AFMoE block: every switch on, a
+window of 2,048 without rotary on the full layer, a ring of 17 pages, a
+shared expert, the whole 200,192-row vocabulary with the head on a
+prefill's last row): both decode kernels against their dense twins on
+the chip, 40 decode rounds past a ring's wrap, two classes of pools
+written in place.
 
 ``--four-chips`` adds the same train step over a real 2x2 mesh in two
 layouts (mp2 x dp2 on ParallelEngine; pp2 x mp2 on GPTForCausalLMPipe via
@@ -73,7 +78,7 @@ EXTRA_PHASES = ("serve_latent", "serve_hybrid")
 # they took 72, 122 and 106 s (CHANGES.md PR 21).
 PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
                  "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 400,
-                 "serve_hybrid": 500}
+                 "serve_hybrid": 900}    # two shapes since PR 35
 RESULT_TAG = "CHIP_SMOKE_PHASE_RESULT "
 
 # Tolerances, each with its reason. Every comparison is
@@ -165,6 +170,28 @@ class Sizes:
             # prompts that end just short of a ring's wrap (position 256
             # at pages of 128, a ring of 2), then 40 decode rounds
             self.hybrid_lens, self.hybrid_new = (250, 240, 200, 100), 40
+            # the same phase's second shape: the AFMoE block (every
+            # switch of HybridMoEConfig on) at its published widths: 32
+            # heads on 4 KV heads of 128, a window of 2048 (a ring of 17
+            # pages), a dense layer and two expert layers that hold 16
+            # of 128 experts beside a shared one, the whole vocabulary
+            # (1.15B parameters); the longest prompt ends just short of
+            # the ring's wrap at position 2176
+            self.afmoe = dict(
+                vocab_size=200192, hidden_size=2048,
+                attention_kinds=["window", "full", "window"],
+                ffn_kinds=["dense", "experts", "experts"], num_heads=32,
+                num_kv_heads=4, window_num_kv_heads=4, qk_head_dim=128,
+                v_head_dim=128, rotary_dim=128, window_rope_theta=10000.0,
+                rotary_kinds=("window",), sliding_window=2048,
+                window_sink=False, value_scale=1.0,
+                intermediate_size=6144, moe_intermediate_size=1024,
+                num_experts=128, num_local_experts=16,
+                routed_scaling_factor=2.826, num_shared_experts=1,
+                qk_norm=True, attention_gate=True, sandwich_norm=True,
+                embedding_multiplier=2048 ** 0.5, head_on_last_row=True,
+                max_position_embeddings=2304, dtype="bfloat16")
+            self.afmoe_lens = (2150, 2040, 1000, 100)
         else:
             self.gpt = dict(vocab_size=1024, hidden_size=128,
                             num_layers=2, num_heads=4,
@@ -201,6 +228,22 @@ class Sizes:
                 attention_block=32, dtype="bfloat16")
             self.hybrid_batch = 4
             self.hybrid_lens, self.hybrid_new = (30, 28, 20, 10), 12
+            self.afmoe = dict(
+                vocab_size=512, hidden_size=128,
+                attention_kinds=["window", "full", "window"],
+                ffn_kinds=["dense", "experts", "experts"], num_heads=8,
+                num_kv_heads=2, window_num_kv_heads=2, qk_head_dim=32,
+                v_head_dim=32, rotary_dim=32, window_rope_theta=100.0,
+                rotary_kinds=("window",), sliding_window=48,
+                window_sink=False, value_scale=1.0,
+                intermediate_size=256, moe_intermediate_size=64,
+                num_experts=16, num_local_experts=4,
+                num_experts_per_tok=4, routed_scaling_factor=2.826,
+                num_shared_experts=1, qk_norm=True, attention_gate=True,
+                sandwich_norm=True, embedding_multiplier=128 ** 0.5,
+                head_on_last_row=True, max_position_embeddings=288,
+                attention_block=32, dtype="bfloat16")
+            self.afmoe_lens = (60, 50, 20, 10)
 
 
 # ---------------------------------------------------------------------------
@@ -946,11 +989,25 @@ def phase_serve_latent(sz: Sizes) -> None:
 
 def phase_serve_hybrid(sz: Sizes) -> None:
     """The decoder with window and full attention layers through
-    ServingEngine in its default mode: both decode kernels agree with
-    their dense twins at the model's own shapes, decode runs past a
-    ring's wrap, two classes of pools are written in place, no routed
-    pair is dropped."""
-    jax, device, events = start_child(sz.rehearsal)
+    ServingEngine in its default mode, in both of its shapes
+    (``serve_hybrid_shape``)."""
+    _, device, events = start_child(sz.rehearsal)
+    extra = {}
+    for name, sizes, lens in (("mimo", sz.hybrid, sz.hybrid_lens),
+                              ("afmoe", sz.afmoe, sz.afmoe_lens)):
+        print(f"  -- {name} --", flush=True)
+        extra[name] = serve_hybrid_shape(sz, sizes, lens)
+    finish_child("serve_hybrid", device, events, extra)
+
+
+def serve_hybrid_shape(sz: Sizes, sizes: dict, prompt_lens) -> dict:
+    """One ``HybridMoEConfig``: both decode kernels agree with their
+    dense twins at the model's own shapes, decode runs past a ring's
+    wrap, two classes of pools are written in place, no routed pair is
+    dropped."""
+    import gc
+
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -961,7 +1018,7 @@ def phase_serve_hybrid(sz: Sizes) -> None:
                                               HybridMoEForCausalLM)
     from paddle_tpu.ops.pallas import decode_attention as da
 
-    cfg = HybridMoEConfig(**sz.hybrid)
+    cfg = HybridMoEConfig(**sizes)
     page, B = sz.page, sz.hybrid_batch
     ring = -(-cfg.sliding_window // page) + 1
     r = np.random.RandomState(0)
@@ -969,8 +1026,10 @@ def phase_serve_hybrid(sz: Sizes) -> None:
     # with: rows below, at and past the window, and at the ring's seam
     W = cfg.sliding_window
     lens = np.resize([0, W - 1, W, W + 1, 2 * page - 1, 2 * page,
-                      5 * page + 3], B).astype("int32")
-    for kind, ncols, window in (("full", 6, None), ("window", ring, W)):
+                      5 * page + 3, ring * page + 5], B).astype("int32")
+    full_cols = int(lens.max()) // page + 1
+    for kind, ncols, window in (("full", full_cols, None),
+                                ("window", ring, W)):
         KV = cfg.kv_heads(kind)
         P = B * ncols + 1
         rnd = lambda *shape: jnp.asarray(r.standard_normal(shape),
@@ -1007,7 +1066,7 @@ def phase_serve_hybrid(sz: Sizes) -> None:
     pred = create_predictor(
         Config().set_model(model).enable_paged_kv(page_size=page))
     mix = [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
-           for n in sz.hybrid_lens]
+           for n in prompt_lens]
     logits = pred.run([mix[0][None, :]])[0]
     ref_last = logits[0, -1].astype("float32")
     check(np.isfinite(ref_last).all(), "reference forward: finite logits")
@@ -1069,9 +1128,16 @@ def phase_serve_hybrid(sz: Sizes) -> None:
         if site == ("decode",):     # the routing counters ride along
             check_decode_donation(eng, text, 3 * cfg.num_layers)
     check_overlap(eng)
-    finish_child("serve_hybrid", device, events,
-                 {"run_s": round(t_run, 2), "pool_pages": eng.P,
-                  "window_pool_pages": eng.cache.Pw})
+    out = {"run_s": round(t_run, 2), "pool_pages": eng.P,
+           "window_pool_pages": eng.cache.Pw, "ring": ring}
+    # the next shape needs the memory
+    eng.release_pools()
+    for p in model.parameters():
+        p._value = None
+    del eng, pred, model
+    gc.collect()
+    jax.clear_caches()
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -284,12 +284,20 @@ class Predictor:
         from ..autograd import no_grad
         from ..distributed.engine import bind_params
 
+        # a model may run its head on each row's last prompt token alone
+        # (``head_on_last_row``: its forward takes the lengths and
+        # returns [B, vocab]); the others compute every position's
+        # logits and one row of them is gathered here
+        last_row = bool(getattr(model, "head_on_last_row", False))
+
         def prefill(pvals, ids, caches, lengths):
             with no_grad(), bind_params(params, pvals):
                 logits, caches = model.forward(
                     Tensor(ids, stop_gradient=True), caches=caches,
-                    offset=0)
+                    offset=0, **({"lengths": lengths} if last_row else {}))
             lv = logits._value if isinstance(logits, Tensor) else logits
+            if last_row:
+                return lv, caches
             # gather each row's logits at its true last prompt token
             last = jnp.take_along_axis(
                 lv, (lengths - 1)[:, None, None], axis=1)[:, 0]

@@ -853,6 +853,8 @@ class ServingEngine:
         m["prefill_seconds"].observe(now - t0)
         m["ttft"].observe(now - req.t_submit)
         m["tokens"].inc(1, phase="prefill")
+        m["prefill_tokens"].inc(L, kind="prompt")
+        m["prefill_tokens"].inc(Sb, kind="bucket")
         tr = self._live_traces.get(req.rid)
         if tr is not None:
             held = {"full_pages": len(slot.pages),
@@ -1498,10 +1500,15 @@ class ServingEngine:
         live = [s for s in self.slots if s is not None]
         if live:
             cache = self.cache
+            ctx = [s.pos + len(s.req.new_tokens) for s in live]
             m["kv_bytes_per_token"].set(
                 (sum(len(s.pages) for s in live) * cache.page_bytes
                  + len(live) * cache.ring * cache.window_page_bytes)
-                / sum(s.pos + len(s.req.new_tokens) for s in live))
+                / sum(ctx))
+            if cache.window:
+                m["window_ring_fill"].set(
+                    sum(min(cache.pages_for(c), cache.ring) for c in ctx)
+                    / (len(live) * cache.ring))
 
     def _drain(self):
         """Retire the round in flight, if any: whoever reads or

@@ -8,11 +8,12 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
                     llama_tiny, llama_tiny_draft)
 
 from .mla_moe import MLAMoEConfig, MLAMoEForCausalLM, mla_moe_tiny
-from .hybrid_moe import (HybridMoEConfig, HybridMoEForCausalLM,
+from .hybrid_moe import (HybridMoEConfig, HybridMoEForCausalLM, afmoe_tiny,
                          hybrid_moe_tiny)
 
 __all__ = ["MLAMoEConfig", "MLAMoEForCausalLM", "mla_moe_tiny",
            "HybridMoEConfig", "HybridMoEForCausalLM", "hybrid_moe_tiny",
+           "afmoe_tiny",
            "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTForCausalLMPipe",
            "GPTPretrainingCriterion", "gpt_tiny", "gpt_125m", "gpt_350m",
            "gpt_1p3b", "gpt_13b", "gpt_moe_tiny", "ernie_moe_base",

@@ -4,14 +4,26 @@ Attention is ``"full"`` (every earlier position) or ``"window"`` (the
 last ``sliding_window`` positions, the current one included), each kind
 with its own number of KV heads and rotary base; keys and queries are
 ``qk_head_dim`` wide against ``v_head_dim``-wide values, rotary on the
-first ``rotary_dim`` dims of a head, the values scaled by
-``value_scale``, and a kind may carry one learned SINK logit a query
-head that takes weight in the softmax and gives no value. The
+first ``rotary_dim`` dims of a head of the kinds in ``rotary_kinds`` (a
+kind left out turns nothing: its scores carry no position), the values
+scaled by ``value_scale``, and a kind may carry one learned SINK logit a
+query head that takes weight in the softmax and gives no value. The
 feed-forward is ``"dense"`` (SwiGLU) or ``"experts"`` (``GatedMoELayer``:
 a sigmoid top-k router over ``num_experts``, of which this holder has
-``num_local_experts`` from ``expert_offset`` on, no shared expert). The
-block of the MiMo-V2 line (``model_type`` ``mimo_v2_flash``); every size
-is data of ``HybridMoEConfig``.
+``num_local_experts`` from ``expert_offset`` on, beside
+``num_shared_experts`` that every holder runs whole).
+
+Switches, all data of ``HybridMoEConfig`` and all off by default:
+``qk_norm`` (an RMSNorm on every q and k head before the rotation),
+``attention_gate`` (the attention result times ``sigmoid(u W_g)``, as
+wide as the result, before ``o_proj``), ``sandwich_norm`` (a norm on
+each branch's OUTPUT as well as on its input: four a layer),
+``embedding_multiplier`` (the embedding's rows scaled once, at entry)
+and ``head_on_last_row`` (below). With the defaults this is the block
+of the MiMo-V2 line (``model_type`` ``mimo_v2_flash``); with window-only
+rotary, q/k norms, the gate, sandwich norms, a multiplier of
+``sqrt(hidden_size)`` and a shared expert it is the block of the AFMoE
+line (``model_type`` ``afmoe``). Nothing asks a model's name.
 
 It honours the serving contract of ``LlamaForCausalLM``:
 ``forward(input_ids, caches, offset)`` with per-layer paged tuples
@@ -23,6 +35,15 @@ a ring of pages a row instead of the whole context, and its table is
 that ring. The forward takes no ``valid``: the unified ragged step
 (chunked prefill, and with it the prefix cache, host spill and
 speculative decoding) is refused by the engine at construction.
+
+THE HEAD ON THE LAST ROW. A prefill needs one row of logits a prompt.
+With ``head_on_last_row`` the model says so (``model.head_on_last_row``)
+and ``Predictor._prefill_fn`` hands ``forward`` the rows' ``lengths``:
+the final norm and the head then run on ``x[b, lengths[b] - 1]`` alone
+and the program returns ``[B, vocab]``; no ``[B, S, vocab]`` array
+exists (6.6 GB in float32 for 8,192 positions of a 200,192-row
+vocabulary). Off, the program computes every position's logits and the
+caller gathers one row, as it always did.
 
 Attention forms, chosen at trace time:
 
@@ -44,7 +65,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,7 +85,8 @@ from ..tensor import Tensor
 from .llama import _apply_rope, _dispatch_kernel
 from .mla_moe import DenseSwiGLU, _attr, _mm, _rms
 
-__all__ = ["HybridMoEConfig", "HybridMoEForCausalLM", "hybrid_moe_tiny"]
+__all__ = ["HybridMoEConfig", "HybridMoEForCausalLM", "hybrid_moe_tiny",
+           "afmoe_tiny"]
 
 
 @dataclass
@@ -99,6 +121,14 @@ class HybridMoEConfig:
     rms_norm_eps: float = 1e-5
     initializer_range: float = 0.02
     attention_block: int = 512               # prefill's rows a block
+    # -- switches (module docstring); the defaults are MiMo-V2's block ----
+    rotary_kinds: Tuple[str, ...] = ("full", "window")
+    qk_norm: bool = False
+    attention_gate: bool = False
+    sandwich_norm: bool = False
+    embedding_multiplier: float = 1.0
+    num_shared_experts: int = 0
+    head_on_last_row: bool = False
     dtype: str = "float32"
 
     def __post_init__(self):
@@ -112,6 +142,9 @@ class HybridMoEConfig:
         enforce(self.rotary_dim % 2 == 0
                 and self.rotary_dim <= self.qk_head_dim,
                 "rotary_dim is an even number of a head's leading dims")
+        self.rotary_kinds = tuple(self.rotary_kinds)
+        enforce(set(self.rotary_kinds) <= {"full", "window"},
+                "rotary_kinds names attention kinds (full | window)")
 
     @property
     def num_layers(self) -> int:
@@ -170,8 +203,29 @@ class HybridAttention(Layer):
         self.has_sink = cfg.sink(kind)
         if self.has_sink:
             self.sinks = self.create_parameter((H,), attr=_attr(1.0))
-        self._rope = _rope_tables(cfg.rotary_dim, cfg.theta(kind),
-                                  cfg.max_position_embeddings)
+        if cfg.qk_norm:
+            ones = ParamAttr(initializer=I.Constant(1.0))
+            self.q_norm = self.create_parameter((cfg.qk_head_dim,),
+                                                attr=ones)
+            self.k_norm = self.create_parameter((cfg.qk_head_dim,),
+                                                attr=ones)
+        if cfg.attention_gate:
+            self.gate_proj = self.create_parameter(
+                (h, H * cfg.v_head_dim), attr=_attr(std))
+        self.rotates = kind in cfg.rotary_kinds
+        if self.rotates:
+            self._rope = _rope_tables(cfg.rotary_dim, cfg.theta(kind),
+                                      cfg.max_position_embeddings)
+
+    def _heads(self, x, proj, heads, norm, offset):
+        """One of q / k: the projection split into ``heads``, each head
+        normed (``qk_norm``) and turned (a kind in ``rotary_kinds``)."""
+        cfg = self.cfg
+        y = _mm(x, proj._value).reshape(
+            x.shape[0], x.shape[1], heads, cfg.qk_head_dim)
+        if cfg.qk_norm:
+            y = _rms(y, norm._value, cfg.rms_norm_eps)
+        return self._rotate(y, offset) if self.rotates else y
 
     def _rotate(self, x, offset):
         r = self.cfg.rotary_dim
@@ -188,10 +242,10 @@ class HybridAttention(Layer):
             cfg.v_head_dim
         scale, window = cfg.softmax_scale, self.window
         sinks = self.sinks._value if self.has_sink else None
-        q = self._rotate(_mm(x, self.q_proj._value).reshape(B, S, H, dk),
-                         offset)
-        k = self._rotate(_mm(x, self.k_proj._value).reshape(B, S, KV, dk),
-                         offset)
+        q = self._heads(x, self.q_proj, H,
+                        self.q_norm if cfg.qk_norm else None, offset)
+        k = self._heads(x, self.k_proj, KV,
+                        self.k_norm if cfg.qk_norm else None, offset)
         v = (_mm(x, self.v_proj._value) * cfg.value_scale).astype(
             x.dtype).reshape(B, S, KV, dv)
         lanes = ((0, 0),) * 3 + ((0, cfg.k_cache_width - dk),)
@@ -245,7 +299,11 @@ class HybridAttention(Layer):
                                        (B, M))
                 o = _da.attention_dense_masked(qp, k_pool, v_pool, pos,
                                                off, scale, sinks, window)
-        return _mm(o.reshape(B, S, H * dv), self.o_proj._value), new_cache
+        o = o.reshape(B, S, H * dv)
+        if cfg.attention_gate:
+            g = _mm(x, self.gate_proj._value).astype(jnp.float32)
+            o = (o.astype(jnp.float32) * jax.nn.sigmoid(g)).astype(x.dtype)
+        return _mm(o, self.o_proj._value), new_cache
 
 
 class HybridMoEDecoderLayer(Layer):
@@ -258,7 +316,12 @@ class HybridMoEDecoderLayer(Layer):
                                                      attr=ones)
         self.self_attn = HybridAttention(cfg, self.attn_kind)
         self.post_attention_layernorm = self.create_parameter(
-            (cfg.hidden_size,), attr=ones)
+            (cfg.hidden_size,), attr=ones)      # the feed-forward's INPUT
+        if cfg.sandwich_norm:                   # and each branch's output
+            self.attention_out_layernorm = self.create_parameter(
+                (cfg.hidden_size,), attr=ones)
+            self.mlp_out_layernorm = self.create_parameter(
+                (cfg.hidden_size,), attr=ones)
         self.is_moe = cfg.ffn_kinds[index] == "experts"
         if self.is_moe:
             std = cfg.initializer_range
@@ -267,17 +330,21 @@ class HybridMoEDecoderLayer(Layer):
                 cfg.num_experts, cfg.num_local_experts, cfg.expert_offset,
                 top_k=cfg.num_experts_per_tok,
                 routed_scaling_factor=cfg.routed_scaling_factor,
-                num_shared_experts=0, weight_attr=_attr(std),
+                num_shared_experts=cfg.num_shared_experts,
+                weight_attr=_attr(std),
                 down_attr=_attr(std / math.sqrt(2 * cfg.num_layers)))
         else:
             self.mlp = DenseSwiGLU(cfg)
 
     def forward(self, x, cache=None, offset=0):
         eps, i = self.cfg.rms_norm_eps, self.index
+        sandwich = self.cfg.sandwich_norm
         with _annotate(f"layer{i}.attn.{self.attn_kind}"):
             a, cache = self.self_attn(
                 _rms(x, self.input_layernorm._value, eps), cache=cache,
                 offset=offset)
+            if sandwich:
+                a = _rms(a, self.attention_out_layernorm._value, eps)
         x = x + a
         with _annotate(f"layer{i}.moe" if self.is_moe else f"layer{i}.mlp"):
             h = _rms(x, self.post_attention_layernorm._value, eps)
@@ -288,6 +355,8 @@ class HybridMoEDecoderLayer(Layer):
                 y, cache = y._value, cache[:3] + (counts,)
             else:
                 y = self.mlp(h)._value
+            if sandwich:
+                y = _rms(y, self.mlp_out_layernorm._value, eps)
         return x + y, cache
 
 
@@ -339,21 +408,41 @@ class HybridMoEForCausalLM(Layer):
                  jnp.zeros((B,) + b[1:2] + (max_len,) + b[3:], dtype))
                 for a, b in self.kv_pool_shapes(1, 1)]
 
-    def forward(self, input_ids, caches=None, offset=0):
+    @property
+    def head_on_last_row(self) -> bool:
+        """Whether a prefill program hands ``forward`` the rows'
+        ``lengths`` (``Predictor._prefill_fn`` asks)."""
+        return self.config.head_on_last_row
+
+    def _head(self, x):
+        x = _rms(x, self.norm._value, self.config.rms_norm_eps)
+        return Tensor(jnp.dot(x, self.lm_head._value,
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype), stop_gradient=True)
+
+    def forward(self, input_ids, caches=None, offset=0, lengths=None):
+        """Logits ``[B, S, vocab]``; with ``lengths`` ``[B]`` (a prefill
+        of a ``head_on_last_row`` model) ``[B, vocab]``, of row b's
+        position ``lengths[b] - 1`` alone."""
         ids = input_ids._value if isinstance(input_ids, Tensor) \
             else jnp.asarray(input_ids)
         with _annotate("hybrid_moe"):
             with _annotate("embed"):
                 x = self.embed_tokens._value[ids]
+                if self.config.embedding_multiplier != 1.0:
+                    x = x * self.config.embedding_multiplier
             new_caches = []
             for i, layer in enumerate(self.layers):
                 x, nc = layer(x, cache=None if caches is None
                               else caches[i], offset=offset)
                 new_caches.append(nc)
-            x = _rms(x, self.norm._value, self.config.rms_norm_eps)
-            logits = Tensor(jnp.dot(x, self.lm_head._value,
-                                    preferred_element_type=jnp.float32
-                                    ).astype(x.dtype), stop_gradient=True)
+            if lengths is None:
+                logits = self._head(x)
+            else:
+                with _annotate("head"):
+                    last = jnp.asarray(lengths, jnp.int32) - 1
+                    logits = self._head(jnp.take_along_axis(
+                        x, last[:, None, None], axis=1)[:, 0])
         return logits if caches is None else (logits, new_caches)
 
 
@@ -373,6 +462,33 @@ def hybrid_moe_tiny(**kw) -> HybridMoEConfig:
                 moe_intermediate_size=32, num_experts=16,
                 num_local_experts=4, expert_offset=4,
                 num_experts_per_tok=4, max_position_embeddings=128,
+                attention_block=16)
+    base.update(kw)
+    return HybridMoEConfig(**base)
+
+
+def afmoe_tiny(**kw) -> HybridMoEConfig:
+    """CPU-test size with every switch on: window layers that turn the
+    whole head beside full layers that turn nothing, q/k norms, the
+    output gate, sandwich norms, the embedding multiplier, one head size
+    and the same KV heads on both kinds, no sink, two leading dense
+    layers, a shared expert beside held experts that are a strict share
+    of the router's, the head on a prefill's last row; a window of 24
+    over pages of 8 is a ring of 4 pages."""
+    base = dict(vocab_size=256, hidden_size=64,
+                attention_kinds=["window", "window", "window", "full",
+                                 "window", "full"],
+                ffn_kinds=["dense", "dense"] + ["experts"] * 4,
+                num_heads=8, num_kv_heads=2, window_num_kv_heads=2,
+                qk_head_dim=16, v_head_dim=16, rotary_dim=16,
+                window_rope_theta=100.0, rotary_kinds=("window",),
+                sliding_window=24, window_sink=False, value_scale=1.0,
+                intermediate_size=128, moe_intermediate_size=32,
+                num_experts=16, num_local_experts=4, expert_offset=8,
+                num_experts_per_tok=4, routed_scaling_factor=2.826,
+                num_shared_experts=1, qk_norm=True, attention_gate=True,
+                sandwich_norm=True, embedding_multiplier=8.0,
+                head_on_last_row=True, max_position_embeddings=160,
                 attention_block=16)
     base.update(kw)
     return HybridMoEConfig(**base)

@@ -532,6 +532,19 @@ def serving_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
             "pool bytes the live rows hold (their pages of every class, "
             "written or not) over the context tokens they have so far, "
             "at the last retired decode round", unit="By"),
+        "window_ring_fill": r.gauge(
+            "paddle_tpu_serving_window_ring_fill",
+            "over the live rows of a model with window layers, the ring "
+            "pages that hold a position of the row's context "
+            "(min(ceil(context / page), ring)) over the ring pages the "
+            "row holds, mean, at the last retired decode round",
+            unit="ratio"),
+        "prefill_tokens": r.counter(
+            "paddle_tpu_serving_prefill_tokens_total",
+            "tokens the prefill programs were given: kind=prompt the "
+            "prompts' own lengths, kind=bucket the lattice buckets they "
+            "ran at (1 - prompt / bucket is the padding's share)",
+            labelnames=("kind",)),
         "requests": r.counter(
             "paddle_tpu_serving_requests_total",
             "request lifecycle events: submitted / admitted / "

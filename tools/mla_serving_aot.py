@@ -2,8 +2,9 @@
 place and with its kernels? (no chip needed)
 
 Builds the model of ``--config`` (``sarvam-105b``: ``MLAMoEForCausalLM``,
-the latent-attention decoder; ``mimo-v2-flash``: ``HybridMoEForCausalLM``,
-window and full layers over two classes of pages) at the benchmark
+the latent-attention decoder; ``mimo-v2-flash`` and ``trinity-mini``:
+``HybridMoEForCausalLM``, window and full layers over two classes of
+pages) at the benchmark
 configuration's widths (``benchmarks/configs/<config>.json``) with
 ``--layers`` layers (2: the
 dense layer and one expert layer) and NO weights (``LazyGuard``), takes
@@ -74,6 +75,8 @@ def main(argv):
     cfg = json.load(open(os.path.join(
         root, "benchmarks", "configs", args.config + ".json")))
     cfg["num_hidden_layers"] = args.layers
+    if "layers_run" in cfg:     # a depth cut that names published layers
+        cfg["layers_run"] = cfg["layers_run"][:args.layers]
     srv = cfg["serving"]
     fam = importlib.import_module(
         "benchmarks.harness.families." + cfg["family"])

@@ -18,7 +18,18 @@ same file times an older tree:
   best at);
 - ``prefill_<Sb>``: one row of Sb new tokens at offset 0, the prefill
   programs' call.
+
+``--kv-width D`` pools keys ``D`` wide (256: a 192-wide key padded to
+the lanes) against 128-wide values; ``--kv-heads`` / ``--q-heads`` set
+the heads. ``--window W`` times the WINDOW kernel instead
+(``paged_window_decode_attention``: the table is a ring of
+``ceil(W / 128) + 1`` columns): 64 rows whose windows intersect 1, 2, 8
+and ``ring`` pages (``window_<n>p``), then the cell's mix of contexts:
+
+    chiprun -- python tools/paged_attention_timing.py --window 2048 \
+        --kv-heads 4
 """
+import argparse
 import json
 import os
 import sys
@@ -38,7 +49,9 @@ from paddle_tpu.ops.pallas.decode_attention import (  # noqa: E402
     paged_attention_dense, paged_decode_attention)
 
 P, KV, H, PAGE, D, NPAGES = 512, 8, 32, 128, 128, 19
+DV = 128
 STEPS, CALLS = 64, 5
+ROWS = 64                      # the window cases' batch
 
 
 def shapes(r):
@@ -52,24 +65,55 @@ def shapes(r):
         yield f"prefill_{Sb}", Sb, np.zeros(1, np.int32)
 
 
-def reading(name, Sq, lens, r, peak):
+def pages_seen(ctx, window):
+    """Pages a row with ``ctx`` cached tokens fetches for its next
+    position: all up to its own page, or those its window intersects."""
+    last = ctx // PAGE
+    if window is None:
+        return last + 1
+    return last - np.maximum(ctx - (window - 1), 0) // PAGE + 1
+
+
+def window_shapes(r, window):
+    """64 rows that all fetch n pages, n = 1, 2, 8 and the whole ring;
+    then contexts drawn like the mixed-length cell's (prompts lognormal
+    around 2,048 clipped to 256..8,192, up to 512 tokens into their
+    answers)."""
+    ring = -(-window // PAGE) + 1
+    for n in sorted({1, 2, min(8, ring - 1), ring}):
+        # under the window a row sees every page so far; past it, a
+        # context that ends mid-page sees ring pages
+        ctx = n * PAGE - 28 if n < ring else 2 * window + PAGE - 28
+        lens = np.full(ROWS, ctx, np.int32)
+        assert (pages_seen(lens, window) == n).all(), (n, ctx)
+        yield f"window_{n}p", 1, lens
+    mix = np.clip(np.exp(r.normal(np.log(2048), 1.0, ROWS)), 256, 8192)
+    yield "window_mix", 1, (mix + r.randint(0, 512, ROWS)).astype(np.int32)
+
+
+def reading(name, Sq, lens, r, peak, window=None):
     B = len(lens)
     lengths = jnp.asarray(lens)
-    tbl = jnp.asarray(np.stack([r.permutation(P - 1)[:NPAGES]
-                                for _ in range(B)]), jnp.int32)
+    ncols = NPAGES if window is None else -(-window // PAGE) + 1
+    pool = max(P, B * ncols + 1)
+    tbl = jnp.asarray(r.permutation(pool - 1)[:B * ncols].reshape(
+        B, ncols), jnp.int32)
     q = jnp.asarray(r.randn(B, Sq, H, D), jnp.bfloat16)
-    kp = jnp.asarray(r.randn(P, KV, PAGE, D), jnp.bfloat16)
-    vp = jnp.asarray(r.randn(P, KV, PAGE, D), jnp.bfloat16)
+    kp = jnp.asarray(r.randn(pool, KV, PAGE, D), jnp.bfloat16)
+    vp = jnp.asarray(r.randn(pool, KV, PAGE, DV), jnp.bfloat16)
+    kw = {} if window is None else {"window": window}
 
-    got = paged_decode_attention(q, kp, vp, tbl, lengths)
-    want = paged_attention_dense(q, kp, vp, tbl, lengths)
+    got = paged_decode_attention(q, kp, vp, tbl, lengths, **kw)
+    want = paged_attention_dense(q, kp, vp, tbl, lengths, **kw)
     err = float(jnp.abs(got.astype(jnp.float32)
                         - want.astype(jnp.float32)).max())
 
     @jax.jit
     def prog(q, kp, vp):
         def body(q, _):
-            o = paged_decode_attention(q, kp, vp, tbl, lengths)
+            o = paged_decode_attention(q, kp, vp, tbl, lengths, **kw)
+            if DV != D:
+                o = jnp.pad(o, ((0, 0),) * 3 + ((0, D - DV),))
             return (q + o * 1e-3).astype(q.dtype), None
 
         return lax.scan(body, q, None, length=STEPS)[0]
@@ -80,17 +124,26 @@ def reading(name, Sq, lens, r, peak):
         t0 = time.perf_counter()
         prog(q, kp, vp).block_until_ready()
         best = min(best, time.perf_counter() - t0)
-    pages = int(((lens + Sq - 1) // PAGE + 1).sum())
-    nbytes = 2 * pages * KV * PAGE * D * 2
+    pages = int(pages_seen(lens + Sq - 1, window).sum())
+    nbytes = pages * KV * PAGE * (D + DV) * 2
     us = best / STEPS * 1e6
     print(json.dumps({
-        "shape": name, "rows": B, "Sq": Sq, "us_per_call": round(us, 1),
+        "shape": name, "rows": B, "Sq": Sq, "kv_heads": KV, "q_heads": H,
+        "kv_width": D, "window": window, "us_per_call": round(us, 1),
         "pages_referenced": pages, "us_per_page": round(us / pages, 3),
         "hbm_share_pct": round(100 * nbytes / (us * 1e-6) / peak, 1),
         "max_err_vs_dense": round(err, 4)}), flush=True)
 
 
 def main():
+    global KV, H, D
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--window", type=int, default=None)
+    ap.add_argument("--kv-width", type=int, default=D)
+    ap.add_argument("--kv-heads", type=int, default=KV)
+    ap.add_argument("--q-heads", type=int, default=H)
+    args = ap.parse_args()
+    KV, H, D = args.kv_heads, args.q_heads, args.kv_width
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(json.dumps({"error": f"needs a TPU, found {dev.platform}"}))
@@ -99,8 +152,10 @@ def main():
     print(json.dumps({"device": dev.device_kind, "steps": STEPS}),
           flush=True)
     r = np.random.RandomState(0)
-    for name, Sq, lens in shapes(r):
-        reading(name, Sq, lens, r, peak)
+    cases = shapes(r) if args.window is None \
+        else window_shapes(r, args.window)
+    for name, Sq, lens in cases:
+        reading(name, Sq, lens, r, peak, args.window)
     return 0
 
 
