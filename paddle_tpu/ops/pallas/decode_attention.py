@@ -19,22 +19,36 @@ row's frontier (``offset`` + new tokens, a SCALAR-PREFETCH input):
   still costs its grid step).
 - ``paged_decode_attention`` (page pool): the work follows the pages the
   rows own. Grid = (B * KV/hb,), one step per row and block of ``hb`` KV
-  heads; inside it a ``fori_loop`` over the row's OWN page count fetches
-  ``[hb, page, D]`` of K and of V (contiguous in the head-major pool,
-  which stays in HBM) by hand into two VMEM buffers, the next page's
-  copy, or the next grid step's first page, in flight while this one
-  computes. A free slot (length 0) costs one step and one page. ``hb``
-  follows the shape (``_paged_head_block``): every KV head when the q
-  rows are few (decode), one head for a prefill bucket. The heads of a
-  block share ONE matmul per page: scores are ``[hb*Sq*G, hb*page]`` and
-  the mask that keeps a row to positions <= its own also keeps it to
-  its own head's columns, so the MXU sees two large products, not
-  ``2*hb`` of four rows.
+  heads; inside it a ``fori_loop`` over the row's OWN page count. One
+  page of one grid step is a VISIT: ``[hb, page, D]`` of K and of V
+  (contiguous in the head-major pool, which stays in HBM) copied by hand
+  into one of ``depth`` VMEM slots a pool. The kernel's visits, in grid
+  order, are ONE sequence, and a cursor in SMEM runs ``depth - 1``
+  visits ahead of the one being computed, across rows and grid steps
+  alike: a row of one page (a free slot, length 0, costs one step and
+  one page) has its successors' pages on their way while it computes,
+  and the batch's last visits find nothing left to start. Why: with one
+  fetch in flight, started when the page before it began to compute, a
+  page cost ``0.32 us + bytes / 819 GB/s`` on a v5e whatever its size
+  (half the bandwidth at 262 kB a fetch); of that 0.32 us the copies'
+  start was the part a second fetch in flight hides, and the visit's
+  own compute chain the rest (PERF.md, PR 36). ``hb`` and ``depth``
+  follow the shape (``_paged_plan``, a pure function, what the tests
+  and ``tools/paged_attention_timing.py`` read): every KV head when the
+  q rows are few (decode), one head for a prefill bucket; slots enough
+  that two fetches and half a megabyte fly beside the page being
+  computed, and never at the price of a head. The heads of a fetch are
+  the BATCH of the visit's two products: scores are ``[hb, Sq*G,
+  page]``, one ``[Sq*G, D] x [D, page]`` product a head, masked by
+  position alone. (Until PR 36 they shared one ``[hb*Sq*G, hb*page]``
+  product whose mask also kept a row to its own head's columns: at
+  ``hb`` heads ``hb`` times the softmax for the same MXU tiles, 0.47
+  against 0.41 us a page of compute at 4 heads.)
   K and V may differ in width (the result is V's), a learned SINK logit
   a query head may join the softmax's denominator, and with ``window=``
   (kernel name ``paged_window_decode_attention``) the table is a ring of
-  pages a row and the loop walks only the one or two that intersect the
-  row's last ``window`` positions, masked by position.
+  pages a row and a step visits only the pages that intersect the row's
+  last ``window`` positions, masked by position.
 - GQA is native: the q heads of one KV group form the sublane axis of a
   single [Sq*G, D] block, so the cache is read once per KV head (the
   dense fallback repeats it per q head).
@@ -52,6 +66,7 @@ from __future__ import annotations
 
 import operator
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import numpy as np
@@ -177,171 +192,206 @@ def decode_attention(q, k_cache, v_cache, offset, scale=None,
 # ---------------------------------------------------------------------------
 # Paged (block-table) KV cache attention
 # ---------------------------------------------------------------------------
-# What a block of several KV heads may hold in VMEM, by the count of
-# ``_paged_vmem_bytes``. A quarter of Mosaic's 16 MiB scoped default:
-# the count of the compiler's temporaries is an estimate.
+# What a block of several KV heads may hold in VMEM at TWO slots a pool,
+# by the count of ``_paged_vmem_bytes``: the rule that picks ``hb``. A
+# quarter of Mosaic's 16 MiB scoped default: the count of the compiler's
+# temporaries is an estimate.
 _PAGED_VMEM_BUDGET = 4 * 1024 * 1024
+# What the same count may reach with the slots the lookahead adds (they
+# are counted exactly: page buffers). Half of the scoped default, so no
+# call raises ``vmem_limit_bytes`` for its depth.
+_PAGED_VMEM_DEEP = 8 * 1024 * 1024
+# Bytes to keep on their way beside the page being computed: what the
+# chip moves in the ~0.5 us a copy takes from ``start()`` to its first
+# byte (PERF.md, PR 36). Never fewer than two fetches, whatever their
+# size: with one, started as the page before it begins to compute, the
+# queue behind a finished copy is empty and every page pays that start
+# (786 kB a fetch: 1.27 us a page with one in flight, 1.15 with two,
+# the copies alone 1.15).
+_PAGED_IN_FLIGHT = 512 * 1024
+_PAGED_MAX_DEPTH = 4
 
 
-def _paged_vmem_bytes(hb, Sq, G, page, D, itemsize, Dv=None) -> int:
+class PagedPlan(NamedTuple):
+    """What ``paged_decode_attention`` does with a call's shapes."""
+    hb: int                 # KV heads one fetch brings
+    depth: int              # VMEM slots a pool: depth - 1 fetches fly
+    in_flight_bytes: int    # (depth - 1) fetches of K and V
+
+
+def _paged_vmem_bytes(hb, Sq, G, page, D, itemsize, Dv=None,
+                      depth=2) -> int:
     """VMEM the paged kernel needs for a block of ``hb`` KV heads: the
-    two page buffers of K (``D`` wide) and of V (``Dv`` wide, ``D``
-    where not given), q in and o out (two pipeline buffers each), the
-    softmax statistics and the accumulator, and the f32 temporaries of
-    one page's ``[hb*Sq*G, hb*page]`` scores (the score, its mask bound,
-    the probabilities in f32 and in the pool's dtype, and what the
-    compiler keeps beside them: counted as six)."""
+    ``depth`` page buffers of K (``D`` wide) and of V (``Dv`` wide,
+    ``D`` where not given), q in and o out (two pipeline buffers each),
+    the softmax statistics and the accumulator, and the f32 temporaries
+    of one page's scores (the score, its mask bound, the probabilities
+    in f32 and in the pool's dtype, and what the compiler keeps beside
+    them: counted as six). The scores are counted as ``[hb*Sq*G,
+    hb*page]``, the heads' one shared product until PR 36; they are
+    ``[hb, Sq*G, page]`` since, a product a head, so the count is an
+    upper bound and every call keeps the ``hb`` it had."""
     Dv = D if Dv is None else Dv
     rows, cols = hb * Sq * G, hb * page
-    pages = 2 * cols * (D + Dv) * itemsize
+    pages = depth * cols * (D + Dv) * itemsize
     qo = 2 * rows * (D + Dv) * itemsize
     stats = rows * (2 * 128 + Dv) * 4
     scores = 6 * rows * max(cols, 128) * 4
     return pages + qo + stats + scores
 
 
-def _paged_head_block(Sq, G, KV, page, D, itemsize, Dv=None) -> int:
-    """KV heads one fetch brings: the largest divisor of ``KV`` whose
-    block fits ``_PAGED_VMEM_BUDGET``; one head where none does (the
-    gate's ``Sq*G <= 2048`` bounds that block). The scores of a block
-    are ``[hb*Sq*G, hb*page]`` (heads share one matmul and a mask keeps
-    each to its own page), so few q rows take every head and a prefill
-    bucket takes one."""
-    for hb in range(KV, 1, -1):
-        if KV % hb == 0 and _paged_vmem_bytes(
-                hb, Sq, G, page, D, itemsize, Dv) <= _PAGED_VMEM_BUDGET:
-            return hb
-    return 1
+def _paged_plan(Sq, G, KV, page, D, itemsize, Dv=None) -> PagedPlan:
+    """The one rule, from the call's shapes alone.
+
+    ``hb``: the largest divisor of ``KV`` whose block fits
+    ``_PAGED_VMEM_BUDGET`` at two slots; one head where none does (the
+    gate's ``Sq*G <= 2048`` bounds that block): few q rows take every
+    head and a prefill bucket takes one.
+
+    ``depth``: slots enough that two fetches, and ``_PAGED_IN_FLIGHT``
+    bytes, fly beside the page being computed: 3, or
+    ``_PAGED_MAX_DEPTH`` where a fetch is small; fewer only where
+    ``_PAGED_VMEM_DEEP`` has no room (a single head at the gate's
+    edge). Depth never costs a head: ``hb`` is chosen first."""
+    Dv = D if Dv is None else Dv
+    hb = next((h for h in range(KV, 1, -1) if KV % h == 0
+               and _paged_vmem_bytes(h, Sq, G, page, D, itemsize, Dv)
+               <= _PAGED_VMEM_BUDGET), 1)
+    fetch = hb * page * (D + Dv) * itemsize
+    depth = min(max(3, 1 + -(-_PAGED_IN_FLIGHT // fetch)), _PAGED_MAX_DEPTH)
+    while depth > 2 and _paged_vmem_bytes(
+            hb, Sq, G, page, D, itemsize, Dv, depth) > _PAGED_VMEM_DEEP:
+        depth -= 1
+    return PagedPlan(hb, depth, (depth - 1) * fetch)
 
 
 def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
-                  G, hb, nh, window=None, sink=False):
-    """``window``: the rows see only the last ``window`` positions up to
+                  G, hb, nh, depth, window=None, sink=False):
+    """One grid step a (row, block of ``hb`` KV heads), and inside it a
+    loop over the pages the row owns. A VISIT is one page of one grid
+    step; the kernel's visits, in grid order, form one sequence, and a
+    cursor in SMEM runs ``depth - 1`` visits ahead of the one being
+    computed, across rows and grid steps, starting each visit's copies
+    into the slot the visit before the current one has left. The heads
+    of a fetch are the batch of a visit's products: rows are (s, g)
+    within a head, and the mask knows positions only.
+
+    ``window``: the rows see only the last ``window`` positions up to
     their own, the table is a RING of ``npages`` columns (logical page
-    ``j`` sits in column ``j % npages``) and the loop walks only the
+    ``j`` sits in column ``j % npages``) and a step visits only the
     pages that intersect the window. ``sink``: one more input, a logit a
     query row that takes weight in the softmax and gives no value."""
     sink_ref = refs[0] if sink else None
-    (k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, slot_ref, m_s, l_s,
+    (k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur, m_s, l_s,
      acc_s) = refs[1:] if sink else refs
-    t = pl.program_id(0)
-    b, blk = t // nh, t % nh
-    rows, cols = Sq * hb * G, hb * page
+    t, steps = pl.program_id(0), pl.num_programs(0)
+    b = t // nh
+    rows = Sq * G
 
-    def first_page(row):
-        """The lowest logical page a q row of ``row`` can see."""
-        return jnp.maximum(len_ref[row] - (window - 1), 0) // page
-
-    def fetch(row, head_blk, j, slot):
-        """The copies of logical page ``j`` of ``row``: K and V of
-        ``hb`` heads, ``[hb, page, D]`` contiguous in each pool."""
+    def span(row):
+        """(first logical page, pages) a grid step of ``row`` visits: up
+        to the page the last q row's own position falls in, so at least
+        one (a free slot, position 0); never past the table (a ring has
+        no end: its columns are reused), from the first page the window
+        of the first q row reaches."""
+        last = (len_ref[row] + Sq - 1) // page
         if window is None:
-            pid = tbl_ref[row * npages + j]
-        else:
-            pid = tbl_ref[row * npages + j % npages]
-        heads = pl.ds(head_blk * hb, hb)
+            return 0, jnp.minimum(last, npages - 1) + 1
+        lo = jnp.maximum(len_ref[row] - (window - 1), 0) // page
+        return lo, last + 1 - lo
+
+    def fetch(step, j, slot):
+        """The copies of logical page ``j`` of grid step ``step``: K and
+        V of ``hb`` heads, ``[hb, page, D]`` contiguous in each pool."""
+        pid = tbl_ref[step // nh * npages
+                      + (j if window is None else j % npages)]
+        heads = pl.ds((step % nh) * hb, hb)
         return (pltpu.make_async_copy(k_hbm.at[pid, heads], k_buf.at[slot],
                                       sem.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[pid, heads], v_buf.at[slot],
                                       sem.at[1, slot]))
 
+    def issue():
+        """Start the copies of the next visit not yet fetched, if the
+        batch has one left, and move the cursor on: ``cur`` = (its grid
+        step, its visit in that step, visits fetched, visits computed)."""
+        step, k, i = cur[0], cur[1], cur[2]
+
+        @pl.when(step < steps)
+        def _():
+            first, pages = span(step // nh)
+            for copy in fetch(step, first + k, i % depth):
+                copy.start()
+            end = k + 1 >= pages
+            cur[0] = jnp.where(end, step + 1, step)
+            cur[1] = jnp.where(end, 0, k + 1)
+            cur[2] = i + 1
+
     @pl.when(t == 0)
     def _():
-        slot_ref[0] = 0
-        for copy in fetch(0, 0, 0 if window is None else first_page(0), 0):
-            copy.start()
+        for c in range(4):
+            cur[c] = 0
+        for _ in range(depth - 1):
+            issue()
 
     off = len_ref[b]
-    # pages up to the one the last q row's own position falls in: at
-    # least one (a free slot, position 0), never past the table (a ring
-    # has no end: its columns are reused)
-    if window is None:
-        lo = 0
-        n = jnp.minimum((off + Sq - 1) // page, npages - 1) + 1
-    else:
-        lo = first_page(b)
-        n = (off + Sq - 1) // page + 1
-    slot0 = slot_ref[0]
+    lo, n = span(b)
+    i0 = cur[3]
     m_s[...] = jnp.full_like(m_s, _NEG)
     l_s[...] = jnp.zeros_like(l_s)
     acc_s[...] = jnp.zeros_like(acc_s)
-    qb = q_ref[0].reshape(rows, -1)                  # rows (s, head, g)
+    # one [Sq*G, D] block a head, rows (s, g): the heads are the batch
+    # of both products
+    qb = jnp.stack([q_ref[0, :, h].reshape(rows, -1) for h in range(hb)])
 
     def mask_bound():
-        """Column (head', p) of page j is position j*page + p; row (s,
-        head, g) sees it if head' == head and the position is <= off +
-        s: if ``j * page <= bound``."""
-        ri = lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
-        ci = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-        bound = off + ri // (hb * G) - ci % page
-        if hb > 1:
-            bound = jnp.where((ri // G) % hb == ci // page, bound, -1)
-        return bound
+        """Column p of page j is position j*page + p; row (s, g) sees
+        it if the position is <= off + s: if ``j * page <= bound``. The
+        same for every head."""
+        ri = lax.broadcasted_iota(jnp.int32, (rows, page), 0)
+        ci = lax.broadcasted_iota(jnp.int32, (rows, page), 1)
+        return (off + ri // G - ci)[None]
 
-    # the same for every page. A block of several heads was sized with
-    # room for it; a single head may fill VMEM (the gate's edge), and
-    # builds it page by page as its grid steps did
+    # the same for every page. A block of several heads has few rows; a
+    # single head may fill VMEM (the gate's edge), and builds it page by
+    # page as its grid steps did
     bound = mask_bound() if hb > 1 else None
 
-    def visit(j, _):
-        slot = (slot0 + j) % 2 if window is None else (slot0 + j - lo) % 2
-        more = j + 1 < n
-
-        # the next page of this row, or the first page of the next grid
-        # step (every step has one), is in flight while this one computes
-        @pl.when(more | (t + 1 < pl.num_programs(0)))
-        def _():
-            if window is None:
-                first = 0
-            else:       # the last step looks no row up past the batch
-                first = first_page(jnp.minimum(
-                    (t + 1) // nh, pl.num_programs(0) // nh - 1))
-            nxt = [jnp.where(more, here, there) for here, there in
-                   ((b, (t + 1) // nh), (blk, (t + 1) % nh),
-                    (j + 1, first))]
-            for copy in fetch(*nxt, 1 - slot):
-                copy.start()
-
-        for copy in fetch(b, blk, j, slot):
+    def visit(k, _):
+        slot = (i0 + k) % depth
+        issue()         # into the slot the visit before this one read
+        for copy in fetch(t, lo + k, slot):
             copy.wait()
-        kb = k_buf[slot].reshape(cols, -1)               # [hb*page, D]
-        vb = v_buf[slot].reshape(cols, -1)
-        s = lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
+        s = lax.dot_general(qb, k_buf[slot], (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32) * scale
-        if window is None:
-            keep = j * page <= (mask_bound() if bound is None else bound)
-        else:           # and no further back than the window
-            bd = mask_bound() if bound is None else bound
-            keep = (j * page <= bd) & (j * page > bd - window)
-        s = jnp.where(keep, s, _NEG)
-        m_prev = m_s[:, :1]
+        first = (lo + k) * page             # the page's first position
+        bd = mask_bound() if bound is None else bound
+        keep = first <= bd
+        if window is not None:  # and no further back than the window
+            keep = keep & (first > bd - window)
+        s = jnp.where(keep, s, _NEG)                 # [hb, rows, page]
+        m_prev = m_s[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_s[:, :1] = l_s[:, :1] * corr + jnp.sum(p, -1, keepdims=True)
+        l_s[:, :, :1] = l_s[:, :, :1] * corr + jnp.sum(p, -1, keepdims=True)
         acc_s[...] = acc_s[...] * corr + lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            p.astype(v_buf.dtype), v_buf[slot],
+            (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        m_s[:, :1] = m_new
+        m_s[:, :, :1] = m_new
 
-    if window is None:
-        lax.fori_loop(0, n, visit, None)
-        slot_ref[0] = (slot0 + n) % 2
-    else:
-        lax.fori_loop(lo, n, visit, None)
-        slot_ref[0] = (slot0 + n - lo) % 2
+    lax.fori_loop(0, n, visit, None)
+    cur[3] = i0 + n
     if not sink:
-        l = jnp.maximum(l_s[:, :1], 1e-30)
-        o_ref[0] = (acc_s[...] / l).reshape(o_ref.shape[1:]).astype(
-            o_ref.dtype)
-        return
-    # the sink joins the denominator as one more score with no value
-    m, sk = m_s[:, :1], sink_ref[0]                     # [rows, 1]
-    m_fin = jnp.maximum(m, sk)
-    corr = jnp.exp(m - m_fin)
-    l = l_s[:, :1] * corr + jnp.exp(sk - m_fin)
-    o_ref[0] = (acc_s[...] * corr / l).reshape(o_ref.shape[1:]).astype(
-        o_ref.dtype)
+        out = acc_s[...] / jnp.maximum(l_s[:, :, :1], 1e-30)
+    else:   # the sink joins the denominator as one more score, no value
+        m, sk = m_s[:, :, :1], sink_ref[0]               # [hb, rows, 1]
+        m_fin = jnp.maximum(m, sk)
+        corr = jnp.exp(m - m_fin)
+        out = acc_s[...] * corr / (l_s[:, :, :1] * corr + jnp.exp(sk - m_fin))
+    for h in range(hb):
+        o_ref[0, :, h] = out[h].reshape(Sq, G, -1).astype(o_ref.dtype)
 
 
 def paged_supported(q_shape, pool_shape, v_shape=None) -> bool:
@@ -402,10 +452,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     G = H // KV
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    hb = _paged_head_block(Sq, G, KV, page, D, k_pool.dtype.itemsize,
-                           None if Dv == D else Dv)
+    hb, depth, _ = _paged_plan(Sq, G, KV, page, D, k_pool.dtype.itemsize,
+                               Dv)
     nh = KV // hb
-    rows = Sq * hb * G
+    rows = Sq * G
     q5 = q.reshape(B, Sq, KV, G, D)
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
     tbl = jnp.asarray(block_tables, jnp.int32).reshape(B * npages)
@@ -415,12 +465,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 
     ins, in_specs = [q5], [pl.BlockSpec((1, Sq, hb, G, D), q_index)]
     if sinks is not None:
-        # one logit a score row, in the kernel's row order (s, head, g)
+        # one logit a score row, in the kernel's order: head, then (s, g)
         ins.append(jnp.broadcast_to(
-            jnp.asarray(sinks, jnp.float32).reshape(nh, 1, hb * G),
-            (nh, Sq, hb * G)).reshape(nh, rows, 1))
-        in_specs.append(pl.BlockSpec((1, rows, 1),
-                                     lambda t, ln, tb: (t % nh, 0, 0)))
+            jnp.asarray(sinks, jnp.float32).reshape(nh, hb, 1, G),
+            (nh, hb, Sq, G)).reshape(nh, hb, rows, 1))
+        in_specs.append(pl.BlockSpec((1, hb, rows, 1),
+                                     lambda t, ln, tb: (t % nh, 0, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B * nh,),
@@ -430,13 +480,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         ],
         out_specs=pl.BlockSpec((1, Sq, hb, G, Dv), q_index),
         scratch_shapes=[
-            pltpu.VMEM((2, hb, page, D), k_pool.dtype),
-            pltpu.VMEM((2, hb, page, Dv), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, Dv), jnp.float32),
+            pltpu.VMEM((depth, hb, page, D), k_pool.dtype),
+            pltpu.VMEM((depth, hb, page, Dv), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, depth)),
+            pltpu.SMEM((4,), jnp.int32),
+            pltpu.VMEM((hb, rows, 128), jnp.float32),
+            pltpu.VMEM((hb, rows, 128), jnp.float32),
+            pltpu.VMEM((hb, rows, Dv), jnp.float32),
         ],
     )
     kw = {}
@@ -446,13 +496,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         kw["sink"] = True
     out = pl.pallas_call(
         partial(_paged_kernel, scale=scale, page=page, npages=npages,
-                Sq=Sq, G=G, hb=hb, nh=nh, **kw),
+                Sq=Sq, G=G, hb=hb, nh=nh, depth=depth, **kw),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, KV, G, Dv), q.dtype),
         interpret=interpret,
         name="paged_decode_attention" if window is None
         else "paged_window_decode_attention",
-        # one sequential axis: a step starts the next step's first page
+        # one sequential axis: a step starts later steps' pages
         **_compiler_params(0, interpret),
     )(lengths, tbl, *ins, k_pool, v_pool)
     return out.reshape(B, Sq, H, Dv)

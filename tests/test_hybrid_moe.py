@@ -306,6 +306,54 @@ def test_full_kernel_with_two_widths_is_its_dense_twin(heads, sinks):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+# (Sq, G, KV, page, dtype) as tests/test_paged_ragged.py's DEPTH_CASES:
+# plans of 2, 3 and 4 slots, whole and in head blocks
+RING_DEPTH_CASES = [
+    (512, 4, 1, 8, "float32"), (512, 4, 2, 16, "float32"),
+    (1, 8, 4, 128, "bfloat16"), (1, 1, 32, 128, "bfloat16"),
+    (1, 4, 2, 16, "float32"), (64, 4, 8, 128, "bfloat16"),
+]
+
+# lengths in ring columns ``c`` and pages ``pg``: rings wrapped several
+# times, so that a row's first page is far from 0 and its pages sit in
+# columns out of order, among short rows and free slots; rows of one
+# page and free slots only, where the lookahead crosses a row at every
+# visit and the last visits have nothing left to fetch
+RING_WALKS = {
+    "rings_wrapped_several_times": lambda c, pg, Sq: [
+        3 * c * pg + 5, 0, (2 * c + 1) * pg, 1, 5 * c * pg - Sq, 0,
+        c * pg - 1, (4 * c - 1) * pg + pg // 2],
+    "one_page_and_free_slots": lambda c, pg, Sq: [
+        0, min(5, max(pg - Sq, 0)), 0, 0, max(pg - Sq, 0), 0, 0],
+}
+
+
+@pytest.mark.parametrize("sinks", [False, True])
+@pytest.mark.parametrize("walk", sorted(RING_WALKS))
+@pytest.mark.parametrize("Sq,G,KV,page,dtype", RING_DEPTH_CASES)
+def test_window_kernel_keeps_its_fetches_in_flight_across_rows(
+        Sq, G, KV, page, dtype, walk, sinks):
+    """The window kernel at every depth ``_paged_plan`` can return: a
+    window of two pages and a bit, the ring that holds it."""
+    window = 2 * page + 3
+    ring = (window + Sq - 2) // page + 2
+    lens = np.asarray(RING_WALKS[walk](ring, page, Sq), np.int32)
+    rng = np.random.default_rng(Sq + 7 * G + 31 * KV + page + len(walk))
+    kp, vp, tbl = _pools(rng, len(lens), KV, page, 128, 128, ring)
+    q = jnp.asarray(rng.standard_normal((len(lens), Sq, KV * G, 128)),
+                    dtype)
+    kp, vp = kp.astype(dtype), vp.astype(dtype)
+    sk = jnp.asarray(rng.standard_normal(KV * G), jnp.float32) \
+        if sinks else None
+    got = da.paged_decode_attention(q, kp, vp, tbl, jnp.asarray(lens),
+                                    interpret=True, sinks=sk, window=window)
+    want = da.paged_attention_dense(q, kp, vp, tbl, jnp.asarray(lens),
+                                    sinks=sk, window=window)
+    tol = 2e-5 if dtype == "float32" else 3e-2
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               want.astype(jnp.float32), rtol=tol, atol=tol)
+
+
 def test_window_dense_twin_sees_exactly_the_window():
     """The twin itself against attention written out over a contiguous
     cache: a ring of 3 pages of 8 filled position by position, window
@@ -337,15 +385,18 @@ def test_kernel_gate_and_head_block_follow_the_shapes():
     the window and full calls of the published widths take every KV
     head in one fetch."""
     assert da.paged_supported((48, 1, 32, 128), (1024, 8, 128, 128))
-    assert da._paged_head_block(1, 4, 8, 128, 128, 2) == 8
+    assert da._paged_plan(1, 4, 8, 128, 128, 2).hb == 8
     assert da._paged_vmem_bytes(8, 1, 4, 128, 128, 2) \
         == da._paged_vmem_bytes(8, 1, 4, 128, 128, 2, 128)
     assert da.paged_supported((128, 1, 64, 256), (257, 8, 128, 256),
                               (257, 8, 128, 128))
     assert not da.paged_supported((128, 1, 64, 256), (257, 8, 128, 256),
                                   (257, 8, 128, 64))
-    assert da._paged_head_block(1, 8, 8, 128, 256, 2, 128) == 8
-    assert da._paged_head_block(1, 16, 4, 128, 256, 2, 128) == 4
+    # depth never costs a head: MiMo's window call keeps its 8, its full
+    # call and Trinity's two (8 query heads on each of 4 KV heads) 4
+    assert da._paged_plan(1, 8, 8, 128, 256, 2, 128).hb == 8
+    assert da._paged_plan(1, 16, 4, 128, 256, 2, 128).hb == 4
+    assert da._paged_plan(1, 8, 4, 128, 128, 2).hb == 4
 
 
 def test_ring_write_lands_at_the_position_s_ring_column():

@@ -177,45 +177,144 @@ class TestPagedKernel:
         assert float(jnp.abs(paged - dense).max()) < 1e-4
 
 
-class TestHeadBlockRule:
-    """``_paged_head_block``: a pure function of the call's shapes."""
+# (Sq, G, KV, page, dtype) whose plans hold 2 (a single head at the
+# gate's edge: VMEM has no room for more), 3 and 4 slots, each once with
+# every KV head in a fetch and once with a block smaller than KV
+# (several grid steps a row): TestPlanRule pins which
+DEPTH_CASES = [
+    (512, 4, 1, 8, "float32"), (512, 4, 2, 16, "float32"),
+    (1, 8, 4, 128, "bfloat16"), (1, 1, 32, 128, "bfloat16"),
+    (1, 4, 2, 16, "float32"), (64, 4, 8, 128, "bfloat16"),
+]
+
+# what the lookahead must survive, as (lengths in pages and tokens,
+# sinks): rows of one page and free slots only, so that it crosses a
+# row at every visit; a batch that ends in free slots behind a long
+# row, and one of a single free slot, so that fewer visits are left
+# than it runs ahead; mixed rows with a sink
+DEEP_WALKS = {
+    "one_page_and_free_slots": (lambda pg, Sq: [
+        0, min(5, max(pg - Sq, 0)), 0, 0, max(pg - Sq, 0), 0, 1, 0, 0],
+        False),
+    "the_batch_ends_in_free_slots": (lambda pg, Sq: [
+        pg + 1, 0, max(3 * pg - Sq, 0), 0, 0], False),
+    "a_single_free_slot": (lambda pg, Sq: [0], False),
+    "mixed_rows_with_a_sink": (lambda pg, Sq: [
+        0, 1, pg, 2 * pg + 1, 0, pg - 1], True),
+}
+
+
+class TestDeepWalk:
+    """The page walk keeps ``depth - 1`` fetches in flight across rows
+    and grid steps: at every depth the plan can return the kernel is
+    its dense twin, and reads no page a row does not own (NaN there,
+    and table entries past a frontier that name no page)."""
+
+    @pytest.mark.parametrize("walk", sorted(DEEP_WALKS))
+    @pytest.mark.parametrize("Sq,G,KV,page,dtype", DEPTH_CASES)
+    def test_kernel_is_its_dense_twin(self, Sq, G, KV, page, dtype, walk):
+        lens_of, sink = DEEP_WALKS[walk]
+        lens = np.asarray(lens_of(page, Sq), np.int32)
+        r = np.random.RandomState(len(walk) + Sq + 7 * G + 31 * KV + page)
+        B, npages = len(lens), int(lens.max() + Sq - 1) // page + 2
+        P = B * npages + 2
+        order = r.permutation(P)
+        tbl = order[:B * npages].reshape(B, npages).astype(np.int32)
+        past = np.arange(npages)[None] > ((lens + Sq - 1) // page)[:, None]
+        kp = r.randn(P, KV, page, 128).astype("float32")
+        vp = r.randn(P, KV, page, 128).astype("float32")
+        unowned = np.ones(P, bool)
+        unowned[tbl[~past]] = False
+        dirty = [np.where(unowned[:, None, None, None], np.nan, x)
+                 for x in (kp, vp)]
+        clean = [np.where(unowned[:, None, None, None], 0, x)
+                 for x in (kp, vp)]
+        q = jnp.asarray(r.randn(B, Sq, KV * G, 128), dtype)
+        sk = jnp.asarray(r.randn(KV * G), jnp.float32) if sink else None
+        out = paged_decode_attention(
+            q, *(jnp.asarray(x, dtype) for x in dirty),
+            jnp.asarray(np.where(past, P + 1000, tbl), jnp.int32),
+            jnp.asarray(lens), interpret=True, sinks=sk)
+        ref = paged_attention_dense(
+            q, *(jnp.asarray(x, dtype) for x in clean),
+            jnp.asarray(np.where(past, order[-1], tbl), jnp.int32),
+            jnp.asarray(lens), sinks=sk)
+        assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+        err = jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))
+        assert float(err.max()) < _tol(dtype)
+
+
+class TestPlanRule:
+    """``_paged_plan``: a pure function of the call's shapes."""
 
     def test_decode_takes_every_head_and_a_prefill_bucket_one(self):
         # the serving cells: 32 q heads over 8 KV heads of 128, page 128
-        assert da._paged_head_block(1, 4, 8, 128, 128, 2) == 8
+        assert da._paged_plan(1, 4, 8, 128, 128, 2).hb == 8
         for Sb in (64, 128, 256, 512):
-            assert da._paged_head_block(Sb, 4, 8, 128, 128, 2) == 1
+            assert da._paged_plan(Sb, 4, 8, 128, 128, 2).hb == 1
         # MHA at Llama-7B widths (chip_smoke.py): a proper divisor
-        assert da._paged_head_block(1, 1, 32, 128, 128, 2) == 16
+        assert da._paged_plan(1, 1, 32, 128, 128, 2).hb == 16
 
     def test_walk_cases_cover_one_head_a_divisor_and_all(self):
         kinds = set()
         for Sq, G, KV, page, dtype in WALK_CASES:
-            hb = da._paged_head_block(Sq, G, KV, page, 128,
-                                      jnp.dtype(dtype).itemsize)
+            hb = da._paged_plan(Sq, G, KV, page, 128,
+                                jnp.dtype(dtype).itemsize).hb
             assert KV % hb == 0
             kinds.add("one" if hb == 1 and KV > 1 else
                       "all" if hb == KV else "divisor")
         assert kinds == {"one", "divisor", "all"}
 
+    def test_depth_cases_cover_every_depth_whole_and_in_head_blocks(self):
+        seen = set()
+        for Sq, G, KV, page, dtype in DEPTH_CASES:
+            plan = da._paged_plan(Sq, G, KV, page, 128,
+                                  jnp.dtype(dtype).itemsize)
+            seen.add((plan.depth, plan.hb < KV))
+        assert seen == {(d, blocks) for d in (2, 3, 4)
+                        for blocks in (False, True)}
+
+    def test_depth_follows_the_bytes_of_a_fetch(self):
+        """Slots enough that two fetches and ``_PAGED_IN_FLIGHT`` bytes
+        fly beside the page being computed, to ``_PAGED_MAX_DEPTH``; the
+        bytes are those of ``depth - 1`` fetches of K and V."""
+        for KV, D, Dv in ((4, 128, 128), (8, 128, 128), (8, 256, 128),
+                          (4, 256, 128), (1, 128, 128)):
+            hb, depth, flying = da._paged_plan(1, 4, KV, 128, D, 2, Dv)
+            fetch = hb * 128 * (D + Dv) * 2
+            assert flying == (depth - 1) * fetch
+            assert 2 <= depth <= da._PAGED_MAX_DEPTH
+            assert flying >= da._PAGED_IN_FLIGHT \
+                or depth == da._PAGED_MAX_DEPTH
+            assert depth == 3 or (depth - 2) * fetch < da._PAGED_IN_FLIGHT
+        # the serving cells' decode calls: two fetches in flight each
+        assert da._paged_plan(1, 8, 4, 128, 128, 2).depth == 3      # trinity
+        assert da._paged_plan(1, 4, 8, 128, 128, 2).depth == 3      # mistral
+        assert da._paged_plan(1, 8, 8, 128, 256, 2, 128).depth == 3  # mimo
+
     @pytest.mark.parametrize("itemsize", [2, 4])
     @pytest.mark.parametrize("page", [8, 16, 64, 128])
     def test_nothing_exceeds_the_budget(self, page, itemsize):
-        """A block of several heads fits the budget; a single head (the
-        floor) fits Mosaic's scoped limit at every shape the gate
-        admits."""
+        """A block of several heads fits the budget at two slots and
+        the deeper one at its depth; a single head (the floor) fits
+        Mosaic's scoped limit at every shape the gate admits, and a
+        depth over 2 is never what takes it past the deeper budget."""
         for KV in (1, 2, 4, 8, 32):
             for G in (1, 4, 8):
                 for Sq in (1, 2, 5, 16, 64, 128, 256, 512, 2048):
                     if not da.paged_supported((1, Sq, KV * G, 128),
                                               (4, KV, page, 128)):
                         continue
-                    hb = da._paged_head_block(Sq, G, KV, page, 128,
-                                              itemsize)
+                    hb, depth, _ = da._paged_plan(Sq, G, KV, page, 128,
+                                                  itemsize)
                     need = da._paged_vmem_bytes(hb, Sq, G, page, 128,
                                                 itemsize)
                     assert need <= (da._PAGED_VMEM_BUDGET if hb > 1
                                     else 16 * 2 ** 20), (Sq, G, KV, hb)
+                    if depth > 2:
+                        assert da._paged_vmem_bytes(
+                            hb, Sq, G, page, 128, itemsize, depth=depth) \
+                            <= da._PAGED_VMEM_DEEP, (Sq, G, KV, hb, depth)
                     # the largest: the next divisor up does not fit
                     for up in range(hb + 1, KV + 1):
                         if KV % up == 0:

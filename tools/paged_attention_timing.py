@@ -16,15 +16,23 @@ same file times an older tree:
 - ``longprompt``: 16 rows, all live, 1100-2100 tokens;
 - ``full``: 48 rows, all live, 2200-2400 tokens (what a fixed grid is
   best at);
+- ``one_page_half_free``: 48 rows that all walk ONE page, half of them
+  free slots (the chat cell's shape of call: a lookahead that stopped
+  at a row's last page would change nothing here);
 - ``prefill_<Sb>``: one row of Sb new tokens at offset 0, the prefill
   programs' call.
+
+Every reading carries the kernel's plan for its shapes (``plan``: KV
+heads a fetch, VMEM slots a pool, bytes in flight beside the page being
+computed; ``null`` on a tree from before PR 36).
 
 ``--kv-width D`` pools keys ``D`` wide (256: a 192-wide key padded to
 the lanes) against 128-wide values; ``--kv-heads`` / ``--q-heads`` set
 the heads. ``--window W`` times the WINDOW kernel instead
 (``paged_window_decode_attention``: the table is a ring of
 ``ceil(W / 128) + 1`` columns): 64 rows whose windows intersect 1, 2, 8
-and ``ring`` pages (``window_<n>p``), then the cell's mix of contexts:
+and ``ring`` pages (``window_<n>p``), then the cell's mix of contexts and
+the one-page case:
 
     chiprun -- python tools/paged_attention_timing.py --window 2048 \
         --kv-heads 4
@@ -45,6 +53,7 @@ from jax import lax  # noqa: E402
 
 import paddle_tpu  # noqa: E402,F401  (places the compile cache)
 from benchmarks.harness.peaks import peaks_for  # noqa: E402
+from paddle_tpu.ops.pallas import decode_attention as da  # noqa: E402
 from paddle_tpu.ops.pallas.decode_attention import (  # noqa: E402
     paged_attention_dense, paged_decode_attention)
 
@@ -54,11 +63,19 @@ STEPS, CALLS = 64, 5
 ROWS = 64                      # the window cases' batch
 
 
+def one_page_half_free(r, rows=48):
+    """Rows that all walk one page, every second one a free slot."""
+    lens = r.randint(16, PAGE - 1, rows).astype(np.int32)
+    lens[::2] = 0
+    return "one_page_half_free", 1, lens
+
+
 def shapes(r):
     live = r.permutation(48)[:19]
     chat = np.zeros(48, np.int32)
     chat[live] = r.randint(256, 1600, 19)
     yield "chat", 1, chat
+    yield one_page_half_free(r)
     yield "longprompt", 1, r.randint(1100, 2100, 16).astype(np.int32)
     yield "full", 1, r.randint(2200, 2400, 48).astype(np.int32)
     for Sb in (64, 512):
@@ -89,6 +106,7 @@ def window_shapes(r, window):
         yield f"window_{n}p", 1, lens
     mix = np.clip(np.exp(r.normal(np.log(2048), 1.0, ROWS)), 256, 8192)
     yield "window_mix", 1, (mix + r.randint(0, 512, ROWS)).astype(np.int32)
+    yield one_page_half_free(r, ROWS)
 
 
 def reading(name, Sq, lens, r, peak, window=None):
@@ -102,6 +120,11 @@ def reading(name, Sq, lens, r, peak, window=None):
     kp = jnp.asarray(r.randn(pool, KV, PAGE, D), jnp.bfloat16)
     vp = jnp.asarray(r.randn(pool, KV, PAGE, DV), jnp.bfloat16)
     kw = {} if window is None else {"window": window}
+    if not da.paged_supported(q.shape, kp.shape, vp.shape):
+        print(json.dumps({"shape": name, "rows": B, "Sq": Sq,
+                          "skipped": "the kernel's gate refuses it"}),
+              flush=True)
+        return
 
     got = paged_decode_attention(q, kp, vp, tbl, lengths, **kw)
     want = paged_attention_dense(q, kp, vp, tbl, lengths, **kw)
@@ -125,6 +148,9 @@ def reading(name, Sq, lens, r, peak, window=None):
         prog(q, kp, vp).block_until_ready()
         best = min(best, time.perf_counter() - t0)
     pages = int(pages_seen(lens + Sq - 1, window).sum())
+    plan = getattr(da, "_paged_plan", None)     # not on an older tree
+    if plan is not None:
+        plan = plan(Sq, H // KV, KV, PAGE, D, 2, DV)._asdict()
     nbytes = pages * KV * PAGE * (D + DV) * 2
     us = best / STEPS * 1e6
     print(json.dumps({
@@ -132,7 +158,7 @@ def reading(name, Sq, lens, r, peak, window=None):
         "kv_width": D, "window": window, "us_per_call": round(us, 1),
         "pages_referenced": pages, "us_per_page": round(us / pages, 3),
         "hbm_share_pct": round(100 * nbytes / (us * 1e-6) / peak, 1),
-        "max_err_vs_dense": round(err, 4)}), flush=True)
+        "max_err_vs_dense": round(err, 4), "plan": plan}), flush=True)
 
 
 def main():

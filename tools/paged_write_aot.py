@@ -128,10 +128,14 @@ def main(argv):
             "kernel": kernel is not None and kernel in text,
             "donated": ServingEngine.donated_params(text),
         })
-        if attn == "kernel":    # by the kernel's own count, which picks hb
-            hb = da._paged_head_block(S, H // KV, KV, page, D, dt.itemsize)
-            out[-1].update(head_block=hb, vmem_bytes=da._paged_vmem_bytes(
-                hb, S, H // KV, page, D, dt.itemsize))
+        if attn == "kernel":    # by the kernel's own count and plan
+            plan = da._paged_plan(S, H // KV, KV, page, D, dt.itemsize)
+            out[-1].update(
+                head_block=plan.hb, depth=plan.depth,
+                in_flight_bytes=plan.in_flight_bytes,
+                vmem_bytes=da._paged_vmem_bytes(
+                    plan.hb, S, H // KV, page, D, dt.itemsize,
+                    depth=plan.depth))
     print(json.dumps({"cases": out, "old": "--old" in argv}))
     return 0
 
