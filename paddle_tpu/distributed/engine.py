@@ -44,6 +44,7 @@ from ..observability import healthmon as _hm
 from ..observability import memledger as _ml
 from ..observability import moestats as _moestats
 from ..observability.catalog import train_metrics as _train_metrics
+from ..observability.trace import annotate as _annotate, span as _span
 from ..tensor import Tensor
 
 
@@ -725,7 +726,8 @@ class ParallelEngine:
                 if collect_moe:
                     _moestats.begin()
                 try:
-                    loss = fn(self.model, t_batch)
+                    with _annotate("forward"):
+                        loss = fn(self.model, t_batch)
                 finally:
                     moe_recs = _moestats.drain() if collect_moe else []
                 moe_tel = {}
@@ -757,11 +759,13 @@ class ParallelEngine:
                     # loss scaling = seeding the tape with `scale` instead
                     # of 1 (same grads as (loss*scale).backward(), one
                     # less op); the reported loss stays unscaled
-                    loss.backward(Tensor(
-                        scale_v.astype(loss._value.dtype),
-                        stop_gradient=True))
+                    with _annotate("backward"):
+                        loss.backward(Tensor(
+                            scale_v.astype(loss._value.dtype),
+                            stop_gradient=True))
                 else:
-                    loss.backward()
+                    with _annotate("backward"):
+                        loss.backward()
                 raw_grads = {
                     id(p): (p.grad._value if p.grad is not None
                             else jnp.zeros_like(p._value))
@@ -772,67 +776,69 @@ class ParallelEngine:
                 # with the grad-norm sum-of-squares folded into the
                 # bucket scan and the quantization error-feedback
                 # residuals threaded through as train state
-                if bucket_plan is not None:
-                    bsync, bgsq, new_qr = bucket_plan.sync(
-                        raw_grads, qcfg=qcfg, residuals=qvals)
-                else:
-                    bsync, bgsq, new_qr = {}, None, {}
-                upd_in, grads = [], []
-                for i, p in zip(t_index, trainable):
-                    g = raw_grads[id(p)]
-                    e = zero.entry(p)
-                    if id(p) in bsync:
-                        g = bsync[id(p)]
-                        if e is not None:
+                with _annotate("grad_sync"):
+                    if bucket_plan is not None:
+                        bsync, bgsq, new_qr = bucket_plan.sync(
+                            raw_grads, qcfg=qcfg, residuals=qvals)
+                    else:
+                        bsync, bgsq, new_qr = {}, None, {}
+                    upd_in, grads = [], []
+                    for i, p in zip(t_index, trainable):
+                        g = raw_grads[id(p)]
+                        e = zero.entry(p)
+                        if id(p) in bsync:
+                            g = bsync[id(p)]
+                            if e is not None:
+                                upd_in.append(
+                                    mvals[i] if mvals and i in mvals
+                                    else (pshards[i] if e[1]
+                                          else _shard_of(p, pvals[i], e[0])))
+                            else:
+                                upd_in.append(mvals[i] if mvals and i in mvals
+                                              else pvals[i])
+                        elif e is not None:
+                            # grad mean over plain dp, then reduce-scatter the
+                            # sharding axis onto the owner shard (ZeRO)
+                            dim = e[0]
+                            dp_only = tuple(a for a in gmean_axes
+                                            if a != zero.axis)
+                            if dp_only:
+                                g = C.t_pmean(g, dp_only)
+                            psum_axes = _grad_axes(p)
+                            if psum_axes:
+                                g = C.t_psum(g, psum_axes)
+                            if zero.axis in data_axes:
+                                g = C.t_psum_scatter(
+                                    g, zero.axis, scatter_dimension=dim,
+                                    tiled=True) / zero.n
+                            else:
+                                g = _shard_of(p, g, dim)
                             upd_in.append(
                                 mvals[i] if mvals and i in mvals
                                 else (pshards[i] if e[1]
-                                      else _shard_of(p, pvals[i], e[0])))
+                                      else _shard_of(p, pvals[i], dim)))
                         else:
+                            # params sharded over a data axis (MoE experts over
+                            # dp) already receive their cross-rank grad sum via
+                            # the all_to_all transpose — no pmean over that
+                            # axis, only the global-batch mean rescale
+                            spec_axes = _spec_axes(p)
+                            pm = tuple(a for a in gmean_axes
+                                       if a not in spec_axes)
+                            if pm:
+                                g = C.t_pmean(g, pm)
+                            dup = 1
+                            for a in gmean_axes:
+                                if a in spec_axes:
+                                    dup *= mesh.shape[a]
+                            if dup > 1:
+                                g = g / dup
+                            psum_axes = _grad_axes(p)
+                            if psum_axes:
+                                g = C.t_psum(g, psum_axes)
                             upd_in.append(mvals[i] if mvals and i in mvals
                                           else pvals[i])
-                    elif e is not None:
-                        # grad mean over plain dp, then reduce-scatter the
-                        # sharding axis onto the owner shard (ZeRO)
-                        dim = e[0]
-                        dp_only = tuple(a for a in gmean_axes
-                                        if a != zero.axis)
-                        if dp_only:
-                            g = C.t_pmean(g, dp_only)
-                        psum_axes = _grad_axes(p)
-                        if psum_axes:
-                            g = C.t_psum(g, psum_axes)
-                        if zero.axis in data_axes:
-                            g = C.t_psum_scatter(
-                                g, zero.axis, scatter_dimension=dim,
-                                tiled=True) / zero.n
-                        else:
-                            g = _shard_of(p, g, dim)
-                        upd_in.append(mvals[i] if mvals and i in mvals
-                                      else (pshards[i] if e[1]
-                                            else _shard_of(p, pvals[i], dim)))
-                    else:
-                        # params sharded over a data axis (MoE experts over
-                        # dp) already receive their cross-rank grad sum via
-                        # the all_to_all transpose — no pmean over that
-                        # axis, only the global-batch mean rescale
-                        spec_axes = _spec_axes(p)
-                        pm = tuple(a for a in gmean_axes
-                                   if a not in spec_axes)
-                        if pm:
-                            g = C.t_pmean(g, pm)
-                        dup = 1
-                        for a in gmean_axes:
-                            if a in spec_axes:
-                                dup *= mesh.shape[a]
-                        if dup > 1:
-                            g = g / dup
-                        psum_axes = _grad_axes(p)
-                        if psum_axes:
-                            g = C.t_psum(g, psum_axes)
-                        upd_in.append(mvals[i] if mvals and i in mvals
-                                      else pvals[i])
-                    grads.append(g)
+                        grads.append(g)
                 amp_out = ()
                 if use_scaler:
                     # traced found_inf, synced across EVERY parallel axis
@@ -891,8 +897,9 @@ class ParallelEngine:
                         loc = C.t_psum(loc, ax)
                     gsq = gsq + loc
                 gnorm = jnp.sqrt(gsq)
-                new_p, new_s = opt._fused_update(
-                    tuple(upd_in), tuple(grads), tuple(svals), lr, stepc)
+                with _annotate("optimizer"):
+                    new_p, new_s = opt._fused_update(
+                        tuple(upd_in), tuple(grads), tuple(svals), lr, stepc)
                 if use_scaler:
                     new_p = tuple(jnp.where(found_b, u, n)
                                   for u, n in zip(upd_in, new_p))
@@ -984,6 +991,10 @@ class ParallelEngine:
                            donate_argnums=(0, 1, 2, 3) if donate else ())
 
         def step(batch):
+            with _span("train.step", step=int(opt._step_count) + 1):
+                return _step_host(batch)
+
+        def _step_host(batch):
             t_entry = time.perf_counter()
             # fault-injection site for crash/hang tests: fires before
             # any state mutates, so a killed dispatch never tears a step
@@ -992,42 +1003,45 @@ class ParallelEngine:
             # (one-step lag): the device has certainly finished the
             # prior step by the next dispatch, so telemetry never adds
             # a sync on the critical path
-            self._flush_pending_scalars()
-            self._check_mesh_epoch()
-            # host-offload prefetch: every offloaded slot re-placed at
-            # its live sharding, bucket by bucket, BEFORE the mvals /
-            # pvals assembly below reads them. Same shapes, dtypes and
-            # shardings every step — the compile key never notices.
-            if self._offload is not None:
-                self._offload.prefetch_step(self)
-            leaves, treedef = jax.tree_util.tree_flatten(
-                batch, is_leaf=lambda x: isinstance(x, Tensor))
-            leaf_vals = tuple(v._value if isinstance(v, Tensor) else
-                              jnp.asarray(v) for v in leaves)
-            if batch_specs is not None:
-                b_specs = tuple(batch_specs)
-            else:
-                b_specs = tuple(
-                    P(data_axes) if data_axes and v.ndim > 0 else P()
-                    for v in leaf_vals)
-            n_tok = _batch_tokens(leaf_vals)   # host-local batch tokens
-            mvals = {i: opt._master_weights[id(p)]
-                     for i, p in zip(t_index, trainable)
-                     if id(p) in opt._master_weights}
-            mspecs = {i: zero.state_spec(params[i]) for i in mvals}
-            # scaler hyperparameters are baked into the trace as Python
-            # constants — key them so two differently-configured scalers
-            # never share an executable
-            amp_key = ((scaler._dynamic, scaler._incr_every,
-                        scaler._decr_every, scaler._incr_ratio,
-                        scaler._decr_ratio) if use_scaler else None)
-            # commledger.ablation_token() keys the exposed-comm
-            # profiler's comm-ablated replays OUT of the real program
-            # cache (None in normal operation, so live keys are
-            # unchanged and steady state stays recompile-free)
-            key = (treedef, tuple((v.shape, str(v.dtype))
-                                  for v in leaf_vals), b_specs,
-                   tuple(sorted(mvals)), amp_key, _cl.ablation_token())
+            with _span("train.flush_scalars"):
+                self._flush_pending_scalars()
+            with _span("train.assemble"):
+                self._check_mesh_epoch()
+                # host-offload prefetch: every offloaded slot re-placed
+                # at its live sharding, bucket by bucket, BEFORE the
+                # mvals / pvals assembly below reads them. Same shapes,
+                # dtypes and shardings every step — the compile key
+                # never notices.
+                if self._offload is not None:
+                    self._offload.prefetch_step(self)
+                leaves, treedef = jax.tree_util.tree_flatten(
+                    batch, is_leaf=lambda x: isinstance(x, Tensor))
+                leaf_vals = tuple(v._value if isinstance(v, Tensor) else
+                                  jnp.asarray(v) for v in leaves)
+                if batch_specs is not None:
+                    b_specs = tuple(batch_specs)
+                else:
+                    b_specs = tuple(
+                        P(data_axes) if data_axes and v.ndim > 0 else P()
+                        for v in leaf_vals)
+                n_tok = _batch_tokens(leaf_vals)  # host-local batch tokens
+                mvals = {i: opt._master_weights[id(p)]
+                         for i, p in zip(t_index, trainable)
+                         if id(p) in opt._master_weights}
+                mspecs = {i: zero.state_spec(params[i]) for i in mvals}
+                # scaler hyperparameters are baked into the trace as
+                # Python constants — key them so two differently-
+                # configured scalers never share an executable
+                amp_key = ((scaler._dynamic, scaler._incr_every,
+                            scaler._decr_every, scaler._incr_ratio,
+                            scaler._decr_ratio) if use_scaler else None)
+                # commledger.ablation_token() keys the exposed-comm
+                # profiler's comm-ablated replays OUT of the real program
+                # cache (None in normal operation, so live keys are
+                # unchanged and steady state stays recompile-free)
+                key = (treedef, tuple((v.shape, str(v.dtype))
+                                      for v in leaf_vals), b_specs,
+                       tuple(sorted(mvals)), amp_key, _cl.ablation_token())
             if not self._profiling:
                 self.stats.note("train", key)
             # goodput attribution (observability/goodput): a known key
@@ -1059,88 +1073,96 @@ class ParallelEngine:
 
         def _dispatch(key, treedef, b_specs, mspecs, leaf_vals,
                       t_entry, n_tok, mvals):
-            if key not in self._compiled:
-                self._compiled[key] = make(treedef, b_specs, mspecs)
-            pvals = tuple(p._value for p in params)
-            svals = tuple(opt._states[id(p)] for p in trainable)
-            qvals = dict(self._quant_residuals)
-            opt._step_count += 1
-            self._seed += 1
-            lr = jnp.asarray(opt.get_lr(), jnp.float32)
-            stepc = jnp.asarray(opt._step_count, jnp.int32)
-            seed = jnp.asarray(self._seed, jnp.uint32)
-            # -1: _step_count was already incremented for THIS step; the
-            # traced counter advances inside the step on application
-            amp_in = (scaler._traced_state(fallback_step=opt._step_count - 1)
-                      if use_scaler else ())
-            leaf_vals = _globalize_batch(leaf_vals, b_specs, mesh)
-            if _multiprocess(mesh):
-                lr = global_put(lr, mesh, P())
-                stepc = global_put(stepc, mesh, P())
-                seed = global_put(seed, mesh, P())
-                # amp state from a previous compiled step is already a
-                # committed global array — re-global_put would force a
-                # blocking host sync on every step
-                if use_scaler and not scaler._dev_global:
-                    amp_in = tuple(global_put(v, mesh, P())
-                                   for v in amp_in)
-                    scaler._dev = amp_in
-                    scaler._dev_global = True
-            # the capture collects comm notes only if THIS call traces
-            # (first execution of the program); cached executions note
-            # nothing and reuse the stored ledger
-            with _cl.capture() as cap:
-                (lv, gnorm, qnorm, new_p, new_s, new_m, new_qr, amp_out,
-                 moe_tel) = \
-                    self._compiled[key](pvals, svals, mvals, qvals,
-                                        leaf_vals, lr, stepc, seed,
-                                        amp_in)
-            if len(cap):
-                self._ledgers[key] = cap
-            for k, v in new_qr.items():
-                self._quant_residuals[k] = v
-            if not self._profiling:
-                self._last_key = key
-                # example args for on-demand AOT memory analysis of
-                # this program (references only; the batch leaves are
-                # never donated). Params/states are rebuilt from the
-                # engine's CURRENT values at analysis time, so the
-                # stored tuple only pins shapes/dtypes/tree structure.
-                self._mem_args[key] = (leaf_vals, lr, stepc, seed,
-                                       amp_in)
-            for p, nv in zip(params, new_p):
-                p._value = nv
-            for p, ns in zip(trainable, new_s):
-                opt._states[id(p)] = ns
-            for i, nv in new_m.items():
-                opt._master_weights[id(params[i])] = nv
-            if use_scaler:
-                scaler._store_traced(amp_out)
-            # host-offload page-out: the step's FRESH output state (the
-            # donated inputs are already dead buffers) moves to the
-            # host tier, then the leading buckets start warming on the
-            # background thread for the next dispatch
-            if self._offload is not None:
-                self._offload.page_out_step(self)
-            from ..optimizer.lr import LRScheduler
+            # the program on its way: state gathered, scalars uploaded,
+            # the compiled call returned (a fresh key traces and
+            # compiles inside it)
+            with _span("train.dispatch", fresh=key not in self._compiled):
+                if key not in self._compiled:
+                    self._compiled[key] = make(treedef, b_specs, mspecs)
+                pvals = tuple(p._value for p in params)
+                svals = tuple(opt._states[id(p)] for p in trainable)
+                qvals = dict(self._quant_residuals)
+                opt._step_count += 1
+                self._seed += 1
+                lr = jnp.asarray(opt.get_lr(), jnp.float32)
+                stepc = jnp.asarray(opt._step_count, jnp.int32)
+                seed = jnp.asarray(self._seed, jnp.uint32)
+                # -1: _step_count was already incremented for THIS step; the
+                # traced counter advances inside the step on application
+                amp_in = (scaler._traced_state(
+                    fallback_step=opt._step_count - 1)
+                    if use_scaler else ())
+                leaf_vals = _globalize_batch(leaf_vals, b_specs, mesh)
+                if _multiprocess(mesh):
+                    lr = global_put(lr, mesh, P())
+                    stepc = global_put(stepc, mesh, P())
+                    seed = global_put(seed, mesh, P())
+                    # amp state from a previous compiled step is already a
+                    # committed global array — re-global_put would force a
+                    # blocking host sync on every step
+                    if use_scaler and not scaler._dev_global:
+                        amp_in = tuple(global_put(v, mesh, P())
+                                       for v in amp_in)
+                        scaler._dev = amp_in
+                        scaler._dev_global = True
+                # the capture collects comm notes only if THIS call traces
+                # (first execution of the program); cached executions note
+                # nothing and reuse the stored ledger
+                with _cl.capture() as cap:
+                    (lv, gnorm, qnorm, new_p, new_s, new_m, new_qr, amp_out,
+                     moe_tel) = \
+                        self._compiled[key](pvals, svals, mvals, qvals,
+                                            leaf_vals, lr, stepc, seed,
+                                            amp_in)
+            # what the host owes the step after it: the new state
+            # written back, ledgers, `_note_step`
+            with _span("train.record"):
+                if len(cap):
+                    self._ledgers[key] = cap
+                for k, v in new_qr.items():
+                    self._quant_residuals[k] = v
+                if not self._profiling:
+                    self._last_key = key
+                    # example args for on-demand AOT memory analysis of
+                    # this program (references only; the batch leaves are
+                    # never donated). Params/states are rebuilt from the
+                    # engine's CURRENT values at analysis time, so the
+                    # stored tuple only pins shapes/dtypes/tree structure.
+                    self._mem_args[key] = (leaf_vals, lr, stepc, seed,
+                                           amp_in)
+                for p, nv in zip(params, new_p):
+                    p._value = nv
+                for p, ns in zip(trainable, new_s):
+                    opt._states[id(p)] = ns
+                for i, nv in new_m.items():
+                    opt._master_weights[id(params[i])] = nv
+                if use_scaler:
+                    scaler._store_traced(amp_out)
+                # host-offload page-out: the step's FRESH output state (the
+                # donated inputs are already dead buffers) moves to the
+                # host tier, then the leading buckets start warming on the
+                # background thread for the next dispatch
+                if self._offload is not None:
+                    self._offload.page_out_step(self)
+                from ..optimizer.lr import LRScheduler
 
-            if isinstance(opt._lr, LRScheduler):
-                opt._lr.step()  # advance the schedule once per train step
-            if not self._profiling:
-                led = self._ledgers.get(key)
-                if led is not None:
-                    led.publish(self._metrics["comm_bytes"],
-                                self._metrics["comm_ops"])
-                    # realized per-axis wire compression of this
-                    # program (quant_comm payload_ratio stamps); empty
-                    # when nothing on the wire is quantized
-                    for ax, rv in led.quant_ratios().items():
-                        self._metrics["comm_quant_ratio"].set(
-                            rv, axis=ax)
-                self._note_step(t_entry, n_tok, lv, gnorm,
-                                found=amp_out[4] if amp_out else None,
-                                qnorm=qnorm if new_qr else None)
-                self._pending_moe = moe_tel
+                if isinstance(opt._lr, LRScheduler):
+                    opt._lr.step()  # advance the schedule once per train step
+                if not self._profiling:
+                    led = self._ledgers.get(key)
+                    if led is not None:
+                        led.publish(self._metrics["comm_bytes"],
+                                    self._metrics["comm_ops"])
+                        # realized per-axis wire compression of this
+                        # program (quant_comm payload_ratio stamps); empty
+                        # when nothing on the wire is quantized
+                        for ax, rv in led.quant_ratios().items():
+                            self._metrics["comm_quant_ratio"].set(
+                                rv, axis=ax)
+                    self._note_step(t_entry, n_tok, lv, gnorm,
+                                    found=amp_out[4] if amp_out else None,
+                                    qnorm=qnorm if new_qr else None)
+                    self._pending_moe = moe_tel
             return Tensor(lv, stop_gradient=True)
 
         return step
@@ -1362,17 +1384,22 @@ class ParallelEngine:
             self._mem_ledgers[key] = led
         return led
 
-    def lowered_text(self, key=None) -> Optional[str]:
+    def lowered_text(self, key=None,
+                     debug_info: bool = False) -> Optional[str]:
         """StableHLO text of the last-run (or given-key) compiled train
         step, lowered again from the SAME jitted program at the engine's
         current values (one extra trace, no XLA compile; the live jit
         cache and CompileStats are untouched). This is how a caller
         proves which kernels the step contains: each Pallas kernel is a
-        ``tpu_custom_call`` carrying its ``kernel_name``. None before
-        any step has run."""
+        ``tpu_custom_call`` carrying its ``kernel_name``. With
+        ``debug_info`` the text also carries every op's name stack: the
+        step's ``forward`` / ``backward`` / ``grad_sync`` / ``optimizer``
+        scopes and the models' own, as they reach a device trace. None
+        before any step has run."""
         key = key if key is not None else self._last_key
         return self._with_aot_args(
-            key, lambda fn, args: fn.lower(*args).as_text())
+            key, lambda fn, args: fn.lower(*args).as_text(
+                debug_info=debug_info))
 
     def _with_aot_args(self, key, use):
         """``use(jitted_step, example_args)`` for the program under

@@ -126,6 +126,7 @@ from ..observability.spans import (RequestTrace, SpanRing,
                                    _format_traceparent,
                                    parse_traceparent as
                                    _parse_traceparent)
+from ..observability.trace import span as _span
 from ..tensor import Tensor
 from .kv_cache import (PagedKVCache, page_classes, with_table,
                        without_table)
@@ -181,11 +182,16 @@ class ServingRequest:
                                np.asarray(self.new_tokens, np.int64)])
 
 
+# retired decode rounds the engine keeps (``ServingEngine.rounds``)
+ROUNDS_KEPT = 4096
+
+
 class _Slot:
     """Host-side state of one in-flight batch row."""
 
     __slots__ = ("req", "pages", "pos", "state", "fed", "chunks", "seq",
-                 "hashes", "registered", "hit_pages", "inflight")
+                 "hashes", "registered", "hit_pages", "inflight",
+                 "first_round", "last_round")
 
     def __init__(self, req: ServingRequest, pages: List[int],
                  state: str = "decode", seq: int = 0):
@@ -212,13 +218,22 @@ class _Slot:
         self.hashes: Optional[List[int]] = None
         self.registered = 0
         self.hit_pages = 0
+        # indices of the first and the last round the row rode (the
+        # request's ``decode`` span carries them at eviction)
+        self.first_round: Optional[int] = None
+        self.last_round: Optional[int] = None
+
+    def rode(self, index: int):
+        if self.first_round is None:
+            self.first_round = index
+        self.last_round = index
 
 
 @dataclass
 class _Round:
     """One launched decode round the host has not read yet: the device
-    array of its tokens, and per row in it ``(b, slot, tokens it takes,
-    the request's live trace)``."""
+    array of its tokens, and per row in it ``(b, slot, tokens it
+    takes)``."""
 
     toks: Any
     rows: List[tuple]
@@ -404,6 +419,10 @@ class ServingEngine:
         self.traces = SpanRing(maxlen=trace_ring)
         self._live_traces: Dict[int, RequestTrace] = {}
         self._round = 0
+        # the decode rounds, kept once and not once a row: the last
+        # ROUNDS_KEPT retired rounds as (index, t_launch, t_retire,
+        # rows), the ``engine`` lane of the Chrome export
+        self.rounds: deque = deque(maxlen=ROUNDS_KEPT)
         # static comm ledgers of the prefill/decode programs (empty on
         # a single-device mesh; populated the first time a program
         # traces with collectives, republished per execution)
@@ -831,40 +850,47 @@ class ServingEngine:
         t0 = time.perf_counter()
         L = len(req.prompt)
         Sb = min(_bucket(L), self.M)
-        ids = np.zeros((1, Sb), np.int32)
-        ids[0, :L] = req.prompt
-        caches = self.cache.bind(
-            self.cache.rows(b),
-            wrows=self.cache.window_prefill_rows(b, L))
-        fn = self.pred._prefill_fn(1, Sb, self.M)
-        self.stats.note("prefill", (1, Sb, self.M, self.page, self.P,
-                                    str(ids.dtype), str(self._dtype)))
-        last, caches = self._run_captured(
-            ("prefill", Sb), fn, self._pvals(), jnp.asarray(ids), caches,
-            jnp.asarray([L], jnp.int32))
-        self.cache.commit(caches)
-        self._rng, sub = jax.random.split(self._rng)
-        tok0 = int(np.asarray(_sample(last, sub, self.gen))[0])
-        req.new_tokens.append(tok0)
-        self.stats.count_tokens(("prefill", Sb, self.P), 1)
-        now = time.perf_counter()
-        req.t_first_token = now
-        m = self._metrics
-        m["prefill_seconds"].observe(now - t0)
-        m["ttft"].observe(now - req.t_submit)
-        m["tokens"].inc(1, phase="prefill")
-        m["prefill_tokens"].inc(L, kind="prompt")
-        m["prefill_tokens"].inc(Sb, kind="bucket")
-        tr = self._live_traces.get(req.rid)
-        if tr is not None:
-            held = {"full_pages": len(slot.pages),
-                    "window_pages": self.cache.ring}
-            tr.add("prefill", t0, now, {"seq_bucket": Sb, **held})
-            m["stage_seconds"].observe(now - t0, stage="prefill")
-            tr.begin("decode", now, held)    # closed at eviction
-        if len(req.new_tokens) >= req.max_new_tokens or \
-                (req.eos_token_id is not None and tok0 == req.eos_token_id):
-            self._finish(b)
+        with _span("serving.prefill", rid=req.rid, seq_bucket=Sb,
+                   prompt_tokens=L):
+            with _span("serving.prefill.dispatch"):
+                ids = np.zeros((1, Sb), np.int32)
+                ids[0, :L] = req.prompt
+                caches = self.cache.bind(
+                    self.cache.rows(b),
+                    wrows=self.cache.window_prefill_rows(b, L))
+                fn = self.pred._prefill_fn(1, Sb, self.M)
+                self.stats.note("prefill",
+                                (1, Sb, self.M, self.page, self.P,
+                                 str(ids.dtype), str(self._dtype)))
+                last, caches = self._run_captured(
+                    ("prefill", Sb), fn, self._pvals(), jnp.asarray(ids),
+                    caches, jnp.asarray([L], jnp.int32))
+                self.cache.commit(caches)
+                self._rng, sub = jax.random.split(self._rng)
+                tok = _sample(last, sub, self.gen)
+            with _span("serving.prefill.fetch"):
+                tok0 = int(np.asarray(tok)[0])
+            req.new_tokens.append(tok0)
+            self.stats.count_tokens(("prefill", Sb, self.P), 1)
+            now = time.perf_counter()
+            req.t_first_token = now
+            m = self._metrics
+            m["prefill_seconds"].observe(now - t0)
+            m["ttft"].observe(now - req.t_submit)
+            m["tokens"].inc(1, phase="prefill")
+            m["prefill_tokens"].inc(L, kind="prompt")
+            m["prefill_tokens"].inc(Sb, kind="bucket")
+            tr = self._live_traces.get(req.rid)
+            if tr is not None:
+                held = {"full_pages": len(slot.pages),
+                        "window_pages": self.cache.ring}
+                tr.add("prefill", t0, now, {"seq_bucket": Sb, **held})
+                m["stage_seconds"].observe(now - t0, stage="prefill")
+                tr.begin("decode", now, held)    # closed at eviction
+            if len(req.new_tokens) >= req.max_new_tokens or \
+                    (req.eos_token_id is not None
+                     and tok0 == req.eos_token_id):
+                self._finish(b)
 
     # -- decode ----------------------------------------------------------
     def _decode_step_fn(self):
@@ -1311,8 +1337,11 @@ class ServingEngine:
                 self._spec["accepted"] += acc
             else:
                 seq = [int(toks[b])]
+            self.slots[b].rode(self._round)
             tr = self._live_traces.get(req.rid)
             if tr is not None:
+                # a row's span and not the round's: it carries what the
+                # ROW proposed and accepted, which a round cannot
                 meta = {"round": self._round, "unified": True}
                 if spec:
                     meta.update(proposed=k_use[b], accepted=acc)
@@ -1388,7 +1417,9 @@ class ServingEngine:
         has_decode = any(s is not None and s.state == "decode"
                          for s in self.slots)
         if feeders or (self._draft is not None and has_decode):
-            self._unified_round(feeders)
+            with _span("serving.unified_round", rows=self.num_active,
+                       chunks=len(feeders)):
+                self._unified_round(feeders)
         elif stalled and not has_decode:
             self._preempt_youngest()
         else:
@@ -1416,99 +1447,103 @@ class ServingEngine:
                 if s is not None and s.state == "decode"]
         if not rows:
             return None
-        t0 = time.perf_counter()
-        riding = [b for b, _ in rows]
-        npg = self.cache.npages + self.cache.ring
-        host = np.zeros((self.B, npg + 3), np.int32)
-        host[:, :self.cache.npages] = self.cache.rows(only=riding)
-        if self.cache.ring:
-            host[:, self.cache.npages:npg] = \
-                self.cache.window_rows(only=riding)
-        taken = []
-        for b, s in rows:
-            req = s.req
-            have = len(req.new_tokens) + s.inflight
-            host[b, npg] = s.pos + have - 1
-            if not s.inflight:
-                # new to the batch, or the pipeline was drained: the
-                # host knows the row's last token and feeds it
-                host[b, npg + 1] = req.new_tokens[-1]
-                host[b, npg + 2] = 1
-            take = min(self.chunk, req.max_new_tokens - have)
-            s.inflight += take
-            if have + take >= req.max_new_tokens:
-                s.state = "finishing"
-            taken.append((b, s, take, self._live_traces.get(req.rid)))
-        fn = self._decode_step_fn()
-        self.stats.note("serve_decode",
-                        (self.B, self.M, self.chunk, self.P,
-                         self.gen.temperature, self.gen.top_k,
-                         self.gen.top_p, str(self._dtype)))
-        toks, self._tok_last, state, self._rng = self._run_captured(
-            ("decode",), fn, self._pvals(), self.cache.lend(),
-            jnp.asarray(host), self._tok_last, self._rng)
-        self.cache.take_back(state)
         overlapped = self._inflight is not None    # still unretired
-        self._overlap["rounds"] += 1
-        self._overlap["overlapped"] += overlapped
-        self._metrics["rounds"].inc(
-            overlapped="true" if overlapped else "false")
-        rnd = _Round(toks, taken, t0, self._round)
-        self._round += 1
-        return rnd
+        with _span("serving.launch", round=self._round, rows=len(rows),
+                   overlapped=overlapped):
+            t0 = time.perf_counter()
+            riding = [b for b, _ in rows]
+            npg = self.cache.npages + self.cache.ring
+            host = np.zeros((self.B, npg + 3), np.int32)
+            host[:, :self.cache.npages] = self.cache.rows(only=riding)
+            if self.cache.ring:
+                host[:, self.cache.npages:npg] = \
+                    self.cache.window_rows(only=riding)
+            taken = []
+            for b, s in rows:
+                req = s.req
+                have = len(req.new_tokens) + s.inflight
+                host[b, npg] = s.pos + have - 1
+                if not s.inflight:
+                    # new to the batch, or the pipeline was drained: the
+                    # host knows the row's last token and feeds it
+                    host[b, npg + 1] = req.new_tokens[-1]
+                    host[b, npg + 2] = 1
+                take = min(self.chunk, req.max_new_tokens - have)
+                s.inflight += take
+                s.rode(self._round)
+                if have + take >= req.max_new_tokens:
+                    s.state = "finishing"
+                taken.append((b, s, take))
+            fn = self._decode_step_fn()
+            self.stats.note("serve_decode",
+                            (self.B, self.M, self.chunk, self.P,
+                             self.gen.temperature, self.gen.top_k,
+                             self.gen.top_p, str(self._dtype)))
+            toks, self._tok_last, state, self._rng = self._run_captured(
+                ("decode",), fn, self._pvals(), self.cache.lend(),
+                jnp.asarray(host), self._tok_last, self._rng)
+            self.cache.take_back(state)
+            self._overlap["rounds"] += 1
+            self._overlap["overlapped"] += overlapped
+            self._metrics["rounds"].inc(
+                overlapped="true" if overlapped else "false")
+            rnd = _Round(toks, taken, t0, self._round)
+            self._round += 1
+            return rnd
 
     def _retire_round(self, rnd: _Round):
         """The one blocking fetch of a decode round, then what the host
         owes its rows: tokens appended, finished rows evicted, metrics
-        and spans. A row that an ``eos_token_id`` finished a round ago
-        rode this round too (the hit was not known at its launch): its
-        slot is gone or another request's, and its token is dropped."""
-        t_wait = time.perf_counter()
-        toks = np.asarray(rnd.toks)
-        now = time.perf_counter()
-        m = self._metrics
-        m["fetch_wait"].observe(now - t_wait)
-        emitted = 0
-        for b, s, take, tr in rnd.rows:
-            if self.slots[b] is not s:
-                continue
-            s.inflight -= take
-            req = s.req
-            if tr is not None:
-                # one "decode_round" span per request per round, launch
-                # to retire (the Chrome export shows the shared rounds
-                # lining up across rids, each overlapping the next)
-                tr.add("decode_round", rnd.t0, now,
-                       {"round": rnd.index, "chunk": self.chunk})
-            for t in toks[b, :take]:
-                t = int(t)
-                req.new_tokens.append(t)
-                emitted += 1
-                if len(req.new_tokens) >= req.max_new_tokens or \
-                        (req.eos_token_id is not None
-                         and t == req.eos_token_id):
-                    self._finish(b)
-                    break               # rest of the chunk is discarded
-        self.stats.count_tokens(("decode", self.B, self.chunk, self.P),
-                                emitted)
-        # a round's time is what it had of the device: from its launch,
-        # or from the moment the round ahead of it was read
-        m["decode_round_seconds"].observe(
-            now - max(rnd.t0, self._t_retired))
-        m["tokens"].inc(emitted, phase="decode")
-        self._t_retired = now
-        live = [s for s in self.slots if s is not None]
-        if live:
-            cache = self.cache
-            ctx = [s.pos + len(s.req.new_tokens) for s in live]
-            m["kv_bytes_per_token"].set(
-                (sum(len(s.pages) for s in live) * cache.page_bytes
-                 + len(live) * cache.ring * cache.window_page_bytes)
-                / sum(ctx))
-            if cache.window:
-                m["window_ring_fill"].set(
-                    sum(min(cache.pages_for(c), cache.ring) for c in ctx)
-                    / (len(live) * cache.ring))
+        and the round's one record. A row that an ``eos_token_id``
+        finished a round ago rode this round too (the hit was not known
+        at its launch): its slot is gone or another request's, and its
+        token is dropped."""
+        with _span("serving.retire", round=rnd.index, rows=len(rnd.rows)):
+            t_wait = time.perf_counter()
+            with _span("serving.retire.fetch"):
+                toks = np.asarray(rnd.toks)
+            now = time.perf_counter()
+            m = self._metrics
+            m["fetch_wait"].observe(now - t_wait)
+            # the round is kept once (``self.rounds``), launch to retire,
+            # and not as a span a row: 128 rows were 128 span objects
+            self.rounds.append((rnd.index, rnd.t0, now, len(rnd.rows)))
+            emitted = 0
+            for b, s, take in rnd.rows:
+                if self.slots[b] is not s:
+                    continue
+                s.inflight -= take
+                req = s.req
+                for t in toks[b, :take]:
+                    t = int(t)
+                    req.new_tokens.append(t)
+                    emitted += 1
+                    if len(req.new_tokens) >= req.max_new_tokens or \
+                            (req.eos_token_id is not None
+                             and t == req.eos_token_id):
+                        self._finish(b)
+                        break           # rest of the chunk is discarded
+            self.stats.count_tokens(
+                ("decode", self.B, self.chunk, self.P), emitted)
+            # a round's time is what it had of the device: from its
+            # launch, or from the moment the round ahead of it was read
+            m["decode_round_seconds"].observe(
+                now - max(rnd.t0, self._t_retired))
+            m["tokens"].inc(emitted, phase="decode")
+            self._t_retired = now
+            live = [s for s in self.slots if s is not None]
+            if live:
+                cache = self.cache
+                ctx = [s.pos + len(s.req.new_tokens) for s in live]
+                m["kv_bytes_per_token"].set(
+                    (sum(len(s.pages) for s in live) * cache.page_bytes
+                     + len(live) * cache.ring * cache.window_page_bytes)
+                    / sum(ctx))
+                if cache.window:
+                    m["window_ring_fill"].set(
+                        sum(min(cache.pages_for(c), cache.ring)
+                            for c in ctx)
+                        / (len(live) * cache.ring))
 
     def _drain(self):
         """Retire the round in flight, if any: whoever reads or
@@ -1539,6 +1574,9 @@ class ServingEngine:
             sp = tr.end("decode", req.t_finish)
             if sp is not None:
                 m["stage_seconds"].observe(sp.seconds, stage="decode")
+                if slot.first_round is not None:
+                    sp.meta.update(first_round=slot.first_round,
+                                   last_round=slot.last_round)
             tr.meta["new_tokens"] = len(req.new_tokens)
             tr.add("e2e", req.t_submit, req.t_finish)
             m["stage_seconds"].observe(req.t_finish - req.t_submit,
@@ -1671,12 +1709,16 @@ class ServingEngine:
         round k-1, so the tokens of round k reach ``req.new_tokens``
         one call later, and a call with nothing to launch retires what
         is in flight."""
-        self._admit()
-        if self.chunked:
-            self._chunked_round()
-        else:
-            self._decode_round()
-        self._note_tick()
+        active = self.num_active
+        with _span("serving.step", active=active, queued=len(self.queue)):
+            with _span("serving.admit", free_slots=self.B - active):
+                self._admit()
+            if self.chunked:
+                self._chunked_round()
+            else:
+                self._decode_round()
+            with _span("serving.tick"):
+                self._note_tick()
 
     def _note_tick(self):
         """Per-tick occupancy gauges + compile-counter deltas, then one
@@ -1938,19 +1980,28 @@ class ServingEngine:
     # -- per-request traces ----------------------------------------------
     def request_traces(self) -> List[Dict[str, Any]]:
         """Finished request traces (bounded ring), oldest first — each
-        with its queued/prefill/decode_round/decode/e2e spans."""
+        with its queued/prefill/decode/e2e spans; ``decode`` carries
+        ``first_round``/``last_round``, indices into ``self.rounds``
+        (chunked and speculative rounds also leave a ``decode_round``
+        span a row: ``_unified_round``)."""
         return self.traces.to_dicts()
 
     def export_request_traces(self, path: Optional[str] = None
                               ) -> Dict[str, Any]:
         """Chrome-trace JSON (chrome://tracing / Perfetto) of the
-        finished request traces plus any still in flight; writes to
+        finished request traces plus any still in flight, and ONE
+        ``engine`` lane with the last decode rounds (``self.rounds``:
+        ``decode_round`` events, launch to retire, each overlapping the
+        next); writes to
         ``path`` when given and returns the trace dict. Every event's
         args carry the request's ``trace_id``/``span_id`` (and
         ``parent_span_id`` when the caller supplied one), so traces
         exported by different replicas stitch on ``trace_id``."""
+        rounds = [("decode_round", t0, t1, {"round": i, "rows": rows})
+                  for i, t0, t1, rows in self.rounds]
         return self.traces.to_chrome_trace(
-            path, extra=list(self._live_traces.values()))
+            path, extra=list(self._live_traces.values()),
+            lanes={"engine": rounds})
 
     def trace_context(self, rid: int) -> Optional[Dict[str, Any]]:
         """The W3C trace identity of one request — live or finished —

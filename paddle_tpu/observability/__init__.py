@@ -14,7 +14,11 @@ subsystems instrument into:
   onto transformer layers, the collective-matmul rings, and the paged-
   attention kernels so XLA/Perfetto device traces carry framework
   names, and mirrors them into host region stacks that
-  ``flight.dump()`` (the watchdog's stall flight-record) reports,
+  ``flight.dump()`` (the watchdog's stall flight-record) reports;
+  ``trace.span`` puts the engines' own host phases (admit / prefill /
+  launch / retire / tick; flush / assemble / dispatch / record) into
+  the profiler's file as ``paddle_tpu/...`` events on the device
+  trace's clock, and on the same region stacks,
 - **comm**     — ``commledger`` accounts every collective the traced
   step issues (axis / op / dtype / bytes, via the shim in
   ``distributed/collective.py``) and backs the exposed-comm
@@ -64,7 +68,7 @@ from .metrics import (Counter, Gauge, Histogram, JsonlSink,  # noqa: F401
                       MetricsRegistry, DEFAULT_LATENCY_BUCKETS,
                       get_registry, parse_prometheus_text,
                       reset_registry)
-from .trace import annotate, current_regions  # noqa: F401
+from .trace import annotate, current_regions, span  # noqa: F401
 from .flight import FlightRecorder, dump as dump_flight_record, \
     get_recorder  # noqa: F401
 from . import flops  # noqa: F401
@@ -89,7 +93,7 @@ from .exporter import MetricsServer, serve_metrics  # noqa: F401
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "JsonlSink",
     "DEFAULT_LATENCY_BUCKETS", "get_registry", "reset_registry",
-    "parse_prometheus_text", "annotate", "current_regions",
+    "parse_prometheus_text", "annotate", "span", "current_regions",
     "FlightRecorder", "dump_flight_record", "get_recorder", "flops",
     "cross_host_sum", "commledger", "CommLedger", "fleet",
     "FleetCollector", "goodput", "GoodputLedger", "healthmon",

@@ -1,14 +1,17 @@
 """Per-request lifecycle spans for the serving engine.
 
 Every ``ServingRequest`` gets a ``RequestTrace``: a list of named spans
-(queued → prefill → decode, plus one span per shared decode round the
-request was in flight for — and, under chunked prefill, one
-``prefill_chunk`` span per scheduled chunk carrying the chunk index +
-token count, plus ``preempt`` instants when a page-starved row bounces
-back to the queue) on the ``time.perf_counter`` clock. Finished traces
-land in a bounded ``SpanRing`` so a long-running engine keeps the
-last-N request histories without growing memory. The Chrome export thus
-shows chunk scheduling interleaved with the decode rounds; TTFT stays
+(queued → prefill → decode; ``decode`` names the first and the last
+shared decode round the request rode, and the rounds themselves are the
+engine's, kept once: ``ServingEngine.rounds``, drawn as one ``engine``
+lane — and, under chunked prefill, one ``prefill_chunk`` span per
+scheduled chunk carrying the chunk index + token count, one
+``decode_round`` span per row of a unified round with what the row
+proposed and accepted, plus ``preempt`` instants when a page-starved
+row bounces back to the queue) on the ``time.perf_counter`` clock.
+Finished traces land in a bounded ``SpanRing`` so a long-running engine
+keeps the last-N request histories without growing memory. The Chrome
+export thus shows chunk scheduling interleaved with the decode rounds; TTFT stays
 defined as first-token time (the ``prefill`` stage span closes when the
 last chunk samples, not per chunk).
 
@@ -49,7 +52,7 @@ __all__ = ["Span", "RequestTrace", "SpanRing", "make_trace_id",
            "make_span_id", "format_traceparent", "parse_traceparent"]
 
 # the per-request lifecycle stages, in order (the stage histogram's
-# label values; "decode_round" additionally marks shared-round spans)
+# label values)
 STAGES = ("queued", "prefill", "decode", "e2e")
 
 # W3C trace-context identity: trace_id is 32 lowercase hex chars,
@@ -228,16 +231,31 @@ class SpanRing:
         return [t.to_dict() for t in self.traces()]
 
     def to_chrome_trace(self, path: Optional[str] = None,
-                        extra: Optional[List[RequestTrace]] = None
+                        extra: Optional[List[RequestTrace]] = None,
+                        lanes: Optional[Dict[str, List[Tuple[
+                            str, float, float, Dict[str, Any]]]]] = None
                         ) -> Dict[str, Any]:
         """Chrome-trace JSON of every finished trace (plus ``extra``
         in-flight ones): one ``tid`` lane per request, "X" complete
-        events in microseconds rebased to the earliest span. Writes to
-        ``path`` when given; always returns the dict."""
+        events in microseconds rebased to the earliest span. ``lanes``
+        adds named lanes that belong to no request (the engine's decode
+        rounds): ``{lane: [(name, t0, t1, args)]}``, at negative
+        ``tid``s. Writes to ``path`` when given; always returns the
+        dict."""
         traces = self.traces() + list(extra or [])
+        lanes = lanes or {}
         events: List[Dict[str, Any]] = []
-        t_base = min((s.t0 for t in traces for s in t.spans),
+        t_base = min([s.t0 for t in traces for s in t.spans]
+                     + [e[1] for evs in lanes.values() for e in evs],
                      default=0.0)
+        for k, (lane, evs) in enumerate(lanes.items()):
+            events.append({"ph": "M", "name": "thread_name", "pid": 0,
+                           "tid": -1 - k, "args": {"name": lane}})
+            events.extend({"ph": "X", "cat": "serving", "name": name,
+                           "pid": 0, "tid": -1 - k,
+                           "ts": (t0 - t_base) * 1e6,
+                           "dur": (t1 - t0) * 1e6, "args": args}
+                          for name, t0, t1, args in evs)
         for tr in traces:
             events.append({"ph": "M", "name": "thread_name", "pid": 0,
                            "tid": tr.rid,

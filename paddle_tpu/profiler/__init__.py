@@ -9,7 +9,8 @@ TPU-native: the device side is the XLA/TPU profiler (xplane) reached
 through ``jax.profiler`` — traces open in TensorBoard/Perfetto, covering
 what CUPTI covered. The host side is a lightweight in-process event
 recorder (RecordEvent) feeding ``summary()`` and the chrome-trace
-exporter, the host_tracer role.
+exporter, the host_tracer role; a RecordEvent also reaches the xplane
+as a ``TraceAnnotation`` of its name.
 """
 from __future__ import annotations
 
@@ -55,17 +56,31 @@ def _append_event(name: str, t0: float, t1: float, cat: str):
 
 
 class RecordEvent:
-    """Host-side named range (reference profiler/utils.py RecordEvent)."""
+    """Host-side named range (reference profiler/utils.py RecordEvent).
+    While it is open it also holds a ``jax.profiler.TraceAnnotation`` of
+    the same name, so under a device trace (``jax.profiler.start_trace``,
+    or a ``Profiler`` that is not ``timer_only``) an operator's own
+    ranges land in the same ``.xplane.pb`` as the engines' spans
+    (``observability/trace.py::span``); with no session open that costs
+    about a microsecond and records nothing."""
 
     def __init__(self, name: str, event_type: str = "UserDefined"):
         self.name = name
         self.event_type = event_type
         self._t0 = None
+        self._ann = None
 
     def begin(self):
+        import jax
+
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
 
     def end(self):
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
         if self._t0 is None or not _active:
             return
         _append_event(self.name, self._t0, time.perf_counter(),

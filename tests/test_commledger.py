@@ -632,7 +632,18 @@ class TestServingSpans:
             names = [s["name"] for s in t["spans"]]
             for stage in ("queued", "prefill", "decode", "e2e"):
                 assert stage in names
-            assert "decode_round" in names
+            # the rounds are the engine's, kept once (eng.rounds), not a
+            # span a request a round: the request names the ones it rode
+            assert "decode_round" not in names
+            dec = next(s for s in t["spans"] if s["name"] == "decode")
+            kept = {r[0]: r for r in eng.rounds}
+            first, last = (dec["meta"]["first_round"],
+                           dec["meta"]["last_round"])
+            assert first <= last and {first, last} <= set(kept)
+            # 6 tokens: one from the prefill, five over rounds of 2
+            assert last - first + 1 >= 3
+            assert kept[first][1] >= dec["t0"] - 1e-3
+            assert kept[last][2] <= dec["t1"] + 1e-3
             for s in t["spans"]:
                 assert s["t1"] is not None and s["seconds"] >= 0
             e2e = next(s for s in t["spans"] if s["name"] == "e2e")
@@ -662,7 +673,16 @@ class TestServingSpans:
         assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in xs)
         assert {"queued", "prefill", "decode", "decode_round",
                 "e2e"} <= {e["name"] for e in xs}
-        assert any(e["ph"] == "M" for e in evs)   # lane names
+        # lane names: one per request and ONE for the engine's rounds
+        named = {e["tid"]: e["args"]["name"] for e in evs
+                 if e["ph"] == "M"}
+        engine = [tid for tid, n in named.items() if n == "engine"]
+        assert len(engine) == 1 and engine[0] not in rids
+        rounds = [e for e in xs if e["name"] == "decode_round"]
+        assert {e["tid"] for e in rounds} == set(engine)
+        assert [e["args"]["round"] for e in rounds] == \
+            [r[0] for r in eng.rounds]
+        assert all(1 <= e["args"]["rows"] <= 2 for e in rounds)
 
     def test_ring_is_bounded(self):
         ring = obs.SpanRing(maxlen=3)
