@@ -357,6 +357,30 @@ def kernel_names(text: str) -> dict:
     return out
 
 
+def check_flash_calls(found: dict, eng, num_layers: int, pipe: bool) -> None:
+    """The compiled train step's flash kernels, from ``kernel_names`` of
+    its lowered text. Every layout holds the forward and both backward
+    kernels. Where the tape differentiates the model (not ``pipe``) its
+    backward reuses the forward's out and lse, so the text holds one
+    forward a layer (the generic jax.vjp ran it a second time) and the
+    tape counts one explicit grad kernel a layer. The pipeline's stages
+    run under no_grad() and scan over the stacked layers: its text holds
+    one call for all of them, the remat's beside it, and its tape records
+    no op node."""
+    check(all(found.get(n, 0) >= 1 for n in
+              ("flash_attention_fwd", "flash_attention_dq",
+               "flash_attention_dkv")),
+          f"compiled step holds flash fwd and bwd as Mosaic calls {found}")
+    if pipe:
+        return
+    check(found.get("flash_attention_fwd", 0) == num_layers,
+          f"exactly {num_layers} flash_attention_fwd calls in the compiled "
+          f"step, one a layer (got {found.get('flash_attention_fwd', 0)})")
+    check(eng.backward_nodes[0] == num_layers,
+          f"the tape took {num_layers} explicit grad kernels "
+          f"(explicit, generic = {eng.backward_nodes})")
+
+
 def finish_child(phase: str, device: dict, events: JaxEvents,
                  extra: dict) -> None:
     out = {"phase": phase, "ok": True, "device": device}
@@ -676,11 +700,9 @@ def phase_train(sz: Sizes, layout: str) -> None:
     check(acct["optimizer_state"] <= 2 * ratio * acct["params"] * 1.01,
           f"optimizer moments are stored in {sz.state_dtype} as asked "
           f"(two moments, {ratio:g}x the {cfg.dtype} parameter bytes each)")
-    found = kernel_names(eng.lowered_text())
-    check(sz.rehearsal or all(found.get(n, 0) >= 1 for n in
-                              ("flash_attention_fwd", "flash_attention_dq",
-                               "flash_attention_dkv")),
-          f"compiled step holds flash fwd and bwd as Mosaic calls {found}")
+    if not sz.rehearsal:
+        check_flash_calls(kernel_names(eng.lowered_text()), eng,
+                          cfg.num_layers, pipe)
 
     # every device of the mesh holds memory, and each parameter's shards
     # have the shape its PartitionSpec says

@@ -29,7 +29,7 @@ __all__ = ["auto_cast", "amp_guard", "decorate", "GradScaler",
 WHITE_LIST: Set[str] = {
     "matmul", "linear", "conv2d", "conv1d", "conv2d_transpose", "bmm",
     "fused_gemm_epilogue", "einsum_op", "flash_attention",
-    "scaled_dot_product_attention", "addmm",
+    "flash_attention_pallas", "scaled_dot_product_attention", "addmm",
 }
 # ops that must stay fp32 (numerically sensitive)
 BLACK_LIST: Set[str] = {

@@ -35,6 +35,7 @@ __all__ = [
     "is_grad_enabled",
     "set_grad_enabled",
     "record_op",
+    "last_backward_nodes",
     "register_backward_end_callback",
     "unregister_backward_end_callback",
 ]
@@ -78,6 +79,15 @@ class _GradModeGuard(contextlib.ContextDecorator):
     def __exit__(self, *exc):
         set_grad_enabled(self._prev)
         return False
+
+
+def last_backward_nodes() -> Tuple[int, int]:
+    """Of the op nodes this thread's last ``backward()`` ran: how many
+    took their op's explicit grad kernel, and how many the generic
+    ``jax.vjp`` that runs the forward again (core/registry.py
+    ``run_grad``). Counted while the tape walks, so under a trace it
+    costs the compiled program nothing."""
+    return getattr(_state, "backward_nodes", (0, 0))
 
 
 def no_grad():
@@ -256,9 +266,12 @@ def backward(tensors: Sequence, grad_tensors: Optional[Sequence] = None,
 
     queue = deque(n for n in pending if pending[n] == 0)
     processed = []
+    by_path = [0, 0]    # op nodes by explicit grad kernel | generic vjp
     while queue:
         node = queue.popleft()
         out_grads = buffers.pop(node, [None] * node.n_outputs)
+        if node.call is not None:
+            by_path[node.call.opdef.grad_fn is None] += 1
         in_grads = node.apply(out_grads)
         if node._hooks:
             for hook in node._hooks:
@@ -277,6 +290,7 @@ def backward(tensors: Sequence, grad_tensors: Optional[Sequence] = None,
                 if pending[producer] == 0:
                     queue.append(producer)
 
+    _state.backward_nodes = tuple(by_path)
     for cb in list(_backward_end_callbacks):
         cb()
 

@@ -198,8 +198,12 @@ def run_grad(call: OpCall, in_values, out_values, out_grads):
     """Execute the backward kernel for a recorded forward call.
 
     Uses the op's explicit grad kernel when registered, otherwise the
-    generic jax.vjp path (jit-cached; XLA CSEs the recomputed forward with
-    the original under whole-graph traces).
+    generic jax.vjp path (jit-cached). The generic path runs the forward
+    again for its residuals. Under a whole-graph trace XLA removes that
+    replay where it is plain HLO: it merges with the original or is dead
+    code. It does not merge two custom calls, so an op whose forward
+    computes residuals in one (a Pallas kernel) registers a grad kernel
+    and returns those residuals as outputs (ops/attention.py).
     """
     opdef = call.opdef
     if opdef.grad_fn is not None:

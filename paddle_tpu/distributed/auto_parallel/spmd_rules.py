@@ -233,9 +233,11 @@ def _embedding(op, in_ts, out_vals, args, kwargs):
     return [tuple(si) + (tail,)]
 
 
-@register_rule("flash_attention", "scaled_dot_product_attention")
+@register_rule("flash_attention", "flash_attention_pallas",
+               "scaled_dot_product_attention")
 def _attention(op, in_ts, out_vals, args, kwargs):
-    """(reference FlashAttInferSpmd) output follows q."""
+    """(reference FlashAttInferSpmd) output follows q (the Pallas op's
+    second output, the rows' logsumexp, is left unannotated)."""
     s = _spec_of(in_ts[0])
     return [s] if s is not None else None
 

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -346,6 +346,10 @@ class ParallelEngine:
         # that force recompiles (e.g. an overlap path keyed on a traced
         # shape) surface here and on the bench JSON lines
         self.stats = CompileStats()
+        # the tape's op nodes in the newest traced step's backward():
+        # (took an explicit grad kernel, took the generic jax.vjp, which
+        # runs the op's forward again). Written while tracing only.
+        self.backward_nodes: Optional[Tuple[int, int]] = None
         # unified telemetry (observability/): per-step wall time, loss,
         # grad-norm, tokens/s, MFU, device memory, compile counters —
         # all host-side on fetched scalars, never inside the trace
@@ -766,6 +770,7 @@ class ParallelEngine:
                 else:
                     with _annotate("backward"):
                         loss.backward()
+                self.backward_nodes = _ad.last_backward_nodes()
                 raw_grads = {
                     id(p): (p.grad._value if p.grad is not None
                             else jnp.zeros_like(p._value))

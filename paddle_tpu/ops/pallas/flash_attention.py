@@ -48,7 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 from . import (_BLOCKS_LARGE as _BLOCKS, compiler_params as
                _compiler_params, pick_block as _pick_block)
 
-__all__ = ["flash_attention_fwd", "flash_supported"]
+__all__ = ["flash_attention_fwd", "flash_attention_with_lse",
+           "flash_attention_bwd", "flash_supported"]
 
 _VMEM = pltpu.VMEM
 
@@ -507,3 +508,39 @@ flash_attention_fwd.defvjp(
     lambda q, k, v, causal, scale, interpret, qseg=None, kseg=None:
     _fa_fwd(q, k, v, causal, scale, interpret, qseg, kseg),
     _fa_bwd)
+
+
+def _fa_fwd_with_lse(q, k, v, causal, scale, interpret, qseg=None,
+                     kseg=None):
+    out, res = _fa_fwd(q, k, v, causal, scale, interpret, qseg, kseg)
+    return (out, res[4]), res
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention_with_lse(q, k, v, causal=False, scale=None,
+                             interpret=False, q_segment_ids=None,
+                             kv_segment_ids=None):
+    """:func:`flash_attention_fwd` handing out what its backward needs
+    beside ``out``: the rows' logsumexp ``lse`` [B*H, 1, Sq] f32 (the
+    reference's ``softmax_lse``). For a caller that keeps both and calls
+    :func:`flash_attention_bwd` itself (the tape's grad kernel,
+    ops/attention.py) instead of having ``jax.vjp`` run the forward
+    again for them. ``lse`` is a residual, not a second result: its
+    cotangent is ignored."""
+    return _fa_fwd_with_lse(q, k, v, causal, scale, interpret,
+                            q_segment_ids, kv_segment_ids)[0]
+
+
+flash_attention_with_lse.defvjp(
+    _fa_fwd_with_lse,
+    lambda causal, scale, interpret, res, g:
+    _fa_bwd(causal, scale, interpret, res, g[0]))
+
+
+def flash_attention_bwd(q, k, v, out, lse, g, causal=False, scale=None,
+                        interpret=False, q_segment_ids=None,
+                        kv_segment_ids=None):
+    """(dq, dk, dv) from the forward's own ``out`` and ``lse`` and the
+    cotangent ``g`` of ``out``: the two backward kernels, no forward."""
+    return _fa_bwd(causal, scale, interpret,
+                   (q, k, v, out, lse, q_segment_ids, kv_segment_ids), g)[:3]

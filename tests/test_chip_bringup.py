@@ -140,7 +140,7 @@ def _as_tpu(monkeypatch):
 def _flash_site(monkeypatch):
     import paddle_tpu.ops.attention as attn
 
-    monkeypatch.setattr(attn, "flash_attention_fwd", _boom)
+    monkeypatch.setattr(attn, "flash_attention_with_lse", _boom)
     q = paddle.to_tensor(jnp.zeros((1, 128, 2, 128), jnp.float32))
     return lambda: attn.flash_attention(q, q, q, causal=True)
 

@@ -47,7 +47,8 @@ def test_gqa_broadcast_matches_repeated_kv():
     import jax.numpy as jnp
 
     import jax
-    from paddle_tpu.ops.attention import flash_attention
+    from paddle_tpu.ops.attention import \
+        flash_attention_xla as flash_attention
     from paddle_tpu.ops.pallas.decode_attention import _dense_ragged
 
     r = np.random.RandomState(3)
