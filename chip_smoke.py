@@ -30,6 +30,16 @@ prefill's last row): both decode kernels against their dense twins on
 the chip, 40 decode rounds past a ring's wrap, two classes of pools
 written in place.
 
+``--phases serve_sparse`` (only when named) serves the tiny preset of
+the decoder whose attention keeps the keys a learned index chooses
+(``sparse_moe_tiny`` with head widths the chip tiles: heads of 128, index
+heads of 64, the 256 best keys of contexts to 1,000) the same way: the
+decode kernel under a kept mask against its dense twin, the kept count
+of every row of a full forward (``kept_keys_wrong`` 0) and of every row
+the decode steps selected for (their own count on the device), three
+pooled arrays a layer written in place and
+``paged_sparse_decode_attention`` in the decode program.
+
 ``--four-chips`` adds the same train step over a real 2x2 mesh in two
 layouts (mp2 x dp2 on ParallelEngine; pp2 x mp2 on GPTForCausalLMPipe via
 ``fleet.distributed_model(...).train_batch``); asked for, fewer than four
@@ -72,13 +82,14 @@ import time
 ONE_CHIP_PHASES = ("kernels", "train", "serve")
 FOUR_CHIP_PHASES = ("mp2dp2", "pp2mp2")
 # run only when named in --phases: the default three fill their time limit
-EXTRA_PHASES = ("serve_latent", "serve_hybrid")
+EXTRA_PHASES = ("serve_latent", "serve_hybrid", "serve_sparse")
 # seconds per child, compilation included. The one-chip three sum to
 # 1100, inside the 1200 s that run is allowed; measured cold on a v5e
 # they took 72, 122 and 106 s (CHANGES.md PR 21).
 PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
                  "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 400,
-                 "serve_hybrid": 900}    # two shapes since PR 35
+                 "serve_hybrid": 900,    # two shapes since PR 35
+                 "serve_sparse": 400}
 RESULT_TAG = "CHIP_SMOKE_PHASE_RESULT "
 
 # Tolerances, each with its reason. Every comparison is
@@ -192,6 +203,20 @@ class Sizes:
                 embedding_multiplier=2048 ** 0.5, head_on_last_row=True,
                 max_position_embeddings=2304, dtype="bfloat16")
             self.afmoe_lens = (2150, 2040, 1000, 100)
+            # serve_sparse: models.hybrid_moe.sparse_moe_tiny (three
+            # sparse layers, a softmax router) with the head widths the
+            # chip tiles; the 256 best keys of contexts to 1,000
+            self.sparse = dict(
+                qk_head_dim=128, v_head_dim=128, rotary_dim=128,
+                index_head_dim=64, index_topk=256, attention_block=128,
+                max_position_embeddings=1152, dtype="bfloat16")
+            self.sparse_lens, self.sparse_new = (900, 700, 300, 100), 40
+            # a pool the engine sizes itself here (512 pages, 33 MB an
+            # array) fits the chip's VMEM, and XLA then stages the whole
+            # V pool there around the decode step's scatter: a copy of a
+            # pool that no deployment's pool (gigabytes) can get. 2,048
+            # pages (134 MB an array) keep the check on layout changes
+            self.sparse_pool = 2048
         else:
             self.gpt = dict(vocab_size=1024, hidden_size=128,
                             num_layers=2, num_heads=4,
@@ -244,6 +269,10 @@ class Sizes:
                 head_on_last_row=True, max_position_embeddings=288,
                 attention_block=32, dtype="bfloat16")
             self.afmoe_lens = (60, 50, 20, 10)
+            self.sparse = dict(max_position_embeddings=288,
+                               dtype="bfloat16")
+            self.sparse_lens, self.sparse_new = (100, 60, 30, 10), 12
+            self.sparse_pool = None
 
 
 # ---------------------------------------------------------------------------
@@ -1162,6 +1191,141 @@ def serve_hybrid_shape(sz: Sizes, sizes: dict, prompt_lens) -> dict:
     return out
 
 
+def phase_serve_sparse(sz: Sizes) -> None:
+    """The decoder whose attention keeps the keys a learned index
+    chooses, through ServingEngine in its default mode: the decode
+    kernel under a kept mask agrees with its dense twin, every row of a
+    full forward keeps ``min(t + 1, top-k)`` keys, decode agrees with a
+    full forward, three pooled arrays a layer are written in place."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import (Config, ServingEngine,
+                                      create_predictor)
+    from paddle_tpu.models.hybrid_moe import (HybridMoEForCausalLM,
+                                              collect_selection,
+                                              sparse_moe_tiny)
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    _, device, events = start_child(sz.rehearsal)
+    cfg = sparse_moe_tiny(**sz.sparse)
+    page, B, topk = sz.page, sz.hybrid_batch, cfg.index_topk
+    r = np.random.RandomState(0)
+    # the kernel alone under a kept mask, at the decode program's shapes
+    ncols = -(-(max(sz.sparse_lens) + sz.sparse_new) // page)
+    lens = np.resize([0, topk - 1, topk, topk + 1, 2 * page - 1,
+                      ncols * page - 2], B).astype("int32")
+    P = B * ncols + 1
+    rnd = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.bfloat16)
+    KV = cfg.num_kv_heads
+    kp = rnd(P, KV, page, cfg.k_cache_width)
+    vp = rnd(P, KV, page, cfg.v_head_dim)
+    tbl = r.permutation(P - 1)[:B * ncols].reshape(B, ncols).astype("int32")
+    q = rnd(B, 1, cfg.num_heads, cfg.k_cache_width)
+    cols = np.arange(ncols * page)[None]
+    keep = jnp.asarray((cols <= lens[:, None])
+                       & ((r.random_sample((B, ncols * page)) < 0.25)
+                          | (cols == lens[:, None])))
+    if sz.rehearsal or da.paged_supported(q.shape, kp.shape, vp.shape):
+        got = da.paged_decode_attention(
+            q, kp, vp, tbl, lens, scale=cfg.softmax_scale, keep=keep,
+            interpret=sz.rehearsal)
+        want = da.paged_attention_dense(q, kp, vp, tbl, lens,
+                                        cfg.softmax_scale, None, None, keep)
+        err = float(jnp.abs(got.astype(jnp.float32)
+                            - want.astype(jnp.float32)).max())
+        check(err <= TOL_ATTN,
+              f"decode kernel under a kept mask ({cfg.num_heads // KV} "
+              f"query heads a KV head, {ncols} pages a row) within "
+              f"{TOL_ATTN} of its dense twin (max err {err:.2e})")
+    t0 = time.perf_counter()
+    paddle.set_default_dtype(cfg.dtype)
+    paddle.seed(0)
+    model = HybridMoEForCausalLM(cfg)
+    pred = create_predictor(
+        Config().set_model(model).enable_paged_kv(page_size=page))
+    mix = [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
+           for n in sz.sparse_lens]
+    eng = ServingEngine(pred, max_batch=B, debug_invariants=True,
+                        pool_pages=sz.sparse_pool)
+    check(eng.cache.arrays == [3] * cfg.num_layers
+          and all(layer[2].shape[1:] == (1, page, cfg.index_cache_width)
+                  for layer in eng.pools),
+          f"three pooled arrays a layer, the index keys "
+          f"{eng.pools[0][2].shape}")
+    t_setup = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    rids = [eng.submit(p, max_new_tokens=sz.sparse_new) for p in mix]
+    done = eng.run()
+    t_run = time.perf_counter() - t0
+    outs = [np.asarray(done[rid].new_tokens) for rid in rids if rid in done]
+    check(len(outs) == len(rids)
+          and all(len(o) == sz.sparse_new for o in outs)
+          and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
+          f"every request returned {sz.sparse_new} tokens of the "
+          f"vocabulary; the longest context {len(mix[0]) + sz.sparse_new} "
+          f"is {(len(mix[0]) + sz.sparse_new) / topk:.1f} times the "
+          f"{topk} keys a query keeps")
+    # one forward over the whole context: what every row kept, and the
+    # served tokens' logits
+    from paddle_tpu.autograd import no_grad
+    from paddle_tpu.distributed.engine import bind_params
+
+    params = list(model.parameters())
+
+    def whole(pvals, ids):
+        with no_grad(), bind_params(params, pvals), \
+                collect_selection() as sets:
+            logits = model.forward(ids)
+        return logits._value, [k[0].sum(-1) for k in sets]
+
+    seq = np.concatenate([mix[0], outs[0][:-1]])
+    full, kept = jax.jit(whole)(tuple(p._value for p in params),
+                                jnp.asarray(seq[None, :]))
+    full = np.asarray(full[0].astype(jnp.float32))
+    want_kept = np.minimum(np.arange(len(seq)) + 1, topk)
+    wrong = sum(int((np.asarray(k) != want_kept).sum()) for k in kept)
+    check(len(kept) == cfg.num_layers and wrong == 0,
+          f"kept_keys_wrong == {wrong}: every row of {len(kept)} layers "
+          f"of a full forward keeps min(t + 1, {topk}) keys")
+    sel = eng.selection_stats()
+    check(sel["rows"] > 0 and sel["kept_keys_wrong"] == 0,
+          f"the decode steps' own count on the device: kept_keys_wrong "
+          f"== {sel['kept_keys_wrong']} of {sel['rows']} (row, layer) "
+          f"pairs")
+    at = full[len(mix[0]) - 1:]
+    gaps = at.max(-1) - at[np.arange(len(outs[0])), outs[0]]
+    check(float(gaps.max()) <= TOL_LOGIT,
+          f"every served token of the longest request scores within "
+          f"{TOL_LOGIT} of a full forward's best (widest "
+          f"{float(gaps.max()):.3f})")
+    st = eng.moe_stats()
+    check(st["dropped"] == 0 and st["tokens"][-1] > 0,
+          f"expert layers dropped {st['dropped']} routed pairs of "
+          f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
+    c = eng.cache.counts()
+    check(c["free"] == eng.cache.usable, f"every page back to free: {c}")
+    found = kernel_names(eng.lowered_text(("decode",)))
+    check(sz.rehearsal
+          or found.get("paged_sparse_decode_attention", 0) >= 1,
+          f"program ('decode',) holds Mosaic calls {found}")
+    shapes = {a.shape for layer in eng.pools for a in layer}
+    for site in [("decode",)] + sorted(
+            s for s in eng.program_sites() if s[0] == "prefill")[-1:]:
+        text = eng.compiled_text(site)
+        n = sum(eng.pool_copies(text, s) for s in shapes)
+        check(sz.rehearsal or n == 0,
+              f"compiled program {site}: {n} copies of a whole pool")
+        if site == ("decode",):     # the routing counters ride along
+            check_decode_donation(eng, text, 4 * cfg.num_layers)
+    check_overlap(eng)
+    finish_child("serve_sparse", device, events,
+                 {"setup_s": round(t_setup, 1), "run_s": round(t_run, 2),
+                  "pool_pages": eng.P, "kept_keys_wrong": wrong})
+
+
 # ---------------------------------------------------------------------------
 # parent: children, in order, one at a time; never imports JAX
 # ---------------------------------------------------------------------------
@@ -1232,6 +1396,8 @@ def main(argv=None) -> int:
                 phase_serve_latent(sz)
             elif args.phase == "serve_hybrid":
                 phase_serve_hybrid(sz)
+            elif args.phase == "serve_sparse":
+                phase_serve_sparse(sz)
             else:
                 phase_train(sz, args.phase)
         except Failed as e:

@@ -1,5 +1,6 @@
 """MoE gates: naive top-k, Switch (top-1), GShard (top-2), and the
-sigmoid top-k gate with a selection bias (no capacity, no drops).
+top-k gate over a sigmoid score with a selection bias or a softmax
+(no capacity, no drops).
 
 TPU-native re-design of the reference's gate zoo
 (reference: python/paddle/incubate/distributed/models/moe/gate/
@@ -96,21 +97,36 @@ class GShardGate(BaseGate):
 
 
 class SigmoidTopKGate(BaseGate):
-    """Sigmoid scores, top-k chosen on ``score + bias`` (the bias steers
-    the CHOICE only: auxiliary-loss-free load balancing), weights
-    ``scaling * score / sum(chosen scores)``. No capacity: every chosen
-    pair is computed (``GatedMoELayer``). The router product and the
-    top-k run in float32 whatever the model's type, because a near-tie
-    between the k-th and the next score flips an expert under bf16
-    rounding."""
+    """Top-k on a score of ALL ``num_experts``, weights ``scaling *
+    score / sum(chosen scores)``. No capacity: every chosen pair is
+    computed (``GatedMoELayer``). The score function is data
+    (``score_func``):
+
+    - ``"sigmoid"``: sigmoid scores, chosen on ``score + bias`` (the bias
+      steers the CHOICE only: auxiliary-loss-free load balancing);
+    - ``"softmax"``: a softmax over all experts, chosen on the
+      probabilities themselves, renormalised over the chosen; no bias
+      (the gate then has no such parameter).
+
+    The router product, the score and the top-k run in float32 whatever
+    the model's type, because a near-tie between the k-th and the next
+    score flips an expert under bf16 rounding."""
+
+    SCORE_FUNCS = ("sigmoid", "softmax")
 
     def __init__(self, d_model, num_experts, topk: int = 8,
-                 routed_scaling_factor: float = 1.0, **kw):
+                 routed_scaling_factor: float = 1.0,
+                 score_func: str = "sigmoid", **kw):
         super().__init__(d_model, num_experts)
+        if score_func not in self.SCORE_FUNCS:
+            raise ValueError(f"score_func is one of {self.SCORE_FUNCS}, "
+                             f"not {score_func!r}")
         self.top_k = topk
         self.capacity_factor = None
         self.routed_scaling_factor = float(routed_scaling_factor)
-        self.bias = self.create_parameter((num_experts,), is_bias=True)
+        self.score_func = score_func
+        if score_func == "sigmoid":
+            self.bias = self.create_parameter((num_experts,), is_bias=True)
 
     def route(self, x2d):
         """Values in, values out: tokens [T, d] -> (expert ids [T, k]
@@ -120,11 +136,16 @@ class SigmoidTopKGate(BaseGate):
         import jax.numpy as jnp
         from jax import lax
 
-        s = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             x2d.astype(jnp.float32), self.weight._value.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        _, idx = lax.top_k(s + self.bias._value.astype(jnp.float32),
-                           self.top_k)
+            precision=lax.Precision.HIGHEST)
+        if self.score_func == "sigmoid":
+            s = jax.nn.sigmoid(logits)
+            _, idx = lax.top_k(s + self.bias._value.astype(jnp.float32),
+                               self.top_k)
+        else:
+            s = jax.nn.softmax(logits, axis=-1)
+            _, idx = lax.top_k(s, self.top_k)
         sel = jnp.take_along_axis(s, idx, axis=-1)
         w = self.routed_scaling_factor * sel / jnp.sum(sel, -1,
                                                        keepdims=True)

@@ -456,7 +456,8 @@ class GatedMoELayer(Layer):
     """Routed SwiGLU experts plus shared experts, for one holder of an
     expert-parallel layer: it is TOLD which experts it holds
     (``expert_offset``, ``num_local_experts``), routes every token over
-    all ``num_experts`` (``SigmoidTopKGate``), and computes its own
+    all ``num_experts`` (``SigmoidTopKGate``, whose ``score_func`` is a
+    sigmoid with a selection bias or a softmax), and computes its own
     experts' part of the sum (``routed_swiglu``: batched over the held
     experts for a decode step's few tokens, where the weights' bytes set
     the time whatever is computed; sorted and grouped, with work in
@@ -477,7 +478,7 @@ class GatedMoELayer(Layer):
                  expert_offset: int = 0, top_k: int = 8,
                  routed_scaling_factor: float = 1.0,
                  num_shared_experts: int = 1, weight_attr=None,
-                 down_attr=None):
+                 down_attr=None, score_func: str = "sigmoid"):
         super().__init__()
         El = num_experts if num_local_experts is None \
             else int(num_local_experts)
@@ -489,7 +490,8 @@ class GatedMoELayer(Layer):
         self.expert_offset = int(expert_offset)
         self.gate = SigmoidTopKGate(
             d_model, num_experts, topk=top_k,
-            routed_scaling_factor=routed_scaling_factor)
+            routed_scaling_factor=routed_scaling_factor,
+            score_func=score_func)
         d, h, hs = d_model, d_hidden, d_hidden * num_shared_experts
         down_attr = down_attr if down_attr is not None else weight_attr
         self.w_gate = self.create_parameter((El, d, h), attr=weight_attr)

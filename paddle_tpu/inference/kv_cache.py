@@ -39,14 +39,15 @@ __all__ = ["PagedKVCache", "pool_shapes", "page_bytes", "page_classes",
 
 
 def pool_shapes(model, P: int, page: int):
-    """Per layer, the shapes ``(first, second)`` of the two arrays the
-    page pool holds, ``[P, cache heads, page, width]`` each. A model
-    says so itself (``kv_pool_shapes(P, page)``: a latent-attention
-    model pools a latent and a rotated key); without that it is K and V
-    of ``num_kv_heads x head_dim``."""
+    """Per layer, the shapes of the arrays the page pool holds for it,
+    ``[P, cache heads, page, width]`` each: two (K and V; a latent and a
+    rotated key of a latent-attention model) or more (a layer whose
+    attention selects keys by a learned index pools its index keys as a
+    third). A model says so itself (``kv_pool_shapes(P, page)``);
+    without that it is K and V of ``num_kv_heads x head_dim``."""
     fn = getattr(model, "kv_pool_shapes", None)
     if fn is not None:
-        return [(tuple(a), tuple(b)) for a, b in fn(P, page)]
+        return [tuple(tuple(a) for a in layer) for layer in fn(P, page)]
     cfg = model.config
     shape = (P, cfg.num_kv_heads, page, cfg.head_dim)
     return [(shape, shape)] * cfg.num_layers
@@ -70,13 +71,13 @@ def page_classes(model) -> Tuple[List[bool], Optional[int]]:
 
 
 def page_bytes(model, page: int, dtype, window: bool = False) -> int:
-    """Bytes one page takes over the two pooled arrays of every layer
-    of its class (``window``: the window layers; else the full ones,
-    which without ``kv_page_classes`` is every layer)."""
+    """Bytes one page takes over the pooled arrays of every layer of
+    its class (``window``: the window layers; else the full ones, which
+    without ``kv_page_classes`` is every layer)."""
     mask, _ = page_classes(model)
-    return sum(int(np.prod(a)) + int(np.prod(b))
-               for (a, b), w in zip(pool_shapes(model, 1, page), mask)
-               if w == window) * np.dtype(dtype).itemsize
+    return sum(int(np.prod(a))
+               for layer, w in zip(pool_shapes(model, 1, page), mask)
+               if w == window for a in layer) * np.dtype(dtype).itemsize
 
 
 def _payload_nbytes(payload) -> int:
@@ -159,18 +160,19 @@ class PagedKVCache:
         self.shapes = pool_shapes(model, self.P, self.page)
         if self.window:
             self.shapes = [
-                ((self.Pw,) + a[1:], (self.Pw,) + b[1:]) if w else (a, b)
-                for (a, b), w in zip(self.shapes, self.window_layers)]
-        self.pools = [(jnp.zeros(a, dtype), jnp.zeros(b, dtype))
-                      for a, b in self.shapes]
+                tuple((self.Pw,) + a[1:] for a in layer) if w else layer
+                for layer, w in zip(self.shapes, self.window_layers)]
+        # arrays a layer pools: where its table sits in a cache tuple
+        self.arrays = [len(layer) for layer in self.shapes]
+        self.pools = [tuple(jnp.zeros(a, dtype) for a in layer)
+                      for layer in self.shapes]
         self.draft_pools = None
         self.draft_dtype = None
         if draft is not None:
             dmodel, self.draft_dtype = draft
             self.draft_pools = [
-                (jnp.zeros(a, self.draft_dtype),
-                 jnp.zeros(b, self.draft_dtype))
-                for a, b in pool_shapes(dmodel, self.P, self.page)]
+                tuple(jnp.zeros(a, self.draft_dtype) for a in layer)
+                for layer in pool_shapes(dmodel, self.P, self.page)]
         # device counters a model keeps beside its pools (the routing
         # counters of an expert model): one small int32 array per
         # layer, donated to the decode program with the caches
@@ -231,9 +233,9 @@ class PagedKVCache:
 
     def pool_bytes(self, window: Optional[bool] = None) -> int:
         """Bytes of the pooled arrays: every layer's, or one class's."""
-        return sum(_ml.shard_bytes(a) + _ml.shard_bytes(b)
-                   for (a, b), w in zip(self.pools, self.window_layers)
-                   if window is None or w == window)
+        return sum(_ml.shard_bytes(a)
+                   for layer, w in zip(self.pools, self.window_layers)
+                   if window is None or w == window for a in layer)
 
     def release(self) -> None:
         """Give the device arrays back; the cache serves nothing after."""
@@ -321,17 +323,18 @@ class PagedKVCache:
 
     def bind(self, rows: np.ndarray, draft: bool = False,
              wrows: Optional[np.ndarray] = None) -> List[tuple]:
-        """The per-layer ``(a, b, table)`` tuples a compiled program
-        takes whole: ``rows`` for a full layer, ``wrows`` for a window
-        layer. One table upload per layer: the cache pytree is
+        """The per-layer ``(a, b[, c], table)`` tuples a compiled
+        program takes whole: ``rows`` for a full layer, ``wrows`` for a
+        window layer. One table upload per layer: the cache pytree is
         DONATED to the program, and XLA rejects donating one buffer
         twice. A program that runs every round takes ``lend()`` and ONE
         table of its own instead (``with_table``)."""
         if not self.window:
-            return [(a, b, jnp.asarray(rows))
-                    for a, b in (self.draft_pools if draft else self.pools)]
-        return [(a, b, jnp.asarray(wrows if w else rows))
-                for (a, b), w in zip(self.pools, self.window_layers)]
+            return [layer + (jnp.asarray(rows),)
+                    for layer in (self.draft_pools if draft
+                                  else self.pools)]
+        return [layer + (jnp.asarray(wrows if w else rows),)
+                for layer, w in zip(self.pools, self.window_layers)]
 
     def layer_tables(self, table, wtable):
         """Inside a traced program: per layer, the table of its class."""
@@ -339,7 +342,8 @@ class PagedKVCache:
 
     def commit(self, caches: List[tuple], draft: bool = False) -> None:
         """Take back what ``bind`` lent, as the program returned it."""
-        pools = [(c[0], c[1]) for c in caches]
+        pools = [tuple(c[:len(have)]) for c, have in zip(
+            caches, self.draft_pools if draft else self.pools)]
         if draft:
             self.draft_pools = pools
         else:
@@ -347,17 +351,18 @@ class PagedKVCache:
 
     def lend(self) -> List[tuple]:
         """What a program that brings its own table is given to donate:
-        per layer ``(a, b)``, ``+ (counter,)`` for a model that keeps
-        device counters beside its pools. No table, so no upload."""
+        per layer its pooled arrays, ``+ (counter,)`` for a model that
+        keeps device counters beside its pools. No table, so no
+        upload."""
         if self.counters is None:
             return list(self.pools)
         return [p + (n,) for p, n in zip(self.pools, self.counters)]
 
     def take_back(self, state: List[tuple]) -> None:
         """Take back what ``lend`` lent, as the program returned it."""
-        self.pools = [(s[0], s[1]) for s in state]
+        self.pools = [tuple(s[:n]) for s, n in zip(state, self.arrays)]
         if self.counters is not None:
-            self.counters = [s[2] for s in state]
+            self.counters = [s[n] for s, n in zip(state, self.arrays)]
 
     # -- page accounting (ref-counted pool + prefix cache) ---------------
     def available(self) -> int:
@@ -754,25 +759,26 @@ class PagedKVCache:
 
     # -- a row's export / import (disaggregated serving) -----------------
     def check_stackable(self) -> None:
-        """A page migrates as ONE stacked array of every layer's two
-        pooled arrays; pools of two shapes cannot be stacked."""
+        """A page migrates as ONE stacked array of every layer's pooled
+        arrays; pools of several shapes cannot be stacked."""
         self.refuse_windowed(True, "the migration of a row's pages "
                              "(export / import, the disaggregated phases)")
-        a, b = self.shapes[0]
-        enforce(all(x == y for x, y in self.shapes),
+        shapes = sorted({a[1:] for layer in self.shapes for a in layer})
+        enforce(len(shapes) == 1,
                 "the disaggregated phases migrate a page as ONE stacked "
-                "array of every layer's two pooled arrays; this model "
-                "pools two arrays of different shapes "
-                f"({a[1:]} and {b[1:]}: a latent "
-                "cache), so run it on unified replicas (phase=None)")
+                "array of every layer's pooled arrays; this model pools "
+                f"{max(self.arrays)} arrays a layer, of the shapes "
+                + " and ".join(map(str, shapes)) + " (a latent cache, or "
+                "a layer's index keys beside its K and V), so run it on "
+                "unified replicas (phase=None)")
 
     def export_row(self, b: int, pages: List[int]
                    ) -> Tuple[List[np.ndarray], np.ndarray]:
         """The payloads of ``pages`` (read through the compiled
         page-read program — traced src index, so exports never
         recompile) and a copy of table row b. Each payload is one
-        ``[2*layers, heads, page, width]`` array (the two pooled arrays
-        interleaved per layer)."""
+        ``[arrays, heads, page, width]`` array (every layer's pooled
+        arrays in order, layer by layer)."""
         self.check_stackable()
         payloads = [np.stack([a for kv in
                               self.read_page(pg)["target"]
@@ -788,28 +794,35 @@ class PagedKVCache:
         ``available``."""
         self.check_stackable()
         pages = self.allocate(n_pages)
-        nl = len(self.pools)
+        cuts = np.cumsum(self.arrays)[:-1]
         for pg, arr in zip(pages, payloads):
-            self.write_page(pg, {"target": [(arr[2 * l], arr[2 * l + 1])
-                                            for l in range(nl)]})
+            self.write_page(pg, {"target": [
+                tuple(layer) for layer in np.split(arr, cuts)]})
         self.set_row(b, pages)
         return pages
 
 
-def with_table(state: List[tuple], table) -> List[tuple]:
-    """Inside a traced program: the per-layer ``(a, b, table[,
+def with_table(state: List[tuple], table,
+               arrays: Optional[List[int]] = None) -> List[tuple]:
+    """Inside a traced program: the per-layer ``(a, b[, c], table[,
     counter])`` tuples ``model.forward`` takes, put together from what
     ``lend`` lent and the ONE table every layer reads (or a list, the
-    table of each layer's class: ``PagedKVCache.layer_tables``)."""
-    if isinstance(table, list):
-        return [(s[0], s[1], t) + tuple(s[2:])
-                for s, t in zip(state, table)]
-    return [(s[0], s[1], table) + tuple(s[2:]) for s in state]
+    table of each layer's class: ``PagedKVCache.layer_tables``).
+    ``arrays``: how many arrays each layer pools
+    (``PagedKVCache.arrays``; two each where not given): the table
+    follows them."""
+    arrays = arrays or [2] * len(state)
+    tables = table if isinstance(table, list) else [table] * len(state)
+    return [tuple(s[:n]) + (t,) + tuple(s[n:])
+            for s, t, n in zip(state, tables, arrays)]
 
 
-def without_table(caches: List[tuple]) -> List[tuple]:
+def without_table(caches: List[tuple],
+                  arrays: Optional[List[int]] = None) -> List[tuple]:
     """The inverse: what the program hands back for ``take_back``."""
-    return [(c[0], c[1]) + tuple(c[3:]) for c in caches]
+    arrays = arrays or [2] * len(caches)
+    return [tuple(c[:n]) + tuple(c[n + 1:])
+            for c, n in zip(caches, arrays)]
 
 
 def _page_programs():

@@ -275,8 +275,8 @@ class ServingEngine:
     reads it): ``P``, ``slots`` (``pos``, ``req.new_tokens``, ``state``),
     ``queue``, ``finished``, ``num_active``, ``stats``, ``submit``,
     ``step``, ``run``, ``request_traces()``, ``program_sites()``,
-    ``lowered_text(site)``, ``moe_stats()``, ``overlap_stats()``,
-    ``release_pools()``, and
+    ``lowered_text(site)``, ``moe_stats()``, ``selection_stats()``,
+    ``overlap_stats()``, ``release_pools()``, and
     ``pools``, which may be ASSIGNED ``None`` to free the device arrays.
     """
 
@@ -345,6 +345,29 @@ class ServingEngine:
                     "serve a model with window layers: a hit at position "
                     "p would need the window layers' keys before p, and "
                     f"the donor's ring holds {ring}")
+        # a model whose attention selects keys by a learned index
+        # (model.key_selection: the keys a query keeps) scores a row's
+        # index keys through the block table in its decode step alone
+        self._key_selection = getattr(predictor._model, "key_selection",
+                                      None)
+        if self._key_selection:
+            why = ("a model whose attention selects keys by a learned "
+                   f"index (the {self._key_selection} of largest index "
+                   "score a query): its forward takes no `valid`, so a "
+                   "row is fed whole (bucketed prefill) or one position "
+                   "at a time, and a chunk's rows would have to score "
+                   "and select over the index keys that earlier chunks "
+                   "wrote through the block table")
+            enforce(not self.chunked,
+                    "prefill_chunk (the unified ragged step) cannot "
+                    f"serve {why}; serve it in the default mode")
+            enforce(draft_predictor is None and not spec_tokens,
+                    f"speculative decoding cannot serve {why}, and the "
+                    "verify step rides the unified ragged step")
+            enforce(not prefix_cache and not host_spill_pages,
+                    "the prefix cache (and its host spill tier) cannot "
+                    f"serve {why}, and a prefix hit feeds the rest of "
+                    "the prompt as a chunk")
         if self.chunked:
             enforce(int(prefill_chunk) >= 1, "prefill_chunk must be >= 1")
             self.Sc = min(_bucket(int(prefill_chunk), lo=self.page),
@@ -884,6 +907,10 @@ class ServingEngine:
             if tr is not None:
                 held = {"full_pages": len(slot.pages),
                         "window_pages": self.cache.ring}
+                if self._key_selection:
+                    # its index keys are pooled with K and V, a page
+                    # each of the row's full-class pages
+                    held["index_pages"] = len(slot.pages)
                 tr.add("prefill", t0, now, {"seq_bucket": Sb, **held})
                 m["stage_seconds"].observe(now - t0, stage="prefill")
                 tr.begin("decode", now, held)    # closed at eviction
@@ -945,10 +972,10 @@ class ServingEngine:
                 return (nxt, caches, pos + 1, rng), nxt
 
             (tok, caches, _, rng), toks = lax.scan(
-                body, (tok0, with_table(state, table), pos0, rng), None,
-                length=chunk)
+                body, (tok0, with_table(state, table, cache.arrays), pos0,
+                       rng), None, length=chunk)
             return (jnp.swapaxes(toks, 0, 1), tok,     # [B, chunk], [B]
-                    without_table(caches), rng)
+                    without_table(caches, cache.arrays), rng)
 
         self._step_fns[key] = jax.jit(step, donate_argnums=(1,))
         return self._step_fns[key]
@@ -1539,6 +1566,10 @@ class ServingEngine:
                     (sum(len(s.pages) for s in live) * cache.page_bytes
                      + len(live) * cache.ring * cache.window_page_bytes)
                     / sum(ctx))
+                if self._key_selection:
+                    m["sparse_selected_share"].set(
+                        sum(min(c, self._key_selection) for c in ctx)
+                        / sum(ctx))
                 if cache.window:
                     m["window_ring_fill"].set(
                         sum(min(cache.pages_for(c), cache.ring)
@@ -1916,6 +1947,24 @@ class ServingEngine:
         self._drain()
         self.cache.release()
 
+    def _device_counters(self) -> np.ndarray:
+        self._drain()
+        return np.stack([np.asarray(a) for a in self.cache.counters]
+                        ).astype(np.int64)
+
+    def selection_stats(self) -> Optional[Dict[str, int]]:
+        """What the DECODE steps of a model that selects keys counted on
+        the device beside the routing counters, fetched now: ``rows`` =
+        (row, layer) pairs that selected (every row of the decode batch,
+        live or not, in every step since the engine was built) and
+        ``kept_keys_wrong`` = those whose kept count was not ``min(t +
+        1, topk)``. None for a model that selects nothing."""
+        if not self._key_selection or self.cache.counters is None:
+            return None
+        c = self._device_counters()
+        return {"kept_keys_wrong": int(c[:, -2].sum()),
+                "rows": int(c[:, -1].sum())}
+
     def moe_stats(self) -> Optional[Dict[str, Any]]:
         """Routing counters of an expert model's decode steps, fetched
         from the device now (the only time the host reads them; no step
@@ -1934,9 +1983,9 @@ class ServingEngine:
         has not traced. None for a model without routed experts."""
         if self.cache.counters is None:
             return None
-        self._drain()
-        c = np.stack([np.asarray(a) for a in self.cache.counters]
-                     ).astype(np.int64)
+        c = self._device_counters()
+        if self._key_selection:         # selection_stats()' two slots
+            c = c[:, :-2]
         k = int(getattr(self.pred._model.config, "num_experts_per_tok", 0))
         return {"pairs": c[:, :-3], "absent_pairs": c[:, -3],
                 "summed_pairs": c[:, -2], "tokens": c[:, -1],

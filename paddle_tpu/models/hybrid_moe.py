@@ -1,8 +1,10 @@
 """A decoder whose layers differ by KIND, for serving.
 
-Attention is ``"full"`` (every earlier position) or ``"window"`` (the
-last ``sliding_window`` positions, the current one included), each kind
-with its own number of KV heads and rotary base; keys and queries are
+Attention is ``"full"`` (every earlier position), ``"window"`` (the
+last ``sliding_window`` positions, the current one included) or
+``"sparse"`` (below: a full-class layer that attends to the keys a
+learned index chooses), each kind with its own number of KV heads and
+rotary base (a sparse layer takes the full kind's); keys and queries are
 ``qk_head_dim`` wide against ``v_head_dim``-wide values, rotary on the
 first ``rotary_dim`` dims of a head of the kinds in ``rotary_kinds`` (a
 kind left out turns nothing: its scores carry no position), the values
@@ -18,8 +20,10 @@ Switches, all data of ``HybridMoEConfig`` and all off by default:
 ``attention_gate`` (the attention result times ``sigmoid(u W_g)``, as
 wide as the result, before ``o_proj``), ``sandwich_norm`` (a norm on
 each branch's OUTPUT as well as on its input: four a layer),
-``embedding_multiplier`` (the embedding's rows scaled once, at entry)
-and ``head_on_last_row`` (below). With the defaults this is the block
+``embedding_multiplier`` (the embedding's rows scaled once, at entry),
+``router_score_func`` (``"softmax"``: the router scores by a softmax
+over all experts and has no selection bias) and ``head_on_last_row``
+(below). With the defaults this is the block
 of the MiMo-V2 line (``model_type`` ``mimo_v2_flash``); with window-only
 rotary, q/k norms, the gate, sandwich norms, a multiplier of
 ``sqrt(hidden_size)`` and a shared expert it is the block of the AFMoE
@@ -35,6 +39,17 @@ a ring of pages a row instead of the whole context, and its table is
 that ring. The forward takes no ``valid``: the unified ragged step
 (chunked prefill, and with it the prefix cache, host spill and
 speculative decoding) is refused by the engine at construction.
+
+A SPARSE LAYER (``ops/sparse_attention.py`` has the equations) projects,
+beside q, k and v, ``index_heads`` index queries of ``index_head_dim``,
+ONE index key a position (a LayerNorm, then rotary over the whole index
+head at the layer's base) and a weight an index head; a query attends to
+the ``index_topk`` positions of largest index score alone, exactly, and
+to every earlier one while there are no more than that. Its index keys
+are a THIRD pooled array of the layer (``[P, 1, page,
+index_cache_width]``) under the same table and page class as K and V:
+its cache tuple is ``(k_pool, v_pool, index_pool, table[, counts])``.
+``collect_selection()`` hands a check the kept sets of a forward.
 
 THE HEAD ON THE LAST ROW. A prefill needs one row of logits a prompt.
 With ``head_on_last_row`` the model says so (``model.head_on_last_row``)
@@ -56,14 +71,18 @@ Attention forms, chosen at trace time:
 - decode (one new position a row, paged): the row is written at
   ``(pos // page) % ring`` of a window layer's ring, then
   ``paged_decode_attention`` (``window=`` on a window layer) on TPU, its
-  dense twin elsewhere;
+  dense twin elsewhere; a sparse layer first scores the row's index
+  pages and selects, then the same kernel walks every page of the row
+  with the kept positions as a mask (``keep=``);
 - the static caches of ``Predictor.generate``: the dense function.
 
 Inference only: parameters are plain arrays, nothing records a tape.
 """
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
@@ -81,19 +100,40 @@ from ..nn.layer import Layer
 from ..observability import annotate as _annotate
 from ..ops.blockwise_attention import blockwise_causal_attention
 from ..ops.pallas import decode_attention as _da
+from ..ops.sparse_attention import (index_scores, keep_topk,
+                                    sparse_causal_attention)
 from ..tensor import Tensor
 from .llama import _apply_rope, _dispatch_kernel
 from .mla_moe import DenseSwiGLU, _attr, _mm, _rms
 
 __all__ = ["HybridMoEConfig", "HybridMoEForCausalLM", "hybrid_moe_tiny",
-           "afmoe_tiny"]
+           "afmoe_tiny", "sparse_moe_tiny", "collect_selection"]
+
+# the attention kinds a layer may name
+_KINDS = ("full", "window", "sparse")
+_selection = threading.local()
+
+
+@contextlib.contextmanager
+def collect_selection():
+    """While open on this thread, every sparse layer's PREFILL-form
+    forward appends its kept set, ``[B, S, S]`` bool (row t, key s), to
+    the list this yields, in layer order: a check's reading of what the
+    program's model selected (an S-squared array a layer: not for a
+    timed program)."""
+    _selection.kept = kept = []
+    try:
+        yield kept
+    finally:
+        _selection.kept = None
 
 
 @dataclass
 class HybridMoEConfig:
     vocab_size: int = 32000
     hidden_size: int = 4096
-    # one entry a layer: "full" | "window", and "dense" | "experts"
+    # one entry a layer: "full" | "window" | "sparse", and
+    # "dense" | "experts"
     attention_kinds: List[str] = field(default_factory=lambda: [
         "full", "window", "window", "window", "window", "full", "window"])
     ffn_kinds: List[str] = field(default_factory=lambda: [
@@ -129,36 +169,49 @@ class HybridMoEConfig:
     embedding_multiplier: float = 1.0
     num_shared_experts: int = 0
     head_on_last_row: bool = False
+    router_score_func: str = "sigmoid"       # | "softmax" (no bias)
+    # -- a "sparse" layer's index (module docstring); unused otherwise ----
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     dtype: str = "float32"
 
     def __post_init__(self):
         if self.num_local_experts is None:
             self.num_local_experts = self.num_experts
         enforce(len(self.attention_kinds) == len(self.ffn_kinds)
-                and set(self.attention_kinds) <= {"full", "window"}
+                and set(self.attention_kinds) <= set(_KINDS)
                 and set(self.ffn_kinds) <= {"dense", "experts"},
-                "attention_kinds (full | window) and ffn_kinds (dense | "
-                "experts) name every layer once")
+                "attention_kinds (full | window | sparse) and ffn_kinds "
+                "(dense | experts) name every layer once")
         enforce(self.rotary_dim % 2 == 0
                 and self.rotary_dim <= self.qk_head_dim,
                 "rotary_dim is an even number of a head's leading dims")
         self.rotary_kinds = tuple(self.rotary_kinds)
-        enforce(set(self.rotary_kinds) <= {"full", "window"},
-                "rotary_kinds names attention kinds (full | window)")
+        enforce(set(self.rotary_kinds) <= set(_KINDS),
+                "rotary_kinds names attention kinds (full | window | "
+                "sparse)")
+        if "sparse" in self.attention_kinds:
+            enforce(self.index_heads >= 1 and self.index_topk >= 1
+                    and self.index_head_dim >= 2
+                    and self.index_head_dim % 2 == 0,
+                    "a sparse layer needs index_heads, an even "
+                    "index_head_dim and index_topk")
 
     @property
     def num_layers(self) -> int:
         return len(self.attention_kinds)
 
     def kv_heads(self, kind: str) -> int:
-        return self.num_kv_heads if kind == "full" \
-            else self.window_num_kv_heads
+        return self.window_num_kv_heads if kind == "window" \
+            else self.num_kv_heads
 
     def theta(self, kind: str) -> float:
-        return self.rope_theta if kind == "full" else self.window_rope_theta
+        return self.window_rope_theta if kind == "window" \
+            else self.rope_theta
 
     def sink(self, kind: str) -> bool:
-        return self.full_sink if kind == "full" else self.window_sink
+        return self.window_sink if kind == "window" else self.full_sink
 
     @property
     def k_cache_width(self) -> int:
@@ -169,6 +222,12 @@ class HybridMoEConfig:
         either side of a kernel call (``MLAMoEConfig.rope_cache_width``
         met it at 64)."""
         return -(-self.qk_head_dim // 128) * 128
+
+    @property
+    def index_cache_width(self) -> int:
+        """Columns of a sparse layer's pooled index key: whole lanes,
+        as ``k_cache_width``."""
+        return -(-self.index_head_dim // 128) * 128
 
     @property
     def softmax_scale(self) -> float:
@@ -184,9 +243,10 @@ def _rope_tables(dim: int, theta: float, max_len: int):
 
 
 class HybridAttention(Layer):
-    def __init__(self, cfg: HybridMoEConfig, kind: str):
+    def __init__(self, cfg: HybridMoEConfig, kind: str,
+                 scope: str = "attn"):
         super().__init__()
-        self.cfg, self.kind = cfg, kind
+        self.cfg, self.kind, self.scope = cfg, kind, scope
         h, H, KV = cfg.hidden_size, cfg.num_heads, cfg.kv_heads(kind)
         std = cfg.initializer_range
         self.kv = KV
@@ -216,6 +276,22 @@ class HybridAttention(Layer):
         if self.rotates:
             self._rope = _rope_tables(cfg.rotary_dim, cfg.theta(kind),
                                       cfg.max_position_embeddings)
+        self.sparse = kind == "sparse"
+        self.n_pools = 3 if self.sparse else 2   # arrays it pools
+        if self.sparse:
+            Hi, di = cfg.index_heads, cfg.index_head_dim
+            self.index_q_proj = self.create_parameter((h, Hi * di),
+                                                      attr=_attr(std))
+            self.index_k_proj = self.create_parameter((h, di),
+                                                      attr=_attr(std))
+            self.index_w_proj = self.create_parameter((h, Hi),
+                                                      attr=_attr(std))
+            self.index_k_norm = self.create_parameter(
+                (di,), attr=ParamAttr(initializer=I.Constant(1.0)))
+            self.index_k_norm_bias = self.create_parameter(
+                (di,), attr=ParamAttr(initializer=I.Constant(0.0)))
+            self._index_rope = _rope_tables(di, cfg.theta(kind),
+                                            cfg.max_position_embeddings)
 
     def _heads(self, x, proj, heads, norm, offset):
         """One of q / k: the projection split into ``heads``, each head
@@ -233,9 +309,54 @@ class HybridAttention(Layer):
         return jnp.concatenate(
             [_apply_rope(x[..., :r], cos, sin, offset), x[..., r:]], axis=-1)
 
+    def _index(self, x, offset):
+        """A sparse layer's index of the new positions: queries
+        [B, S, Hi, di] and the ONE key [B, S, 1, di], both turned over
+        the whole index head, and the heads' weights [B, S, Hi] float32
+        (scaled by ``Hi ** -0.5 * di ** -0.5``)."""
+        cfg = self.cfg
+        B, S = x.shape[0], x.shape[1]
+        Hi, di = cfg.index_heads, cfg.index_head_dim
+        cos, sin = self._index_rope
+        iq = _mm(x, self.index_q_proj._value).reshape(B, S, Hi, di)
+        ik = _mm(x, self.index_k_proj._value).astype(jnp.float32)
+        ik = ik - ik.mean(-1, keepdims=True)            # LayerNorm
+        ik = ik * lax.rsqrt((ik * ik).mean(-1, keepdims=True)
+                            + cfg.rms_norm_eps)
+        ik = (ik * self.index_k_norm._value.astype(jnp.float32)
+              + self.index_k_norm_bias._value.astype(jnp.float32)
+              ).astype(x.dtype).reshape(B, S, 1, di)
+        iw = _mm(x, self.index_w_proj._value).astype(jnp.float32) \
+            * (Hi ** -0.5 * di ** -0.5)
+        return (_apply_rope(iq, cos, sin, offset),
+                _apply_rope(ik, cos, sin, offset), iw)
+
+    def _count_kept(self, keep, off, counts):
+        """The step's device counter with this call's rows added to its
+        last two slots: the rows whose kept count is not ``min(t + 1,
+        index_topk)``, and the rows (``keep`` [B, S, M] at positions
+        ``off[b]..``)."""
+        t = off[:, None] + jnp.arange(keep.shape[1], dtype=jnp.int32)[None]
+        wrong = keep.sum(-1, dtype=jnp.int32) != jnp.minimum(
+            t + 1, self.cfg.index_topk)
+        return counts.at[-2:].add(jnp.stack(
+            [wrong.sum(dtype=jnp.int32), jnp.int32(wrong.size)]))
+
+    def _select(self, iq, iw, index_keys, off):
+        """The kept positions [B, S, M] of S new rows at ``off[b]..``
+        against the index keys [B, M, di] of positions 0..M-1."""
+        S, M = iq.shape[1], index_keys.shape[1]
+        with _annotate(f"{self.scope}.index"):
+            sc = index_scores(iq, index_keys, iw)
+        with _annotate(f"{self.scope}.select"):
+            qpos = off[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+            seen = jnp.arange(M, dtype=jnp.int32)[None, None] \
+                <= qpos[:, :, None]
+            return keep_topk(sc, seen, self.cfg.index_topk)
+
     def forward(self, x, cache=None, offset=0):
         """x: values [B, S, hidden]. Returns (values [B, S, hidden],
-        the cache tuple with its two arrays updated)."""
+        the cache tuple with its pooled arrays updated)."""
         cfg = self.cfg
         B, S = x.shape[0], x.shape[1]
         H, KV, dk, dv = cfg.num_heads, self.kv, cfg.qk_head_dim, \
@@ -250,33 +371,88 @@ class HybridAttention(Layer):
             x.dtype).reshape(B, S, KV, dv)
         lanes = ((0, 0),) * 3 + ((0, cfg.k_cache_width - dk),)
         prefill = cache is None or _da._concrete_zero(offset)
+        n = self.n_pools
+        more = ()
+        if self.sparse:
+            with _annotate(f"{self.scope}.index"):
+                iq, ik, iw = self._index(x, offset)
+            if cache is not None:       # the index key joins K and V
+                more = ((cache[2], jnp.pad(ik, ((0, 0),) * 3 + ((
+                    0, cfg.index_cache_width - ik.shape[-1]),))),)
 
-        paged = cache is not None and len(cache) >= 3
+        paged = cache is not None and len(cache) > n
         if paged:
-            k_pool, v_pool, table = cache[:3]
+            table = cache[n]
             # a window layer's decode table is its ring; its prefill
             # table is logical (see the module docstring)
-            k_pool, v_pool = _da.paged_kv_write(
-                k_pool, v_pool, jnp.pad(k, lanes), v, table, offset,
-                ring=window is not None and not prefill)
-            new_cache = (k_pool, v_pool, table) + tuple(cache[3:])
+            pools = _da.paged_kv_write(
+                cache[0], cache[1], jnp.pad(k, lanes), v, table, offset,
+                ring=window is not None and not prefill, more=more)
+            new_cache = pools + tuple(cache[n:])
         elif cache is not None:         # static [B, KV, M, d] caches
             off = jnp.broadcast_to(
                 jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
             dus = lambda buf, new, o: lax.dynamic_update_slice_in_dim(
                 buf, new, o, axis=1)
-            k_pool = jax.vmap(dus)(cache[0], jnp.swapaxes(
-                jnp.pad(k, lanes), 1, 2).astype(cache[0].dtype), off)
-            v_pool = jax.vmap(dus)(cache[1], jnp.swapaxes(
-                v, 1, 2).astype(cache[1].dtype), off)
-            new_cache = (k_pool, v_pool)
+            pools = tuple(
+                jax.vmap(dus)(buf, jnp.swapaxes(new, 1, 2).astype(
+                    buf.dtype), off)
+                for buf, new in ((cache[0], jnp.pad(k, lanes)),
+                                 (cache[1], v)) + more)
+            new_cache = pools
         else:
-            new_cache = None
+            new_cache = pools = None
+        if pools is not None:
+            k_pool, v_pool = pools[:2]
 
-        if prefill:
+        if prefill and self.sparse:
+            kept = getattr(_selection, "kept", None)
+            o = sparse_causal_attention(
+                q, k, v, iq, ik[:, :, 0], iw, scale, cfg.index_topk,
+                cfg.attention_block,
+                scopes=tuple(f"{self.scope}.{part}" for part in
+                             ("index", "select", "attend")),
+                want_mask=kept is not None)
+            if kept is not None:
+                o, mask = o
+                kept.append(mask)
+        elif prefill:
             with _annotate("blockwise_attention"):
                 o = blockwise_causal_attention(
                     q, k, v, scale, window, sinks, cfg.attention_block)
+        elif self.sparse:
+            off = jnp.broadcast_to(
+                jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
+            qp = jnp.pad(q, lanes)
+            di = cfg.index_head_dim
+            if paged:
+                with _annotate(f"{self.scope}.index"):
+                    keys = _da.gather_pages(pools[2], table)[:, 0, :, :di]
+                keep = self._select(iq, iw, keys, off)
+                if len(cache) == n + 2:     # the step's device counter
+                    with _annotate(f"{self.scope}.select"):
+                        new_cache = new_cache[:-1] + (self._count_kept(
+                            keep, off, new_cache[-1]),)
+                with _annotate(f"{self.scope}.attend"):
+                    o = _dispatch_kernel(
+                        "paged_sparse_decode_attention",
+                        lambda: S == 1 and _da.paged_supported(
+                            qp.shape, k_pool.shape, v_pool.shape),
+                        lambda: _da.paged_decode_attention(
+                            qp, k_pool, v_pool, table, off, scale=scale,
+                            sinks=sinks, keep=keep[:, 0]),
+                        lambda: _da.paged_attention_dense(
+                            qp, k_pool, v_pool, table, off, scale, sinks,
+                            None, keep))
+            else:
+                keep = self._select(iq, iw, pools[2][:, 0, :, :di], off)
+                M = k_pool.shape[2]
+                pos = jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32),
+                                       (B, M))
+                with _annotate(f"{self.scope}.attend"):
+                    o = _da.attention_dense_masked(
+                        qp, k_pool, v_pool, pos, off, scale, sinks, None,
+                        keep)
         else:
             off = jnp.broadcast_to(
                 jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
@@ -314,7 +490,8 @@ class HybridMoEDecoderLayer(Layer):
         ones = ParamAttr(initializer=I.Constant(1.0))
         self.input_layernorm = self.create_parameter((cfg.hidden_size,),
                                                      attr=ones)
-        self.self_attn = HybridAttention(cfg, self.attn_kind)
+        self.self_attn = HybridAttention(
+            cfg, self.attn_kind, f"layer{index}.attn.{self.attn_kind}")
         self.post_attention_layernorm = self.create_parameter(
             (cfg.hidden_size,), attr=ones)      # the feed-forward's INPUT
         if cfg.sandwich_norm:                   # and each branch's output
@@ -332,7 +509,8 @@ class HybridMoEDecoderLayer(Layer):
                 routed_scaling_factor=cfg.routed_scaling_factor,
                 num_shared_experts=cfg.num_shared_experts,
                 weight_attr=_attr(std),
-                down_attr=_attr(std / math.sqrt(2 * cfg.num_layers)))
+                down_attr=_attr(std / math.sqrt(2 * cfg.num_layers)),
+                score_func=cfg.router_score_func)
         else:
             self.mlp = DenseSwiGLU(cfg)
 
@@ -350,9 +528,16 @@ class HybridMoEDecoderLayer(Layer):
             h = _rms(x, self.post_attention_layernorm._value, eps)
             if not self.is_moe:
                 y = self.mlp(h)
-            elif cache is not None and len(cache) == 4:   # routing counter
-                y, counts = self.mlp(h, counts=cache[3])
-                y, cache = y._value, cache[:3] + (counts,)
+            elif cache is not None and \
+                    len(cache) == self.self_attn.n_pools + 2:
+                # the routing counter follows the pools and their table;
+                # a selecting model's two slots follow it
+                counts, m = cache[-1], self.cfg.num_local_experts + 3
+                more = counts.shape[0] > m
+                y, moe = self.mlp(h, counts=counts[:m] if more else counts)
+                if more:
+                    moe = jnp.concatenate([moe, counts[m:]])
+                y, cache = y._value, cache[:-1] + (moe,)
             else:
                 y = self.mlp(h)._value
             if sandwich:
@@ -384,29 +569,44 @@ class HybridMoEForCausalLM(Layer):
     # -- what the serving engine asks of a model -------------------------
     def kv_pool_shapes(self, P: int, page: int):
         """Per layer, the shapes of the pooled K and V: the layer's own
-        KV heads, K at ``k_cache_width``."""
+        KV heads, K at ``k_cache_width``; a sparse layer pools a third
+        array, its one index key a position."""
         cfg = self.config
         return [((P, cfg.kv_heads(kind), page, cfg.k_cache_width),
                  (P, cfg.kv_heads(kind), page, cfg.v_head_dim))
+                + (((P, 1, page, cfg.index_cache_width),)
+                   if kind == "sparse" else ())
                 for kind in cfg.attention_kinds]
 
     def kv_page_classes(self):
         """Per layer ``"full"`` (a row holds a page for every page of
-        its context) or ``("window", n)`` (a row holds a ring of pages
-        that covers its last ``n`` positions)."""
+        its context: a full or a sparse layer) or ``("window", n)`` (a
+        row holds a ring of pages that covers its last ``n``
+        positions)."""
         cfg = self.config
-        return ["full" if kind == "full" else ("window", cfg.sliding_window)
-                for kind in cfg.attention_kinds]
+        return [("window", cfg.sliding_window) if kind == "window"
+                else "full" for kind in cfg.attention_kinds]
+
+    @property
+    def key_selection(self) -> Optional[int]:
+        """How many keys a query of a sparse layer keeps; None where no
+        layer selects. The serving engine asks: it states its refusals
+        for such a model and reports the share of keys kept."""
+        return self.config.index_topk \
+            if "sparse" in self.config.attention_kinds else None
 
     def moe_counter_shape(self):
         """[layers, held experts + 3] routing counters (``GatedMoELayer``);
-        rows of dense layers stay 0."""
-        return (self.config.num_layers, self.config.num_local_experts + 3)
+        rows of dense layers stay 0. A model that selects keys has two
+        slots more a layer: the decode step's rows whose kept count was
+        not ``min(t + 1, index_topk)``, and its rows."""
+        return (self.config.num_layers, self.config.num_local_experts + 3
+                + (2 if self.key_selection else 0))
 
     def _empty_caches(self, B: int, max_len: int, dtype):
-        return [(jnp.zeros((B,) + a[1:2] + (max_len,) + a[3:], dtype),
-                 jnp.zeros((B,) + b[1:2] + (max_len,) + b[3:], dtype))
-                for a, b in self.kv_pool_shapes(1, 1)]
+        return [tuple(jnp.zeros((B,) + a[1:2] + (max_len,) + a[3:], dtype)
+                      for a in layer)
+                for layer in self.kv_pool_shapes(1, 1)]
 
     @property
     def head_on_last_row(self) -> bool:
@@ -463,6 +663,28 @@ def hybrid_moe_tiny(**kw) -> HybridMoEConfig:
                 num_local_experts=4, expert_offset=4,
                 num_experts_per_tok=4, max_position_embeddings=128,
                 attention_block=16)
+    base.update(kw)
+    return HybridMoEConfig(**base)
+
+
+def sparse_moe_tiny(**kw) -> HybridMoEConfig:
+    """CPU-test size of a decoder whose every layer is SPARSE: 2 index
+    heads of 8 that keep 8 keys over pages of 8 (contexts several times
+    that), q/k norms, rotary over the whole head, a softmax router with
+    no bias and no shared expert, held experts a strict share of the
+    router's, the head on a prefill's last row."""
+    base = dict(vocab_size=256, hidden_size=64,
+                attention_kinds=["sparse"] * 3, ffn_kinds=["experts"] * 3,
+                num_heads=8, num_kv_heads=2, window_num_kv_heads=2,
+                qk_head_dim=16, v_head_dim=16, rotary_dim=16,
+                rope_theta=10000.0, rotary_kinds=("sparse",),
+                full_sink=False, window_sink=False, value_scale=1.0,
+                moe_intermediate_size=32, num_experts=16,
+                num_local_experts=4, expert_offset=4,
+                num_experts_per_tok=4, router_score_func="softmax",
+                qk_norm=True, head_on_last_row=True, index_heads=2,
+                index_head_dim=8, index_topk=8,
+                max_position_embeddings=128, attention_block=16)
     base.update(kw)
     return HybridMoEConfig(**base)
 

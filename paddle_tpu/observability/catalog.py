@@ -539,6 +539,13 @@ def serving_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
             "(min(ceil(context / page), ring)) over the ring pages the "
             "row holds, mean, at the last retired decode round",
             unit="ratio"),
+        "sparse_selected_share": r.gauge(
+            "paddle_tpu_serving_sparse_selected_share",
+            "over the live rows of a model whose attention selects keys "
+            "by a learned index, the keys a query keeps "
+            "(min(context, top-k)) over the keys of its context, summed "
+            "over the rows, at the last retired decode round",
+            unit="ratio"),
         "prefill_tokens": r.counter(
             "paddle_tpu_serving_prefill_tokens_total",
             "tokens the prefill programs were given: kind=prompt the "

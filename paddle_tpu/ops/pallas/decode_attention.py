@@ -266,7 +266,7 @@ def _paged_plan(Sq, G, KV, page, D, itemsize, Dv=None) -> PagedPlan:
 
 
 def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
-                  G, hb, nh, depth, window=None, sink=False):
+                  G, hb, nh, depth, window=None, sink=False, kept=False):
     """One grid step a (row, block of ``hb`` KV heads), and inside it a
     loop over the pages the row owns. A VISIT is one page of one grid
     step; the kernel's visits, in grid order, form one sequence, and a
@@ -280,10 +280,14 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
     their own, the table is a RING of ``npages`` columns (logical page
     ``j`` sits in column ``j % npages``) and a step visits only the
     pages that intersect the window. ``sink``: one more input, a logit a
-    query row that takes weight in the softmax and gives no value."""
-    sink_ref = refs[0] if sink else None
+    query row that takes weight in the softmax and gives no value.
+    ``kept``: one more input, the row's KEPT positions ``[npages, page]``
+    (1.0 | 0.0): a key takes part only where it is set as well."""
+    refs = list(refs)
+    sink_ref = refs.pop(0) if sink else None
+    keep_ref = refs.pop(0) if kept else None
     (k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur, m_s, l_s,
-     acc_s) = refs[1:] if sink else refs
+     acc_s) = refs
     t, steps = pl.program_id(0), pl.num_programs(0)
     b = t // nh
     rows = Sq * G
@@ -369,6 +373,8 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
         keep = first <= bd
         if window is not None:  # and no further back than the window
             keep = keep & (first > bd - window)
+        if kept:                # and only what the row's index chose
+            keep = keep & (keep_ref[0, pl.ds(lo + k, 1), :] > 0.5)[None]
         s = jnp.where(keep, s, _NEG)                 # [hb, rows, page]
         m_prev = m_s[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -409,7 +415,7 @@ def paged_supported(q_shape, pool_shape, v_shape=None) -> bool:
 @partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            scale=None, interpret=False, sinks=None,
-                           window=None):
+                           window=None, keep=None):
     """Block-table KV attention (the TPU redesign of the reference's
     paged cache kernel: phi/kernels/fusion/gpu/
     block_multi_head_attention_kernel.cu + block_attn.h — there, CUDA
@@ -437,6 +443,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                                 only the pages that intersect the window
                                 are fetched (kernel name
                                 ``paged_window_decode_attention``)
+    keep         None or [B, npages * page] bool: the positions a row's
+                                learned index KEPT (logical positions,
+                                page-major as the table); a key takes
+                                part only if it is also kept. Every page
+                                up to the frontier is still walked
+                                (kernel name
+                                ``paged_sparse_decode_attention``)
 
     Table entries up to a row's frontier page must name pages of the
     pool; later entries are never read.
@@ -471,6 +484,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
             (nh, hb, Sq, G)).reshape(nh, hb, rows, 1))
         in_specs.append(pl.BlockSpec((1, hb, rows, 1),
                                      lambda t, ln, tb: (t % nh, 0, 0, 0)))
+    if keep is not None:
+        ins.append(keep.astype(jnp.float32).reshape(B, npages, page))
+        in_specs.append(pl.BlockSpec((1, npages, page),
+                                     lambda t, ln, tb: (t // nh, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B * nh,),
@@ -494,14 +511,19 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         kw["window"] = int(window)
     if sinks is not None:
         kw["sink"] = True
+    if keep is not None:
+        kw["kept"] = True
+    name = "paged_decode_attention" if window is None \
+        else "paged_window_decode_attention"
+    if keep is not None:
+        name = "paged_sparse_decode_attention"
     out = pl.pallas_call(
         partial(_paged_kernel, scale=scale, page=page, npages=npages,
                 Sq=Sq, G=G, hb=hb, nh=nh, depth=depth, **kw),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, KV, G, Dv), q.dtype),
         interpret=interpret,
-        name="paged_decode_attention" if window is None
-        else "paged_window_decode_attention",
+        name=name,
         # one sequential axis: a step starts later steps' pages
         **_compiler_params(0, interpret),
     )(lengths, tbl, *ins, k_pool, v_pool)
@@ -519,9 +541,11 @@ def _concrete_zero(offset) -> bool:
 
 
 def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
-                   valid=None, ring=False):
+                   valid=None, ring=False, more=()):
     """Land new K/V rows in their physical pages, touching only the
-    pages written; returns the updated ``(k_pool, v_pool)``.
+    pages written; returns the updated ``(k_pool, v_pool)``. ``more``:
+    further ``(pool, new)`` pairs under the same table (a layer that
+    pools a third array, its index keys); their updated pools follow.
 
     k/v_pool     [P, KV, page, D]  (the layout the kernels above read;
                                    the two may differ in D: a latent
@@ -572,7 +596,7 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
         pids = tbl[:, :n].reshape(B * n)
 
         def put(pool, new):
-            new = new.astype(pool.dtype).reshape(B * n, -1, KV,
+            new = new.astype(pool.dtype).reshape(B * n, -1, pool.shape[1],
                                                  pool.shape[-1])
             new = jnp.swapaxes(new, 1, 2)          # [B*n, KV, rows, D]
             return pool.at[pids, :, :new.shape[2], :].set(new)
@@ -589,44 +613,58 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, block_tables, offset,
             lpage = lpage % npages
         pid = jnp.take_along_axis(
             tbl, jnp.minimum(lpage, npages - 1), axis=1)        # [B,S]
-        heads = jnp.arange(KV, dtype=jnp.int32)
-        rows = (pid[:, :, None] * KV + heads) * page \
-            + (pos % page)[:, :, None]
-        # the flat index of a page id outside the pool would wrap or
-        # land in another page: send it, and positions past the table,
-        # one past the view's end, where the scatter drops it
-        lost = (lpage >= npages) | (pid < 0) | (pid >= P)
-        rows = jnp.where(lost[:, :, None], P * KV * page, rows)
-        rows = rows.reshape(B * S * KV)
+        def flat_rows(KV):
+            """Rows of the flat ``[P*KV*page, D]`` view of a pool of
+            ``KV`` heads."""
+            heads = jnp.arange(KV, dtype=jnp.int32)
+            rows = (pid[:, :, None] * KV + heads) * page \
+                + (pos % page)[:, :, None]
+            # the flat index of a page id outside the pool would wrap
+            # or land in another page: send it, and positions past the
+            # table, one past the view's end, where the scatter drops it
+            lost = (lpage >= npages) | (pid < 0) | (pid >= P)
+            rows = jnp.where(lost[:, :, None], P * KV * page, rows)
+            return rows.reshape(B * S * KV)
+
+        by_heads = {KV: flat_rows(KV)}
 
         def put(pool, new):
-            D = pool.shape[-1]
-            flat = pool.reshape(P * KV * page, D)
-            flat = flat.at[rows].set(
-                new.astype(pool.dtype).reshape(B * S * KV, D), mode="drop")
-            return flat.reshape(P, KV, page, D)
+            KVp, D = pool.shape[1], pool.shape[-1]
+            if KVp not in by_heads:     # a third array of other heads
+                by_heads[KVp] = flat_rows(KVp)
+            flat = pool.reshape(P * KVp * page, D)
+            flat = flat.at[by_heads[KVp]].set(
+                new.astype(pool.dtype).reshape(B * S * KVp, D),
+                mode="drop")
+            return flat.reshape(P, KVp, page, D)
 
-    return put(k_pool, k_new), put(v_pool, v_new)
+    return (put(k_pool, k_new), put(v_pool, v_new)) + tuple(
+        put(pool, new) for pool, new in more)
+
+
+def gather_pages(pool, block_tables):
+    """A row's pages side by side: pool [P, KV, page, D] through
+    block_tables [B, npages] -> [B, KV, npages * page, D]."""
+    B, npages = block_tables.shape
+    g = jnp.swapaxes(pool[block_tables], 1, 2)   # [B, KV, npages, page, D]
+    return g.reshape(B, pool.shape[1], npages * pool.shape[2],
+                     pool.shape[-1])
 
 
 def paged_attention_dense(q, k_pool, v_pool, block_tables, lengths,
-                          scale=None, sinks=None, window=None):
+                          scale=None, sinks=None, window=None, keep=None):
     """XLA reference/fallback: gather the pages into a contiguous view,
     then run the (ragged-aware) dense cache attention. ``scale``,
     ``sinks`` and ``window`` as ``paged_decode_attention``; with a
     window the table is the ring, and a column is masked by the POSITION
     its page holds now (the newest logical page of its ring column that
-    is not past the row's last q position)."""
+    is not past the row's last q position). ``keep`` [B, npages * page]
+    (or [B, Sq, npages * page]): the positions a row's index kept."""
     B, Sq, H, D = q.shape
     page = k_pool.shape[2]
     npages = block_tables.shape[1]
-    # [B, npages, KV, page, D] -> [B, KV, npages*page, D]
-    def gather(pool):
-        g = pool[block_tables]                       # [B, npages, KV, page, D]
-        g = jnp.swapaxes(g, 1, 2)                     # [B, KV, npages, page, D]
-        return g.reshape(B, pool.shape[1], npages * page, pool.shape[-1])
-
-    if scale is None and sinks is None and window is None:
+    gather = partial(gather_pages, block_tables=block_tables)
+    if scale is None and sinks is None and window is None and keep is None:
         return _dense_ragged(q, gather(k_pool), gather(v_pool), lengths)
     off = jnp.asarray(lengths, jnp.int32).reshape(B)
     cols = jnp.arange(npages, dtype=jnp.int32)[None]
@@ -638,16 +676,19 @@ def paged_attention_dense(q, k_pool, v_pool, block_tables, lengths,
     pos = (held[:, :, None] * page + jnp.arange(page, dtype=jnp.int32)
            ).reshape(B, npages * page)
     return attention_dense_masked(q, gather(k_pool), gather(v_pool), pos,
-                                  off, scale, sinks, window)
+                                  off, scale, sinks, window, keep)
 
 
 def attention_dense_masked(q, k_cache, v_cache, pos, lengths, scale=None,
-                           sinks=None, window=None):
+                           sinks=None, window=None, keep=None):
     """Dense cache attention in float32 with everything the paged kernel
     takes: q [B, S, H, D] at positions lengths[b].., caches
     [B, KV, M, D / Dv] whose column m holds position ``pos[b, m]``
     (negative: nothing), a row sees positions <= its own and, with a
-    window, > its own - window; ``sinks [H]`` joins the denominator."""
+    window, > its own - window; ``sinks [H]`` joins the denominator;
+    ``keep`` ([B, M] or [B, S, M] bool, by column): and only the columns
+    it sets."""
+    kept = keep
     B, S, H, D = q.shape
     KV, Dv = k_cache.shape[1], v_cache.shape[-1]
     rep = H // KV
@@ -661,6 +702,8 @@ def attention_dense_masked(q, k_cache, v_cache, pos, lengths, scale=None,
     keep = (pos[:, None, :] >= 0) & (pos[:, None, :] <= q_pos)
     if window is not None:
         keep = keep & (pos[:, None, :] > q_pos - window)
+    if kept is not None:
+        keep = keep & (kept[:, None, :] if kept.ndim == 2 else kept)
     scores = jnp.where(keep[:, None, None], scores, _NEG)
     if sinks is not None:
         sk = jnp.broadcast_to(

@@ -4,7 +4,8 @@ place and with its kernels? (no chip needed)
 Builds the model of ``--config`` (``sarvam-105b``: ``MLAMoEForCausalLM``,
 the latent-attention decoder; ``mimo-v2-flash`` and ``trinity-mini``:
 ``HybridMoEForCausalLM``, window and full layers over two classes of
-pages) at the benchmark
+pages; ``keye-vl-2.0-30b-a3b``: the same model with layers that select
+keys by a learned index and pool a third array) at the benchmark
 configuration's widths (``benchmarks/configs/<config>.json``) with
 ``--layers`` layers (2: the
 dense layer and one expert layer) and NO weights (``LazyGuard``), takes
@@ -14,7 +15,8 @@ topology, as ``tools/paged_write_aot.py`` does for the page-pool write.
 Per program: pool-shaped ``copy`` ops in the optimized HLO (0 = the
 pools of every page class are written in place), which of the decode
 kernels (``mla_paged_decode_attention``, ``paged_decode_attention``,
-``paged_window_decode_attention``) and whether XLA's grouped matmul
+``paged_window_decode_attention``, ``paged_sparse_decode_attention``)
+and whether XLA's grouped matmul
 (``ragged-dot``) are in it (the prefill programs' expert layers sort and
 group; a decode step's are batched over the held experts and hold none),
 copies or transposes of a stacked expert weight array (0: the batched
@@ -86,8 +88,10 @@ def main(argv):
     with paddle.LazyGuard():
         model = getattr(models, type(mcfg).__name__.replace(
             "Config", "ForCausalLM"))(mcfg)
-    pred = create_predictor(Config().set_model(model).enable_paged_kv(
-        page_size=srv["page_size"]))
+    conf = Config().set_model(model).enable_paged_kv(
+        page_size=srv["page_size"])
+    conf.max_length = srv["max_length"]
+    pred = create_predictor(conf)
     eng = ServingEngine(pred, max_batch=args.batch,
                         pool_pages=srv["pool_pages"])
     dev = SingleDeviceSharding(topo.devices[0])
@@ -112,11 +116,13 @@ def main(argv):
         f"prefill_{args.prefill}": (
             pred._prefill_fn(1, args.prefill, eng.M),
             (pvals, i32(1, args.prefill),
-             [(sds(c), sds(r), i32(1, npages)) for c, r in eng.pools],
+             [tuple(map(sds, layer)) + (i32(1, npages),)
+              for layer in eng.pools],
              i32(1))),
     }
     kernels = ("mla_paged_decode_attention", "paged_decode_attention",
-               "paged_window_decode_attention")
+               "paged_window_decode_attention",
+               "paged_sparse_decode_attention")
     pool_shapes = {s.shape for pair in eng.pools for s in pair}
     # the held experts' stacked weights, [El, d, h] and [El, h, d] (and
     # [El * h, d], as the batched down product reads them): an op named
