@@ -1275,15 +1275,22 @@ def phase_serve_sparse(sz: Sizes) -> None:
 
     params = list(model.parameters())
 
+    from paddle_tpu.observability import moestats
+
     def whole(pvals, ids):
         with no_grad(), bind_params(params, pvals), \
                 collect_selection() as sets:
-            logits = model.forward(ids)
-        return logits._value, [k[0].sum(-1) for k in sets]
+            moestats.begin()
+            try:
+                logits = model.forward(ids)
+            finally:
+                recs = moestats.drain()
+        return (logits._value, [k[0].sum(-1) for k in sets],
+                [r["passes"] for r in recs if "passes" in r])
 
     seq = np.concatenate([mix[0], outs[0][:-1]])
-    full, kept = jax.jit(whole)(tuple(p._value for p in params),
-                                jnp.asarray(seq[None, :]))
+    full, kept, passes = jax.jit(whole)(tuple(p._value for p in params),
+                                        jnp.asarray(seq[None, :]))
     full = np.asarray(full[0].astype(jnp.float32))
     want_kept = np.minimum(np.arange(len(seq)) + 1, topk)
     wrong = sum(int((np.asarray(k) != want_kept).sum()) for k in kept)
@@ -1305,6 +1312,18 @@ def phase_serve_sparse(sz: Sizes) -> None:
     check(st["dropped"] == 0 and st["tokens"][-1] > 0,
           f"expert layers dropped {st['dropped']} routed pairs of "
           f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
+    # the sorted rows each prefill bucket's expert layers hold at a time,
+    # and the passes a full forward's layers took over them (a rehearsal's
+    # few tokens take the batched form: no rows, no pass)
+    passes = [int(p) for p in passes]
+    check(all(m <= n for m, n in st["rows"].values())
+          and passes == [1] * len(passes)
+          and (sz.rehearsal or (len(passes) == cfg.num_layers and st["rows"]
+                                and all(m < n
+                                        for m, n in st["rows"].values()))),
+          f"sorted rows a prefill bucket (bound, routed pairs) "
+          f"{st['rows']}; passes of a full forward over {len(seq)} tokens, "
+          f"layer by layer: {passes}")
     c = eng.cache.counts()
     check(c["free"] == eng.cache.usable, f"every page back to free: {c}")
     found = kernel_names(eng.lowered_text(("decode",)))

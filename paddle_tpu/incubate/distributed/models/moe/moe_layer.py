@@ -344,7 +344,7 @@ def swiglu(x, w_gate, w_up, w_down):
 # nothing. 128 is the largest size of the serving lattice (powers of two)
 # under that ridge. Above it the batched form would be compute-bound and
 # do El / (k x held share) times the work: the sorted form, whose work
-# follows the routed pairs, takes over.
+# follows the pairs routed to the held experts, takes over.
 _BATCHED_MAX_TOKENS = 128
 
 
@@ -353,8 +353,25 @@ def routed_form(T: int) -> str:
     return "batched" if T <= _BATCHED_MAX_TOKENS else "sorted"
 
 
+# The sorted form works on M sorted rows at a time, a static bound from the
+# call's shapes: the pairs a uniform router sends to the held experts
+# (T k El / E) with half as many again for a router that is not, rounded up
+# to the rows the grouped product tiles by. What a prompt sends beyond M
+# takes another pass of the same body; nothing is dropped.
+_SORTED_SLACK = 1.5
+_SORTED_TILE = 256
+
+
+def sorted_rows(T: int, k: int, El: int, E: int) -> int:
+    """The sorted rows ``routed_swiglu_sorted`` holds at a time for ``T``
+    tokens of ``k`` choices over ``E`` experts, ``El`` of them held:
+    never above ``T * k``, and ``T * k`` for a layer held whole."""
+    want = math.ceil(T * k * El / E * _SORTED_SLACK)
+    return min(T * k, -(-want // _SORTED_TILE) * _SORTED_TILE)
+
+
 def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
-                  expert_offset: int = 0):
+                  expert_offset: int = 0, num_experts: Optional[int] = None):
     """The held experts' part of a routed SwiGLU layer. No capacity, no
     ``[T, E, C]`` tensor, no dropped pair, in either form
     (``routed_form(T)``: ``routed_swiglu_batched`` for a decode step's
@@ -365,48 +382,107 @@ def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
     weights  [T, k] f32   their weights (normalised over all k chosen)
     w_*      [El, ...]    the experts held here: expert_offset ..
                           expert_offset + El - 1
+    num_experts           the router's width E (None: the held El)
 
     Returns (y [T, d] float32: the sum over chosen AND held experts;
     sizes [El + 2] int32: the pairs of each held expert, the pairs whose
     expert is held elsewhere, and the pairs whose product was computed
     AND summed into y: counted from what was computed and not from the
     router's choice, so a held pair that the products missed shows as
-    missing from the last)."""
-    form = (routed_swiglu_batched if routed_form(x2d.shape[0]) == "batched"
-            else routed_swiglu_sorted)
-    return form(x2d, idx, weights, w_gate, w_up, w_down, expert_offset)
+    missing from the last; trace: what the call's form was as it was
+    traced, ``form`` and, for the sorted one, ``rows`` = (the bound M,
+    T * k) and ``passes``, the windows of M rows it ran: a device
+    value)."""
+    form = routed_form(x2d.shape[0])
+    if form == "batched":
+        y, sizes = routed_swiglu_batched(x2d, idx, weights, w_gate, w_up,
+                                         w_down, expert_offset)
+        return y, sizes, {"form": form}
+    (T, k), El = idx.shape, w_gate.shape[0]
+    E = El if num_experts is None else num_experts
+    y, sizes, passes = routed_swiglu_sorted(
+        x2d, idx, weights, w_gate, w_up, w_down, expert_offset, E)
+    return y, sizes, {"form": form, "passes": passes,
+                      "rows": (sorted_rows(T, k, El, E), T * k)}
+
+
+def _group_sizes(e, El: int):
+    """[El] int32: how many of the keys ``e`` name each held expert."""
+    return (e[:, None] == jnp.arange(El, dtype=e.dtype)).sum(
+        axis=0, dtype=jnp.int32)
+
+
+def _sum_by_token(y, rows, tok):
+    """``y [T, d]`` with the float32 ``rows [M, d]`` added, each to the
+    token ``tok [M]`` it belongs to (a name of its own so that
+    ``tools/routed_swiglu_timing.py`` can time other ways to)."""
+    return y.at[tok].add(rows)
 
 
 def routed_swiglu_sorted(x2d, idx, weights, w_gate, w_up, w_down,
-                         expert_offset: int = 0):
-    """``routed_swiglu`` with work in proportion to the routed pairs: the
-    (token, expert) pairs are sorted by expert and the three products
-    are grouped over the held experts (``lax.ragged_dot``: XLA's grouped
-    matmul on TPU). The computed pairs are counted from the sorted rows
-    that lay inside a group. Pairs of absent experts sort behind every
-    group and are never multiplied, but they are rows of the
-    ``[T*k, d]`` operand all the same."""
+                         expert_offset: int = 0,
+                         num_experts: Optional[int] = None):
+    """``routed_swiglu`` with work in proportion to the HELD pairs. The
+    (token, expert) pairs' int32 keys are sorted by expert, held ones
+    first; a window of ``M = sorted_rows(T, k, El, E)`` sorted rows is
+    gathered (``[M, d]``), goes through the three products grouped over
+    the held experts (``lax.ragged_dot``: XLA's grouped matmul on TPU),
+    is scaled and added to its tokens' rows of ``y``. A prompt that
+    sends more than M pairs to the held experts runs the same body on
+    the next M sorted rows, ``ceil(held / M)`` passes in all (returned
+    third): each pass's groups are the part of every group inside its
+    window. Pairs of absent experts sort behind every group and are rows
+    of nothing: no array has ``T * k`` rows of width d or h unless the
+    layer is held whole. The computed pairs are counted, over the
+    passes, from the sorted rows that lay inside a group."""
     T, k = idx.shape
     El = w_gate.shape[0]
+    pairs = T * k
+    M = sorted_rows(T, k, El, El if num_experts is None else num_experts)
     local = idx - expert_offset
     held = (local >= 0) & (local < El)
-    e = jnp.where(held, local, El).reshape(T * k)
-    order = jnp.argsort(e, stable=True)
-    gs = jnp.bincount(e, length=El).astype(jnp.int32)
-    xs = x2d[order // k]                                   # [T*k, d]
-    g = lax.ragged_dot(xs, w_gate, gs,
-                       preferred_element_type=jnp.float32)
-    u = lax.ragged_dot(xs, w_up, gs, preferred_element_type=jnp.float32)
-    out = lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype), w_down,
-                         gs, preferred_element_type=jnp.float32)
-    # a sorted row counts if its pair is held and a group covered it
-    used = held.reshape(T * k)[order] & (jnp.arange(T * k) < gs.sum())
-    wf = weights.reshape(T * k)[order]
-    out = jnp.where(used[:, None], out * wf[:, None], 0.0)
-    y = out[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
-    sizes = jnp.concatenate([gs, jnp.stack([(~held).sum(), used.sum()])
+    e = jnp.where(held, local, El).reshape(pairs)
+    gs = _group_sizes(e, El)
+    ends = jnp.cumsum(gs)
+    starts = ends - gs
+    # the sort moves 12 bytes a pair: its key, its number, its weight
+    _, order, wf = lax.sort(
+        (e, jnp.arange(pairs, dtype=jnp.int32), weights.reshape(pairs)),
+        num_keys=1, is_stable=True)
+    order, wf = (jnp.pad(a, (0, -pairs % M)) for a in (order, wf))
+    n_held = held.sum()
+    row = jnp.arange(M, dtype=jnp.int32)
+
+    def window(p, carry):
+        y, summed = carry
+        lo = p * M
+        tok = lax.dynamic_slice(order, (lo,), (M,)) // k
+        gw = jnp.clip(ends, lo, lo + M) - jnp.clip(starts, lo, lo + M)
+        xs = x2d[tok]                                          # [M, d]
+        g = lax.ragged_dot(xs, w_gate, gw,
+                           preferred_element_type=jnp.float32)
+        u = lax.ragged_dot(xs, w_up, gw,
+                           preferred_element_type=jnp.float32)
+        out = lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype),
+                             w_down, gw,
+                             preferred_element_type=jnp.float32)
+        # a sorted row counts if its pair is held and a group covered it
+        used = (lo + row < n_held) & (row < gw.sum())
+        scale = lax.dynamic_slice(wf, (lo,), (M,))
+        out = jnp.where(used[:, None], out * scale[:, None], 0.0)
+        return (_sum_by_token(y, out, tok),
+                summed + used.sum(dtype=jnp.int32))
+
+    init = (jnp.zeros((T, w_down.shape[2]), jnp.float32), jnp.int32(0))
+    if M == pairs:          # one window holds every pair: no loop
+        passes = jnp.int32(1)
+        y, summed = window(0, init)
+    else:
+        passes = lax.div(ends[-1] + (M - 1), jnp.int32(M))
+        y, summed = lax.fori_loop(0, passes, window, init)
+    sizes = jnp.concatenate([gs, jnp.stack([(~held).sum(), summed])
                              .astype(jnp.int32)])
-    return y, sizes
+    return y, sizes, passes
 
 
 def _combine(idx, weights, expert_offset: int, El: int):
@@ -460,9 +536,10 @@ class GatedMoELayer(Layer):
     sigmoid with a selection bias or a softmax), and computes its own
     experts' part of the sum (``routed_swiglu``: batched over the held
     experts for a decode step's few tokens, where the weights' bytes set
-    the time whatever is computed; sorted and grouped, with work in
-    proportion to the routed pairs, for a prefill's many). The shared
-    experts run whole on every holder. Inference only (no tape
+    the time whatever is computed; sorted and grouped for a prefill's
+    many, the held pairs alone gathered and multiplied, a bound of
+    ``sorted_rows`` rows at a time that the router's width fixes). The
+    shared experts run whole on every holder. Inference only (no tape
     backward); on one chip there is no exchange, and nothing stands in
     for the absent holders.
 
@@ -470,7 +547,12 @@ class GatedMoELayer(Layer):
     ``[num_local_experts + 3]`` int32 routing counter (``routed_swiglu``'s
     sizes: pairs per held expert, pairs of absent experts, pairs
     computed and summed; then the tokens seen); when given the updated
-    counter is returned beside the output.
+    counter is returned beside the output. While a collection of
+    ``observability.moestats`` is open the layer records, as it is
+    traced, ``choices``, ``load`` (those sizes), ``form`` and, in the
+    sorted form, ``rows`` (the bound, the call's routed pairs) and
+    ``passes`` (a device value: the windows of that many rows the call
+    took; 1 unless a prompt sends the held experts more than the bound).
     """
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
@@ -511,12 +593,11 @@ class GatedMoELayer(Layer):
         shape = xv.shape
         x2d = xv.reshape(-1, self.d_model)
         idx, w = self.gate.route(x2d)
-        y, sizes = routed_swiglu(
+        y, sizes, trace = routed_swiglu(
             x2d, idx, w, self.w_gate._value, self.w_up._value,
-            self.w_down._value, self.expert_offset)
+            self.w_down._value, self.expert_offset, self.num_experts)
         # a no-op unless a collection is open on this thread
-        _moestats.record({"choices": idx, "load": sizes,
-                          "form": routed_form(x2d.shape[0])})
+        _moestats.record({"choices": idx, "load": sizes, **trace})
         if self.shared:
             y = y + swiglu(x2d, self.shared_gate._value,
                             self.shared_up._value, self.shared_down._value)

@@ -463,6 +463,7 @@ class ServingEngine:
         # "decode" | "prefill" | .. -> the forms an expert model's
         # layers traced in this engine's programs of that kind
         self._moe_forms: Dict[str, set] = {}
+        self._moe_rows: Dict[int, Tuple[int, int]] = {}
         self._live_peak = 0
         self.gen = cfg.generation
         self._rng = jax.random.PRNGKey(self.gen.seed)
@@ -1819,8 +1820,12 @@ class ServingEngine:
             return self._run_site(site, fn, *args)
         finally:
             if listen:
+                recs = [r for r in _moestats.drain() if "form" in r]
                 self._moe_forms.setdefault(site[0], set()).update(
-                    r["form"] for r in _moestats.drain() if "form" in r)
+                    r["form"] for r in recs)
+                if site[0] == "prefill":    # the bucket's sorted rows
+                    self._moe_rows.update(
+                        {site[1]: r["rows"] for r in recs if "rows" in r})
 
     def _run_site(self, site, fn, *args):
         if self._mem_on and site not in self._mem_ledgers:
@@ -1980,7 +1985,10 @@ class ServingEngine:
         program and in the prefill programs (``routed_form``: "batched"
         | "sorted"; both joined by "+" if the buckets differ), as they
         recorded it when this engine traced them; None for a kind it
-        has not traced. None for a model without routed experts."""
+        has not traced. ``rows`` = for each prefill bucket traced in the
+        sorted form, (the sorted rows its expert layers hold at a time,
+        ``moe_layer.sorted_rows``; the bucket's routed pairs). None for
+        a model without routed experts."""
         if self.cache.counters is None:
             return None
         c = self._device_counters()
@@ -1992,7 +2000,8 @@ class ServingEngine:
                 "dropped": int((c[:, -1] * k - c[:, -3] - c[:, -2]).sum()),
                 "forms": {kind: "+".join(sorted(
                     self._moe_forms.get(kind, ()))) or None
-                    for kind in ("decode", "prefill")}}
+                    for kind in ("decode", "prefill")},
+                "rows": dict(sorted(self._moe_rows.items()))}
 
     def roofline_report(self):
         """Roofline verdict of the shared decode round

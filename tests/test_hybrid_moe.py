@@ -139,6 +139,7 @@ def test_engine_prefill_then_decode_is_the_reference(model, tokens):
     st = eng.moe_stats()
     assert st["dropped"] == 0
     assert st["forms"] == {"decode": "batched", "prefill": "batched"}
+    assert st["rows"] == {}
     assert st["tokens"][0] == 0 and (st["tokens"][1:] > 0).all()
     np.testing.assert_array_equal(
         st["pairs"].sum(1) + st["absent_pairs"], st["tokens"] * 4)
@@ -163,6 +164,9 @@ def test_decode_is_batched_and_a_long_prefill_sorted():
     st = eng.moe_stats()
     assert st["forms"] == {"decode": "batched", "prefill": "sorted"}
     assert st["dropped"] == 0
+    # the 256 bucket's 1,024 routed pairs, a quarter of them held: its
+    # expert layers hold sorted_rows(256, 4, 4, 16) = 512 rows at a time
+    assert st["rows"] == {256: (512, 1024)}
     assert eng.program_sites() == [("prefill", 256), ("decode",)]
     texts = {}
     for site in eng.program_sites():

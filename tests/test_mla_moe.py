@@ -113,6 +113,7 @@ def test_engine_prefill_then_decode_is_the_reference(model, tokens):
     assert st["dropped"] == 0
     # prompts of 21 and 13 tokens: buckets under the threshold too
     assert st["forms"] == {"decode": "batched", "prefill": "batched"}
+    assert st["rows"] == {}
     assert (st["tokens"] == [0, st["tokens"][1], st["tokens"][1]]).all()
     assert st["tokens"][1] > 0 and st["tokens"][1] % 2 == 0   # B rows a step
     np.testing.assert_array_equal(
@@ -146,6 +147,9 @@ def test_decode_is_batched_and_a_long_prefill_sorted():
     st = eng.moe_stats()
     assert st["forms"] == {"decode": "batched", "prefill": "sorted"}
     assert st["dropped"] == 0
+    # the 256 bucket's 1,024 routed pairs, a quarter of them held: its
+    # expert layers hold sorted_rows(256, 4, 4, 16) = 512 rows at a time
+    assert st["rows"] == {256: (512, 1024)}
     np.testing.assert_array_equal(st["summed_pairs"], st["pairs"].sum(1))
     assert eng.program_sites() == [("prefill", 256), ("decode",)]
     assert "ragged_dot" in tpu_program_text(eng, ("prefill", 256))
@@ -267,7 +271,7 @@ def test_every_token_to_one_expert_drops_nothing(form):
     wd = jnp.asarray(rng.normal(0, 0.2, (4, 32, 64)), jnp.float32)
     idx = jnp.tile(jnp.asarray([[9, 6, 12, 1]], jnp.int32), (40, 1))
     g = jnp.asarray(rng.uniform(0.1, 1, (40, 4)), jnp.float32)
-    y, sizes = FORMS[form](x, idx, g, wg, wu, wd, expert_offset=4)
+    y, sizes = FORMS[form](x, idx, g, wg, wu, wd, expert_offset=4)[:2]
     # pairs an expert, pairs of absent experts, pairs computed and summed
     assert list(np.asarray(sizes)) == [0, 0, 40, 0, 120, 40]
     want = g[:, 1:2] * ((jax.nn.silu(x @ wg[2]) * (x @ wu[2])) @ wd[2])
@@ -275,26 +279,51 @@ def test_every_token_to_one_expert_drops_nothing(form):
                                atol=1e-5)
 
 
+# case -> (tokens, router's width, held experts, passes of the sorted
+# form). 24 tokens of 4 choices make 96 pairs, under one tile of sorted
+# rows: one window, no loop. 192 tokens make 768 pairs over a bound of
+# sorted_rows(192, 4, 4, 16) = 512.
+ROUTINGS = {"random": (24, 16, 4, 1), "random_at_an_offset": (24, 16, 4, 1),
+            "one_held_expert": (24, 16, 4, 1), "no_held_pair": (24, 16, 4, 1),
+            "no_held_pair_of_many": (192, 16, 4, 0),
+            "random_of_many": (192, 16, 4, 1),
+            "every_pair_held": (192, 16, 4, 2),
+            "held_pairs_at_the_bound": (192, 16, 4, 1),
+            "held_pairs_one_over_the_bound": (192, 16, 4, 2),
+            "all_experts_held": (192, 4, 4, 1)}
+
+
 def _routing(case, rng, T, k, E):
     """[T, k] choices over E experts of which 4..7 are held (offset 4),
-    or 0..3 for ``random`` (offset 0)."""
+    or 0..3 for ``random`` and ``all_experts_held`` (offset 0)."""
+    absent = np.asarray([0, 1, 2, 3, 8, 9, 10, 11, 12, 13, 14, 15])
     if case == "one_held_expert":
         return np.tile([[9, 6, 12, 1]], (T, 1)), 4
-    if case == "no_held_pair":      # every choice outside 4..7
-        pool = np.asarray([0, 1, 2, 3, 8, 9, 10, 11, 12, 13, 14, 15])
-        return np.stack([rng.permutation(pool)[:k] for _ in range(T)]), 4
+    if case.startswith("no_held_pair"):     # every choice outside 4..7
+        return np.stack([rng.permutation(absent)[:k] for _ in range(T)]), 4
+    if case == "every_pair_held":
+        return np.stack([4 + rng.permutation(4) for _ in range(T)]), 4
+    if case.startswith("held_pairs_"):
+        # whole tokens on the held experts up to the bound, the rest
+        # elsewhere; one pair more for the case over it
+        M = moe_layer.sorted_rows(T, k, 4, E)
+        idx = np.stack([4 + rng.permutation(4) if t < M // k
+                        else rng.permutation(absent)[:k] for t in range(T)])
+        if case.endswith("one_over_the_bound"):
+            idx[-1, 2] = 6
+        return idx, 4
     idx = np.stack([rng.permutation(E)[:k] for _ in range(T)])
-    return idx, (4 if case == "random_at_an_offset" else 0)
+    return idx, (0 if case in ("random", "all_experts_held") else 4)
 
 
-@pytest.mark.parametrize("case", ["random", "random_at_an_offset",
-                                  "one_held_expert", "no_held_pair"])
+@pytest.mark.parametrize("case", ROUTINGS)
 def test_batched_form_is_the_sorted_form_is_a_loop_over_tokens(case):
     """The two forms of ``routed_swiglu`` and a plain loop (token by
     token, choice by choice) agree on seeded float32 weights, and their
-    ``sizes`` agree element by element."""
+    ``sizes`` agree element by element: also where the held pairs pass
+    the sorted form's bound and it takes a second window of rows."""
     rng = np.random.default_rng(11)
-    T, k, E, El, d, h = 24, 4, 16, 4, 64, 32
+    (T, E, El, passes_want), k, d, h = ROUTINGS[case], 4, 64, 32
     x = rng.normal(0, 1, (T, d)).astype(np.float32)
     wg, wu = (rng.normal(0, 0.2, (El, d, h)).astype(np.float32)
               for _ in range(2))
@@ -311,19 +340,35 @@ def test_batched_form_is_the_sorted_form_is_a_loop_over_tokens(case):
                 want[t] += w[t, j] * ((g / (1 + np.exp(-g)) * u) @ wd[e])
                 pairs[e] += 1
     sizes_want = list(pairs) + [T * k - pairs.sum(), pairs.sum()]
-    if case == "no_held_pair":
-        assert pairs.sum() == 0
+    M = moe_layer.sorted_rows(T, k, El, E)
+    held_want = {"no_held_pair": 0, "no_held_pair_of_many": 0,
+                 "every_pair_held": T * k, "all_experts_held": T * k,
+                 "held_pairs_at_the_bound": M,
+                 "held_pairs_one_over_the_bound": M + 1}
+    assert pairs.sum() == held_want.get(case, pairs.sum())
     args = (jnp.asarray(x), jnp.asarray(idx, jnp.int32), jnp.asarray(w),
             jnp.asarray(wg), jnp.asarray(wu), jnp.asarray(wd), off)
-    for form in FORMS.values():
-        y, sizes = form(*args)
+    for name, form in FORMS.items():
+        if name == "sorted":
+            y, sizes, passes = form(*args, E)
+            assert int(passes) == passes_want == max(
+                -(-int(pairs.sum()) // M), int(M == T * k))
+        else:
+            y, sizes = form(*args)
         assert sizes.dtype == jnp.int32 and sizes.shape == (El + 2,)
         assert list(np.asarray(sizes)) == sizes_want
         np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4,
                                    atol=1e-5)
-    # the public entry takes the form its token count asks for
-    y, sizes = routed_swiglu(*args)
+    # the public entry takes the form its token count asks for, and says
+    # what it took
+    y, sizes, trace = routed_swiglu(*args, E)
     assert list(np.asarray(sizes)) == sizes_want
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=1e-5)
+    if T <= moe_layer._BATCHED_MAX_TOKENS:
+        assert trace == {"form": "batched"}
+    else:
+        assert (trace["form"], trace["rows"], int(trace["passes"])) == \
+            ("sorted", (M, T * k), passes_want)
 
 
 def test_a_capacity_on_the_groups_shows_as_dropped_pairs(monkeypatch):
@@ -332,13 +377,13 @@ def test_a_capacity_on_the_groups_shows_as_dropped_pairs(monkeypatch):
     to 16 rows, as a capacity would, and 24 of the 40 held pairs are
     missing from the count (``ServingEngine.moe_stats()["dropped"]`` =
     held - summed)."""
-    real = jnp.bincount
-    monkeypatch.setattr(jnp, "bincount", lambda *a, **k: jnp.minimum(
-        real(*a, **k), 16))
+    real = moe_layer._group_sizes
+    monkeypatch.setattr(moe_layer, "_group_sizes",
+                        lambda *a: jnp.minimum(real(*a), 16))
     x = jnp.ones((40, 64), jnp.float32)
     w = jnp.ones((4, 64, 32), jnp.float32)
     idx = jnp.tile(jnp.asarray([[9, 6, 12, 1]], jnp.int32), (40, 1))
-    _, sizes = moe_layer.routed_swiglu_sorted(
+    _, sizes, _ = moe_layer.routed_swiglu_sorted(
         x, idx, jnp.ones((40, 4)), w, w, jnp.ones((4, 32, 64)),
         expert_offset=4)
     absent, summed = map(int, sizes[-2:])
@@ -375,10 +420,24 @@ def test_a_combine_that_misses_an_expert_shows_as_dropped_pairs(
                                np.asarray(y_all[:10]) / 2, rtol=1e-6)
 
 
+def _shapes(jaxpr):
+    """Result shapes of every equation, those of the loops' and the
+    called functions' bodies among them."""
+    out = []
+    for eq in jaxpr.eqns:
+        out += [tuple(v.aval.shape) for v in eq.outvars
+                if hasattr(v.aval, "shape")]
+        for sub in eq.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                out += _shapes(sub)
+    return out
+
+
 def _traced(layer, T):
-    """(intermediate shapes of the layer's forward over T tokens, the
-    forms its ``routed_swiglu`` recorded, whether XLA's grouped matmul is
-    in it)."""
+    """(intermediate shapes of the layer's forward over T tokens, what
+    its ``routed_swiglu`` recorded as it was traced, whether XLA's
+    grouped matmul is in it)."""
     from paddle_tpu.observability import moestats
 
     moestats.begin()
@@ -386,33 +445,86 @@ def _traced(layer, T):
         jaxpr = jax.make_jaxpr(lambda v: layer(v)._value)(
             jnp.zeros((T, layer.d_model), jnp.float32))
     finally:
-        forms = [r["form"] for r in moestats.drain()]
-    shapes = [tuple(v.aval.shape) for eq in jaxpr.jaxpr.eqns
-              for v in eq.outvars if hasattr(v.aval, "shape")]
-    return shapes, forms, "ragged_dot" in str(jaxpr)
+        recs = moestats.drain()
+    return _shapes(jaxpr.jaxpr), recs, "ragged_dot" in str(jaxpr)
 
 
 @pytest.mark.parametrize("form", FORMS)
 def test_expert_layer_builds_no_dispatch_tensor(form):
     """Neither form has [T, E, C] algebra: the router's width (24) shows
     in its own [T, 24] scores and nowhere else, there is no capacity,
-    and the largest intermediate is the sorted pairs' [T*k, d] above the
-    threshold (by h, the wider of h and d here) and the held experts'
+    and the largest intermediate is a window of the sorted held pairs,
+    [M, h] above the threshold (h the wider of h and d here; M =
+    ``sorted_rows``, 256 of the 672 pairs) and the held experts'
     [El, T, h] at or below it."""
     paddle.seed(0)
     d, h, E, El, k = 32, 64, 24, 6, 4
     layer = GatedMoELayer(d, h, E, El, 6, top_k=k,
                           routed_scaling_factor=2.5)
     T = moe_layer._BATCHED_MAX_TOKENS + (40 if form == "sorted" else -40)
-    shapes, forms, _ = _traced(layer, T)
-    assert forms == [form]
+    shapes, recs, _ = _traced(layer, T)
+    assert [r["form"] for r in recs] == [form]
     assert all(s in ((T, E), (E,), (1, E)) for s in shapes if E in s), \
         [s for s in shapes if E in s]
     biggest = max(shapes, key=lambda s: int(np.prod(s)))
     # (the shared expert's [T, h] and the broadcast tokens [El, T, d]
     # are smaller than either)
+    M = moe_layer.sorted_rows(T, k, El, E)
+    assert M == 256 < T * k
     assert int(np.prod(biggest)) == \
-        (T * k * h if form == "sorted" else El * T * h), biggest
+        (M * h if form == "sorted" else El * T * h), biggest
+
+
+@pytest.mark.parametrize("T", [168, 1024])
+def test_sorted_form_has_no_array_of_every_routed_pair(T):
+    """No intermediate of the traced sorted form has ``T * k`` rows and
+    more than one column: the pairs' int32 keys, numbers and weights are
+    sorted, and what is gathered, multiplied, scaled and summed is a
+    window of ``sorted_rows`` rows. The layer records that bound beside
+    the pairs as it is traced."""
+    paddle.seed(0)
+    d, h, E, El, k = 32, 64, 24, 6, 4
+    layer = GatedMoELayer(d, h, E, El, 6, top_k=k,
+                          routed_scaling_factor=2.5)
+    shapes, recs, grouped = _traced(layer, T)
+    M = moe_layer.sorted_rows(T, k, El, E)
+    assert grouped and M < T * k and M % moe_layer._SORTED_TILE == 0
+    assert [(r["form"], r["rows"]) for r in recs] == [("sorted", (M, T * k))]
+    wide = {d, h}
+    assert [s for s in shapes if s and s[0] == T * k
+            and (len(s) > 1 and s[-1] in wide)] == []
+    assert (M, h) in shapes and (M, d) in shapes
+    assert not any(int(np.prod(s)) >= T * k * min(d, h) for s in shapes)
+
+
+def test_a_prompt_past_the_bound_takes_a_second_pass_of_the_same_rows():
+    """A selection bias that sends every token to the four held experts:
+    768 held pairs over a bound of 512 sorted rows. The layer reports 2
+    passes (a device value, in its record), drops nothing, and gives what
+    the batched form gives for the same choices."""
+    from paddle_tpu.observability import moestats
+
+    layer = _expert_layer(4, 4)
+    layer.gate.bias._value = jnp.zeros(16).at[4:8].set(10.0)
+    x = jnp.asarray(np.random.default_rng(5).normal(0, 1, (192, 64)),
+                    jnp.float32)
+    moestats.begin()
+    try:
+        y = layer(x)._value
+    finally:
+        (rec,) = moestats.drain()
+    assert (rec["form"], rec["rows"], int(rec["passes"])) == \
+        ("sorted", (512, 768), 2)
+    assert list(np.asarray(rec["load"])) == [192] * 4 + [0, 768]
+    idx, w = layer.gate.route(x)
+    want, _ = moe_layer.routed_swiglu_batched(
+        x, idx, w, layer.w_gate._value, layer.w_up._value,
+        layer.w_down._value, 4)
+    want = want + moe_layer.swiglu(x, layer.shared_gate._value,
+                                   layer.shared_up._value,
+                                   layer.shared_down._value)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
+                               atol=1e-5)
 
 
 def test_the_form_is_a_function_of_the_token_count_alone():
@@ -423,8 +535,9 @@ def test_the_form_is_a_function_of_the_token_count_alone():
     assert [moe_layer.routed_form(t) for t in (1, edge, edge + 1, 4096)] \
         == ["batched", "batched", "sorted", "sorted"]
     for T, form in ((edge, "batched"), (edge + 1, "sorted")):
-        _, forms, grouped = _traced(layer, T)
-        assert forms == [form] and grouped == (form == "sorted")
+        _, recs, grouped = _traced(layer, T)
+        assert [r["form"] for r in recs] == [form]
+        assert grouped == (form == "sorted")
 
 
 @pytest.mark.parametrize("lengths", [[0, 5, 17, 63], [31, 32, 33, 16]])

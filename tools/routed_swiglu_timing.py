@@ -1,18 +1,30 @@
 """Time the held experts' products of one expert layer alone on the chip
-(PERF.md, PR 32).
+(PERF.md, PRs 32 and 40).
 
-At the two expert cells' shapes (``mimo``: 16 of 256 experts held;
-``sarvam``: 32 of 128; both 128 tokens a decode step, top-8, d 4096, h
-2048, bf16 weights) and at a prefill's (256, 512, 1,024 tokens: where
-the two forms cross): ``STEPS`` calls in
-one ``lax.scan``, each call's tokens made from the last call's output so
-that they run one after the other and dispatch does not count. One JSON
-line a reading: microseconds a call (three products), the bytes of expert
-weights a call streams, and that over the time as a share of the chip's
-HBM bandwidth. The forms:
+Two sets of readings. ``decode``: at the two expert cells' decode shapes
+(``mimo``: 16 of 256 experts held; ``sarvam``: 32 of 128; both 128 tokens
+a decode step, top-8, d 4096, h 2048, bf16 weights) and at 256, 512 and
+1,024 tokens, where the two forms cross. ``prefill``: at the prefill
+buckets the four expert cells run (``keye``: 16 of 128 held, d 2048, h
+768, and ``trinity``: h 1,024, at 2,048 / 4,096 / 8,192 tokens; ``mimo``
+at 2,048; ``sarvam`` at 1,024). ``STEPS`` calls in one ``lax.scan``, each
+call's tokens made from the last call's output so that they run one
+after the other and dispatch does not count. One JSON line a reading:
+microseconds a call (three products), the bytes of expert weights a call
+streams, and that over the time as a share of the chip's HBM bandwidth;
+every form's output is checked against ``sorted_full``'s. The forms:
 
 - ``sorted`` / ``batched``: ``moe_layer.routed_swiglu_sorted`` /
-  ``routed_swiglu_batched`` as the library has them;
+  ``routed_swiglu_batched`` as the library has them (the sorted one on
+  ``moe_layer.sorted_rows`` rows at a time since PR 40: ``rows`` and
+  ``passes`` in its line);
+- ``sorted_full``: the sorted form as it was before PR 40, every routed
+  pair a row of the ``[T*k, d]`` operand and of the float32 result;
+- ``sorted_by_token`` / ``sorted_gather_k``: the library's sorted form
+  with another way to sum a window's rows into their tokens: the rows
+  put in token order first and a scatter-add told so (what XLA makes of
+  the library's scatter-add by itself: the two read alike); ``k`` masked
+  gathers from the window in token order, one a choice;
 - ``batched_unfolded``: the batched form with a down product per expert
   and the combine after it (an ``[El, T, d]`` float32 array);
 - ``batched_plain``: the batched form without the expert as a batch
@@ -20,7 +32,7 @@ HBM bandwidth. The forms:
 - ``batched_pallas``: the batched form as one Mosaic kernel over
   (expert, tile of 256 of the hidden width; 512 read 1-5% slower).
 
-    chiprun -- python tools/routed_swiglu_timing.py
+    chiprun -- python tools/routed_swiglu_timing.py [decode|prefill] [cell ...]
 """
 import json
 import os
@@ -41,9 +53,71 @@ import paddle_tpu  # noqa: E402,F401  (places the compile cache)
 from benchmarks.harness.peaks import peaks_for  # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe import moe_layer as ml  # noqa: E402
 
-D, H, K = 4096, 2048, 8
+K = 8
 STEPS, CALLS = 16, 4
-CELLS = {"mimo": (16, 256), "sarvam": (32, 128)}     # held, routed over
+SHAPES = {"mimo": (16, 256, 4096, 2048),     # held, routed over, d, h
+          "sarvam": (32, 128, 4096, 2048),
+          "keye": (16, 128, 2048, 768),
+          "trinity": (16, 128, 2048, 1024)}
+DECODE = ("mimo", "sarvam")
+PREFILL = {"keye": (2048, 4096, 8192), "trinity": (2048, 4096, 8192),
+           "mimo": (2048,), "sarvam": (1024,)}
+
+
+def sorted_full(x2d, idx, weights, w_gate, w_up, w_down, off=0):
+    """``routed_swiglu_sorted`` before PR 40."""
+    T, k = idx.shape
+    El = w_gate.shape[0]
+    local = idx - off
+    held = (local >= 0) & (local < El)
+    e = jnp.where(held, local, El).reshape(T * k)
+    order = jnp.argsort(e, stable=True)
+    gs = jnp.bincount(e, length=El).astype(jnp.int32)
+    xs = x2d[order // k]
+    g = lax.ragged_dot(xs, w_gate, gs, preferred_element_type=jnp.float32)
+    u = lax.ragged_dot(xs, w_up, gs, preferred_element_type=jnp.float32)
+    out = lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype), w_down,
+                         gs, preferred_element_type=jnp.float32)
+    used = held.reshape(T * k)[order] & (jnp.arange(T * k) < gs.sum())
+    wf = weights.reshape(T * k)[order]
+    out = jnp.where(used[:, None], out * wf[:, None], 0.0)
+    return out[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1), gs
+
+
+def by_token(y, rows, tok):
+    """The window's rows in token order, then a scatter-add that is
+    told its indices are sorted."""
+    tok, at = lax.sort((tok, jnp.arange(tok.shape[0], dtype=jnp.int32)),
+                       num_keys=1)
+    return y.at[tok].add(rows[at], indices_are_sorted=True)
+
+
+def gather_k(y, rows, tok):
+    """``k`` masked gathers, one a choice: a token's j-th row of the
+    window if it has one (a token's rows share its number, so ranking
+    the window's rows by token finds them), summed in one pass."""
+    M, T = tok.shape[0], y.shape[0]
+    # stable: the zero rows that pad the last window sort behind token
+    # 0's own
+    tok, at = lax.sort((tok, jnp.arange(M, dtype=jnp.int32)), num_keys=1,
+                       is_stable=True)
+    first = jnp.searchsorted(tok, jnp.arange(T + 1, dtype=tok.dtype))
+    for j in range(K):
+        i = first[:-1] + j
+        y = y + jnp.where((i < first[1:])[:, None],
+                          rows[at[jnp.minimum(i, M - 1)]], 0.0)
+    return y
+
+
+def with_sum(fn):
+    """The library's sorted form with ``fn`` for ``_sum_by_token``."""
+    def form(*a):
+        keep, ml._sum_by_token = ml._sum_by_token, fn
+        try:
+            return ml.routed_swiglu_sorted(*a)
+        finally:
+            ml._sum_by_token = keep
+    return form
 
 
 def batched_unfolded(x, idx, w, wg, wu, wd, off=0):
@@ -115,20 +189,26 @@ def batched_pallas(x, idx, w, wg, wu, wd, off=0, th=256, interpret=False):
     return y, pairs.sum(axis=0)
 
 
+# every form takes (x, idx, w, wg, wu, wd, expert_offset, router's width)
 FORMS = {"sorted": ml.routed_swiglu_sorted,
-         "batched": ml.routed_swiglu_batched,
-         "batched_unfolded": batched_unfolded,
-         "batched_plain": batched_plain,
-         "batched_pallas": batched_pallas}
+         "sorted_full": lambda *a: sorted_full(*a[:7]),
+         "sorted_by_token": with_sum(by_token),
+         "sorted_gather_k": with_sum(gather_k),
+         "batched": lambda *a: ml.routed_swiglu_batched(*a[:7]),
+         "batched_unfolded": lambda *a: batched_unfolded(*a[:7]),
+         "batched_plain": lambda *a: batched_plain(*a[:7]),
+         "batched_pallas": lambda *a: batched_pallas(*a[:7])}
+BATCHED = [f for f in FORMS if f.startswith("batched")]
+SORTED = [f for f in FORMS if f.startswith("sorted")]
 
 
-def reading(cell, T, form, ops, peak):
+def reading(cell, T, form, ops, E, peak):
     x, idx, w, wg, wu, wd = ops
 
     @jax.jit
     def run(x, idx, w, wg, wu, wd):
         def step(x, _):
-            y, _ = FORMS[form](x, idx, w, wg, wu, wd)
+            y = FORMS[form](x, idx, w, wg, wu, wd, 0, E)[0]
             return (x + 1e-3 * y.astype(x.dtype)), None
         return lax.scan(step, x, None, length=STEPS)[0]
 
@@ -147,31 +227,55 @@ def reading(cell, T, form, ops, peak):
             "hbm_share": nbytes / (us * 1e-6) / peak.hbm_bytes}
 
 
+def cases(which, cells):
+    """(cell, tokens, forms) of the readings asked for."""
+    out = []
+    if "decode" in which:
+        out += [(c, T, BATCHED + ["sorted"] if T == 128
+                 else ["sorted", "batched"])
+                for c in DECODE for T in (128, 256, 512, 1024)]
+    if "prefill" in which:
+        out += [(c, T, SORTED) for c, Ts in PREFILL.items() for T in Ts]
+    return [c for c in out if not cells or c[0] in cells]
+
+
 def main():
     dev = jax.devices()[0]
     peak = peaks_for(dev.device_kind)
     r = np.random.RandomState(0)
-    out = []
-    for cell, (El, E) in CELLS.items():
-        keys = jax.random.split(jax.random.PRNGKey(El), 3)
-        wg, wu, wd = (0.02 * jax.random.normal(k, s, jnp.bfloat16)
-                      for k, s in zip(keys, ((El, D, H), (El, D, H),
-                                             (El, H, D))))
-        for T, forms in ((128, list(FORMS)),) + tuple(
-                (T, ["sorted", "batched"]) for T in (256, 512, 1024)):
-            x = jnp.asarray(r.randn(T, D), jnp.bfloat16)
-            idx = jnp.asarray(np.stack([r.permutation(E)[:K]
-                                        for _ in range(T)]), jnp.int32)
-            w = jnp.asarray(r.uniform(0.05, 0.2, (T, K)), jnp.float32)
-            ops = (x, idx, w, wg, wu, wd)
-            want = np.asarray(jax.jit(ml.routed_swiglu_sorted)(*ops)[0])
-            for form in forms:
-                row = reading(cell, T, form, ops, peak)
-                got = np.asarray(jax.jit(FORMS[form])(*ops)[0])
-                row["max_gap_to_sorted"] = float(np.abs(got - want).max())
-                row["max_abs"] = float(np.abs(want).max())
-                print(json.dumps(row), flush=True)
-                out.append(row)
+    sets = [a for a in sys.argv[1:] if a in ("decode", "prefill")]
+    cells = [a for a in sys.argv[1:] if a in SHAPES]
+    out, made = [], {}
+    for cell, T, forms in cases(sets or ["decode", "prefill"], cells):
+        El, E, d, h = SHAPES[cell]
+        if cell not in made:       # one cell's weights at a time
+            keys = jax.random.split(jax.random.PRNGKey(El), 3)
+            made = {cell: tuple(
+                0.02 * jax.random.normal(k, s, jnp.bfloat16)
+                for k, s in zip(keys, ((El, d, h), (El, d, h),
+                                       (El, h, d))))}
+        x = jnp.asarray(r.randn(T, d), jnp.bfloat16)
+        idx = jnp.asarray(np.argsort(r.random_sample((T, E)))[:, :K],
+                          jnp.int32)
+        w = jnp.asarray(r.uniform(0.05, 0.2, (T, K)), jnp.float32)
+        ops = (x, idx, w) + made[cell]
+        want = np.asarray(jax.jit(sorted_full)(*ops)[0])
+        for form in forms:
+            try:
+                row = reading(cell, T, form, ops, E, peak)
+                got = jax.jit(lambda *a: FORMS[form](*a, 0, E))(*ops)
+            except Exception as err:    # a form the compiler refuses
+                print(json.dumps({"cell": cell, "tokens": T, "form": form,
+                                  "error": repr(err)[:300]}), flush=True)
+                continue
+            if form == "sorted":
+                row["rows"] = ml.sorted_rows(T, K, El, E)
+                row["passes"] = int(got[2])
+            row["max_gap_to_sorted_full"] = float(
+                np.abs(np.asarray(got[0]) - want).max())
+            row["max_abs"] = float(np.abs(want).max())
+            print(json.dumps(row), flush=True)
+            out.append(row)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out",
                            "routed_swiglu_timing.json"), "w") as f:
