@@ -40,6 +40,16 @@ the decode steps selected for (their own count on the device), three
 pooled arrays a layer written in place and
 ``paged_sparse_decode_attention`` in the decode program.
 
+``--phases serve_sparse_mla`` (only when named) serves the tiny preset
+of the latent-attention decoder whose index selects rows of the LATENT
+cache (``sparse_mla_tiny`` with widths the chip tiles: a 128-wide
+latent, index heads of 128, the 256 best rows of contexts to 1,000, 8
+experts in 4 groups of which 2 are kept) the same way:
+``mla_paged_sparse_decode_attention`` and ``kept_flash_attention``
+against their dense twins, ``kept_keys_wrong`` 0 in a full forward and in
+the decode steps' own count, three pooled arrays a layer written in
+place and the kernel by name in the decode program.
+
 ``--four-chips`` adds the same train step over a real 2x2 mesh in two
 layouts (mp2 x dp2 on ParallelEngine; pp2 x mp2 on GPTForCausalLMPipe via
 ``fleet.distributed_model(...).train_batch``); asked for, fewer than four
@@ -82,14 +92,15 @@ import time
 ONE_CHIP_PHASES = ("kernels", "train", "serve")
 FOUR_CHIP_PHASES = ("mp2dp2", "pp2mp2")
 # run only when named in --phases: the default three fill their time limit
-EXTRA_PHASES = ("serve_latent", "serve_hybrid", "serve_sparse")
+EXTRA_PHASES = ("serve_latent", "serve_hybrid", "serve_sparse",
+                "serve_sparse_mla")
 # seconds per child, compilation included. The one-chip three sum to
 # 1100, inside the 1200 s that run is allowed; measured cold on a v5e
 # they took 72, 122 and 106 s (CHANGES.md PR 21).
 PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
                  "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 400,
                  "serve_hybrid": 900,    # two shapes since PR 35
-                 "serve_sparse": 400}
+                 "serve_sparse": 400, "serve_sparse_mla": 400}
 RESULT_TAG = "CHIP_SMOKE_PHASE_RESULT "
 
 # Tolerances, each with its reason. Every comparison is
@@ -217,6 +228,22 @@ class Sizes:
             # pool that no deployment's pool (gigabytes) can get. 2,048
             # pages (134 MB an array) keep the check on layout changes
             self.sparse_pool = 2048
+            # serve_sparse_mla: models.mla_moe.sparse_mla_tiny (a dense
+            # and two expert layers) with the widths the chip tiles; the
+            # lengths and the new tokens are serve_sparse's. Its pooled
+            # arrays are ONE head of 128 columns: at 2,048 pages an
+            # array is 67 MB and XLA stages one in VMEM around the
+            # decode step's scatter (a `copy` into S(1), seen on the
+            # chip and in an AOT compile, PR 41); 4,096 pages (134 MB)
+            # keep the check on layout changes
+            self.sparse_mla_pool = 4096
+            self.sparse_mla = dict(
+                hidden_size=256, num_heads=8, q_lora_rank=128,
+                kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
+                v_head_dim=128, intermediate_size=512,
+                moe_intermediate_size=128, index_head_dim=128,
+                index_topk=256, attention_block=128,
+                max_position_embeddings=1152, dtype="bfloat16")
         else:
             self.gpt = dict(vocab_size=1024, hidden_size=128,
                             num_layers=2, num_heads=4,
@@ -273,6 +300,9 @@ class Sizes:
                                dtype="bfloat16")
             self.sparse_lens, self.sparse_new = (100, 60, 30, 10), 12
             self.sparse_pool = None
+            self.sparse_mla = dict(max_position_embeddings=288,
+                                   dtype="bfloat16")
+            self.sparse_mla_pool = None
 
 
 # ---------------------------------------------------------------------------
@@ -954,6 +984,25 @@ def phase_serve_latent(sz: Sizes) -> None:
     from paddle_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
 
     cfg = MLAMoEConfig(**sz.latent)
+    # the prefill's attention over kept sets, keys wider than values
+    from paddle_tpu.ops.pallas import kept_attention as ka
+
+    Sk, blk = (64, 16) if sz.rehearsal else (1024, 512)
+    kq, kk, kv = rnd(1, Sk, 8, 192), rnd(1, Sk, 8, 192), rnd(1, Sk, 8, 128)
+    tri = np.tril(np.ones((Sk, Sk), bool))
+    kmask = tri & ((r.random_sample((Sk, Sk)) < 0.3) | np.eye(Sk, dtype=bool))
+    kmask[:blk] = tri[:blk]
+    kmask = jnp.asarray(kmask[None])
+    if sz.rehearsal or ka.kept_flash_supported(kq.shape, kv.shape, blk):
+        got = ka.kept_flash_attention(kq, kk, kv, kmask, 0.07, blk,
+                                      interpret=sz.rehearsal)
+        want = ka.kept_attention_dense(kq, kk, kv, kmask, 0.07)
+        err = float(jnp.abs(got.astype(jnp.float32)
+                            - want.astype(jnp.float32)).max())
+        check(err <= TOL_ATTN,
+              f"prefill attention over kept sets ({Sk} rows, 8 heads of "
+              f"192 against 128) within {TOL_ATTN} of its dense twin "
+              f"(max err {err:.2e})")
     t0 = time.perf_counter()
     paddle.set_default_dtype(cfg.dtype)
     paddle.seed(0)
@@ -1345,6 +1394,156 @@ def phase_serve_sparse(sz: Sizes) -> None:
                   "pool_pages": eng.P, "kept_keys_wrong": wrong})
 
 
+def phase_serve_sparse_mla(sz: Sizes) -> None:
+    """The latent-attention decoder whose index selects rows of the
+    latent cache, through ServingEngine in its default mode: the latent
+    decode kernel under a kept mask agrees with its dense twin, every
+    row of a full forward keeps ``min(t + 1, top-k)`` rows, decode
+    agrees with a full forward, three pooled arrays a layer are written
+    in place."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import (Config, ServingEngine,
+                                      create_predictor)
+    from paddle_tpu.models.mla_moe import (MLAMoEForCausalLM,
+                                           sparse_mla_tiny)
+    from paddle_tpu.ops.pallas import mla_attention as ma
+    from paddle_tpu.ops.sparse_attention import collect_selection
+
+    _, device, events = start_child(sz.rehearsal)
+    cfg = sparse_mla_tiny(**sz.sparse_mla)
+    page, B, topk = sz.page, sz.hybrid_batch, cfg.index_topk
+    H, dc = cfg.num_heads, cfg.kv_lora_rank
+    r = np.random.RandomState(0)
+    # the kernel alone under a kept mask, at the decode program's shapes
+    ncols = -(-(max(sz.sparse_lens) + sz.sparse_new) // page)
+    lens = np.resize([0, topk - 1, topk, topk + 1, 2 * page - 1,
+                      ncols * page - 2], B).astype("int32")
+    P = B * ncols + 1
+    rnd = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.bfloat16)
+    cp, rp = rnd(P, 1, page, dc), rnd(P, 1, page, cfg.rope_cache_width)
+    tbl = r.permutation(P - 1)[:B * ncols].reshape(B, ncols).astype("int32")
+    ql, qr = rnd(B, H, dc), rnd(B, H, cfg.rope_cache_width)
+    cols = np.arange(ncols * page)[None]
+    keep = (cols <= lens[:, None]) & (
+        (r.random_sample((B, ncols * page)) < 0.25)
+        | (cols == lens[:, None]))
+    keep[:, page:2 * page] &= cols[:, page:2 * page] == lens[:, None]
+    keep = jnp.asarray(keep)
+    if sz.rehearsal or ma.mla_paged_supported(ql.shape, cp.shape, rp.shape):
+        want = ma.mla_paged_attention_dense(
+            ql[:, None], qr[:, None], cp, rp, tbl, lens,
+            cfg.softmax_scale, keep)[:, 0]
+        got = ma.mla_paged_decode_attention(
+            ql, qr, cp, rp, tbl, lens, cfg.softmax_scale, keep=keep,
+            interpret=sz.rehearsal)
+        err = float(jnp.abs(got.astype(jnp.float32)
+                            - want.astype(jnp.float32)).max())
+        check(err <= TOL_ATTN,
+              f"latent decode kernel under a kept mask ({H} heads, "
+              f"{ncols} pages a row) within {TOL_ATTN} of its dense twin "
+              f"(max err {err:.2e})")
+    t0 = time.perf_counter()
+    paddle.set_default_dtype(cfg.dtype)
+    paddle.seed(0)
+    model = MLAMoEForCausalLM(cfg)
+    pred = create_predictor(
+        Config().set_model(model).enable_paged_kv(page_size=page))
+    mix = [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
+           for n in sz.sparse_lens]
+    eng = ServingEngine(pred, max_batch=B, debug_invariants=True,
+                        pool_pages=sz.sparse_mla_pool)
+    check(eng.cache.arrays == [3] * cfg.num_layers
+          and all(layer[0].shape[1:] == (1, page, dc)
+                  and layer[2].shape[1:] == (1, page, cfg.index_cache_width)
+                  for layer in eng.pools),
+          f"three pooled arrays a layer: latents {eng.pools[0][0].shape}, "
+          f"rotated keys {eng.pools[0][1].shape}, index keys "
+          f"{eng.pools[0][2].shape}")
+    t_setup = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    rids = [eng.submit(p, max_new_tokens=sz.sparse_new) for p in mix]
+    done = eng.run()
+    t_run = time.perf_counter() - t0
+    outs = [np.asarray(done[rid].new_tokens) for rid in rids if rid in done]
+    check(len(outs) == len(rids)
+          and all(len(o) == sz.sparse_new for o in outs)
+          and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
+          f"every request returned {sz.sparse_new} tokens of the "
+          f"vocabulary; the longest context {len(mix[0]) + sz.sparse_new} "
+          f"is {(len(mix[0]) + sz.sparse_new) / topk:.1f} times the "
+          f"{topk} rows a query keeps")
+    # one forward over the whole context: what every row kept, and the
+    # served tokens' logits
+    from paddle_tpu.autograd import no_grad
+    from paddle_tpu.distributed.engine import bind_params
+
+    params = list(model.parameters())
+
+    def whole(pvals, ids):
+        with no_grad(), bind_params(params, pvals), \
+                collect_selection() as sets:
+            logits = model.forward(ids)
+        return logits._value, [k[0].sum(-1) for k in sets]
+
+    seq = np.concatenate([mix[0], outs[0][:-1]])
+    full, kept = jax.jit(whole)(tuple(p._value for p in params),
+                                jnp.asarray(seq[None, :]))
+    full = np.asarray(full[0].astype(jnp.float32))
+    want_kept = np.minimum(np.arange(len(seq)) + 1, topk)
+    wrong = sum(int((np.asarray(k) != want_kept).sum()) for k in kept)
+    check(len(kept) == cfg.num_layers and wrong == 0,
+          f"kept_keys_wrong == {wrong}: every row of {len(kept)} layers "
+          f"of a full forward keeps min(t + 1, {topk}) cache rows")
+    sel = eng.selection_stats()
+    check(sel["rows"] > 0 and sel["kept_keys_wrong"] == 0,
+          f"the decode steps' own count on the device: kept_keys_wrong "
+          f"== {sel['kept_keys_wrong']} of {sel['rows']} (row, layer) "
+          f"pairs")
+    at = full[len(mix[0]) - 1:]
+    gaps = at.max(-1) - at[np.arange(len(outs[0])), outs[0]]
+    check(float(gaps.max()) <= TOL_LOGIT,
+          f"every served token of the longest request scores within "
+          f"{TOL_LOGIT} of a full forward's best (widest "
+          f"{float(gaps.max()):.3f})")
+    st = eng.moe_stats()
+    check(st["dropped"] == 0 and st["tokens"][-1] > 0
+          and st["tokens"][0] == 0,
+          f"expert layers dropped {st['dropped']} routed pairs of "
+          f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}; the "
+          f"dense layer routed none")
+    c = eng.cache.counts()
+    check(c["free"] == eng.cache.usable, f"every page back to free: {c}")
+    found = kernel_names(eng.lowered_text(("decode",)))
+    check(sz.rehearsal
+          or found.get("mla_paged_sparse_decode_attention", 0) >= 1,
+          f"program ('decode',) holds Mosaic calls {found}")
+    site = max(s for s in eng.program_sites() if s[0] == "prefill")
+    found = kernel_names(eng.lowered_text(site))
+    check(sz.rehearsal or site[1] < cfg.attention_block
+          or found.get("kept_flash_attention", 0) == cfg.num_layers,
+          f"program {site} holds Mosaic calls {found}")
+    shapes = {a.shape for layer in eng.pools for a in layer}
+    copies = 0
+    for site in [("decode",)] + sorted(
+            s for s in eng.program_sites() if s[0] == "prefill")[-1:]:
+        text = eng.compiled_text(site)
+        n = sum(eng.pool_copies(text, s) for s in shapes)
+        copies += n
+        check(sz.rehearsal or n == 0,
+              f"compiled program {site}: {n} copies of a whole pool")
+        if site == ("decode",):     # the routing counters ride along
+            check_decode_donation(eng, text, 4 * cfg.num_layers)
+    check_overlap(eng)
+    finish_child("serve_sparse_mla", device, events,
+                 {"setup_s": round(t_setup, 1), "run_s": round(t_run, 2),
+                  "pool_pages": eng.P, "kept_keys_wrong": wrong,
+                  "pool_copies": copies})
+
+
 # ---------------------------------------------------------------------------
 # parent: children, in order, one at a time; never imports JAX
 # ---------------------------------------------------------------------------
@@ -1417,6 +1616,8 @@ def main(argv=None) -> int:
                 phase_serve_hybrid(sz)
             elif args.phase == "serve_sparse":
                 phase_serve_sparse(sz)
+            elif args.phase == "serve_sparse_mla":
+                phase_serve_sparse_mla(sz)
             else:
                 phase_train(sz, args.phase)
         except Failed as e:

@@ -1,6 +1,7 @@
 """MoE gates: naive top-k, Switch (top-1), GShard (top-2), and the
-top-k gate over a sigmoid score with a selection bias or a softmax
-(no capacity, no drops).
+top-k gate over a sigmoid score with a selection bias or a softmax,
+over all experts or over the groups of experts it keeps first (no
+capacity, no drops).
 
 TPU-native re-design of the reference's gate zoo
 (reference: python/paddle/incubate/distributed/models/moe/gate/
@@ -97,10 +98,9 @@ class GShardGate(BaseGate):
 
 
 class SigmoidTopKGate(BaseGate):
-    """Top-k on a score of ALL ``num_experts``, weights ``scaling *
-    score / sum(chosen scores)``. No capacity: every chosen pair is
-    computed (``GatedMoELayer``). The score function is data
-    (``score_func``):
+    """Top-k on a score of ``num_experts``, weights ``scaling * score /
+    sum(chosen scores)``. No capacity: every chosen pair is computed
+    (``GatedMoELayer``). The score function is data (``score_func``):
 
     - ``"sigmoid"``: sigmoid scores, chosen on ``score + bias`` (the bias
       steers the CHOICE only: auxiliary-loss-free load balancing);
@@ -108,19 +108,43 @@ class SigmoidTopKGate(BaseGate):
       probabilities themselves, renormalised over the chosen; no bias
       (the gate then has no such parameter).
 
-    The router product, the score and the top-k run in float32 whatever
-    the model's type, because a near-tie between the k-th and the next
-    score flips an expert under bf16 rounding."""
+    So is the field the choice is made over (``n_group``,
+    ``topk_group``; 0 = off, the top-k over ALL experts): with
+    ``n_group`` > 1 the experts form that many groups of neighbours
+    (expert ``e`` lies in group ``e // (num_experts // n_group)``), a
+    group's score is the sum of its TWO largest choosing scores, the
+    ``topk_group`` groups of largest score are kept (ties to the lower
+    group), every other expert's choosing score reads 0, and the top-k
+    is taken over that (DeepSeek-V3's ``noaux_tc``). The weights still
+    come from the score itself.
+
+    The router product, the score, the group sums and every top-k run in
+    float32 whatever the model's type, because a near-tie between the
+    k-th and the next score flips an expert under bf16 rounding. All of
+    them are exact (``lax.top_k``)."""
 
     SCORE_FUNCS = ("sigmoid", "softmax")
 
     def __init__(self, d_model, num_experts, topk: int = 8,
                  routed_scaling_factor: float = 1.0,
-                 score_func: str = "sigmoid", **kw):
+                 score_func: str = "sigmoid", n_group: int = 0,
+                 topk_group: int = 0, **kw):
         super().__init__(d_model, num_experts)
         if score_func not in self.SCORE_FUNCS:
             raise ValueError(f"score_func is one of {self.SCORE_FUNCS}, "
                              f"not {score_func!r}")
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        if self.n_group > 1 and not (
+                num_experts % self.n_group == 0
+                and 1 <= self.topk_group <= self.n_group
+                and num_experts // self.n_group >= 2
+                and self.topk_group * (num_experts // self.n_group)
+                >= topk):
+            raise ValueError(
+                f"{num_experts} experts in n_group={n_group} groups of "
+                f"which topk_group={topk_group} are kept: the groups "
+                "must divide the experts, hold two or more each, and "
+                f"the kept ones must hold the {topk} chosen")
         self.top_k = topk
         self.capacity_factor = None
         self.routed_scaling_factor = float(routed_scaling_factor)
@@ -132,6 +156,11 @@ class SigmoidTopKGate(BaseGate):
         """Values in, values out: tokens [T, d] -> (expert ids [T, k]
         int32 over ALL ``num_experts``, weights [T, k] float32,
         normalised over the k chosen)."""
+        return self.route_groups(x2d)[:2]
+
+    def route_groups(self, x2d):
+        """``route`` and the groups it kept: [T, topk_group] int32 group
+        ids, None for a gate without groups."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -141,12 +170,22 @@ class SigmoidTopKGate(BaseGate):
             precision=lax.Precision.HIGHEST)
         if self.score_func == "sigmoid":
             s = jax.nn.sigmoid(logits)
-            _, idx = lax.top_k(s + self.bias._value.astype(jnp.float32),
-                               self.top_k)
+            choose = s + self.bias._value.astype(jnp.float32)
         else:
-            s = jax.nn.softmax(logits, axis=-1)
-            _, idx = lax.top_k(s, self.top_k)
+            choose = s = jax.nn.softmax(logits, axis=-1)
+        groups = None
+        if self.n_group > 1:
+            T, n = choose.shape[0], self.n_group
+            by_group = choose.reshape(T, n, self.num_experts // n)
+            score = lax.top_k(by_group, 2)[0].sum(-1)           # [T, n]
+            _, groups = lax.top_k(score, self.topk_group)
+            kept = jnp.any(groups[:, :, None] == jnp.arange(n)[None, None],
+                           axis=1)                              # [T, n]
+            choose = jnp.where(kept[:, :, None], by_group, 0.0).reshape(
+                T, self.num_experts)
+            groups = groups.astype(jnp.int32)
+        _, idx = lax.top_k(choose, self.top_k)
         sel = jnp.take_along_axis(s, idx, axis=-1)
         w = self.routed_scaling_factor * sel / jnp.sum(sel, -1,
                                                        keepdims=True)
-        return idx.astype(jnp.int32), w
+        return idx.astype(jnp.int32), w, groups

@@ -533,7 +533,9 @@ class GatedMoELayer(Layer):
     expert-parallel layer: it is TOLD which experts it holds
     (``expert_offset``, ``num_local_experts``), routes every token over
     all ``num_experts`` (``SigmoidTopKGate``, whose ``score_func`` is a
-    sigmoid with a selection bias or a softmax), and computes its own
+    sigmoid with a selection bias or a softmax, and which with
+    ``n_group`` / ``topk_group`` keeps that many groups of experts
+    first), and computes its own
     experts' part of the sum (``routed_swiglu``: batched over the held
     experts for a decode step's few tokens, where the weights' bytes set
     the time whatever is computed; sorted and grouped for a prefill's
@@ -549,7 +551,9 @@ class GatedMoELayer(Layer):
     computed and summed; then the tokens seen); when given the updated
     counter is returned beside the output. While a collection of
     ``observability.moestats`` is open the layer records, as it is
-    traced, ``choices``, ``load`` (those sizes), ``form`` and, in the
+    traced, ``choices``, ``load`` (those sizes), under a gate with groups
+    ``groups`` (the kept groups' ids [T, topk_group]) and ``group_load``
+    (the call's routed pairs by group, [n_group]), ``form`` and, in the
     sorted form, ``rows`` (the bound, the call's routed pairs) and
     ``passes`` (a device value: the windows of that many rows the call
     took; 1 unless a prompt sends the held experts more than the bound).
@@ -560,7 +564,8 @@ class GatedMoELayer(Layer):
                  expert_offset: int = 0, top_k: int = 8,
                  routed_scaling_factor: float = 1.0,
                  num_shared_experts: int = 1, weight_attr=None,
-                 down_attr=None, score_func: str = "sigmoid"):
+                 down_attr=None, score_func: str = "sigmoid",
+                 n_group: int = 0, topk_group: int = 0):
         super().__init__()
         El = num_experts if num_local_experts is None \
             else int(num_local_experts)
@@ -573,7 +578,7 @@ class GatedMoELayer(Layer):
         self.gate = SigmoidTopKGate(
             d_model, num_experts, topk=top_k,
             routed_scaling_factor=routed_scaling_factor,
-            score_func=score_func)
+            score_func=score_func, n_group=n_group, topk_group=topk_group)
         d, h, hs = d_model, d_hidden, d_hidden * num_shared_experts
         down_attr = down_attr if down_attr is not None else weight_attr
         self.w_gate = self.create_parameter((El, d, h), attr=weight_attr)
@@ -592,12 +597,17 @@ class GatedMoELayer(Layer):
         xv = x._value if isinstance(x, Tensor) else x
         shape = xv.shape
         x2d = xv.reshape(-1, self.d_model)
-        idx, w = self.gate.route(x2d)
+        idx, w, groups = self.gate.route_groups(x2d)
         y, sizes, trace = routed_swiglu(
             x2d, idx, w, self.w_gate._value, self.w_up._value,
             self.w_down._value, self.expert_offset, self.num_experts)
-        # a no-op unless a collection is open on this thread
-        _moestats.record({"choices": idx, "load": sizes, **trace})
+        if _moestats.active():
+            if groups is not None:
+                n = self.gate.n_group
+                trace = dict(trace, groups=groups, group_load=jnp.sum(
+                    (idx // (self.num_experts // n))[..., None]
+                    == jnp.arange(n), axis=(0, 1), dtype=jnp.int32))
+            _moestats.record({"choices": idx, "load": sizes, **trace})
         if self.shared:
             y = y + swiglu(x2d, self.shared_gate._value,
                             self.shared_up._value, self.shared_down._value)

@@ -80,9 +80,7 @@ Inference only: parameters are plain arrays, nothing records a tape.
 """
 from __future__ import annotations
 
-import contextlib
 import math
-import threading
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
@@ -100,7 +98,8 @@ from ..nn.layer import Layer
 from ..observability import annotate as _annotate
 from ..ops.blockwise_attention import blockwise_causal_attention
 from ..ops.pallas import decode_attention as _da
-from ..ops.sparse_attention import (index_scores, keep_topk,
+from ..ops.sparse_attention import (collect_selection, count_kept,
+                                    select_rows, selection_sink,
                                     sparse_causal_attention)
 from ..tensor import Tensor
 from .llama import _apply_rope, _dispatch_kernel
@@ -111,22 +110,6 @@ __all__ = ["HybridMoEConfig", "HybridMoEForCausalLM", "hybrid_moe_tiny",
 
 # the attention kinds a layer may name
 _KINDS = ("full", "window", "sparse")
-_selection = threading.local()
-
-
-@contextlib.contextmanager
-def collect_selection():
-    """While open on this thread, every sparse layer's PREFILL-form
-    forward appends its kept set, ``[B, S, S]`` bool (row t, key s), to
-    the list this yields, in layer order: a check's reading of what the
-    program's model selected (an S-squared array a layer: not for a
-    timed program)."""
-    _selection.kept = kept = []
-    try:
-        yield kept
-    finally:
-        _selection.kept = None
-
 
 @dataclass
 class HybridMoEConfig:
@@ -331,29 +314,6 @@ class HybridAttention(Layer):
         return (_apply_rope(iq, cos, sin, offset),
                 _apply_rope(ik, cos, sin, offset), iw)
 
-    def _count_kept(self, keep, off, counts):
-        """The step's device counter with this call's rows added to its
-        last two slots: the rows whose kept count is not ``min(t + 1,
-        index_topk)``, and the rows (``keep`` [B, S, M] at positions
-        ``off[b]..``)."""
-        t = off[:, None] + jnp.arange(keep.shape[1], dtype=jnp.int32)[None]
-        wrong = keep.sum(-1, dtype=jnp.int32) != jnp.minimum(
-            t + 1, self.cfg.index_topk)
-        return counts.at[-2:].add(jnp.stack(
-            [wrong.sum(dtype=jnp.int32), jnp.int32(wrong.size)]))
-
-    def _select(self, iq, iw, index_keys, off):
-        """The kept positions [B, S, M] of S new rows at ``off[b]..``
-        against the index keys [B, M, di] of positions 0..M-1."""
-        S, M = iq.shape[1], index_keys.shape[1]
-        with _annotate(f"{self.scope}.index"):
-            sc = index_scores(iq, index_keys, iw)
-        with _annotate(f"{self.scope}.select"):
-            qpos = off[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
-            seen = jnp.arange(M, dtype=jnp.int32)[None, None] \
-                <= qpos[:, :, None]
-            return keep_topk(sc, seen, self.cfg.index_topk)
-
     def forward(self, x, cache=None, offset=0):
         """x: values [B, S, hidden]. Returns (values [B, S, hidden],
         the cache tuple with its pooled arrays updated)."""
@@ -406,7 +366,7 @@ class HybridAttention(Layer):
             k_pool, v_pool = pools[:2]
 
         if prefill and self.sparse:
-            kept = getattr(_selection, "kept", None)
+            kept = selection_sink()
             o = sparse_causal_attention(
                 q, k, v, iq, ik[:, :, 0], iw, scale, cfg.index_topk,
                 cfg.attention_block,
@@ -428,11 +388,12 @@ class HybridAttention(Layer):
             if paged:
                 with _annotate(f"{self.scope}.index"):
                     keys = _da.gather_pages(pools[2], table)[:, 0, :, :di]
-                keep = self._select(iq, iw, keys, off)
+                keep = select_rows(iq, iw, keys, off, cfg.index_topk,
+                                   self.scope)
                 if len(cache) == n + 2:     # the step's device counter
                     with _annotate(f"{self.scope}.select"):
-                        new_cache = new_cache[:-1] + (self._count_kept(
-                            keep, off, new_cache[-1]),)
+                        new_cache = new_cache[:-1] + (count_kept(
+                            keep, off, cfg.index_topk, new_cache[-1]),)
                 with _annotate(f"{self.scope}.attend"):
                     o = _dispatch_kernel(
                         "paged_sparse_decode_attention",
@@ -445,7 +406,8 @@ class HybridAttention(Layer):
                             qp, k_pool, v_pool, table, off, scale, sinks,
                             None, keep))
             else:
-                keep = self._select(iq, iw, pools[2][:, 0, :, :di], off)
+                keep = select_rows(iq, iw, pools[2][:, 0, :, :di], off,
+                                   cfg.index_topk, self.scope)
                 M = k_pool.shape[2]
                 pos = jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32),
                                        (B, M))

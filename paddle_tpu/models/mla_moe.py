@@ -1,32 +1,62 @@
 """Latent-attention mixture-of-experts decoder, for serving.
 
 The block of the DeepSeek-V2/V3 line of models (``model_type``
-``deepseek_v2``/``deepseek_v3``, ``sarvam_mla``): multi-head latent
-attention (one ``kv_lora_rank``-wide latent and one rotated key per
-token, shared by every head), YaRN-scaled rotary positions on the
-rotated part, SwiGLU experts chosen by a sigmoid top-k router with a
-selection bias, shared experts, and leading dense layers.
+``deepseek_v2``/``deepseek_v3``/``deepseek_v32``, ``sarvam_mla``):
+multi-head latent attention (one ``kv_lora_rank``-wide latent and one
+rotated key per token, shared by every head), YaRN-scaled rotary
+positions on the rotated part, SwiGLU experts chosen by a sigmoid top-k
+router with a selection bias, shared experts, and leading dense layers.
+What the members of the line differ in is data of ``MLAMoEConfig``,
+every piece off by default; nothing asks a model's name:
+
+- ``q_lora_rank``: the query goes through a LATENT too (``x W_dq``,
+  an RMSNorm on those ``q_lora_rank`` numbers, then ``W_uq`` to the
+  heads); without it a direct ``W_q``. ``use_qk_norm`` (an RMSNorm on
+  each head's query) is a switch of its own;
+- ``index_heads`` x ``index_head_dim``, ``index_topk``: a learned INDEX
+  (``ops/sparse_attention.py`` has the equations) chooses, for every
+  query, the ``index_topk`` rows of the LATENT cache it attends to,
+  exactly; all of them while there are no more than that. Index queries
+  are projected from the query latent (from the layer's input without
+  one), ONE index key a position (a LayerNorm) and a weight an index
+  head from the layer's input; the first ``qk_rope_head_dim`` numbers of
+  every index query and key turn by the layer's own rotary tables, the
+  rest do not. ``collect_selection()`` hands a check the kept sets;
+- ``n_group`` / ``topk_group``: the router keeps that many groups of
+  experts before its top-k (``SigmoidTopKGate``);
+- ``head_on_last_row``: a prefill program hands ``forward`` the rows'
+  ``lengths`` and gets ``[B, vocab]``, the head on one row a prompt.
 
 It honours the serving contract of ``LlamaForCausalLM``:
 ``forward(input_ids, caches, offset)`` with per-layer paged tuples
-``(c_pool, r_pool, tables[, counts])``, so ``Config.enable_paged_kv`` ->
-``create_predictor`` -> ``ServingEngine`` runs it in the default mode
-(prefill buckets + the decode program). ``kv_pool_shapes`` tells the
-engine what to pool: per layer a latent pool ``[P, 1, page, d_c]`` and a
-rotated-key pool ``[P, 1, page, d_r rounded up to the lanes]`` and nothing
-per head. The forward
-takes no ``valid``: the unified ragged step (chunked prefill, and with it
-the prefix cache, host spill, speculative decoding and the
-disaggregated phases) is refused by the engine at construction.
+``(c_pool, r_pool[, index_pool], tables[, counts])``, so
+``Config.enable_paged_kv`` -> ``create_predictor`` -> ``ServingEngine``
+runs it in the default mode (prefill buckets + the decode program).
+``kv_pool_shapes`` tells the engine what to pool: per layer a latent
+pool ``[P, 1, page, d_c]`` and a rotated-key pool ``[P, 1, page, d_r
+rounded up to the lanes]`` and nothing per head; with an index, its keys
+as a third array ``[P, 1, page, index_head_dim rounded up]`` under the
+same table. The forward takes no ``valid``: the unified ragged step
+(chunked prefill, and with it the prefix cache, host spill, speculative
+decoding and the disaggregated phases) is refused by the engine at
+construction; ``key_selection`` tells it a model selects.
 
 Three attention forms, chosen at trace time:
 
 - prefill (``offset`` a concrete 0): the UNABSORBED form over the new
   positions, per-head keys and values built from the latent, causal
-  self-attention; only ``[c | k_r]`` is written to the cache;
+  self-attention (with an index: the kept sets first, ``kept_mask``,
+  then the Pallas kernel ``kept_flash_attention`` over them, every head
+  a KV head of its own, keys wider than values; off the TPU, or where
+  the rows are not whole blocks, ``sparse_causal_attention``'s loop in
+  plain ``lax``); only ``[c | k_r]`` (and the index key) is written to
+  the cache;
 - decode (one new position per row, paged cache): the ABSORBED form,
   ``q_lat = q_nope @ W_k^T`` against the latent itself, the Pallas
-  kernel ``mla_paged_decode_attention`` on TPU, its dense twin elsewhere;
+  kernel ``mla_paged_decode_attention`` on TPU, its dense twin
+  elsewhere; with an index the row's index-key pages are scored and
+  selected first and ``mla_paged_sparse_decode_attention`` walks the
+  row's pages with the kept positions as one more mask;
 - anything else (several positions at an offset, the static cache of
   ``Predictor.generate``): the absorbed form through the dense function.
 
@@ -37,6 +67,7 @@ expert-parallel layer; see ``GatedMoELayer``.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass, field
 from typing import Dict, Optional
@@ -46,6 +77,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..core.enforce import enforce
 from ..framework.param_attr import ParamAttr
 from ..incubate.distributed.models.moe import GatedMoELayer
 from ..incubate.distributed.models.moe.moe_layer import swiglu
@@ -54,11 +86,19 @@ from ..nn.container import LayerList
 from ..nn.layer import Layer
 from ..observability import annotate as _annotate
 from ..ops.pallas.decode_attention import _concrete_zero
+from ..ops.sparse_attention import (count_kept, kept_mask, select_rows,
+                                    selection_sink,
+                                    sparse_causal_attention)
 from ..tensor import Tensor
 from .llama import _apply_rope, _dispatch_kernel
 
 __all__ = ["MLAMoEConfig", "MLAMoEForCausalLM", "mla_moe_tiny",
-           "yarn_inv_freq", "yarn_mscale"]
+           "sparse_mla_tiny", "yarn_inv_freq", "yarn_mscale"]
+
+# index heads whose products stand side by side in one pass of the
+# index scores (``index_scores(head_block=)``): 16 x 512 rows x 8,192
+# keys float32 are 256 MiB
+_INDEX_HEADS_A_PASS = 16
 
 
 @dataclass
@@ -67,6 +107,7 @@ class MLAMoEConfig:
     hidden_size: int = 4096
     num_layers: int = 6
     num_heads: int = 64
+    q_lora_rank: int = 0                    # 0 = a direct W_q
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -77,10 +118,19 @@ class MLAMoEConfig:
     num_local_experts: Optional[int] = None     # held here; None = all
     expert_offset: int = 0
     num_experts_per_tok: int = 8
+    # the router keeps topk_group of n_group groups first; 0 = no groups
+    n_group: int = 0
+    topk_group: int = 0
     num_shared_experts: int = 1
     first_k_dense_replace: int = 1
     routed_scaling_factor: float = 2.5
-    use_qk_norm: bool = True
+    use_qk_norm: bool = True                # an RMSNorm a query HEAD
+    # the index that selects cache rows (module docstring); 0 = none
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    attention_block: int = 512      # rows and keys a block, with an index
+    head_on_last_row: bool = False
     max_position_embeddings: int = 4096
     rope_theta: float = 10000.0
     # YaRN as DeepSeek's ``deepseek_yarn``; None = plain rotary
@@ -94,6 +144,17 @@ class MLAMoEConfig:
     def __post_init__(self):
         if self.num_local_experts is None:
             self.num_local_experts = self.num_experts
+        if self.index_topk:
+            enforce(self.index_heads >= 1
+                    and self.index_head_dim >= self.qk_rope_head_dim,
+                    "an index needs index_heads and an index_head_dim "
+                    "that holds the qk_rope_head_dim numbers it turns")
+
+    @property
+    def index_cache_width(self) -> int:
+        """Columns of the pooled index key: whole lanes, as
+        ``rope_cache_width``."""
+        return -(-self.index_head_dim // 128) * 128
 
     @property
     def q_head_dim(self) -> int:
@@ -199,14 +260,22 @@ def _causal_attention(q, k, v, scale):
 
 
 class LatentAttention(Layer):
-    def __init__(self, cfg: MLAMoEConfig):
+    def __init__(self, cfg: MLAMoEConfig, scope: str = "attn"):
         super().__init__()
-        self.cfg = cfg
+        self.cfg, self.scope = cfg, scope
         h, H = cfg.hidden_size, cfg.num_heads
         std = cfg.initializer_range
         ones = ParamAttr(initializer=I.Constant(1.0))
-        self.q_proj = self.create_parameter((h, H * cfg.q_head_dim),
-                                            attr=_attr(std))
+        if cfg.q_lora_rank:
+            self.q_a_proj = self.create_parameter((h, cfg.q_lora_rank),
+                                                  attr=_attr(std))
+            self.q_a_norm = self.create_parameter((cfg.q_lora_rank,),
+                                                  attr=ones)
+            self.q_b_proj = self.create_parameter(
+                (cfg.q_lora_rank, H * cfg.q_head_dim), attr=_attr(std))
+        else:
+            self.q_proj = self.create_parameter((h, H * cfg.q_head_dim),
+                                                attr=_attr(std))
         self.kv_a_proj = self.create_parameter(
             (h, cfg.kv_lora_rank + cfg.qk_rope_head_dim), attr=_attr(std))
         self.kv_a_norm = self.create_parameter((cfg.kv_lora_rank,),
@@ -221,6 +290,19 @@ class LatentAttention(Layer):
             (H * cfg.v_head_dim, h),
             attr=_attr(std / math.sqrt(2 * cfg.num_layers)))
         self._rope = _rope_tables(cfg)
+        self.sparse = bool(cfg.index_topk)
+        self.n_pools = 3 if self.sparse else 2      # arrays it pools
+        if self.sparse:
+            Hi, di = cfg.index_heads, cfg.index_head_dim
+            self.index_q_proj = self.create_parameter(
+                (cfg.q_lora_rank or h, Hi * di), attr=_attr(std))
+            self.index_k_proj = self.create_parameter((h, di),
+                                                      attr=_attr(std))
+            self.index_w_proj = self.create_parameter((h, Hi),
+                                                      attr=_attr(std))
+            self.index_k_norm = self.create_parameter((di,), attr=ones)
+            self.index_k_norm_bias = self.create_parameter(
+                (di,), attr=ParamAttr(initializer=I.Constant(0.0)))
 
     def _kv_b(self):
         cfg = self.cfg
@@ -229,9 +311,73 @@ class LatentAttention(Layer):
             cfg.qk_nope_head_dim + cfg.v_head_dim)
         return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
+    def _index(self, x, cq, offset):
+        """The index of the new positions: queries [B, S, Hi, di] off
+        the query latent ``cq`` and the ONE key [B, S, 1, di] off the
+        layer's input (a LayerNorm), the first ``qk_rope_head_dim``
+        numbers of each turned by the layer's rotary tables, and the
+        heads' weights [B, S, Hi] float32 (scaled by ``Hi ** -0.5 *
+        di ** -0.5``)."""
+        cfg = self.cfg
+        B, S = x.shape[0], x.shape[1]
+        Hi, di, dr = (cfg.index_heads, cfg.index_head_dim,
+                      cfg.qk_rope_head_dim)
+        cos, sin = self._rope
+        iq = _mm(cq, self.index_q_proj._value).reshape(B, S, Hi, di)
+        ik = _mm(x, self.index_k_proj._value).astype(jnp.float32)
+        ik = ik - ik.mean(-1, keepdims=True)            # LayerNorm
+        ik = ik * lax.rsqrt((ik * ik).mean(-1, keepdims=True)
+                            + cfg.rms_norm_eps)
+        ik = (ik * self.index_k_norm._value.astype(jnp.float32)
+              + self.index_k_norm_bias._value.astype(jnp.float32)
+              ).astype(x.dtype).reshape(B, S, 1, di)
+        iw = _mm(x, self.index_w_proj._value).astype(jnp.float32) \
+            * (Hi ** -0.5 * di ** -0.5)
+        turn = lambda a: jnp.concatenate(
+            [_apply_rope(a[..., :dr], cos, sin, offset), a[..., dr:]], -1)
+        return turn(iq), turn(ik), iw
+
+    def _kept_prefill(self, q, k, v, iq, ik, iw):
+        """Causal self-attention of positions 0..S-1 over the sets the
+        index keeps, [B, S, H, dv]: on TPU the kept sets first
+        (``kept_mask``) and then ``kept_flash_attention`` over them,
+        where whole blocks of rows fill the sequence; else
+        ``sparse_causal_attention``'s loop over key blocks in plain
+        ``lax`` (the same sets, the same sums)."""
+        from ..ops.pallas import kept_attention as _ka
+
+        cfg, scale = self.cfg, self.cfg.softmax_scale
+        topk, block = cfg.index_topk, cfg.attention_block
+        names = tuple(f"{self.scope}.{part}"
+                      for part in ("index", "select", "attend"))
+        kept = selection_sink()
+
+        def kernel():
+            keep = kept_mask(iq, ik, iw, topk, block, names[:2],
+                             _INDEX_HEADS_A_PASS)
+            if kept is not None:
+                kept.append(keep)
+            with _annotate(names[2]):
+                return _ka.kept_flash_attention(q, k, v, keep, scale, block)
+
+        def loop():
+            o = sparse_causal_attention(
+                q, k, v, iq, ik, iw, scale, topk, block, scopes=names,
+                want_mask=kept is not None,
+                head_block=_INDEX_HEADS_A_PASS)
+            if kept is not None:
+                o, mask = o
+                kept.append(mask)
+            return o
+
+        return _dispatch_kernel(
+            "kept_flash_attention",
+            lambda: _ka.kept_flash_supported(q.shape, v.shape, block),
+            kernel, loop)
+
     def forward(self, x, cache=None, offset=0):
         """x: values [B, S, hidden]. Returns (values [B, S, hidden],
-        the cache tuple with its two arrays updated)."""
+        the cache tuple with its pooled arrays updated)."""
         cfg = self.cfg
         B, S = x.shape[0], x.shape[1]
         H, dc = cfg.num_heads, cfg.kv_lora_rank
@@ -239,7 +385,13 @@ class LatentAttention(Layer):
                       cfg.v_head_dim)
         scale = cfg.softmax_scale
         cos, sin = self._rope
-        q = _mm(x, self.q_proj._value).reshape(B, S, H, dn + dr)
+        if cfg.q_lora_rank:
+            cq = _rms(_mm(x, self.q_a_proj._value), self.q_a_norm._value,
+                      cfg.rms_norm_eps)
+            q = _mm(cq, self.q_b_proj._value).reshape(B, S, H, dn + dr)
+        else:
+            cq = x
+            q = _mm(x, self.q_proj._value).reshape(B, S, H, dn + dr)
         if cfg.use_qk_norm:
             q = _rms(q, self.q_norm._value, cfg.rms_norm_eps)
         q_n, q_r = q[..., :dn], _apply_rope(q[..., dn:], cos, sin, offset)
@@ -248,28 +400,38 @@ class LatentAttention(Layer):
         k_r = _apply_rope(ckr[..., None, dc:], cos, sin, offset)  # [B,S,1,dr]
         w_k, w_v = self._kv_b()
         lanes = ((0, 0),) * 3 + ((0, cfg.rope_cache_width - dr),)
+        n = self.n_pools
+        more = ()
+        if self.sparse:
+            with _annotate(f"{self.scope}.index"):
+                iq, ik, iw = self._index(x, cq, offset)
+            if cache is not None:   # the index key joins c and k_r
+                more = ((cache[2], jnp.pad(ik, ((0, 0),) * 3 + ((
+                    0, cfg.index_cache_width - ik.shape[-1]),))),)
 
-        paged = cache is not None and len(cache) >= 3
+        paged = cache is not None and len(cache) > n
         if paged:
             from ..ops.pallas.decode_attention import paged_kv_write
 
-            c_pool, r_pool, tables = cache[:3]
-            c_pool, r_pool = paged_kv_write(
-                c_pool, r_pool, c[:, :, None, :], jnp.pad(k_r, lanes),
-                tables, offset)
-            new_cache = (c_pool, r_pool, tables) + tuple(cache[3:])
+            tables = cache[n]
+            pools = paged_kv_write(
+                cache[0], cache[1], c[:, :, None, :], jnp.pad(k_r, lanes),
+                tables, offset, **({"more": more} if more else {}))
+            new_cache = pools + tuple(cache[n:])
         elif cache is not None:         # static [B, 1, M, d] caches
             off = jnp.broadcast_to(
                 jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
             dus = lambda buf, new, o: lax.dynamic_update_slice_in_dim(
                 buf, new, o, axis=1)
-            c_pool = jax.vmap(dus)(cache[0], jnp.swapaxes(
-                c[:, :, None, :], 1, 2).astype(cache[0].dtype), off)
-            r_pool = jax.vmap(dus)(cache[1], jnp.swapaxes(
-                jnp.pad(k_r, lanes), 1, 2).astype(cache[1].dtype), off)
-            new_cache = (c_pool, r_pool)
+            new_cache = pools = tuple(
+                jax.vmap(dus)(buf, jnp.swapaxes(new, 1, 2).astype(
+                    buf.dtype), off)
+                for buf, new in ((cache[0], c[:, :, None, :]),
+                                 (cache[1], jnp.pad(k_r, lanes))) + more)
         else:
-            new_cache = None
+            new_cache = pools = None
+        if pools is not None:
+            c_pool, r_pool = pools[:2]
 
         if cache is None or _concrete_zero(offset):
             # unabsorbed: per-head keys and values from the latent
@@ -281,8 +443,11 @@ class LatentAttention(Layer):
                            ).astype(x.dtype)
             k = jnp.concatenate(
                 [kv_n, jnp.broadcast_to(k_r, (B, S, H, dr))], axis=-1)
-            o = _causal_attention(jnp.concatenate([q_n, q_r], -1), k, v,
-                                  scale)
+            q = jnp.concatenate([q_n, q_r], -1)
+            if self.sparse:
+                o = self._kept_prefill(q, k, v, iq, ik[:, :, 0], iw)
+            else:
+                o = _causal_attention(q, k, v, scale)
         else:
             from ..ops.pallas import mla_attention as _ma
 
@@ -292,19 +457,43 @@ class LatentAttention(Layer):
                                preferred_element_type=jnp.float32
                                ).astype(x.dtype)
             q_r = jnp.pad(q_r, lanes)
-            if paged:
-                u = _dispatch_kernel(
-                    "mla_paged_decode_attention",
-                    lambda: S == 1 and _ma.mla_paged_supported(
-                        (B, H, dc), c_pool.shape, r_pool.shape),
-                    lambda: _ma.mla_paged_decode_attention(
-                        q_lat[:, 0], q_r[:, 0], c_pool, r_pool, tables,
-                        off, scale)[:, None],
-                    lambda: _ma.mla_paged_attention_dense(
-                        q_lat, q_r, c_pool, r_pool, tables, off, scale))
-            else:
-                u = _ma.mla_attention_dense(q_lat, q_r, c_pool[:, 0],
-                                            r_pool[:, 0], off, scale)
+            keep = None
+            if self.sparse:
+                di = cfg.index_head_dim
+                if paged:
+                    from ..ops.pallas.decode_attention import gather_pages
+
+                    with _annotate(f"{self.scope}.index"):
+                        keys = gather_pages(pools[2], tables)[:, 0, :, :di]
+                else:
+                    keys = pools[2][:, 0, :, :di]
+                keep = select_rows(iq, iw, keys, off, cfg.index_topk,
+                                   self.scope)
+                if paged and len(cache) == n + 2:   # the device counter
+                    with _annotate(f"{self.scope}.select"):
+                        new_cache = new_cache[:-1] + (count_kept(
+                            keep, off, cfg.index_topk, new_cache[-1]),)
+            # a layer that selects attends under the kept mask, by the
+            # same walk under another kernel name
+            with _annotate(f"{self.scope}.attend") if self.sparse \
+                    else contextlib.nullcontext():
+                if paged:
+                    u = _dispatch_kernel(
+                        "mla_paged_sparse_decode_attention" if self.sparse
+                        else "mla_paged_decode_attention",
+                        lambda: S == 1 and _ma.mla_paged_supported(
+                            (B, H, dc), c_pool.shape, r_pool.shape),
+                        lambda: _ma.mla_paged_decode_attention(
+                            q_lat[:, 0], q_r[:, 0], c_pool, r_pool, tables,
+                            off, scale, keep=None if keep is None
+                            else keep[:, 0])[:, None],
+                        lambda: _ma.mla_paged_attention_dense(
+                            q_lat, q_r, c_pool, r_pool, tables, off, scale,
+                            keep))
+                else:
+                    u = _ma.mla_attention_dense(
+                        q_lat, q_r, c_pool[:, 0], r_pool[:, 0], off, scale,
+                        keep)
             o = jnp.einsum("bshc,chd->bshd", u, w_v,
                            preferred_element_type=jnp.float32
                            ).astype(x.dtype)
@@ -333,7 +522,7 @@ class MLAMoEDecoderLayer(Layer):
         ones = ParamAttr(initializer=I.Constant(1.0))
         self.input_layernorm = self.create_parameter((cfg.hidden_size,),
                                                      attr=ones)
-        self.self_attn = LatentAttention(cfg)
+        self.self_attn = LatentAttention(cfg, f"layer{index}.attn.sparse")
         self.post_attention_layernorm = self.create_parameter(
             (cfg.hidden_size,), attr=ones)
         self.is_moe = index >= cfg.first_k_dense_replace
@@ -346,14 +535,19 @@ class MLAMoEDecoderLayer(Layer):
                 routed_scaling_factor=cfg.routed_scaling_factor,
                 num_shared_experts=cfg.num_shared_experts,
                 weight_attr=_attr(std),
-                down_attr=_attr(std / math.sqrt(2 * cfg.num_layers)))
+                down_attr=_attr(std / math.sqrt(2 * cfg.num_layers)),
+                n_group=cfg.n_group, topk_group=cfg.topk_group)
         else:
             self.mlp = DenseSwiGLU(cfg)
 
     def forward(self, x, cache=None, offset=0):
-        eps = self.cfg.rms_norm_eps
-        with _annotate("attention"):
-            a, cache = self.self_attn(
+        eps, attn = self.cfg.rms_norm_eps, self.self_attn
+        # a selecting layer's ops carry the scopes the sparse layers of
+        # ``hybrid_moe.py`` carry
+        with _annotate("attention"), (
+                _annotate(attn.scope) if attn.sparse
+                else contextlib.nullcontext()):
+            a, cache = attn(
                 _rms(x, self.input_layernorm._value, eps), cache=cache,
                 offset=offset)
         x = x + a
@@ -361,9 +555,15 @@ class MLAMoEDecoderLayer(Layer):
             h = _rms(x, self.post_attention_layernorm._value, eps)
             if not self.is_moe:
                 y = self.mlp(h)
-            elif cache is not None and len(cache) == 4:   # routing counter
-                y, counts = self.mlp(h, counts=cache[3])
-                y, cache = y._value, cache[:3] + (counts,)
+            elif cache is not None and len(cache) == attn.n_pools + 2:
+                # the routing counter follows the pools and their table;
+                # a selecting model's two slots follow it
+                counts, m = cache[-1], self.cfg.num_local_experts + 3
+                more = counts.shape[0] > m
+                y, moe = self.mlp(h, counts=counts[:m] if more else counts)
+                if more:
+                    moe = jnp.concatenate([moe, counts[m:]])
+                y, cache = y._value, cache[:-1] + (moe,)
             else:
                 y = self.mlp(h)._value
         return x + y, cache
@@ -392,25 +592,52 @@ class MLAMoEForCausalLM(Layer):
 
     # -- what the serving engine asks of a model -------------------------
     def kv_pool_shapes(self, P: int, page: int):
-        """Per layer, the shapes of the two pooled arrays: the latent
-        and the rotated shared key, ONE cache head each."""
+        """Per layer, the shapes of the pooled arrays: the latent and
+        the rotated shared key, ONE cache head each; with an index, its
+        one key a position as a third."""
         cfg = self.config
         return [((P, 1, page, cfg.kv_lora_rank),
                  (P, 1, page, cfg.rope_cache_width))
+                + (((P, 1, page, cfg.index_cache_width),)
+                   if cfg.index_topk else ())
                 for _ in range(cfg.num_layers)]
+
+    @property
+    def key_selection(self) -> Optional[int]:
+        """How many cache rows a query keeps; None without an index. The
+        serving engine asks: it states its refusals for such a model and
+        reports the share of rows kept."""
+        return self.config.index_topk or None
 
     def moe_counter_shape(self):
         """[layers, held experts + 3] routing counters (``GatedMoELayer``);
-        rows of dense layers stay 0."""
-        return (self.config.num_layers, self.config.num_local_experts + 3)
+        rows of dense layers stay 0. A model that selects has two slots
+        more a layer: the decode step's rows whose kept count was not
+        ``min(t + 1, index_topk)``, and its rows."""
+        return (self.config.num_layers, self.config.num_local_experts + 3
+                + (2 if self.key_selection else 0))
 
     def _empty_caches(self, B: int, max_len: int, dtype):
-        cfg = self.config
-        return [(jnp.zeros((B, 1, max_len, cfg.kv_lora_rank), dtype),
-                 jnp.zeros((B, 1, max_len, cfg.rope_cache_width), dtype))
-                for _ in range(cfg.num_layers)]
+        return [tuple(jnp.zeros((B, 1, max_len, a[-1]), dtype)
+                      for a in layer)
+                for layer in self.kv_pool_shapes(1, 1)]
 
-    def forward(self, input_ids, caches=None, offset=0):
+    @property
+    def head_on_last_row(self) -> bool:
+        """Whether a prefill program hands ``forward`` the rows'
+        ``lengths`` (``Predictor._prefill_fn`` asks)."""
+        return self.config.head_on_last_row
+
+    def _head(self, x):
+        x = _rms(x, self.norm._value, self.config.rms_norm_eps)
+        return Tensor(jnp.dot(x, self.lm_head._value,
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype), stop_gradient=True)
+
+    def forward(self, input_ids, caches=None, offset=0, lengths=None):
+        """Logits ``[B, S, vocab]``; with ``lengths`` ``[B]`` (a prefill
+        of a ``head_on_last_row`` model) ``[B, vocab]``, of row b's
+        position ``lengths[b] - 1`` alone."""
         ids = input_ids._value if isinstance(input_ids, Tensor) \
             else jnp.asarray(input_ids)
         with _annotate("mla_moe"):
@@ -422,10 +649,13 @@ class MLAMoEForCausalLM(Layer):
                     x, nc = layer(x, cache=None if caches is None
                                   else caches[i], offset=offset)
                 new_caches.append(nc)
-            x = _rms(x, self.norm._value, self.config.rms_norm_eps)
-            logits = Tensor(jnp.dot(x, self.lm_head._value,
-                                    preferred_element_type=jnp.float32
-                                    ).astype(x.dtype), stop_gradient=True)
+            if lengths is None:
+                logits = self._head(x)
+            else:
+                with _annotate("head"):
+                    last = jnp.asarray(lengths, jnp.int32) - 1
+                    logits = self._head(jnp.take_along_axis(
+                        x, last[:, None, None], axis=1)[:, 0])
         return logits if caches is None else (logits, new_caches)
 
 
@@ -444,3 +674,19 @@ def mla_moe_tiny(**kw) -> MLAMoEConfig:
                               "mscale_all_dim": 1})
     base.update(kw)
     return MLAMoEConfig(**base)
+
+
+def sparse_mla_tiny(**kw) -> MLAMoEConfig:
+    """CPU-test size with every switch of the line on: a query latent
+    (no per-head query norm), 2 index heads of 16 (8 of them turned)
+    that keep 8 cache rows over pages of 8 (contexts several times
+    that), 8 experts in 4 groups of which 2 are kept, 4 of them held
+    beside a shared one, a leading dense layer, the head on a prefill's
+    last row."""
+    base = dict(q_lora_rank=24, use_qk_norm=False, index_heads=2,
+                index_head_dim=16, index_topk=8, attention_block=16,
+                num_experts=8, num_local_experts=4, expert_offset=0,
+                num_experts_per_tok=2, n_group=4, topk_group=2,
+                head_on_last_row=True)
+    base.update(kw)
+    return mla_moe_tiny(**base)

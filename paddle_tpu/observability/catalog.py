@@ -529,9 +529,10 @@ def serving_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
             labelnames=("class", "state")),
         "kv_bytes_per_token": r.gauge(
             "paddle_tpu_serving_kv_bytes_per_context_token",
-            "pool bytes the live rows hold (their pages of every class, "
-            "written or not) over the context tokens they have so far, "
-            "at the last retired decode round", unit="By"),
+            "pool bytes the live rows hold (their pages of every class "
+            "over every pooled array of a layer, written or not) over "
+            "the context tokens they have so far, at the last retired "
+            "decode round", unit="By"),
         "window_ring_fill": r.gauge(
             "paddle_tpu_serving_window_ring_fill",
             "over the live rows of a model with window layers, the ring "
@@ -542,9 +543,10 @@ def serving_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
         "sparse_selected_share": r.gauge(
             "paddle_tpu_serving_sparse_selected_share",
             "over the live rows of a model whose attention selects keys "
-            "by a learned index, the keys a query keeps "
-            "(min(context, top-k)) over the keys of its context, summed "
-            "over the rows, at the last retired decode round",
+            "(or rows of a latent cache) by a learned index, the keys a "
+            "query keeps (min(context, top-k)) over the keys of its "
+            "context, summed over the rows, at the last retired decode "
+            "round",
             unit="ratio"),
         "prefill_tokens": r.counter(
             "paddle_tpu_serving_prefill_tokens_total",

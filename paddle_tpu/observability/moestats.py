@@ -14,6 +14,13 @@ stats aux). They cannot be fetched mid-trace, so the flow is:
 4. the fetched host values feed the ``paddle_tpu_moe_*`` gauges with
    the same one-step lag as loss/grad-norm (catalog.train_metrics).
 
+``GatedMoELayer`` (the serving-side expert layer) records through the
+same collector while one is open: ``choices`` and ``load``, ``form`` /
+``rows`` / ``passes`` of its products and, under a gate that keeps groups
+of experts first, ``groups`` (the kept groups' ids a token) and
+``group_load`` (the call's routed pairs by group, so a skewed group
+shows); ``ServingEngine`` and the benchmark's probes read those.
+
 When no collection is active (eager forwards, serving, the pipelined
 path — whose stage-masked scan would record misleading values),
 ``record()`` is a no-op, so MoE layers stay usable everywhere.

@@ -21,6 +21,17 @@ accumulator live in VMEM scratch across the page axis.
 Pools are ``[P, 1, page, d_c]`` and ``[P, 1, page, d_r]``: the page
 pool's layout with ONE cache head, so ``paged_kv_write`` and the
 engine's page programs serve them unchanged.
+
+With ``keep=`` it is the same walk for a layer whose queries attend to a
+SET of cache rows a learned index kept (``ops/sparse_attention.py``;
+kernel name ``mla_paged_sparse_decode_attention``): one more input, the
+row's kept positions
+``[npages, page]``, and a cache row takes part only where it is set as
+well, exactly. At 128 heads the absorbed form does 242 FLOP a byte of
+cache row, the v5e's ridge, so the walk pays for the rows it masks
+(passing over a page that holds no kept row cost more than it saved
+where one row in 3.6 is kept: PERF.md, PR 41). It carries its own kernel
+name in a device trace.
 """
 from __future__ import annotations
 
@@ -40,8 +51,13 @@ __all__ = ["mla_paged_decode_attention", "mla_paged_attention_dense",
 _NEG = -1e30
 
 
-def _kernel(len_ref, tbl_ref, ql_ref, qr_ref, c_ref, r_ref, o_ref, m_s,
-            l_s, acc_s, *, scale, page, npages):
+def _kernel(len_ref, tbl_ref, ql_ref, qr_ref, *refs, scale, page, npages,
+            kept=False):
+    """``kept``: one more input ahead of the pools, the row's kept
+    positions [npages, page] (1.0 | 0.0)."""
+    refs = list(refs)
+    keep_ref = refs.pop(0) if kept else None
+    c_ref, r_ref, o_ref, m_s, l_s, acc_s = refs
     j = pl.program_id(1)
     off = len_ref[pl.program_id(0)]
     j_last = off // page
@@ -65,6 +81,8 @@ def _kernel(len_ref, tbl_ref, ql_ref, qr_ref, c_ref, r_ref, o_ref, m_s,
                                preferred_element_type=jnp.float32)) * scale
         cols = j * page + lax.broadcasted_iota(jnp.int32, s.shape, 1)
         keep = cols <= off
+        if kept:
+            keep = keep & (keep_ref[0, pl.ds(j, 1), :] > 0.5)  # [1, page]
         s = jnp.where(keep, s, _NEG)
         m_prev = m_s[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -99,7 +117,7 @@ def mla_paged_supported(q_lat_shape, c_pool_shape, r_pool_shape) -> bool:
 
 def mla_paged_decode_attention(q_lat, q_rope, c_pool, r_pool,
                                block_tables, lengths, scale,
-                               interpret=False):
+                               interpret=False, keep=None):
     """Absorbed latent attention of ONE new position per row over the
     paged latent cache.
 
@@ -112,6 +130,11 @@ def mla_paged_decode_attention(q_lat, q_rope, c_pool, r_pool,
                                 the row attends positions <= lengths[b]
                                 (its own row is already written)
     scale        the softmax scale (the caller's: it carries YaRN's)
+    keep         None or [B, npages * page] bool (by position): the
+                 cache rows a row's index kept; the softmax then runs
+                 over those alone, among the rows at or before its own,
+                 and the kernel carries the name
+                 ``mla_paged_sparse_decode_attention``
 
     Returns u [B, H, d_c] = softmax(scores) @ c, in q_lat's type.
     """
@@ -128,12 +151,17 @@ def mla_paged_decode_attention(q_lat, q_rope, c_pool, r_pool,
     def row_index(b, j, ln, tb):
         return (b, 0, 0)
 
+    ins, in_specs, kw = [q_lat, q_rope], [
+        pl.BlockSpec((1, H, dc), row_index),
+        pl.BlockSpec((1, H, dr), row_index)], {}
+    if keep is not None:
+        ins.append(keep.astype(jnp.float32).reshape(B, npages, page))
+        in_specs.append(pl.BlockSpec((1, npages, page), row_index))
+        kw = {"kept": True}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, npages),
-        in_specs=[
-            pl.BlockSpec((1, H, dc), row_index),
-            pl.BlockSpec((1, H, dr), row_index),
+        in_specs=in_specs + [
             pl.BlockSpec((1, 1, page, dc), pool_index),
             pl.BlockSpec((1, 1, page, dr), pool_index),
         ],
@@ -145,22 +173,25 @@ def mla_paged_decode_attention(q_lat, q_rope, c_pool, r_pool,
         ],
     )
     return pl.pallas_call(
-        partial(_kernel, scale=float(scale), page=page, npages=npages),
+        partial(_kernel, scale=float(scale), page=page, npages=npages,
+                **kw),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dc), q_lat.dtype),
         interpret=interpret,
-        name="mla_paged_decode_attention",
+        name="mla_paged_decode_attention" if keep is None
+        else "mla_paged_sparse_decode_attention",
         **_compiler_params(1, interpret),
-    )(lengths, tbl, q_lat, q_rope, c_pool, r_pool)
+    )(lengths, tbl, *ins, c_pool, r_pool)
 
 
-def mla_attention_dense(q_lat, q_rope, c, r, lengths, scale):
+def mla_attention_dense(q_lat, q_rope, c, r, lengths, scale, keep=None):
     """The dense twin's arithmetic (and the general XLA path: any
     number S of new positions per row, any per-row offset).
 
     q_lat [B, S, H, d_c], q_rope [B, S, H, d_r] at positions
     lengths[b] .. lengths[b]+S-1; c [B, M, d_c], r [B, M, d_r] the
-    contiguous cache. Returns u [B, S, H, d_c]."""
+    contiguous cache; ``keep`` ([B, M] or [B, S, M] bool): and only the
+    positions a row's index kept. Returns u [B, S, H, d_c]."""
     B, S = q_lat.shape[0], q_lat.shape[1]
     M = c.shape[1]
     f32 = jnp.float32
@@ -169,15 +200,17 @@ def mla_attention_dense(q_lat, q_rope, c, r, lengths, scale):
                       r.astype(f32))) * scale
     off = jnp.asarray(lengths, jnp.int32).reshape(B)
     q_pos = off[:, None] + jnp.arange(S)[None, :]
-    keep = jnp.arange(M)[None, None, :] <= q_pos[:, :, None]   # [B,S,M]
-    s = jnp.where(keep[:, None], s, _NEG)
+    seen = jnp.arange(M)[None, None, :] <= q_pos[:, :, None]   # [B,S,M]
+    if keep is not None:
+        seen = seen & (keep[:, None] if keep.ndim == 2 else keep)
+    s = jnp.where(seen[:, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhsm,bmc->bshc", p, c.astype(f32)).astype(
         q_lat.dtype)
 
 
 def mla_paged_attention_dense(q_lat, q_rope, c_pool, r_pool, block_tables,
-                              lengths, scale):
+                              lengths, scale, keep=None):
     """XLA reference/fallback: gather each row's pages into a contiguous
     cache, then :func:`mla_attention_dense`."""
     B = q_lat.shape[0]
@@ -189,4 +222,4 @@ def mla_paged_attention_dense(q_lat, q_rope, c_pool, r_pool, block_tables,
             B, npages * page, pool.shape[-1])
 
     return mla_attention_dense(q_lat, q_rope, gather(c_pool),
-                               gather(r_pool), lengths, scale)
+                               gather(r_pool), lengths, scale, keep)

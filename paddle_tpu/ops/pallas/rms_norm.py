@@ -30,8 +30,17 @@ def _kernel(x_ref, w_ref, o_ref, *, eps):
                 * w_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _pick_block(T: int) -> int:
-    return pick_block(T, prefer=(256, 128, 512, 64, 32, 16, 8, 4, 2, 1))
+# elements of one block of rows: 256 rows of 4,096. Its two copies in
+# and out and the float32 pass over it fill the 16 MiB of scoped VMEM at
+# 256 rows of 7,168 (21 MiB asked, the compiler's message), so wider
+# rows take fewer of them
+_BLOCK_ELEMENTS = 256 * 4096
+
+
+def _pick_block(T: int, H: int) -> int:
+    return pick_block(T, prefer=tuple(
+        b for b in (256, 128, 512, 64, 32, 16, 8, 4, 2, 1)
+        if b * H <= _BLOCK_ELEMENTS or b == 1))
 
 
 def _rms_ref(x2, w, eps):
@@ -50,7 +59,7 @@ def rms_norm_supported(shape) -> bool:
     T = 1
     for d in shape[:-1]:
         T *= int(d)
-    return _mosaic_tileable(T, _pick_block(T), H)
+    return _mosaic_tileable(T, _pick_block(T, H), H)
 
 
 def rms_norm_dense(x, weight, eps=1e-6):
@@ -80,7 +89,7 @@ def _fwd(x, weight, eps, interpret):
     H = x.shape[-1]
     x2 = x.reshape(-1, H)
     T = x2.shape[0]
-    bt = _pick_block(T)
+    bt = _pick_block(T, H)
     kw = {"memory_space": pltpu.VMEM}
     out = pl.pallas_call(
         partial(_kernel, eps=eps),

@@ -31,7 +31,8 @@ the selection are float32.
 """
 from __future__ import annotations
 
-from typing import Optional
+import contextlib
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -39,15 +40,52 @@ from jax import lax
 
 from ..observability import annotate as _annotate
 
-__all__ = ["index_scores", "keep_topk", "sparse_causal_attention"]
+__all__ = ["index_scores", "keep_topk", "sparse_causal_attention",
+           "kept_mask", "select_rows", "count_kept", "collect_selection",
+           "selection_sink"]
 
 _NEG = -1e30
+_selection = threading.local()
 
 
-def index_scores(iq, ik, iw):
+@contextlib.contextmanager
+def collect_selection():
+    """While open on this thread, every selecting layer's PREFILL-form
+    forward appends its kept set, ``[B, S, S]`` bool (row t, key s), to
+    the list this yields, in layer order: a check's reading of what the
+    program's model selected (an S-squared array a layer: not for a
+    timed program)."""
+    _selection.kept = kept = []
+    try:
+        yield kept
+    finally:
+        _selection.kept = None
+
+
+def selection_sink():
+    """The open collection's list (a layer appends to it), else None."""
+    return getattr(_selection, "kept", None)
+
+
+def index_scores(iq, ik, iw, head_block=None):
     """``I`` of the module docstring: iq [B, S, Hi, di] against
     ik [B, M, di] with weights iw [B, S, Hi] -> [B, S, M] float32, no
-    mask applied."""
+    mask applied. ``head_block``: sum the index heads that many at a
+    time (a divisor of Hi), so that the products of all ``Hi`` heads,
+    [B, Hi, S, M] float32, never stand side by side (64 heads x 512 rows
+    x 8,192 keys are 1 GiB)."""
+    Hi = iq.shape[2]
+    if head_block and head_block < Hi:
+        n = Hi // head_block
+        heads = lambda a: jnp.moveaxis(
+            a.reshape(a.shape[:2] + (n, head_block) + a.shape[3:]), 2, 0)
+
+        def some(acc, qw):
+            return acc + index_scores(qw[0], ik, qw[1]), None
+
+        return lax.scan(some, jnp.zeros(
+            iq.shape[:2] + ik.shape[1:2], jnp.float32),
+            (heads(iq), heads(iw)))[0]
     s = jnp.einsum("bqjd,bmd->bjqm", iq, ik,
                    preferred_element_type=jnp.float32)
     w = jnp.swapaxes(iw.astype(jnp.float32), 1, 2)[..., None]
@@ -107,6 +145,31 @@ def keep_topk(scores, valid, k: int):
     return (over | ties) & valid
 
 
+def select_rows(iq, iw, index_keys, off, topk: int, scope: str):
+    """A decode step's selection: the kept positions [B, S, M] of S new
+    rows at ``off[b]..`` against the index keys [B, M, di] of positions
+    0..M-1 (a row's gathered pages, or its static cache). ``scope``: the
+    layer's, for the index and the selection in the device trace."""
+    S, M = iq.shape[1], index_keys.shape[1]
+    with _annotate(f"{scope}.index"):
+        sc = index_scores(iq, index_keys, iw)
+    with _annotate(f"{scope}.select"):
+        qpos = off[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+        seen = jnp.arange(M, dtype=jnp.int32)[None, None] \
+            <= qpos[:, :, None]
+        return keep_topk(sc, seen, topk)
+
+
+def count_kept(keep, off, topk: int, counts):
+    """A step's device counter with this call's rows added to its last
+    two slots: the rows whose kept count is not ``min(t + 1, topk)``,
+    and the rows (``keep`` [B, S, M] at positions ``off[b]..``)."""
+    t = off[:, None] + jnp.arange(keep.shape[1], dtype=jnp.int32)[None]
+    wrong = keep.sum(-1, dtype=jnp.int32) != jnp.minimum(t + 1, topk)
+    return counts.at[-2:].add(jnp.stack(
+        [wrong.sum(dtype=jnp.int32), jnp.int32(wrong.size)]))
+
+
 def _tiers(S: int, topk: int, block: int):
     """[(first row, end row, selects)]: rows under ``topk`` (whole
     blocks of them) keep everything; then tiers that double."""
@@ -120,16 +183,60 @@ def _tiers(S: int, topk: int, block: int):
     return out
 
 
+def kept_mask(iq, ik, iw, topk: int, block: int = 512, scopes=None,
+              head_block=None):
+    """The kept sets of positions 0..S-1 alone, [B, S, S] bool (row t,
+    key s): what ``sparse_causal_attention`` selects, tier by tier, for a
+    caller that attends by a kernel of its own
+    (``ops/pallas/kept_attention.py``). An S-squared array of one byte
+    an entry a layer, and no ``[heads, S, S]`` one. ``scopes``: names
+    for the index and the selection in the device trace."""
+    B, S0 = iq.shape[:2]
+    block = min(block, S0)
+    pad = -S0 % block
+    if pad:
+        rows = lambda a: jnp.pad(a, ((0, 0), (0, pad))
+                                 + ((0, 0),) * (a.ndim - 2))
+        iq, ik, iw = map(rows, (iq, ik, iw))
+    S = S0 + pad
+    names = scopes or ("index", "select")
+    parts = []
+    for a, b, selects in _tiers(S, topk, block):
+        kpos = jnp.arange(b, dtype=jnp.int32)[None, None]
+
+        def rows_block(i, a=a, E=b, selects=selects, kpos=kpos):
+            r0 = a + i * block
+            qpos = (r0 + jnp.arange(block, dtype=jnp.int32))[None, :, None]
+            keep = jnp.broadcast_to(kpos <= qpos, (B, block, E))
+            if selects:
+                with _annotate(names[0]):
+                    sc = index_scores(
+                        lax.dynamic_slice_in_dim(iq, r0, block, 1),
+                        ik[:, :E],
+                        lax.dynamic_slice_in_dim(iw, r0, block, 1),
+                        head_block)
+                with _annotate(names[1]):
+                    keep = keep_topk(sc, keep, topk)
+            return jnp.pad(keep, ((0, 0), (0, 0), (0, S - E)))
+
+        res = lax.map(rows_block, jnp.arange((b - a) // block,
+                                             dtype=jnp.int32))
+        parts.append(jnp.swapaxes(res, 0, 1).reshape(B, b - a, S))
+    mask = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    return mask[:, :S0, :S0]
+
+
 def sparse_causal_attention(q, k, v, iq, ik, iw, scale: float, topk: int,
                             block: int = 512, scopes=None,
-                            want_mask: bool = False):
+                            want_mask: bool = False, head_block=None):
     """q [B, S, H, D] against k [B, S, KV, D] and v [B, S, KV, Dv] at
     positions 0..S-1, row t attending to the keys ``keep_topk`` chooses
     among ``s <= t`` by the index (iq [B, S, Hi, di], ik [B, S, di],
     iw [B, S, Hi]). Returns [B, S, H, Dv] in q's type; with
     ``want_mask`` also the kept set [B, S, S] bool (a check's reading:
     it IS an S-squared array). ``scopes``: names for the three parts
-    (index, select, attend) in the device trace."""
+    (index, select, attend) in the device trace. ``head_block``: as
+    ``index_scores``."""
     B, S0, H, D = q.shape
     KV, Dv = k.shape[2], v.shape[-1]
     G = H // KV
@@ -156,7 +263,8 @@ def sparse_causal_attention(q, k, v, iq, ik, iw, scale: float, topk: int,
                     sc = index_scores(
                         lax.dynamic_slice_in_dim(iq, r0, block, 1),
                         ik[:, :E],
-                        lax.dynamic_slice_in_dim(iw, r0, block, 1))
+                        lax.dynamic_slice_in_dim(iw, r0, block, 1),
+                        *((head_block,) if head_block else ()))
                 with _annotate(names[1]):
                     keep = keep_topk(sc, keep, topk)
             qb = lax.dynamic_slice_in_dim(q5, r0, block, 1)

@@ -419,8 +419,8 @@ def test_the_decode_step_counts_its_own_kept_keys(model, tokens,
     that keeps every earlier key (planted) shows in it, and the routing
     counters keep their layout."""
     if planted:
-        from paddle_tpu.models import hybrid_moe
-        monkeypatch.setattr(hybrid_moe, "keep_topk",
+        from paddle_tpu.ops import sparse_attention
+        monkeypatch.setattr(sparse_attention, "keep_topk",
                             lambda scores, valid, k: valid)
     eng = engine(model, max_batch=2)
     eng.submit(tokens[:21], max_new_tokens=10)

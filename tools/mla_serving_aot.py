@@ -5,7 +5,9 @@ Builds the model of ``--config`` (``sarvam-105b``: ``MLAMoEForCausalLM``,
 the latent-attention decoder; ``mimo-v2-flash`` and ``trinity-mini``:
 ``HybridMoEForCausalLM``, window and full layers over two classes of
 pages; ``keye-vl-2.0-30b-a3b``: the same model with layers that select
-keys by a learned index and pool a third array) at the benchmark
+keys by a learned index and pool a third array; ``deepseek-v3.2-exp``:
+``MLAMoEForCausalLM`` with a query latent and an index that selects rows
+of the latent cache) at the benchmark
 configuration's widths (``benchmarks/configs/<config>.json``) with
 ``--layers`` layers (2: the
 dense layer and one expert layer) and NO weights (``LazyGuard``), takes
@@ -14,7 +16,9 @@ with the TPU compiler installed beside JAX for a DESCRIBED v5e:2x2
 topology, as ``tools/paged_write_aot.py`` does for the page-pool write.
 Per program: pool-shaped ``copy`` ops in the optimized HLO (0 = the
 pools of every page class are written in place), which of the decode
-kernels (``mla_paged_decode_attention``, ``paged_decode_attention``,
+kernels (``mla_paged_decode_attention``,
+``mla_paged_sparse_decode_attention``, the prefill's
+``kept_flash_attention``, ``paged_decode_attention``,
 ``paged_window_decode_attention``, ``paged_sparse_decode_attention``)
 and whether XLA's grouped matmul
 (``ragged-dot``) are in it (the prefill programs' expert layers sort and
@@ -120,8 +124,9 @@ def main(argv):
               for layer in eng.pools],
              i32(1))),
     }
-    kernels = ("mla_paged_decode_attention", "paged_decode_attention",
-               "paged_window_decode_attention",
+    kernels = ("mla_paged_decode_attention",
+               "mla_paged_sparse_decode_attention", "kept_flash_attention",
+               "paged_decode_attention", "paged_window_decode_attention",
                "paged_sparse_decode_attention")
     pool_shapes = {s.shape for pair in eng.pools for s in pair}
     # the held experts' stacked weights, [El, d, h] and [El, h, d] (and
@@ -149,8 +154,10 @@ def main(argv):
             "program": name,
             "pool_copies": sum(ServingEngine.pool_copies(text, s)
                                for s in pool_shapes),
+            # by the op_name of its call, not by the function-name
+            # table (a wrapper's name is there whatever was traced)
             "kernels": [k for k in kernels
-                        if re.search(rf"(?<!\w){k}(?!\w)", text)],
+                        if f"/{k}/pallas_call" in text],
             "ragged_dot": "ragged-dot" in text,
             "expert_weight_copies": sum(
                 weight_copies(text, s) for s in expert_shapes),
