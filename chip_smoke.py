@@ -984,25 +984,6 @@ def phase_serve_latent(sz: Sizes) -> None:
     from paddle_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
 
     cfg = MLAMoEConfig(**sz.latent)
-    # the prefill's attention over kept sets, keys wider than values
-    from paddle_tpu.ops.pallas import kept_attention as ka
-
-    Sk, blk = (64, 16) if sz.rehearsal else (1024, 512)
-    kq, kk, kv = rnd(1, Sk, 8, 192), rnd(1, Sk, 8, 192), rnd(1, Sk, 8, 128)
-    tri = np.tril(np.ones((Sk, Sk), bool))
-    kmask = tri & ((r.random_sample((Sk, Sk)) < 0.3) | np.eye(Sk, dtype=bool))
-    kmask[:blk] = tri[:blk]
-    kmask = jnp.asarray(kmask[None])
-    if sz.rehearsal or ka.kept_flash_supported(kq.shape, kv.shape, blk):
-        got = ka.kept_flash_attention(kq, kk, kv, kmask, 0.07, blk,
-                                      interpret=sz.rehearsal)
-        want = ka.kept_attention_dense(kq, kk, kv, kmask, 0.07)
-        err = float(jnp.abs(got.astype(jnp.float32)
-                            - want.astype(jnp.float32)).max())
-        check(err <= TOL_ATTN,
-              f"prefill attention over kept sets ({Sk} rows, 8 heads of "
-              f"192 against 128) within {TOL_ATTN} of its dense twin "
-              f"(max err {err:.2e})")
     t0 = time.perf_counter()
     paddle.set_default_dtype(cfg.dtype)
     paddle.seed(0)
@@ -1445,6 +1426,25 @@ def phase_serve_sparse_mla(sz: Sizes) -> None:
         check(err <= TOL_ATTN,
               f"latent decode kernel under a kept mask ({H} heads, "
               f"{ncols} pages a row) within {TOL_ATTN} of its dense twin "
+              f"(max err {err:.2e})")
+    # the prefill's attention over kept sets, keys wider than values
+    from paddle_tpu.ops.pallas import kept_attention as ka
+
+    Sk, blk = (64, 16) if sz.rehearsal else (1024, 512)
+    kq, kk, kv = rnd(1, Sk, 8, 192), rnd(1, Sk, 8, 192), rnd(1, Sk, 8, 128)
+    tri = np.tril(np.ones((Sk, Sk), bool))
+    kmask = tri & ((r.random_sample((Sk, Sk)) < 0.3) | np.eye(Sk, dtype=bool))
+    kmask[:blk] = tri[:blk]
+    kmask = jnp.asarray(kmask[None])
+    if sz.rehearsal or ka.kept_flash_supported(kq.shape, kv.shape, blk):
+        got = ka.kept_flash_attention(kq, kk, kv, kmask, 0.07, blk,
+                                      interpret=sz.rehearsal)
+        want = ka.kept_attention_dense(kq, kk, kv, kmask, 0.07)
+        err = float(jnp.abs(got.astype(jnp.float32)
+                            - want.astype(jnp.float32)).max())
+        check(err <= TOL_ATTN,
+              f"prefill attention over kept sets ({Sk} rows, 8 heads of "
+              f"192 against 128) within {TOL_ATTN} of its dense twin "
               f"(max err {err:.2e})")
     t0 = time.perf_counter()
     paddle.set_default_dtype(cfg.dtype)

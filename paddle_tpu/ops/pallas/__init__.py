@@ -38,12 +38,14 @@ def pick_block(n: int, prefer=(128, 256, 512, 64, 32, 16, 8)) -> int:
 _BLOCKS_LARGE = (512, 256, 128, 64, 32, 16, 8)
 
 
-def compiler_params(n_parallel: int, interpret: bool = False) -> dict:
+def compiler_params(n_parallel: int, interpret: bool = False,
+                    vmem_limit_bytes: int | None = None) -> dict:
     """kwargs for pallas_call telling Mosaic which grid axes are
     parallel — the streaming axis is 'arbitrary' (it carries a scratch
-    recurrence)."""
+    recurrence) — and, where a step holds more than the scoped default,
+    how much VMEM it may take."""
     if interpret:
         return {}
     sem = ("parallel",) * n_parallel + ("arbitrary",)
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=sem)}
+        dimension_semantics=sem, vmem_limit_bytes=vmem_limit_bytes)}

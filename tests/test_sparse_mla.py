@@ -10,6 +10,7 @@ which 2 are kept.
 import hashlib
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -358,37 +359,74 @@ def test_sparse_latent_kernel_is_its_dense_twin_and_the_unabsorbed_form(
         rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("S, block, topk", [(64, 16, 8), (96, 32, 40)])
-def test_kept_flash_kernel_is_its_dense_twin_and_the_loop(S, block, topk):
+@pytest.mark.parametrize("S, block, topk, H, late", [
+    (64, 16, 8, 4, False), (96, 32, 40, 4, False),
+    (64, 16, 8, 12, False),     # two groups of six heads a batch row
+    (64, 16, 8, 11, False),     # no divisor up to eight: a head a step
+    (32, 32, 8, 4, False),      # one block only
+    (512, 256, 64, 2, False),   # a block of two lane widths
+    (64, 16, 8, 4, True),       # key blocks with no kept key first
+    (512, 128, 64, 3, True),
+], ids=["64-16-8", "96-32-40", "heads12", "heads11", "one_block",
+        "two_lane_widths", "late_keys", "late_keys_128"])
+def test_kept_flash_kernel_is_its_dense_twin_and_the_loop(S, block, topk,
+                                                          H, late):
     """``kept_mask`` gives the sets ``sparse_causal_attention`` selects,
     and ``kept_flash_attention`` (interpreted) over them is the dense
     twin's and the loop's output: keys of 24 against values of 16, every
-    head its own."""
+    head its own, several heads a grid step under one mask tile. With
+    ``late`` the mask is cut by hand: no row past the first block keeps
+    a key of key block 0 (a row block whose whole first key block is
+    empty), and every fourth row keeps its own position alone (its
+    first blocks hold no kept key, the last one does): the dropped
+    scores are ``-inf`` over a finite floor, so nothing is NaN."""
     rng = np.random.default_rng(S)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
-    B, H, D, Dv, Hi, di = 2, 4, 24, 16, 4, 8
+    B, D, Dv, Hi, di = 2, 24, 16, 4, 8
     q, k, v = f(B, S, H, D), f(B, S, H, D), f(B, S, H, Dv)
     iq, ik, iw = f(B, S, Hi, di), f(B, S, di), f(B, S, Hi)
-    want, mask = sparse_causal_attention(q, k, v, iq, ik, iw, 0.3, topk,
-                                         block, want_mask=True)
     keep = kept_mask(iq, ik, iw, topk, block, head_block=2)
     assert keep.shape == (B, S, S) and keep.dtype == jnp.bool_
-    np.testing.assert_array_equal(np.asarray(keep), np.asarray(mask))
     np.testing.assert_array_equal(
         np.asarray(keep.sum(-1)),
         np.broadcast_to(np.minimum(np.arange(S) + 1, topk), (B, S)))
+    dense = partial(ka.kept_attention_dense, q, k, v, scale=0.3)
+    if late:
+        cut = np.asarray(keep).copy()
+        cut[:, block:, :block] = False
+        cut[:, 3::4] = False
+        cut[:, np.arange(S), np.arange(S)] = True
+        keep = jnp.asarray(cut)
+        assert not cut[:, block:2 * block, :block].any()
+        want = dense(keep=keep)
+    else:
+        want, mask = sparse_causal_attention(q, k, v, iq, ik, iw, 0.3, topk,
+                                             block, want_mask=True)
+        np.testing.assert_array_equal(np.asarray(keep), np.asarray(mask))
+        np.testing.assert_allclose(np.asarray(dense(keep=keep)),
+                                   np.asarray(want), rtol=2e-5, atol=2e-5)
     got = ka.kept_flash_attention(q, k, v, keep, 0.3, block,
                                   interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(
-        np.asarray(ka.kept_attention_dense(q, k, v, keep, 0.3)),
-        np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def test_kept_flash_gate_and_heads_a_step():
+    """The shape gate, and the heads a grid step takes from the shapes
+    alone: the largest divisor of the head count up to eight."""
     assert ka.kept_flash_supported((1, 8192, 128, 192), (1, 8192, 128, 128))
     assert not ka.kept_flash_supported((1, 8448, 128, 192),
                                        (1, 8448, 128, 128))
     assert not ka.kept_flash_supported((1, 256, 128, 192),
                                        (1, 256, 128, 128))
+    assert not ka.kept_flash_supported((1, 8192, 128, 192),
+                                       (1, 8192, 128, 128), 64)
+    heads = lambda H, D=192: ka._heads_a_step(H, 512, D, 128, 2)
+    assert [heads(H) for H in (128, 12, 11, 4, 1)] == [8, 6, 1, 4, 1]
+    assert heads(128, 1024) < 8         # wider keys: fewer heads fit
+    assert ka._vmem_bytes(8, 512, 192, 128, 2) <= ka._VMEM_BUDGET \
+        < ka._VMEM_LIMIT
 
 
 def test_the_model_s_prefill_takes_the_kernel_where_the_chip_would(
