@@ -161,8 +161,8 @@ class Sizes:
             self.page, self.max_batch = 128, 8
             self.decode_chunk, self.prefill_chunk = 8, 256
             self.new_tokens = 32
-            # one prompt per prefill bucket (128 .. 2048), then a dozen
-            self.warm_lens = (100, 200, 400, 900, 1500)
+            # one prompt per prefill bucket (64 .. 2048), then a dozen
+            self.warm_lens = (40, 100, 200, 400, 900, 1500)
             self.len_range, self.n_requests = (100, 1500), 12
             self.ref_prompt_len = 128
             # serve_latent: the latent-attention expert decoder at its
@@ -573,11 +573,40 @@ def kernel_cases(sz: Sizes):
                   f"lengths={lens.tolist()}",
                   ("paged_decode_attention",),
                   paged(B, 1, jnp.asarray(lens)), TOL_ATTN))
-    # the bucketed prefill runs the SAME paged kernel at Sq = the bucket
-    for Sb in buckets:
+    # the bucketed prefill runs the SAME paged kernel at Sq = the bucket,
+    # up to the rows over which a prompt attends to the K/V its layer has
+    # just written instead (decode_attention.paged_attention_form)
+    from paddle_tpu.ops.pallas.decode_attention import FLASH_OVER_ROWS
+
+    for Sb in [b for b in buckets if b <= FLASH_OVER_ROWS]:
         cases.append((f"paged_decode_attention prefill Sq={Sb}",
                       ("paged_decode_attention",),
                       paged(1, Sb, jnp.zeros((1,), jnp.int32)), TOL_ATTN))
+
+    def fresh(Sb, KV):
+        def build(normal):
+            from paddle_tpu.ops.attention import _sdpa_raw
+            from paddle_tpu.ops.pallas.flash_attention import \
+                flash_attention_gqa
+
+            wide = lambda a: jnp.repeat(a, H // KV, axis=2)
+            return (lambda q, k, v: flash_attention_gqa(
+                        q, k, v, interpret=interpret),
+                    lambda q, k, v: _sdpa_raw(
+                        q, wide(k), wide(v), attn_mask=None,
+                        dropout_p=0.0, is_causal=True),
+                    (normal((1, Sb, H, Ld)), normal((1, Sb, KV, Ld)),
+                     normal((1, Sb, KV, Ld))))
+
+        return build
+
+    # every query head its own KV head (this phase's model) and four to
+    # one (the benchmark's serving cells)
+    for Sb in [b for b in buckets if b > FLASH_OVER_ROWS]:
+        for KV in (H, H // 4):
+            cases.append((f"flash_attention_gqa prefill Sq={Sb} KV={KV}",
+                          ("flash_attention_fwd_gqa",), fresh(Sb, KV),
+                          TOL_ATTN))
 
     def ragged(normal):
         from paddle_tpu.ops.pallas.ragged_paged_attention import (
@@ -806,11 +835,25 @@ def phase_serve(sz: Sizes) -> None:
     import numpy as np
 
     import paddle_tpu as paddle
+    from paddle_tpu.core.bucketing import bucket
     from paddle_tpu.inference import (Config, ServingEngine,
                                       create_predictor)
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.ops.pallas import decode_attention as da
 
     cfg = LlamaConfig(**sz.llama)
+    # the form a prefill program of this model takes on the chip, by
+    # its bucket (the rehearsal's programs are all "dense")
+    def form(b):
+        return "dense" if sz.rehearsal else da.paged_attention_form(
+            (1, b, cfg.num_heads, cfg.head_dim),
+            (1, cfg.num_kv_heads, sz.page, cfg.head_dim),
+            (1, b, cfg.num_kv_heads, cfg.head_dim), 0)
+
+    warm_buckets = {bucket(n) for n in sz.warm_lens}
+    # the prompts whose first tokens are checked against the dense path:
+    # the two largest buckets (1,024 and 2,048 rows on the chip)
+    long_buckets = set(sorted(warm_buckets)[-2:])
     print(f"  Llama widths hidden={cfg.hidden_size} heads={cfg.num_heads}x"
           f"{cfg.head_dim} ffn={cfg.intermediate_size} vocab="
           f"{cfg.vocab_size}, depth {cfg.num_layers} "
@@ -858,11 +901,11 @@ def phase_serve(sz: Sizes) -> None:
         eng = ServingEngine(pred, max_batch=sz.max_batch,
                             decode_chunk=sz.decode_chunk, **kw)
         t0 = time.perf_counter()
-        for p in warm:
-            eng.submit(p, max_new_tokens=sz.new_tokens)
+        wrids = [eng.submit(p, max_new_tokens=sz.new_tokens) for p in warm]
         done = eng.run()
         t_setup = time.perf_counter() - t0
         check(len(done) == len(warm), f"warm-up mix drained ({len(done)})")
+        warm_first = [int(done[rid].new_tokens[0]) for rid in wrids]
         compiles0, xla0 = eng.stats.compiles, events.compiles
         t0 = time.perf_counter()
         rids = [eng.submit(p, max_new_tokens=sz.new_tokens) for p in mix]
@@ -891,14 +934,33 @@ def phase_serve(sz: Sizes) -> None:
         check(gap <= TOL_LOGIT,
               f"first token {tok0} scores within {TOL_LOGIT} of the "
               f"reference forward's best logit (gap {gap:.3f})")
+        # a prompt over FLASH_OVER_ROWS tokens attends to its layer's
+        # fresh K/V as flash attention, a shorter one through the pool
+        forms = eng.prefill_attention_forms()
+        if not kw:
+            check(forms == {b: form(b) for b in warm_buckets | set(forms)}
+                  and (sz.rehearsal or
+                       {"flash", "paged"} <= set(forms.values())
+                       and all(forms[b] == "flash" for b in long_buckets)),
+                  f"prefill programs attend as {forms}")
+        attend = {"flash": "flash_attention_fwd_gqa",
+                  "paged": "paged_decode_attention"}
         want = {"decode": ("paged_decode_attention", "rms_norm_fused"),
-                "prefill": ("paged_decode_attention", "rms_norm_fused"),
                 "unified": ("ragged_paged_attention", "rms_norm_fused")}
         sites = eng.program_sites()
         for site in sites:
             found = kernel_names(eng.lowered_text(site))
+            names = want.get(site[0], ())
+            if site[0] == "prefill":
+                # each kernel is jitted on its own: the text holds it
+                # once however many layers call it
+                mine = attend.get(forms[site[1]])
+                names = (mine, "rms_norm_fused")
+                check(sz.rehearsal or not any(
+                    found.get(n, 0) for n in attend.values() if n != mine),
+                    f"program {site} holds no other attention kernel")
             check(sz.rehearsal or all(found.get(n, 0) >= 1
-                                      for n in want.get(site[0], ())),
+                                      for n in names),
                   f"program {site} holds Mosaic calls {found}")
         # the page pool is written in place: no copy of a whole pool
         # (a layout change around the write) in the compiled decode or
@@ -921,6 +983,25 @@ def phase_serve(sz: Sizes) -> None:
         report[mode] = {"setup_s": round(t_setup, 1),
                         "run_s": round(t_run, 2), "pool_pages": eng.P}
         del eng      # its page pool, before the next engine builds one
+
+    # the long prompts' first tokens against the dense path: a forward
+    # with every kernel off (plain XLA attention over the whole prompt,
+    # no cache), the served token's logit beside its best
+    paddle.set_flags({"use_pallas_kernels": False})
+    dense = create_predictor(Config().set_model(model))
+    gaps = {}
+    for p, tok in zip(warm, warm_first):
+        if bucket(len(p)) in long_buckets:
+            row = dense.run([p[None, :]])[0][0, -1].astype("float32")
+            gaps[len(p)] = (float(row.max() - row[tok]),
+                            tok == int(row.argmax()))
+    paddle.set_flags({"use_pallas_kernels": True})
+    check(len(gaps) == len(long_buckets)
+          and all(g <= TOL_LOGIT for g, _ in gaps.values()),
+          f"first tokens of the prompts in the buckets "
+          f"{sorted(long_buckets)} score within {TOL_LOGIT} of the dense "
+          f"path's best logit "
+          f"(prompt length: (gap, same token) = {gaps})")
     finish_child("serve", device, events, {"modes": report})
 
 

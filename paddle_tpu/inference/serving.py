@@ -463,6 +463,9 @@ class ServingEngine:
         # "decode" | "prefill" | .. -> the forms an expert model's
         # layers traced in this engine's programs of that kind
         self._moe_forms: Dict[str, set] = {}
+        # prefill bucket -> the form its program's attention over the
+        # pool traced ("flash" | "paged" | "dense")
+        self._prefill_attention: Dict[int, str] = {}
         self._moe_rows: Dict[int, Tuple[int, int]] = {}
         self._live_peak = 0
         self.gen = cfg.generation
@@ -875,7 +878,9 @@ class ServingEngine:
         L = len(req.prompt)
         Sb = min(_bucket(L), self.M)
         with _span("serving.prefill", rid=req.rid, seq_bucket=Sb,
-                   prompt_tokens=L):
+                   prompt_tokens=L,
+                   attention=self._prefill_attention.get(
+                       Sb, "unrecorded")):
             with _span("serving.prefill.dispatch"):
                 ids = np.zeros((1, Sb), np.int32)
                 ids[0, :L] = req.prompt
@@ -1810,21 +1815,27 @@ class ServingEngine:
         site's FIRST execution also stores an XLA memory_analysis of
         the same program (lowered BEFORE the call: the cache buffers
         are donated), republished as mem gauges per execution."""
-        # an expert model's layers record the form of their products as
-        # they are traced: listen while a site runs for the first time
-        listen = site not in self._site_programs and \
-            self.cache.counters is not None
+        # a model's layers record, as they are traced, the form of their
+        # attention over the pool and of an expert layer's products:
+        # listen while a site runs for the first time
+        listen = site not in self._site_programs
         if listen:
             _moestats.begin()
         try:
             return self._run_site(site, fn, *args)
         finally:
             if listen:
-                recs = [r for r in _moestats.drain() if "form" in r]
-                self._moe_forms.setdefault(site[0], set()).update(
-                    r["form"] for r in recs)
-                if site[0] == "prefill":    # the bucket's sorted rows
-                    self._moe_rows.update(
+                recs = _moestats.drain()
+                forms = {r["form"] for r in recs if "form" in r}
+                if forms:
+                    self._moe_forms.setdefault(site[0], set()).update(forms)
+                if site[0] == "prefill":
+                    attn = {r["attention"] for r in recs
+                            if "attention" in r}
+                    if attn:
+                        self._prefill_attention[site[1]] = \
+                            "+".join(sorted(attn))
+                    self._moe_rows.update(   # the bucket's sorted rows
                         {site[1]: r["rows"] for r in recs if "rows" in r})
 
     def _run_site(self, site, fn, *args):
@@ -1969,6 +1980,20 @@ class ServingEngine:
         c = self._device_counters()
         return {"kept_keys_wrong": int(c[:, -2].sum()),
                 "rows": int(c[:, -1].sum())}
+
+    def prefill_attention_forms(self) -> Dict[int, str]:
+        """``{prefill bucket: form}``: how each prefill program this
+        engine traced attends to its prompt, as the model's layers
+        recorded it (``models/llama.py::_paged_attention``): "flash"
+        (causal flash attention over the K/V the layer has just
+        written), "paged" (the block-table kernel over the pool) or
+        "dense" (the pool's pages gathered, plain XLA). A bucket whose
+        program an earlier engine of the same predictor traced, or a
+        model that records none, is absent. The ``serving.prefill`` span
+        carries the same word as ``attention`` ("unrecorded" for an
+        absent bucket, its first prefill among them: a span's fields are
+        fixed when it opens, before that program is traced)."""
+        return dict(sorted(self._prefill_attention.items()))
 
     def moe_stats(self) -> Optional[Dict[str, Any]]:
         """Routing counters of an expert model's decode steps, fetched

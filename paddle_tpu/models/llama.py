@@ -119,16 +119,21 @@ def _apply_rope(x, cos, sin, offset):
     return x * c.astype(x.dtype) + _rot_half(x) * s.astype(x.dtype)
 
 
+def _kernels_on() -> bool:
+    """The flag and the platform: whether a dispatch site may run a
+    Pallas kernel at all."""
+    from ..core import flags as _flags
+    from ..ops.pallas import is_tpu_platform
+
+    return bool(_flags._get("use_pallas_kernels", True)) and is_tpu_platform()
+
+
 def _dispatch_kernel(name, supported, kernel, dense):
     """Pallas-kernel dispatch policy, shared by the cache/paged
     attention paths: the flag, the platform and the kernel's shape gate
     choose the lowering; the chosen one is called bare, so a kernel the
     gate admitted and Mosaic refuses fails the run."""
-    from ..core import flags as _flags
-    from ..ops.pallas import is_tpu_platform
-
-    use_kernel = (_flags._get("use_pallas_kernels", True)
-                  and is_tpu_platform() and supported())
+    use_kernel = _kernels_on() and supported()
     # the semantic scope names BOTH lowerings after the kernel, so
     # device traces show e.g. `decode_attention` over whichever ran
     with _annotate(name):
@@ -174,19 +179,35 @@ def _cache_attention(q, k_cache, v_cache, offset, S):
         lambda: _cache_attention_dense(q, k_cache, v_cache, offset, S))
 
 
-def _paged_attention(q, k_pool, v_pool, tables, lengths, S):
+def _paged_attention(q, k_pool, v_pool, tables, lengths, S, fresh=None,
+                     offset=None):
     """Paged-cache attention dispatch: Pallas block-table kernel on TPU
     (reference capability: block_multi_head_attention_kernel.cu), XLA
-    gather + ragged dense mask elsewhere."""
+    gather + ragged dense mask elsewhere. ``fresh`` = the ``(k, v)``
+    ``[B, S, KV, D]`` this call has just written at ``offset``: a
+    prefill at a concrete offset 0 attends to those directly, as causal
+    flash attention (``decode_attention.paged_attention_form`` has the
+    rule). One scope name over whichever lowering ran; the form is
+    recorded for whoever listens (``ServingEngine``)."""
+    from ..observability import moestats as _moestats
     from ..ops.pallas import decode_attention as _da
+    from ..ops.pallas.flash_attention import flash_attention_gqa
 
-    return _dispatch_kernel(
-        "paged_decode_attention",
-        lambda: _da.paged_supported(q.shape, k_pool.shape),
-        lambda: _da.paged_decode_attention(q, k_pool, v_pool, tables,
-                                           lengths),
-        lambda: _da.paged_attention_dense(q, k_pool, v_pool, tables,
-                                          lengths))
+    form = "dense"
+    if _kernels_on():
+        form = _da.paged_attention_form(
+            q.shape, k_pool.shape,
+            None if fresh is None else fresh[0].shape, offset)
+    _moestats.record({"attention": form})
+    with _annotate("paged_decode_attention"):
+        if form == "flash":
+            k, v = fresh      # in the pool's type: what a page would hold
+            return flash_attention_gqa(q, k.astype(k_pool.dtype),
+                                       v.astype(v_pool.dtype))
+        if form == "paged":
+            return _da.paged_decode_attention(q, k_pool, v_pool, tables,
+                                              lengths)
+        return _da.paged_attention_dense(q, k_pool, v_pool, tables, lengths)
 
 
 def _unified_paged_attention(q, k_pool, v_pool, tables, starts, valid):
@@ -298,7 +319,7 @@ class LlamaAttention(Layer):
                         qv, k_pool, v_pool, tables[:, :-1], off, nv)
                 else:
                     ov = _paged_attention(qv, k_pool, v_pool, tables,
-                                          off, S)
+                                          off, S, (kv_, vv), offset)
                 out = Tensor(ov.reshape(B, S, n_local * D),
                              stop_gradient=True)
                 return self.o_proj(out), (k_pool, v_pool, tables)

@@ -21,6 +21,10 @@ of experts first, ``groups`` (the kept groups' ids a token) and
 ``group_load`` (the call's routed pairs by group, so a skewed group
 shows); ``ServingEngine`` and the benchmark's probes read those.
 
+A Llama-family attention layer records ``attention``, the form its
+attention over the page pool took (``models/llama.py``), which
+``ServingEngine.prefill_attention_forms`` keeps a prefill bucket.
+
 When no collection is active (eager forwards, serving, the pipelined
 path — whose stage-masked scan would record misleading values),
 ``record()`` is a no-op, so MoE layers stay usable everywhere.

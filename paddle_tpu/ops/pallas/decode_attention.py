@@ -412,6 +412,49 @@ def paged_supported(q_shape, pool_shape, v_shape=None) -> bool:
     return Sq * (H // KV) <= 2048
 
 
+# A prefill at offset 0 of more rows than this attends to its layer's
+# fresh K/V as flash attention even where ``paged_supported`` admits the
+# shape. On a v5e at 32 query heads on 8 KV heads of 128 and a table of 19
+# pages the flash form alone is the faster at every bucket its gate
+# admits, us a call, paged | flash (``tools/paged_attention_timing.py
+# --prefill``; my chip runs, PR 43): 32.7 | 22.8 at 128 rows, 61.0 | 34.2
+# at 256, 166.7 | 69.4 at 512. The buckets the paged kernel serves keep
+# it all the same: they are a few percent of a serving cell's busy time,
+# and the chat cell's warm ``setup_s`` read +11% with every bucket on the
+# flash kernel against +5.6% with two (readings that a capped compile
+# cache shared by four trees confounds: PERF.md section 6 has both
+# sides). Lower it once a clean reading shows no set-up cost.
+FLASH_OVER_ROWS = 512
+
+
+def paged_attention_form(q_shape, pool_shape, fresh_shape=None, offset=None,
+                         valid=None) -> str:
+    """The form a layer's attention over the page pool takes where the
+    kernels are on (a dispatch site runs "dense" wherever they are not),
+    from what a trace can see:
+
+    - ``"flash"``: ``flash_attention.flash_attention_gqa`` over the
+      ``[B, S, KV, D]`` K/V the call has just written (``fresh_shape``),
+      where ``offset`` is a concrete 0 (``_concrete_zero``: the row's
+      cache then holds nothing the call did not write itself), no
+      ``valid`` metadata came, that kernel's gate admits the shape, and
+      the prompt has more than ``FLASH_OVER_ROWS`` rows or the paged
+      kernel refuses it. Rows past a prompt's end lie after every real
+      row, so under the causal mask no real row sees them, through the
+      pool or not.
+    - ``"paged"``: ``paged_decode_attention`` (``paged_supported``).
+    - ``"dense"``: ``paged_attention_dense``."""
+    from .flash_attention import flash_gqa_supported
+
+    paged = paged_supported(q_shape, pool_shape)
+    fresh = (fresh_shape is not None and valid is None
+             and _concrete_zero(offset)
+             and flash_gqa_supported(q_shape, fresh_shape))
+    if fresh and (q_shape[1] > FLASH_OVER_ROWS or not paged):
+        return "flash"
+    return "paged" if paged else "dense"
+
+
 @partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            scale=None, interpret=False, sinks=None,

@@ -49,7 +49,8 @@ from . import (_BLOCKS_LARGE as _BLOCKS, compiler_params as
                _compiler_params, pick_block as _pick_block)
 
 __all__ = ["flash_attention_fwd", "flash_attention_with_lse",
-           "flash_attention_bwd", "flash_supported"]
+           "flash_attention_bwd", "flash_supported",
+           "flash_attention_gqa", "flash_gqa_supported"]
 
 _VMEM = pltpu.VMEM
 
@@ -544,3 +545,159 @@ def flash_attention_bwd(q, k, v, out, lse, g, causal=False, scale=None,
     cotangent ``g`` of ``out``: the two backward kernels, no forward."""
     return _fa_bwd(causal, scale, interpret,
                    (q, k, v, out, lse, q_segment_ids, kv_segment_ids), g)[:3]
+
+
+# ---------------------------------------------------------------------------
+# Causal forward for grouped-query heads, no backward (a prefill program's
+# attention of a prompt to itself: models/llama.py)
+# ---------------------------------------------------------------------------
+
+_GQA_MAX_HEADS = 8          # query heads a grid step (unrolled)
+_GQA_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def _gqa_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
+                acc_s, *, scale, D):
+    """One block pair at or under the diagonal for the ``hs`` query heads
+    of a step, all under ONE K/V tile. q / o blocks are ``[block, hs *
+    D]`` (a head is a lane-aligned column slice), k / v ``[block, D]``.
+    The heads are unrolled with the next head's score product issued
+    before this head's softmax (the MXU beside the VPU), the row sums
+    stay per-lane until a row block's last step, and a masked score is
+    ``-inf`` over a finite running maximum (``kept_attention.py`` has
+    the readings of each); only the diagonal block is masked at all."""
+    t = pl.program_id(2)
+    i, j = qi_ref[t], kj_ref[t]
+    hs, block, lanes = l_s.shape
+
+    @pl.when(j == 0)            # a row block's first step
+    def _():
+        m_s[...] = jnp.full_like(m_s, _NEG)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    def step(diagonal):
+        kb, vb = k_ref[0], v_ref[0]
+
+        def product(g):
+            return lax.dot_general(
+                q_ref[0, :, g * D:(g + 1) * D], kb,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        if diagonal:            # rows and keys of one block: local indices
+            keep = lax.broadcasted_iota(jnp.int32, (block, block), 0) \
+                >= lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        s_next = product(0)
+        for g in range(hs):
+            s = s_next
+            if g + 1 < hs:
+                s_next = product(g + 1)
+            s = s * scale
+            if diagonal:
+                s = jnp.where(keep, s, -jnp.inf)
+            m_prev = m_s[g]                             # lane-replicated
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            p = jnp.exp(s - m_new[:, :1])
+            corr = jnp.exp(m_prev - m_new)
+            part = p[:, :lanes]                 # per-lane partial row sums
+            for w in range(1, block // lanes):
+                part = part + p[:, w * lanes:(w + 1) * lanes]
+            l_s[g] = l_s[g] * corr + part
+            acc_s[g] = acc_s[g] * corr[:, :1] + lax.dot_general(
+                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_s[g] = m_new
+
+    @pl.when(j < i)
+    def _():
+        step(False)
+
+    @pl.when(j == i)            # the diagonal block: a row block's last
+    def _():
+        step(True)
+        for g in range(hs):
+            l = jnp.sum(l_s[g], -1, keepdims=True)  # > 0: a row sees itself
+            o_ref[0, :, g * D:(g + 1) * D] = (acc_s[g] / l).astype(
+                o_ref.dtype)
+
+
+def _gqa_block(S: int) -> int:
+    return _pick_block(S, prefer=_BLOCKS)
+
+
+def flash_gqa_supported(q_shape, k_shape) -> bool:
+    """Mosaic shape gate of :func:`flash_attention_gqa`: self-attention
+    (as many keys as rows) in whole blocks of at least 128 rows, a head
+    that fills the 128 lanes, whole groups of query heads a KV head."""
+    B, S, H, D = q_shape
+    return (k_shape[1] == S and _gqa_block(S) >= 128 and D % 128 == 0
+            and k_shape[3] == D and H % k_shape[2] == 0)
+
+
+@partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def flash_attention_gqa(q, k, v, scale=None, block=None, interpret=False):
+    """Causal self-attention of q [B, S, H, D] over k, v [B, S, KV, D]
+    (query head h reads KV head ``h // (H / KV)``; row t sees keys
+    ``<= t``), forward only: [B, S, H, D] in q's type. Products in the
+    input type with float32 accumulation, float32 softmax statistics.
+
+    No transposed copy and no widened K/V: the arrays are read as ``[B,
+    S, heads * D]`` (a reshape), a step's q / o block is the ``hs`` heads'
+    columns of a row block and its K/V tile ONE KV head's, shared by the
+    ``hs`` query heads of the step (``hs``: the largest divisor of the
+    group up to 8). The grid is ``(B, H / hs, n (n + 1) / 2)``: the block
+    pairs at or under the diagonal alone, from two scalar-prefetched
+    tables, as ``kept_flash_attention``. ``block`` (rows and keys): the
+    largest of 512, 256, 128 that divides ``S``; alone on a v5e at ``[1,
+    2048, 32, 128]`` on 8 KV heads 512 read 461 us a call (38% of the
+    MXU by the causal half of ``4 S^2 D H``), 256 read 590, 128 read
+    1,127, and ``flash_attention_fwd`` over K/V widened to the query
+    heads, layout copies and all, 720 (my chip run, PR 43).
+
+    Jitted on its own, as ``paged_decode_attention``: a prefill program
+    calls it once a layer with the same shapes, and then traces and
+    lowers it once (unjitted, 16 layers of two buckets added 5 s to a
+    warm set-up; my chip run, PR 43)."""
+    B, S, H, D = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    if scale is None:
+        scale = 1.0 / np.sqrt(D)
+    if block is None:
+        block = _gqa_block(S)
+    if not flash_gqa_supported(q.shape, k.shape) or S % block:
+        raise ValueError("flash_attention_gqa: no block tiles shape "
+                         f"{q.shape}/{k.shape}")
+    hs = next(g for g in range(min(G, _GQA_MAX_HEADS), 0, -1) if G % g == 0)
+    lanes = min(block, 128)
+    qi, kj = np.tril_indices(S // block)
+    rows = lambda b, h, t, qi, kj: (b, qi[t], h)
+    keys = lambda b, h, t, qi, kj: (b, kj[t], h * hs // G)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, H // hs, len(qi)),
+        in_specs=[
+            pl.BlockSpec((1, block, hs * D), rows),
+            pl.BlockSpec((1, block, D), keys),
+            pl.BlockSpec((1, block, D), keys),
+        ],
+        out_specs=pl.BlockSpec((1, block, hs * D), rows),
+        scratch_shapes=[
+            pltpu.VMEM((hs, block, lanes), jnp.float32),
+            pltpu.VMEM((hs, block, lanes), jnp.float32),
+            pltpu.VMEM((hs, block, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        partial(_gqa_kernel, scale=float(scale), D=D),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
+        interpret=interpret,
+        name="flash_attention_fwd_gqa",
+        **_compiler_params(2, interpret,
+                           vmem_limit_bytes=_GQA_VMEM_LIMIT),
+    )(jnp.asarray(qi, jnp.int32), jnp.asarray(kj, jnp.int32),
+      q.reshape(B, S, H * D), k.reshape(B, S, KV * D),
+      v.reshape(B, S, KV * D))
+    return out.reshape(B, S, H, D)

@@ -183,7 +183,7 @@ def _parent(prog, s):
 SERVING_FIELDS = {
     "serving.step": {"active", "queued"},
     "serving.admit": {"free_slots"},
-    "serving.prefill": {"rid", "seq_bucket", "prompt_tokens"},
+    "serving.prefill": {"rid", "seq_bucket", "prompt_tokens", "attention"},
     "serving.prefill.dispatch": set(),
     "serving.prefill.fetch": set(),
     "serving.launch": {"round", "rows", "overlapped"},

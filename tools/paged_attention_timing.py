@@ -20,7 +20,16 @@ same file times an older tree:
   free slots (the chat cell's shape of call: a lookahead that stopped
   at a row's last page would change nothing here);
 - ``prefill_<Sb>``: one row of Sb new tokens at offset 0, the prefill
-  programs' call.
+  programs' call, in every form a program may hold (PR 43;
+  ``decode_attention.paged_attention_form``), one reading each, all
+  checked against the dense one: ``paged`` (the kernel over the pool,
+  where its gate admits the bucket), ``dense`` (the pages gathered,
+  float32 scores in HBM), ``flash`` (``flash_attention_fwd`` over the
+  fresh K/V widened to the query heads, layout copies and all) and
+  ``flash_gqa`` (``flash_attention_gqa`` over the fresh K/V as they
+  are; ``--gqa-block`` adds readings at other block sizes). A flash
+  reading carries its share of the MXU's peak by the causal half of
+  ``4 S^2 D H`` operations.
 
 Every reading carries the kernel's plan for its shapes (``plan``: KV
 heads a fetch, VMEM slots a pool, bytes in flight beside the page being
@@ -78,8 +87,7 @@ def shapes(r):
     yield one_page_half_free(r)
     yield "longprompt", 1, r.randint(1100, 2100, 16).astype(np.int32)
     yield "full", 1, r.randint(2200, 2400, 48).astype(np.int32)
-    for Sb in (64, 512):
-        yield f"prefill_{Sb}", Sb, np.zeros(1, np.int32)
+    yield "prefill_64", 64, np.zeros(1, np.int32)
 
 
 def pages_seen(ctx, window):
@@ -107,6 +115,17 @@ def window_shapes(r, window):
     mix = np.clip(np.exp(r.normal(np.log(2048), 1.0, ROWS)), 256, 8192)
     yield "window_mix", 1, (mix + r.randint(0, 512, ROWS)).astype(np.int32)
     yield one_page_half_free(r, ROWS)
+
+
+def _best(prog, *args):
+    """Seconds a scan step: the best of ``CALLS`` calls of ``STEPS``."""
+    prog(*args).block_until_ready()
+    best = float("inf")
+    for _ in range(CALLS):
+        t0 = time.perf_counter()
+        prog(*args).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return best / STEPS
 
 
 def reading(name, Sq, lens, r, peak, window=None):
@@ -141,24 +160,81 @@ def reading(name, Sq, lens, r, peak, window=None):
 
         return lax.scan(body, q, None, length=STEPS)[0]
 
-    prog(q, kp, vp).block_until_ready()
-    best = float("inf")
-    for _ in range(CALLS):
-        t0 = time.perf_counter()
-        prog(q, kp, vp).block_until_ready()
-        best = min(best, time.perf_counter() - t0)
+    us = _best(prog, q, kp, vp) * 1e6
     pages = int(pages_seen(lens + Sq - 1, window).sum())
     plan = getattr(da, "_paged_plan", None)     # not on an older tree
     if plan is not None:
         plan = plan(Sq, H // KV, KV, PAGE, D, 2, DV)._asdict()
     nbytes = pages * KV * PAGE * (D + DV) * 2
-    us = best / STEPS * 1e6
     print(json.dumps({
         "shape": name, "rows": B, "Sq": Sq, "kv_heads": KV, "q_heads": H,
         "kv_width": D, "window": window, "us_per_call": round(us, 1),
         "pages_referenced": pages, "us_per_page": round(us / pages, 3),
         "hbm_share_pct": round(100 * nbytes / (us * 1e-6) / peak, 1),
         "max_err_vs_dense": round(err, 4), "plan": plan}), flush=True)
+
+
+def prefill_readings(Sb, r, peaks, blocks=()):
+    """One row of ``Sb`` prompt tokens at offset 0 in each form a
+    prefill program may hold. Every array is an ARGUMENT of the jitted
+    program: closed over, a pool is a constant the compiler folds for a
+    minute a form (PR 42)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    q = jnp.asarray(r.randn(1, Sb, H, D), jnp.bfloat16)
+    k = jnp.asarray(r.randn(1, Sb, KV, D), jnp.bfloat16)
+    v = jnp.asarray(r.randn(1, Sb, KV, D), jnp.bfloat16)
+    tbl = jnp.asarray(r.permutation(P - 1)[:NPAGES].reshape(1, NPAGES),
+                      jnp.int32)
+    zero = jnp.zeros((1,), jnp.int32)
+    kp, vp = da.paged_kv_write(
+        jnp.asarray(r.randn(P, KV, PAGE, D), jnp.bfloat16),
+        jnp.asarray(r.randn(P, KV, PAGE, D), jnp.bfloat16), k, v, tbl, 0)
+    G = H // KV
+
+    def widened(q, k, v, kp, vp, tbl):
+        kk, vv = (jnp.repeat(a, G, axis=2) for a in (k, v))
+        return fa.flash_attention_fwd(q, kk, vv, True, None, False)
+
+    forms = {"dense": lambda q, k, v, kp, vp, tbl:
+             paged_attention_dense(q, kp, vp, tbl, zero)}
+    if da.paged_supported(q.shape, kp.shape):
+        forms["paged"] = lambda q, k, v, kp, vp, tbl: \
+            paged_decode_attention(q, kp, vp, tbl, zero)
+    if fa.flash_supported(q.shape, (1, Sb, H, D)):
+        forms["flash"] = widened
+    gqa = getattr(fa, "flash_attention_gqa", None)  # not on an older tree
+    if gqa is not None and fa.flash_gqa_supported(q.shape, k.shape):
+        forms["flash_gqa"] = lambda q, k, v, kp, vp, tbl: gqa(q, k, v)
+        for b in blocks:
+            if b < Sb and Sb % b == 0:
+                forms[f"flash_gqa_{b}"] = \
+                    lambda q, k, v, kp, vp, tbl, b=b: gqa(q, k, v, block=b)
+    args = (q, k, v, kp, vp, tbl)
+    want = jax.jit(forms["dense"])(*args).astype(jnp.float32)
+    for name, form in forms.items():
+        got = jax.jit(form)(*args).astype(jnp.float32)
+
+        @jax.jit
+        def prog(q, k, v, kp, vp, tbl, form=form):
+            def body(q, _):
+                o = form(q, k, v, kp, vp, tbl)
+                return (q + o * 1e-3).astype(q.dtype), None
+
+            return lax.scan(body, q, None, length=STEPS)[0]
+
+        us = _best(prog, *args) * 1e6
+        line = {"shape": f"prefill_{Sb}", "form": name, "Sq": Sb,
+                "kv_heads": KV, "q_heads": H, "table_pages": NPAGES,
+                "us_per_call": round(us, 1),
+                "max_err_vs_dense": round(
+                    float(jnp.abs(got - want).max()), 4),
+                "mean_err_vs_dense": round(
+                    float(jnp.abs(got - want).mean()), 6)}
+        if name.startswith("flash"):
+            line["mxu_share_pct"] = round(
+                100 * 2 * Sb * Sb * D * H / (us * 1e-6) / peaks.flops, 1)
+        print(json.dumps(line), flush=True)
 
 
 def main():
@@ -168,20 +244,29 @@ def main():
     ap.add_argument("--kv-width", type=int, default=D)
     ap.add_argument("--kv-heads", type=int, default=KV)
     ap.add_argument("--q-heads", type=int, default=H)
+    ap.add_argument("--prefill", type=int, nargs="*", default=None,
+                    help="time only the prefill forms at these buckets "
+                         "(none named: 128 256 512 1024 2048)")
+    ap.add_argument("--gqa-block", type=int, nargs="*", default=(),
+                    help="further block sizes of flash_attention_gqa")
     args = ap.parse_args()
     KV, H, D = args.kv_heads, args.q_heads, args.kv_width
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(json.dumps({"error": f"needs a TPU, found {dev.platform}"}))
         return 1
-    peak = peaks_for(dev.device_kind).hbm_bytes
+    peaks = peaks_for(dev.device_kind)
     print(json.dumps({"device": dev.device_kind, "steps": STEPS}),
           flush=True)
     r = np.random.RandomState(0)
     cases = shapes(r) if args.window is None \
         else window_shapes(r, args.window)
-    for name, Sq, lens in cases:
-        reading(name, Sq, lens, r, peak, args.window)
+    if args.prefill is None:
+        for name, Sq, lens in cases:
+            reading(name, Sq, lens, r, peaks.hbm_bytes, args.window)
+    if args.window is None and D == DV:
+        for Sb in args.prefill or (128, 256, 512, 1024, 2048):
+            prefill_readings(Sb, r, peaks, args.gqa_block)
     return 0
 
 

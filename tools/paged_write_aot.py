@@ -35,6 +35,9 @@ CASES = [
     ("prefill_1x512", 1, 512, 128, "bfloat16", "kernel"),
     ("prefill_1x64", 1, 64, 128, "bfloat16", "kernel"),
     ("prefill_1x2048_dense", 1, 2048, 128, "bfloat16", "dense"),
+    # what a prompt over 512 tokens runs since PR 43: the pages written,
+    # the attention over the fresh K/V and not through the pool
+    ("prefill_1x2048_flash", 1, 2048, 128, "bfloat16", "flash"),
     ("decode_page64", 48, 1, 64, "bfloat16", "kernel"),
     ("decode_page16_f32", 48, 1, 16, "float32", "kernel"),
     ("decode_page8_f32", 48, 1, 8, "float32", "kernel"),
@@ -65,6 +68,7 @@ def main(argv):
         return 0
     from paddle_tpu.inference.serving import ServingEngine
     from paddle_tpu.ops.pallas import decode_attention as da
+    from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas import ragged_paged_attention as ra
 
     write = da.paged_kv_write
@@ -83,7 +87,8 @@ def main(argv):
         pools = P * 128 // page           # the same bytes at every page
         prefill = S > 1
         kernel = {"kernel": "paged_decode_attention",
-                  "ragged": "ragged_paged_attention"}.get(attn)
+                  "ragged": "ragged_paged_attention",
+                  "flash": "flash_attention_fwd_gqa"}.get(attn)
 
         one_array = name.endswith("_round")
 
@@ -100,9 +105,13 @@ def main(argv):
                 else:
                     kp, vp = write(kp, vp, new, new, tables,
                                    0 if prefill else off)
-                    attend = (da.paged_decode_attention if attn == "kernel"
-                              else da.paged_attention_dense)
-                    o = attend(q, kp, vp, tables, off)
+                    if attn == "flash":
+                        o = fa.flash_attention_gqa(q, new, new)
+                    else:
+                        attend = (da.paged_decode_attention
+                                  if attn == "kernel"
+                                  else da.paged_attention_dense)
+                        o = attend(q, kp, vp, tables, off)
                 acc = acc + o.astype(jnp.float32)
                 done.append((kp, vp))
             return acc, done
