@@ -6,81 +6,49 @@ calls, at the full width of models the repo supports, on one TPU chip:
   kernels  every Pallas kernel compiled by Mosaic (never interpreted) at
            the shapes the next two phases use, against the dense function
            that sits beside it, flash backward included;
-  train    GPT-3 1.3B (vocab 50304, hidden 2048, 24 layers, 16 heads of
-           128, S=1024, bf16 params and moments, B=4) through
+  train    GPT-3 1.3B (S=1024, B=4, bf16 params and moments) through
            ``fleet.init`` -> ``ParallelEngine.train_step``;
-  serve    Llama-7B widths (hidden 4096, 32 heads of 128, ffn 11008,
-           vocab 32000), depth cut to fit one chip, through
+  serve    Llama-7B widths, depth cut to fit one chip, through
            ``Config.enable_paged_kv`` -> ``create_predictor`` ->
            ``ServingEngine`` in both of its modes.
 
-``--phases serve_latent`` (only when named) serves the latent-attention
-expert decoder (models/mla_moe.py) at its published widths and two
-layers through the same entry points: latent pools written in place,
-``mla_paged_decode_attention`` in the decode program, 0 dropped pairs.
+Every serving phase is one body, ``serve_case``, over an entry of
+``SERVE_CASES``, which holds what differs. Four run only when named in
+``--phases``: ``serve_latent`` (latent attention + routed experts),
+``serve_hybrid`` (window and full attention layers: MiMo-V2's block, then
+the AFMoE block), ``serve_sparse`` and ``serve_sparse_mla`` (attention
+over the keys, or the latent cache rows, a learned index keeps). Each
+holds its decode kernels against their dense twins, its pooled arrays
+written in place and its kernel by name in the decode program; ``Sizes``
+says what each is cut to and why.
 
-``--phases serve_hybrid`` (only when named) serves the decoder with
-window and full attention layers (models/hybrid_moe.py) at its published
-widths and three layers the same way, in its two shapes one after the
-other (MiMo-V2's block: keys 192 against values 128, a window of 128
-with a sink, a ring of 2 pages; then the AFMoE block: every switch on, a
-window of 2,048 without rotary on the full layer, a ring of 17 pages, a
-shared expert, the whole 200,192-row vocabulary with the head on a
-prefill's last row): both decode kernels against their dense twins on
-the chip, 40 decode rounds past a ring's wrap, two classes of pools
-written in place.
-
-``--phases serve_sparse`` (only when named) serves the tiny preset of
-the decoder whose attention keeps the keys a learned index chooses
-(``sparse_moe_tiny`` with head widths the chip tiles: heads of 128, index
-heads of 64, the 256 best keys of contexts to 1,000) the same way: the
-decode kernel under a kept mask against its dense twin, the kept count
-of every row of a full forward (``kept_keys_wrong`` 0) and of every row
-the decode steps selected for (their own count on the device), three
-pooled arrays a layer written in place and
-``paged_sparse_decode_attention`` in the decode program.
-
-``--phases serve_sparse_mla`` (only when named) serves the tiny preset
-of the latent-attention decoder whose index selects rows of the LATENT
-cache (``sparse_mla_tiny`` with widths the chip tiles: a 128-wide
-latent, index heads of 128, the 256 best rows of contexts to 1,000, 8
-experts in 4 groups of which 2 are kept) the same way:
-``mla_paged_sparse_decode_attention`` and ``kept_flash_attention``
-against their dense twins, ``kept_keys_wrong`` 0 in a full forward and in
-the decode steps' own count, three pooled arrays a layer written in
-place and the kernel by name in the decode program.
-
-``--four-chips`` adds the same train step over a real 2x2 mesh in two
-layouts (mp2 x dp2 on ParallelEngine; pp2 x mp2 on GPTForCausalLMPipe via
-``fleet.distributed_model(...).train_batch``); asked for, fewer than four
-TPU devices is an error.
+``--four-chips`` adds the train step over a real 2x2 mesh in two layouts
+(mp2 x dp2 on ParallelEngine; pp2 x mp2 on GPTForCausalLMPipe via
+``fleet.distributed_model(...).train_batch``), or fails under four TPUs.
 
     python chip_smoke.py                        # one chip
     python chip_smoke.py --four-chips           # one four-chip host
     python -m paddle_tpu.distributed.launch chip_smoke.py --four-chips
     python chip_smoke.py --phases kernels       # a subset, in order
 
-Process model: a chip belongs to one process at a time and HBM is only
-reliably released at process exit, so every phase is a child process and
-this parent never touches JAX. A child that finds no TPU exits non-zero
-and says which platform it found; the parent then prints no result.
-
-The last line of stdout on success is one JSON object,
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``
-with the device as JAX reported it to the children. Any failed check, in
-any phase, is a non-zero exit and no such line.
+A chip belongs to one process at a time and HBM is only reliably released
+at process exit, so every phase is a child process and this parent never
+touches JAX. A child that finds no TPU exits non-zero. The last line of
+stdout on success is ``{"ok": true, "device": {"platform": "tpu", "kind":
+..., "count": ...}}`` with the device as JAX reported it to the children;
+any failed check, in any phase, is a non-zero exit and no such line.
 
 ``--rehearsal`` is for whoever types it: the same phases at toy sizes on
 the CPU (kernels in interpret mode, four virtual devices), to debug this
-script without spending chip time. Its output says REHEARSAL on every
-phase and in the result line. The program never chooses it.
-
-The step times and token rates printed here name the device beside them
-and are not metrics: nothing is claimed from them.
+script without chip time. Its output says REHEARSAL on every phase and in
+the result line; the program never chooses it. The times and rates
+printed name their device and are not metrics.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import importlib
 import json
 import math
 import os
@@ -88,15 +56,16 @@ import subprocess
 import sys
 import threading
 import time
+from typing import Callable, Optional
 
 ONE_CHIP_PHASES = ("kernels", "train", "serve")
 FOUR_CHIP_PHASES = ("mp2dp2", "pp2mp2")
 # run only when named in --phases: the default three fill their time limit
 EXTRA_PHASES = ("serve_latent", "serve_hybrid", "serve_sparse",
                 "serve_sparse_mla")
-# seconds per child, compilation included. The one-chip three sum to
-# 1100, inside the 1200 s that run is allowed; measured cold on a v5e
-# they took 72, 122 and 106 s (CHANGES.md PR 21).
+ALL_PHASES = ONE_CHIP_PHASES + FOUR_CHIP_PHASES + EXTRA_PHASES
+# seconds per child, compilation included. The one-chip three sum to 1100,
+# inside that run's 1200 s; cold on a v5e they took 72, 122 and 106 s (PR 21)
 PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
                  "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 400,
                  "serve_hybrid": 900,    # two shapes since PR 35
@@ -122,17 +91,15 @@ TOL_BWD = 5e-2
 #   (tied embedding std 0.02 over a unit-variance hidden state: 0.41 at
 #   hidden 2048), computed through bf16 weights.
 LOSS_BAND = 1.0
-# - one chip against four: same seed, same batch, same math; only the
-#   reduction order (mp splits each contraction, dp/pp split the batch
-#   mean) and where bf16 rounding lands differ. The loss is a mean over
-#   4096 tokens so per-token bf16 noise averages out; a missing or
-#   doubled collective moves it by tenths.
+# - one chip against four: same seed, batch and math; only the reduction
+#   order (mp splits each contraction, dp/pp split the batch mean) and
+#   where bf16 rounding lands differ. A mean over 4096 tokens averages
+#   that out; a missing or doubled collective moves it by tenths.
 TOL_LOSS_4CHIP = 2e-2
-# - serving: the engine's first generated token must score within this
-#   of the best logit of an independent full forward (flash path) on the
-#   same prompt. Random weights make near-ties common, so tokens are not
-#   compared for equality; logits have std ~1.3, a wrong token sits
-#   several units below the maximum.
+# - serving: every token served for the first request must score within
+#   this of the best logit of an independent full forward over the same
+#   context. Random weights make near-ties common, so tokens are not
+#   compared; logits have std ~1.3, a wrong token sits units below the best
 TOL_LOGIT = 0.5
 
 
@@ -142,18 +109,25 @@ class Sizes:
     def __init__(self, rehearsal: bool):
         self.rehearsal = rehearsal
         self.state_dtype = "bfloat16"
+        kinds = dict(ffn_kinds=["dense", "experts", "experts"],
+                     dtype="bfloat16")
+        # the AFMoE block: every switch of HybridMoEConfig on
+        switches = dict(
+            kinds, attention_kinds=["window", "full", "window"],
+            rotary_kinds=("window",), window_sink=False, value_scale=1.0,
+            routed_scaling_factor=2.826, num_shared_experts=1,
+            qk_norm=True, attention_gate=True, sandwich_norm=True,
+            head_on_last_row=True)
         if not rehearsal:
-            # train: exactly bench.py's bench_gpt
-            self.gpt = dict(vocab_size=50304, hidden_size=2048,
-                            num_layers=24, num_heads=16,
-                            max_position_embeddings=1024,
+            # train: GPT-3 1.3B, the widths of benchmarks/configs/gpt3-1.3b
+            self.gpt = dict(vocab_size=50304, hidden_size=2048, num_layers=24,
+                            num_heads=16, max_position_embeddings=1024,
                             dtype="bfloat16")
             self.B, self.S, self.steps = 4, 1024, 4
-            # serve: Llama-7B widths. Depth 8 of 32: 8 layers are 1.88B
-            # parameters = 3.8 GB in bf16, and the default page pool
-            # (8 rows x 18 pages + 1, on the power-of-two lattice: 256
-            # pages x 16.8 MB) is 4.3 GB, so weights + pool + the
-            # 2048-token prefill's activations fit 16 GB with room.
+            # serve: Llama-7B widths. Depth 8 of 32: 1.88B parameters =
+            # 3.8 GB in bf16, and the default page pool (8 rows x 18 pages
+            # + 1 on the power-of-two lattice: 256 pages x 16.8 MB) is 4.3
+            # GB, so both + a 2048-token prefill's activations fit 16 GB
             self.llama = dict(hidden_size=4096, num_heads=32,
                               intermediate_size=11008, vocab_size=32000,
                               max_position_embeddings=2304, num_layers=8,
@@ -161,13 +135,13 @@ class Sizes:
             self.page, self.max_batch = 128, 8
             self.decode_chunk, self.prefill_chunk = 8, 256
             self.new_tokens = 32
-            # one prompt per prefill bucket (64 .. 2048), then a dozen
+            # one prompt per prefill bucket (64 .. 2048), then a dozen;
+            # the first of the mix is checked against a forward
             self.warm_lens = (40, 100, 200, 400, 900, 1500)
-            self.len_range, self.n_requests = (100, 1500), 12
-            self.ref_prompt_len = 128
-            # serve_latent: the latent-attention expert decoder at its
-            # published widths, the dense layer and one expert layer that
-            # holds 32 of the router's 128 experts (1.76B parameters)
+            self.mix_lens = (128, 168, 191, 528, 611, 723, 725, 881, 1037,
+                             1093, 1290, 1324)
+            # serve_latent: published widths, the dense layer and one
+            # expert layer holding 32 of the router's 128 experts (1.76B)
             self.latent = dict(
                 vocab_size=65536, hidden_size=4096, num_layers=2,
                 num_heads=64, kv_lora_rank=512, qk_nope_head_dim=128,
@@ -177,65 +151,51 @@ class Sizes:
                 num_experts_per_tok=8, max_position_embeddings=1152,
                 dtype="bfloat16")
             self.latent_batch = 32
-            # serve_hybrid: window and full attention layers at their
-            # published widths (64 heads, keys 192 against values 128,
-            # 8 and 4 KV heads, a window of 128 with a sink), the dense
-            # layer and two expert layers that hold 16 of the router's
-            # 256 experts (1.44B parameters)
+            # serve_hybrid: published widths (64 heads, keys 192 against
+            # values 128, 8 and 4 KV heads, a window of 128 with a sink),
+            # the dense layer and two expert layers holding 16 of the
+            # router's 256 experts (1.44B parameters)
             self.hybrid = dict(
-                vocab_size=19072, hidden_size=4096,
+                kinds, vocab_size=19072, hidden_size=4096,
                 attention_kinds=["full", "window", "window"],
-                ffn_kinds=["dense", "experts", "experts"],
-                num_local_experts=16, max_position_embeddings=1152,
-                dtype="bfloat16")
+                num_local_experts=16, max_position_embeddings=1152)
             self.hybrid_batch = 32
-            # prompts that end just short of a ring's wrap (position 256
-            # at pages of 128, a ring of 2), then 40 decode rounds
+            # prompts ending just short of a ring's wrap (256), 40 rounds
             self.hybrid_lens, self.hybrid_new = (250, 240, 200, 100), 40
-            # the same phase's second shape: the AFMoE block (every
-            # switch of HybridMoEConfig on) at its published widths: 32
+            # its second shape, the AFMoE block at published widths: 32
             # heads on 4 KV heads of 128, a window of 2048 (a ring of 17
-            # pages), a dense layer and two expert layers that hold 16
-            # of 128 experts beside a shared one, the whole vocabulary
-            # (1.15B parameters); the longest prompt ends just short of
-            # the ring's wrap at position 2176
+            # pages), a dense layer and two expert layers holding 16 of
+            # 128 experts beside a shared one, the whole vocabulary
+            # (1.15B); the longest prompt ends just short of the ring's
+            # wrap at position 2176
             self.afmoe = dict(
-                vocab_size=200192, hidden_size=2048,
-                attention_kinds=["window", "full", "window"],
-                ffn_kinds=["dense", "experts", "experts"], num_heads=32,
+                switches, vocab_size=200192, hidden_size=2048, num_heads=32,
                 num_kv_heads=4, window_num_kv_heads=4, qk_head_dim=128,
                 v_head_dim=128, rotary_dim=128, window_rope_theta=10000.0,
-                rotary_kinds=("window",), sliding_window=2048,
-                window_sink=False, value_scale=1.0,
-                intermediate_size=6144, moe_intermediate_size=1024,
-                num_experts=128, num_local_experts=16,
-                routed_scaling_factor=2.826, num_shared_experts=1,
-                qk_norm=True, attention_gate=True, sandwich_norm=True,
-                embedding_multiplier=2048 ** 0.5, head_on_last_row=True,
-                max_position_embeddings=2304, dtype="bfloat16")
+                sliding_window=2048, intermediate_size=6144,
+                moe_intermediate_size=1024, num_experts=128,
+                num_local_experts=16, embedding_multiplier=2048 ** 0.5,
+                max_position_embeddings=2304)
             self.afmoe_lens = (2150, 2040, 1000, 100)
-            # serve_sparse: models.hybrid_moe.sparse_moe_tiny (three
-            # sparse layers, a softmax router) with the head widths the
-            # chip tiles; the 256 best keys of contexts to 1,000
+            # serve_sparse: sparse_moe_tiny (three sparse layers) at head
+            # widths the chip tiles; the 256 best keys of contexts to 1,000
             self.sparse = dict(
                 qk_head_dim=128, v_head_dim=128, rotary_dim=128,
                 index_head_dim=64, index_topk=256, attention_block=128,
                 max_position_embeddings=1152, dtype="bfloat16")
             self.sparse_lens, self.sparse_new = (900, 700, 300, 100), 40
-            # a pool the engine sizes itself here (512 pages, 33 MB an
-            # array) fits the chip's VMEM, and XLA then stages the whole
-            # V pool there around the decode step's scatter: a copy of a
-            # pool that no deployment's pool (gigabytes) can get. 2,048
-            # pages (134 MB an array) keep the check on layout changes
+            # the pool the engine would size itself (512 pages, 33 MB an
+            # array) fits the chip's VMEM and XLA stages the whole V pool
+            # there around the decode step's scatter: a copy no
+            # deployment's pool (gigabytes) can get. 2,048 pages (134 MB
+            # an array) keep the check on layout changes
             self.sparse_pool = 2048
-            # serve_sparse_mla: models.mla_moe.sparse_mla_tiny (a dense
-            # and two expert layers) with the widths the chip tiles; the
-            # lengths and the new tokens are serve_sparse's. Its pooled
-            # arrays are ONE head of 128 columns: at 2,048 pages an
-            # array is 67 MB and XLA stages one in VMEM around the
-            # decode step's scatter (a `copy` into S(1), seen on the
-            # chip and in an AOT compile, PR 41); 4,096 pages (134 MB)
-            # keep the check on layout changes
+            # serve_sparse_mla: sparse_mla_tiny (a dense and two expert
+            # layers) at widths the chip tiles, serve_sparse's lengths.
+            # Its pooled arrays are ONE head of 128 columns: at 2,048
+            # pages (67 MB) XLA still stages one in VMEM (a `copy` into
+            # S(1), seen on the chip and in an AOT compile, PR 41), so
+            # 4,096 pages (134 MB)
             self.sparse_mla_pool = 4096
             self.sparse_mla = dict(
                 hidden_size=256, num_heads=8, q_lora_rank=128,
@@ -245,9 +205,8 @@ class Sizes:
                 index_topk=256, attention_block=128,
                 max_position_embeddings=1152, dtype="bfloat16")
         else:
-            self.gpt = dict(vocab_size=1024, hidden_size=128,
-                            num_layers=2, num_heads=4,
-                            max_position_embeddings=64,
+            self.gpt = dict(vocab_size=1024, hidden_size=128, num_layers=2,
+                            num_heads=4, max_position_embeddings=64,
                             dtype="bfloat16")
             self.B, self.S, self.steps = 4, 64, 4
             self.llama = dict(hidden_size=128, num_heads=4,
@@ -258,8 +217,7 @@ class Sizes:
             self.decode_chunk, self.prefill_chunk = 4, 32
             self.new_tokens = 6
             self.warm_lens = (20, 100, 200)
-            self.len_range, self.n_requests = (10, 200), 6
-            self.ref_prompt_len = 64
+            self.mix_lens = (64, 57, 127, 77, 113, 19)
             self.latent = dict(
                 vocab_size=512, hidden_size=128, num_layers=2, num_heads=8,
                 kv_lora_rank=128, qk_nope_head_dim=32, qk_rope_head_dim=16,
@@ -269,40 +227,30 @@ class Sizes:
                 max_position_embeddings=288, dtype="bfloat16")
             self.latent_batch = 4
             self.hybrid = dict(
-                vocab_size=512, hidden_size=128,
-                attention_kinds=["full", "window", "window"],
-                ffn_kinds=["dense", "experts", "experts"], num_heads=8,
+                kinds, vocab_size=512, hidden_size=128,
+                attention_kinds=["full", "window", "window"], num_heads=8,
                 num_kv_heads=2, window_num_kv_heads=4, qk_head_dim=48,
                 v_head_dim=32, rotary_dim=16, sliding_window=16,
                 intermediate_size=256, moe_intermediate_size=64,
                 num_experts=16, num_local_experts=4,
                 num_experts_per_tok=4, max_position_embeddings=288,
-                attention_block=32, dtype="bfloat16")
+                attention_block=32)
             self.hybrid_batch = 4
             self.hybrid_lens, self.hybrid_new = (30, 28, 20, 10), 12
             self.afmoe = dict(
-                vocab_size=512, hidden_size=128,
-                attention_kinds=["window", "full", "window"],
-                ffn_kinds=["dense", "experts", "experts"], num_heads=8,
+                switches, vocab_size=512, hidden_size=128, num_heads=8,
                 num_kv_heads=2, window_num_kv_heads=2, qk_head_dim=32,
                 v_head_dim=32, rotary_dim=32, window_rope_theta=100.0,
-                rotary_kinds=("window",), sliding_window=48,
-                window_sink=False, value_scale=1.0,
-                intermediate_size=256, moe_intermediate_size=64,
-                num_experts=16, num_local_experts=4,
-                num_experts_per_tok=4, routed_scaling_factor=2.826,
-                num_shared_experts=1, qk_norm=True, attention_gate=True,
-                sandwich_norm=True, embedding_multiplier=128 ** 0.5,
-                head_on_last_row=True, max_position_embeddings=288,
-                attention_block=32, dtype="bfloat16")
+                sliding_window=48, intermediate_size=256,
+                moe_intermediate_size=64, num_experts=16,
+                num_local_experts=4, num_experts_per_tok=4,
+                embedding_multiplier=128 ** 0.5,
+                max_position_embeddings=288, attention_block=32)
             self.afmoe_lens = (60, 50, 20, 10)
-            self.sparse = dict(max_position_embeddings=288,
-                               dtype="bfloat16")
+            self.sparse = dict(max_position_embeddings=288, dtype="bfloat16")
+            self.sparse_mla = dict(self.sparse)
             self.sparse_lens, self.sparse_new = (100, 60, 30, 10), 12
-            self.sparse_pool = None
-            self.sparse_mla = dict(max_position_embeddings=288,
-                                   dtype="bfloat16")
-            self.sparse_mla_pool = None
+            self.sparse_pool = self.sparse_mla_pool = None
 
 
 # ---------------------------------------------------------------------------
@@ -420,12 +368,11 @@ def check_flash_calls(found: dict, eng, num_layers: int, pipe: bool) -> None:
     """The compiled train step's flash kernels, from ``kernel_names`` of
     its lowered text. Every layout holds the forward and both backward
     kernels. Where the tape differentiates the model (not ``pipe``) its
-    backward reuses the forward's out and lse, so the text holds one
-    forward a layer (the generic jax.vjp ran it a second time) and the
-    tape counts one explicit grad kernel a layer. The pipeline's stages
-    run under no_grad() and scan over the stacked layers: its text holds
-    one call for all of them, the remat's beside it, and its tape records
-    no op node."""
+    backward reuses the forward's out and lse: one forward a layer in the
+    text (the generic jax.vjp ran it twice), one explicit grad kernel a
+    layer on the tape. The pipeline's stages run under no_grad() and scan
+    over the stacked layers: one call for all of them in its text, the
+    remat's beside it, and no op node on its tape."""
     check(all(found.get(n, 0) >= 1 for n in
               ("flash_attention_fwd", "flash_attention_dq",
                "flash_attention_dkv")),
@@ -828,169 +775,311 @@ def phase_train(sz: Sizes, layout: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# phase: serve
+# phases: serve, serve_latent, serve_hybrid, serve_sparse, serve_sparse_mla
+# one body (serve_case); SERVE_CASES holds what differs between them
 # ---------------------------------------------------------------------------
-def phase_serve(sz: Sizes) -> None:
-    jax, device, events = start_child(sz.rehearsal)
+@dataclasses.dataclass(frozen=True)
+class ServeCase:
+    """What one served configuration hands ``serve_case``: a new one is one
+    more entry of ``SERVE_CASES``, a check all share goes into the body."""
+
+    label: str
+    # (module of paddle_tpu.models, config maker, model class, the Sizes
+    # attribute with the maker's arguments)
+    model: tuple
+    # sz -> {"warm": lengths, "mix": lengths, "new": tokens a request,
+    # "batch": max_batch}; no warm-up, no word on compiles
+    traffic: Callable
+    # sz -> ServingEngine keyword arguments, one engine a member, each
+    # over the same predictor and the same traffic, in turn
+    engines: Callable
+    # cfg -> (heads, width) of each array a layer pools; None: no claim
+    pools: Optional[Callable]
+    decode_kernels: Callable    # cfg -> {Mosaic kernel: calls in ('decode',)}
+    donated: int        # state arrays a layer the decode step writes in place
+    # (sz, cfg): the decode kernels alone against their dense twins at
+    # this model's shapes, before the model takes the memory
+    kernels: Optional[Callable] = None
+    # (ctx, ids) -> float32 logits [len(ids), vocab], one forward with no
+    # cache; ``Predictor.run`` (the flash-attention path) where None
+    forward: Optional[Callable] = None
+    # (ctx): this family's own checks, after the shared ones; ctx holds
+    # sz cfg model eng warm mix new full, and warm_first after a warm-up
+    extra: Optional[Callable] = None
+
+    def build(self, sz: Sizes):
+        """(config, model class); the module imported when a child asks."""
+        module, make, model, sizes = self.model
+        mod = importlib.import_module("paddle_tpu.models." + module)
+        return getattr(mod, make)(**getattr(sz, sizes)), getattr(mod, model)
+
+
+def serve_case(sz: Sizes, case: ServeCase, events: JaxEvents,
+               device: dict) -> None:
+    """One configuration through ``Config.enable_paged_kv`` ->
+    ``create_predictor`` -> ``ServingEngine``: the checks every served
+    configuration shares, then the case's own."""
+    import gc
+    import types
+
+    import jax
     import numpy as np
 
     import paddle_tpu as paddle
-    from paddle_tpu.core.bucketing import bucket
+    from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
+        routed_form)
     from paddle_tpu.inference import (Config, ServingEngine,
                                       create_predictor)
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.ops.pallas import decode_attention as da
 
-    cfg = LlamaConfig(**sz.llama)
-    # the form a prefill program of this model takes on the chip, by
-    # its bucket (the rehearsal's programs are all "dense")
-    def form(b):
-        return "dense" if sz.rehearsal else da.paged_attention_form(
-            (1, b, cfg.num_heads, cfg.head_dim),
-            (1, cfg.num_kv_heads, sz.page, cfg.head_dim),
-            (1, b, cfg.num_kv_heads, cfg.head_dim), 0)
-
-    warm_buckets = {bucket(n) for n in sz.warm_lens}
-    # the prompts whose first tokens are checked against the dense path:
-    # the two largest buckets (1,024 and 2,048 rows on the chip)
-    long_buckets = set(sorted(warm_buckets)[-2:])
-    print(f"  Llama widths hidden={cfg.hidden_size} heads={cfg.num_heads}x"
-          f"{cfg.head_dim} ffn={cfg.intermediate_size} vocab="
-          f"{cfg.vocab_size}, depth {cfg.num_layers} "
-          f"({cfg.num_params() / 1e9:.2f}B params; cut from 32 so weights "
-          f"+ the page pool fit one 16 GB chip), page_size={sz.page}",
-          flush=True)
+    cfg, model_cls = case.build(sz)
+    traffic = case.traffic(sz)
+    new, batch = traffic["new"], traffic["batch"]
+    if case.kernels:
+        case.kernels(sz, cfg)
     t0 = time.perf_counter()
     paddle.set_default_dtype(cfg.dtype)
     paddle.seed(0)
-    model = LlamaForCausalLM(cfg)
+    model = model_cls(cfg)
+    n_par = sum(int(np.prod(p.shape)) for p in model.parameters())
     pred = create_predictor(
         Config().set_model(model).enable_paged_kv(page_size=sz.page))
-    print(f"  model + predictor built in {time.perf_counter() - t0:.1f}s",
-          flush=True)
-
     r = np.random.RandomState(0)
-
-    def prompts(lens):
-        return [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
-                for n in lens]
-
-    warm = prompts(sz.warm_lens)
-    lens = r.randint(sz.len_range[0], sz.len_range[1] + 1,
-                     (sz.n_requests,))
-    lens[0] = sz.ref_prompt_len     # the one checked against a reference
-    mix = prompts(lens)
-    print(f"  warm-up prompt lengths {list(sz.warm_lens)}, then "
-          f"{sz.n_requests} requests of {sorted(int(n) for n in lens)} "
-          f"tokens, {sz.new_tokens} new tokens each", flush=True)
-
-    # reference for one prompt: an independent full forward through
-    # Predictor.run (no KV cache: the flash-attention path)
-    logits = pred.run([mix[0][None, :]])[0]
-    check(logits.shape == (1, sz.ref_prompt_len, cfg.vocab_size)
-          and np.isfinite(logits.astype("float32")).all(),
-          f"reference forward: finite logits of shape {logits.shape}")
-    ref_last = logits[0, -1].astype("float32")
-
-    modes = (("bucketed prefill + fused decode scan", {}),
-             ("chunked unified step",
-              {"prefill_chunk": sz.prefill_chunk}))
-    report = {}
-    for mode, kw in modes:
-        print(f"  -- ServingEngine {mode} {kw}", flush=True)
-        eng = ServingEngine(pred, max_batch=sz.max_batch,
-                            decode_chunk=sz.decode_chunk, **kw)
+    warm, mix = ([r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
+                  for n in traffic[k]] for k in ("warm", "mix"))
+    print(f"  {model_cls.__name__}: hidden {cfg.hidden_size}, vocabulary "
+          f"{cfg.vocab_size}, {cfg.num_layers} layers, {n_par / 1e9:.2f}B "
+          f"params, built in {time.perf_counter() - t0:.1f}s; pages of "
+          f"{sz.page}; prompts of {list(traffic['warm'])} tokens to warm up, "
+          f"then {list(traffic['mix'])}, {new} new tokens each", flush=True)
+    for kw in case.engines(sz):
+        print(f"  -- ServingEngine max_batch={batch} {kw}", flush=True)
+        eng = ServingEngine(pred, max_batch=batch, **kw)
+        ctx = types.SimpleNamespace(sz=sz, cfg=cfg, model=model, eng=eng,
+                                    warm=warm, mix=mix, new=new)
+        if case.pools:
+            want = [(h, sz.page, w) for h, w in case.pools(cfg)]
+            check(eng.cache.arrays == [len(want)] * cfg.num_layers
+                  and all([a.shape[1:] for a in layer] == want
+                          for layer in eng.pools),
+                  f"a layer pools {len(want)} arrays of {eng.P} pages: "
+                  f"{' | '.join('x'.join(map(str, w)) for w in want)}")
         t0 = time.perf_counter()
-        wrids = [eng.submit(p, max_new_tokens=sz.new_tokens) for p in warm]
-        done = eng.run()
-        t_setup = time.perf_counter() - t0
-        check(len(done) == len(warm), f"warm-up mix drained ({len(done)})")
-        warm_first = [int(done[rid].new_tokens[0]) for rid in wrids]
-        compiles0, xla0 = eng.stats.compiles, events.compiles
-        t0 = time.perf_counter()
-        rids = [eng.submit(p, max_new_tokens=sz.new_tokens) for p in mix]
+        if warm:
+            wrids = [eng.submit(p, max_new_tokens=new) for p in warm]
+            done = eng.run()
+            check(len(done) == len(warm),
+                  f"warm-up mix drained ({len(done)})")
+            ctx.warm_first = [int(done[rid].new_tokens[0])
+                              for rid in wrids]
+            compiles0, xla0 = eng.stats.compiles, events.compiles
+        t_setup, t0 = time.perf_counter() - t0, time.perf_counter()
+        rids = [eng.submit(p, max_new_tokens=new) for p in mix]
         done = eng.run()
         t_run = time.perf_counter() - t0
         outs = [np.asarray(done[rid].new_tokens) for rid in rids
                 if rid in done]
-        n_tok = sum(len(o) for o in outs)
-        print(f"    set-up (compile + warm-up mix) {t_setup:.1f}s; "
-              f"{len(mix)} requests in {t_run:.2f}s, {n_tok / t_run:.0f} "
-              f"generated tokens/s on {device['kind']} (not a metric); "
-              f"pool {eng.P} pages", flush=True)
-        check(len(outs) == len(rids) and not any(
-            done[rid].shed for rid in rids), "every request finished")
-        check(all(len(o) == sz.new_tokens for o in outs),
-              f"every request returned exactly {sz.new_tokens} tokens")
-        check(all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
-              "every token in vocabulary")
-        check(eng.stats.compiles == compiles0,
-              f"eng.stats.compiles flat after warm-up ({compiles0})")
-        check(events.compiles == xla0,
-              f"no XLA compile after warm-up "
-              f"({events.compiles - xla0} seen)")
-        tok0 = int(outs[0][0])
-        gap = float(ref_last.max() - ref_last[tok0])
-        check(gap <= TOL_LOGIT,
-              f"first token {tok0} scores within {TOL_LOGIT} of the "
-              f"reference forward's best logit (gap {gap:.3f})")
-        # a prompt over FLASH_OVER_ROWS tokens attends to its layer's
-        # fresh K/V as flash attention, a shorter one through the pool
-        forms = eng.prefill_attention_forms()
-        if not kw:
-            check(forms == {b: form(b) for b in warm_buckets | set(forms)}
-                  and (sz.rehearsal or
-                       {"flash", "paged"} <= set(forms.values())
-                       and all(forms[b] == "flash" for b in long_buckets)),
-                  f"prefill programs attend as {forms}")
-        attend = {"flash": "flash_attention_fwd_gqa",
-                  "paged": "paged_decode_attention"}
-        want = {"decode": ("paged_decode_attention", "rms_norm_fused"),
-                "unified": ("ragged_paged_attention", "rms_norm_fused")}
+        print(f"    compile + warm-up mix {t_setup:.1f}s; {len(mix)} requests "
+              f"in {t_run:.2f}s, {sum(len(o) for o in outs) / t_run:.0f} "
+              f"generated tokens/s on {device['kind']} (not a metric); pool "
+              f"{eng.P} pages", flush=True)
+        check(len(outs) == len(rids)
+              and not any(done[rid].shed for rid in rids)
+              and all(len(o) == new for o in outs)
+              and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
+              f"every request returned {new} tokens of the vocabulary")
+        if warm:
+            check(eng.stats.compiles == compiles0 and events.compiles == xla0,
+                  f"no compile after warm-up (eng.stats.compiles "
+                  f"{compiles0}, {events.compiles - xla0} XLA compiles seen)")
+        # the reference: one forward with no cache over the first request's
+        # prompt and the tokens served for it (row t scores the token after)
+        seq = np.concatenate([mix[0], outs[0][:-1]])
+        full = ctx.full = case.forward(ctx, seq) if case.forward else \
+            pred.run([seq[None, :]])[0][0].astype("float32")
+        check(full.shape == (len(seq), cfg.vocab_size)
+              and np.isfinite(full).all(),
+              f"reference forward: finite logits of shape {full.shape}")
+        at = full[len(mix[0]) - 1:]
+        gaps = at.max(-1) - at[np.arange(new), outs[0]]
+        check(float(gaps[0]) <= TOL_LOGIT,
+              f"first token {int(outs[0][0])} scores within {TOL_LOGIT} "
+              f"of the reference forward's best logit (gap "
+              f"{float(gaps[0]):.3f})")
+        check(float(gaps.max()) <= TOL_LOGIT,
+              f"every served token of the first request scores within "
+              f"{TOL_LOGIT} of the reference forward's best (widest "
+              f"{float(gaps.max()):.3f})")
         sites = eng.program_sites()
-        for site in sites:
-            found = kernel_names(eng.lowered_text(site))
-            names = want.get(site[0], ())
-            if site[0] == "prefill":
-                # each kernel is jitted on its own: the text holds it
-                # once however many layers call it
-                mine = attend.get(forms[site[1]])
-                names = (mine, "rms_norm_fused")
-                check(sz.rehearsal or not any(
-                    found.get(n, 0) for n in attend.values() if n != mine),
-                    f"program {site} holds no other attention kernel")
-            check(sz.rehearsal or all(found.get(n, 0) >= 1
-                                      for n in names),
-                  f"program {site} holds Mosaic calls {found}")
-        # the page pool is written in place: no copy of a whole pool
+        st = eng.moe_stats()
+        if st is not None:              # a model with routed experts
+            check(st["dropped"] == 0 and st["tokens"][-1] > 0,
+                  f"expert layers dropped {st['dropped']} routed pairs of "
+                  f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
+            # their products: batched over the held experts in the decode
+            # program (no grouped matmul in it), sorted and grouped in a
+            # prefill bucket over 128 tokens (the token count alone decides)
+            buckets = sorted(s[1] for s in sites if s[0] == "prefill")
+            forms = {"decode": routed_form(eng.B), "prefill": "+".join(
+                sorted({routed_form(Sb) for Sb in buckets}))}
+            check(st["forms"] == forms and forms["decode"] == "batched"
+                  and (sz.rehearsal or "sorted" in forms["prefill"]),
+                  f"expert products' forms {st['forms']} (decode at "
+                  f"{eng.B} rows, prefill buckets {buckets})")
+            grouped = {site: "ragged_dot" in eng.lowered_text(site)
+                       for site in [("decode",), ("prefill", buckets[-1])]}
+            check(sz.rehearsal or list(grouped.values()) == [False, True],
+                  f"XLA's grouped matmul in the lowered programs: {grouped}")
+        found = kernel_names(eng.lowered_text(("decode",)))
+        asked = case.decode_kernels(cfg)
+        check(sz.rehearsal or all(found.get(name, 0) == calls
+                                  for name, calls in asked.items()),
+              f"program ('decode',) holds Mosaic calls {found} "
+              f"(asked: {asked})")
+        # the page pools are written in place: no copy of a whole pool
         # (a layout change around the write) in the compiled decode or
         # unified program, nor in the largest prefill program
-        pool_shape = eng.pools[0][0].shape
+        shapes = sorted({a.shape for layer in eng.pools for a in layer})
         for site in [s for s in sites if s[0] in ("decode", "unified")] \
                 + sorted(s for s in sites if s[0] == "prefill")[-1:]:
             text = eng.compiled_text(site)
-            n = eng.pool_copies(text, pool_shape)
+            n = sum(eng.pool_copies(text, s) for s in shapes)
             check(sz.rehearsal or n == 0,
-                  f"compiled program {site}: {n} copies of a whole "
-                  f"{'x'.join(map(str, pool_shape))} pool")
+                  f"compiled program {site}: {n} copies of a whole pool "
+                  f"{' | '.join('x'.join(map(str, s)) for s in shapes)}")
             if site == ("decode",):
-                check_decode_donation(eng, text, 2 * cfg.num_layers)
-        check_overlap(eng, most=not kw)
-        kinds = {s[0] for s in sites}
-        check(("unified" in kinds) if kw else
-              ({"prefill", "decode"} <= kinds),
-              f"the programs that ran: {sorted(map(str, sites))}")
-        report[mode] = {"setup_s": round(t_setup, 1),
-                        "run_s": round(t_run, 2), "pool_pages": eng.P}
-        del eng      # its page pool, before the next engine builds one
+                # it writes in place what the cache lent it (pools, an
+                # expert model's counters), never its round array (tables,
+                # pos, token, mask), which every layer reads
+                donated = eng.donated_params(text)
+                check(len(donated) == case.donated * cfg.num_layers
+                      and all(name.startswith("state") for name in donated),
+                      f"compiled program ('decode',) donates the "
+                      f"{len(donated)} arrays it was lent and not its "
+                      f"round array: {donated}")
+        # one decode round in flight: rounds were launched before the one
+        # ahead was read (all but the first of a run() in the default mode;
+        # chunked mode overlaps only pure-decode rounds), none left unread
+        ov = eng.overlap_stats()
+        check(ov["in_flight"] == 0 and ov["rounds"] > 0
+              and (eng.chunked or ov["overlapped"] * 2 >= ov["rounds"]),
+              f"decode rounds launched with the one before unretired: "
+              f"{ov['overlapped']} of {ov['rounds']}, {ov['in_flight']} in "
+              f"flight after run()")
+        if case.extra:
+            case.extra(ctx)
+        eng.release_pools()     # before the next engine builds its own
+        del eng, ctx
+    for p in model.parameters():        # the next case needs the memory
+        p._value = None
+    del pred, model
+    gc.collect()
+    jax.clear_caches()
 
-    # the long prompts' first tokens against the dense path: a forward
-    # with every kernel off (plain XLA attention over the whole prompt,
-    # no cache), the served token's logit beside its best
+
+def phase_serve(sz: Sizes, phase: str) -> None:
+    _, device, events = start_child(sz.rehearsal)
+    for case in SERVE_CASES[phase]:
+        print(f"  -- {case.label} --", flush=True)
+        serve_case(sz, case, events, device)
+    finish_child(phase, device, events, {})
+
+
+def check_twin(what: str, got, want) -> None:
+    import jax.numpy as jnp
+
+    err = float(jnp.abs(got.astype(jnp.float32)
+                        - want.astype(jnp.float32)).max())
+    check(err <= TOL_ATTN, f"{what} within {TOL_ATTN} of its dense twin "
+                           f"(max err {err:.2e})")
+
+
+def paged_batch(sz: Sizes, r, ncols: int):
+    """``hybrid_batch`` rows of ``ncols`` pages scattered over a pool:
+    (pages in the pool, block table, a maker of random bf16 arrays)."""
+    import jax.numpy as jnp
+
+    B = sz.hybrid_batch
+    P = B * ncols + 1
+    tbl = r.permutation(P - 1)[:B * ncols].reshape(B, ncols).astype("int32")
+    return P, tbl, lambda *shape: jnp.asarray(r.standard_normal(shape),
+                                              jnp.bfloat16)
+
+
+def paged_twin(sz: Sizes, cfg, r, what: str, KV: int, ncols: int, lens,
+               **kw) -> None:
+    """``paged_decode_attention`` alone against its dense twin over such a
+    batch at the decode program's shapes, where its gate admits them."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    P, tbl, rnd = paged_batch(sz, r, ncols)
+    kp = rnd(P, KV, sz.page, cfg.k_cache_width)
+    vp = rnd(P, KV, sz.page, cfg.v_head_dim)
+    q = rnd(sz.hybrid_batch, 1, cfg.num_heads, cfg.k_cache_width)
+    kw["scale"] = cfg.softmax_scale
+    if sz.rehearsal or da.paged_supported(q.shape, kp.shape, vp.shape):
+        check_twin(f"{what} ({cfg.num_heads // KV} query heads a KV head, "
+                   f"{ncols} pages a row)",
+                   da.paged_decode_attention(q, kp, vp, tbl, lens,
+                                             interpret=sz.rehearsal, **kw),
+                   da.paged_attention_dense(q, kp, vp, tbl, lens, **kw))
+
+
+# -- serve: Llama-7B widths, both engine modes ------------------------------
+def llama_extra(ctx) -> None:
+    """The prefill and unified programs' attention forms and kernels; the
+    long prompts' first tokens against a forward with every kernel off."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core.bucketing import bucket
+    from paddle_tpu.inference import Config, create_predictor
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    sz, cfg, eng = ctx.sz, ctx.cfg, ctx.eng
+
+    warm_buckets = {bucket(len(p)) for p in ctx.warm}
+    # the prompts whose first tokens are checked against the dense path:
+    # the two largest buckets (1,024 and 2,048 rows on the chip)
+    long_buckets = set(sorted(warm_buckets)[-2:])
+    # a prompt over FLASH_OVER_ROWS tokens attends to its layer's fresh
+    # K/V as flash attention, a shorter one through the pool (the
+    # rehearsal's programs are all "dense")
+    forms = eng.prefill_attention_forms()
+    if not eng.chunked:
+        check(forms == {b: "dense" if sz.rehearsal
+                        else da.paged_attention_form(
+                            (1, b, cfg.num_heads, cfg.head_dim),
+                            (1, cfg.num_kv_heads, sz.page, cfg.head_dim),
+                            (1, b, cfg.num_kv_heads, cfg.head_dim), 0)
+                        for b in warm_buckets | set(forms)}
+              and (sz.rehearsal or
+                   {"flash", "paged"} <= set(forms.values())
+                   and all(forms[b] == "flash" for b in long_buckets)),
+              f"prefill programs attend as {forms}")
+    attend = {"flash": "flash_attention_fwd_gqa",
+              "paged": "paged_decode_attention"}
+    sites = eng.program_sites()
+    for site in [s for s in sites if s[0] in ("prefill", "unified")]:
+        found = kernel_names(eng.lowered_text(site))
+        names = ("ragged_paged_attention", "rms_norm_fused")
+        if site[0] == "prefill":
+            # each kernel is jitted on its own: the text holds it
+            # once however many layers call it
+            mine = attend.get(forms[site[1]])
+            names = (mine, "rms_norm_fused")
+            check(sz.rehearsal or not any(
+                found.get(n, 0) for n in attend.values() if n != mine),
+                f"program {site} holds no other attention kernel")
+        check(sz.rehearsal or all(found.get(n, 0) >= 1 for n in names),
+              f"program {site} holds Mosaic calls {found}")
+    kinds = {s[0] for s in sites}
+    check(("unified" in kinds) if eng.chunked else
+          ({"prefill", "decode"} <= kinds),
+          f"the programs that ran: {sorted(map(str, sites))}")
+    # the long prompts' first tokens against the dense path: a forward with
+    # every kernel off (plain XLA attention over the whole prompt, no cache)
     paddle.set_flags({"use_pallas_kernels": False})
-    dense = create_predictor(Config().set_model(model))
+    dense = create_predictor(Config().set_model(ctx.model))
     gaps = {}
-    for p, tok in zip(warm, warm_first):
+    for p, tok in zip(ctx.warm, ctx.warm_first):
         if bucket(len(p)) in long_buckets:
             row = dense.run([p[None, :]])[0][0, -1].astype("float32")
             gaps[len(p)] = (float(row.max() - row[tok]),
@@ -1000,517 +1089,103 @@ def phase_serve(sz: Sizes) -> None:
           and all(g <= TOL_LOGIT for g, _ in gaps.values()),
           f"first tokens of the prompts in the buckets "
           f"{sorted(long_buckets)} score within {TOL_LOGIT} of the dense "
-          f"path's best logit "
-          f"(prompt length: (gap, same token) = {gaps})")
-    finish_child("serve", device, events, {"modes": report})
+          f"path's best logit (prompt length: (gap, same token) = {gaps})")
 
 
-def check_decode_donation(eng, text: str, n_state: int) -> None:
-    """The decode program writes in place what the cache lent it (the
-    pools, an expert model's counters) and nothing else: its round array
-    (tables, pos, token, mask) is read by every layer, not donated."""
-    donated = eng.donated_params(text)
-    check(len(donated) == n_state
-          and all(n.startswith("state") for n in donated),
-          f"compiled program ('decode',) donates the {n_state} arrays it "
-          f"was lent and not its round array: {donated}")
-
-
-def check_overlap(eng, most: bool = True) -> None:
-    """One decode round in flight: rounds were launched before the round
-    ahead of them was read (every round but the first of a ``run()`` in
-    the default mode; chunked mode overlaps only its runs of pure-decode
-    rounds), and ``run()`` left none unread."""
-    st = eng.overlap_stats()
-    check(st["in_flight"] == 0 and st["rounds"] > 0
-          and (not most or st["overlapped"] * 2 >= st["rounds"]),
-          f"decode rounds launched with the one before unretired: "
-          f"{st['overlapped']} of {st['rounds']}, "
-          f"{st['in_flight']} in flight after run()")
-
-
-def check_expert_forms(eng, st, sz) -> None:
-    """The expert layers' products after the decode rounds: batched over
-    the held experts in the decode program (no grouped matmul in it),
-    sorted and grouped in every prefill bucket over the threshold (a
-    prompt of 128 tokens or fewer prefills batched as well: the form
-    follows the token count alone)."""
-    from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
-        routed_form)
-
-    buckets = sorted(s[1] for s in eng.program_sites()
-                     if s[0] == "prefill")
-    want = {"decode": routed_form(eng.B), "prefill": "+".join(sorted(
-        {routed_form(Sb) for Sb in buckets}))}
-    check(st["forms"] == want and want["decode"] == "batched"
-          and (sz.rehearsal or "sorted" in want["prefill"]),
-          f"expert products' forms {st['forms']} (decode at {eng.B} rows, "
-          f"prefill buckets {buckets})")
-    grouped = {site: "ragged_dot" in eng.lowered_text(site)
-               for site in [("decode",), ("prefill", buckets[-1])]}
-    check(sz.rehearsal or list(grouped.values()) == [False, True],
-          f"XLA's grouped matmul in the lowered programs: {grouped}")
-
-
-def phase_serve_latent(sz: Sizes) -> None:
-    """The latent-attention expert decoder through ServingEngine in its
-    default mode: the latent pools written in place, the latent kernel
-    in the decode program, no routed pair dropped."""
-    jax, device, events = start_child(sz.rehearsal)
-    import numpy as np
-
-    import paddle_tpu as paddle
-    from paddle_tpu.inference import (Config, ServingEngine,
-                                      create_predictor)
-    from paddle_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
-
-    cfg = MLAMoEConfig(**sz.latent)
-    t0 = time.perf_counter()
-    paddle.set_default_dtype(cfg.dtype)
-    paddle.seed(0)
-    model = MLAMoEForCausalLM(cfg)
-    n_par = sum(int(np.prod(p.shape)) for p in model.parameters())
-    print(f"  latent attention {cfg.num_heads} heads x ({cfg.qk_nope_head_dim}"
-          f"+{cfg.qk_rope_head_dim}), latent {cfg.kv_lora_rank}; "
-          f"{cfg.num_local_experts} of {cfg.num_experts} experts of "
-          f"{cfg.moe_intermediate_size} held, {cfg.num_experts_per_tok} a "
-          f"token; depth {cfg.num_layers} ({n_par / 1e9:.2f}B params); "
-          f"built in {time.perf_counter() - t0:.1f}s", flush=True)
-    pred = create_predictor(
-        Config().set_model(model).enable_paged_kv(page_size=sz.page))
-    r = np.random.RandomState(0)
-
-    def prompts(lens):
-        return [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
-                for n in lens]
-
-    # one prompt per prefill bucket the mix can reach (up to 1024)
-    warm = prompts([n for n in sz.warm_lens if n <= 900])
-    lens = r.randint(sz.len_range[0], min(sz.len_range[1], 900) + 1,
-                     (sz.n_requests,))
-    lens[0] = sz.ref_prompt_len
-    mix = prompts(lens)
-    logits = pred.run([mix[0][None, :]])[0]
-    ref_last = logits[0, -1].astype("float32")
-    check(np.isfinite(ref_last).all(), "reference forward: finite logits")
-    eng = ServingEngine(pred, max_batch=sz.latent_batch)
-    check([(c.shape[1], c.shape[3], k.shape[3]) for c, k in eng.pools]
-          == [(1, cfg.kv_lora_rank, cfg.rope_cache_width)] * cfg.num_layers,
-          f"the pool holds one latent and one rotated key a position: "
-          f"{eng.pools[0][0].shape} + {eng.pools[0][1].shape}")
-    t0 = time.perf_counter()
-    for p in warm:
-        eng.submit(p, max_new_tokens=sz.new_tokens)
-    done = eng.run()
-    t_setup = time.perf_counter() - t0
-    check(len(done) == len(warm), f"warm-up mix drained ({len(done)})")
-    compiles0, xla0 = eng.stats.compiles, events.compiles
-    t0 = time.perf_counter()
-    rids = [eng.submit(p, max_new_tokens=sz.new_tokens) for p in mix]
-    done = eng.run()
-    t_run = time.perf_counter() - t0
-    outs = [np.asarray(done[rid].new_tokens) for rid in rids if rid in done]
-    print(f"    set-up {t_setup:.1f}s; {len(mix)} requests in {t_run:.2f}s "
-          f"on {device['kind']} (not a metric); pool {eng.P} pages",
-          flush=True)
-    check(len(outs) == len(rids)
-          and all(len(o) == sz.new_tokens for o in outs)
-          and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
-          f"every request returned {sz.new_tokens} tokens of the vocabulary")
-    check(eng.stats.compiles == compiles0 and events.compiles == xla0,
-          "no compile after warm-up")
-    tok0 = int(outs[0][0])
-    gap = float(ref_last.max() - ref_last[tok0])
-    check(gap <= TOL_LOGIT,
-          f"first token {tok0} scores within {TOL_LOGIT} of the reference "
-          f"forward's best logit (gap {gap:.3f})")
-    st = eng.moe_stats()
-    check(st["dropped"] == 0 and st["tokens"][-1] > 0,
-          f"expert layers dropped {st['dropped']} routed pairs of "
-          f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
-    check_expert_forms(eng, st, sz)
-    sites = eng.program_sites()
-    found = kernel_names(eng.lowered_text(("decode",)))
-    check(sz.rehearsal or found.get("mla_paged_decode_attention", 0)
-          == cfg.num_layers,
-          f"program ('decode',) holds Mosaic calls {found}")
-    for site in [("decode",)] + sorted(s for s in sites
-                                       if s[0] == "prefill")[-1:]:
-        text = eng.compiled_text(site)
-        n = sum(eng.pool_copies(text, a.shape) for a in eng.pools[0])
-        check(sz.rehearsal or n == 0,
-              f"compiled program {site}: {n} copies of a whole latent or "
-              f"rotated-key pool")
-        if site == ("decode",):     # the routing counters ride along
-            check_decode_donation(eng, text, 3 * cfg.num_layers)
-    check_overlap(eng)
-    finish_child("serve_latent", device, events,
-                 {"setup_s": round(t_setup, 1), "run_s": round(t_run, 2),
-                  "pool_pages": eng.P})
-
-
-def phase_serve_hybrid(sz: Sizes) -> None:
-    """The decoder with window and full attention layers through
-    ServingEngine in its default mode, in both of its shapes
-    (``serve_hybrid_shape``)."""
-    _, device, events = start_child(sz.rehearsal)
-    extra = {}
-    for name, sizes, lens in (("mimo", sz.hybrid, sz.hybrid_lens),
-                              ("afmoe", sz.afmoe, sz.afmoe_lens)):
-        print(f"  -- {name} --", flush=True)
-        extra[name] = serve_hybrid_shape(sz, sizes, lens)
-    finish_child("serve_hybrid", device, events, extra)
-
-
-def serve_hybrid_shape(sz: Sizes, sizes: dict, prompt_lens) -> dict:
-    """One ``HybridMoEConfig``: both decode kernels agree with their
-    dense twins at the model's own shapes, decode runs past a ring's
-    wrap, two classes of pools are written in place, no routed pair is
-    dropped."""
-    import gc
-
-    import jax
+# -- serve_hybrid: window and full attention layers, two shapes -------------
+def hybrid_kernels(sz, cfg) -> None:
+    """Both decode kernels alone: rows below, at and past the window, and
+    at the ring's seam."""
     import jax.numpy as jnp
     import numpy as np
 
-    import paddle_tpu as paddle
-    from paddle_tpu.inference import (Config, ServingEngine,
-                                      create_predictor)
-    from paddle_tpu.models.hybrid_moe import (HybridMoEConfig,
-                                              HybridMoEForCausalLM)
-    from paddle_tpu.ops.pallas import decode_attention as da
-
-    cfg = HybridMoEConfig(**sizes)
-    page, B = sz.page, sz.hybrid_batch
-    ring = -(-cfg.sliding_window // page) + 1
+    page, W = sz.page, cfg.sliding_window
+    ring = -(-W // page) + 1
     r = np.random.RandomState(0)
-    # the kernels alone, at the shapes the decode program calls them
-    # with: rows below, at and past the window, and at the ring's seam
-    W = cfg.sliding_window
     lens = np.resize([0, W - 1, W, W + 1, 2 * page - 1, 2 * page,
-                      5 * page + 3, ring * page + 5], B).astype("int32")
-    full_cols = int(lens.max()) // page + 1
-    for kind, ncols, window in (("full", full_cols, None),
+                      5 * page + 3, ring * page + 5],
+                     sz.hybrid_batch).astype("int32")
+    for kind, ncols, window in (("full", int(lens.max()) // page + 1, None),
                                 ("window", ring, W)):
-        KV = cfg.kv_heads(kind)
-        P = B * ncols + 1
-        rnd = lambda *shape: jnp.asarray(r.standard_normal(shape),
-                                         jnp.bfloat16)
-        kp = rnd(P, KV, page, cfg.k_cache_width)
-        vp = rnd(P, KV, page, cfg.v_head_dim)
-        tbl = r.permutation(P - 1)[:B * ncols].reshape(B, ncols).astype(
-            "int32")
-        q = rnd(B, 1, cfg.num_heads, cfg.k_cache_width)
         sk = jnp.asarray(r.standard_normal(cfg.num_heads), jnp.float32) \
             if cfg.sink(kind) else None
-        kw = dict(scale=cfg.softmax_scale, sinks=sk, window=window)
-        got = da.paged_decode_attention(q, kp, vp, tbl, lens,
-                                        interpret=sz.rehearsal, **kw)
-        want = da.paged_attention_dense(q, kp, vp, tbl, lens, **kw)
-        err = float(jnp.abs(got.astype(jnp.float32)
-                            - want.astype(jnp.float32)).max())
-        check(err <= TOL_ATTN,
-              f"{kind} decode kernel ({cfg.num_heads // KV} query heads a "
-              f"KV head, keys {cfg.k_cache_width} against values "
-              f"{cfg.v_head_dim}, sink {sk is not None}, window {window}) "
-              f"within {TOL_ATTN} of its dense twin (max err {err:.2e})")
-    t0 = time.perf_counter()
-    paddle.set_default_dtype(cfg.dtype)
-    paddle.seed(0)
-    model = HybridMoEForCausalLM(cfg)
-    n_par = sum(int(np.prod(p.shape)) for p in model.parameters())
-    print(f"  attention {cfg.attention_kinds}: {cfg.num_heads} heads, keys "
-          f"{cfg.qk_head_dim} against values {cfg.v_head_dim}, window "
-          f"{cfg.sliding_window}; {cfg.num_local_experts} of "
-          f"{cfg.num_experts} experts held; depth {cfg.num_layers} "
-          f"({n_par / 1e9:.2f}B params); built in "
-          f"{time.perf_counter() - t0:.1f}s", flush=True)
-    pred = create_predictor(
-        Config().set_model(model).enable_paged_kv(page_size=page))
-    mix = [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
-           for n in prompt_lens]
-    logits = pred.run([mix[0][None, :]])[0]
-    ref_last = logits[0, -1].astype("float32")
-    check(np.isfinite(ref_last).all(), "reference forward: finite logits")
-    eng = ServingEngine(pred, max_batch=B, debug_invariants=True)
+        paged_twin(sz, cfg, r, f"{kind} decode kernel, keys "
+                   f"{cfg.k_cache_width} against values {cfg.v_head_dim}, "
+                   f"sink {sk is not None}, window {window}",
+                   cfg.kv_heads(kind), ncols, lens, sinks=sk, window=window)
+
+
+def hybrid_extra(ctx) -> None:
+    """Decode ran past a ring's wrap and still agrees with a forward over
+    the whole context; both classes of pages came back."""
+    eng, B, page = ctx.eng, ctx.sz.hybrid_batch, ctx.sz.page
+    ring = -(-ctx.cfg.sliding_window // page) + 1
+    longest = len(ctx.mix[0]) + ctx.new
     check((eng.cache.ring, eng.cache.Pw) == (ring, B * ring + 1)
           and [a.shape[0] for a, _ in eng.pools]
-          == [eng.cache.Pw if w else eng.P
-              for w in eng.cache.window_layers],
-          f"two classes of pages: {eng.P} full, {eng.cache.Pw} window "
-          f"(a ring of {ring} a row)")
-    t0 = time.perf_counter()
-    rids = [eng.submit(p, max_new_tokens=sz.hybrid_new) for p in mix]
-    done = eng.run()
-    t_run = time.perf_counter() - t0
-    outs = [np.asarray(done[rid].new_tokens) for rid in rids if rid in done]
-    check(len(outs) == len(rids)
-          and all(len(o) == sz.hybrid_new for o in outs)
-          and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
-          f"every request returned {sz.hybrid_new} tokens of the "
-          f"vocabulary; the longest context {len(mix[0]) + sz.hybrid_new} "
-          f"wrapped its ring at {ring * page}")
-    tok0 = int(outs[0][0])
-    gap = float(ref_last.max() - ref_last[tok0])
-    check(gap <= TOL_LOGIT,
-          f"first token {tok0} scores within {TOL_LOGIT} of the reference "
-          f"forward's best logit (gap {gap:.3f})")
-    # decode through the ring against one forward over the whole context
-    seq = np.concatenate([mix[0], outs[0][:-1]])
-    full = pred.run([seq[None, :]])[0][0].astype("float32")
-    at = full[len(mix[0]) - 1:]
-    gaps = at.max(-1) - at[np.arange(len(outs[0])), outs[0]]
-    check(float(gaps.max()) <= TOL_LOGIT,
-          f"every served token of the longest request scores within "
-          f"{TOL_LOGIT} of a full forward's best (widest "
-          f"{float(gaps.max()):.3f})")
-    st = eng.moe_stats()
-    check(st["dropped"] == 0 and st["tokens"][-1] > 0,
-          f"expert layers dropped {st['dropped']} routed pairs of "
-          f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
-    check_expert_forms(eng, st, sz)
+          == [eng.cache.Pw if w else eng.P for w in eng.cache.window_layers],
+          f"two classes of pages: {eng.P} full, {eng.cache.Pw} window (a "
+          f"ring of {ring} a row)")
+    check(longest > ring * page,
+          f"the longest context {longest} wrapped its ring at {ring * page}")
     c = eng.cache.counts()["classes"]
     check(c["full"]["used"] == 0 and c["window"]["used"] == 0,
           f"both classes back to free: {c}")
-    found = kernel_names(eng.lowered_text(("decode",)))
-    # the kernel is jitted on its own: one call site a kind in the text,
-    # however many layers call it
-    check(sz.rehearsal or (
-        found.get("paged_decode_attention", 0) >= 1
-        and found.get("paged_window_decode_attention", 0) >= 1),
-        f"program ('decode',) holds Mosaic calls {found}")
-    shapes = {a.shape for pair in eng.pools for a in pair}
-    for site in [("decode",)] + sorted(
-            s for s in eng.program_sites() if s[0] == "prefill")[-1:]:
-        text = eng.compiled_text(site)
-        n = sum(eng.pool_copies(text, s) for s in shapes)
-        check(sz.rehearsal or n == 0,
-              f"compiled program {site}: {n} copies of a whole pool of "
-              f"either class")
-        if site == ("decode",):     # the routing counters ride along
-            check_decode_donation(eng, text, 3 * cfg.num_layers)
-    check_overlap(eng)
-    out = {"run_s": round(t_run, 2), "pool_pages": eng.P,
-           "window_pool_pages": eng.cache.Pw, "ring": ring}
-    # the next shape needs the memory
-    eng.release_pools()
-    for p in model.parameters():
-        p._value = None
-    del eng, pred, model
-    gc.collect()
-    jax.clear_caches()
-    return out
 
 
-def phase_serve_sparse(sz: Sizes) -> None:
-    """The decoder whose attention keeps the keys a learned index
-    chooses, through ServingEngine in its default mode: the decode
-    kernel under a kept mask agrees with its dense twin, every row of a
-    full forward keeps ``min(t + 1, top-k)`` keys, decode agrees with a
-    full forward, three pooled arrays a layer are written in place."""
-    import jax
+# -- serve_sparse, serve_sparse_mla: attention over the keys (the latent
+# cache rows) a learned index keeps ------------------------------------------
+def kept_rows(sz, topk: int):
+    """A decode batch for a kernel under a kept mask, at the decode
+    program's shapes: (r, pages a row, lengths, mask). A row keeps its
+    newest key and a quarter of the others, and of its second page the
+    newest key alone."""
     import jax.numpy as jnp
     import numpy as np
 
-    import paddle_tpu as paddle
-    from paddle_tpu.inference import (Config, ServingEngine,
-                                      create_predictor)
-    from paddle_tpu.models.hybrid_moe import (HybridMoEForCausalLM,
-                                              collect_selection,
-                                              sparse_moe_tiny)
-    from paddle_tpu.ops.pallas import decode_attention as da
-
-    _, device, events = start_child(sz.rehearsal)
-    cfg = sparse_moe_tiny(**sz.sparse)
-    page, B, topk = sz.page, sz.hybrid_batch, cfg.index_topk
+    page, B = sz.page, sz.hybrid_batch
     r = np.random.RandomState(0)
-    # the kernel alone under a kept mask, at the decode program's shapes
     ncols = -(-(max(sz.sparse_lens) + sz.sparse_new) // page)
     lens = np.resize([0, topk - 1, topk, topk + 1, 2 * page - 1,
                       ncols * page - 2], B).astype("int32")
-    P = B * ncols + 1
-    rnd = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.bfloat16)
-    KV = cfg.num_kv_heads
-    kp = rnd(P, KV, page, cfg.k_cache_width)
-    vp = rnd(P, KV, page, cfg.v_head_dim)
-    tbl = r.permutation(P - 1)[:B * ncols].reshape(B, ncols).astype("int32")
-    q = rnd(B, 1, cfg.num_heads, cfg.k_cache_width)
     cols = np.arange(ncols * page)[None]
-    keep = jnp.asarray((cols <= lens[:, None])
-                       & ((r.random_sample((B, ncols * page)) < 0.25)
-                          | (cols == lens[:, None])))
-    if sz.rehearsal or da.paged_supported(q.shape, kp.shape, vp.shape):
-        got = da.paged_decode_attention(
-            q, kp, vp, tbl, lens, scale=cfg.softmax_scale, keep=keep,
-            interpret=sz.rehearsal)
-        want = da.paged_attention_dense(q, kp, vp, tbl, lens,
-                                        cfg.softmax_scale, None, None, keep)
-        err = float(jnp.abs(got.astype(jnp.float32)
-                            - want.astype(jnp.float32)).max())
-        check(err <= TOL_ATTN,
-              f"decode kernel under a kept mask ({cfg.num_heads // KV} "
-              f"query heads a KV head, {ncols} pages a row) within "
-              f"{TOL_ATTN} of its dense twin (max err {err:.2e})")
-    t0 = time.perf_counter()
-    paddle.set_default_dtype(cfg.dtype)
-    paddle.seed(0)
-    model = HybridMoEForCausalLM(cfg)
-    pred = create_predictor(
-        Config().set_model(model).enable_paged_kv(page_size=page))
-    mix = [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
-           for n in sz.sparse_lens]
-    eng = ServingEngine(pred, max_batch=B, debug_invariants=True,
-                        pool_pages=sz.sparse_pool)
-    check(eng.cache.arrays == [3] * cfg.num_layers
-          and all(layer[2].shape[1:] == (1, page, cfg.index_cache_width)
-                  for layer in eng.pools),
-          f"three pooled arrays a layer, the index keys "
-          f"{eng.pools[0][2].shape}")
-    t_setup = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    rids = [eng.submit(p, max_new_tokens=sz.sparse_new) for p in mix]
-    done = eng.run()
-    t_run = time.perf_counter() - t0
-    outs = [np.asarray(done[rid].new_tokens) for rid in rids if rid in done]
-    check(len(outs) == len(rids)
-          and all(len(o) == sz.sparse_new for o in outs)
-          and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
-          f"every request returned {sz.sparse_new} tokens of the "
-          f"vocabulary; the longest context {len(mix[0]) + sz.sparse_new} "
-          f"is {(len(mix[0]) + sz.sparse_new) / topk:.1f} times the "
-          f"{topk} keys a query keeps")
-    # one forward over the whole context: what every row kept, and the
-    # served tokens' logits
-    from paddle_tpu.autograd import no_grad
-    from paddle_tpu.distributed.engine import bind_params
-
-    params = list(model.parameters())
-
-    from paddle_tpu.observability import moestats
-
-    def whole(pvals, ids):
-        with no_grad(), bind_params(params, pvals), \
-                collect_selection() as sets:
-            moestats.begin()
-            try:
-                logits = model.forward(ids)
-            finally:
-                recs = moestats.drain()
-        return (logits._value, [k[0].sum(-1) for k in sets],
-                [r["passes"] for r in recs if "passes" in r])
-
-    seq = np.concatenate([mix[0], outs[0][:-1]])
-    full, kept, passes = jax.jit(whole)(tuple(p._value for p in params),
-                                        jnp.asarray(seq[None, :]))
-    full = np.asarray(full[0].astype(jnp.float32))
-    want_kept = np.minimum(np.arange(len(seq)) + 1, topk)
-    wrong = sum(int((np.asarray(k) != want_kept).sum()) for k in kept)
-    check(len(kept) == cfg.num_layers and wrong == 0,
-          f"kept_keys_wrong == {wrong}: every row of {len(kept)} layers "
-          f"of a full forward keeps min(t + 1, {topk}) keys")
-    sel = eng.selection_stats()
-    check(sel["rows"] > 0 and sel["kept_keys_wrong"] == 0,
-          f"the decode steps' own count on the device: kept_keys_wrong "
-          f"== {sel['kept_keys_wrong']} of {sel['rows']} (row, layer) "
-          f"pairs")
-    at = full[len(mix[0]) - 1:]
-    gaps = at.max(-1) - at[np.arange(len(outs[0])), outs[0]]
-    check(float(gaps.max()) <= TOL_LOGIT,
-          f"every served token of the longest request scores within "
-          f"{TOL_LOGIT} of a full forward's best (widest "
-          f"{float(gaps.max()):.3f})")
-    st = eng.moe_stats()
-    check(st["dropped"] == 0 and st["tokens"][-1] > 0,
-          f"expert layers dropped {st['dropped']} routed pairs of "
-          f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
-    # the sorted rows each prefill bucket's expert layers hold at a time,
-    # and the passes a full forward's layers took over them (a rehearsal's
-    # few tokens take the batched form: no rows, no pass)
-    passes = [int(p) for p in passes]
-    check(all(m <= n for m, n in st["rows"].values())
-          and passes == [1] * len(passes)
-          and (sz.rehearsal or (len(passes) == cfg.num_layers and st["rows"]
-                                and all(m < n
-                                        for m, n in st["rows"].values()))),
-          f"sorted rows a prefill bucket (bound, routed pairs) "
-          f"{st['rows']}; passes of a full forward over {len(seq)} tokens, "
-          f"layer by layer: {passes}")
-    c = eng.cache.counts()
-    check(c["free"] == eng.cache.usable, f"every page back to free: {c}")
-    found = kernel_names(eng.lowered_text(("decode",)))
-    check(sz.rehearsal
-          or found.get("paged_sparse_decode_attention", 0) >= 1,
-          f"program ('decode',) holds Mosaic calls {found}")
-    shapes = {a.shape for layer in eng.pools for a in layer}
-    for site in [("decode",)] + sorted(
-            s for s in eng.program_sites() if s[0] == "prefill")[-1:]:
-        text = eng.compiled_text(site)
-        n = sum(eng.pool_copies(text, s) for s in shapes)
-        check(sz.rehearsal or n == 0,
-              f"compiled program {site}: {n} copies of a whole pool")
-        if site == ("decode",):     # the routing counters ride along
-            check_decode_donation(eng, text, 4 * cfg.num_layers)
-    check_overlap(eng)
-    finish_child("serve_sparse", device, events,
-                 {"setup_s": round(t_setup, 1), "run_s": round(t_run, 2),
-                  "pool_pages": eng.P, "kept_keys_wrong": wrong})
-
-
-def phase_serve_sparse_mla(sz: Sizes) -> None:
-    """The latent-attention decoder whose index selects rows of the
-    latent cache, through ServingEngine in its default mode: the latent
-    decode kernel under a kept mask agrees with its dense twin, every
-    row of a full forward keeps ``min(t + 1, top-k)`` rows, decode
-    agrees with a full forward, three pooled arrays a layer are written
-    in place."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    import paddle_tpu as paddle
-    from paddle_tpu.inference import (Config, ServingEngine,
-                                      create_predictor)
-    from paddle_tpu.models.mla_moe import (MLAMoEForCausalLM,
-                                           sparse_mla_tiny)
-    from paddle_tpu.ops.pallas import mla_attention as ma
-    from paddle_tpu.ops.sparse_attention import collect_selection
-
-    _, device, events = start_child(sz.rehearsal)
-    cfg = sparse_mla_tiny(**sz.sparse_mla)
-    page, B, topk = sz.page, sz.hybrid_batch, cfg.index_topk
-    H, dc = cfg.num_heads, cfg.kv_lora_rank
-    r = np.random.RandomState(0)
-    # the kernel alone under a kept mask, at the decode program's shapes
-    ncols = -(-(max(sz.sparse_lens) + sz.sparse_new) // page)
-    lens = np.resize([0, topk - 1, topk, topk + 1, 2 * page - 1,
-                      ncols * page - 2], B).astype("int32")
-    P = B * ncols + 1
-    rnd = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.bfloat16)
-    cp, rp = rnd(P, 1, page, dc), rnd(P, 1, page, cfg.rope_cache_width)
-    tbl = r.permutation(P - 1)[:B * ncols].reshape(B, ncols).astype("int32")
-    ql, qr = rnd(B, H, dc), rnd(B, H, cfg.rope_cache_width)
-    cols = np.arange(ncols * page)[None]
+    newest = cols == lens[:, None]
     keep = (cols <= lens[:, None]) & (
-        (r.random_sample((B, ncols * page)) < 0.25)
-        | (cols == lens[:, None]))
-    keep[:, page:2 * page] &= cols[:, page:2 * page] == lens[:, None]
-    keep = jnp.asarray(keep)
-    if sz.rehearsal or ma.mla_paged_supported(ql.shape, cp.shape, rp.shape):
-        want = ma.mla_paged_attention_dense(
-            ql[:, None], qr[:, None], cp, rp, tbl, lens,
-            cfg.softmax_scale, keep)[:, 0]
-        got = ma.mla_paged_decode_attention(
-            ql, qr, cp, rp, tbl, lens, cfg.softmax_scale, keep=keep,
-            interpret=sz.rehearsal)
-        err = float(jnp.abs(got.astype(jnp.float32)
-                            - want.astype(jnp.float32)).max())
-        check(err <= TOL_ATTN,
-              f"latent decode kernel under a kept mask ({H} heads, "
-              f"{ncols} pages a row) within {TOL_ATTN} of its dense twin "
-              f"(max err {err:.2e})")
-    # the prefill's attention over kept sets, keys wider than values
-    from paddle_tpu.ops.pallas import kept_attention as ka
+        (r.random_sample((B, ncols * page)) < 0.25) | newest)
+    keep[:, page:2 * page] &= newest[:, page:2 * page]
+    return r, ncols, lens, jnp.asarray(keep)
 
+
+def sparse_kernels(sz, cfg) -> None:
+    r, ncols, lens, keep = kept_rows(sz, cfg.index_topk)
+    paged_twin(sz, cfg, r, "decode kernel under a kept mask",
+               cfg.num_kv_heads, ncols, lens, keep=keep)
+
+
+def sparse_mla_kernels(sz, cfg) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas import kept_attention as ka
+    from paddle_tpu.ops.pallas import mla_attention as ma
+
+    r, ncols, lens, keep = kept_rows(sz, cfg.index_topk)
+    P, tbl, rnd = paged_batch(sz, r, ncols)
+    H, dc, B = cfg.num_heads, cfg.kv_lora_rank, sz.hybrid_batch
+    cp = rnd(P, 1, sz.page, dc)
+    rp = rnd(P, 1, sz.page, cfg.rope_cache_width)
+    ql, qr = rnd(B, H, dc), rnd(B, H, cfg.rope_cache_width)
+    if sz.rehearsal or ma.mla_paged_supported(ql.shape, cp.shape, rp.shape):
+        check_twin(
+            f"latent decode kernel under a kept mask ({H} heads, {ncols} "
+            f"pages a row)",
+            ma.mla_paged_decode_attention(
+                ql, qr, cp, rp, tbl, lens, cfg.softmax_scale, keep=keep,
+                interpret=sz.rehearsal),
+            ma.mla_paged_attention_dense(
+                ql[:, None], qr[:, None], cp, rp, tbl, lens,
+                cfg.softmax_scale, keep)[:, 0])
+    # the prefill's attention over kept sets, keys wider than values
     Sk, blk = (64, 16) if sz.rehearsal else (1024, 512)
     kq, kk, kv = rnd(1, Sk, 8, 192), rnd(1, Sk, 8, 192), rnd(1, Sk, 8, 128)
     tri = np.tril(np.ones((Sk, Sk), bool))
@@ -1518,111 +1193,177 @@ def phase_serve_sparse_mla(sz: Sizes) -> None:
     kmask[:blk] = tri[:blk]
     kmask = jnp.asarray(kmask[None])
     if sz.rehearsal or ka.kept_flash_supported(kq.shape, kv.shape, blk):
-        got = ka.kept_flash_attention(kq, kk, kv, kmask, 0.07, blk,
-                                      interpret=sz.rehearsal)
-        want = ka.kept_attention_dense(kq, kk, kv, kmask, 0.07)
-        err = float(jnp.abs(got.astype(jnp.float32)
-                            - want.astype(jnp.float32)).max())
-        check(err <= TOL_ATTN,
-              f"prefill attention over kept sets ({Sk} rows, 8 heads of "
-              f"192 against 128) within {TOL_ATTN} of its dense twin "
-              f"(max err {err:.2e})")
-    t0 = time.perf_counter()
-    paddle.set_default_dtype(cfg.dtype)
-    paddle.seed(0)
-    model = MLAMoEForCausalLM(cfg)
-    pred = create_predictor(
-        Config().set_model(model).enable_paged_kv(page_size=page))
-    mix = [r.randint(0, cfg.vocab_size, (int(n),)).astype("int32")
-           for n in sz.sparse_lens]
-    eng = ServingEngine(pred, max_batch=B, debug_invariants=True,
-                        pool_pages=sz.sparse_mla_pool)
-    check(eng.cache.arrays == [3] * cfg.num_layers
-          and all(layer[0].shape[1:] == (1, page, dc)
-                  and layer[2].shape[1:] == (1, page, cfg.index_cache_width)
-                  for layer in eng.pools),
-          f"three pooled arrays a layer: latents {eng.pools[0][0].shape}, "
-          f"rotated keys {eng.pools[0][1].shape}, index keys "
-          f"{eng.pools[0][2].shape}")
-    t_setup = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    rids = [eng.submit(p, max_new_tokens=sz.sparse_new) for p in mix]
-    done = eng.run()
-    t_run = time.perf_counter() - t0
-    outs = [np.asarray(done[rid].new_tokens) for rid in rids if rid in done]
-    check(len(outs) == len(rids)
-          and all(len(o) == sz.sparse_new for o in outs)
-          and all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
-          f"every request returned {sz.sparse_new} tokens of the "
-          f"vocabulary; the longest context {len(mix[0]) + sz.sparse_new} "
-          f"is {(len(mix[0]) + sz.sparse_new) / topk:.1f} times the "
-          f"{topk} rows a query keeps")
-    # one forward over the whole context: what every row kept, and the
-    # served tokens' logits
+        check_twin(
+            f"prefill attention over kept sets ({Sk} rows, 8 heads of 192 "
+            f"against 128)",
+            ka.kept_flash_attention(kq, kk, kv, kmask, 0.07, blk,
+                                    interpret=sz.rehearsal),
+            ka.kept_attention_dense(kq, kk, kv, kmask, 0.07))
+
+
+def selecting_forward(ctx, seq):
+    """One jitted forward over the whole context (an eager one compiles
+    a program a length): the logits, and into ``ctx`` what every row kept
+    a layer and the passes its sorted expert layers took."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
     from paddle_tpu.autograd import no_grad
     from paddle_tpu.distributed.engine import bind_params
+    from paddle_tpu.observability import moestats
+    from paddle_tpu.ops.sparse_attention import collect_selection
 
-    params = list(model.parameters())
+    params = list(ctx.model.parameters())
 
     def whole(pvals, ids):
         with no_grad(), bind_params(params, pvals), \
                 collect_selection() as sets:
-            logits = model.forward(ids)
-        return logits._value, [k[0].sum(-1) for k in sets]
+            moestats.begin()
+            try:
+                logits = ctx.model.forward(ids)
+            finally:
+                recs = moestats.drain()
+        return (logits._value, [k[0].sum(-1) for k in sets],
+                [r["passes"] for r in recs if "passes" in r])
 
-    seq = np.concatenate([mix[0], outs[0][:-1]])
-    full, kept = jax.jit(whole)(tuple(p._value for p in params),
-                                jnp.asarray(seq[None, :]))
-    full = np.asarray(full[0].astype(jnp.float32))
-    want_kept = np.minimum(np.arange(len(seq)) + 1, topk)
-    wrong = sum(int((np.asarray(k) != want_kept).sum()) for k in kept)
-    check(len(kept) == cfg.num_layers and wrong == 0,
-          f"kept_keys_wrong == {wrong}: every row of {len(kept)} layers "
-          f"of a full forward keeps min(t + 1, {topk}) cache rows")
+    full, kept, passes = jax.jit(whole)(tuple(p._value for p in params),
+                                        jnp.asarray(seq[None, :]))
+    ctx.kept = [np.asarray(k) for k in kept]
+    ctx.passes = [int(p) for p in passes]
+    return np.asarray(full[0].astype(jnp.float32))
+
+
+def sparse_extra(ctx) -> None:
+    """Every row of a full forward, and every row the decode steps
+    selected for, kept ``min(t + 1, top-k)`` keys, at contexts several
+    times the top-k; every page came back."""
+    import numpy as np
+
+    sz, cfg, eng = ctx.sz, ctx.cfg, ctx.eng
+    topk, longest = cfg.index_topk, len(ctx.mix[0]) + ctx.new
+    check(longest > 2 * topk,
+          f"the longest context {longest} is {longest / topk:.1f} times "
+          f"the {topk} keys a query keeps")
+    want_kept = np.minimum(np.arange(len(ctx.full)) + 1, topk)
+    wrong = sum(int((k != want_kept).sum()) for k in ctx.kept)
+    check(len(ctx.kept) == cfg.num_layers and wrong == 0,
+          f"kept_keys_wrong == {wrong}: every row of {len(ctx.kept)} "
+          f"layers of a full forward keeps min(t + 1, {topk}) keys")
     sel = eng.selection_stats()
     check(sel["rows"] > 0 and sel["kept_keys_wrong"] == 0,
           f"the decode steps' own count on the device: kept_keys_wrong "
           f"== {sel['kept_keys_wrong']} of {sel['rows']} (row, layer) "
           f"pairs")
-    at = full[len(mix[0]) - 1:]
-    gaps = at.max(-1) - at[np.arange(len(outs[0])), outs[0]]
-    check(float(gaps.max()) <= TOL_LOGIT,
-          f"every served token of the longest request scores within "
-          f"{TOL_LOGIT} of a full forward's best (widest "
-          f"{float(gaps.max()):.3f})")
-    st = eng.moe_stats()
-    check(st["dropped"] == 0 and st["tokens"][-1] > 0
-          and st["tokens"][0] == 0,
-          f"expert layers dropped {st['dropped']} routed pairs of "
-          f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}; the "
-          f"dense layer routed none")
     c = eng.cache.counts()
     check(c["free"] == eng.cache.usable, f"every page back to free: {c}")
-    found = kernel_names(eng.lowered_text(("decode",)))
-    check(sz.rehearsal
-          or found.get("mla_paged_sparse_decode_attention", 0) >= 1,
-          f"program ('decode',) holds Mosaic calls {found}")
+
+
+def sparse_rows(ctx) -> None:
+    sparse_extra(ctx)
+    # the sorted rows each prefill bucket's expert layers hold at a time,
+    # and the passes a full forward's layers took over them (a rehearsal's
+    # few tokens take the batched form: no rows, no pass)
+    rows, passes = ctx.eng.moe_stats()["rows"], ctx.passes
+    check(all(m <= n for m, n in rows.values())
+          and passes == [1] * len(passes)
+          and (ctx.sz.rehearsal or (
+              len(passes) == ctx.cfg.num_layers and rows
+              and all(m < n for m, n in rows.values()))),
+          f"sorted rows a prefill bucket (bound, routed pairs) {rows}; "
+          f"passes of a full forward over {len(ctx.full)} tokens, layer "
+          f"by layer: {passes}")
+
+
+def sparse_mla_extra(ctx) -> None:
+    sparse_extra(ctx)
+    sz, cfg, eng = ctx.sz, ctx.cfg, ctx.eng
+    check(eng.moe_stats()["tokens"][0] == 0,
+          "the dense layer routed no pair")
     site = max(s for s in eng.program_sites() if s[0] == "prefill")
     found = kernel_names(eng.lowered_text(site))
     check(sz.rehearsal or site[1] < cfg.attention_block
           or found.get("kept_flash_attention", 0) == cfg.num_layers,
           f"program {site} holds Mosaic calls {found}")
-    shapes = {a.shape for layer in eng.pools for a in layer}
-    copies = 0
-    for site in [("decode",)] + sorted(
-            s for s in eng.program_sites() if s[0] == "prefill")[-1:]:
-        text = eng.compiled_text(site)
-        n = sum(eng.pool_copies(text, s) for s in shapes)
-        copies += n
-        check(sz.rehearsal or n == 0,
-              f"compiled program {site}: {n} copies of a whole pool")
-        if site == ("decode",):     # the routing counters ride along
-            check_decode_donation(eng, text, 4 * cfg.num_layers)
-    check_overlap(eng)
-    finish_child("serve_sparse_mla", device, events,
-                 {"setup_s": round(t_setup, 1), "run_s": round(t_run, 2),
-                  "pool_pages": eng.P, "kept_keys_wrong": wrong,
-                  "pool_copies": copies})
+
+
+def cold_traffic(lens: str, new: str):
+    """The named ``Sizes`` lengths as the mix, no warm-up."""
+    return lambda sz: {"warm": (), "mix": getattr(sz, lens),
+                       "new": getattr(sz, new), "batch": sz.hybrid_batch}
+
+
+def hybrid_case(label: str, sizes: str, lens: str) -> ServeCase:
+    return ServeCase(
+        label=label, model=("hybrid_moe", "HybridMoEConfig",
+                            "HybridMoEForCausalLM", sizes),
+        traffic=cold_traffic(lens, "hybrid_new"),
+        engines=lambda sz: [{"debug_invariants": True}],
+        pools=None, kernels=hybrid_kernels,
+        # each jitted on its own: one call site in the text for all layers
+        decode_kernels=lambda cfg: {"paged_decode_attention": 1,
+                                    "paged_window_decode_attention": 1},
+        donated=3,      # K, V, the routing counters
+        extra=hybrid_extra)
+
+
+SERVE_CASES = {
+    "serve": (ServeCase(
+        label="llama",
+        model=("llama", "LlamaConfig", "LlamaForCausalLM", "llama"),
+        # one prompt per prefill bucket (64 .. 2048), then a dozen
+        traffic=lambda sz: {"warm": sz.warm_lens, "mix": sz.mix_lens,
+                            "new": sz.new_tokens, "batch": sz.max_batch},
+        # bucketed prefill + fused decode scan, then the chunked unified step
+        engines=lambda sz: [{"decode_chunk": sz.decode_chunk},
+                            {"decode_chunk": sz.decode_chunk,
+                             "prefill_chunk": sz.prefill_chunk}],
+        pools=lambda cfg: [(cfg.num_kv_heads, cfg.head_dim)] * 2,
+        decode_kernels=lambda cfg: {"paged_decode_attention": 1,
+                                    "rms_norm_fused": 2 * cfg.num_layers + 1},
+        donated=2, extra=llama_extra),),
+    "serve_latent": (ServeCase(
+        label="latent",
+        model=("mla_moe", "MLAMoEConfig", "MLAMoEForCausalLM", "latent"),
+        # the prompts of up to 900 tokens (prefill buckets to 1,024)
+        traffic=lambda sz: {
+            "warm": [n for n in sz.warm_lens if n <= 900],
+            "mix": [n for n in sz.mix_lens if n <= 900],
+            "new": sz.new_tokens, "batch": sz.latent_batch},
+        engines=lambda sz: [{}],
+        # one latent and one rotated key a position
+        pools=lambda cfg: [(1, cfg.kv_lora_rank), (1, cfg.rope_cache_width)],
+        decode_kernels=lambda cfg: {
+            "mla_paged_decode_attention": cfg.num_layers},
+        donated=3),),       # latents, rotated keys, the routing counters
+    "serve_hybrid": (hybrid_case("mimo", "hybrid", "hybrid_lens"),
+                     hybrid_case("afmoe", "afmoe", "afmoe_lens")),
+    "serve_sparse": (ServeCase(
+        label="sparse", model=("hybrid_moe", "sparse_moe_tiny",
+                               "HybridMoEForCausalLM", "sparse"),
+        traffic=cold_traffic("sparse_lens", "sparse_new"),
+        engines=lambda sz: [{"debug_invariants": True,
+                             "pool_pages": sz.sparse_pool}],
+        pools=lambda cfg: [(cfg.num_kv_heads, cfg.k_cache_width),
+                           (cfg.num_kv_heads, cfg.v_head_dim),
+                           (1, cfg.index_cache_width)],
+        kernels=sparse_kernels,
+        decode_kernels=lambda cfg: {"paged_sparse_decode_attention": 1},
+        donated=4,      # K, V, index keys, the counters
+        forward=selecting_forward, extra=sparse_rows),),
+    "serve_sparse_mla": (ServeCase(
+        label="sparse_mla", model=("mla_moe", "sparse_mla_tiny",
+                                   "MLAMoEForCausalLM", "sparse_mla"),
+        traffic=cold_traffic("sparse_lens", "sparse_new"),
+        engines=lambda sz: [{"debug_invariants": True,
+                             "pool_pages": sz.sparse_mla_pool}],
+        pools=lambda cfg: [(1, cfg.kv_lora_rank), (1, cfg.rope_cache_width),
+                           (1, cfg.index_cache_width)],
+        kernels=sparse_mla_kernels,
+        decode_kernels=lambda cfg: {
+            "mla_paged_sparse_decode_attention": cfg.num_layers},
+        donated=4, forward=selecting_forward, extra=sparse_mla_extra),),
+}
 
 
 # ---------------------------------------------------------------------------
@@ -1677,8 +1418,7 @@ def main(argv=None) -> int:
                          "layouts; fewer than four TPU devices is an error")
     ap.add_argument("--phases", default=None,
                     help="comma-separated subset, run in the given order: "
-                         + ",".join(ONE_CHIP_PHASES + FOUR_CHIP_PHASES
-                                    + EXTRA_PHASES))
+                         + ",".join(ALL_PHASES))
     ap.add_argument("--rehearsal", action="store_true",
                     help="toy sizes on the CPU; output says REHEARSAL")
     ap.add_argument("--phase", default=None, help=argparse.SUPPRESS)
@@ -1689,16 +1429,8 @@ def main(argv=None) -> int:
         try:
             if args.phase == "kernels":
                 phase_kernels(sz)
-            elif args.phase == "serve":
-                phase_serve(sz)
-            elif args.phase == "serve_latent":
-                phase_serve_latent(sz)
-            elif args.phase == "serve_hybrid":
-                phase_serve_hybrid(sz)
-            elif args.phase == "serve_sparse":
-                phase_serve_sparse(sz)
-            elif args.phase == "serve_sparse_mla":
-                phase_serve_sparse_mla(sz)
+            elif args.phase in SERVE_CASES:
+                phase_serve(sz, args.phase)
             else:
                 phase_train(sz, args.phase)
         except Failed as e:
@@ -1709,8 +1441,7 @@ def main(argv=None) -> int:
 
     if args.phases:
         phases = tuple(args.phases.split(","))
-        unknown = set(phases) - set(ONE_CHIP_PHASES + FOUR_CHIP_PHASES
-                                    + EXTRA_PHASES)
+        unknown = set(phases) - set(ALL_PHASES)
         if unknown:
             ap.error(f"unknown phases {sorted(unknown)}")
     else:

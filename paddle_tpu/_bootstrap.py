@@ -2,14 +2,15 @@
 persistent compile cache, then (multi-process only) join the jax runtime.
 
 Compile cache (:func:`configure_compile_cache`): every entry point —
-``chip_smoke.py``, ``bench.py``'s children, the examples, the launcher's
-children — imports the package first, so they all share one cache. If
+``chip_smoke.py``'s children, ``benchmarks.run``, the examples, the
+launcher's children — imports the package first, so they all share one
+cache. If
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and the code
 sets no directory. Otherwise the cache lives at ONE fixed path inside
 the checkout (:data:`CACHE_ROOT`, git-ignored), never a temp dir, pid or
 time: a directory that moves never hits. A process pinned to the CPU
-(``JAX_PLATFORMS=cpu``: the tests, ``BENCH_FORCE_CPU=1``, rehearsals)
-gets no cache from this code.
+(``JAX_PLATFORMS=cpu``: the tests, rehearsals) gets no cache from this
+code.
 
 Multi-process:
 

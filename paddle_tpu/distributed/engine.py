@@ -101,7 +101,7 @@ class _ZeroPlan:
     the stacked-params seam as a scan_trips-exact lax.scan), else per
     parameter; the grads keep flowing through EXACTLY the stage-2
     reduce-scatter path, which is what makes stage-3 loss/params
-    bit-match stage-2 (pinned by tests/bench).
+    bit-match stage-2 (pinned by tests/test_zero_stage3.py).
 
     ``row_dims`` (the per-bucket ZeRO plan): {id(param): k} marking k
     leading stacked-layer dims the shard-dim search must skip — set
@@ -344,7 +344,7 @@ class ParallelEngine:
         # a healthy train loop compiles each (shape, spec) signature
         # once and shows only cache hits in steady state — regressions
         # that force recompiles (e.g. an overlap path keyed on a traced
-        # shape) surface here and on the bench JSON lines
+        # shape) surface here
         self.stats = CompileStats()
         # the tape's op nodes in the newest traced step's backward():
         # (took an explicit grad kernel, took the generic jax.vjp, which
@@ -1335,8 +1335,8 @@ class ParallelEngine:
         get_registry().snapshot()    # feeds the stall flight-record ring
 
     def metrics_snapshot(self):
-        """Fetch pending scalars, then return the registry snapshot —
-        the in-process API bench.py emits from."""
+        """Fetch pending scalars, then return the registry snapshot:
+        the in-process API."""
         self._flush_pending_scalars()
         from ..observability import get_registry
 

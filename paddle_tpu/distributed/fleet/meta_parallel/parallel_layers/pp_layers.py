@@ -41,8 +41,8 @@ interleave. Each microbatch makes ``vpp`` circuits of the ICI ring
 (stage S-1's output ppermutes back into stage 0, which applies its
 NEXT chunk to it), so the scan runs ``T = vpp*M + S - 1`` ticks of
 ``1/vpp``-sized stage work: bubble (S-1)/(vpp*M+S-1) instead of
-(S-1)/(M+S-1) — the up-to-~2x small-M win measured in
-PP_SCHEDULE.json (tools/pp_schedule_measure.py). Microbatches are
+(S-1)/(M+S-1): up to ~2x at small M by that arithmetic, which no chip
+run has measured (the four-chip cell runs vpp = 1). Microbatches are
 admitted in groups of S (circuit v+1 of a microbatch re-enters stage 0
 exactly S ticks after circuit v left it — a pure shift register, no
 carry buffering), which is why ``accumulate_steps % pp == 0`` is

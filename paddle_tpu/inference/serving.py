@@ -2034,8 +2034,8 @@ class ServingEngine:
         the full B x chunk round, HBM traffic from the decode
         executable's memory ledger, ICI from its comm ledger's wire
         bytes, against the median measured round time. Serving decode
-        is expected HBM-bound on chip (the weight-bandwidth roofline
-        bench.py's decode lines report against)."""
+        is expected HBM-bound on chip (the weight-bandwidth
+        roofline)."""
         cfg = getattr(self.pred._model, "config", None)
         n_params = None
         fn = getattr(cfg, "num_params", None)
@@ -2108,7 +2108,7 @@ class ServingEngine:
 
     def metrics_snapshot(self):
         """Current registry snapshot (TTFT/TPOT histograms, occupancy,
-        counters) — the in-process API bench.py emits from."""
+        counters): the in-process API."""
         self._note_tick()
         from ..observability import get_registry
 

@@ -417,7 +417,7 @@ def train_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
             "analytic pipeline bubble fraction of the attached "
             "schedule, (S-1)/(vpp*M+S-1) — published per step when a "
             "pipelined model is attached, labeled by the virtual-stage "
-            "count (realized bubble: tools/pp_schedule_measure.py)",
+            "count",
             labelnames=("pp_vpp",)),
         "compiles": r.counter(
             "paddle_tpu_compiles_total",

@@ -3,11 +3,10 @@
 MFU follows the PaLM/Megatron convention (PAPERS.md: Megatron-LM): a
 decoder-only transformer spends ~6*N FLOPs per token (fwd 2N + bwd 4N),
 optionally plus the attention term 12*L*h*S that 6N omits; recompute
-FLOPs are deliberately EXCLUDED so remat lowers measured MFU honestly
-(the bench.py convention). The accountant reads whatever config the
-model carries (GPTConfig / LlamaConfig expose ``num_params()``); when
-there is no config it falls back to summing parameter sizes, which the
-engine can always do.
+FLOPs are deliberately EXCLUDED so remat lowers measured MFU honestly.
+The accountant reads whatever config the model carries (GPTConfig /
+LlamaConfig expose ``num_params()``); when there is no config it falls
+back to summing parameter sizes, which the engine can always do.
 """
 from __future__ import annotations
 
@@ -24,7 +23,7 @@ __all__ = ["params_from_config", "train_flops_per_token",
 # Gbit/s interchip interconnect; likewise "TPU v4", "TPU v5p", "TPU
 # v6e"). A TPU whose kind is not here is an error, not a default: every
 # MFU, roofline share and comm floor would silently be about another
-# chip. bench.py imports this table.
+# chip.
 _V5E = (197e12, 0.819e12, 200e9)
 _V5P = (459e12, 2.765e12, 600e9)
 _V6E = (918e12, 1.64e12, 448e9)

@@ -26,10 +26,10 @@ provider protocol, and — for loss/grad events — dumps a stall-style
 flight record (rate-limited) so the post-mortem holds the metric ring
 around the spike.
 
-Detection arms only after ``warmup`` observations per signal, so the
-deterministic bench/smoke lines (a handful of steps) run entirely
-unarmed and MUST report zero events — ``bench_compare`` gates the
-``*_health_spike_events`` lines at exactly 0.
+Detection arms only after ``warmup`` observations per signal, so a
+deterministic smoke run of a handful of steps runs entirely unarmed and
+MUST report zero events (tests/test_goodput_health.py holds clean runs
+at ``event_count() == 0``).
 
 Deliberate spike injection for tests rides the failpoint table
 (``health.loss_spike=corrupt@N`` perturbs the N-th OBSERVED loss —

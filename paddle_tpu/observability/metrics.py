@@ -6,11 +6,11 @@ redesigned for a pull/push hybrid: every metric lives in one in-process
 ``MetricsRegistry`` and is exported three ways —
 
 - ``snapshot()``       — the in-process API (dict of plain values; the
-  flight recorder keeps the last N of these, bench.py emits them),
+  flight recorder keeps the last N of these),
 - ``prometheus_text()``— Prometheus/OpenMetrics text exposition for a
   scrape endpoint (``parse_prometheus_text`` round-trips it in tests),
 - ``JsonlSink``        — append-one-JSON-object-per-snapshot to disk
-  (the bench.py lineage: machine-parsable longitudinal records).
+  (machine-parsable longitudinal records).
 
 Histograms use FIXED buckets so percentile estimates are rank-stable
 and mergeable across hosts (Megatron/vLLM-style p50/p99 TTFT / TPOT /
@@ -415,8 +415,8 @@ def _split_labels(body: str) -> List[str]:
 
 
 class JsonlSink:
-    """Append registry snapshots to a JSONL file (one object per line,
-    the bench.py emission format). ``read`` round-trips the file."""
+    """Append registry snapshots to a JSONL file (one object per
+    line). ``read`` round-trips the file."""
 
     def __init__(self, path: str):
         self.path = str(path)

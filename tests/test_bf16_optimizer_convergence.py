@@ -1,10 +1,11 @@
 """bf16 optimizer-moment convergence guard (VERDICT item 10).
 
-The TPU bench trains with AdamW moments stored bfloat16 (state_dtype=
-"bfloat16", re-quantized every step; update math stays f32 —
+The train cells (benchmarks/, chip_smoke.py) train with AdamW moments
+stored bfloat16 (state_dtype="bfloat16", re-quantized every step; update
+math stays f32 —
 optimizer/__init__.py _cast_state_in). This guards that the loss curve
 stays inside a tolerance band of f32 moments over 200 steps — if this
-ever fails, flip the bench default or add stochastic rounding."""
+ever fails, flip the cells' state_dtype or add stochastic rounding."""
 import pytest
 import numpy as np
 

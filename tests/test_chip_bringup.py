@@ -2,11 +2,14 @@
 the CPU: every Pallas kernel lowers for the TPU at the smoke's full-width
 shapes; importing the package or the launcher creates no backend; the
 compile-cache rule; the peaks table refuses an unknown TPU; a kernel the
-gate admitted is called bare, so its failure is the caller's failure.
+gate admitted is called bare, so its failure is the caller's failure;
+every entry of the smoke's ``SERVE_CASES`` is complete and passes its
+phase's checks at toy sizes.
 """
 import inspect
 import json
 import os
+import re
 import subprocess
 import sys
 import warnings
@@ -53,6 +56,206 @@ def test_no_kernel_resolves_interpret_for_itself():
         fn = getattr(fn, "__wrapped__", fn)
         assert inspect.signature(fn).parameters["interpret"].default \
             is False, fn
+
+
+# ---------------------------------------------------------------------------
+# the serving phases: one body, a table of cases
+# ---------------------------------------------------------------------------
+_SERVE = [(phase, case) for phase, cases in chip_smoke.SERVE_CASES.items()
+          for case in cases]
+_SERVE_IDS = [case.label for _, case in _SERVE]
+
+
+@pytest.fixture(scope="module")
+def pallas_sources():
+    root = os.path.join(REPO, "paddle_tpu", "ops", "pallas")
+    return "".join(open(os.path.join(root, f)).read()
+                   for f in sorted(os.listdir(root)) if f.endswith(".py"))
+
+
+@pytest.mark.parametrize("rehearsal", [True, False], ids=["toy", "full"])
+@pytest.mark.parametrize("phase,case", _SERVE, ids=_SERVE_IDS)
+def test_serve_case_is_complete(phase, case, rehearsal, pallas_sources):
+    """An entry of ``SERVE_CASES`` builds its config at both sizes (no
+    model, no engine), says everything ``serve_case`` asks of it, names
+    decode kernels that exist, and belongs to a phase the parent runs."""
+    sz = chip_smoke.Sizes(rehearsal)
+    cfg, model_cls = case.build(sz)
+    assert isinstance(model_cls, type) and cfg.num_layers >= 1
+    assert phase in chip_smoke.ONE_CHIP_PHASES + chip_smoke.EXTRA_PHASES
+    assert chip_smoke.PHASE_TIMEOUT[phase] > 0
+    traffic = case.traffic(sz)
+    assert sorted(traffic) == ["batch", "mix", "new", "warm"]
+    longest = max(traffic["mix"]) + traffic["new"]
+    assert traffic["mix"] and longest <= cfg.max_position_embeddings
+    assert all(n + traffic["new"] <= cfg.max_position_embeddings
+               for n in traffic["warm"])
+    engines = case.engines(sz)
+    assert engines and all(isinstance(kw, dict) for kw in engines)
+    assert case.pools is None or all(
+        len(hw) == 2 and min(hw) >= 1 for hw in case.pools(cfg))
+    assert case.donated >= 2
+    kernels = case.decode_kernels(cfg)
+    assert kernels
+    for name, calls in kernels.items():
+        assert calls >= 1 and f'"{name}"' in pallas_sources, name
+
+
+@pytest.fixture(scope="module")
+def jax_events():
+    return chip_smoke.JaxEvents()
+
+
+@pytest.fixture
+def smoke_globals():
+    """``serve_case`` sets the process's default dtype, as a child of the
+    smoke may; a test worker gets it back."""
+    dtype = paddle.get_default_dtype()
+    yield
+    paddle.set_default_dtype(dtype)
+    paddle.set_flags({"use_pallas_kernels": True})
+
+
+def _stem(line: str) -> str:
+    return re.sub(r"\d[\d.]*(e[-+]?\d+)?", "#", line)[:_STEM]
+
+
+_STEM = 56
+# the `  ok   ` lines a rehearsal of each case prints, in order, numbers
+# as "#" and cut at _STEM characters
+_OK_LINES = {
+    "llama": [
+        "a layer pools # arrays of # pages: #x#x# | #x#x#",
+        "warm-up mix drained (#)",
+        "every request returned # tokens of the vocabulary",
+        "no compile after warm-up (eng.stats.compiles #, # XLA co",
+        "reference forward: finite logits of shape (#, #)",
+        "first token # scores within # of the reference forward's",
+        "every served token of the first request scores within # ",
+        "program ('decode',) holds Mosaic calls {} (asked: {'page",
+        "compiled program ('decode',): # copies of a whole pool #",
+        "compiled program ('decode',) donates the # arrays it was",
+        "compiled program ('prefill', #): # copies of a whole poo",
+        "decode rounds launched with the one before unretired: # ",
+        "prefill programs attend as {#: 'dense', #: 'dense', #: '",
+        "program ('prefill', #) holds no other attention kernel",
+        "program ('prefill', #) holds Mosaic calls {}",
+        "program ('prefill', #) holds no other attention kernel",
+        "program ('prefill', #) holds Mosaic calls {}",
+        "program ('prefill', #) holds no other attention kernel",
+        "program ('prefill', #) holds Mosaic calls {}",
+        "the programs that ran: [\"('decode',)\", \"('prefill', #)\",",
+        "first tokens of the prompts in the buckets [#, #] score ",
+        "a layer pools # arrays of # pages: #x#x# | #x#x#",
+        "warm-up mix drained (#)",
+        "every request returned # tokens of the vocabulary",
+        "no compile after warm-up (eng.stats.compiles #, # XLA co",
+        "reference forward: finite logits of shape (#, #)",
+        "first token # scores within # of the reference forward's",
+        "every served token of the first request scores within # ",
+        "program ('decode',) holds Mosaic calls {} (asked: {'page",
+        "compiled program ('unified', #): # copies of a whole poo",
+        "compiled program ('decode',): # copies of a whole pool #",
+        "compiled program ('decode',) donates the # arrays it was",
+        "decode rounds launched with the one before unretired: # ",
+        "program ('unified', #) holds Mosaic calls {}",
+        "the programs that ran: [\"('decode',)\", \"('unified', #)\"]",
+        "first tokens of the prompts in the buckets [#, #] score ",
+    ],
+    "latent": [
+        "a layer pools # arrays of # pages: #x#x# | #x#x#",
+        "warm-up mix drained (#)",
+        "every request returned # tokens of the vocabulary",
+        "no compile after warm-up (eng.stats.compiles #, # XLA co",
+        "reference forward: finite logits of shape (#, #)",
+        "first token # scores within # of the reference forward's",
+        "every served token of the first request scores within # ",
+        "expert layers dropped # routed pairs of #",
+        "expert products' forms {'decode': 'batched', 'prefill': ",
+        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "program ('decode',) holds Mosaic calls {} (asked: {'mla_",
+        "compiled program ('decode',): # copies of a whole pool #",
+        "compiled program ('decode',) donates the # arrays it was",
+        "compiled program ('prefill', #): # copies of a whole poo",
+        "decode rounds launched with the one before unretired: # ",
+    ],
+    "mimo": [
+        "full decode kernel, keys # against values #, sink False,",
+        "window decode kernel, keys # against values #, sink True",
+        "every request returned # tokens of the vocabulary",
+        "reference forward: finite logits of shape (#, #)",
+        "first token # scores within # of the reference forward's",
+        "every served token of the first request scores within # ",
+        "expert layers dropped # routed pairs of #",
+        "expert products' forms {'decode': 'batched', 'prefill': ",
+        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "program ('decode',) holds Mosaic calls {} (asked: {'page",
+        "compiled program ('decode',): # copies of a whole pool #",
+        "compiled program ('decode',) donates the # arrays it was",
+        "compiled program ('prefill', #): # copies of a whole poo",
+        "decode rounds launched with the one before unretired: # ",
+        "two classes of pages: # full, # window (a ring of # a ro",
+        "the longest context # wrapped its ring at #",
+        "both classes back to free: {'full': {'used': #, 'free': ",
+    ],
+    "afmoe": [
+        "full decode kernel, keys # against values #, sink False,",
+        "window decode kernel, keys # against values #, sink Fals",
+        "every request returned # tokens of the vocabulary",
+        "reference forward: finite logits of shape (#, #)",
+        "first token # scores within # of the reference forward's",
+        "every served token of the first request scores within # ",
+        "expert layers dropped # routed pairs of #",
+        "expert products' forms {'decode': 'batched', 'prefill': ",
+        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "program ('decode',) holds Mosaic calls {} (asked: {'page",
+        "compiled program ('decode',): # copies of a whole pool #",
+        "compiled program ('decode',) donates the # arrays it was",
+        "compiled program ('prefill', #): # copies of a whole poo",
+        "decode rounds launched with the one before unretired: # ",
+        "two classes of pages: # full, # window (a ring of # a ro",
+        "the longest context # wrapped its ring at #",
+        "both classes back to free: {'full': {'used': #, 'free': ",
+    ],
+    "sparse": [
+        "decode kernel under a kept mask (# query heads a KV head",
+        "a layer pools # arrays of # pages: #x#x# | #x#x# | #x#x#",
+        "every request returned # tokens of the vocabulary",
+        "reference forward: finite logits of shape (#, #)",
+        "first token # scores within # of the reference forward's",
+        "every served token of the first request scores within # ",
+        "expert layers dropped # routed pairs of #",
+        "expert products' forms {'decode': 'batched', 'prefill': ",
+        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "program ('decode',) holds Mosaic calls {} (asked: {'page",
+        "compiled program ('decode',): # copies of a whole pool #",
+        "compiled program ('decode',) donates the # arrays it was",
+        "compiled program ('prefill', #): # copies of a whole poo",
+        "decode rounds launched with the one before unretired: # ",
+        "the longest context # is # times the # keys a query keep",
+        "kept_keys_wrong == #: every row of # layers of a full fo",
+        "the decode steps' own count on the device: kept_keys_wro",
+        "every page back to free: {'free': #, 'idle': #, 'registe",
+        "sorted rows a prefill bucket (bound, routed pairs) {}; p",
+    ],
+}
+
+
+@pytest.mark.parametrize(
+    "case", [c for _, c in _SERVE if c.label in _OK_LINES],
+    ids=list(_OK_LINES))
+def test_serve_case_rehearsal_in_process(case, capsys, jax_events,
+                                         smoke_globals):
+    """The body and the case's own checks run at toy sizes on the CPU
+    and print the lines they printed when this list was written
+    (sparse_mla takes 35 s here and is left to ``--rehearsal``)."""
+    chip_smoke.serve_case(chip_smoke.Sizes(rehearsal=True), case,
+                          jax_events, {"kind": "cpu"})
+    out = capsys.readouterr().out
+    assert "  FAIL " not in out
+    ok = [_stem(ln[7:]) for ln in out.splitlines()
+          if ln.startswith("  ok   ")]
+    assert ok == _OK_LINES[case.label]
 
 
 # ---------------------------------------------------------------------------

@@ -15,8 +15,6 @@ Under test:
 - per-request serving spans: lifecycle stages, bounded ring, Chrome
   trace export, stage-latency histogram
 - the stdlib /metrics HTTP exporter round-trips the exposition
-- tools/bench_compare: regression verdicts + trajectory on synthetic
-  rounds
 """
 import json
 import sys
@@ -735,78 +733,6 @@ class TestExporter:
 
 
 # ---------------------------------------------------------------------------
-# tools/bench_compare
-# ---------------------------------------------------------------------------
-class TestBenchCompare:
-    def _round(self, n, lines):
-        return {"n": n, "cmd": "python bench.py", "rc": 0,
-                "tail": "\n".join(json.dumps(ln) for ln in lines)}
-
-    def _write(self, tmp_path, docs):
-        for i, doc in enumerate(docs, 1):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps(doc))
-
-    def test_regression_and_trajectory(self, tmp_path):
-        repo = Path(__file__).resolve().parents[1]
-        sys.path.insert(0, str(repo))
-        try:
-            from tools import bench_compare as bc
-        finally:
-            sys.path.remove(str(repo))
-        mk = lambda v, ms: [
-            {"metric": "gpt_smoke_train_tokens_per_sec", "value": v,
-             "unit": "tokens/s", "vs_baseline": 0.0},
-            {"metric": "llama_ms_per_token", "value": ms, "unit": "ms",
-             "vs_baseline": 0.0},
-            {"metric": "pallas_kernel_parity_interpret", "value": 1.0,
-             "unit": "pass", "vs_baseline": 1.0},
-            {"metric": "bench_moe", "value": 0.0, "unit": "error",
-             "vs_baseline": 0.0, "error": "boom"},
-        ]
-        self._write(tmp_path, [self._round(1, mk(1000.0, 10.0)),
-                               self._round(2, mk(600.0, 6.0))])
-        rounds = bc.load_rounds(str(tmp_path))
-        assert [n for n, _ in rounds] == [1, 2]
-        rows = {r["metric"]: r for r in bc.compare(
-            bc.parse_metrics(rounds[0][1]),
-            bc.parse_metrics(rounds[1][1]), threshold=0.25)}
-        # tokens/s dropped 40% -> regressed; ms dropped -> improved
-        assert rows["gpt_smoke_train_tokens_per_sec"]["verdict"] == \
-            "regressed"
-        assert rows["llama_ms_per_token"]["verdict"] == "improved"
-        assert rows["pallas_kernel_parity_interpret"]["verdict"] == "ok"
-        assert rows["bench_moe"]["verdict"] == "unmeasured"
-        traj = bc.trajectory(rounds)
-        assert traj["gpt_smoke_train_tokens_per_sec"] == [1000.0, 600.0]
-        assert traj["bench_moe"] == [None, None]
-        # CLI: default exit 0, --strict exits 1 on the regression
-        assert bc.main(["--dir", str(tmp_path)]) == 0
-        assert bc.main(["--dir", str(tmp_path), "--strict"]) == 1
-        assert bc.main(["--dir", str(tmp_path), "--strict",
-                        "--json"]) == 1
-
-    def test_exact_gate_and_insufficient_rounds(self, tmp_path):
-        repo = Path(__file__).resolve().parents[1]
-        sys.path.insert(0, str(repo))
-        try:
-            from tools import bench_compare as bc
-        finally:
-            sys.path.remove(str(repo))
-        assert bc.main(["--dir", str(tmp_path)]) == 2   # no rounds
-        lines1 = [{"metric": "pallas_kernel_parity_interpret",
-                   "value": 1.0, "unit": "pass", "vs_baseline": 1.0}]
-        lines2 = [{"metric": "pallas_kernel_parity_interpret",
-                   "value": 0.0, "unit": "pass", "vs_baseline": 0.0}]
-        self._write(tmp_path, [self._round(1, lines1),
-                               self._round(2, lines2)])
-        rounds = bc.load_rounds(str(tmp_path))
-        rows = bc.compare(bc.parse_metrics(rounds[0][1]),
-                          bc.parse_metrics(rounds[1][1]), 0.25)
-        assert rows[0]["verdict"] == "regressed"   # parity is exact
-
-
-# ---------------------------------------------------------------------------
 # tpulint: the new modules must stay clean with ZERO baseline entries
 # ---------------------------------------------------------------------------
 def test_tpulint_commledger_surface_zero_baseline():
@@ -816,8 +742,7 @@ def test_tpulint_commledger_surface_zero_baseline():
         from tools.tpulint import ALL_RULES, lint_paths
 
         findings = lint_paths(
-            [repo / "paddle_tpu" / "observability",
-             repo / "tools" / "bench_compare.py"],
+            [repo / "paddle_tpu" / "observability"],
             ALL_RULES, root=repo)
     finally:
         sys.path.remove(str(repo))

@@ -16,8 +16,7 @@ Under test:
   and NO recompile, on the engine counters AND the registry counters
 - ServingEngine — shed decisions land in the span ring as zero-length
   "shed" events, exported as Chrome "i" instants
-- tools/run_report.py — journal waterfall/timeline + BENCH goodput
-  trajectory; tools/step_report.py --strict goodput gate
+- tools/run_report.py — journal waterfall/timeline
 - SIGKILL matrix (slow): a kill mid-segment leaves a parseable
   journal; the relaunch closes it as recovery_restart and the
   cross-restart goodput_pct matches the straight run
@@ -528,37 +527,21 @@ class TestServingShedTraces:
 
 
 # ---------------------------------------------------------------------------
-# tools: run_report + step_report goodput gate
+# tools: run_report
 # ---------------------------------------------------------------------------
 def _import_tools():
     repo = Path(__file__).resolve().parents[1]
     sys.path.insert(0, str(repo))
     try:
         from tools import run_report as rr
-        from tools import step_report as sr
     finally:
         sys.path.remove(str(repo))
-    return rr, sr
-
-
-def _bench_round(n, goodput_pct):
-    line = {"metric": "gpt13b_hybrid_smoke_tokens_per_sec",
-            "value": 3000.0, "unit": "tokens/s", "vs_baseline": 0.0,
-            "roofline": {"bound": "hbm-bound", "step_seconds": 0.01,
-                         "seconds": {}, "headroom_pct": {},
-                         "util_pct": {}},
-            "goodput": {"goodput_pct": goodput_pct,
-                        "wall_seconds": 12.5, "restarts": 0,
-                        "segment_pct": {"compile": 90.0,
-                                        "step_compute": goodput_pct},
-                        "segments": {}}}
-    return {"n": n, "cmd": "python bench.py", "rc": 0,
-            "tail": json.dumps(line)}
+    return rr
 
 
 class TestRunReportTool:
     def test_journal_report_and_timeline(self, tmp_path, capsys):
-        rr, _ = _import_tools()
+        rr = _import_tools()
         led = gp.attach_dir(str(tmp_path))
         with gp.segment("step_compute"):
             time.sleep(0.02)
@@ -571,73 +554,14 @@ class TestRunReportTool:
         whats = [e["what"] for e in rep["timeline"]]
         assert "start" in whats and "resume" in whats
         assert "loss_spike" in whats and "recovery_restart" in whats
-        assert rr.main(["--run-dir", str(tmp_path),
-                        "--bench-dir", str(tmp_path)]) == 0
+        assert rr.main(["--run-dir", str(tmp_path)]) == 0
         out = capsys.readouterr().out
         assert "goodput waterfall" in out
         assert "step_compute" in out
 
-    def test_bench_trajectory_and_json(self, tmp_path, capsys):
-        rr, _ = _import_tools()
-        for i, pct in ((1, 40.0), (2, 55.0)):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps(_bench_round(i, pct)))
-        traj = rr.goodput_trajectory(
-            __import__("tools.bench_compare",
-                       fromlist=["load_rounds"]).load_rounds(
-                           str(tmp_path)))
-        assert traj["gpt13b_hybrid_smoke_tokens_per_sec"] == \
-            [40.0, 55.0]
-        assert rr.main(["--bench-dir", str(tmp_path), "--json"]) == 0
-        doc = json.loads(capsys.readouterr().out)
-        assert doc["bench_goodput_trajectory"][
-            "gpt13b_hybrid_smoke_tokens_per_sec"] == [40.0, 55.0]
-
     def test_nothing_found_exit_code(self, tmp_path):
-        rr, _ = _import_tools()
-        assert rr.main(["--run-dir", str(tmp_path / "none"),
-                        "--bench-dir", str(tmp_path)]) == 2
-
-
-class TestStepReportGoodputGate:
-    def test_goodput_rows_and_column(self, tmp_path, capsys):
-        _, sr = _import_tools()
-        from tools.bench_compare import load_rounds, parse_metrics
-
-        (tmp_path / "BENCH_r01.json").write_text(
-            json.dumps(_bench_round(1, 61.0)))
-        metrics = parse_metrics(load_rounds(str(tmp_path))[-1][1])
-        rows = sr.goodput_rows(metrics)
-        assert rows[0]["goodput_pct"] == 61.0
-        assert sr.main(["--dir", str(tmp_path)]) == 0
-        out = capsys.readouterr().out
-        assert "goodput" in out and "61.0" in out
-
-    def test_strict_gate_on_regression(self, tmp_path, capsys):
-        _, sr = _import_tools()
-        (tmp_path / "BENCH_r01.json").write_text(
-            json.dumps(_bench_round(1, 60.0)))
-        (tmp_path / "BENCH_r02.json").write_text(
-            json.dumps(_bench_round(2, 40.0)))
-        # 20pp drop: flagged under --strict, reported otherwise
-        assert sr.main(["--dir", str(tmp_path)]) == 0
-        assert sr.main(["--dir", str(tmp_path), "--strict"]) == 1
-        capsys.readouterr()
-        assert sr.main(["--dir", str(tmp_path), "--strict",
-                        "--json"]) == 1
-        doc = json.loads(capsys.readouterr().out)
-        assert doc["goodput_regressions"][0]["drop_pp"] == 20.0
-        # a generous tolerance passes
-        assert sr.main(["--dir", str(tmp_path), "--strict",
-                        "--goodput-drop-pp", "25"]) == 0
-
-    def test_strict_ok_within_tolerance(self, tmp_path):
-        _, sr = _import_tools()
-        (tmp_path / "BENCH_r01.json").write_text(
-            json.dumps(_bench_round(1, 60.0)))
-        (tmp_path / "BENCH_r02.json").write_text(
-            json.dumps(_bench_round(2, 58.0)))
-        assert sr.main(["--dir", str(tmp_path), "--strict"]) == 0
+        rr = _import_tools()
+        assert rr.main(["--run-dir", str(tmp_path / "none")]) == 2
 
 
 # ---------------------------------------------------------------------------

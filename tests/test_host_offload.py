@@ -289,15 +289,25 @@ def _build_gpt_hybrid(offload):
     return dm, opt, scaler, batch
 
 
+@pytest.fixture(scope="module")
+def gpt_hybrid_runs():
+    """Three steps of the topology with the tier off, then on: (losses,
+    wrapped model, optimizer, scaler, batch) of each."""
+    out = {}
+    for name, offload in (("off", None),
+                          ("on", {"optimizer": True, "prefetch_buckets": 2})):
+        dm, opt, sc, b = _build_gpt_hybrid(offload)
+        losses = [float(dm.train_batch(b, opt, scaler=sc))
+                  for _ in range(3)]
+        out[name] = (losses, dm, opt, sc, b)
+    return out
+
+
 class TestGpt13bSmokeParity:
-    def test_hybrid_offload_bit_exact_and_recompile_free(self):
-        dm0, opt0, sc0, b0 = _build_gpt_hybrid(None)
-        gold = [float(dm0.train_batch(b0, opt0, scaler=sc0))
-                for _ in range(3)]
-        dm, opt, sc, b = _build_gpt_hybrid(
-            {"optimizer": True, "prefetch_buckets": 2})
-        got = [float(dm.train_batch(b, opt, scaler=sc))
-               for _ in range(3)]
+    def test_hybrid_offload_bit_exact_and_recompile_free(
+            self, gpt_hybrid_runs):
+        gold = gpt_hybrid_runs["off"][0]
+        got, dm, opt, sc, b = gpt_hybrid_runs["on"]
         assert got == gold  # bit-exact across mp x pp x sharding + vpp
         eng = dm._engine
         n = eng.stats.compiles
@@ -313,6 +323,20 @@ class TestGpt13bSmokeParity:
         assert tier.host_resident_bytes() == slot_closed
         assert (tier.transfer_bytes(direction="d2h")
                 - tier.transfer_bytes(direction="h2d")) == slot_closed
+
+    def test_hybrid_offload_memory_closed_form(self, gpt_hybrid_runs):
+        """Between steps the measured accounting books the offloaded
+        slots under ``host_state`` == the closed form, byte for byte, and
+        the device-resident image is the tier-off run's less exactly
+        that."""
+        off = ml.account_engine(gpt_hybrid_runs["off"][1]._engine)
+        eng = gpt_hybrid_runs["on"][1]._engine
+        on = ml.account_engine(eng)
+        for k, v in ml.closed_form_state_bytes(eng).items():
+            assert on.components.get(k) == v, (k, on.components)
+        host = on.components["host_state"]
+        assert host == eng._offload.host_resident_bytes() > 0
+        assert on.device_bytes == off.device_bytes - host
 
 
 # ---------------------------------------------------------------------------

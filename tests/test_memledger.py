@@ -18,9 +18,7 @@ Under test:
 - /healthz on the metrics exporter: 200 + snapshot age that scrapes
   do NOT refresh
 - flight records carry the memory context
-- tools/step_report over synthetic BENCH rounds
-- tpulint: memledger + step_report stay clean with ZERO baseline
-  entries
+- tpulint: memledger stays clean with ZERO baseline entries
 """
 import json
 import sys
@@ -75,7 +73,7 @@ def dp_mem_engine():
 
 @pytest.fixture(scope="module")
 def hybrid_engine():
-    """The gpt13b bench smoke config: mp2 x pp2 x sharding2 stage-2,
+    """The GPT hybrid smoke topology: mp2 x pp2 x sharding2 stage-2,
     vpp=2 — the pinned target for chunk-aware state accounting."""
     from paddle_tpu.distributed import fleet
     from paddle_tpu.models import GPTForCausalLMPipe
@@ -262,7 +260,7 @@ class TestStateAccounting:
                           "analytic_drift"}
         # no offload on this engine: nothing host-resident
         assert d["device_bytes"] == d["measured_bytes"]
-        json.dumps(d)     # bench lines must serialize
+        json.dumps(d)     # the report must serialize
 
     def test_autotuner_crosscheck_matches_gauge_math(self):
         from paddle_tpu.distributed.auto_tuner import AutoTuner
@@ -462,92 +460,6 @@ class TestFlightMemoryContext:
 
 
 # ---------------------------------------------------------------------------
-# tools/step_report
-# ---------------------------------------------------------------------------
-class TestStepReport:
-    def _round(self, n, lines):
-        return {"n": n, "cmd": "python bench.py", "rc": 0,
-                "tail": "\n".join(json.dumps(ln) for ln in lines)}
-
-    def _line(self, bound="hbm-bound"):
-        return {
-            "metric": "gpt13b_hybrid_smoke_tokens_per_sec",
-            "value": 3000.0, "unit": "tokens/s", "vs_baseline": 0.0,
-            "memory": {
-                "executable": {"program": "train", "temp_bytes": 10,
-                               "argument_bytes": 20, "output_bytes": 30,
-                               "alias_bytes": 5, "peak_bytes": 55},
-                "state": {"components": {"params": 100,
-                                         "optimizer_state": 200},
-                          "analytic_drift": 0.25}},
-            "roofline": {"bound": bound, "step_seconds": 0.01,
-                         "seconds": {"compute": 0.002, "hbm": 0.006,
-                                     "ici": 0.001},
-                         "headroom_pct": {"compute": 66.7, "hbm": 0.0,
-                                          "ici": 83.3},
-                         "util_pct": {"compute": 20.0, "hbm": 60.0,
-                                      "ici": 10.0}},
-        }
-
-    def _import(self):
-        repo = Path(__file__).resolve().parents[1]
-        sys.path.insert(0, str(repo))
-        try:
-            from tools import step_report as sr
-        finally:
-            sys.path.remove(str(repo))
-        return sr
-
-    def test_rows_and_trajectory(self, tmp_path):
-        sr = self._import()
-        from tools.bench_compare import load_rounds, parse_metrics
-
-        docs = [self._round(1, [self._line("compute-bound")]),
-                self._round(2, [self._line("hbm-bound")])]
-        for i, doc in enumerate(docs, 1):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps(doc))
-        rounds = load_rounds(str(tmp_path))
-        metrics = parse_metrics(rounds[-1][1])
-        roof = sr.roofline_rows(metrics)
-        assert roof[0]["bound"] == "hbm-bound"
-        assert roof[0]["headroom_pct"]["ici"] == 83.3
-        mem = sr.memory_rows(metrics)
-        assert mem[0]["executables"]["train"]["temp_bytes"] == 10
-        assert mem[0]["state"]["params"] == 100
-        assert mem[0]["analytic_drift"] == 0.25
-        traj = sr.verdict_trajectory(rounds)
-        assert traj["gpt13b_hybrid_smoke_tokens_per_sec"] == ["C", "H"]
-        assert sr.main(["--dir", str(tmp_path)]) == 0
-        assert sr.main(["--dir", str(tmp_path), "--json"]) == 0
-
-    def test_serving_multi_executable_form(self, tmp_path):
-        sr = self._import()
-        from tools.bench_compare import parse_metrics
-
-        line = {"metric": "serving", "value": 1.0, "unit": "tokens/s",
-                "vs_baseline": 0.0,
-                "memory": {"executables": {
-                    "decode": {"temp_bytes": 1, "argument_bytes": 2,
-                               "output_bytes": 3, "alias_bytes": 0,
-                               "peak_bytes": 6}},
-                    "state": {"params_bytes": 7, "kv_pool_bytes": 8}},
-                "roofline": {"bound": "unknown", "step_seconds": 0.0,
-                             "seconds": {}, "headroom_pct": {},
-                             "util_pct": {}}}
-        doc = self._round(1, [line])
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps(doc))
-        metrics = parse_metrics(doc["tail"])
-        mem = sr.memory_rows(metrics)
-        assert mem[0]["executables"]["decode"]["peak_bytes"] == 6
-        assert mem[0]["state"]["kv_pool_bytes"] == 8
-
-    def test_no_rounds_exit_code(self, tmp_path):
-        sr = self._import()
-        assert sr.main(["--dir", str(tmp_path)]) == 2
-
-
-# ---------------------------------------------------------------------------
 # tpulint: the new modules must stay clean with ZERO baseline entries
 # ---------------------------------------------------------------------------
 def test_tpulint_memledger_surface_zero_baseline():
@@ -557,8 +469,7 @@ def test_tpulint_memledger_surface_zero_baseline():
         from tools.tpulint import ALL_RULES, lint_paths
 
         findings = lint_paths(
-            [repo / "paddle_tpu" / "observability" / "memledger.py",
-             repo / "tools" / "step_report.py"],
+            [repo / "paddle_tpu" / "observability" / "memledger.py"],
             ALL_RULES, root=repo)
     finally:
         sys.path.remove(str(repo))

@@ -287,7 +287,7 @@ class TestMeshParity:
 
 
 # ---------------------------------------------------------------------------
-# GPT-MoE end-to-end on the TP x EP x DP mesh (the bench config)
+# GPT-MoE end-to-end on the TP x EP x DP mesh
 # ---------------------------------------------------------------------------
 class TestGptMoeHybrid:
     def test_trains_with_ring_and_matches_golden_first_step(self):

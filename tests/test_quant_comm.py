@@ -445,8 +445,7 @@ class TestCheckpointResidual:
 
 def _gpt_pipe(quant_dtype="int8", chunk=64):
     """The gpt13b smoke topology (mp2 x pp2 x sharding2, stage 2,
-    comm_overlap, rings on) with quant_comm — the bench flagship
-    shape, tiny."""
+    comm_overlap, rings on) with quant_comm, tiny."""
     from paddle_tpu.models import GPTForCausalLMPipe
     from paddle_tpu.models.gpt import GPTConfig
 

@@ -14,6 +14,9 @@ Under test (inference/serving.py, the two PR-16 serving optimizations):
   admit/evict/preempt/shed/finish (debug_invariants mode)
 - ZERO recompiles after warmup with both features on (the compile
   lattice gains no data-dependent shapes)
+- a multi-tenant trace (a few system prompts, many users) served with
+  the cache on, off and on under speculation gives ONE token stream,
+  its fed + skipped ledger closes and most lookups hit
 """
 import numpy as np
 import pytest
@@ -304,3 +307,41 @@ class TestComposedCompileStability:
         for req in done.values():
             ref = _solo(tiny_model, req.prompt, req.max_new_tokens)
             np.testing.assert_array_equal(req.output_ids, ref)
+
+
+class TestMultiTenantTrace:
+    def test_cache_on_off_and_speculation_give_one_stream(
+            self, tiny_model, paged_pred):
+        """Both features reorder the same computation: 12 users over 3
+        system prompts of 4 pages arrive two a round; the three serves
+        agree token for token, every prompt token was fed or skipped
+        exactly once, and more than half of the page lookups hit."""
+        r = np.random.RandomState(16)
+        systems = [r.randint(1, 256, (4 * PAGE,)) for _ in range(3)]
+        trace = [np.concatenate([systems[r.randint(3)],
+                                 r.randint(1, 256, (r.randint(4, 12),))])
+                 for _ in range(12)]
+
+        def serve(**kw):
+            eng = ServingEngine(paged_pred, max_batch=4, prefill_chunk=16,
+                                debug_invariants=True, **kw)
+            rids, i = [], 0
+            while i < len(trace) or eng.queue or eng.num_active:
+                for p in trace[i:i + 2]:
+                    rids.append(eng.submit(p, max_new_tokens=8))
+                i += 2
+                eng.step()
+            eng.run()           # nothing left in flight
+            return eng, [tuple(eng.finished[rid].new_tokens)
+                         for rid in rids]
+
+        eng_on, out_on = serve(prefix_cache=True)
+        _, out_off = serve()
+        _, out_spec = serve(prefix_cache=True, draft_predictor=paged_pred,
+                            spec_tokens=3)
+        assert out_on == out_off == out_spec
+        assert all(len(o) == 8 for o in out_on)
+        s = eng_on.prefix_cache_stats()
+        assert s["fed_tokens"] + s["skipped_tokens"] == \
+            sum(len(p) for p in trace)
+        assert s["hits"] / s["lookups"] > 0.5

@@ -146,7 +146,7 @@ class TestFlatParity:
         _assert_same_trajectory(l3, l2)
         # params: stage 2 and stage 3 are different XLA programs, so
         # elementwise-update fusion may differ by an ulp — the repo's
-        # parity gate (<= 1e-5, the bench _EXACT bound) applies
+        # parity gate (<= 1e-5) applies
         for p2, p3 in zip(m2.parameters(), m3.parameters()):
             np.testing.assert_allclose(np.asarray(p3._value),
                                        np.asarray(p2._value),

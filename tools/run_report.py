@@ -1,6 +1,5 @@
 """run_report: render a run's goodput waterfall and health-event
-timeline from the crash-durable goodput journal (+ the BENCH_r*
-goodput trajectory).
+timeline from the crash-durable goodput journal.
 
 The goodput ledger (paddle_tpu/observability/goodput.py) journals every
 second of a — possibly crash-interrupted — run into
@@ -16,16 +15,11 @@ human-facing view:
   productive step seconds / wall seconds — spanning every restart the
   journal absorbed,
 - **event timeline**: health events (loss/grad spikes, stalls,
-  restart signals) and process restarts in run-relative time,
-- **BENCH trajectory**: every bench line carrying a ``goodput``
-  section, its ``goodput_pct`` across all BENCH_r*.json rounds (the
-  longitudinal column next to bench_compare's throughput and
-  step_report's roofline verdicts).
+  restart signals) and process restarts in run-relative time.
 
 Usage::
 
-    python -m tools.run_report --run-dir <ckpt base> [--bench-dir REPO]
-                               [--json]
+    python -m tools.run_report --run-dir <ckpt base> [--json]
     python -m tools.run_report --merge <host-dir> <host-dir>... [--json]
 
 ``--merge`` overlays several hosts' goodput journals into one fleet
@@ -35,11 +29,8 @@ timeline on the fleet clock (seconds since the earliest start any
 journal recorded). The full cross-host view (step-time skew, byte
 totals from metrics.jsonl) lives in tools/fleet_report.py.
 
-Exit codes: 0 on success, 2 when neither a journal nor bench rounds
-were found. The tool only reads; regression gating lives in
-tools/bench_compare.py (``goodput_pct`` higher-better,
-``*_health_spike_events`` exact-0) and ``tools/step_report.py
---strict``.
+Exit codes: 0 on success, 2 when no journal was found. The tool only
+reads.
 """
 from __future__ import annotations
 
@@ -47,13 +38,11 @@ import argparse
 import json
 import os
 import sys
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 from paddle_tpu.observability import goodput as _gp
-from tools.bench_compare import load_rounds, parse_metrics
 
-__all__ = ["journal_report", "goodput_trajectory", "merge_report",
-           "main"]
+__all__ = ["journal_report", "merge_report", "main"]
 
 _BAR_WIDTH = 40
 
@@ -95,25 +84,6 @@ def journal_report(base_or_path: str) -> Optional[Dict[str, Any]]:
                 "seconds": round(float(r["t1"]) - float(r["t0"]), 3)})
     timeline.sort(key=lambda e: e["t"])
     return {"journal": path, "summary": summary, "timeline": timeline}
-
-
-def goodput_trajectory(rounds: List[Tuple[int, str]]
-                       ) -> Dict[str, List[Optional[float]]]:
-    """{metric: [goodput_pct per round]} over every bench line that
-    ever carried a ``goodput`` section (None where a round lacks it)."""
-    parsed = [(n, parse_metrics(tail)) for n, tail in rounds]
-    names = sorted({m for _, p in parsed for m, line in p.items()
-                    if isinstance(line.get("goodput"), dict)})
-    out: Dict[str, List[Optional[float]]] = {}
-    for name in names:
-        vals: List[Optional[float]] = []
-        for _, p in parsed:
-            gp = (p.get(name) or {}).get("goodput")
-            vals.append(float(gp["goodput_pct"])
-                        if isinstance(gp, dict)
-                        and "goodput_pct" in gp else None)
-        out[name] = vals
-    return out
 
 
 def merge_report(dirs: List[str]) -> Dict[str, Any]:
@@ -209,41 +179,30 @@ def _print_merge(rep: Dict[str, Any]) -> None:
                   f"{e['what']:<18} {extra}")
 
 
-def _print_report(rep: Optional[Dict[str, Any]],
-                  traj: Dict[str, List[Optional[float]]],
-                  rounds: List[Tuple[int, str]]) -> None:
-    if rep is not None:
-        s = rep["summary"]
-        print(f"run_report: {rep['journal']}")
-        print(f"  wall {s['wall_seconds']:.3f}s   goodput "
-              f"{s['goodput_pct']:.1f}%   restarts {s['restarts']}   "
-              f"events {s['events']}")
-        print("\ngoodput waterfall (foreground segments sum to wall)")
-        segs = sorted(s["segments"].items(), key=lambda kv: -kv[1])
-        width = max((len(k) for k, _ in segs), default=8)
-        for seg, sec in segs:
-            pct = s["segment_pct"].get(seg, 0.0)
-            print(f"  {seg:<{width}} {sec:>10.3f}s {pct:>6.2f}% "
-                  f"{_bar(pct)}")
-        if s["overlapped_seconds"]:
-            over = "  ".join(f"{k} {v:.3f}s" for k, v in
-                             s["overlapped_seconds"].items())
-            print(f"  overlapped (off the critical path): {over}")
-        if rep["timeline"]:
-            print("\nevent timeline (t = seconds since run start)")
-            for e in rep["timeline"]:
-                extra = " ".join(f"{k}={e[k]}" for k in
-                                 ("pid", "step", "value", "z",
-                                  "seconds", "reason") if k in e)
-                print(f"  t+{e['t']:>10.3f}  {e['what']:<18} {extra}")
-    if traj:
-        print("\nBENCH goodput_pct trajectory "
-              f"({', '.join(f'r{n:02d}' for n, _ in rounds)})")
-        width = max(len(m) for m in traj)
-        for name, vals in traj.items():
-            cells = " ".join(f"{v:>8.2f}" if v is not None else
-                             f"{'-':>8}" for v in vals)
-            print(f"  {name:<{width}} {cells}")
+def _print_report(rep: Dict[str, Any]) -> None:
+    s = rep["summary"]
+    print(f"run_report: {rep['journal']}")
+    print(f"  wall {s['wall_seconds']:.3f}s   goodput "
+          f"{s['goodput_pct']:.1f}%   restarts {s['restarts']}   "
+          f"events {s['events']}")
+    print("\ngoodput waterfall (foreground segments sum to wall)")
+    segs = sorted(s["segments"].items(), key=lambda kv: -kv[1])
+    width = max((len(k) for k, _ in segs), default=8)
+    for seg, sec in segs:
+        pct = s["segment_pct"].get(seg, 0.0)
+        print(f"  {seg:<{width}} {sec:>10.3f}s {pct:>6.2f}% "
+              f"{_bar(pct)}")
+    if s["overlapped_seconds"]:
+        over = "  ".join(f"{k} {v:.3f}s" for k, v in
+                         s["overlapped_seconds"].items())
+        print(f"  overlapped (off the critical path): {over}")
+    if rep["timeline"]:
+        print("\nevent timeline (t = seconds since run start)")
+        for e in rep["timeline"]:
+            extra = " ".join(f"{k}={e[k]}" for k in
+                             ("pid", "step", "value", "z",
+                              "seconds", "reason") if k in e)
+            print(f"  t+{e['t']:>10.3f}  {e['what']:<18} {extra}")
 
 
 def main(argv=None) -> int:
@@ -252,8 +211,6 @@ def main(argv=None) -> int:
     ap.add_argument("--run-dir", default=None,
                     help="checkpoint base dir (or goodput.jsonl path) "
                          "holding the run's goodput journal")
-    ap.add_argument("--bench-dir", default=".",
-                    help="directory holding BENCH_r*.json (default .)")
     ap.add_argument("--merge", nargs="+", default=None,
                     metavar="host-dir",
                     help="overlay several hosts' goodput journals "
@@ -276,21 +233,15 @@ def main(argv=None) -> int:
         return 0
 
     rep = journal_report(args.run_dir) if args.run_dir else None
-    rounds = load_rounds(args.bench_dir)
-    traj = goodput_trajectory(rounds)
-    if rep is None and not traj:
+    if rep is None:
         print("run_report: no goodput journal"
-              + (f" under {args.run_dir!r}" if args.run_dir else "")
-              + f" and no BENCH goodput sections under "
-                f"{args.bench_dir!r}", file=sys.stderr)
+              + (f" under {args.run_dir!r}" if args.run_dir else ""),
+              file=sys.stderr)
         return 2
     if args.as_json:
-        print(json.dumps({
-            "run": rep,
-            "bench_goodput_trajectory": traj,
-            "rounds": [n for n, _ in rounds]}, indent=1))
+        print(json.dumps({"run": rep}, indent=1))
         return 0
-    _print_report(rep, traj, rounds)
+    _print_report(rep)
     return 0
 
 
