@@ -14,7 +14,8 @@ calls, at the full width of models the repo supports, on one TPU chip:
 
 Every serving phase is one body, ``serve_case``, over an entry of
 ``SERVE_CASES``, which holds what differs. Four run only when named in
-``--phases``: ``serve_latent`` (latent attention + routed experts),
+``--phases``: ``serve_latent`` (latent attention + routed experts; two
+shapes: sarvam's block and the shortcut-connected double layer),
 ``serve_hybrid`` (window and full attention layers: MiMo-V2's block, then
 the AFMoE block), ``serve_sparse`` and ``serve_sparse_mla`` (attention
 over the keys, or the latent cache rows, a learned index keeps). Each
@@ -67,7 +68,7 @@ ALL_PHASES = ONE_CHIP_PHASES + FOUR_CHIP_PHASES + EXTRA_PHASES
 # seconds per child, compilation included. The one-chip three sum to 1100,
 # inside that run's 1200 s; cold on a v5e they took 72, 122 and 106 s (PR 21)
 PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
-                 "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 400,
+                 "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 700,
                  "serve_hybrid": 900,    # two shapes since PR 35
                  "serve_sparse": 400, "serve_sparse_mla": 400}
 RESULT_TAG = "CHIP_SMOKE_PHASE_RESULT "
@@ -118,6 +119,12 @@ class Sizes:
             routed_scaling_factor=2.826, num_shared_experts=1,
             qk_norm=True, attention_gate=True, sandwich_norm=True,
             head_on_last_row=True)
+        # the shortcut-connected block: MLAMoEConfig's switches for it
+        shortcut = dict(
+            shortcut_moe=True, use_qk_norm=False, router_score_func="softmax",
+            router_bias=True, norm_topk_prob=False, mla_scale_q_lora=True,
+            mla_scale_kv_lora=True, num_shared_experts=0,
+            first_k_dense_replace=0, rope_scaling=None)
         if not rehearsal:
             # train: GPT-3 1.3B, the widths of benchmarks/configs/gpt3-1.3b
             self.gpt = dict(vocab_size=50304, hidden_size=2048, num_layers=24,
@@ -151,6 +158,22 @@ class Sizes:
                 num_experts_per_tok=8, max_position_embeddings=1152,
                 dtype="bfloat16")
             self.latent_batch = 32
+            # serve_latent's second shape, the shortcut-connected double
+            # layer at longcat-flash-omni's published widths: two layers
+            # (four latent attentions off a query latent, four dense
+            # parts, two expert branches holding 16 of 512 real experts
+            # beside 256 identity ones, top-12 of a softmax), an eighth
+            # of the vocabulary (2.69B parameters)
+            self.shortcut = dict(
+                vocab_size=16384, hidden_size=6144, num_layers=2,
+                num_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+                qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                intermediate_size=12288, moe_intermediate_size=2048,
+                num_experts=512, num_local_experts=16,
+                num_experts_per_tok=12, zero_expert_num=256,
+                routed_scaling_factor=6.0, rope_theta=1e7,
+                rms_norm_eps=1e-5, max_position_embeddings=1152,
+                dtype="bfloat16", **shortcut)
             # serve_hybrid: published widths (64 heads, keys 192 against
             # values 128, 8 and 4 KV heads, a window of 128 with a sink),
             # the dense layer and two expert layers holding 16 of the
@@ -226,6 +249,14 @@ class Sizes:
                 num_local_experts=4, num_experts_per_tok=4,
                 max_position_embeddings=288, dtype="bfloat16")
             self.latent_batch = 4
+            self.shortcut = dict(
+                vocab_size=512, hidden_size=128, num_layers=2, num_heads=8,
+                q_lora_rank=64, kv_lora_rank=128, qk_nope_head_dim=32,
+                qk_rope_head_dim=16, v_head_dim=32, intermediate_size=256,
+                moe_intermediate_size=64, num_experts=16,
+                num_local_experts=4, num_experts_per_tok=4,
+                zero_expert_num=8, routed_scaling_factor=6.0,
+                max_position_embeddings=288, dtype="bfloat16", **shortcut)
             self.hybrid = dict(
                 kinds, vocab_size=512, hidden_size=128,
                 attention_kinds=["full", "window", "window"], num_heads=8,
@@ -856,9 +887,11 @@ def serve_case(sz: Sizes, case: ServeCase, events: JaxEvents,
         eng = ServingEngine(pred, max_batch=batch, **kw)
         ctx = types.SimpleNamespace(sz=sz, cfg=cfg, model=model, eng=eng,
                                     warm=warm, mix=mix, new=new)
+        # pooled tuples: one a layer, two a layer of two attentions
+        tuples = cfg.num_layers * getattr(cfg, "attention_sublayers", 1)
         if case.pools:
             want = [(h, sz.page, w) for h, w in case.pools(cfg)]
-            check(eng.cache.arrays == [len(want)] * cfg.num_layers
+            check(eng.cache.arrays == [len(want)] * tuples
                   and all([a.shape[1:] for a in layer] == want
                           for layer in eng.pools),
                   f"a layer pools {len(want)} arrays of {eng.P} pages: "
@@ -912,7 +945,7 @@ def serve_case(sz: Sizes, case: ServeCase, events: JaxEvents,
         sites = eng.program_sites()
         st = eng.moe_stats()
         if st is not None:              # a model with routed experts
-            check(st["dropped"] == 0 and st["tokens"][-1] > 0,
+            check(st["dropped"] == 0 and st["tokens"].max() > 0,
                   f"expert layers dropped {st['dropped']} routed pairs of "
                   f"{int(st['tokens'].sum()) * cfg.num_experts_per_tok}")
             # their products: batched over the held experts in the decode
@@ -951,7 +984,7 @@ def serve_case(sz: Sizes, case: ServeCase, events: JaxEvents,
                 # expert model's counters), never its round array (tables,
                 # pos, token, mask), which every layer reads
                 donated = eng.donated_params(text)
-                check(len(donated) == case.donated * cfg.num_layers
+                check(len(donated) == case.donated * tuples
                       and all(name.startswith("state") for name in donated),
                       f"compiled program ('decode',) donates the "
                       f"{len(donated)} arrays it was lent and not its "
@@ -1090,6 +1123,26 @@ def llama_extra(ctx) -> None:
           f"first tokens of the prompts in the buckets "
           f"{sorted(long_buckets)} score within {TOL_LOGIT} of the dense "
           f"path's best logit (prompt length: (gap, same token) = {gaps})")
+
+
+# -- serve_latent's second shape: the shortcut-connected double layer -------
+def shortcut_extra(ctx) -> None:
+    """Every pick of the decode steps is a held real expert's, an absent
+    one's or an identity expert's, and identity picks are about their
+    share of the router's width."""
+    cfg, st = ctx.cfg, ctx.eng.moe_stats()
+    k, live = cfg.num_experts_per_tok, st["tokens"] > 0
+    check((st["pairs"].sum(1) + st["absent_pairs"] + st["zero_pairs"]
+           == st["tokens"] * k).all()
+          and live.tolist() == [True, False] * cfg.num_layers,
+          f"tokens x {k} = held + absent + identity pairs in every expert "
+          f"branch; its counter rides the first attention's tuple (tokens "
+          f"{st['tokens'].tolist()})")
+    share = st["zero_pairs"].sum() / (st["tokens"].sum() * k)
+    even = cfg.zero_expert_num / (cfg.num_experts + cfg.zero_expert_num)
+    check(even / 2 <= share <= even * 2,
+          f"identity experts took {share:.3f} of the decode steps' picks "
+          f"({even:.3f} of the router's outputs are theirs)")
 
 
 # -- serve_hybrid: window and full attention layers, two shapes -------------
@@ -1335,7 +1388,20 @@ SERVE_CASES = {
         pools=lambda cfg: [(1, cfg.kv_lora_rank), (1, cfg.rope_cache_width)],
         decode_kernels=lambda cfg: {
             "mla_paged_decode_attention": cfg.num_layers},
-        donated=3),),       # latents, rotated keys, the routing counters
+        donated=3),         # latents, rotated keys, the routing counters
+        ServeCase(
+        label="shortcut",
+        model=("mla_moe", "MLAMoEConfig", "MLAMoEForCausalLM", "shortcut"),
+        traffic=lambda sz: {
+            "warm": [n for n in sz.warm_lens if n <= 900],
+            "mix": [n for n in sz.mix_lens if n <= 900],
+            "new": sz.new_tokens, "batch": sz.latent_batch},
+        engines=lambda sz: [{}],
+        pools=lambda cfg: [(1, cfg.kv_lora_rank), (1, cfg.rope_cache_width)],
+        # two attentions a layer, each over its own pooled pair
+        decode_kernels=lambda cfg: {
+            "mla_paged_decode_attention": 2 * cfg.num_layers},
+        donated=3, extra=shortcut_extra)),
     "serve_hybrid": (hybrid_case("mimo", "hybrid", "hybrid_lens"),
                      hybrid_case("afmoe", "afmoe", "afmoe_lens")),
     "serve_sparse": (ServeCase(

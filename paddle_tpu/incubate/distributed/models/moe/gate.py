@@ -1,7 +1,8 @@
 """MoE gates: naive top-k, Switch (top-1), GShard (top-2), and the
-top-k gate over a sigmoid score with a selection bias or a softmax,
-over all experts or over the groups of experts it keeps first (no
-capacity, no drops).
+top-k gate over a sigmoid score or a softmax, with or without a
+selection bias, its weights renormalised over the chosen or left as the
+scores are, over all experts or over the groups of experts it keeps
+first (no capacity, no drops).
 
 TPU-native re-design of the reference's gate zoo
 (reference: python/paddle/incubate/distributed/models/moe/gate/
@@ -108,6 +109,16 @@ class SigmoidTopKGate(BaseGate):
       probabilities themselves, renormalised over the chosen; no bias
       (the gate then has no such parameter).
 
+    Two switches of their own: ``bias_on_choice`` (None = as above: a
+    sigmoid gate has the bias, a softmax gate has not; True gives a
+    softmax gate one too, added to the PROBABILITIES for the choice
+    alone) and ``norm_topk_prob`` (False: the weights are ``scaling *
+    score`` as the score function gave them, not divided by the chosen
+    ones' sum, so a token's weights need not add up to ``scaling``).
+    ``num_experts`` is the ROUTER's width: a layer with identity experts
+    (``GatedMoELayer(zero_expert_num=)``) routes over more outputs than
+    it has experts.
+
     So is the field the choice is made over (``n_group``,
     ``topk_group``; 0 = off, the top-k over ALL experts): with
     ``n_group`` > 1 the experts form that many groups of neighbours
@@ -128,7 +139,8 @@ class SigmoidTopKGate(BaseGate):
     def __init__(self, d_model, num_experts, topk: int = 8,
                  routed_scaling_factor: float = 1.0,
                  score_func: str = "sigmoid", n_group: int = 0,
-                 topk_group: int = 0, **kw):
+                 topk_group: int = 0, bias_on_choice: Optional[bool] = None,
+                 norm_topk_prob: bool = True, **kw):
         super().__init__(d_model, num_experts)
         if score_func not in self.SCORE_FUNCS:
             raise ValueError(f"score_func is one of {self.SCORE_FUNCS}, "
@@ -149,13 +161,17 @@ class SigmoidTopKGate(BaseGate):
         self.capacity_factor = None
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.score_func = score_func
-        if score_func == "sigmoid":
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.bias_on_choice = score_func == "sigmoid" \
+            if bias_on_choice is None else bool(bias_on_choice)
+        if self.bias_on_choice:
             self.bias = self.create_parameter((num_experts,), is_bias=True)
 
     def route(self, x2d):
         """Values in, values out: tokens [T, d] -> (expert ids [T, k]
         int32 over ALL ``num_experts``, weights [T, k] float32,
-        normalised over the k chosen)."""
+        normalised over the k chosen unless ``norm_topk_prob`` is
+        off)."""
         return self.route_groups(x2d)[:2]
 
     def route_groups(self, x2d):
@@ -170,9 +186,10 @@ class SigmoidTopKGate(BaseGate):
             precision=lax.Precision.HIGHEST)
         if self.score_func == "sigmoid":
             s = jax.nn.sigmoid(logits)
-            choose = s + self.bias._value.astype(jnp.float32)
         else:
-            choose = s = jax.nn.softmax(logits, axis=-1)
+            s = jax.nn.softmax(logits, axis=-1)
+        choose = s + self.bias._value.astype(jnp.float32) \
+            if self.bias_on_choice else s
         groups = None
         if self.n_group > 1:
             T, n = choose.shape[0], self.n_group
@@ -186,6 +203,7 @@ class SigmoidTopKGate(BaseGate):
             groups = groups.astype(jnp.int32)
         _, idx = lax.top_k(choose, self.top_k)
         sel = jnp.take_along_axis(s, idx, axis=-1)
-        w = self.routed_scaling_factor * sel / jnp.sum(sel, -1,
-                                                       keepdims=True)
+        w = self.routed_scaling_factor * sel
+        if self.norm_topk_prob:
+            w = w / jnp.sum(sel, -1, keepdims=True)
         return idx.astype(jnp.int32), w, groups

@@ -545,13 +545,26 @@ class GatedMoELayer(Layer):
     backward); on one chip there is no exchange, and nothing stands in
     for the absent holders.
 
+    ``zero_expert_num = Z`` > 0: the router is ``num_experts + Z`` wide
+    and its last Z outputs are IDENTITY experts ("zero-computation
+    experts"): a chosen id ``>= num_experts`` costs no product and adds
+    ``weight * x``. They live where the token lives, so every holder
+    computes them WHOLE for its own rows, whatever its
+    ``expert_offset`` (as a shared expert; counted once when the
+    holders' parts are added up), and they are "absent" nowhere.
+    ``router_bias`` / ``norm_topk_prob`` are the gate's
+    ``bias_on_choice`` / ``norm_topk_prob``.
+
     ``forward(x, counts=None)``: ``counts`` is an optional
     ``[num_local_experts + 3]`` int32 routing counter (``routed_swiglu``'s
     sizes: pairs per held expert, pairs of absent experts, pairs
-    computed and summed; then the tokens seen); when given the updated
+    computed and summed; then the tokens seen; with identity experts
+    ``[num_local_experts + 4]``: their pairs stand before the tokens,
+    and tokens x k = held + absent + identity); when given the updated
     counter is returned beside the output. While a collection of
     ``observability.moestats`` is open the layer records, as it is
-    traced, ``choices``, ``load`` (those sizes), under a gate with groups
+    traced, ``choices``, ``load`` (those sizes), ``zero`` (the call's
+    identity pairs, with identity experts), under a gate with groups
     ``groups`` (the kept groups' ids [T, topk_group]) and ``group_load``
     (the call's routed pairs by group, [n_group]), ``form`` and, in the
     sorted form, ``rows`` (the bound, the call's routed pairs) and
@@ -565,20 +578,29 @@ class GatedMoELayer(Layer):
                  routed_scaling_factor: float = 1.0,
                  num_shared_experts: int = 1, weight_attr=None,
                  down_attr=None, score_func: str = "sigmoid",
-                 n_group: int = 0, topk_group: int = 0):
+                 n_group: int = 0, topk_group: int = 0,
+                 zero_expert_num: int = 0,
+                 router_bias: Optional[bool] = None,
+                 norm_topk_prob: bool = True):
         super().__init__()
         El = num_experts if num_local_experts is None \
             else int(num_local_experts)
         enforce(0 <= expert_offset and expert_offset + El <= num_experts,
                 f"held experts {expert_offset}..{expert_offset + El - 1} "
                 f"lie outside the router's {num_experts}")
+        self.zero_expert_num = int(zero_expert_num)
+        enforce(self.zero_expert_num >= 0
+                and not (self.zero_expert_num and n_group > 1),
+                "identity experts belong to no group of experts: "
+                "zero_expert_num and n_group exclude each other")
         self.d_model, self.d_hidden = d_model, d_hidden
         self.num_experts, self.num_local_experts = num_experts, El
         self.expert_offset = int(expert_offset)
         self.gate = SigmoidTopKGate(
-            d_model, num_experts, topk=top_k,
+            d_model, num_experts + self.zero_expert_num, topk=top_k,
             routed_scaling_factor=routed_scaling_factor,
-            score_func=score_func, n_group=n_group, topk_group=topk_group)
+            score_func=score_func, n_group=n_group, topk_group=topk_group,
+            bias_on_choice=router_bias, norm_topk_prob=norm_topk_prob)
         d, h, hs = d_model, d_hidden, d_hidden * num_shared_experts
         down_attr = down_attr if down_attr is not None else weight_attr
         self.w_gate = self.create_parameter((El, d, h), attr=weight_attr)
@@ -598,9 +620,21 @@ class GatedMoELayer(Layer):
         shape = xv.shape
         x2d = xv.reshape(-1, self.d_model)
         idx, w, groups = self.gate.route_groups(x2d)
+        # the sorted form's bound counts on the ROUTER's width: an
+        # identity pair is no row of the held experts' products
         y, sizes, trace = routed_swiglu(
             x2d, idx, w, self.w_gate._value, self.w_up._value,
-            self.w_down._value, self.expert_offset, self.num_experts)
+            self.w_down._value, self.expert_offset, self.gate.num_experts)
+        if self.zero_expert_num:
+            # ids past the real experts: weight * x for every row here;
+            # routed_swiglu counted them absent, they are nobody's
+            zero = idx >= self.num_experts
+            y = y + jnp.where(zero, w, 0.0).sum(-1)[:, None] \
+                * x2d.astype(jnp.float32)
+            n_zero = zero.sum(dtype=jnp.int32)
+            sizes = jnp.concatenate([
+                sizes.at[self.num_local_experts].add(-n_zero), n_zero[None]])
+            trace = dict(trace, zero=n_zero)
         if _moestats.active():
             if groups is not None:
                 n = self.gate.n_group

@@ -356,6 +356,12 @@ class PagedKVCache:
         upload."""
         if self.counters is None:
             return list(self.pools)
+        enforce(len(self.pools) == len(self.counters),
+                f"{len(self.pools)} pooled tuples and "
+                f"{len(self.counters)} device counters: a model keeps one "
+                "counter a tuple of kv_pool_shapes() (a layer of two "
+                "attentions two), and a zip would drop the longer "
+                "list's tail in silence")
         return [p + (n,) for p, n in zip(self.pools, self.counters)]
 
     def take_back(self, state: List[tuple]) -> None:

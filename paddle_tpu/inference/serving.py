@@ -2002,10 +2002,14 @@ class ServingEngine:
         took, the pairs that went to experts held elsewhere, the pairs
         whose product was computed and summed, and the tokens seen
         (every row of the decode batch, live or not: a dead row is
-        routed like any other). ``dropped`` = the pairs the router sent
-        to a held expert (tokens x k less the absent ones) that the
-        grouped products did not cover; the layer has no capacity, so
-        anything but 0 is a fault of the products' bookkeeping.
+        routed like any other). ``zero_pairs`` = the pairs that chose an
+        IDENTITY expert (a layer with ``zero_expert_num``; 0 elsewhere):
+        they cost no product, are held by nobody and absent nowhere, and
+        feed the gauge ``paddle_tpu_moe_zero_pick_share``. ``dropped`` =
+        the pairs the router sent to a held expert (tokens x k less the
+        absent and the identity ones) that the grouped products did not
+        cover; the layer has no capacity, so anything but 0 is a fault
+        of the products' bookkeeping.
         ``forms`` = the form the expert layers took in the decode
         program and in the prefill programs (``routed_form``: "batched"
         | "sorted"; both joined by "+" if the buckets differ), as they
@@ -2019,10 +2023,21 @@ class ServingEngine:
         c = self._device_counters()
         if self._key_selection:         # selection_stats()' two slots
             c = c[:, :-2]
-        k = int(getattr(self.pred._model.config, "num_experts_per_tok", 0))
-        return {"pairs": c[:, :-3], "absent_pairs": c[:, -3],
-                "summed_pairs": c[:, -2], "tokens": c[:, -1],
-                "dropped": int((c[:, -1] * k - c[:, -3] - c[:, -2]).sum()),
+        mcfg = self.pred._model.config
+        k = int(getattr(mcfg, "num_experts_per_tok", 0))
+        # [pairs a held expert .., absent, summed, (identity,) tokens]
+        z = 1 if getattr(mcfg, "zero_expert_num", 0) else 0
+        tokens = c[:, -1]
+        zero = c[:, -2] if z else np.zeros_like(tokens)
+        absent, summed = c[:, -3 - z], c[:, -2 - z]
+        out = {"pairs": c[:, :-3 - z], "absent_pairs": absent,
+               "summed_pairs": summed, "zero_pairs": zero,
+               "tokens": tokens,
+               "dropped": int((tokens * k - absent - summed - zero).sum())}
+        if z and tokens.any():
+            self._metrics["moe_zero_pick_share"].set(
+                float(zero.sum()) / (float(tokens.sum()) * k))
+        return {**out,
                 "forms": {kind: "+".join(sorted(
                     self._moe_forms.get(kind, ()))) or None
                     for kind in ("decode", "prefill")},

@@ -25,7 +25,25 @@ every piece off by default; nothing asks a model's name:
 - ``n_group`` / ``topk_group``: the router keeps that many groups of
   experts before its top-k (``SigmoidTopKGate``);
 - ``head_on_last_row``: a prefill program hands ``forward`` the rows'
-  ``lengths`` and gets ``[B, vocab]``, the head on one row a prompt.
+  ``lengths`` and gets ``[B, vocab]``, the head on one row a prompt;
+- ``shortcut_moe``: the SHORTCUT-CONNECTED double layer of the
+  LongCat-Flash line (``ShortcutMoEDecoderLayer``): two latent
+  attentions and two dense SwiGLUs a layer around ONE expert branch,
+  which reads the first attention's output and whose result is added a
+  sublayer later (so that an expert exchange could run beside the dense
+  path and the second attention); each attention pools its own
+  ``[c | k_r]``, so the model pools ``2 x num_layers`` tuples;
+- ``zero_expert_num``: the router's last outputs are IDENTITY experts
+  (``GatedMoELayer``); ``router_score_func`` / ``router_bias`` /
+  ``norm_topk_prob``: the router's score (``"sigmoid"`` |
+  ``"softmax"``), whether a bias steers its choice (None: a sigmoid's
+  does, a softmax's has none) and whether the chosen weights are
+  renormalised (``SigmoidTopKGate``);
+- ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: the query heads off the
+  query latent times ``(hidden_size / q_lora_rank) ** 0.5``, the keys
+  and values off the latent times ``(hidden_size / kv_lora_rank) **
+  0.5``. The cache stays the normed latent: in the absorbed form the
+  factor stands on ``q_lat`` and on the output product.
 
 It honours the serving contract of ``LlamaForCausalLM``:
 ``forward(input_ids, caches, offset)`` with per-layer paged tuples
@@ -93,7 +111,8 @@ from ..tensor import Tensor
 from .llama import _apply_rope, _dispatch_kernel
 
 __all__ = ["MLAMoEConfig", "MLAMoEForCausalLM", "mla_moe_tiny",
-           "sparse_mla_tiny", "yarn_inv_freq", "yarn_mscale"]
+           "sparse_mla_tiny", "shortcut_moe_tiny", "yarn_inv_freq",
+           "yarn_mscale"]
 
 # index heads whose products stand side by side in one pass of the
 # index scores (``index_scores(head_block=)``): 16 x 512 rows x 8,192
@@ -131,6 +150,14 @@ class MLAMoEConfig:
     index_topk: int = 0
     attention_block: int = 512      # rows and keys a block, with an index
     head_on_last_row: bool = False
+    # the shortcut-connected double layer and its router (docstring)
+    shortcut_moe: bool = False
+    zero_expert_num: int = 0
+    router_score_func: str = "sigmoid"
+    router_bias: Optional[bool] = None      # None = the score's default
+    norm_topk_prob: bool = True
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     max_position_embeddings: int = 4096
     rope_theta: float = 10000.0
     # YaRN as DeepSeek's ``deepseek_yarn``; None = plain rotary
@@ -149,6 +176,31 @@ class MLAMoEConfig:
                     and self.index_head_dim >= self.qk_rope_head_dim,
                     "an index needs index_heads and an index_head_dim "
                     "that holds the qk_rope_head_dim numbers it turns")
+        enforce(not (self.mla_scale_q_lora and not self.q_lora_rank),
+                "mla_scale_q_lora scales the query off a query latent: "
+                "set q_lora_rank")
+        enforce(not (self.shortcut_moe and (
+            self.index_topk or self.first_k_dense_replace
+            or self.num_shared_experts)),
+            "a shortcut-connected layer has its two dense parts and one "
+            "expert branch in EVERY layer, no shared expert and no "
+            "index: first_k_dense_replace, num_shared_experts and "
+            "index_topk are 0 with shortcut_moe")
+
+    @property
+    def attention_sublayers(self) -> int:
+        """Latent attentions a layer, each with its own pooled arrays."""
+        return 2 if self.shortcut_moe else 1
+
+    @property
+    def q_lora_scale(self) -> float:
+        return (self.hidden_size / self.q_lora_rank) ** 0.5 \
+            if self.mla_scale_q_lora else 1.0
+
+    @property
+    def kv_lora_scale(self) -> float:
+        return (self.hidden_size / self.kv_lora_rank) ** 0.5 \
+            if self.mla_scale_kv_lora else 1.0
 
     @property
     def index_cache_width(self) -> int:
@@ -230,8 +282,16 @@ def _rms(x, weight, eps):
         lambda: rms_norm_dense(x, weight, float(eps)))
 
 
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+def _mm(x, w, scale: float = 1.0):
+    """x @ w, accumulated in float32; a ``scale`` other than 1 stands on
+    that float32 product, before it is rounded to x's type."""
+    return _scaled(jnp.dot(x, w, preferred_element_type=jnp.float32),
+                   scale).astype(x.dtype)
+
+
+def _scaled(y, scale: float):
+    """``y * scale``; ``y`` itself (no op traced) at a scale of 1."""
+    return y if scale == 1.0 else y * scale
 
 
 def _causal_attention(q, k, v, scale):
@@ -383,12 +443,13 @@ class LatentAttention(Layer):
         H, dc = cfg.num_heads, cfg.kv_lora_rank
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
-        scale = cfg.softmax_scale
+        scale, kvs = cfg.softmax_scale, cfg.kv_lora_scale
         cos, sin = self._rope
         if cfg.q_lora_rank:
             cq = _rms(_mm(x, self.q_a_proj._value), self.q_a_norm._value,
                       cfg.rms_norm_eps)
-            q = _mm(cq, self.q_b_proj._value).reshape(B, S, H, dn + dr)
+            q = _mm(cq, self.q_b_proj._value, cfg.q_lora_scale).reshape(
+                B, S, H, dn + dr)
         else:
             cq = x
             q = _mm(x, self.q_proj._value).reshape(B, S, H, dn + dr)
@@ -435,12 +496,12 @@ class LatentAttention(Layer):
 
         if cache is None or _concrete_zero(offset):
             # unabsorbed: per-head keys and values from the latent
-            kv_n = jnp.einsum("bsc,chd->bshd", c, w_k,
-                              preferred_element_type=jnp.float32
-                              ).astype(x.dtype)
-            v = jnp.einsum("bsc,chd->bshd", c, w_v,
-                           preferred_element_type=jnp.float32
-                           ).astype(x.dtype)
+            kv_n = _scaled(jnp.einsum("bsc,chd->bshd", c, w_k,
+                                      preferred_element_type=jnp.float32),
+                           kvs).astype(x.dtype)
+            v = _scaled(jnp.einsum("bsc,chd->bshd", c, w_v,
+                                   preferred_element_type=jnp.float32),
+                        kvs).astype(x.dtype)
             k = jnp.concatenate(
                 [kv_n, jnp.broadcast_to(k_r, (B, S, H, dr))], axis=-1)
             q = jnp.concatenate([q_n, q_r], -1)
@@ -453,9 +514,9 @@ class LatentAttention(Layer):
 
             off = jnp.broadcast_to(
                 jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
-            q_lat = jnp.einsum("bshd,chd->bshc", q_n, w_k,
-                               preferred_element_type=jnp.float32
-                               ).astype(x.dtype)
+            q_lat = _scaled(jnp.einsum("bshd,chd->bshc", q_n, w_k,
+                                       preferred_element_type=jnp.float32),
+                            kvs).astype(x.dtype)
             q_r = jnp.pad(q_r, lanes)
             keep = None
             if self.sparse:
@@ -494,9 +555,9 @@ class LatentAttention(Layer):
                     u = _ma.mla_attention_dense(
                         q_lat, q_r, c_pool[:, 0], r_pool[:, 0], off, scale,
                         keep)
-            o = jnp.einsum("bshc,chd->bshd", u, w_v,
-                           preferred_element_type=jnp.float32
-                           ).astype(x.dtype)
+            o = _scaled(jnp.einsum("bshc,chd->bshd", u, w_v,
+                                   preferred_element_type=jnp.float32),
+                        kvs).astype(x.dtype)
         return _mm(o.reshape(B, S, H * dv), self.o_proj._value), new_cache
 
 
@@ -515,6 +576,36 @@ class DenseSwiGLU(Layer):
                       self.down_proj._value).astype(x.dtype)
 
 
+def _expert_layer(cfg: MLAMoEConfig) -> GatedMoELayer:
+    std = cfg.initializer_range
+    return GatedMoELayer(
+        cfg.hidden_size, cfg.moe_intermediate_size,
+        cfg.num_experts, cfg.num_local_experts, cfg.expert_offset,
+        top_k=cfg.num_experts_per_tok,
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        num_shared_experts=cfg.num_shared_experts,
+        weight_attr=_attr(std),
+        down_attr=_attr(std / math.sqrt(2 * cfg.num_layers)),
+        score_func=cfg.router_score_func,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        zero_expert_num=cfg.zero_expert_num, router_bias=cfg.router_bias,
+        norm_topk_prob=cfg.norm_topk_prob)
+
+
+def _counted(mlp: GatedMoELayer, h, cache):
+    """The expert layer on ``h`` with the routing counter that ends the
+    cache tuple brought up to date: (values, the cache tuple). The
+    counter follows the pools and their table; a selecting model's two
+    slots follow it."""
+    counts = cache[-1]
+    m = mlp.num_local_experts + 3 + bool(mlp.zero_expert_num)
+    more = counts.shape[0] > m
+    y, moe = mlp(h, counts=counts[:m] if more else counts)
+    if more:
+        moe = jnp.concatenate([moe, counts[m:]])
+    return y._value, cache[:-1] + (moe,)
+
+
 class MLAMoEDecoderLayer(Layer):
     def __init__(self, cfg: MLAMoEConfig, index: int):
         super().__init__()
@@ -526,19 +617,7 @@ class MLAMoEDecoderLayer(Layer):
         self.post_attention_layernorm = self.create_parameter(
             (cfg.hidden_size,), attr=ones)
         self.is_moe = index >= cfg.first_k_dense_replace
-        if self.is_moe:
-            std = cfg.initializer_range
-            self.mlp = GatedMoELayer(
-                cfg.hidden_size, cfg.moe_intermediate_size,
-                cfg.num_experts, cfg.num_local_experts, cfg.expert_offset,
-                top_k=cfg.num_experts_per_tok,
-                routed_scaling_factor=cfg.routed_scaling_factor,
-                num_shared_experts=cfg.num_shared_experts,
-                weight_attr=_attr(std),
-                down_attr=_attr(std / math.sqrt(2 * cfg.num_layers)),
-                n_group=cfg.n_group, topk_group=cfg.topk_group)
-        else:
-            self.mlp = DenseSwiGLU(cfg)
+        self.mlp = _expert_layer(cfg) if self.is_moe else DenseSwiGLU(cfg)
 
     def forward(self, x, cache=None, offset=0):
         eps, attn = self.cfg.rms_norm_eps, self.self_attn
@@ -556,17 +635,66 @@ class MLAMoEDecoderLayer(Layer):
             if not self.is_moe:
                 y = self.mlp(h)
             elif cache is not None and len(cache) == attn.n_pools + 2:
-                # the routing counter follows the pools and their table;
-                # a selecting model's two slots follow it
-                counts, m = cache[-1], self.cfg.num_local_experts + 3
-                more = counts.shape[0] > m
-                y, moe = self.mlp(h, counts=counts[:m] if more else counts)
-                if more:
-                    moe = jnp.concatenate([moe, counts[m:]])
-                y, cache = y._value, cache[:-1] + (moe,)
+                y, cache = _counted(self.mlp, h, cache)
             else:
                 y = self.mlp(h)._value
         return x + y, cache
+
+
+class ShortcutMoEDecoderLayer(Layer):
+    """The shortcut-connected double layer (``shortcut_moe``), with x
+    the layer's input::
+
+        x1 = x  + MLA_0(RMSNorm(x))
+        h  = RMSNorm(x1);  s = MoE(h)        # the shortcut branch
+        x2 = x1 + FFN_0(h)
+        x3 = x2 + MLA_1(RMSNorm(x2))
+        x4 = x3 + FFN_1(RMSNorm(x3)) + s     # the layer's output
+
+    ``forward``'s ``cache`` is a PAIR of cache tuples, one an attention;
+    the routing counter, when the engine lends one, ends the first (the
+    second's is lent alike and stays 0: the pool's ``lend`` knows one
+    counter a pooled tuple). The norms are ``[2, hidden]``, a row a
+    sublayer. The ops carry the scopes ``layerN.attn0`` / ``.attn1`` /
+    ``.mlp0`` / ``.mlp1`` / ``.moe.shortcut``."""
+
+    def __init__(self, cfg: MLAMoEConfig, index: int):
+        super().__init__()
+        self.cfg, self.scope = cfg, f"layer{index}"
+        ones = ParamAttr(initializer=I.Constant(1.0))
+        self.input_layernorm = self.create_parameter(
+            (2, cfg.hidden_size), attr=ones)
+        self.post_attention_layernorm = self.create_parameter(
+            (2, cfg.hidden_size), attr=ones)
+        self.self_attn = LayerList([
+            LatentAttention(cfg, f"{self.scope}.attn{j}") for j in (0, 1)])
+        self.mlps = LayerList([DenseSwiGLU(cfg) for _ in (0, 1)])
+        self.mlp = _expert_layer(cfg)
+
+    def forward(self, x, cache=None, offset=0):
+        eps, name = self.cfg.rms_norm_eps, self.scope
+        c0, c1 = (None, None) if cache is None else cache
+        pre, post = (self.input_layernorm._value,
+                     self.post_attention_layernorm._value)
+        with _annotate(f"{name}.attn0"):
+            a, c0 = self.self_attn[0](_rms(x, pre[0], eps), cache=c0,
+                                      offset=offset)
+        x = x + a
+        h = _rms(x, post[0], eps)
+        with _annotate(f"{name}.moe.shortcut"):
+            if c0 is not None and len(c0) == self.self_attn[0].n_pools + 2:
+                s, c0 = _counted(self.mlp, h, c0)
+            else:
+                s = self.mlp(h)._value
+        with _annotate(f"{name}.mlp0"):
+            x = x + self.mlps[0](h)
+        with _annotate(f"{name}.attn1"):
+            a, c1 = self.self_attn[1](_rms(x, pre[1], eps), cache=c1,
+                                      offset=offset)
+        x = x + a
+        with _annotate(f"{name}.mlp1"):
+            x = x + self.mlps[1](_rms(x, post[1], eps)) + s
+        return x, (c0, c1)
 
 
 class MLAMoEForCausalLM(Layer):
@@ -580,7 +708,9 @@ class MLAMoEForCausalLM(Layer):
         std = cfg.initializer_range
         self.embed_tokens = self.create_parameter(
             (cfg.vocab_size, cfg.hidden_size), attr=_attr(std))
-        self.layers = LayerList([MLAMoEDecoderLayer(cfg, i)
+        block = ShortcutMoEDecoderLayer if cfg.shortcut_moe \
+            else MLAMoEDecoderLayer
+        self.layers = LayerList([block(cfg, i)
                                  for i in range(cfg.num_layers)])
         self.norm = self.create_parameter(
             (cfg.hidden_size,),
@@ -592,15 +722,16 @@ class MLAMoEForCausalLM(Layer):
 
     # -- what the serving engine asks of a model -------------------------
     def kv_pool_shapes(self, P: int, page: int):
-        """Per layer, the shapes of the pooled arrays: the latent and
-        the rotated shared key, ONE cache head each; with an index, its
-        one key a position as a third."""
+        """Per ATTENTION (one a layer; two a shortcut-connected layer,
+        in the order they run), the shapes of the pooled arrays: the
+        latent and the rotated shared key, ONE cache head each; with an
+        index, its one key a position as a third."""
         cfg = self.config
         return [((P, 1, page, cfg.kv_lora_rank),
                  (P, 1, page, cfg.rope_cache_width))
                 + (((P, 1, page, cfg.index_cache_width),)
                    if cfg.index_topk else ())
-                for _ in range(cfg.num_layers)]
+                for _ in range(cfg.num_layers * cfg.attention_sublayers)]
 
     @property
     def key_selection(self) -> Optional[int]:
@@ -610,11 +741,16 @@ class MLAMoEForCausalLM(Layer):
         return self.config.index_topk or None
 
     def moe_counter_shape(self):
-        """[layers, held experts + 3] routing counters (``GatedMoELayer``);
-        rows of dense layers stay 0. A model that selects has two slots
+        """[pooled tuples, held experts + 3] routing counters
+        (``GatedMoELayer``), one a tuple of ``kv_pool_shapes``; rows of
+        dense layers, and of a shortcut-connected layer's second
+        attention, stay 0. With identity experts a slot more (their
+        pairs, before the tokens). A model that selects has two slots
         more a layer: the decode step's rows whose kept count was not
         ``min(t + 1, index_topk)``, and its rows."""
-        return (self.config.num_layers, self.config.num_local_experts + 3
+        cfg = self.config
+        return (cfg.num_layers * cfg.attention_sublayers,
+                cfg.num_local_experts + 3 + bool(cfg.zero_expert_num)
                 + (2 if self.key_selection else 0))
 
     def _empty_caches(self, B: int, max_len: int, dtype):
@@ -644,11 +780,17 @@ class MLAMoEForCausalLM(Layer):
             with _annotate("embed"):
                 x = self.embed_tokens._value[ids]
             new_caches = []
+            two = self.config.shortcut_moe      # two tuples a layer
             for i, layer in enumerate(self.layers):
                 with _annotate(f"layer{i}"):
-                    x, nc = layer(x, cache=None if caches is None
-                                  else caches[i], offset=offset)
-                new_caches.append(nc)
+                    x, nc = layer(
+                        x, cache=None if caches is None
+                        else tuple(caches[2 * i:2 * i + 2]) if two
+                        else caches[i], offset=offset)
+                if two:
+                    new_caches.extend(nc)
+                else:
+                    new_caches.append(nc)
             if lengths is None:
                 logits = self._head(x)
             else:
@@ -688,5 +830,25 @@ def sparse_mla_tiny(**kw) -> MLAMoEConfig:
                 num_experts=8, num_local_experts=4, expert_offset=0,
                 num_experts_per_tok=2, n_group=4, topk_group=2,
                 head_on_last_row=True)
+    base.update(kw)
+    return mla_moe_tiny(**base)
+
+
+def shortcut_moe_tiny(**kw) -> MLAMoEConfig:
+    """CPU-test size of the shortcut-connected line: two attentions off
+    a query latent and two dense parts a layer around one expert branch,
+    both latent scale factors, a softmax router of 8 + 4 outputs (the
+    last 4 identity experts) with a bias on the choice and no
+    renormalisation, top-3, 4 of the 8 real experts held, plain rotary,
+    no shared expert, no leading dense layer."""
+    base = dict(num_layers=2, q_lora_rank=24, use_qk_norm=False,
+                shortcut_moe=True, zero_expert_num=4,
+                router_score_func="softmax", router_bias=True,
+                norm_topk_prob=False, mla_scale_q_lora=True,
+                mla_scale_kv_lora=True, num_experts=8,
+                num_local_experts=4, expert_offset=0,
+                num_experts_per_tok=3, num_shared_experts=0,
+                first_k_dense_replace=0, routed_scaling_factor=6.0,
+                rope_scaling=None, rms_norm_eps=1e-5)
     base.update(kw)
     return mla_moe_tiny(**base)

@@ -548,6 +548,15 @@ def serving_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
             "context, summed over the rows, at the last retired decode "
             "round",
             unit="ratio"),
+        "moe_zero_pick_share": r.gauge(
+            "paddle_tpu_moe_zero_pick_share",
+            "of the expert choices the decode steps of a serving engine "
+            "made so far (tokens x top-k, every row of the batch, every "
+            "expert layer), the share that chose an IDENTITY expert of a "
+            "layer with zero_expert_num: they cost no product, so the "
+            "work a token gets varies with it; set when "
+            "ServingEngine.moe_stats() fetches the device counters",
+            unit="ratio"),
         "prefill_tokens": r.counter(
             "paddle_tpu_serving_prefill_tokens_total",
             "tokens the prefill programs were given: kind=prompt the "

@@ -179,6 +179,25 @@ _OK_LINES = {
         "compiled program ('prefill', #): # copies of a whole poo",
         "decode rounds launched with the one before unretired: # ",
     ],
+    "shortcut": [
+        "a layer pools # arrays of # pages: #x#x# | #x#x#",
+        "warm-up mix drained (#)",
+        "every request returned # tokens of the vocabulary",
+        "no compile after warm-up (eng.stats.compiles #, # XLA co",
+        "reference forward: finite logits of shape (#, #)",
+        "first token # scores within # of the reference forward's",
+        "every served token of the first request scores within # ",
+        "expert layers dropped # routed pairs of #",
+        "expert products' forms {'decode': 'batched', 'prefill': ",
+        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "program ('decode',) holds Mosaic calls {} (asked: {'mla_",
+        "compiled program ('decode',): # copies of a whole pool #",
+        "compiled program ('decode',) donates the # arrays it was",
+        "compiled program ('prefill', #): # copies of a whole poo",
+        "decode rounds launched with the one before unretired: # ",
+        "tokens x # = held + absent + identity pairs in every exp",
+        "identity experts took # of the decode steps' picks (# of",
+    ],
     "mimo": [
         "full decode kernel, keys # against values #, sink False,",
         "window decode kernel, keys # against values #, sink True",

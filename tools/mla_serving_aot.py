@@ -7,7 +7,9 @@ the latent-attention decoder; ``mimo-v2-flash`` and ``trinity-mini``:
 pages; ``keye-vl-2.0-30b-a3b``: the same model with layers that select
 keys by a learned index and pool a third array; ``deepseek-v3.2-exp``:
 ``MLAMoEForCausalLM`` with a query latent and an index that selects rows
-of the latent cache) at the benchmark
+of the latent cache; ``longcat-flash-omni``: the same class as
+shortcut-connected double layers, ``--layers`` of them, two pooled
+tuples and two kernel calls each) at the benchmark
 configuration's widths (``benchmarks/configs/<config>.json``) with
 ``--layers`` layers (2: the
 dense layer and one expert layer) and NO weights (``LazyGuard``), takes
@@ -80,7 +82,13 @@ def main(argv):
     pallas.is_tpu_platform = lambda: True
     cfg = json.load(open(os.path.join(
         root, "benchmarks", "configs", args.config + ".json")))
-    cfg["num_hidden_layers"] = args.layers
+    if "num_layers" in cfg:     # the source's depth key; the file's
+        # num_hidden_layers counts its attention sublayers (two a layer)
+        per = cfg["num_hidden_layers"] // cfg["num_layers"]
+        cfg["num_layers"] = args.layers
+        cfg["num_hidden_layers"] = per * args.layers
+    else:
+        cfg["num_hidden_layers"] = args.layers
     if "layers_run" in cfg:     # a depth cut that names published layers
         cfg["layers_run"] = cfg["layers_run"][:args.layers]
     srv = cfg["serving"]
