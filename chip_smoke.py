@@ -200,6 +200,10 @@ class Sizes:
                 num_local_experts=16, embedding_multiplier=2048 ** 0.5,
                 max_position_embeddings=2304)
             self.afmoe_lens = (2150, 2040, 1000, 100)
+            # the flash prefill kernel under a window at that block's
+            # shapes and the benchmark cell's three longest buckets
+            self.window_prefill = dict(heads=32, kv=4, window=2048,
+                                       rows=(2048, 4096, 8192))
             # serve_sparse: sparse_moe_tiny (three sparse layers) at head
             # widths the chip tiles; the 256 best keys of contexts to 1,000
             self.sparse = dict(
@@ -278,6 +282,8 @@ class Sizes:
                 embedding_multiplier=128 ** 0.5,
                 max_position_embeddings=288, attention_block=32)
             self.afmoe_lens = (60, 50, 20, 10)
+            self.window_prefill = dict(heads=8, kv=1, window=150,
+                                       rows=(128, 384))
             self.sparse = dict(max_position_embeddings=288, dtype="bfloat16")
             self.sparse_mla = dict(self.sparse)
             self.sparse_lens, self.sparse_new = (100, 60, 30, 10), 12
@@ -585,6 +591,36 @@ def kernel_cases(sz: Sizes):
             cases.append((f"flash_attention_gqa prefill Sq={Sb} KV={KV}",
                           ("flash_attention_fwd_gqa",), fresh(Sb, KV),
                           TOL_ATTN))
+
+    def windowed(Sb, heads, KV, window):
+        def build(normal):
+            from paddle_tpu.ops.blockwise_attention import \
+                blockwise_causal_attention
+            from paddle_tpu.ops.pallas.flash_attention import \
+                flash_attention_gqa
+
+            scale = 128 ** -0.5
+            return (lambda q, k, v: flash_attention_gqa(
+                        q, k, v, scale=scale, window=window,
+                        interpret=interpret),
+                    lambda q, k, v: blockwise_causal_attention(
+                        q, k, v, scale, window),
+                    (normal((1, Sb, heads, 128)), normal((1, Sb, KV, 128)),
+                     normal((1, Sb, KV, 128))))
+
+        return build
+
+    # a hybrid model's prefill (models/hybrid_moe.py): eight query heads
+    # under one K/V tile, a window layer's band at each bucket and a full
+    # layer's triangle at the longest, against the lax blocks they replace
+    wp = sz.window_prefill
+    for Sb, window in [(Sb, wp["window"]) for Sb in wp["rows"]] + [
+            (wp["rows"][-1], None)]:
+        cases.append((f"flash_attention_gqa prefill Sq={Sb} "
+                      f"{wp['heads']} heads on {wp['kv']} window={window}",
+                      ("flash_attention_fwd_gqa",),
+                      windowed(Sb, wp["heads"], wp["kv"], window),
+                      TOL_ATTN))
 
     def ragged(normal):
         from paddle_tpu.ops.pallas.ragged_paged_attention import (
@@ -1184,6 +1220,35 @@ def hybrid_extra(ctx) -> None:
     c = eng.cache.counts()["classes"]
     check(c["full"]["used"] == 0 and c["window"]["used"] == 0,
           f"both classes back to free: {c}")
+    # a prompt's own attention is the flash kernel on the layers whose q,
+    # k and v are one width of whole lanes with no sink (afmoe's), lax
+    # blocks on the others (mimo's 192 against 128; the rehearsal's CPU)
+    from paddle_tpu.ops.pallas.flash_attention import flash_gqa_supported
+
+    sz, cfg = ctx.sz, ctx.cfg
+
+    def form(b):
+        words = set()
+        for kind in cfg.attention_kinds:
+            KV = cfg.window_num_kv_heads if kind == "window" \
+                else cfg.num_kv_heads
+            words.add("flash" if not (
+                sz.rehearsal or cfg.sink(kind)
+                or cfg.v_head_dim != cfg.qk_head_dim)
+                and flash_gqa_supported(
+                    (1, b, cfg.num_heads, cfg.qk_head_dim),
+                    (1, b, KV, cfg.qk_head_dim)) else "blockwise")
+        return "+".join(sorted(words))
+
+    forms = eng.prefill_attention_forms()
+    check(forms and forms == {b: form(b) for b in forms},
+          f"prefill programs attend as {forms}")
+    for site in [s for s in eng.program_sites() if s[0] == "prefill"]:
+        found = kernel_names(eng.lowered_text(site))
+        # jitted on its own: in the text once a kind of layer at most
+        check(("flash" in forms[site[1]])
+              == (found.get("flash_attention_fwd_gqa", 0) >= 1),
+              f"program {site} holds Mosaic calls {found}")
 
 
 # -- serve_sparse, serve_sparse_mla: attention over the keys (the latent

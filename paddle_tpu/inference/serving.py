@@ -1984,10 +1984,14 @@ class ServingEngine:
     def prefill_attention_forms(self) -> Dict[int, str]:
         """``{prefill bucket: form}``: how each prefill program this
         engine traced attends to its prompt, as the model's layers
-        recorded it (``models/llama.py::_paged_attention``): "flash"
-        (causal flash attention over the K/V the layer has just
-        written), "paged" (the block-table kernel over the pool) or
-        "dense" (the pool's pages gathered, plain XLA). A bucket whose
+        recorded it (``models/llama.py::_paged_attention``,
+        ``models/hybrid_moe.py``): "flash" (causal flash attention over
+        the K/V the layer has just written, under a window on a hybrid
+        model's window layers), "paged" (the block-table kernel over the
+        pool), "dense" (the pool's pages gathered, plain XLA) or
+        "blockwise" (a hybrid model's prompt in ``lax`` blocks:
+        ``ops/blockwise_attention.py``); layers that differ give both
+        words joined by "+". A bucket whose
         program an earlier engine of the same predictor traced, or a
         model that records none, is absent. The ``serving.prefill`` span
         carries the same word as ``attention`` ("unrecorded" for an

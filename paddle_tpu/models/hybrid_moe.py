@@ -62,9 +62,13 @@ caller gathers one row, as it always did.
 
 Attention forms, chosen at trace time:
 
-- prefill (``offset`` a concrete 0, or no cache): blockwise causal
-  self-attention over the new positions (``ops/blockwise_attention.py``:
-  no ``[H, S, S]`` array, the band alone on a window layer). K and V of
+- prefill (``offset`` a concrete 0, or no cache): causal self-attention
+  over the new positions, the band alone on a window layer and no ``[H,
+  S, S]`` array either way: ``flash_attention_gqa`` (``window=`` on a
+  window layer) where the kernels are on, the layer has no sink, keys
+  and values are one width and ``flash_gqa_supported`` admits the
+  shapes; else ``ops/blockwise_attention.py`` in plain ``lax``. The form
+  is recorded (``ServingEngine.prefill_attention_forms``). K and V of
   the prompt are written by the table: a window layer's PREFILL table is
   logical, one column a page, and the cache has sent every page but the
   prompt's last ``ring`` to the trash page;
@@ -96,12 +100,15 @@ from ..nn import initializer as I
 from ..nn.container import LayerList
 from ..nn.layer import Layer
 from ..observability import annotate as _annotate
+from ..observability import moestats as _moestats
 from ..ops.blockwise_attention import blockwise_causal_attention
 from ..ops.pallas import decode_attention as _da
+from ..ops.pallas import flash_attention as _fa
 from ..ops.sparse_attention import (collect_selection, count_kept,
                                     select_rows, selection_sink,
                                     sparse_causal_attention)
 from ..tensor import Tensor
+from . import llama as _llama
 from .llama import _apply_rope, _dispatch_kernel
 from .mla_moe import DenseSwiGLU, _attr, _mm, _rms
 
@@ -377,9 +384,21 @@ class HybridAttention(Layer):
                 o, mask = o
                 kept.append(mask)
         elif prefill:
-            with _annotate("blockwise_attention"):
-                o = blockwise_causal_attention(
-                    q, k, v, scale, window, sinks, cfg.attention_block)
+            # one head size, no sink: the flash kernel's shapes (padding
+            # rows lie after every real row, so they need no mask)
+            flash = (_llama._kernels_on() and sinks is None and dv == dk
+                     and _fa.flash_gqa_supported(q.shape, k.shape))
+            if cache is not None:       # a serving program's prefill
+                _moestats.record(
+                    {"attention": "flash" if flash else "blockwise"})
+            if flash:
+                with _annotate("flash_attention"):
+                    o = _fa.flash_attention_gqa(q, k, v, scale=scale,
+                                                window=window)
+            else:
+                with _annotate("blockwise_attention"):
+                    o = blockwise_causal_attention(
+                        q, k, v, scale, window, sinks, cfg.attention_block)
         elif self.sparse:
             off = jnp.broadcast_to(
                 jnp.asarray(offset, jnp.int32).reshape(-1), (B,))

@@ -22,7 +22,9 @@ of experts first, ``groups`` (the kept groups' ids a token) and
 shows); ``ServingEngine`` and the benchmark's probes read those.
 
 A Llama-family attention layer records ``attention``, the form its
-attention over the page pool took (``models/llama.py``), which
+attention over the page pool took (``models/llama.py``), and a hybrid
+model's plain prefill layer the form of the prompt's own attention
+(``models/hybrid_moe.py``), which
 ``ServingEngine.prefill_attention_forms`` keeps a prefill bucket.
 
 When no collection is active (eager forwards, serving, the pipelined

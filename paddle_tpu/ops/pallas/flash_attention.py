@@ -557,7 +557,7 @@ _GQA_VMEM_LIMIT = 48 * 1024 * 1024
 
 
 def _gqa_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
-                acc_s, *, scale, D):
+                acc_s, *, scale, D, window=None):
     """One block pair at or under the diagonal for the ``hs`` query heads
     of a step, all under ONE K/V tile. q / o blocks are ``[block, hs *
     D]`` (a head is a lane-aligned column slice), k / v ``[block, D]``.
@@ -565,18 +565,27 @@ def _gqa_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
     before this head's softmax (the MXU beside the VPU), the row sums
     stay per-lane until a row block's last step, and a masked score is
     ``-inf`` over a finite running maximum (``kept_attention.py`` has
-    the readings of each); only the diagonal block is masked at all."""
+    the readings of each); only the diagonal block is masked at all
+    and, under a ``window`` (static), the pairs ``far`` blocks under the
+    diagonal or further, where some row has lost a key of the block to
+    the window: one more compare of the same two ``iota``s. With no
+    window the steps are what they were without the argument."""
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
     hs, block, lanes = l_s.shape
+    if window is None:
+        first, far = 0, None
+    else:       # the tables' rule (flash_attention_gqa), and its far end
+        first = jnp.maximum(i * block - window + 1, 0) // block
+        far = max(0, -((block - 1 - window) // block))
 
-    @pl.when(j == 0)            # a row block's first step
+    @pl.when(j == first)        # a row block's first step
     def _():
         m_s[...] = jnp.full_like(m_s, _NEG)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    def step(diagonal):
+    def step(diagonal, edge=False):
         kb, vb = k_ref[0], v_ref[0]
 
         def product(g):
@@ -585,16 +594,21 @@ def _gqa_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        if diagonal:            # rows and keys of one block: local indices
-            keep = lax.broadcasted_iota(jnp.int32, (block, block), 0) \
-                >= lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        if diagonal or edge:    # rows and keys of a pair: local indices
+            row = lax.broadcasted_iota(jnp.int32, (block, block), 0)
+            key = lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        if diagonal:
+            keep = row >= key
+        if edge:        # row t keeps key s while t - s < window
+            near = row - key < window - (i - j) * block
+            keep = keep & near if diagonal else near
         s_next = product(0)
         for g in range(hs):
             s = s_next
             if g + 1 < hs:
                 s_next = product(g + 1)
             s = s * scale
-            if diagonal:
+            if diagonal or edge:
                 s = jnp.where(keep, s, -jnp.inf)
             m_prev = m_s[g]                             # lane-replicated
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -609,13 +623,16 @@ def _gqa_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
                 preferred_element_type=jnp.float32)
             m_s[g] = m_new
 
-    @pl.when(j < i)
-    def _():
-        step(False)
+    if far is None:
+        pl.when(j < i)(lambda: step(False))
+    else:
+        if far > 1:             # else every pair under the diagonal is far
+            pl.when((j < i) & (i - j < far))(lambda: step(False))
+        pl.when((j < i) & (i - j >= far))(lambda: step(False, edge=True))
 
     @pl.when(j == i)            # the diagonal block: a row block's last
     def _():
-        step(True)
+        step(True, edge=far == 0)
         for g in range(hs):
             l = jnp.sum(l_s[g], -1, keepdims=True)  # > 0: a row sees itself
             o_ref[0, :, g * D:(g + 1) * D] = (acc_s[g] / l).astype(
@@ -635,20 +652,40 @@ def flash_gqa_supported(q_shape, k_shape) -> bool:
             and k_shape[3] == D and H % k_shape[2] == 0)
 
 
-@partial(jax.jit, static_argnames=("scale", "block", "interpret"))
-def flash_attention_gqa(q, k, v, scale=None, block=None, interpret=False):
+def _gqa_pairs(n: int, block: int, window=None):
+    """The block pairs ``(qi, kj)`` of ``n`` row blocks that hold a key
+    some row sees, a row block's pairs together and its diagonal last:
+    all at or under the diagonal and, under a window, those alone whose
+    last key the row block's first row still sees."""
+    qi, kj = np.tril_indices(n)
+    if window is not None:
+        seen = kj * block + block - 1 > qi * block - window
+        qi, kj = qi[seen], kj[seen]
+    return qi, kj
+
+
+@partial(jax.jit,
+         static_argnames=("scale", "window", "block", "interpret"))
+def flash_attention_gqa(q, k, v, scale=None, window=None, block=None,
+                        interpret=False):
     """Causal self-attention of q [B, S, H, D] over k, v [B, S, KV, D]
-    (query head h reads KV head ``h // (H / KV)``; row t sees keys
-    ``<= t``), forward only: [B, S, H, D] in q's type. Products in the
-    input type with float32 accumulation, float32 softmax statistics.
+    (query head h reads KV head ``h // (H / KV)``; row t sees keys ``s
+    <= t`` and, with a ``window``, ``t - s < window``:
+    ``blockwise_causal_attention``'s rule), forward only: [B, S, H, D]
+    in q's type. Products in the input type with float32 accumulation,
+    float32 softmax statistics.
 
     No transposed copy and no widened K/V: the arrays are read as ``[B,
     S, heads * D]`` (a reshape), a step's q / o block is the ``hs`` heads'
     columns of a row block and its K/V tile ONE KV head's, shared by the
     ``hs`` query heads of the step (``hs``: the largest divisor of the
-    group up to 8). The grid is ``(B, H / hs, n (n + 1) / 2)``: the block
-    pairs at or under the diagonal alone, from two scalar-prefetched
-    tables, as ``kept_flash_attention``. ``block`` (rows and keys): the
+    group up to 8). The grid is ``(B, H / hs, pairs)``: the block pairs
+    that hold a visible key alone (``_gqa_pairs``: ``n (n + 1) / 2``
+    with no window, at most ``window / block + 1`` a row block under
+    one), from two scalar-prefetched tables, as
+    ``kept_flash_attention``. A window that drops no key of ``S`` rows
+    (``window >= S``) is no window: the same tables, the same kernel.
+    ``block`` (rows and keys): the
     largest of 512, 256, 128 that divides ``S``; alone on a v5e at ``[1,
     2048, 32, 128]`` on 8 KV heads 512 read 461 us a call (38% of the
     MXU by the causal half of ``4 S^2 D H``), 256 read 590, 128 read
@@ -656,9 +693,10 @@ def flash_attention_gqa(q, k, v, scale=None, block=None, interpret=False):
     heads, layout copies and all, 720 (my chip run, PR 43).
 
     Jitted on its own, as ``paged_decode_attention``: a prefill program
-    calls it once a layer with the same shapes, and then traces and
-    lowers it once (unjitted, 16 layers of two buckets added 5 s to a
-    warm set-up; my chip run, PR 43)."""
+    calls it once a layer with the same shapes (and the same window, on
+    the layers of one kind), and then traces and lowers it once
+    (unjitted, 16 layers of two buckets added 5 s to a warm set-up; my
+    chip run, PR 43)."""
     B, S, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -669,9 +707,11 @@ def flash_attention_gqa(q, k, v, scale=None, block=None, interpret=False):
     if not flash_gqa_supported(q.shape, k.shape) or S % block:
         raise ValueError("flash_attention_gqa: no block tiles shape "
                          f"{q.shape}/{k.shape}")
+    if window is not None and window >= S:
+        window = None
     hs = next(g for g in range(min(G, _GQA_MAX_HEADS), 0, -1) if G % g == 0)
     lanes = min(block, 128)
-    qi, kj = np.tril_indices(S // block)
+    qi, kj = _gqa_pairs(S // block, block, window)
     rows = lambda b, h, t, qi, kj: (b, qi[t], h)
     keys = lambda b, h, t, qi, kj: (b, kj[t], h * hs // G)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -690,7 +730,7 @@ def flash_attention_gqa(q, k, v, scale=None, block=None, interpret=False):
         ],
     )
     out = pl.pallas_call(
-        partial(_gqa_kernel, scale=float(scale), D=D),
+        partial(_gqa_kernel, scale=float(scale), D=D, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
         interpret=interpret,

@@ -472,3 +472,163 @@ def test_closed_loop_model_counts_what_the_benchmark_counts():
     assert 15_000 < a < 19_000 and abs(a - b) < 0.1 * a
     assert clm.spread([1.0, 2.0, 3.0, 4.0, 5.0, 60.0]) > \
         clm.spread_without_farthest([1.0, 2.0, 3.0, 4.0, 5.0, 60.0])
+
+
+# -- (g) the prompt's own attention: the flash kernel where the shapes allow ---
+def wide_model(**kw):
+    """Trinity's shape of layer at a tiny size: 8 query heads on one KV
+    head, q, k and v all 128 wide, no sink, a window (150) that is no
+    multiple of the kernel's block and passes one; a window, a full and
+    a second window layer."""
+    paddle.set_default_dtype("float32")
+    paddle.seed(46)
+    cfg = afmoe_tiny(**dict(dict(
+        attention_kinds=["window", "full", "window"],
+        ffn_kinds=["dense", "experts", "experts"], num_heads=8,
+        num_kv_heads=1, window_num_kv_heads=1, qk_head_dim=128,
+        v_head_dim=128, rotary_dim=128, sliding_window=150,
+        initializer_range=0.3, max_position_embeddings=320,
+        attention_block=128), **kw))
+    model = HybridMoEForCausalLM(cfg)
+    model.eval()
+    return model
+
+
+@pytest.fixture
+def kernels_on_cpu(monkeypatch):
+    """The dispatch as a TPU takes it, the attention kernels in the
+    interpreter (``interpret=True`` is the test's to pass); the fused
+    norm's gate closed (not what is tested)."""
+    from functools import partial
+
+    from paddle_tpu.models import llama
+    from paddle_tpu.ops.pallas import decode_attention as da
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import rms_norm
+
+    monkeypatch.setattr(llama, "_kernels_on", lambda: True)
+    monkeypatch.setattr(rms_norm, "rms_norm_supported", lambda shape: False)
+    monkeypatch.setattr(fa, "flash_attention_gqa",
+                        partial(fa.flash_attention_gqa, interpret=True))
+    monkeypatch.setattr(da, "paged_decode_attention",
+                        partial(da.paged_decode_attention, interpret=True))
+
+
+def _forms(model, S=256):
+    """The form each layer of a forward with no cache takes (which of
+    the two functions it calls), the windows the flash kernel was
+    handed, the logits."""
+    from paddle_tpu.models import hybrid_moe
+    from paddle_tpu.observability import moestats
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    forms, windows = [], []
+    kernel, blocks = fa.flash_attention_gqa, \
+        hybrid_moe.blockwise_causal_attention
+
+    def flash(q, k, v, **kw):
+        forms.append("flash")
+        windows.append(kw["window"])
+        return kernel(q, k, v, **kw)
+
+    def blockwise(*args):
+        forms.append("blockwise")
+        return blocks(*args)
+
+    ids = paddle.to_tensor(np.arange(S, dtype=np.int32)[None] % 256)
+    moestats.begin()
+    try:
+        fa.flash_attention_gqa = flash
+        hybrid_moe.blockwise_causal_attention = blockwise
+        logits = np.asarray(model(ids)._value)
+    finally:
+        fa.flash_attention_gqa = kernel
+        hybrid_moe.blockwise_causal_attention = blocks
+        recs = moestats.drain()
+    # no cache, no serving program: the collector holds the expert
+    # layers' records alone (the benchmark's probe reads every record of
+    # such a forward as one: mla_moe_serving.program_choices)
+    assert all("choices" in r for r in recs) and recs
+    return forms, windows, logits
+
+
+@pytest.mark.parametrize("name,kw,S,want,windows", [
+    ("trinity's shapes: every layer, the window layers under theirs",
+     {}, 256, ["flash"] * 3, [150, None, 150]),
+    ("a sink on the window layers: those stay in lax blocks",
+     dict(window_sink=True), 256, ["blockwise", "flash", "blockwise"],
+     [None]),
+    ("keys wider than values",
+     dict(qk_head_dim=256, rotary_dim=256), 256, ["blockwise"] * 3, []),
+    ("values wider than a key head",
+     dict(v_head_dim=256), 256, ["blockwise"] * 3, []),
+    ("half the lanes", dict(qk_head_dim=64, v_head_dim=64, rotary_dim=64),
+     256, ["blockwise"] * 3, []),
+    ("a bucket no block of 128 rows divides", {}, 200,
+     ["blockwise"] * 3, []),
+])
+def test_prefill_form_follows_the_layer_s_shapes(kernels_on_cpu, name, kw,
+                                                 S, want, windows):
+    model = wide_model(**kw)
+    forms, handed, got = _forms(model, S)
+    assert (forms, handed) == (want, windows), name
+    if "flash" in forms:        # and the same function either way
+        from paddle_tpu.models import llama
+
+        llama._kernels_on = lambda: False       # the fixture restores it
+        off, none, ref_logits_ = _forms(model, S)
+        assert (off, none) == (["blockwise"] * 3, [])
+        np.testing.assert_allclose(got, ref_logits_, rtol=2e-4, atol=2e-4)
+
+
+def test_kernels_off_is_the_lax_blocks_on_any_shape():
+    assert _forms(wide_model())[:2] == (["blockwise"] * 3, [])
+
+
+def test_engine_prefills_on_the_flash_kernel_and_serves_the_same(
+        kernels_on_cpu, monkeypatch):
+    """A prompt of 200 tokens (a bucket of 256 rows, two blocks of 128
+    under a window of 150) and one of 100 through ``ServingEngine``: the
+    prefill programs hold the flash forward once a layer, the engine
+    names the form a bucket, and the first token's logits and the served
+    tokens are the blockwise path's."""
+    from test_flash_grad_kernel import _pallas_calls
+
+    from paddle_tpu.models import llama
+    from paddle_tpu.observability import moestats
+
+    model = wide_model()
+    r = np.random.RandomState(46)
+    prompts = [r.randint(0, 256, (n,)).astype(np.int32) for n in (200, 100)]
+
+    def serve():
+        eng = engine(model, max_batch=2, decode_chunk=2)
+        rids = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        done = eng.run()
+        # and the prefill program's own logits, the head on the last row
+        ids = np.zeros((1, 256), np.int32)
+        ids[0, :200] = prompts[0]
+        pred = create_predictor(Config().set_model(model))
+        moestats.begin()
+        logits, _ = pred._prefill_fn(1, 256, 320)(
+            tuple(p._value for p in pred._params), jnp.asarray(ids),
+            model._empty_caches(1, 320, jnp.float32),
+            jnp.asarray([200], jnp.int32))
+        assert {r["attention"] for r in moestats.drain()
+                if "attention" in r} == {eng.prefill_attention_forms()[256]}
+        return eng, [list(done[r].new_tokens) for r in rids], \
+            np.asarray(logits)
+
+    eng, tokens, logits = serve()
+    assert eng.prefill_attention_forms() == {128: "flash", 256: "flash"}
+    for bucket in (128, 256):
+        fn, avals = eng._site_programs[("prefill", bucket)]
+        assert _pallas_calls(jax.make_jaxpr(fn)(*avals).jaxpr) == {
+            "flash_attention_fwd_gqa": 3}
+    monkeypatch.setattr(llama, "_kernels_on", lambda: False)
+    lax_eng, want, want_logits = serve()
+    assert lax_eng.prefill_attention_forms() == {128: "blockwise",
+                                                 256: "blockwise"}
+    assert tokens == want
+    assert logits.shape == (1, 256)
+    np.testing.assert_allclose(logits, want_logits, rtol=2e-4, atol=2e-4)

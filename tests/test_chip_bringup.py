@@ -216,6 +216,8 @@ _OK_LINES = {
         "two classes of pages: # full, # window (a ring of # a ro",
         "the longest context # wrapped its ring at #",
         "both classes back to free: {'full': {'used': #, 'free': ",
+        "prefill programs attend as {#: 'blockwise'}",
+        "program ('prefill', #) holds Mosaic calls {}",
     ],
     "afmoe": [
         "full decode kernel, keys # against values #, sink False,",
@@ -235,6 +237,8 @@ _OK_LINES = {
         "two classes of pages: # full, # window (a ring of # a ro",
         "the longest context # wrapped its ring at #",
         "both classes back to free: {'full': {'used': #, 'free': ",
+        "prefill programs attend as {#: 'blockwise'}",
+        "program ('prefill', #) holds Mosaic calls {}",
     ],
     "sparse": [
         "decode kernel under a kept mask (# query heads a KV head",

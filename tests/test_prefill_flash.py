@@ -70,6 +70,135 @@ def test_a_block_is_the_bucket_by_default():
                                atol=2e-5, rtol=2e-5)
 
 
+# ---------------------------------------------------------------------------
+# (a') under a sliding window (PR 46): the lax blocks' rule, block for block
+# ---------------------------------------------------------------------------
+def _against_the_lax_blocks(B, S, H, KV, window, dtype=jnp.float32):
+    from paddle_tpu.ops.blockwise_attention import (
+        blockwise_causal_attention)
+
+    r = np.random.RandomState(S + H + (window or 0))
+    q = _rand(r, B, S, H, 128, dtype=dtype)
+    k = _rand(r, B, S, KV, 128, dtype=dtype)
+    v = _rand(r, B, S, KV, 128, dtype=dtype)
+    got = fa.flash_attention_gqa(q, k, v, scale=0.11, window=window,
+                                 block=128, interpret=True)
+    want = blockwise_causal_attention(q, k, v, 0.11, window, None, 128)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("window", [
+    None,
+    256,        # whole blocks: the far pair is half dropped
+    300,        # no multiple of the block: two far pairs a row block
+    24,         # under a block: the diagonal is a far pair too
+    128,        # one block: every pair under the diagonal is far
+    512,        # drops nothing of 512 rows
+])
+def test_window_is_the_lax_blocks_window(window, group):
+    _against_the_lax_blocks(1, 512, group * (2 if group < 8 else 1),
+                            2 if group < 8 else 1, window)
+
+
+@pytest.mark.parametrize("B,S,H,KV,window,dtype", [
+    (2, 384, 8, 2, 200, jnp.float32),      # two prompts a call
+    (2, 256, 8, 1, 1, jnp.float32),        # a row sees itself alone
+    (1, 384, 8, 1, 257, jnp.bfloat16),     # the served type
+])
+def test_window_with_a_batch_and_in_bfloat16(B, S, H, KV, window, dtype):
+    _against_the_lax_blocks(B, S, H, KV, window, dtype)
+
+
+def _pairs(S, block, window):
+    qi, kj = fa._gqa_pairs(S // block, block, window)
+    return list(zip(qi.tolist(), kj.tolist()))
+
+
+def test_tables_hold_the_pairs_with_a_visible_key_and_no_other():
+    # no window: the lower triangle, as np.tril_indices gives it
+    for n in (1, 4, 16):
+        qi, kj = fa._gqa_pairs(n, 512, None)
+        assert len(qi) == n * (n + 1) // 2
+        want = np.tril_indices(n)
+        assert (qi == want[0]).all() and (kj == want[1]).all()
+    # trinity's window over blocks of 512: at most 5 pairs a row block
+    assert [len(_pairs(S, 512, 2048)) for S in (8192, 4096, 2048, 512)] \
+        == [70, 30, 10, 1]
+    assert _pairs(2048, 512, 2048) == _pairs(2048, 512, None)
+    for S, block, window in ((8192, 512, 2048), (1024, 128, 300),
+                             (512, 128, 24), (512, 128, 128),
+                             (1024, 256, 1)):
+        pairs = _pairs(S, block, window)
+        t, s = np.tril_indices(S)
+        seen = t - s < window
+        want = sorted({(a // block, b // block)
+                       for a, b in zip(t[seen], s[seen])})
+        assert pairs == want, (S, block, window)
+        # a row block's pairs lie together, first key block first, the
+        # diagonal last: the kernel's first and last steps
+        rows = [a for a, _ in pairs]
+        assert rows == sorted(rows)
+        for i in set(rows):
+            mine = [b for a, b in pairs if a == i]
+            assert mine[-1] == i
+            assert mine[0] == max(i * block - window + 1, 0) // block
+
+
+# sha256 of the jaxpr (the kernel's body in it) of the call with no
+# window, read from the parent commit (c61e29a) by this very code
+GQA_NO_WINDOW = {
+    ((1, 2048, 32, 128), 8):
+        "8ec062c2101c3c8adb15853858f9be3b2fb1303f3cf8e8fc482199cdc16d8f5f",
+    ((2, 1024, 32, 128), 4):
+        "6de1f6d49b3dd86facea1f7b0d8fe49d62239d1b969316a276f31d951d4df2c8",
+}
+
+
+def _gqa_jaxpr(q_shape, KV, **kw):
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct(q_shape[:2] + (KV, q_shape[3]), jnp.bfloat16)
+    return jax.make_jaxpr(partial(fa.flash_attention_gqa, scale=0.088,
+                                  **kw))(q, k, k)
+
+
+@pytest.mark.parametrize("q_shape,KV", sorted(GQA_NO_WINDOW))
+def test_no_window_is_the_kernel_it_was(q_shape, KV):
+    """Tables, grid and step bodies of the Llama cells' call: the text
+    of its jaxpr is the parent's, and a window that drops nothing traces
+    to the same (the static argument apart)."""
+    from test_flash_grad_kernel import _pallas_calls
+
+    jaxpr = _gqa_jaxpr(q_shape, KV)
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest() \
+        == GQA_NO_WINDOW[(q_shape, KV)]
+    B, S, H, _ = q_shape
+    n = S // 512
+
+    def grids(j):
+        out = []
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append(tuple(eqn.params["grid_mapping"].grid))
+            for val in eqn.params.values():
+                sub = getattr(val, "jaxpr", val)
+                if hasattr(sub, "eqns"):
+                    out += grids(sub)
+        return out
+
+    assert grids(jaxpr.jaxpr) == [(B, H // min(H // KV, 8),
+                                   n * (n + 1) // 2)]
+    wide = _gqa_jaxpr(q_shape, KV, window=S)
+    assert _pallas_calls(wide.jaxpr) == {"flash_attention_fwd_gqa": 1}
+    assert str(wide) == str(jaxpr)
+    under = _gqa_jaxpr(q_shape, KV, window=S - 1)       # one key dropped
+    assert grids(under.jaxpr) == grids(jaxpr.jaxpr)     # the same pairs,
+    assert str(under).count("select_n") > str(jaxpr).count("select_n")
+
+
 @pytest.mark.parametrize("q,kv,ok", [
     ((1, 2048, 32, 128), (1, 2048, 8, 128), True),
     ((2, 128, 4, 128), (2, 128, 4, 128), True),

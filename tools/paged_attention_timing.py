@@ -37,7 +37,14 @@ computed; ``null`` on a tree from before PR 36).
 
 ``--kv-width D`` pools keys ``D`` wide (256: a 192-wide key padded to
 the lanes) against 128-wide values; ``--kv-heads`` / ``--q-heads`` set
-the heads. ``--window W`` times the WINDOW kernel instead
+the heads. ``--prefill [Sb ...] --window W`` times a hybrid model's
+prompt to itself under the window and without one, as
+``flash_attention_gqa`` and as ``blockwise_causal_attention`` (PR 46):
+
+    chiprun -- python tools/paged_attention_timing.py --prefill \
+        --window 2048 --kv-heads 4
+
+``--window W`` alone times the WINDOW decode kernel instead
 (``paged_window_decode_attention``: the table is a ring of
 ``ceil(W / 128) + 1`` columns): 64 rows whose windows intersect 1, 2, 8
 and ``ring`` pages (``window_<n>p``), then the cell's mix of contexts and
@@ -237,6 +244,63 @@ def prefill_readings(Sb, r, peaks, blocks=()):
         print(json.dumps(line), flush=True)
 
 
+def window_prefill_readings(Sb, window, r, peaks):
+    """A hybrid model's prompt of ``Sb`` rows to itself (PR 46), a window
+    layer's band and a full layer's triangle, each as
+    ``flash_attention_gqa`` and as the ``lax`` blocks it replaces
+    (``blockwise_causal_attention``): us a call, us a head-pair (a 512 x
+    512 block pair that holds a visible key, one query head), the flash
+    form's share of the MXU by ``4 D H`` operations a visible (row, key).
+    On a tree whose kernel knows no window only the full layer's flash
+    form is timed."""
+    import inspect
+
+    from paddle_tpu.ops.blockwise_attention import \
+        blockwise_causal_attention
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    q = jnp.asarray(r.randn(1, Sb, H, D), jnp.bfloat16)
+    k = jnp.asarray(r.randn(1, Sb, KV, D), jnp.bfloat16)
+    v = jnp.asarray(r.randn(1, Sb, KV, D), jnp.bfloat16)
+    scale = D ** -0.5
+    windowed = "window" in inspect.signature(
+        fa.flash_attention_gqa).parameters
+    for w in (window, None):
+        forms = {"lax": lambda q, k, v, w=w: blockwise_causal_attention(
+            q, k, v, scale, w)}
+        if w is None or windowed:
+            kw = {} if w is None else {"window": w}
+            forms["flash_gqa"] = lambda q, k, v, kw=kw: \
+                fa.flash_attention_gqa(q, k, v, scale=scale, **kw)
+        n = Sb // 512
+        seen = [(i, j) for i in range(n) for j in range(i + 1)
+                if w is None or j * 512 + 511 > i * 512 - w]
+        t = np.arange(Sb)
+        keys = int((np.minimum(t + 1, w) if w else t + 1).sum())
+        want = jax.jit(forms["lax"])(q, k, v).astype(jnp.float32)
+        for name, form in forms.items():
+            got = jax.jit(form)(q, k, v).astype(jnp.float32)
+
+            @jax.jit
+            def prog(q, k, v, form=form):
+                def body(q, _):
+                    return (q + form(q, k, v) * 1e-3).astype(q.dtype), None
+
+                return lax.scan(body, q, None, length=STEPS)[0]
+
+            us = _best(prog, q, k, v) * 1e6
+            line = {"shape": f"prefill_{Sb}", "form": name, "window": w,
+                    "Sq": Sb, "kv_heads": KV, "q_heads": H,
+                    "us_per_call": round(us, 1), "block_pairs": len(seen),
+                    "us_per_head_pair": round(us / (len(seen) * H), 3),
+                    "max_err_vs_lax": round(
+                        float(jnp.abs(got - want).max()), 4)}
+            if name != "lax":
+                line["mxu_share_pct"] = round(
+                    100 * 4 * keys * D * H / (us * 1e-6) / peaks.flops, 1)
+            print(json.dumps(line), flush=True)
+
+
 def main():
     global KV, H, D
     ap = argparse.ArgumentParser()
@@ -267,6 +331,9 @@ def main():
     if args.window is None and D == DV:
         for Sb in args.prefill or (128, 256, 512, 1024, 2048):
             prefill_readings(Sb, r, peaks, args.gqa_block)
+    elif args.prefill is not None and D == DV:
+        for Sb in args.prefill or (2048, 4096, 8192):
+            window_prefill_readings(Sb, args.window, r, peaks)
     return 0
 
 
