@@ -1451,8 +1451,8 @@ SERVE_CASES = {
         engines=lambda sz: [{}],
         # one latent and one rotated key a position
         pools=lambda cfg: [(1, cfg.kv_lora_rank), (1, cfg.rope_cache_width)],
-        decode_kernels=lambda cfg: {
-            "mla_paged_decode_attention": cfg.num_layers},
+        # jitted on its own since PR 47: lowered once, called a layer
+        decode_kernels=lambda cfg: {"mla_paged_decode_attention": 1},
         donated=3),         # latents, rotated keys, the routing counters
         ServeCase(
         label="shortcut",
@@ -1463,9 +1463,9 @@ SERVE_CASES = {
             "new": sz.new_tokens, "batch": sz.latent_batch},
         engines=lambda sz: [{}],
         pools=lambda cfg: [(1, cfg.kv_lora_rank), (1, cfg.rope_cache_width)],
-        # two attentions a layer, each over its own pooled pair
-        decode_kernels=lambda cfg: {
-            "mla_paged_decode_attention": 2 * cfg.num_layers},
+        # two attentions a layer, each over its own pooled pair, all
+        # calls of the one lowered kernel
+        decode_kernels=lambda cfg: {"mla_paged_decode_attention": 1},
         donated=3, extra=shortcut_extra)),
     "serve_hybrid": (hybrid_case("mimo", "hybrid", "hybrid_lens"),
                      hybrid_case("afmoe", "afmoe", "afmoe_lens")),
@@ -1492,7 +1492,7 @@ SERVE_CASES = {
                            (1, cfg.index_cache_width)],
         kernels=sparse_mla_kernels,
         decode_kernels=lambda cfg: {
-            "mla_paged_sparse_decode_attention": cfg.num_layers},
+            "mla_paged_sparse_decode_attention": 1},
         donated=4, forward=selecting_forward, extra=sparse_mla_extra),),
 }
 

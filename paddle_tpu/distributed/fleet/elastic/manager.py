@@ -85,12 +85,14 @@ class ElasticManager:
                 # ERROR flips restart_needed) instead of silently
                 # letting the pod split-brain
                 if not self._stop.is_set():
-                    self._set_status(ElasticStatus.ERROR)
+                    # the reason first: a thread that polls the status
+                    # reads the log once it sees ERROR
                     logger.error(
                         "elastic heartbeat for rank %d failed (%s: %s); "
                         "peers will see this node as dead — flagging "
                         "ERROR for the recovery loop", self.rank,
                         type(e).__name__, e)
+                    self._set_status(ElasticStatus.ERROR)
                 return
 
     # -- watching -------------------------------------------------------

@@ -25,9 +25,11 @@ row's frontier (``offset`` + new tokens, a SCALAR-PREFETCH input):
   into one of ``depth`` VMEM slots a pool. The kernel's visits, in grid
   order, are ONE sequence, and a cursor in SMEM runs ``depth - 1``
   visits ahead of the one being computed, across rows and grid steps
-  alike: a row of one page (a free slot, length 0, costs one step and
-  one page) has its successors' pages on their way while it computes,
-  and the batch's last visits find nothing left to start. Why: with one
+  alike (``_page_walk``: the cursor exists once, and the latent kernel
+  of ``mla_attention.py`` walks its one-head pools on it too): a row of
+  one page (a free slot, length 0, costs one step and one page) has its
+  successors' pages on their way while it computes, and the batch's
+  last visits find nothing left to start. Why: with one
   fetch in flight, started when the page before it began to compute, a
   page cost ``0.32 us + bytes / 819 GB/s`` on a v5e whatever its size
   (half the bandwidth at 262 kB a fetch); of that 0.32 us the copies'
@@ -258,23 +260,86 @@ def _paged_plan(Sq, G, KV, page, D, itemsize, Dv=None) -> PagedPlan:
                and _paged_vmem_bytes(h, Sq, G, page, D, itemsize, Dv)
                <= _PAGED_VMEM_BUDGET), 1)
     fetch = hb * page * (D + Dv) * itemsize
-    depth = min(max(3, 1 + -(-_PAGED_IN_FLIGHT // fetch)), _PAGED_MAX_DEPTH)
-    while depth > 2 and _paged_vmem_bytes(
-            hb, Sq, G, page, D, itemsize, Dv, depth) > _PAGED_VMEM_DEEP:
-        depth -= 1
+    depth = _walk_depth(fetch, lambda depth: _paged_vmem_bytes(
+        hb, Sq, G, page, D, itemsize, Dv, depth))
     return PagedPlan(hb, depth, (depth - 1) * fetch)
+
+
+def _walk_depth(fetch, vmem_bytes) -> int:
+    """VMEM slots a pool for a page walk whose visit copies ``fetch``
+    bytes (``_paged_plan``'s rule, and the latent kernel's): enough that
+    two fetches, and ``_PAGED_IN_FLIGHT`` bytes, fly beside the page
+    being computed, at most ``_PAGED_MAX_DEPTH``; fewer only where
+    ``vmem_bytes(depth)`` passes ``_PAGED_VMEM_DEEP``."""
+    depth = min(max(3, 1 + -(-_PAGED_IN_FLIGHT // fetch)), _PAGED_MAX_DEPTH)
+    while depth > 2 and vmem_bytes(depth) > _PAGED_VMEM_DEEP:
+        depth -= 1
+    return depth
+
+
+def _page_walk(cur, depth, t, steps, span, fetch):
+    """The visits of a paged kernel as ONE sequence, for ``_paged_kernel``
+    and the latent kernel (``mla_attention.py``), each with its own
+    pools. A VISIT is one page of one grid step; the kernel's visits, in
+    grid order, form one sequence, and a cursor runs ``depth - 1``
+    visits ahead of the one being computed, across rows and grid steps,
+    starting each visit's copies into the slot the visit before the
+    current one has left.
+
+    ``cur``: four int32 in SMEM = (the grid step of the next visit to
+    fetch, its visit in that step, visits fetched, visits computed).
+    ``span(step)`` -> (first logical page, pages) of a grid step;
+    ``fetch(step, j, slot)`` -> the copies of logical page ``j`` of that
+    step into slot ``slot``. Called once a grid step ``t`` of ``steps``,
+    before the step's own work: at the first step it starts the first
+    ``depth - 1`` visits. Returns ``visits(i0, lo, n, compute)``: the
+    step's ``n`` visits from page ``lo``, where ``i0 = cur[3]`` is read
+    by the caller beside its ``span``; each waits for its page, having
+    started the one ``depth - 1`` ahead, and runs ``compute(k, slot)``."""
+
+    def issue():
+        """Start the copies of the next visit not yet fetched, if the
+        batch has one left, and move the cursor on."""
+        step, k, i = cur[0], cur[1], cur[2]
+
+        @pl.when(step < steps)
+        def _():
+            first, pages = span(step)
+            for copy in fetch(step, first + k, i % depth):
+                copy.start()
+            end = k + 1 >= pages
+            cur[0] = jnp.where(end, step + 1, step)
+            cur[1] = jnp.where(end, 0, k + 1)
+            cur[2] = i + 1
+
+    @pl.when(t == 0)
+    def _():
+        for c in range(4):
+            cur[c] = 0
+        for _ in range(depth - 1):
+            issue()
+
+    def visits(i0, lo, n, compute):
+        def visit(k, _):
+            slot = (i0 + k) % depth
+            issue()         # into the slot the visit before this one read
+            for copy in fetch(t, lo + k, slot):
+                copy.wait()
+            compute(k, slot)
+
+        lax.fori_loop(0, n, visit, None)
+        cur[3] = i0 + n
+
+    return visits
 
 
 def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
                   G, hb, nh, depth, window=None, sink=False, kept=False):
     """One grid step a (row, block of ``hb`` KV heads), and inside it a
-    loop over the pages the row owns. A VISIT is one page of one grid
-    step; the kernel's visits, in grid order, form one sequence, and a
-    cursor in SMEM runs ``depth - 1`` visits ahead of the one being
-    computed, across rows and grid steps, starting each visit's copies
-    into the slot the visit before the current one has left. The heads
-    of a fetch are the batch of a visit's products: rows are (s, g)
-    within a head, and the mask knows positions only.
+    loop over the pages the row owns, fetched ``depth - 1`` visits ahead
+    (``_page_walk``). The heads of a fetch are the batch of a visit's
+    products: rows are (s, g) within a head, and the mask knows
+    positions only.
 
     ``window``: the rows see only the last ``window`` positions up to
     their own, the table is a RING of ``npages`` columns (logical page
@@ -315,29 +380,8 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
                 pltpu.make_async_copy(v_hbm.at[pid, heads], v_buf.at[slot],
                                       sem.at[1, slot]))
 
-    def issue():
-        """Start the copies of the next visit not yet fetched, if the
-        batch has one left, and move the cursor on: ``cur`` = (its grid
-        step, its visit in that step, visits fetched, visits computed)."""
-        step, k, i = cur[0], cur[1], cur[2]
-
-        @pl.when(step < steps)
-        def _():
-            first, pages = span(step // nh)
-            for copy in fetch(step, first + k, i % depth):
-                copy.start()
-            end = k + 1 >= pages
-            cur[0] = jnp.where(end, step + 1, step)
-            cur[1] = jnp.where(end, 0, k + 1)
-            cur[2] = i + 1
-
-    @pl.when(t == 0)
-    def _():
-        for c in range(4):
-            cur[c] = 0
-        for _ in range(depth - 1):
-            issue()
-
+    visits = _page_walk(cur, depth, t, steps,
+                        lambda step: span(step // nh), fetch)
     off = len_ref[b]
     lo, n = span(b)
     i0 = cur[3]
@@ -361,11 +405,7 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
     # page as its grid steps did
     bound = mask_bound() if hb > 1 else None
 
-    def visit(k, _):
-        slot = (i0 + k) % depth
-        issue()         # into the slot the visit before this one read
-        for copy in fetch(t, lo + k, slot):
-            copy.wait()
+    def compute(k, slot):
         s = lax.dot_general(qb, k_buf[slot], (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32) * scale
         first = (lo + k) * page             # the page's first position
@@ -387,8 +427,7 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, *refs, scale, page, npages, Sq,
             preferred_element_type=jnp.float32)
         m_s[:, :, :1] = m_new
 
-    lax.fori_loop(0, n, visit, None)
-    cur[3] = i0 + n
+    visits(i0, lo, n, compute)
     if not sink:
         out = acc_s[...] / jnp.maximum(l_s[:, :, :1], 1e-30)
     else:   # the sink joins the denominator as one more score, no value

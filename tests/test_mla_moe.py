@@ -540,33 +540,99 @@ def test_the_form_is_a_function_of_the_token_count_alone():
         assert grouped == (form == "sorted")
 
 
-@pytest.mark.parametrize("lengths", [[0, 5, 17, 63], [31, 32, 33, 16]])
-def test_latent_kernel_is_its_dense_twin(lengths):
-    B, H, dc, dr, page, npages, P = 4, 8, 128, 64, 16, 4, 24
+# (columns of the table, tokens in cache a row): pages of 16 and a
+# 128-wide latent in float32, so a visit holds TWO pages
+LATENT_WALKS = {
+    "ragged_with_a_free_row": (4, [0, 5, 17, 63]),
+    "around_a_page_edge": (4, [31, 32, 33, 16]),
+    # a row's own position is its page's last, and the next page's first
+    "ends_on_a_page_edge": (4, [15, 16, 47, 48, 0]),
+    # a whole table, and a scan that stepped on past it
+    "fills_the_table": (4, [63, 0, 62, 63, 67, 48]),
+    # the cursor crosses grid steps with fetches in flight; B is no
+    # multiple of the slots; odd and even counts of pages a row
+    "one_page_beside_ten": (10, [3, 159, 0, 150, 7, 155, 9]),
+    "every_row_one_page": (10, [0, 0, 9, 0, 15]),
+    "one_row": (10, [100]),
+    "an_odd_table": (5, [79, 40, 64, 0]),
+    # pages of 128 and a 512-wide latent: two pages pass the bytes a
+    # walk keeps in flight, and a visit holds ONE
+    "a_page_a_visit": (3, [130, 0, 383, 127]),
+}
+LATENT_DIMS = {"a_page_a_visit": (128, 512)}        # else (16, 128)
+
+
+@pytest.mark.parametrize("kept", [False, True], ids=["all", "kept"])
+@pytest.mark.parametrize("walk", sorted(LATENT_WALKS))
+def test_latent_kernel_is_its_dense_twin(walk, kept):
+    """The page walk against the dense twin. Table entries past a row's
+    frontier name no page of the pool, and the pages an index outside
+    the pool would clamp to hold NaN: a page read in error shows."""
+    npages, lengths = LATENT_WALKS[walk]
+    page, dc = LATENT_DIMS.get(walk, (16, 128))
+    B, H, dr = len(lengths), 8, 128
+    assert ma._latent_plan(H, page, dc, dr, 4)[0] == (1 if page == 128
+                                                      else 2)
+    P = B * npages + 3
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     ql = jax.random.normal(ks[0], (B, H, dc), jnp.float32)
     qr = jax.random.normal(ks[1], (B, H, dr), jnp.float32)
     cp = jax.random.normal(ks[2], (P, 1, page, dc), jnp.float32)
     rp = jax.random.normal(ks[3], (P, 1, page, dr), jnp.float32)
-    tbl = np.random.default_rng(1).permutation(P - 1)[:B * npages] \
-        .reshape(B, npages).astype(np.int32)
-    ln = jnp.asarray(lengths, jnp.int32)
+    rng = np.random.default_rng(1)
+    clean = 1 + rng.permutation(P - 2)[:B * npages].reshape(
+        B, npages).astype(np.int32)
+    ln = np.asarray(lengths, np.int32)
+    owned = np.arange(npages)[None] <= (ln // page)[:, None]
+    tbl = np.where(owned, clean, P + 7)
+    poison = jnp.asarray([0, P - 1])
+    keep = None
+    if kept:
+        M = npages * page
+        keep = rng.random((B, M)) < 0.4
+        keep[:, page:2 * page] = False          # a page with no kept row
+        keep[np.arange(B), np.minimum(ln, M - 1)] = True
+        keep = jnp.asarray(keep)
     assert ma.mla_paged_supported(ql.shape, cp.shape, rp.shape)
-    got = ma.mla_paged_decode_attention(ql, qr, cp, rp, tbl, ln, 0.11,
-                                        interpret=True)
+    got = ma.mla_paged_decode_attention(
+        ql, qr, cp.at[poison].set(jnp.nan), rp.at[poison].set(jnp.nan),
+        tbl, ln, 0.11, interpret=True, keep=keep)
     want = ma.mla_paged_attention_dense(ql[:, None], qr[:, None], cp, rp,
-                                        tbl, ln, 0.11)[:, 0]
+                                        clean, ln, 0.11, keep)[:, 0]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
 
 
 def test_latent_kernel_gate():
-    ok = ((128, 64, 512), (2048, 1, 128, 512), (2048, 1, 128, 64))
+    ok = ((128, 64, 512), (2048, 1, 128, 512), (2048, 1, 128, 128))
     assert ma.mla_paged_supported(*ok)
+    # the three cells' shapes of call
+    for B, H, P in ((128, 64, 1408), (128, 64, 1536), (48, 128, 4096)):
+        assert ma.mla_paged_supported((B, H, 512), (P, 1, 128, 512),
+                                      (P, 1, 128, 128))
     assert not ma.mla_paged_supported((128, 64, 32), (64, 1, 16, 32),
                                       (64, 1, 16, 8))      # tiny latent
     assert not ma.mla_paged_supported((4, 64, 512), (64, 8, 128, 512),
                                       (64, 8, 128, 64))    # per-head cache
+    # a rotated key that fills no lane: a page is copied whole from HBM
+    assert not ma.mla_paged_supported(ok[0], ok[1], (2048, 1, 128, 64))
+    # a row's blocks past the scoped VMEM (1,448 heads compile on a v5e,
+    # 1,536 do not)
+    assert ma.mla_paged_supported((8, 1448, 512), *ok[1:])
+    assert not ma.mla_paged_supported((8, 1536, 512), *ok[1:])
+
+
+@pytest.mark.parametrize("H, itemsize, plan", [
+    (64, 2, (2, 3)), (128, 2, (2, 3)),  # the cells: 328 kB a visit of two
+    (64, 4, (1, 3)),        # float32: two pages pass the bytes in flight
+    (1448, 2, (1, 2)),      # the gate's last head count: VMEM's room
+])
+def test_latent_kernel_plan_follows_the_shapes(H, itemsize, plan):
+    """(pages a visit, slots a pool) at d_c 512, d_r 128, pages of 128."""
+    assert ma._latent_plan(H, 128, 512, 128, itemsize) == plan
+    pages, depth = plan
+    assert depth == 2 or ma._latent_vmem_bytes(
+        H, 128, 512, 128, itemsize, depth, pages) <= ma._PAGED_VMEM_DEEP
 
 
 @pytest.mark.parametrize("kw, needle", [

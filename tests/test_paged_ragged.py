@@ -324,6 +324,56 @@ class TestPlanRule:
                             break
 
 
+# The cells' shapes of call of ``paged_decode_attention``:
+# (B, H, KV, D, npages, P, keywords) -> sha256 of the jaxpr (the kernel's
+# body in it), read from the parent of PR 47 (ff0c998) by this very code:
+# the page walk moved into ``_page_walk``, which the latent kernel
+# shares, and the dense kernel's text did not change by it
+WALK_TEXT = {
+    "chat": ((48, 32, 8, 128, 19, 512, {}),
+        "2ae43a8f67a37a5d0555a8b5196e1bc0b91f9908891958b0e6fd708910fd8666"),
+    "trinity_window_ring": (
+        (64, 32, 4, 128, 17, 2048, {"window": 2048}),
+        "b3cd091d17770c8c463fcf8ef7e3b160001adedac2b5001ad40f02cce9bbfd00"),
+    "mimo_sink_window": (
+        (128, 64, 8, 256, 2, 1024, {"window": 128, "sinks": True}),
+        "7edc5ef19fdd2baad6666ed936d77499103405e66ed71aac7e2084643a56cc86"),
+    "keye_keep": ((128, 32, 4, 128, 70, 4096, {"keep": True}),
+        "feb6cebc7e0a8b2a27fce1ffda8593dfacb5009475f479793f287f391097c462"),
+}
+
+
+def _walk_text(B, H, KV, D, npages, P, kw):
+    import hashlib
+
+    import jax
+
+    page, Dv = 128, 128
+    S = jax.ShapeDtypeStruct
+    args = [S((B, 1, H, D), jnp.bfloat16), S((P, KV, page, D), jnp.bfloat16),
+            S((P, KV, page, Dv), jnp.bfloat16), S((B, npages), jnp.int32),
+            S((B,), jnp.int32)]
+    names = [n for n in ("sinks", "keep") if kw.get(n)]
+    if "sinks" in names:
+        args.append(S((H,), jnp.float32))
+    if "keep" in names:
+        args.append(S((B, npages * page), jnp.bool_))
+
+    def call(q, kp, vp, tbl, ln, *rest):
+        return paged_decode_attention.__wrapped__(
+            q, kp, vp, tbl, ln, window=kw.get("window"),
+            **dict(zip(names, rest)))
+
+    return hashlib.sha256(
+        str(jax.make_jaxpr(call)(*args)).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("cell", sorted(WALK_TEXT))
+def test_the_dense_kernel_on_the_shared_walk_is_the_kernel_it_was(cell):
+    shape, parent = WALK_TEXT[cell]
+    assert _walk_text(*shape) == parent
+
+
 class TestRaggedGenerate:
     @classmethod
     def setup_class(cls):

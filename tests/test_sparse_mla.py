@@ -312,7 +312,11 @@ def test_forward_scopes_name_the_index_the_selection_and_the_attention(
 
 
 # -- (c) the absorbed form under the kept mask ---------------------------------
-@pytest.mark.parametrize("B, H, npages", [(3, 8, 5), (2, 16, 3)])
+@pytest.mark.parametrize("B, H, npages", [
+    (3, 8, 5), (2, 16, 3),
+    (7, 8, 10),     # more rows than slots, and no multiple of them
+    (5, 16, 1),     # every row one page: its own position alone is kept
+])
 def test_sparse_latent_kernel_is_its_dense_twin_and_the_unabsorbed_form(
         B, H, npages):
     """``mla_paged_sparse_decode_attention`` (interpreted) against the
