@@ -63,14 +63,15 @@ ONE_CHIP_PHASES = ("kernels", "train", "serve")
 FOUR_CHIP_PHASES = ("mp2dp2", "pp2mp2")
 # run only when named in --phases: the default three fill their time limit
 EXTRA_PHASES = ("serve_latent", "serve_hybrid", "serve_sparse",
-                "serve_sparse_mla")
+                "serve_sparse_mla", "serve_ssm")
 ALL_PHASES = ONE_CHIP_PHASES + FOUR_CHIP_PHASES + EXTRA_PHASES
 # seconds per child, compilation included. The one-chip three sum to 1100,
 # inside that run's 1200 s; cold on a v5e they took 72, 122 and 106 s (PR 21)
 PHASE_TIMEOUT = {"kernels": 200, "train": 400, "serve": 500,
                  "mp2dp2": 400, "pp2mp2": 400, "serve_latent": 700,
                  "serve_hybrid": 900,    # two shapes since PR 35
-                 "serve_sparse": 400, "serve_sparse_mla": 400}
+                 "serve_sparse": 400, "serve_sparse_mla": 400,
+                 "serve_ssm": 500}
 RESULT_TAG = "CHIP_SMOKE_PHASE_RESULT "
 
 # Tolerances, each with its reason. Every comparison is
@@ -224,6 +225,23 @@ class Sizes:
             # S(1), seen on the chip and in an AOT compile, PR 41), so
             # 4,096 pages (134 MB)
             self.sparse_mla_pool = 4096
+            # serve_ssm: one mixer a layer at nemotron-3-super's published
+            # widths: two state-space layers (a float32 slot of 4 MiB a
+            # row each), two latent relu2 expert layers holding 32 of the
+            # router's 512 experts, one attention layer of 2 KV heads, a
+            # quarter of the vocabulary (0.98B parameters)
+            self.ssm = dict(
+                vocab_size=32768, hidden_size=4096,
+                mixer_kinds=["ssm", "experts", "attention", "ssm",
+                             "experts"],
+                num_heads=32, num_kv_heads=2, head_dim=128,
+                ssm_num_heads=128, ssm_head_dim=64, ssm_groups=8,
+                ssm_state_size=128, conv_kernel=4, chunk_size=128,
+                num_experts=512, num_local_experts=32,
+                num_experts_per_tok=22, routed_scaling_factor=5.0,
+                moe_intermediate_size=2688, moe_latent_size=1024,
+                shared_expert_intermediate_size=5376,
+                max_position_embeddings=1152, dtype="bfloat16")
             self.sparse_mla = dict(
                 hidden_size=256, num_heads=8, q_lora_rank=128,
                 kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
@@ -288,6 +306,18 @@ class Sizes:
             self.sparse_mla = dict(self.sparse)
             self.sparse_lens, self.sparse_new = (100, 60, 30, 10), 12
             self.sparse_pool = self.sparse_mla_pool = None
+            self.ssm = dict(
+                vocab_size=512, hidden_size=128,
+                mixer_kinds=["ssm", "experts", "attention", "ssm",
+                             "experts"],
+                num_heads=8, num_kv_heads=2, head_dim=32, ssm_num_heads=8,
+                ssm_head_dim=32, ssm_groups=2, ssm_state_size=16,
+                conv_kernel=4, chunk_size=16, num_experts=16,
+                num_local_experts=4, num_experts_per_tok=4,
+                routed_scaling_factor=5.0, moe_intermediate_size=64,
+                moe_latent_size=64, shared_expert_intermediate_size=128,
+                max_position_embeddings=288, attention_block=32,
+                dtype="bfloat16")
 
 
 # ---------------------------------------------------------------------------
@@ -863,7 +893,9 @@ class ServeCase:
     # cfg -> (heads, width) of each array a layer pools; None: no claim
     pools: Optional[Callable]
     decode_kernels: Callable    # cfg -> {Mosaic kernel: calls in ('decode',)}
-    donated: int        # state arrays a layer the decode step writes in place
+    # arrays the decode step writes in place: a layer (int), or cfg -> all
+    # of them where the layers differ
+    donated: object
     # (sz, cfg): the decode kernels alone against their dense twins at
     # this model's shapes, before the model takes the memory
     kernels: Optional[Callable] = None
@@ -1020,7 +1052,9 @@ def serve_case(sz: Sizes, case: ServeCase, events: JaxEvents,
                 # expert model's counters), never its round array (tables,
                 # pos, token, mask), which every layer reads
                 donated = eng.donated_params(text)
-                check(len(donated) == case.donated * tuples
+                lent = case.donated(cfg) if callable(case.donated) \
+                    else case.donated * tuples
+                check(len(donated) == lent
                       and all(name.startswith("state") for name in donated),
                       f"compiled program ('decode',) donates the "
                       f"{len(donated)} arrays it was lent and not its "
@@ -1425,6 +1459,39 @@ def hybrid_case(label: str, sizes: str, lens: str) -> ServeCase:
         extra=hybrid_extra)
 
 
+def ssm_extra(ctx) -> None:
+    """A model with state layers: every slot taken was given back, the
+    recurrent state is float32 beside a bf16 model, what the cache
+    accounts is what the arrays take, and the engine says why it refuses
+    a prefix cache."""
+    from paddle_tpu.inference import ServingEngine
+
+    eng, cfg = ctx.eng, ctx.cfg
+    n = cfg.mixer_kinds.count("ssm")
+    row = n * (cfg.ssm_num_heads * cfg.ssm_head_dim * cfg.ssm_state_size
+               * 4 + (cfg.conv_kernel - 1) * cfg.conv_dim * 2)
+    mem = eng.memory_summary()["state"]
+    kept = sorted({str(layer[0].dtype) for layer, st in zip(
+        eng.pools, eng.cache.state_layers) if st})
+    check(mem["state_row_bytes"] == row and kept == ["float32"]
+          and mem["state_bytes"] == eng.B * row,
+          f"a row's slot over {n} state layers is {row} bytes, H kept in "
+          f"{kept}; {eng.B} slots hold {mem['state_bytes']} bytes")
+    eng.check_invariants()
+    classes = eng.cache.counts()["classes"]
+    check(classes["state"] == {"used": 0, "free": eng.B}
+          and classes["full"]["used"] == 0,
+          f"every slot and page back to free: {classes}")
+    try:
+        ServingEngine(eng.pred, max_batch=eng.B, prefill_chunk=ctx.sz.page,
+                      prefix_cache=True)
+        why = ""
+    except Exception as e:      # the refusal, with its reason
+        why = str(e)
+    check("state layers" in why and "one slot a row" in why,
+          f"a chunked engine is refused with its reason: {why[:60]}")
+
+
 SERVE_CASES = {
     "serve": (ServeCase(
         label="llama",
@@ -1494,6 +1561,22 @@ SERVE_CASES = {
         decode_kernels=lambda cfg: {
             "mla_paged_sparse_decode_attention": 1},
         donated=4, forward=selecting_forward, extra=sparse_mla_extra),),
+    "serve_ssm": (ServeCase(
+        label="ssm", model=("ssm_moe", "SSMMoEConfig", "SSMMoEForCausalLM",
+                            "ssm"),
+        traffic=lambda sz: {
+            "warm": [n for n in sz.warm_lens if n <= 900],
+            "mix": [n for n in sz.mix_lens if n <= 900],
+            "new": sz.new_tokens, "batch": sz.latent_batch},
+        engines=lambda sz: [{"debug_invariants": True}],
+        pools=None,     # the layers differ: state, pages, nothing
+        decode_kernels=lambda cfg: {"paged_decode_attention": 1},
+        # H and the tail of a state layer, K and V of an attention
+        # layer, the routing counter of an expert layer
+        donated=lambda cfg: sum(
+            {"ssm": 2, "attention": 2, "experts": 1}[k]
+            for k in cfg.mixer_kinds),
+        extra=ssm_extra),),
 }
 
 

@@ -22,6 +22,7 @@ expert modules (the reference loops over ``self.experts`` per rank).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from functools import partial
 from typing import List, Optional
@@ -36,6 +37,7 @@ from .....autograd import engine as _engine
 from .....core.enforce import enforce
 from .....distributed import collective as C
 from .....nn.layer import Layer
+from .....observability import annotate as _annotate
 from .....observability import moestats as _moestats
 from .....tensor import Tensor
 from .gate import (BaseGate, GShardGate, NaiveGate, SigmoidTopKGate,
@@ -334,6 +336,21 @@ def swiglu(x, w_gate, w_up, w_down):
                    preferred_element_type=jnp.float32)
 
 
+def _activation(g, u):
+    """What stands between an expert's first product(s) and its last:
+    ``silu(g) * u`` of a gated expert, ``relu(u)^2`` of one that has no
+    gate matrix (``g`` None)."""
+    return jax.nn.silu(g) * u if g is not None \
+        else jnp.square(jax.nn.relu(u))
+
+
+def relu2_mlp(x, w_up, w_down):
+    """``relu(x W_u)^2 W_d``: ``swiglu`` without a gate matrix."""
+    u = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+    return jnp.dot(_activation(None, u).astype(x.dtype), w_down,
+                   preferred_element_type=jnp.float32)
+
+
 # The held experts' products take one of two forms, chosen from the number
 # of tokens T in the call (a static shape). Batched over the held experts,
 # every token goes through every held expert and the combine selects: T
@@ -381,7 +398,8 @@ def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
     idx      [T, k] int32 chosen experts, numbered over ALL experts
     weights  [T, k] f32   their weights (normalised over all k chosen)
     w_*      [El, ...]    the experts held here: expert_offset ..
-                          expert_offset + El - 1
+                          expert_offset + El - 1; ``w_gate`` None: two
+                          matrices an expert, ``relu(x W_u)^2 W_d``
     num_experts           the router's width E (None: the held El)
 
     Returns (y [T, d] float32: the sum over chosen AND held experts;
@@ -398,7 +416,7 @@ def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
         y, sizes = routed_swiglu_batched(x2d, idx, weights, w_gate, w_up,
                                          w_down, expert_offset)
         return y, sizes, {"form": form}
-    (T, k), El = idx.shape, w_gate.shape[0]
+    (T, k), El = idx.shape, w_up.shape[0]
     E = El if num_experts is None else num_experts
     y, sizes, passes = routed_swiglu_sorted(
         x2d, idx, weights, w_gate, w_up, w_down, expert_offset, E)
@@ -436,7 +454,7 @@ def routed_swiglu_sorted(x2d, idx, weights, w_gate, w_up, w_down,
     layer is held whole. The computed pairs are counted, over the
     passes, from the sorted rows that lay inside a group."""
     T, k = idx.shape
-    El = w_gate.shape[0]
+    El = w_up.shape[0]
     pairs = T * k
     M = sorted_rows(T, k, El, El if num_experts is None else num_experts)
     local = idx - expert_offset
@@ -459,11 +477,11 @@ def routed_swiglu_sorted(x2d, idx, weights, w_gate, w_up, w_down,
         tok = lax.dynamic_slice(order, (lo,), (M,)) // k
         gw = jnp.clip(ends, lo, lo + M) - jnp.clip(starts, lo, lo + M)
         xs = x2d[tok]                                          # [M, d]
-        g = lax.ragged_dot(xs, w_gate, gw,
-                           preferred_element_type=jnp.float32)
+        g = None if w_gate is None else lax.ragged_dot(
+            xs, w_gate, gw, preferred_element_type=jnp.float32)
         u = lax.ragged_dot(xs, w_up, gw,
                            preferred_element_type=jnp.float32)
-        out = lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype),
+        out = lax.ragged_dot(_activation(g, u).astype(x2d.dtype),
                              w_down, gw,
                              preferred_element_type=jnp.float32)
         # a sorted row counts if its pair is held and a group covered it
@@ -507,18 +525,19 @@ def routed_swiglu_batched(x2d, idx, weights, w_gate, w_up, w_down,
     product with 0, drops an unchosen activation: it may be non-finite.
     The computed pairs are counted from the combine that was applied."""
     T = x2d.shape[0]
-    El, d, h = w_gate.shape
+    El, d, h = w_up.shape
     pairs, c = _combine(idx, weights, expert_offset, El)       # [T, El]
     xb = jnp.broadcast_to(x2d, (El, T, d))
     per_expert = (((2,), (1,)), ((0,), (0,)))
-    g = lax.dot_general(xb, w_gate, per_expert,
-                        preferred_element_type=jnp.float32)    # [El, T, h]
+    g = None if w_gate is None else lax.dot_general(
+        xb, w_gate, per_expert,
+        preferred_element_type=jnp.float32)                    # [El, T, h]
     u = lax.dot_general(xb, w_up, per_expert,
                         preferred_element_type=jnp.float32)
     a = jnp.where((pairs > 0).T[:, :, None],
-                  jax.nn.silu(g) * u * c.T[:, :, None], 0.0)
+                  _activation(g, u) * c.T[:, :, None], 0.0)
     y = jnp.dot(a.astype(x2d.dtype).transpose(1, 0, 2).reshape(T, El * h),
-                w_down.reshape(El * h, d),
+                w_down.reshape(El * h, w_down.shape[2]),
                 preferred_element_type=jnp.float32)
     local = idx - expert_offset
     absent = ((local < 0) | (local >= El)).sum()
@@ -555,6 +574,19 @@ class GatedMoELayer(Layer):
     ``router_bias`` / ``norm_topk_prob`` are the gate's
     ``bias_on_choice`` / ``norm_topk_prob``.
 
+    LATENT EXPERTS (``latent_size = dl``): the ROUTER reads the
+    ``d_model``-wide token, the EXPERTS read ``l = x W_dn`` (``[d_model,
+    dl]``) and are ``dl -> d_hidden -> dl``, and ``W_up`` (``[dl,
+    d_model]``) stands after the ROUTED sum alone: the two projections
+    are linear, so the holders' parts still add up, and the shared
+    expert reads and writes ``d_model``. With ``latent_scope`` that part
+    (``W_dn``, the routed products, ``W_up``) runs under that
+    ``observability.annotate`` scope. ``activation="relu2"``: an expert
+    is TWO matrices, ``relu(x W_u)^2 W_d``, routed and shared alike (no
+    ``w_gate`` / ``shared_gate`` parameter exists). ``shared_hidden``:
+    the shared expert's own width (default ``d_hidden *
+    num_shared_experts``).
+
     ``forward(x, counts=None)``: ``counts`` is an optional
     ``[num_local_experts + 3]`` int32 routing counter (``routed_swiglu``'s
     sizes: pairs per held expert, pairs of absent experts, pairs
@@ -581,10 +613,23 @@ class GatedMoELayer(Layer):
                  n_group: int = 0, topk_group: int = 0,
                  zero_expert_num: int = 0,
                  router_bias: Optional[bool] = None,
-                 norm_topk_prob: bool = True):
+                 norm_topk_prob: bool = True,
+                 latent_size: Optional[int] = None,
+                 activation: str = "swiglu",
+                 shared_hidden: Optional[int] = None,
+                 latent_scope: Optional[str] = None):
         super().__init__()
         El = num_experts if num_local_experts is None \
             else int(num_local_experts)
+        enforce(activation in ("swiglu", "relu2"),
+                f"an expert is 'swiglu' or 'relu2', not {activation!r}")
+        enforce(not (latent_size and zero_expert_num),
+                "an identity expert returns the token, which a latent "
+                "expert never sees: latent_size and zero_expert_num "
+                "exclude each other")
+        self.gated = activation == "swiglu"
+        self.latent_size = int(latent_size) if latent_size else None
+        self.latent_scope = latent_scope
         enforce(0 <= expert_offset and expert_offset + El <= num_experts,
                 f"held experts {expert_offset}..{expert_offset + El - 1} "
                 f"lie outside the router's {num_experts}")
@@ -601,19 +646,49 @@ class GatedMoELayer(Layer):
             routed_scaling_factor=routed_scaling_factor,
             score_func=score_func, n_group=n_group, topk_group=topk_group,
             bias_on_choice=router_bias, norm_topk_prob=norm_topk_prob)
-        d, h, hs = d_model, d_hidden, d_hidden * num_shared_experts
+        d, h = self.latent_size or d_model, d_hidden
+        hs = d_hidden * num_shared_experts if shared_hidden is None \
+            else int(shared_hidden)
         down_attr = down_attr if down_attr is not None else weight_attr
-        self.w_gate = self.create_parameter((El, d, h), attr=weight_attr)
+        if self.gated:
+            self.w_gate = self.create_parameter((El, d, h),
+                                                attr=weight_attr)
         self.w_up = self.create_parameter((El, d, h), attr=weight_attr)
         self.w_down = self.create_parameter((El, h, d), attr=down_attr)
         self.shared = hs > 0
         if self.shared:
-            self.shared_gate = self.create_parameter((d, hs),
-                                                     attr=weight_attr)
-            self.shared_up = self.create_parameter((d, hs),
+            if self.gated:
+                self.shared_gate = self.create_parameter(
+                    (d_model, hs), attr=weight_attr)
+            self.shared_up = self.create_parameter((d_model, hs),
                                                    attr=weight_attr)
-            self.shared_down = self.create_parameter((hs, d),
+            self.shared_down = self.create_parameter((hs, d_model),
                                                      attr=down_attr)
+        if self.latent_size:
+            self.latent_down = self.create_parameter(
+                (d_model, d), attr=weight_attr)
+            self.latent_up = self.create_parameter((d, d_model),
+                                                   attr=down_attr)
+
+    def _routed(self, x2d, idx, w):
+        """The held experts' part over ``x2d``: ``routed_swiglu`` as it
+        is, or between the two latent projections."""
+        gate = self.w_gate._value if self.gated else None
+        if not self.latent_size:
+            return routed_swiglu(
+                x2d, idx, w, gate, self.w_up._value, self.w_down._value,
+                self.expert_offset, self.gate.num_experts)
+        with _annotate(self.latent_scope) if self.latent_scope \
+                else contextlib.nullcontext():
+            low = jnp.dot(x2d, self.latent_down._value,
+                          preferred_element_type=jnp.float32
+                          ).astype(x2d.dtype)
+            y, sizes, trace = routed_swiglu(
+                low, idx, w, gate, self.w_up._value, self.w_down._value,
+                self.expert_offset, self.gate.num_experts)
+            y = jnp.dot(y.astype(x2d.dtype), self.latent_up._value,
+                        preferred_element_type=jnp.float32)
+        return y, sizes, trace
 
     def forward(self, x, counts=None):
         xv = x._value if isinstance(x, Tensor) else x
@@ -622,9 +697,7 @@ class GatedMoELayer(Layer):
         idx, w, groups = self.gate.route_groups(x2d)
         # the sorted form's bound counts on the ROUTER's width: an
         # identity pair is no row of the held experts' products
-        y, sizes, trace = routed_swiglu(
-            x2d, idx, w, self.w_gate._value, self.w_up._value,
-            self.w_down._value, self.expert_offset, self.gate.num_experts)
+        y, sizes, trace = self._routed(x2d, idx, w)
         if self.zero_expert_num:
             # ids past the real experts: weight * x for every row here;
             # routed_swiglu counted them absent, they are nobody's
@@ -642,9 +715,12 @@ class GatedMoELayer(Layer):
                     (idx // (self.num_experts // n))[..., None]
                     == jnp.arange(n), axis=(0, 1), dtype=jnp.int32))
             _moestats.record({"choices": idx, "load": sizes, **trace})
-        if self.shared:
+        if self.shared and self.gated:
             y = y + swiglu(x2d, self.shared_gate._value,
                             self.shared_up._value, self.shared_down._value)
+        elif self.shared:
+            y = y + relu2_mlp(x2d, self.shared_up._value,
+                               self.shared_down._value)
         out = Tensor(y.astype(xv.dtype).reshape(shape),
                      stop_gradient=True)
         if counts is None:

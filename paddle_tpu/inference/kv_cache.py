@@ -10,7 +10,10 @@ a compiled program, ``commit`` takes the donated arrays back), the page
 ACCOUNTING (free list, refcounts, the hash<->page bijection and LRU of
 the prefix cache) under ONE re-entrant lock that lives here and nowhere
 else, the PAGE PROGRAMS (read, write, copy; traced page ids), the HOST
-SPILL TIER and a row's EXPORT / IMPORT payload.
+SPILL TIER and a row's EXPORT / IMPORT payload. Beside the pages it holds
+the second kind of per-request state, for a model with STATE layers (a
+state-space mixer's recurrent state): arrays of FIXED size a row, one
+slot a row, never paged, never shared (the class docstring).
 
 No jitted dispatch ever runs under the lock: a reclaim only STAGES
 ``(page, hash)`` for the host tier, and the device read that captures
@@ -35,7 +38,7 @@ from ..core.enforce import enforce
 from ..observability import memledger as _ml
 
 __all__ = ["PagedKVCache", "pool_shapes", "page_bytes", "page_classes",
-           "with_table", "without_table"]
+           "state_shapes", "with_table", "without_table"]
 
 
 def pool_shapes(model, P: int, page: int):
@@ -58,16 +61,44 @@ def page_classes(model) -> Tuple[List[bool], Optional[int]]:
     says so itself (``kv_page_classes()``: per layer ``"full"`` — a row
     holds a page for every page of its context — or ``("window", n)`` —
     it only ever reads its last ``n`` positions); without that every
-    layer is full."""
+    layer is full. A layer may also name ``"state"`` (it keeps arrays
+    of fixed size a row and no page: ``state_shapes``) or ``"none"`` (it
+    keeps nothing); neither is a window layer."""
     fn = getattr(model, "kv_page_classes", None)
     if fn is None:
         return [False] * len(pool_shapes(model, 1, 1)), None
     classes = list(fn())
-    windows = {c[1] for c in classes if c != "full"}
+    enforce(all(isinstance(c, tuple) or c in ("full", "state", "none")
+                for c in classes),
+            'a layer\'s class is "full", ("window", n), "state" or '
+            f'"none": the model names {classes}')
+    windows = {c[1] for c in classes if isinstance(c, tuple)}
     enforce(len(windows) <= 1,
             f"one window class a model: its layers name {sorted(windows)}")
-    return [c != "full" for c in classes], \
+    return [isinstance(c, tuple) for c in classes], \
         (int(windows.pop()) if windows else None)
+
+
+def state_shapes(model, dtype) -> List[tuple]:
+    """Per layer, ``((shape a row, dtype), ..)`` of the arrays a STATE
+    layer keeps, ``()`` of every other layer. A model says so itself
+    (``state_shapes()``, a dtype of None meaning the cache's); without
+    that no layer keeps any. The layers that do are those
+    ``kv_page_classes()`` names ``"state"``, and they pool no page."""
+    fn = getattr(model, "state_shapes", None)
+    n = len(pool_shapes(model, 1, 1))
+    if fn is None:
+        return [()] * n
+    out = [tuple((tuple(int(d) for d in shape), jnp.dtype(dt or dtype))
+                 for shape, dt in layer) for layer in fn()]
+    classes = list(model.kv_page_classes())
+    enforce(len(out) == n and all(
+        bool(layer) == (c == "state") and not (layer and pools)
+        for layer, c, pools in zip(out, classes,
+                                   pool_shapes(model, 1, 1))),
+        "state_shapes() names arrays for exactly the layers "
+        'kv_page_classes() calls "state", and those pool no page')
+    return out
 
 
 def page_bytes(model, page: int, dtype, window: bool = False) -> int:
@@ -118,6 +149,19 @@ class PagedKVCache:
     (``refuse_windowed``): a page shared or moved at position p would
     need the window layers' last keys at p, which the ring has
     overwritten.
+
+    THE STATE CLASS for a model with state layers (``state_shapes``): a
+    state layer's arrays are ``[B, ...]`` in their own dtypes, row b the
+    SLOT of table row b (so a decode step updates them in place, row
+    for row, with no gather), taken at admission (``take_slot``) and
+    given back in ``release_row``. They ride ``bind`` / ``lend`` /
+    ``take_back`` / ``commit`` donated, in the layer's tuple where a
+    paged layer has its pools, and a prefill is bound the slots it
+    writes in the table's place. A layer that keeps NOTHING has an empty
+    tuple, and a model's device counters ride on the layers it names
+    (``moe_counter_layers()``; every layer without that). What shares,
+    spills, copies or moves pages is REFUSED for such a model
+    (``refuse_stateful``): the state at the page's position is gone.
     """
 
     def __init__(self, model, page: int, max_length: int, max_batch: int,
@@ -141,6 +185,16 @@ class PagedKVCache:
             self.refuse_windowed(spill_pages, "the host spill tier")
             self.refuse_windowed(draft is not None, "a draft's pools")
             resident_bytes += self.Pw * self.window_page_bytes
+        # the state class: fixed by the batch too, a slot a row
+        self.state = state_shapes(model, dtype)
+        self.state_layers = [bool(layer) for layer in self.state]
+        self.state_row_bytes = sum(
+            int(np.prod(shape)) * dt.itemsize
+            for layer in self.state for shape, dt in layer)
+        if self.state_row_bytes:
+            self.refuse_stateful(spill_pages, "the host spill tier")
+            self.refuse_stateful(draft is not None, "a draft's pools")
+            resident_bytes += self.B * self.state_row_bytes
         # one pool for the owner's whole lifetime, on the power-of-two
         # bucket lattice: the compiled programs are keyed on this shape
         # and NEVER change it. "auto" sizes it from measured HBM
@@ -162,10 +216,15 @@ class PagedKVCache:
             self.shapes = [
                 tuple((self.Pw,) + a[1:] for a in layer) if w else layer
                 for layer, w in zip(self.shapes, self.window_layers)]
-        # arrays a layer pools: where its table sits in a cache tuple
+        self.shapes = [
+            tuple((self.B,) + shape for shape, _ in st) if st else layer
+            for layer, st in zip(self.shapes, self.state)]
+        # arrays a layer keeps: where its table sits in a cache tuple
         self.arrays = [len(layer) for layer in self.shapes]
-        self.pools = [tuple(jnp.zeros(a, dtype) for a in layer)
-                      for layer in self.shapes]
+        self.pools = [
+            tuple(jnp.zeros(a, dt) for a, (_, dt) in zip(layer, st)) if st
+            else tuple(jnp.zeros(a, dtype) for a in layer)
+            for layer, st in zip(self.shapes, self.state)]
         self.draft_pools = None
         self.draft_dtype = None
         if draft is not None:
@@ -182,11 +241,19 @@ class PagedKVCache:
             layers, *row = cshape()
             self.counters = [jnp.zeros(row, jnp.int32)
                              for _ in range(layers)]
+        # the layers whose tuple a counter rides on: the model's own
+        # list, or every layer in order
+        named = getattr(model, "moe_counter_layers", None)
+        self.counter_layers = list(named()) if named is not None \
+            else list(range(len(self.pools)))
         self.tables = np.full((self.B, self.npages), self.trash, np.int32)
         self.wtrash = self.Pw - 1
         self.wtables = np.full((self.B, self.ring), self.wtrash, np.int32)
         self._wfree = list(range(self.Pw - 1))
         self._rings: Dict[int, List[int]] = {}      # table row -> ring
+        # state slots: slot b belongs to table row b and to no other
+        self._sfree = list(range(self.B)) if self.state_row_bytes else []
+        self._slots: Dict[int, int] = {}            # table row -> slot
         # Pages become ref-counted and content-addressable. _hash_page
         # maps the rolling prompt-prefix hash of a COMPLETED
         # page-aligned chunk to the physical page that holds its KV;
@@ -232,10 +299,18 @@ class PagedKVCache:
         return -(-tokens // self.page)
 
     def pool_bytes(self, window: Optional[bool] = None) -> int:
-        """Bytes of the pooled arrays: every layer's, or one class's."""
+        """Bytes of the PAGED arrays: every layer's, or one class's."""
         return sum(_ml.shard_bytes(a)
-                   for layer, w in zip(self.pools, self.window_layers)
-                   if window is None or w == window for a in layer)
+                   for layer, w, st in zip(self.pools, self.window_layers,
+                                           self.state_layers)
+                   if not st and (window is None or w == window)
+                   for a in layer)
+
+    def state_bytes(self) -> int:
+        """Bytes of the state layers' arrays, every slot."""
+        return sum(_ml.shard_bytes(a)
+                   for layer, st in zip(self.pools, self.state_layers)
+                   if st for a in layer)
 
     def release(self) -> None:
         """Give the device arrays back; the cache serves nothing after."""
@@ -251,6 +326,42 @@ class PagedKVCache:
                 "shared, spilled, copied or moved at position p would "
                 "need the window layers' keys up to p, which the ring "
                 "has overwritten")
+
+    def refuse_stateful(self, asked, what: str) -> None:
+        """``what`` needs a row's state at a position other than its
+        last: refused, with the reason, for a model with state layers."""
+        enforce(not (self.state_row_bytes and asked),
+                f"{what} cannot serve a model with state layers: a state "
+                f"layer keeps ONE slot a row ({self.state_row_bytes} "
+                "bytes over its layers), the recurrent state after the "
+                "row's last position and no other, so a page shared, "
+                "spilled, copied or moved at position p would need the "
+                "state at p, which the slot no longer holds")
+
+    # -- the state class: one slot a row ---------------------------------
+    def slots_available(self) -> bool:
+        """Whether one more row can take a slot (always, without state
+        layers)."""
+        with self._lock:
+            return bool(self._sfree) or not self.state_row_bytes
+
+    def take_slot(self, b: int) -> int:
+        """Table row b takes its slot, slot b, for life. Nothing is
+        cleared here: the row's prefill writes the slot whole."""
+        with self._lock:
+            enforce(b not in self._slots and b in self._sfree,
+                    f"table row {b} holds its state slot already")
+            self._sfree.remove(b)
+            self._slots[b] = b
+        return b
+
+    def prefill_slots(self, b: int) -> Optional[np.ndarray]:
+        """What a prefill of table row b is bound in a state layer's
+        table's place: the slot it writes, ``[1]``. None without state
+        layers."""
+        if not self.state_row_bytes:
+            return None
+        return np.asarray([self._slots[b]], np.int32)
 
     # -- the window class: a ring of pages a row -------------------------
     def rings_available(self) -> bool:
@@ -322,19 +433,25 @@ class PagedKVCache:
         return tbl
 
     def bind(self, rows: np.ndarray, draft: bool = False,
-             wrows: Optional[np.ndarray] = None) -> List[tuple]:
+             wrows: Optional[np.ndarray] = None,
+             slots: Optional[np.ndarray] = None) -> List[tuple]:
         """The per-layer ``(a, b[, c], table)`` tuples a compiled
         program takes whole: ``rows`` for a full layer, ``wrows`` for a
-        window layer. One table upload per layer: the cache pytree is
+        window layer, ``slots`` (the slots written; the rows' own in
+        order where not given) for a state layer. One table upload per
+        layer: the cache pytree is
         DONATED to the program, and XLA rejects donating one buffer
         twice. A program that runs every round takes ``lend()`` and ONE
         table of its own instead (``with_table``)."""
-        if not self.window:
+        if draft:
             return [layer + (jnp.asarray(rows),)
-                    for layer in (self.draft_pools if draft
-                                  else self.pools)]
-        return [layer + (jnp.asarray(wrows if w else rows),)
-                for layer, w in zip(self.pools, self.window_layers)]
+                    for layer in self.draft_pools]
+        if slots is None and self.state_row_bytes:
+            slots = np.arange(len(rows), dtype=np.int32)
+        return [layer + (jnp.asarray(
+            slots if st else wrows if w else rows),)
+            for layer, w, st in zip(self.pools, self.window_layers,
+                                    self.state_layers)]
 
     def layer_tables(self, table, wtable):
         """Inside a traced program: per layer, the table of its class."""
@@ -356,19 +473,28 @@ class PagedKVCache:
         upload."""
         if self.counters is None:
             return list(self.pools)
-        enforce(len(self.pools) == len(self.counters),
+        on = self.counter_layers
+        enforce(len(on) == len(self.counters)
+                and all(0 <= i < len(self.pools) for i in on)
+                and len(set(on)) == len(on),
                 f"{len(self.pools)} pooled tuples and "
-                f"{len(self.counters)} device counters: a model keeps one "
-                "counter a tuple of kv_pool_shapes() (a layer of two "
-                "attentions two), and a zip would drop the longer "
+                f"{len(self.counters)} device counters, to ride on layers "
+                f"{on}: a model keeps "
+                "one counter a tuple of kv_pool_shapes() (a layer of two "
+                "attentions two) or names the tuples that carry one "
+                "(moe_counter_layers()), and a zip would drop the longer "
                 "list's tail in silence")
-        return [p + (n,) for p, n in zip(self.pools, self.counters)]
+        out = list(self.pools)
+        for i, n in zip(on, self.counters):
+            out[i] = out[i] + (n,)
+        return out
 
     def take_back(self, state: List[tuple]) -> None:
         """Take back what ``lend`` lent, as the program returned it."""
         self.pools = [tuple(s[:n]) for s, n in zip(state, self.arrays)]
         if self.counters is not None:
-            self.counters = [s[n] for s, n in zip(state, self.arrays)]
+            self.counters = [state[i][self.arrays[i]]
+                             for i in self.counter_layers]
 
     # -- page accounting (ref-counted pool + prefix cache) ---------------
     def available(self) -> int:
@@ -440,13 +566,18 @@ class PagedKVCache:
 
     def release_row(self, b: int, pages: List[int]) -> None:
         """Evict table row b: its pages released (and its ring of window
-        pages, if it holds one), the row all-trash."""
+        pages and its state slot, if it holds them), the row all-trash.
+        The slot's arrays are left as they are: the next request's
+        prefill writes them whole."""
         self.release_pages(pages)
         self.set_row(b, [])
         with self._lock:
             ring = self._rings.pop(b, None)
             if ring is not None:
                 self._wfree.extend(ring)
+            slot = self._slots.pop(b, None)
+            if slot is not None:
+                self._sfree.append(slot)
         if ring is not None:
             self.wtables[b, :] = self.wtrash
 
@@ -537,6 +668,9 @@ class PagedKVCache:
                 wfree = len(self._wfree)
                 out["classes"]["window"] = {
                     "used": self.Pw - 1 - wfree, "free": wfree}
+            if self.state_row_bytes:
+                out["classes"]["state"] = {
+                    "used": len(self._slots), "free": len(self._sfree)}
             return out
 
     def prefix_stats(self) -> Dict[str, Any]:
@@ -561,11 +695,27 @@ class PagedKVCache:
         With window layers: the free window pages and the rows' rings
         partition that class, a row's ring is what its table row names,
         and (given ``live_rows``, the table rows in use) exactly those
-        rows hold a ring. Raises on any violation — double free, leak,
-        or refcount drift."""
+        rows hold a ring. With state layers: the free slots and the
+        rows' slots partition the slots, a row's slot is its own, and
+        (given ``live_rows``) exactly those rows hold one. Raises on any
+        violation — double free, leak, or refcount drift."""
         held = Counter(pg for pages in held_pages for pg in pages)
         with self._lock:
             bad: List[str] = []
+            if self.state_row_bytes:
+                taken = list(self._slots.values())
+                if sorted(taken + self._sfree) != list(range(self.B)):
+                    bad.append(
+                        f"state class: free({len(self._sfree)}) + held"
+                        f"({len(taken)}) do not partition its {self.B} "
+                        "slots (a leaked or doubly held slot)")
+                if any(b != slot for b, slot in self._slots.items()):
+                    bad.append("a row holds another row's state slot")
+                if live_rows is not None and \
+                        set(live_rows) != set(self._slots):
+                    bad.append(
+                        f"rows holding a slot {sorted(self._slots)} != "
+                        f"rows in use {sorted(set(live_rows))}")
             if self.window:
                 rung = [pg for r in self._rings.values() for pg in r]
                 if sorted(rung + self._wfree) != list(range(self.Pw - 1)):
@@ -674,6 +824,7 @@ class PagedKVCache:
         freshly allocated page); the reference on the shared original
         is dropped. Returns the new page."""
         self.refuse_windowed(True, "copy-on-write of a shared page")
+        self.refuse_stateful(True, "copy-on-write of a shared page")
         [new] = self.allocate(1)
         self.copy_page(old, new)
         self.release_pages([old])
@@ -767,8 +918,10 @@ class PagedKVCache:
     def check_stackable(self) -> None:
         """A page migrates as ONE stacked array of every layer's pooled
         arrays; pools of several shapes cannot be stacked."""
-        self.refuse_windowed(True, "the migration of a row's pages "
-                             "(export / import, the disaggregated phases)")
+        what = ("the migration of a row's pages (export / import, the "
+                "disaggregated phases)")
+        self.refuse_windowed(True, what)
+        self.refuse_stateful(True, what)
         shapes = sorted({a[1:] for layer in self.shapes for a in layer})
         enforce(len(shapes) == 1,
                 "the disaggregated phases migrate a page as ONE stacked "

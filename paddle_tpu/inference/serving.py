@@ -128,8 +128,8 @@ from ..observability.spans import (RequestTrace, SpanRing,
                                    _parse_traceparent)
 from ..observability.trace import span as _span
 from ..tensor import Tensor
-from .kv_cache import (PagedKVCache, page_classes, with_table,
-                       without_table)
+from .kv_cache import (PagedKVCache, page_classes, state_shapes,
+                       with_table, without_table)
 
 __all__ = ["ServingEngine", "ServingRequest"]
 
@@ -345,6 +345,34 @@ class ServingEngine:
                     "serve a model with window layers: a hit at position "
                     "p would need the window layers' keys before p, and "
                     f"the donor's ring holds {ring}")
+        # a model with state layers (a state-space mixer's recurrent
+        # state; kv_cache.PagedKVCache's state class) keeps ONE slot a
+        # row, the state after the row's last position: what needs the
+        # state at another position is refused, with its reason
+        if any(state_shapes(predictor._model, "float32")):
+            slot = ("a state layer keeps one slot a row, the recurrent "
+                    "state after the row's LAST position and no other")
+            enforce(not self.chunked,
+                    "prefill_chunk (the unified ragged step) cannot serve "
+                    f"a model with state layers: {slot}, its forward takes "
+                    "no `valid`, and a chunk that starts at position p "
+                    "would have to carry the state from p - 1 through a "
+                    "round that other rows ride; serve it in the default "
+                    "mode (bucketed prefill)")
+            enforce(draft_predictor is None and not spec_tokens,
+                    "speculative decoding cannot serve a model with state "
+                    f"layers: {slot}, so a rejected draft token has "
+                    "already advanced the slot and nothing holds the "
+                    "state to roll back to")
+            enforce(phase is None,
+                    "the disaggregated phases cannot serve a model with "
+                    f"state layers: a row migrates as its pages, and {slot}"
+                    ", which is not among them; run unified replicas")
+            enforce(not prefix_cache and not host_spill_pages,
+                    "the prefix cache (and its host spill tier) cannot "
+                    f"serve a model with state layers: {slot}, so a hit "
+                    "at position p would need the donor's state at p, "
+                    "which its slot has long since left behind")
         # a model whose attention selects keys by a learned index
         # (model.key_selection: the keys a query keeps) scores a row's
         # index keys through the block table in its decode step alone
@@ -823,7 +851,8 @@ class ServingEngine:
                 self._drain()
             cold, reserve, hits, hashes, fed0 = self._admit_plan(req)
             if cold + reserve > self.cache.available() \
-                    or not self.cache.rings_available():
+                    or not self.cache.rings_available() \
+                    or not self.cache.slots_available():
                 return                    # head-of-line waits for evictions
             self.queue.popleft()
             b = free[0]
@@ -835,6 +864,8 @@ class ServingEngine:
             self.cache.set_row(b, pages)
             if self.cache.window:
                 self.cache.take_ring(b)
+            if self.cache.state_row_bytes:
+                self.cache.take_slot(b)
             slot = _Slot(
                 req, pages, state="prefill" if self.chunked else "decode",
                 seq=self._admit_seq)
@@ -886,7 +917,8 @@ class ServingEngine:
                 ids[0, :L] = req.prompt
                 caches = self.cache.bind(
                     self.cache.rows(b),
-                    wrows=self.cache.window_prefill_rows(b, L))
+                    wrows=self.cache.window_prefill_rows(b, L),
+                    slots=self.cache.prefill_slots(b))
                 fn = self.pred._prefill_fn(1, Sb, self.M)
                 self.stats.note("prefill",
                                 (1, Sb, self.M, self.page, self.P,
@@ -1772,6 +1804,11 @@ class ServingEngine:
         m["page_occupancy"].set(
             (usable - n_free - n_idle) / usable if usable else 0.0)
         for cls, n in c["classes"].items():
+            if cls == "state":          # slots, not pages
+                m["state_slots"].set(n["used"])
+                m["state_bytes"].set(
+                    n["used"] * self.cache.state_row_bytes)
+                continue
             m["kv_pages"].set(n["used"], **{"class": cls, "state": "used"})
             m["kv_pages"].set(n["free"], **{"class": cls, "state": "free"})
         if self.prefix:
@@ -1951,6 +1988,10 @@ class ServingEngine:
                 "window_page_bytes": self.cache.window_page_bytes,
                 "window_pool_pages": self.cache.Pw,
                 "window_ring": self.cache.ring,
+                # a model with state layers: one slot a row beside the
+                # pages, state_bytes == state_row_bytes * max_batch
+                "state_bytes": self.cache.state_bytes(),
+                "state_row_bytes": self.cache.state_row_bytes,
                 "live_peak_bytes": self._live_peak,
             },
         }

@@ -10,8 +10,10 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
 from .mla_moe import MLAMoEConfig, MLAMoEForCausalLM, mla_moe_tiny
 from .hybrid_moe import (HybridMoEConfig, HybridMoEForCausalLM, afmoe_tiny,
                          hybrid_moe_tiny)
+from .ssm_moe import SSMMoEConfig, SSMMoEForCausalLM, ssm_moe_tiny
 
 __all__ = ["MLAMoEConfig", "MLAMoEForCausalLM", "mla_moe_tiny",
+           "SSMMoEConfig", "SSMMoEForCausalLM", "ssm_moe_tiny",
            "HybridMoEConfig", "HybridMoEForCausalLM", "hybrid_moe_tiny",
            "afmoe_tiny",
            "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTForCausalLMPipe",

@@ -548,6 +548,18 @@ def serving_metrics(reg: MetricsRegistry = None) -> Dict[str, object]:
             "context, summed over the rows, at the last retired decode "
             "round",
             unit="ratio"),
+        "state_slots": r.gauge(
+            "paddle_tpu_state_slots_in_use",
+            "state slots the live rows of a serving engine hold: one a "
+            "row of a model with state layers (a state-space mixer's "
+            "recurrent state and convolution tail, of fixed size "
+            "whatever the context), taken at admission and given back "
+            "at eviction; 0 for a model without such layers"),
+        "state_bytes": r.gauge(
+            "paddle_tpu_state_bytes",
+            "device bytes of the state slots in use: slots in use x the "
+            "bytes a row's state takes over the model's state layers",
+            unit="By"),
         "moe_zero_pick_share": r.gauge(
             "paddle_tpu_moe_zero_pick_share",
             "of the expert choices the decode steps of a serving engine "

@@ -385,3 +385,151 @@ def test_check_invariants_catches(fault, needle):
     fault(c, held)
     with pytest.raises(PreconditionNotMetError, match=needle):
         c.check_invariants(held)
+
+
+# -- the state class: one slot a row beside the pages -------------------------
+def state_model(counters=True):
+    """Five layers: state, none, full, state, none (a state-space mixer,
+    an expert layer, an attention layer...): H in float32 and a tail in
+    the cache's type a state layer, K and V the one paged layer, and a
+    routing counter on the two layers that keep nothing."""
+    kv = lambda P, page: ((P, 2, page, 8), (P, 2, page, 8))
+    st = (((3, 2, 4), "float32"), ((6,), None))
+    m = SimpleNamespace(
+        kv_pool_shapes=lambda P, page: [(), (), kv(P, page), (), ()],
+        kv_page_classes=lambda: ["state", "none", "full", "state", "none"],
+        state_shapes=lambda: [st, (), (), st, ()])
+    if counters:
+        m.moe_counter_shape = lambda: (2, 5)
+        m.moe_counter_layers = lambda: [1, 4]
+    return m
+
+
+def state_cache(**kw):
+    return PagedKVCache(state_model(), PAGE, 32, 3, jnp.bfloat16,
+                        pool_pages=8, **kw)
+
+
+def test_state_class_geometry_and_what_rides():
+    c = state_cache()
+    assert c.state_layers == [True, False, False, True, False]
+    assert c.arrays == [2, 0, 2, 2, 0]
+    # a slot: 2 layers x (24 float32 + 6 bfloat16), whatever the context
+    assert c.state_row_bytes == 2 * (24 * 4 + 6 * 2)
+    assert c.state_bytes() == 3 * c.state_row_bytes
+    assert [a.dtype for a in c.pools[0]] == [jnp.float32, jnp.bfloat16]
+    assert [a.shape for a in c.pools[3]] == [(3, 3, 2, 4), (3, 6)]
+    # the paged class counts its pages alone
+    assert c.page_bytes == 2 * 2 * PAGE * 8 * 2
+    assert c.pool_bytes() == c.P * c.page_bytes
+    assert c.counts()["classes"]["state"] == {"used": 0, "free": 3}
+    # a prefill is bound the slot it writes in a state layer's table's
+    # place, the block table elsewhere; a layer that keeps nothing is
+    # its table alone
+    c.take_slot(1)
+    bound = c.bind(c.rows(1), slots=c.prefill_slots(1))
+    assert [len(t) for t in bound] == [3, 1, 3, 3, 1]
+    assert np.asarray(bound[0][2]).tolist() == [1]
+    assert bound[2][2].shape == (1, c.npages)
+    c.commit(bound)
+    assert [len(p) for p in c.pools] == [2, 0, 2, 2, 0]
+    # the decode program is lent arrays and counters, and the counters
+    # ride on the layers the model names
+    state = c.lend()
+    assert [len(t) for t in state] == [2, 1, 2, 2, 1]
+    table = jnp.asarray(c.rows())
+    caches = with_table(state, table, c.arrays)
+    assert [len(t) for t in caches] == [3, 2, 3, 3, 2]
+    assert caches[1][0] is table and caches[0][2] is table
+    back = without_table([t[:-1] + (t[-1] + 1,) if len(t) == 2 else t
+                          for t in caches], c.arrays)
+    c.take_back(back)
+    assert [int(n.sum()) for n in c.counters] == [5, 5]
+    assert c.pools[0][0] is state[0][0]
+
+
+def test_counter_layers_must_match_the_counters():
+    m = state_model()
+    m.moe_counter_layers = lambda: [1]          # two counters, one layer
+    c = PagedKVCache(m, PAGE, 32, 3, jnp.bfloat16, pool_pages=8)
+    with pytest.raises(PreconditionNotMetError, match="zip would drop"):
+        c.lend()
+    m = state_model()
+    del m.moe_counter_layers                    # five tuples, two counters
+    c = PagedKVCache(m, PAGE, 32, 3, jnp.bfloat16, pool_pages=8)
+    with pytest.raises(PreconditionNotMetError, match="zip would drop"):
+        c.lend()
+
+
+def test_slots_are_taken_at_admission_and_given_back():
+    c = state_cache()
+    assert c.slots_available()
+    for b in (0, 2, 1):
+        assert c.take_slot(b) == b
+    assert not c.slots_available()
+    with pytest.raises(PreconditionNotMetError, match="slot already"):
+        c.take_slot(2)
+    c.check_invariants([], live_rows=[0, 1, 2])
+    c.release_row(2, [])
+    assert c.slots_available()
+    assert c.counts()["classes"]["state"] == {"used": 2, "free": 1}
+    c.check_invariants([], live_rows=[0, 1])
+    assert c.take_slot(2) == 2
+    # a cache without state layers never runs out of what it has not
+    assert make().slots_available() and make().prefill_slots(0) is None
+
+
+def _slot_held_twice(c):
+    c._slots[1] = c._slots[0]
+
+
+def _slot_leaked(c):
+    c._slots.pop(0)                 # neither free nor any row's
+
+
+def _slot_of_a_dead_row(c):
+    c.take_slot(2)                  # row 2 serves nothing
+
+
+@pytest.mark.parametrize("fault, needle", [
+    (_slot_held_twice, "leaked or doubly held slot"),
+    (_slot_leaked, "leaked or doubly held slot"),
+    (_slot_of_a_dead_row, "rows holding a slot"),
+])
+def test_check_invariants_catches_a_slot(fault, needle):
+    c = state_cache()
+    c.take_slot(0)
+    c.take_slot(1)
+    c.check_invariants([], live_rows=[0, 1])
+    fault(c)
+    with pytest.raises(PreconditionNotMetError, match=needle):
+        c.check_invariants([], live_rows=[0, 1])
+
+
+@pytest.mark.parametrize("call, what", [
+    (lambda: state_cache(spill_pages=2), "the host spill tier"),
+    (lambda: PagedKVCache(state_model(), PAGE, 32, 3, jnp.bfloat16,
+                          pool_pages=8,
+                          draft=(kv_model(), jnp.bfloat16)),
+     "a draft's pools"),
+    (lambda: state_cache().copy_on_write(0), "copy-on-write"),
+    (lambda: state_cache().check_stackable(), "migration of a row"),
+    (lambda: state_cache().refuse_stateful(True, "this"), "this"),
+])
+def test_state_layers_refuse_what_moves_pages(call, what):
+    with pytest.raises(PreconditionNotMetError,
+                       match=f"{what}.*state layers.*ONE slot a row"):
+        call()
+    state_cache().refuse_stateful(False, "this")
+    make().refuse_stateful(True, "a cache without state layers")
+
+
+def test_state_shapes_must_name_the_state_layers():
+    m = state_model()
+    m.kv_page_classes = lambda: ["state", "none", "full", "none", "none"]
+    with pytest.raises(PreconditionNotMetError, match="state_shapes"):
+        PagedKVCache(m, PAGE, 32, 3, jnp.bfloat16, pool_pages=8)
+    m = state_model()
+    m.kv_page_classes = lambda: ["state", "none", "ring", "state", "none"]
+    with pytest.raises(PreconditionNotMetError, match="layer's class"):
+        PagedKVCache(m, PAGE, 32, 3, jnp.bfloat16, pool_pages=8)

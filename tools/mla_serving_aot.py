@@ -9,7 +9,10 @@ keys by a learned index and pool a third array; ``deepseek-v3.2-exp``:
 ``MLAMoEForCausalLM`` with a query latent and an index that selects rows
 of the latent cache; ``longcat-flash-omni``: the same class as
 shortcut-connected double layers, ``--layers`` of them, two pooled
-tuples and two kernel calls each) at the benchmark
+tuples and two kernel calls each; ``nemotron-3-super-120b-a12b``:
+``SSMMoEForCausalLM``, the first ``--layers`` mixers of its pattern,
+whose state arrays count among the pools: a copy of one shows in
+``pool_copies``) at the benchmark
 configuration's widths (``benchmarks/configs/<config>.json``) with
 ``--layers`` layers (2: the
 dense layer and one expert layer) and NO weights (``LazyGuard``), takes
@@ -91,6 +94,9 @@ def main(argv):
         cfg["num_hidden_layers"] = args.layers
     if "layers_run" in cfg:     # a depth cut that names published layers
         cfg["layers_run"] = cfg["layers_run"][:args.layers]
+    if "hybrid_override_pattern" in cfg:    # a mixer kind a character
+        cfg["hybrid_override_pattern"] = \
+            cfg["hybrid_override_pattern"][:args.layers]
     srv = cfg["serving"]
     fam = importlib.import_module(
         "benchmarks.harness.families." + cfg["family"])
@@ -128,8 +134,9 @@ def main(argv):
         f"prefill_{args.prefill}": (
             pred._prefill_fn(1, args.prefill, eng.M),
             (pvals, i32(1, args.prefill),
-             [tuple(map(sds, layer)) + (i32(1, npages),)
-              for layer in eng.pools],
+             # a state layer is bound the slot it writes, not a table
+             [tuple(map(sds, layer)) + (i32(1) if st else i32(1, npages),)
+              for layer, st in zip(eng.pools, eng.cache.state_layers)],
              i32(1))),
     }
     kernels = ("mla_paged_decode_attention",
