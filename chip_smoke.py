@@ -1026,10 +1026,18 @@ def serve_case(sz: Sizes, case: ServeCase, events: JaxEvents,
                   and (sz.rehearsal or "sorted" in forms["prefill"]),
                   f"expert products' forms {st['forms']} (decode at "
                   f"{eng.B} rows, prefill buckets {buckets})")
-            grouped = {site: "ragged_dot" in eng.lowered_text(site)
+            # a sorted bucket's grouped product is the one the layers
+            # recorded: ours ("grouped_matmul") at the published widths,
+            # XLA's ("ragged_dot") where the kernel's gate refuses them
+            grouped = {site: [n for n in ("ragged_dot", "grouped_matmul")
+                              if n in eng.lowered_text(site)]
                        for site in [("decode",), ("prefill", buckets[-1])]}
-            check(sz.rehearsal or list(grouped.values()) == [False, True],
-                  f"XLA's grouped matmul in the lowered programs: {grouped}")
+            said = st["grouped"]
+            check(sz.rehearsal or (
+                said == {"decode": None, "prefill": "pallas"}
+                and list(grouped.values()) == [[], ["grouped_matmul"]]),
+                f"grouped products in the lowered programs: {grouped}, "
+                f"traced as {said}")
         found = kernel_names(eng.lowered_text(("decode",)))
         asked = case.decode_kernels(cfg)
         check(sz.rehearsal or all(found.get(name, 0) == calls

@@ -34,11 +34,15 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from .....autograd import engine as _engine
+from .....core import flags as _flags
 from .....core.enforce import enforce
 from .....distributed import collective as C
 from .....nn.layer import Layer
 from .....observability import annotate as _annotate
 from .....observability import moestats as _moestats
+from .....ops import pallas as _pallas
+from .....ops.pallas.grouped_matmul import (group_visits, grouped_matmul,
+                                            grouped_matmul_supported)
 from .....tensor import Tensor
 from .gate import (BaseGate, GShardGate, NaiveGate, SigmoidTopKGate,
                    SwitchGate)
@@ -361,7 +365,10 @@ def relu2_mlp(x, w_up, w_down):
 # nothing. 128 is the largest size of the serving lattice (powers of two)
 # under that ridge. Above it the batched form would be compute-bound and
 # do El / (k x held share) times the work: the sorted form, whose work
-# follows the pairs routed to the held experts, takes over.
+# follows the pairs routed to the held experts, takes over. Which grouped
+# matmul that form's products run on is ``grouped_product``'s to say: our
+# Mosaic kernel on a TPU (every held expert's weights read once a call),
+# XLA's ``ragged_dot`` off it.
 _BATCHED_MAX_TOKENS = 128
 
 
@@ -373,8 +380,13 @@ def routed_form(T: int) -> str:
 # The sorted form works on M sorted rows at a time, a static bound from the
 # call's shapes: the pairs a uniform router sends to the held experts
 # (T k El / E) with half as many again for a router that is not, rounded up
-# to the rows the grouped product tiles by. What a prompt sends beyond M
-# takes another pass of the same body; nothing is dropped.
+# to _SORTED_TILE rows. What a prompt sends beyond M takes another pass of
+# the same body; nothing is dropped. 256 is a multiple of both row tiles of
+# the grouped product that runs on a TPU (``ops/pallas/grouped_matmul.py``:
+# 128 rows a visit up to 128 rows a group, 256 above, both timed there:
+# ``row_tile``'s docstring), so its gate admits every M this gives; the kernel's tiles start at each group's own first
+# row, so the rounding buys no alignment and is kept for the programs'
+# shapes (it was XLA's ``ragged_dot``'s row tile until PR 49).
 _SORTED_SLACK = 1.5
 _SORTED_TILE = 256
 
@@ -409,8 +421,9 @@ def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
     router's choice, so a held pair that the products missed shows as
     missing from the last; trace: what the call's form was as it was
     traced, ``form`` and, for the sorted one, ``rows`` = (the bound M,
-    T * k) and ``passes``, the windows of M rows it ran: a device
-    value)."""
+    T * k), ``passes``, the windows of M rows it ran: a device value,
+    and ``grouped``, the grouped product its calls took:
+    ``grouped_product``'s "pallas" | "xla")."""
     form = routed_form(x2d.shape[0])
     if form == "batched":
         y, sizes = routed_swiglu_batched(x2d, idx, weights, w_gate, w_up,
@@ -418,10 +431,26 @@ def routed_swiglu(x2d, idx, weights, w_gate, w_up, w_down,
         return y, sizes, {"form": form}
     (T, k), El = idx.shape, w_up.shape[0]
     E = El if num_experts is None else num_experts
-    y, sizes, passes = routed_swiglu_sorted(
+    y, sizes, passes, grouped = routed_swiglu_sorted(
         x2d, idx, weights, w_gate, w_up, w_down, expert_offset, E)
-    return y, sizes, {"form": form, "passes": passes,
+    return y, sizes, {"form": form, "passes": passes, "grouped": grouped,
                       "rows": (sorted_rows(T, k, El, E), T * k)}
+
+
+def grouped_product(M: int, dtype, *weights) -> str:
+    """The grouped product the sorted form's calls over ``M`` rows of
+    ``dtype`` take through the stacked ``weights`` (None: no such
+    matrix): ``"pallas"`` (``ops/pallas/grouped_matmul.py``) on a TPU
+    with kernels on (``FLAGS_use_pallas_kernels``, as every dispatch
+    site) where that kernel's shape gate admits every one of them, else
+    ``"xla"`` (``lax.ragged_dot``). A function of the call's static
+    shapes and the platform alone."""
+    if not (_flags._get("use_pallas_kernels", True)
+            and _pallas.is_tpu_platform()):
+        return "xla"
+    ours = all(w.dtype == dtype and grouped_matmul_supported(
+        (M, w.shape[1]), w.shape, dtype) for w in weights if w is not None)
+    return "pallas" if ours else "xla"
 
 
 def _group_sizes(e, El: int):
@@ -443,13 +472,22 @@ def routed_swiglu_sorted(x2d, idx, weights, w_gate, w_up, w_down,
     """``routed_swiglu`` with work in proportion to the HELD pairs. The
     (token, expert) pairs' int32 keys are sorted by expert, held ones
     first; a window of ``M = sorted_rows(T, k, El, E)`` sorted rows is
-    gathered (``[M, d]``), goes through the three products grouped over
-    the held experts (``lax.ragged_dot``: XLA's grouped matmul on TPU),
-    is scaled and added to its tokens' rows of ``y``. A prompt that
-    sends more than M pairs to the held experts runs the same body on
-    the next M sorted rows, ``ceil(held / M)`` passes in all (returned
-    third): each pass's groups are the part of every group inside its
-    window. Pairs of absent experts sort behind every group and are rows
+    gathered (``[M, d]``), goes through the two or three products grouped
+    over the held experts, is scaled and added to its tokens' rows of
+    ``y``. The grouped product is ``grouped_product``'s: on a TPU
+    ``ops/pallas/grouped_matmul.py``, which reads each held expert's
+    weights once a call and runs at 1.16-1.28 times their stream where
+    XLA's kernel took 2.5-4 times it at 16-100 rows a group and was
+    5-30% behind at 400-800 (PERF.md, PR 49), one table of visits a
+    window for its products; off the TPU, or at widths that kernel's
+    gate refuses, ``lax.ragged_dot`` (XLA's grouped matmul; what the CPU
+    tests and the benchmark's references run). Rows past the window's
+    last group are nobody's: the select below drops them, not a
+    product. A prompt that sends more than M pairs to the held experts
+    runs the same body on the next M sorted rows, ``ceil(held / M)``
+    passes in all (returned third; fourth, the grouped product the
+    windows took): each pass's groups are the part of every group inside
+    its window. Pairs of absent experts sort behind every group and are rows
     of nothing: no array has ``T * k`` rows of width d or h unless the
     layer is held whole. The computed pairs are counted, over the
     passes, from the sorted rows that lay inside a group."""
@@ -470,20 +508,23 @@ def routed_swiglu_sorted(x2d, idx, weights, w_gate, w_up, w_down,
     order, wf = (jnp.pad(a, (0, -pairs % M)) for a in (order, wf))
     n_held = held.sum()
     row = jnp.arange(M, dtype=jnp.int32)
+    grouped = grouped_product(M, x2d.dtype, w_gate, w_up, w_down)
 
     def window(p, carry):
         y, summed = carry
         lo = p * M
         tok = lax.dynamic_slice(order, (lo,), (M,)) // k
         gw = jnp.clip(ends, lo, lo + M) - jnp.clip(starts, lo, lo + M)
+        if grouped == "pallas":     # one table of visits a window
+            dot = partial(grouped_matmul, group_sizes=gw,
+                          visits=group_visits(gw, M))
+        else:
+            dot = partial(lax.ragged_dot, group_sizes=gw,
+                          preferred_element_type=jnp.float32)
         xs = x2d[tok]                                          # [M, d]
-        g = None if w_gate is None else lax.ragged_dot(
-            xs, w_gate, gw, preferred_element_type=jnp.float32)
-        u = lax.ragged_dot(xs, w_up, gw,
-                           preferred_element_type=jnp.float32)
-        out = lax.ragged_dot(_activation(g, u).astype(x2d.dtype),
-                             w_down, gw,
-                             preferred_element_type=jnp.float32)
+        g = None if w_gate is None else dot(xs, w_gate)
+        u = dot(xs, w_up)
+        out = dot(_activation(g, u).astype(x2d.dtype), w_down)
         # a sorted row counts if its pair is held and a group covered it
         used = (lo + row < n_held) & (row < gw.sum())
         scale = lax.dynamic_slice(wf, (lo,), (M,))
@@ -500,7 +541,7 @@ def routed_swiglu_sorted(x2d, idx, weights, w_gate, w_up, w_down,
         y, summed = lax.fori_loop(0, passes, window, init)
     sizes = jnp.concatenate([gs, jnp.stack([(~held).sum(), summed])
                              .astype(jnp.int32)])
-    return y, sizes, passes
+    return y, sizes, passes, grouped
 
 
 def _combine(idx, weights, expert_offset: int, El: int):
@@ -599,9 +640,10 @@ class GatedMoELayer(Layer):
     identity pairs, with identity experts), under a gate with groups
     ``groups`` (the kept groups' ids [T, topk_group]) and ``group_load``
     (the call's routed pairs by group, [n_group]), ``form`` and, in the
-    sorted form, ``rows`` (the bound, the call's routed pairs) and
+    sorted form, ``rows`` (the bound, the call's routed pairs),
     ``passes`` (a device value: the windows of that many rows the call
-    took; 1 unless a prompt sends the held experts more than the bound).
+    took; 1 unless a prompt sends the held experts more than the bound)
+    and ``grouped`` (``grouped_product``: "pallas" | "xla").
     """
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,

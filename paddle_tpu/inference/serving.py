@@ -489,8 +489,10 @@ class ServingEngine:
         # site -> (jitted fn, arg shapes) of every program that has run
         self._site_programs: Dict[Any, Any] = {}
         # "decode" | "prefill" | .. -> the forms an expert model's
-        # layers traced in this engine's programs of that kind
+        # layers traced in this engine's programs of that kind, and the
+        # grouped product their sorted form took
         self._moe_forms: Dict[str, set] = {}
+        self._moe_grouped: Dict[str, set] = {}
         # prefill bucket -> the form its program's attention over the
         # pool traced ("flash" | "paged" | "dense")
         self._prefill_attention: Dict[int, str] = {}
@@ -1863,9 +1865,11 @@ class ServingEngine:
         finally:
             if listen:
                 recs = _moestats.drain()
-                forms = {r["form"] for r in recs if "form" in r}
-                if forms:
-                    self._moe_forms.setdefault(site[0], set()).update(forms)
+                for key, kept in (("form", self._moe_forms),
+                                  ("grouped", self._moe_grouped)):
+                    said = {r[key] for r in recs if key in r}
+                    if said:
+                        kept.setdefault(site[0], set()).update(said)
                 if site[0] == "prefill":
                     attn = {r["attention"] for r in recs
                             if "attention" in r}
@@ -2059,8 +2063,12 @@ class ServingEngine:
         program and in the prefill programs (``routed_form``: "batched"
         | "sorted"; both joined by "+" if the buckets differ), as they
         recorded it when this engine traced them; None for a kind it
-        has not traced. ``rows`` = for each prefill bucket traced in the
-        sorted form, (the sorted rows its expert layers hold at a time,
+        has not traced. ``grouped`` = beside it, the grouped product the
+        sorted form's calls took there (``moe_layer.grouped_product``:
+        "pallas" = ``ops/pallas/grouped_matmul.py`` | "xla" =
+        ``lax.ragged_dot``; None where no program of the kind was traced
+        in the sorted form). ``rows`` = for each prefill bucket traced in
+        the sorted form, (the sorted rows its expert layers hold at a time,
         ``moe_layer.sorted_rows``; the bucket's routed pairs). None for
         a model without routed experts."""
         if self.cache.counters is None:
@@ -2082,10 +2090,10 @@ class ServingEngine:
         if z and tokens.any():
             self._metrics["moe_zero_pick_share"].set(
                 float(zero.sum()) / (float(tokens.sum()) * k))
-        return {**out,
-                "forms": {kind: "+".join(sorted(
-                    self._moe_forms.get(kind, ()))) or None
-                    for kind in ("decode", "prefill")},
+        said = lambda kept: {kind: "+".join(sorted(kept.get(kind, ())))
+                             or None for kind in ("decode", "prefill")}
+        return {**out, "forms": said(self._moe_forms),
+                "grouped": said(self._moe_grouped),
                 "rows": dict(sorted(self._moe_rows.items()))}
 
     def roofline_report(self):

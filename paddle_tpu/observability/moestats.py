@@ -16,7 +16,9 @@ stats aux). They cannot be fetched mid-trace, so the flow is:
 
 ``GatedMoELayer`` (the serving-side expert layer) records through the
 same collector while one is open: ``choices`` and ``load``, ``form`` /
-``rows`` / ``passes`` of its products and, under a gate that keeps groups
+``rows`` / ``passes`` / ``grouped`` of its products (the last:
+``moe_layer.grouped_product``, which grouped matmul the sorted form
+took) and, under a gate that keeps groups
 of experts first, ``groups`` (the kept groups' ids a token) and
 ``group_load`` (the call's routed pairs by group, so a skewed group
 shows); ``ServingEngine`` and the benchmark's probes read those.

@@ -173,7 +173,7 @@ _OK_LINES = {
         "every served token of the first request scores within # ",
         "expert layers dropped # routed pairs of #",
         "expert products' forms {'decode': 'batched', 'prefill': ",
-        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "grouped products in the lowered programs: {('decode',): ",
         "program ('decode',) holds Mosaic calls {} (asked: {'mla_",
         "compiled program ('decode',): # copies of a whole pool #",
         "compiled program ('decode',) donates the # arrays it was",
@@ -190,7 +190,7 @@ _OK_LINES = {
         "every served token of the first request scores within # ",
         "expert layers dropped # routed pairs of #",
         "expert products' forms {'decode': 'batched', 'prefill': ",
-        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "grouped products in the lowered programs: {('decode',): ",
         "program ('decode',) holds Mosaic calls {} (asked: {'mla_",
         "compiled program ('decode',): # copies of a whole pool #",
         "compiled program ('decode',) donates the # arrays it was",
@@ -208,7 +208,7 @@ _OK_LINES = {
         "every served token of the first request scores within # ",
         "expert layers dropped # routed pairs of #",
         "expert products' forms {'decode': 'batched', 'prefill': ",
-        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "grouped products in the lowered programs: {('decode',): ",
         "program ('decode',) holds Mosaic calls {} (asked: {'page",
         "compiled program ('decode',): # copies of a whole pool #",
         "compiled program ('decode',) donates the # arrays it was",
@@ -229,7 +229,7 @@ _OK_LINES = {
         "every served token of the first request scores within # ",
         "expert layers dropped # routed pairs of #",
         "expert products' forms {'decode': 'batched', 'prefill': ",
-        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "grouped products in the lowered programs: {('decode',): ",
         "program ('decode',) holds Mosaic calls {} (asked: {'page",
         "compiled program ('decode',): # copies of a whole pool #",
         "compiled program ('decode',) donates the # arrays it was",
@@ -250,7 +250,7 @@ _OK_LINES = {
         "every served token of the first request scores within # ",
         "expert layers dropped # routed pairs of #",
         "expert products' forms {'decode': 'batched', 'prefill': ",
-        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "grouped products in the lowered programs: {('decode',): ",
         "program ('decode',) holds Mosaic calls {} (asked: {'page",
         "compiled program ('decode',): # copies of a whole pool #",
         "compiled program ('decode',) donates the # arrays it was",
@@ -271,7 +271,7 @@ _OK_LINES = {
         "every served token of the first request scores within # ",
         "expert layers dropped # routed pairs of #",
         "expert products' forms {'decode': 'batched', 'prefill': ",
-        "XLA's grouped matmul in the lowered programs: {('decode'",
+        "grouped products in the lowered programs: {('decode',): ",
         "program ('decode',) holds Mosaic calls {} (asked: {'page",
         "compiled program ('decode',): # copies of a whole pool #",
         "compiled program ('decode',) donates the # arrays it was",

@@ -153,7 +153,9 @@ def test_engine_prefill_then_decode_is_the_reference(model, tokens):
 def test_decode_is_batched_and_a_long_prefill_sorted():
     """A prompt of 150 tokens prefills in the 256 bucket, over the
     threshold: its expert layers sort and group (``ragged_dot`` in the
-    program as a TPU would get it), the decode program's (2 rows a step)
+    program lowered for a TPU from here: traced off the TPU, and at
+    widths the gate of ``ops/pallas/grouped_matmul.py`` refuses, the
+    grouped product is XLA's), the decode program's (2 rows a step)
     are batched over the held experts and hold none, and the served
     tokens are the reference's through both."""
     prompt = np.random.default_rng(5).integers(0, 256, 150).astype(np.int32)
@@ -163,6 +165,7 @@ def test_decode_is_batched_and_a_long_prefill_sorted():
     assert ref.served_gap(ref_logits(prompt, served), served).max() < 1e-3
     st = eng.moe_stats()
     assert st["forms"] == {"decode": "batched", "prefill": "sorted"}
+    assert st["grouped"] == {"decode": None, "prefill": "xla"}
     assert st["dropped"] == 0
     # the 256 bucket's 1,024 routed pairs, a quarter of them held: its
     # expert layers hold sorted_rows(256, 4, 4, 16) = 512 rows at a time
@@ -175,6 +178,7 @@ def test_decode_is_batched_and_a_long_prefill_sorted():
             lowering_platforms=("tpu",)).as_text()
     assert "ragged_dot" in texts["prefill"]
     assert "ragged_dot" not in texts["decode"]
+    assert not any("grouped_matmul" in t for t in texts.values())
 
 
 def test_the_layers_form_follows_the_token_count_alone(model):
@@ -192,9 +196,11 @@ def test_the_layers_form_follows_the_token_count_alone(model):
             jaxpr = jax.make_jaxpr(lambda v: layer(v)._value)(
                 jnp.zeros((1, T, 64), jnp.float32))
         finally:
-            forms = [r["form"] for r in moestats.drain()]
-        assert forms == [form]
+            recs = moestats.drain()
+        assert [r["form"] for r in recs] == [form]
         assert ("ragged_dot" in str(jaxpr)) == (form == "sorted")
+        assert [r.get("grouped") for r in recs] == \
+            ["xla" if form == "sorted" else None]
 
 
 def test_generate_over_the_static_cache_is_the_reference(model, tokens):
@@ -618,12 +624,12 @@ def test_serving_programs_compile_for_a_v5e_in_place():   # run's workers
     for c in progs.values():
         assert c["pool_copies"] == 0 and c["expert_weight_copies"] == 0, c
     # batched over the 16 held experts in decode, sorted and grouped in
-    # the prefill
+    # the prefill, on our kernel
     assert not progs["decode"]["ragged_dot"]
-    assert progs["prefill_1024"]["ragged_dot"]
+    assert not progs["prefill_1024"]["ragged_dot"]
     assert progs["decode"]["kernels"] == [
         "paged_decode_attention", "paged_window_decode_attention"]
-    assert not progs["prefill_1024"]["kernels"]
+    assert progs["prefill_1024"]["kernels"] == ["grouped_matmul"]
     assert progs["prefill_1024"]["largest_f32_elements"] < 64 * 1024 * 1024
     donated = progs["decode"]["donated"]
     assert len(donated) == 3 * 3, donated       # 3 layers x (k, v, counter)

@@ -126,7 +126,11 @@ def test_engine_prefill_then_decode_is_the_reference(model, tokens):
 def tpu_program_text(eng, site):
     """A serving program that has run, lowered for a TPU from here: the
     CPU's own lowering writes XLA's grouped matmul out as a masked dense
-    product, a TPU's keeps it (``ragged_dot``)."""
+    product, a TPU's keeps it (``ragged_dot``). (Traced here, so the
+    sorted products are XLA's whatever the widths: a TPU traces
+    ``ops/pallas/grouped_matmul.py`` where that kernel's gate admits
+    them, which this model's 32 and 64 are not; at the published widths
+    see ``test_serving_programs_compile_for_a_v5e_in_place``.)"""
     fn, avals = eng._site_programs[site]
     return fn.trace(*avals).lower(lowering_platforms=("tpu",)).as_text()
 
@@ -146,14 +150,17 @@ def test_decode_is_batched_and_a_long_prefill_sorted():
     assert ref.served_gap(ref_logits(prompt, served), served).max() < 1e-3
     st = eng.moe_stats()
     assert st["forms"] == {"decode": "batched", "prefill": "sorted"}
+    assert st["grouped"] == {"decode": None, "prefill": "xla"}
     assert st["dropped"] == 0
     # the 256 bucket's 1,024 routed pairs, a quarter of them held: its
     # expert layers hold sorted_rows(256, 4, 4, 16) = 512 rows at a time
     assert st["rows"] == {256: (512, 1024)}
     np.testing.assert_array_equal(st["summed_pairs"], st["pairs"].sum(1))
     assert eng.program_sites() == [("prefill", 256), ("decode",)]
-    assert "ragged_dot" in tpu_program_text(eng, ("prefill", 256))
-    assert "ragged_dot" not in tpu_program_text(eng, ("decode",))
+    text = tpu_program_text(eng, ("prefill", 256))
+    assert "ragged_dot" in text and "grouped_matmul" not in text
+    text = tpu_program_text(eng, ("decode",))
+    assert "ragged_dot" not in text and "grouped_matmul" not in text
 
 
 def test_generate_static_and_paged_agree_with_full_forwards(model, tokens):
@@ -350,7 +357,7 @@ def test_batched_form_is_the_sorted_form_is_a_loop_over_tokens(case):
             jnp.asarray(wg), jnp.asarray(wu), jnp.asarray(wd), off)
     for name, form in FORMS.items():
         if name == "sorted":
-            y, sizes, passes = form(*args, E)
+            y, sizes, passes, _ = form(*args, E)
             assert int(passes) == passes_want == max(
                 -(-int(pairs.sum()) // M), int(M == T * k))
         else:
@@ -383,7 +390,7 @@ def test_a_capacity_on_the_groups_shows_as_dropped_pairs(monkeypatch):
     x = jnp.ones((40, 64), jnp.float32)
     w = jnp.ones((4, 64, 32), jnp.float32)
     idx = jnp.tile(jnp.asarray([[9, 6, 12, 1]], jnp.int32), (40, 1))
-    _, sizes, _ = moe_layer.routed_swiglu_sorted(
+    _, sizes, _, _ = moe_layer.routed_swiglu_sorted(
         x, idx, jnp.ones((40, 4)), w, w, jnp.ones((4, 32, 64)),
         expert_offset=4)
     absent, summed = map(int, sizes[-2:])
@@ -489,7 +496,8 @@ def test_sorted_form_has_no_array_of_every_routed_pair(T):
     shapes, recs, grouped = _traced(layer, T)
     M = moe_layer.sorted_rows(T, k, El, E)
     assert grouped and M < T * k and M % moe_layer._SORTED_TILE == 0
-    assert [(r["form"], r["rows"]) for r in recs] == [("sorted", (M, T * k))]
+    assert [(r["form"], r["rows"], r["grouped"]) for r in recs] == \
+        [("sorted", (M, T * k), "xla")]
     wide = {d, h}
     assert [s for s in shapes if s and s[0] == T * k
             and (len(s) > 1 and s[-1] in wide)] == []
@@ -538,6 +546,10 @@ def test_the_form_is_a_function_of_the_token_count_alone():
         _, recs, grouped = _traced(layer, T)
         assert [r["form"] for r in recs] == [form]
         assert grouped == (form == "sorted")
+        # off the TPU the grouped product is XLA's; the batched form
+        # has none to name
+        assert [r.get("grouped") for r in recs] == \
+            ["xla" if grouped else None]
 
 
 # (columns of the table, tokens in cache a row): pages of 16 and a
@@ -673,10 +685,11 @@ def test_serving_programs_compile_for_a_v5e_in_place():
     """The engine's own decode and prefill programs of the decoder at
     the benchmark configuration's widths (two layers, no weights),
     compiled by the TPU compiler for a described v5e in a process of its
-    own: 0 pool-shaped copies, the latent kernel in decode, XLA's
-    grouped matmul in the prefill program and not in the decode program
-    (its products are batched over the 32 held experts), and no copy or
-    transpose of a stacked expert weight array in either."""
+    own: 0 pool-shaped copies, the latent kernel in decode, our grouped
+    matmul (``grouped_matmul``, and not XLA's) in the prefill program and
+    neither in the decode program (its products are batched over the 32
+    held experts), and no copy or transpose of a stacked expert weight
+    array in either."""
     import json
     import subprocess
 
@@ -692,9 +705,9 @@ def test_serving_programs_compile_for_a_v5e_in_place():
     for c in progs.values():
         assert c["pool_copies"] == 0 and c["expert_weight_copies"] == 0, c
     assert not progs["decode"]["ragged_dot"]
-    assert progs["prefill_1024"]["ragged_dot"]
+    assert not progs["prefill_1024"]["ragged_dot"]
     assert progs["decode"]["kernels"] == ["mla_paged_decode_attention"] \
-        and not progs["prefill_1024"]["kernels"]
+        and progs["prefill_1024"]["kernels"] == ["grouped_matmul"]
     # the pools and the routing counters are donated, the round's one
     # host array (tables, pos, token, mask) is not
     donated = progs["decode"]["donated"]

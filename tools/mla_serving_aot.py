@@ -24,10 +24,12 @@ pools of every page class are written in place), which of the decode
 kernels (``mla_paged_decode_attention``,
 ``mla_paged_sparse_decode_attention``, the prefill's
 ``kept_flash_attention``, ``paged_decode_attention``,
-``paged_window_decode_attention``, ``paged_sparse_decode_attention``)
+``paged_window_decode_attention``, ``paged_sparse_decode_attention``,
+and ``grouped_matmul``, the prefill's sorted expert products since PR 49)
 and whether XLA's grouped matmul
 (``ragged-dot``) are in it (the prefill programs' expert layers sort and
-group; a decode step's are batched over the held experts and hold none),
+group, on our kernel where its gate admits the widths and on XLA's where
+not; a decode step's are batched over the held experts and hold neither),
 copies or transposes of a stacked expert weight array (0: the batched
 products read ``[El, d, h]`` and ``[El, h, d]`` as they lie), the largest
 float32 buffer (a prefill program
@@ -142,7 +144,7 @@ def main(argv):
     kernels = ("mla_paged_decode_attention",
                "mla_paged_sparse_decode_attention", "kept_flash_attention",
                "paged_decode_attention", "paged_window_decode_attention",
-               "paged_sparse_decode_attention")
+               "paged_sparse_decode_attention", "grouped_matmul")
     pool_shapes = {s.shape for pair in eng.pools for s in pair}
     # the held experts' stacked weights, [El, d, h] and [El, h, d] (and
     # [El * h, d], as the batched down product reads them): an op named

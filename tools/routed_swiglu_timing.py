@@ -1,23 +1,39 @@
 """Time the held experts' products of one expert layer alone on the chip
-(PERF.md, PRs 32 and 40).
+(PERF.md, PRs 32, 40 and 49).
 
 Two sets of readings. ``decode``: at the two expert cells' decode shapes
 (``mimo``: 16 of 256 experts held; ``sarvam``: 32 of 128; both 128 tokens
 a decode step, top-8, d 4096, h 2048, bf16 weights) and at 256, 512 and
 1,024 tokens, where the two forms cross. ``prefill``: at the prefill
-buckets the four expert cells run (``keye``: 16 of 128 held, d 2048, h
-768, and ``trinity``: h 1,024, at 2,048 / 4,096 / 8,192 tokens; ``mimo``
-at 2,048; ``sarvam`` at 1,024). ``STEPS`` calls in one ``lax.scan``, each
-call's tokens made from the last call's output so that they run one
+buckets the expert cells run (``keye``: 16 of 128 held, d 2048, h 768,
+and ``trinity``: h 1,024, at 2,048 / 4,096 / 8,192 tokens; ``mimo`` at
+2,048; ``sarvam`` at 1,024; ``nemotron``: 128 of 512 held, top-22, latent
+1,024 -> 2,688 -> 1,024 with no gate matrix, at 1,024; ``longcat``: 16
+held of a 768-wide router, top-12, 6,144 -> 2,048, at 512; ``dsv32``: 16
+of 256, 7,168 -> 2,048, at 8,192). ``STEPS`` calls in one ``lax.scan``,
+each call's tokens made from the last call's output so that they run one
 after the other and dispatch does not count. One JSON line a reading:
-microseconds a call (three products), the bytes of expert weights a call
-streams, and that over the time as a share of the chip's HBM bandwidth;
-every form's output is checked against ``sorted_full``'s. The forms:
+microseconds a call (its two or three products), the bytes of expert
+weights a call streams, and that over the time as a share of the chip's
+HBM bandwidth; every form's output is checked against ``sorted_full``'s.
+``--forms a,b`` keeps the named forms. The forms:
 
 - ``sorted`` / ``batched``: ``moe_layer.routed_swiglu_sorted`` /
   ``routed_swiglu_batched`` as the library has them (the sorted one on
   ``moe_layer.sorted_rows`` rows at a time since PR 40: ``rows`` and
-  ``passes`` in its line);
+  ``passes`` in its line; since PR 49 its products are
+  ``ops/pallas/grouped_matmul.py`` where ``moe_layer.grouped_product``
+  says ``"pallas"``: ``grouped``, ``tm`` and ``tn`` in its line);
+- ``sorted_xla``: the same with kernels off: the products are
+  ``lax.ragged_dot``, XLA's grouped matmul (the library's before PR 49);
+- ``sorted_tm_other``: the library's form with the kernel's row tile
+  swapped, 128 for 256 and 256 for 128 (``grouped_matmul.row_tile``'s
+  docstring holds the readings);
+- ``sorted_gmm``: the library's form with ``jax.experimental.pallas.
+  ops.tpu.megablox.gmm`` for the product, K whole, row tile 256, column
+  tile up to 512 (128 x 1,024 passed Mosaic's VMEM at nemotron's widths
+  alone): a probe and a second opinion, fixed row tiles and a table of
+  (tile, group) visits;
 - ``sorted_full``: the sorted form as it was before PR 40, every routed
   pair a row of the ``[T*k, d]`` operand and of the float32 result;
 - ``sorted_by_token`` / ``sorted_gather_k``: the library's sorted form
@@ -52,31 +68,37 @@ from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 import paddle_tpu  # noqa: E402,F401  (places the compile cache)
 from benchmarks.harness.peaks import peaks_for  # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe import moe_layer as ml  # noqa: E402
+from paddle_tpu.ops.pallas import grouped_matmul as gm  # noqa: E402
 
-K = 8
 STEPS, CALLS = 16, 4
-SHAPES = {"mimo": (16, 256, 4096, 2048),     # held, routed over, d, h
-          "sarvam": (32, 128, 4096, 2048),
-          "keye": (16, 128, 2048, 768),
-          "trinity": (16, 128, 2048, 1024)}
+# held, routed over, d, h, choices a token, a gate matrix
+SHAPES = {"mimo": (16, 256, 4096, 2048, 8, True),
+          "sarvam": (32, 128, 4096, 2048, 8, True),
+          "keye": (16, 128, 2048, 768, 8, True),
+          "trinity": (16, 128, 2048, 1024, 8, True),
+          "nemotron": (128, 512, 1024, 2688, 22, False),
+          "longcat": (16, 768, 6144, 2048, 12, True),
+          "dsv32": (16, 256, 7168, 2048, 8, True)}
 DECODE = ("mimo", "sarvam")
 PREFILL = {"keye": (2048, 4096, 8192), "trinity": (2048, 4096, 8192),
-           "mimo": (2048,), "sarvam": (1024,)}
+           "mimo": (2048,), "sarvam": (1024,), "nemotron": (1024,),
+           "longcat": (512,), "dsv32": (8192,)}
 
 
 def sorted_full(x2d, idx, weights, w_gate, w_up, w_down, off=0):
     """``routed_swiglu_sorted`` before PR 40."""
     T, k = idx.shape
-    El = w_gate.shape[0]
+    El = w_up.shape[0]
     local = idx - off
     held = (local >= 0) & (local < El)
     e = jnp.where(held, local, El).reshape(T * k)
     order = jnp.argsort(e, stable=True)
     gs = jnp.bincount(e, length=El).astype(jnp.int32)
     xs = x2d[order // k]
-    g = lax.ragged_dot(xs, w_gate, gs, preferred_element_type=jnp.float32)
+    g = None if w_gate is None else lax.ragged_dot(
+        xs, w_gate, gs, preferred_element_type=jnp.float32)
     u = lax.ragged_dot(xs, w_up, gs, preferred_element_type=jnp.float32)
-    out = lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype), w_down,
+    out = lax.ragged_dot(ml._activation(g, u).astype(x2d.dtype), w_down,
                          gs, preferred_element_type=jnp.float32)
     used = held.reshape(T * k)[order] & (jnp.arange(T * k) < gs.sum())
     wf = weights.reshape(T * k)[order]
@@ -102,22 +124,54 @@ def gather_k(y, rows, tok):
     tok, at = lax.sort((tok, jnp.arange(M, dtype=jnp.int32)), num_keys=1,
                        is_stable=True)
     first = jnp.searchsorted(tok, jnp.arange(T + 1, dtype=tok.dtype))
-    for j in range(K):
+    for j in range(8):          # the four cells it was timed at: top-8
         i = first[:-1] + j
         y = y + jnp.where((i < first[1:])[:, None],
                           rows[at[jnp.minimum(i, M - 1)]], 0.0)
     return y
 
 
-def with_sum(fn):
-    """The library's sorted form with ``fn`` for ``_sum_by_token``."""
+def with_swap(name, fn):
+    """The library's sorted form with ``fn`` for ``moe_layer``'s
+    ``name`` while it is traced."""
     def form(*a):
-        keep, ml._sum_by_token = ml._sum_by_token, fn
+        keep = getattr(ml, name)
+        setattr(ml, name, fn)
         try:
             return ml.routed_swiglu_sorted(*a)
         finally:
-            ml._sum_by_token = keep
+            setattr(ml, name, keep)
     return form
+
+
+def sorted_tm_other(*a):
+    """The library's sorted form with the kernel's OTHER row tile (256
+    where ``row_tile`` says 128, 128 where 256)."""
+    keep = gm.row_tile
+    gm.row_tile = lambda M, G: 384 - keep(M, G)
+    try:
+        return ml.routed_swiglu_sorted(*a)
+    finally:
+        gm.row_tile = keep
+
+
+def sorted_xla(*a):
+    """The library's sorted form traced with kernels off."""
+    paddle_tpu.set_flags({"use_pallas_kernels": False})
+    try:
+        return ml.routed_swiglu_sorted(*a)
+    finally:
+        paddle_tpu.set_flags({"use_pallas_kernels": True})
+
+
+def megablox(lhs, rhs, group_sizes, visits=None):
+    """``megablox.gmm`` in ``grouped_matmul``'s place: K whole, rows in
+    fixed tiles of 256, columns in the largest lane-multiple tile of N
+    that divides it, up to 512."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    K, N = rhs.shape[1:]
+    tn = max(t for t in range(128, min(N, 512) + 1, 128) if N % t == 0)
+    return gmm(lhs, rhs, group_sizes, jnp.float32, (256, K, tn))
 
 
 def batched_unfolded(x, idx, w, wg, wu, wd, off=0):
@@ -191,9 +245,12 @@ def batched_pallas(x, idx, w, wg, wu, wd, off=0, th=256, interpret=False):
 
 # every form takes (x, idx, w, wg, wu, wd, expert_offset, router's width)
 FORMS = {"sorted": ml.routed_swiglu_sorted,
+         "sorted_xla": sorted_xla,
+         "sorted_tm_other": sorted_tm_other,
+         "sorted_gmm": with_swap("grouped_matmul", megablox),
          "sorted_full": lambda *a: sorted_full(*a[:7]),
-         "sorted_by_token": with_sum(by_token),
-         "sorted_gather_k": with_sum(gather_k),
+         "sorted_by_token": with_swap("_sum_by_token", by_token),
+         "sorted_gather_k": with_swap("_sum_by_token", gather_k),
          "batched": lambda *a: ml.routed_swiglu_batched(*a[:7]),
          "batched_unfolded": lambda *a: batched_unfolded(*a[:7]),
          "batched_plain": lambda *a: batched_plain(*a[:7]),
@@ -218,12 +275,14 @@ def reading(cell, T, form, ops, E, peak):
         out = run(x, idx, w, wg, wu, wd)
     out.block_until_ready()
     us = (time.perf_counter() - t0) / (CALLS * STEPS) * 1e6
-    nbytes = 3 * wg.size * wg.dtype.itemsize
-    held = int(((np.asarray(idx) >= 0) & (np.asarray(idx) < wg.shape[0]))
+    products = 2 if wg is None else 3
+    nbytes = products * wu.size * wu.dtype.itemsize
+    held = int(((np.asarray(idx) >= 0) & (np.asarray(idx) < wu.shape[0]))
                .sum())
     return {"cell": cell, "tokens": T, "form": form, "us_a_call": us,
-            "us_a_product": us / 3, "weight_bytes": nbytes,
-            "held_pairs": held, "sorted_rows": T * K,
+            "us_a_product": us / products, "weight_bytes": nbytes,
+            "stream_us": nbytes / peak.hbm_bytes * 1e6,
+            "held_pairs": held, "sorted_rows": idx.size,
             "hbm_share": nbytes / (us * 1e-6) / peak.hbm_bytes}
 
 
@@ -243,34 +302,51 @@ def main():
     dev = jax.devices()[0]
     peak = peaks_for(dev.device_kind)
     r = np.random.RandomState(0)
-    sets = [a for a in sys.argv[1:] if a in ("decode", "prefill")]
-    cells = [a for a in sys.argv[1:] if a in SHAPES]
+    args = sys.argv[1:]
+    sets = [a for a in args if a in ("decode", "prefill")]
+    cells = [a for a in args if a in SHAPES]
+    only = args[args.index("--forms") + 1].split(",") \
+        if "--forms" in args else None
     out, made = [], {}
     for cell, T, forms in cases(sets or ["decode", "prefill"], cells):
-        El, E, d, h = SHAPES[cell]
+        El, E, d, h, K, gated = SHAPES[cell]
         if cell not in made:       # one cell's weights at a time
             keys = jax.random.split(jax.random.PRNGKey(El), 3)
             made = {cell: tuple(
                 0.02 * jax.random.normal(k, s, jnp.bfloat16)
                 for k, s in zip(keys, ((El, d, h), (El, d, h),
                                        (El, h, d))))}
+            if not gated:
+                made[cell] = (None,) + made[cell][1:]
         x = jnp.asarray(r.randn(T, d), jnp.bfloat16)
         idx = jnp.asarray(np.argsort(r.random_sample((T, E)))[:, :K],
                           jnp.int32)
         w = jnp.asarray(r.uniform(0.05, 0.2, (T, K)), jnp.float32)
         ops = (x, idx, w) + made[cell]
         want = np.asarray(jax.jit(sorted_full)(*ops)[0])
-        for form in forms:
+        for form in forms if only is None else \
+                [f for f in forms if f in only]:
             try:
                 row = reading(cell, T, form, ops, E, peak)
-                got = jax.jit(lambda *a: FORMS[form](*a, 0, E))(*ops)
+                said = []     # what the form returned beside arrays
+
+                def call(*a):
+                    got = FORMS[form](*a, 0, E)
+                    said[:] = got[3:]
+                    return got[:3]
+                got = jax.jit(call)(*ops)
             except Exception as err:    # a form the compiler refuses
                 print(json.dumps({"cell": cell, "tokens": T, "form": form,
                                   "error": repr(err)[:300]}), flush=True)
                 continue
-            if form == "sorted":
-                row["rows"] = ml.sorted_rows(T, K, El, E)
+            if form.startswith("sorted") and form != "sorted_full":
+                M = row["rows"] = ml.sorted_rows(T, K, El, E)
                 row["passes"] = int(got[2])
+                row["grouped"], = said
+                if form == "sorted":
+                    tm = row["tm"] = gm.row_tile(M, El)
+                    row["tn"] = [gm.col_tile(M, a, b, tm, 2)
+                                 for a, b in ((d, h), (h, d))]
             row["max_gap_to_sorted_full"] = float(
                 np.abs(np.asarray(got[0]) - want).max())
             row["max_abs"] = float(np.abs(want).max())
